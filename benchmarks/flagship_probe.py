@@ -28,7 +28,7 @@ import sys
 import numpy as np
 
 from common import (adaptive_slope, best_of_calls, control_block,
-                    detect_platform, emit, gen_of, measure_null_rtt)
+                    detect_platform, emit, gen_of, measure_dispatch_floor)
 
 # a real (small-LLM-block-sized) config: bf16 params/activations, f32 loss
 D_MODEL, N_HEADS, N_LAYERS, D_FF = 1024, 16, 8, 4096
@@ -87,7 +87,7 @@ def main() -> None:
     record["generation"] = gen
     record["bf16_peak_tflops"] = peak / 1e12
 
-    rtt = measure_null_rtt()
+    rtt = measure_dispatch_floor()
     cfg = TransformerConfig(vocab=VOCAB, d_model=D_MODEL, n_heads=N_HEADS,
                             n_layers=N_LAYERS, d_ff=D_FF, max_seq=SEQ,
                             dtype=jnp.bfloat16)

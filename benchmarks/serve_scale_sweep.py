@@ -150,7 +150,7 @@ def bench_fleet(target, tenants: int, ops: int, drivers: int,
                 rounds: int, token: str) -> dict:
     """Attach ``tenants`` concurrent leases (through the router when the
     target is one, else straight at the single broker), drive the op phase
-    ``rounds`` times (best rate kept — a 1-core box draws weather), detach.
+    ``rounds`` times (best rate kept — a 1-core box is noisy), detach.
     """
     from tpu_mpi import serve
     x = np.ones(8, np.float32)

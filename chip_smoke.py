@@ -1,0 +1,652 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+Drives the four ways a user reaches the device, once each, through the
+normal entry points, in ONE process (a chip belongs to one process; this
+script starts no child that needs it):
+
+1. host   — ``tpu_mpi.launcher.main(["-n", "4", <script>])`` (the ``tpurun``
+   entry, rank threads): ``DeviceBuffer`` Float32[2^26] on ``comm.device``
+   through Allreduce (eager first call, the compiled ``_jitted_fold`` with
+   the fused Pallas kernel, the auto-armed registered lane), a hand-armed
+   ``Allreduce_init``/``Start``/``Wait`` (donated fold), Bcast,
+   Reduce_scatter, Alltoall, a Sendrecv ring, one Win Put/Get epoch, Barrier;
+2. ingraph — ``xla.allreduce/allgather/reduce_scatter/alltoall/sendrecv``
+   under ``jit(shard_map)`` at Float32[2^26] per device, and
+   ``transformer_train_step`` at the widest configuration the repo runs
+   (benchmarks/flagship_probe.py), a few steps on a fixed batch;
+3. kernels — every public kernel of ``tpu_mpi.xla.pallas_kernels`` compiled
+   by Mosaic, numerics against the XLA collective or a jnp reference;
+4. serve  — ``serve.Broker(nranks=4, infer=True)`` answering three
+   ``session.generate`` calls. The engine is host numpy by design (ROADMAP
+   S3): this leg proves the broker, the event front door and the native
+   transport build and answer on this machine, and says so.
+
+Every result is checked against a closed form or a reference after a host
+readback; on the chip every collective result is asserted to be a
+``jax.Array`` resident on the TPU device that owns it. With four chips rank
+i's buffers live on chip i, the train mesh is dp x tp x sp = 1 x 2 x 2 and
+the ring kernels run at n = 4 with remote DMA.
+
+Refuses to run (exit 2, one line on stderr, no result) unless JAX's default
+backend is a TPU whose ``device_kind`` the capability table knows. It sets
+``TPU_MPI_BACKEND=tpu``, so nothing underneath falls back to the CPU either.
+``--tiny-cpu`` runs the same leg functions at toy sizes on the CPU with the
+kernels in interpret mode; it exists for tests/test_chip_smoke.py and must
+be asked for — the absence of a chip never selects it.
+
+Per-leg compile and run seconds are printed as set-up facts, not metrics.
+The last line of stdout is one JSON object:
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+import traceback
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+NRANKS = 4
+PERIOD = 251          # pattern period: coprime to every block size used
+
+# What runs on the chip. The train configuration is the widest the repo
+# runs (benchmarks/flagship_probe.py): nothing is cut.
+FULL = {
+    "n": 1 << 26,                 # Float32[2^26] per rank / per device
+    "win": 1 << 20,               # RMA window elements (Put/Get go via host)
+    "train": dict(vocab=32768, d_model=1024, n_heads=16, n_layers=8,
+                  d_ff=4096, max_seq=1024, batch=8, steps=4, lr=0.01),
+    "ring": 250_000,              # per-device elements of the ring kernels
+    "attn": (2048, 128),          # per-device (seq, head_dim), bf16 causal
+    "fused": 1 << 26,             # 4 x [2^26] fused fold, f32 and bf16
+    "max_new": 8,
+}
+# Toy sizes for the tier-1 CPU test only.
+TINY = {
+    "n": 1 << 12,
+    "win": 1 << 8,
+    "train": dict(vocab=128, d_model=64, n_heads=4, n_layers=2, d_ff=128,
+                  max_seq=32, batch=4, steps=3, lr=0.02),
+    "ring": 1000,                 # the interpreter stalls on larger rings
+    "attn": (32, 64),
+    "fused": 1 << 12,
+    "max_new": 4,
+}
+
+
+def _log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# ---------------------------------------------------------------------------
+# leg 1: the host MPI path
+# ---------------------------------------------------------------------------
+
+def _pattern_at(i: int, lo: int, blk: int, mul: int, add: int,
+                step: int) -> float:
+    """The closed form every host-leg operand and result follows, at one
+    position, in plain Python: ``mul * ((lo + i % blk) % PERIOD) + add +
+    (i // blk) * step``."""
+    return float(mul * ((lo + i % blk) % PERIOD) + add + (i // blk) * step)
+
+
+@functools.lru_cache(maxsize=None)
+def _device_fns():
+    """(pattern, verify), jitted once for every rank thread: the closed form
+    as a device array, and a result's agreement with it (everywhere, as one
+    bool, plus three sampled values for the host to judge itself)."""
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def pattern(cnt, lo, blk, mul, add, step):
+        i = jnp.arange(cnt, dtype=jnp.int32)
+        return (mul * ((lo + i % blk) % PERIOD) + add
+                + (i // blk) * step).astype(jnp.float32)
+
+    @jax.jit
+    def verify(v, lo, blk, mul, add, step):
+        want = pattern(v.size, lo, blk, mul, add, step)
+        at = jnp.array([0, v.size // 2 + 1, v.size - 1])
+        return jnp.array_equal(v, want), v[at]
+
+    return pattern, verify
+
+
+def _host_rank(outdir: str, n: int, win_n: int) -> None:
+    """The SPMD program every rank runs (under ``tpurun`` as rank threads,
+    or — by hand on the four-chip host — under ``tpurun --procs`` with one
+    chip per process). Writes ``rank<r>.json`` into ``outdir``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import tpu_mpi as MPI
+    from tpu_mpi import collective
+    from tpu_mpi.overlap import plans
+
+    MPI.Init()
+    comm = MPI.COMM_WORLD
+    r, size = comm.rank(), comm.size()
+    dev = comm.device
+    threads = os.environ.get("TPU_MPI_PROC_RANK") is None
+    facts: dict = {"rank": r, "device": dev.id, "platform": dev.platform,
+                   "tier": "threads" if threads else "procs"}
+    chunk = n // size
+    const = size * (size - 1) // 2
+    left, right = (r - 1) % size, (r + 1) % size
+    pattern, verify = _device_fns()
+
+    def buf(cnt: int, *form) -> "MPI.DeviceBuffer":
+        """A DeviceBuffer on this rank's chip: the pattern, or zeros."""
+        return MPI.DeviceBuffer(pattern(cnt, *form) if form
+                                else jnp.zeros(cnt, jnp.float32), device=dev)
+
+    def check(what: str, x: "MPI.DeviceBuffer", *form) -> None:
+        """The result is a jax.Array resident on this rank's chip and equals
+        the closed form: everywhere (compared on the device, one bool read
+        back) and at three positions read back and judged in Python."""
+        v = x.value
+        assert isinstance(v, jax.Array), (what, type(v))
+        assert v.devices() == {dev}, (what, v.devices(), dev)
+        ok, got = verify(v, *form)
+        assert bool(ok), f"{what}: wrong values"
+        at = (0, v.size // 2 + 1, v.size - 1)
+        assert np.asarray(got).tolist() == \
+            [_pattern_at(i, *form) for i in at], (what, got)
+
+    whole = (0, n)                      # (lo, blk) of an unblocked array
+    total = (*whole, size, const, 0)    # the sum over ranks of pattern + r
+    with jax.default_device(dev):
+        send = buf(n, *whole, 1, r, 0)
+        out = buf(n)
+
+        # -- plain Allreduce, repeated past the auto-arm threshold ----------
+        times = []
+        for k in range(8):
+            out.fill(0)
+            out.value.block_until_ready()
+            t0 = time.perf_counter()
+            MPI.Allreduce(send, out, MPI.SUM, comm)
+            out.value.block_until_ready()
+            times.append(time.perf_counter() - t0)
+            check(f"Allreduce#{k}", out, *total)
+        facts["allreduce_s"] = {"first_eager": times[0],
+                                "second_compile": times[1],
+                                "last_armed": times[-1]}
+        if threads:
+            assert plans.auto_hits > 0, "the auto-armed lane never ran"
+            key = (MPI.SUM.fn, "reduce", size, "float32", ((n,),) * size)
+            fold = collective._fold_compiled.get(key)
+            assert fold is not None and fold is not collective._NOT_JITTABLE
+            if dev.platform == "tpu":
+                text = fold.lower(*[jax.ShapeDtypeStruct(
+                    (n,), jnp.float32)] * size).as_text()
+                assert "tpu_custom_call" in text, \
+                    "the compiled fold is not the Mosaic fused kernel"
+            facts["fold"] = "fused" if dev.platform == "tpu" else "traced"
+
+        # -- hand-armed persistent Allreduce: the donated fold --------------
+        t0 = time.perf_counter()
+        req = MPI.Allreduce_init(send, out, MPI.SUM, comm)
+        for k in range(3):
+            out.fill(0)
+            MPI.Start(req)
+            MPI.Wait(req)
+            check(f"Allreduce_init#{k}", out, *total)
+        facts["persistent_s"] = time.perf_counter() - t0
+
+        # -- Bcast from rank 1 ----------------------------------------------
+        root = 1 % size
+        b = buf(n, *whole, 1, r, 0)
+        MPI.Bcast(b, root, comm)
+        check("Bcast", b, *whole, 1, root, 0)
+
+        # -- Reduce_scatter: rank r keeps block r of the sum -----------------
+        rs = buf(chunk)
+        MPI.Reduce_scatter(send, rs, [chunk] * size, MPI.SUM, comm)
+        check("Reduce_scatter", rs, r * chunk, chunk, size, const, 0)
+
+        # -- Alltoall: block s of the result is sender s's block r -----------
+        a2a = buf(n)
+        MPI.Alltoall(send, a2a, chunk, comm)
+        check("Alltoall", a2a, r * chunk, chunk, 1, 0, 1)
+
+        # -- Sendrecv ring ----------------------------------------------------
+        ring = buf(n)
+        MPI.Sendrecv(send, right, 7, ring, left, 7, comm)
+        check("Sendrecv", ring, *whole, 1, left, 0)
+
+        # -- one RMA epoch: Put into the right neighbour, Get it back --------
+        wbuf, got = buf(win_n), buf(win_n)
+        win = MPI.Win_create(wbuf, comm)
+        MPI.Win_fence(0, win)
+        MPI.Put(buf(win_n, 0, win_n, 1, r, 0), right, win)
+        MPI.Win_fence(0, win)
+        MPI.Get(got, right, win)
+        MPI.Win_fence(0, win)
+        check("Put", wbuf, 0, win_n, 1, left, 0)
+        check("Get", got, 0, win_n, 1, r, 0)
+
+    MPI.Barrier(comm)
+    MPI.Finalize()
+    with open(os.path.join(outdir, f"rank{r}.json"), "w") as f:
+        json.dump(facts, f)
+
+
+def _host_rank_entry(argv: list) -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--host-rank", dest="outdir", required=True)
+    ap.add_argument("--n", type=int, default=FULL["n"])
+    ap.add_argument("--win", type=int, default=FULL["win"])
+    a = ap.parse_args(argv)
+    os.makedirs(a.outdir, exist_ok=True)
+    try:
+        _host_rank(a.outdir, a.n, a.win)
+    except BaseException:
+        traceback.print_exc()       # tpurun reports only the message
+        raise
+
+
+def leg_host(sz: dict, platform: str) -> dict:
+    import jax
+    from tpu_mpi import launcher
+
+    outdir = tempfile.mkdtemp(prefix="chip_smoke_host_")
+    try:
+        t0 = time.perf_counter()
+        rc = launcher.main(["-n", str(NRANKS), os.path.abspath(__file__),
+                            "--host-rank", outdir, "--n", str(sz["n"]),
+                            "--win", str(sz["win"])])
+        wall = time.perf_counter() - t0
+        assert rc == 0, f"tpurun -n {NRANKS} exited {rc}"
+        ranks = []
+        for r in range(NRANKS):
+            with open(os.path.join(outdir, f"rank{r}.json")) as f:
+                ranks.append(json.load(f))
+    finally:
+        shutil.rmtree(outdir, ignore_errors=True)
+    assert all(f["platform"] == platform for f in ranks), ranks
+    devices = sorted({f["device"] for f in ranks})
+    want = min(NRANKS, len(jax.devices()))
+    assert len(devices) == want, \
+        f"rank operands on devices {devices}, expected {want} distinct"
+    return {"wall_s": round(wall, 2), "rank_devices": devices,
+            "allreduce_s": {k: round(v, 4) for k, v in
+                            ranks[0]["allreduce_s"].items()},
+            "persistent_s": round(ranks[0]["persistent_s"], 3),
+            "fold": ranks[0]["fold"]}
+
+
+# ---------------------------------------------------------------------------
+# leg 2: the in-graph tier
+# ---------------------------------------------------------------------------
+
+def _train_axes(ndev: int) -> dict:
+    """dp x tp x sp over ndev devices: 1x1x1 on one chip, 1x2x2 on four."""
+    tp = 2 if ndev % 2 == 0 else 1
+    sp = 2 if (ndev // tp) % 2 == 0 else 1
+    return {"dp": ndev // (tp * sp), "tp": tp, "sp": sp}
+
+
+def leg_ingraph(sz: dict, platform: str) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    import tpu_mpi as MPI
+    from tpu_mpi import xla
+    from tpu_mpi.models.transformer import (TransformerConfig,
+                                            transformer_init,
+                                            transformer_train_step)
+
+    devs = jax.devices()
+    nd, n = len(devs), sz["n"]
+    c = n // nd
+    const = nd * (nd - 1) // 2
+    mesh = xla.make_mesh({"x": nd})
+    shard = NamedSharding(mesh, P("x"))
+    facts: dict = {"devices": nd}
+
+    def on_mesh(fn, length):
+        """A global [length] array sharded over x, from its index formula."""
+        return jax.jit(lambda: fn(jnp.arange(length, dtype=jnp.int32))
+                       .astype(jnp.float32), out_shardings=shard)()
+
+    # device d's shard is pattern(i) + d
+    x = on_mesh(lambda j: (j % n) % PERIOD + j // n, nd * n)
+    ring = [(d + 1) % nd for d in range(nd)]
+    cases = {
+        "allreduce": (lambda v: xla.allreduce(v, MPI.SUM, axis="x"),
+                      lambda j: nd * ((j % n) % PERIOD) + const, nd * n),
+        # device d's (nd, n) stack flattened: row s is source s's shard
+        "allgather": (lambda v: xla.allgather(v, axis="x").reshape(-1),
+                      lambda j: (j % n) % PERIOD + (j // n) % nd,
+                      nd * nd * n),
+        "reduce_scatter": (lambda v: xla.reduce_scatter(v, MPI.SUM,
+                                                        axis="x"),
+                           lambda j: nd * (j % PERIOD) + const, n),
+        # device d, local i: source s = i // c sent its block d
+        "alltoall": (lambda v: xla.alltoall(v, axis="x"),
+                     lambda j: ((j // n) * c + (j % n) % c) % PERIOD
+                     + (j % n) // c, nd * n),
+        "sendrecv": (lambda v: xla.sendrecv(v, dest=ring, axis="x"),
+                     lambda j: (j % n) % PERIOD + (j // n - 1) % nd, nd * n),
+    }
+    facts["collectives_s"] = {}
+    for name, (fn, formula, length) in cases.items():
+        f = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("x"),
+                                  out_specs=P("x")))
+        t0 = time.perf_counter()
+        compiled = f.lower(x).compile()
+        t1 = time.perf_counter()
+        y = compiled(x)
+        y.block_until_ready()
+        t2 = time.perf_counter()
+        assert y.shape == (length,), (name, y.shape)
+        assert {d.platform for d in y.devices()} == {platform}, name
+        assert bool(jnp.array_equal(y, on_mesh(formula, length))), \
+            f"xla.{name}: wrong values"
+        for j in (0, length // 2 + 1, length - 1):     # host readback
+            assert float(y[j]) == float(formula(np.int64(j))), (name, j)
+        facts["collectives_s"][name] = {"compile": round(t1 - t0, 3),
+                                        "run": round(t2 - t1, 4)}
+        del y
+
+    # -- the flagship train step, full width ----------------------------------
+    tr = dict(sz["train"])
+    batch, steps, lr = tr.pop("batch"), tr.pop("steps"), tr.pop("lr")
+    axes = _train_axes(nd)
+    cfg = TransformerConfig(dtype=jnp.bfloat16, **tr)
+    tmesh = xla.make_mesh(axes)
+    step, specs = transformer_train_step(cfg, tmesh, lr=lr)
+    # inputs placed as the step shards them, so the compiled step's outputs
+    # feed straight back in
+    params = jax.device_put(
+        transformer_init(jax.random.PRNGKey(0), cfg),
+        jax.tree.map(lambda s: NamedSharding(tmesh, s), specs))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, cfg.max_seq),
+                                0, cfg.vocab)
+    tokens, labels = jax.device_put(
+        (tokens, jnp.roll(tokens, -1, axis=1)),
+        NamedSharding(tmesh, P("dp", "sp")))
+    t0 = time.perf_counter()
+    compiled = step.lower(params, tokens, labels).compile()
+    compile_s = time.perf_counter() - t0
+    losses, step_s = [], []
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        params, loss = compiled(params, tokens, labels)
+        losses.append(float(loss))                      # host readback
+        step_s.append(time.perf_counter() - t0)
+    assert all(np.isfinite(v) for v in losses), losses
+    assert losses[-1] < losses[0], f"loss did not fall: {losses}"
+    leaf = jax.tree_util.tree_leaves(params)[0]
+    assert {d.platform for d in leaf.devices()} == {platform}
+    facts["train"] = {"mesh": axes, "config": dict(sz["train"]),
+                      "compile_s": round(compile_s, 2),
+                      "step_s": [round(s, 4) for s in step_s],
+                      "losses": [round(v, 4) for v in losses]}
+    return facts
+
+
+# ---------------------------------------------------------------------------
+# leg 3: the Pallas kernels
+# ---------------------------------------------------------------------------
+
+def leg_kernels(sz: dict, platform: str) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    import tpu_mpi as MPI
+    from tpu_mpi import xla
+    from tpu_mpi.xla import pallas_kernels as pk
+
+    interpret = platform != "tpu"       # explicit, either way
+    devs = jax.devices()
+    nd = len(devs)
+    mesh = xla.make_mesh({"x": nd})
+    shard = NamedSharding(mesh, P("x"))
+    facts: dict = {"n": nd, "interpret": interpret, "seconds": {}}
+
+    def timed(name, f, *args):
+        t0 = time.perf_counter()
+        compiled = f.lower(*args).compile()
+        t1 = time.perf_counter()
+        if not interpret:
+            assert "tpu_custom_call" in compiled.as_text(), \
+                f"{name}: no Mosaic custom call in the compiled module"
+        out = compiled(*args)
+        jax.block_until_ready(out)
+        facts["seconds"][name] = {"compile": round(t1 - t0, 3),
+                                  "run": round(time.perf_counter() - t1, 4)}
+        return out
+
+    def smap(fn, nargs=1):
+        return jax.jit(jax.shard_map(fn, mesh=mesh,
+                                     in_specs=(P("x"),) * nargs,
+                                     out_specs=P("x"), check_vma=False))
+
+    # -- the five RDMA collectives against their XLA counterparts --------------
+    # per-device length divisible by nd (block collectives) but with a row
+    # count that is not a multiple of any sublane tile, so the padding runs
+    m = sz["ring"] // nd * nd
+    perm = [(d + 1) % nd for d in range(nd)]
+    for dtype in (jnp.float32, jnp.bfloat16):
+        tag = jnp.dtype(dtype).name
+        x = jax.jit(lambda: ((jnp.arange(nd * m, dtype=jnp.int32) % 13)
+                             + jnp.arange(nd * m, dtype=jnp.int32) // m)
+                    .astype(dtype), out_shardings=shard)()
+        pairs = {
+            "collective_permute": (
+                lambda v: pk.collective_permute(v, perm, axis="x",
+                                                interpret=interpret),
+                lambda v: xla.sendrecv(v, dest=perm, axis="x")),
+            "ring_allgather": (
+                lambda v: pk.ring_allgather(v, axis="x",
+                                            interpret=interpret).reshape(-1),
+                lambda v: xla.allgather(v, axis="x").reshape(-1)),
+            "ring_allreduce": (
+                lambda v: pk.ring_allreduce(v, MPI.SUM, axis="x",
+                                            interpret=interpret),
+                lambda v: xla.allreduce(v, MPI.SUM, axis="x")),
+            "ring_reduce_scatter": (
+                lambda v: pk.ring_reduce_scatter(v, MPI.SUM, axis="x",
+                                                 interpret=interpret),
+                lambda v: xla.reduce_scatter(v, MPI.SUM, axis="x")),
+            "pairwise_alltoall": (
+                lambda v: pk.pairwise_alltoall(v, axis="x",
+                                               interpret=interpret),
+                lambda v: xla.alltoall(v, axis="x")),
+        }
+        for name, (kern, ref) in pairs.items():
+            if nd == 1 and name not in ("collective_permute",
+                                        "ring_allgather"):
+                continue    # a ring of one returns its operand: no kernel
+            got = timed(f"{name}[{tag}]", smap(kern), x)
+            want = smap(ref)(x)
+            assert {d.platform for d in got.devices()} == {platform}
+            assert bool(jnp.array_equal(got, want)), f"{name}[{tag}] != XLA"
+            assert np.array_equal(np.asarray(got[:8], np.float32),
+                                  np.asarray(want[:8], np.float32))
+    if nd == 1:
+        facts["identity_at_n1"] = ["ring_allreduce", "ring_reduce_scatter",
+                                   "pairwise_alltoall"]
+
+    # an operand the ring kernels cannot hold in VMEM is refused by name
+    big = jax.ShapeDtypeStruct((nd * (1 << 26),), jnp.float32, sharding=shard)
+    try:
+        smap(lambda v: pk.ring_allgather(v, axis="x",
+                                         interpret=interpret)).lower(big)
+    except ValueError as e:
+        assert "VMEM" in str(e), e
+        facts["oversize"] = "ValueError"
+    else:
+        raise AssertionError("a 256 MiB ring operand was not refused")
+
+    # -- fused causal ring attention against a float32 jnp reference -----------
+    t, d = sz["attn"]
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.device_put(
+        jax.random.normal(kk, (nd * t, d), jnp.float32).astype(jnp.bfloat16),
+        shard) for kk in keys)
+    got = timed("ring_attention[bfloat16]", smap(
+        lambda a, b, c: pk.ring_attention(a, b, c, axis="x", causal=True,
+                                          interpret=interpret), 3), q, k, v)
+
+    @jax.jit
+    def reference(q, k, v):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        s = jnp.dot(q, k.T, precision="highest") / np.sqrt(d)
+        s = jnp.where(jnp.tril(jnp.ones(s.shape, bool)), s, -jnp.inf)
+        return jnp.dot(jax.nn.softmax(s, axis=-1), v, precision="highest")
+
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                - reference(q, k, v))))
+    # bf16 operands and a bf16 result against float32 math: half a bf16 ulp
+    # of O(1) outputs is 4e-3, the probabilities' rounding adds the rest
+    assert err < 2e-2, f"ring_attention max abs err {err}"
+    facts["attention_max_abs_err"] = err
+
+    # -- the fused multi-operand fold: 4 streams, f32 and bf16 -----------------
+    nf = sz["fused"]
+    for dtype in (jnp.float32, jnp.bfloat16):
+        tag = jnp.dtype(dtype).name
+        xs = [((jnp.arange(nf, dtype=jnp.int32) % 7) + s).astype(dtype)
+              for s in range(NRANKS)]
+        got = timed(f"fused_multi_reduce[{tag}]", jax.jit(
+            lambda *a: pk.fused_multi_reduce(a, MPI.SUM,
+                                             interpret=interpret)), *xs)
+        want = jax.jit(lambda *a: ((a[0] + a[1]) + a[2]) + a[3])(*xs)
+        assert bool(jnp.array_equal(got, want)), f"fused fold [{tag}]"
+        assert float(got[nf - 1]) == float(4 * ((nf - 1) % 7) + 6)
+        del xs, got, want
+    return facts
+
+
+# ---------------------------------------------------------------------------
+# leg 4: the server
+# ---------------------------------------------------------------------------
+
+def leg_serve(sz: dict, platform: str) -> dict:
+    from tpu_mpi import serve
+
+    token = "chip-smoke"
+    t0 = time.perf_counter()
+    broker = serve.Broker(nranks=NRANKS, token=token, infer=True)
+    broker.run_in_thread()
+    try:
+        up = time.perf_counter() - t0
+        s = serve.attach(broker.address, token=token, tenant="smoke")
+        try:
+            prompts = ([1, 2, 3, 4, 5, 6, 7], [1, 2, 3, 4, 5, 6, 7],
+                       list(range(40, 56)))
+            t0 = time.perf_counter()
+            outs = [s.generate(p, max_new=sz["max_new"]) for p in prompts]
+            gen = time.perf_counter() - t0
+        finally:
+            s.detach()
+        vocab = broker.infer_engine.cfg.vocab
+        transport = broker.transport
+    finally:
+        broker.close()
+    assert all(len(o) == sz["max_new"] for o in outs), outs
+    assert all(0 <= tok < vocab for o in outs for tok in o), outs
+    assert outs[0] == outs[1], "identical prompts decoded differently"
+    return {"device_work": "none — host engine", "transport": transport,
+            "requests": len(outs), "broker_up_s": round(up, 3),
+            "generate_s": round(gen, 3)}
+
+
+LEGS = (("host", leg_host), ("ingraph", leg_ingraph),
+        ("kernels", leg_kernels), ("serve", leg_serve))
+
+
+# ---------------------------------------------------------------------------
+# the driver
+# ---------------------------------------------------------------------------
+
+def _refuse(why: str) -> int:
+    print(f"chip_smoke: refusing to run: {why}", file=sys.stderr)
+    return 2
+
+
+def main(argv: list) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tiny-cpu", action="store_true",
+                    help="toy sizes on the CPU backend, kernels interpreted "
+                         "(for tests/test_chip_smoke.py only)")
+    args = ap.parse_args(argv)
+
+    want = "cpu" if args.tiny_cpu else "tpu"
+    if args.tiny_cpu:
+        os.environ["TPU_MPI_FUSED_FOLD"] = "interp"
+    else:
+        os.environ["TPU_MPI_BACKEND"] = "tpu"
+    from tpu_mpi import _native
+    from tpu_mpi._runtime import enable_compile_cache
+    from tpu_mpi.implementations import CAPABILITIES, tpu_generation
+    # g++ is the one child process this script starts; it runs here, before
+    # JAX is touched, so nothing is spawned once the chip is held
+    _native.load()
+    cache = enable_compile_cache()      # before the backend comes up
+    import jax
+    import jaxlib
+    try:
+        found = jax.default_backend()
+    except RuntimeError as e:
+        return _refuse(f"JAX could not initialize a backend ({e})")
+    if found != want:
+        return _refuse(f"JAX's default backend is {found!r}, not {want!r} "
+                       f"(JAX_PLATFORMS="
+                       f"{os.environ.get('JAX_PLATFORMS', '')!r})")
+    dev = jax.devices()[0]
+    if not args.tiny_cpu and tpu_generation() not in CAPABILITIES:
+        return _refuse(f"device_kind {dev.device_kind!r} is not in "
+                       f"tpu_mpi.implementations.CAPABILITIES")
+    try:
+        from importlib.metadata import version
+        libtpu = version("libtpu")
+    except Exception:       # noqa: BLE001 - a version string, nothing more
+        libtpu = "unknown"
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    _log(f"platform: {dev.platform}")
+    _log(f"device_kind: {dev.device_kind}")
+    _log(f"device_count: {device['count']}")
+    _log(f"versions: jax {jax.__version__} jaxlib {jaxlib.__version__} "
+         f"libtpu {libtpu} python {sys.version.split()[0]}")
+    _log(f"compile_cache: {cache}")
+
+    sz = TINY if args.tiny_cpu else FULL
+    for name, leg in LEGS:
+        t0 = time.perf_counter()
+        try:
+            facts = leg(sz, dev.platform)
+        except BaseException:
+            traceback.print_exc()
+            print(f"chip_smoke: leg {name} FAILED", file=sys.stderr)
+            return 1
+        _log(f"leg {name}: ok in {time.perf_counter() - t0:.1f}s "
+             f"{json.dumps(facts, ensure_ascii=False)}")
+    _log(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    if "--host-rank" in sys.argv[1:]:
+        # a rank: run the one imported copy of this module (its jitted
+        # helpers compile once for all rank threads) and return, never exit
+        import chip_smoke
+        chip_smoke._host_rank_entry(sys.argv[1:])
+    else:
+        sys.exit(main(sys.argv[1:]))

@@ -34,19 +34,15 @@ out through the same typed-error recovery path.
 import os
 import signal
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 import tpu_mpi as MPI
 from tpu_mpi.error import ProcFailedError, RevokedError
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax                                              # noqa: E402
-import jax.numpy as jnp                                 # noqa: E402
-
-from tpu_mpi.models.transformer import (                # noqa: E402
+from tpu_mpi.models.transformer import (
     TransformerConfig, _xent, transformer_forward, transformer_init)
-from tpu_mpi.train import DDPTrainer                    # noqa: E402
+from tpu_mpi.train import DDPTrainer
 
 CFG = TransformerConfig(vocab=64, d_model=32, n_heads=2, n_layers=2,
                         d_ff=64, max_seq=32)

@@ -136,7 +136,8 @@ def test_capability_tables():
     for gen, row in CAPABILITIES.items():
         assert {"ici_gbps", "hbm_gbps", "hbm_gib", "cores", "bf16_tflops"} <= set(row)
     assert capabilities("v5e")["hbm_gbps"] == 819.0
-    assert capabilities("nonsense")["hbm_gbps"] == 819.0  # fallback row
+    with pytest.raises(KeyError, match="no-such-chip"):
+        capabilities("no-such-chip")    # an unknown device is not a v5e
 
 
 def test_telemetry_knob_defaults(clean_env):

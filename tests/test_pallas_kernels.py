@@ -16,22 +16,6 @@ from tpu_mpi import xla
 from tpu_mpi.xla import pallas_kernels as pk
 
 
-# The ring kernels trace barrier semaphores / remote DMA (collective_id);
-# off-TPU they need the Pallas TPU interpret machine, which jax grew in 0.5
-# (pltpu.InterpretParams). On older jax the generic interpreter cannot lower
-# get_barrier_semaphore on CPU, so those tests skip rather than fail.
-def _can_run_remote_dma():
-    if jax.default_backend() == "tpu":
-        return True
-    from jax.experimental.pallas import tpu as pltpu
-    return hasattr(pltpu, "InterpretParams")
-
-
-requires_remote_dma = pytest.mark.skipif(
-    not _can_run_remote_dma(),
-    reason="needs TPU or the Pallas TPU interpret machine (jax >= 0.5)")
-
-
 def _mesh(n):
     if len(jax.devices()) < n:
         pytest.skip(f"needs {n} devices")
@@ -48,7 +32,6 @@ def _run(mesh, fn, *args, in_specs=None, out_specs=None):
 
 
 @pytest.mark.parametrize("n", [4, 8])
-@requires_remote_dma
 def test_ring_allgather(n):
     mesh = _mesh(n)
     x = jnp.arange(n * 6 * 5, dtype=jnp.float32).reshape(n * 6, 5)
@@ -61,7 +44,6 @@ def test_ring_allgather(n):
 
 @pytest.mark.parametrize("op,npop", [("sum", np.add), ("max", np.maximum),
                                      ("min", np.minimum)])
-@requires_remote_dma
 def test_ring_allreduce(op, npop):
     n = 4
     mesh = _mesh(n)
@@ -77,7 +59,6 @@ def test_ring_allreduce(op, npop):
         np.testing.assert_allclose(got[r], expect, rtol=1e-6)
 
 
-@requires_remote_dma
 def test_ring_allreduce_large_uneven():
     # element count not divisible by n*8*128: exercises the padding path
     n = 4
@@ -91,7 +72,6 @@ def test_ring_allreduce_large_uneven():
         np.testing.assert_allclose(got[r], x.sum(0), rtol=1e-5)
 
 
-@requires_remote_dma
 def test_collective_permute_ring_shift():
     n = 4
     mesh = _mesh(n)
@@ -112,7 +92,6 @@ def test_collective_permute_rejects_non_permutation():
         _run(mesh, lambda v: pk.collective_permute(v, [0, 0, 1, 2], axis="x"), x)
 
 
-@requires_remote_dma
 def test_ring_attention_matches_full_attention():
     n = 4
     t_local, d = 8, 16
@@ -132,7 +111,6 @@ def test_ring_attention_matches_full_attention():
     np.testing.assert_allclose(np.asarray(out), expect, rtol=2e-4, atol=2e-5)
 
 
-@requires_remote_dma
 def test_ring_reduce_scatter():
     n = 4
     mesh = _mesh(n)
@@ -146,7 +124,6 @@ def test_ring_reduce_scatter():
         np.testing.assert_allclose(got[r], total[r], rtol=1e-5)
 
 
-@requires_remote_dma
 def test_pairwise_alltoall():
     n = 4
     mesh = _mesh(n)
@@ -166,7 +143,6 @@ def test_pairwise_alltoall():
                 100 * s + 10 * r + np.arange(per, dtype=np.float32))
 
 
-@requires_remote_dma
 def test_ring_attention_causal():
     n = 4
     t_local, d = 8, 16

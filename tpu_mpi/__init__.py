@@ -12,7 +12,6 @@ inside jit/shard_map over a jax.sharding.Mesh.
 
 from .version import __version__
 
-from . import _jax_compat  # installs jax.shard_map on older jax; keep first
 from . import implementations
 from .implementations import Get_library_version, Get_version
 

@@ -3,12 +3,16 @@
 Build model mirrors the reference's deps/ stage (deps/build.jl compiles
 gen_consts.c with the system compiler at install time): the shared library is
 compiled from the vendored C++ source with the system g++ on first use and
-cached next to the source; a stale cache (source newer than .so) rebuilds.
+cached next to the source under a name that carries the source's content
+hash — so a copied or re-checked-out tree (where file times say nothing) can
+only ever load a library built from the ``transport.cc`` beside it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -16,7 +20,7 @@ from typing import Optional
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "transport.cc")
-_LIB = os.path.join(_HERE, "libtpumpi_transport.so")
+_LOCK = os.path.join(_HERE, "libtpumpi_transport.so.lock")
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -26,35 +30,48 @@ class NativeBuildError(RuntimeError):
     pass
 
 
-def _stale() -> bool:
-    return (not os.path.exists(_LIB)
-            or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
+def _lib_path() -> str:
+    """The library file for the transport.cc on disk right now."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libtpumpi_transport-{digest}.so")
 
 
-def _build() -> None:
+def _build(lib: str) -> None:
     """Compile under an inter-process lock: N launched rank processes may hit
     first-use simultaneously (tpurun --procs); each builds to its own temp
-    file and the winner publishes atomically."""
+    file and the winner publishes atomically. Libraries of other source
+    versions are removed."""
     import fcntl
     import tempfile
 
-    with open(_LIB + ".lock", "w") as lf:
+    with open(_LOCK, "w") as lf:
         fcntl.flock(lf, fcntl.LOCK_EX)
         try:
-            if not _stale():     # a sibling built it while we waited
+            if os.path.exists(lib):     # a sibling built it while we waited
                 return
-            fd, tmp = tempfile.mkstemp(dir=_HERE, suffix=".so")
+            fd, tmp = tempfile.mkstemp(dir=_HERE, suffix=".so.tmp")
             os.close(fd)
             cxx = os.environ.get("TPU_MPI_CXX", "g++")
             cmd = [cxx, "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
                    _SRC, "-o", tmp]
-            proc = subprocess.run(cmd, capture_output=True, text=True)
+            try:
+                proc = subprocess.run(cmd, capture_output=True, text=True)
+            except OSError as e:        # no compiler on this machine
+                os.unlink(tmp)
+                raise NativeBuildError(
+                    f"native transport build failed ({' '.join(cmd)}): "
+                    f"{e}") from e
             if proc.returncode != 0:
                 os.unlink(tmp)
                 raise NativeBuildError(
                     f"native transport build failed ({' '.join(cmd)}):\n"
                     f"{proc.stderr}")
-            os.replace(tmp, _LIB)
+            os.replace(tmp, lib)
+            for old in glob.glob(os.path.join(_HERE,
+                                              "libtpumpi_transport*.so")):
+                if old != lib:
+                    os.unlink(old)
         finally:
             fcntl.flock(lf, fcntl.LOCK_UN)
 
@@ -65,9 +82,10 @@ def load() -> ctypes.CDLL:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if _stale():
-            _build()
-        lib = ctypes.CDLL(_LIB)
+        path = _lib_path()
+        if not os.path.exists(path):
+            _build(path)
+        lib = ctypes.CDLL(path)
         lib.tm_create.restype = ctypes.c_void_p
         lib.tm_create.argtypes = [ctypes.c_int, ctypes.c_int]
         lib.tm_port.restype = ctypes.c_int

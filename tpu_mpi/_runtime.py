@@ -24,10 +24,12 @@ from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Optional, Sequence
 
+from . import error as _ec
 from . import locksmith
 from .error import (AbortError, CollectiveMismatchError, DeadlockError,
                     MPIError, ProcFailedError, RevokedError, SessionError)
@@ -607,8 +609,8 @@ class CollectiveChannel(_Waitable):
     no wait for slow peers to drain round k. The original single-slot
     design paid two full condition barriers per op (previous-round drain +
     last-picker reset); head-of-line blocking across back-to-back ops was
-    the largest share of the host-lane dispatch overhead (ISSUE-3,
-    ``BENCH_r05.json`` host_lane.overhead_ms). At most two rounds are ever
+    the largest share of the host-lane dispatch overhead (ISSUE-3). At
+    most two rounds are ever
     live: round k+1 cannot complete its rendezvous before every rank
     arrived in it, which requires every rank to have picked (and thereby
     freed) round k.
@@ -1187,6 +1189,71 @@ class FailureDetector:
                 ctx.peer_failed(peer)
 
 
+def cpu_platform_selected() -> bool:
+    """Whether the environment pins JAX to the CPU backend
+    (``JAX_PLATFORMS=cpu``: tests, ``tpurun --sim``) — answered without
+    touching JAX, so a parent that must stay off it can ask."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+
+
+def compile_cache_dir() -> str:
+    """Directory of JAX's persistent compilation cache for this checkout:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment places it, else
+    ``<checkout>/.jax_cache`` — derived from this file, so the launcher, its
+    rank processes and ``Comm_spawn`` children all agree, and stable across
+    runs (the path is part of the cache key: a directory that moves never
+    hits)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Turn the persistent compilation cache on before the backend is
+    warmed; returns the directory in use. Where the environment names the
+    directory JAX reads it itself and nothing is set in code. Otherwise the
+    checkout's own directory is configured — on the live config when jax is
+    already imported, and through the environment either way, so a process
+    that imports jax later (a numpy-only rank, a child) gets the same one
+    without paying the import here. A process pinned to the CPU backend
+    is left alone and gets None: the cache exists for accelerator compiles, and XLA:CPU
+    complains about its own reloaded entries."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return os.environ["JAX_COMPILATION_CACHE_DIR"]
+    if cpu_platform_selected():
+        return None
+    path = compile_cache_dir()
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def require_backend() -> None:
+    """Enforce ``config.backend == "tpu"`` (``TPU_MPI_BACKEND=tpu``): the
+    job was told it runs on a TPU, so a JAX whose default backend is
+    anything else — the CPU it silently falls back to when no accelerator
+    initializes — is a typed error at launch, not a slow run that looks like
+    a result."""
+    from . import config
+    if config.load().backend != "tpu":
+        return
+    import jax
+    try:
+        found = jax.default_backend()
+    except RuntimeError as e:
+        raise MPIError(f"TPU_MPI_BACKEND=tpu but JAX could not initialize "
+                       f"a backend: {e}",
+                       code=_ec.ERR_UNSUPPORTED_OPERATION) from e
+    if found != "tpu":
+        raise MPIError(
+            f"TPU_MPI_BACKEND=tpu but JAX's default backend is {found!r} "
+            f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r}): no "
+            f"TPU is visible to this process",
+            code=_ec.ERR_UNSUPPORTED_OPERATION)
+
+
 _jax_warmed = False
 
 
@@ -1196,17 +1263,15 @@ def _warm_jax_backend() -> None:
     PJRT client creation is not safe under concurrent first-initialization
     from many threads (observed hang in make_c_api_client); the launcher owns
     backend bring-up, like mpiexec owns process bring-up in the reference.
+    A backend that fails to come up fails the launch.
     """
     global _jax_warmed
     if _jax_warmed:
         return
-    try:
-        import jax
-        jax.devices()
-        import jax.numpy as jnp
-        jnp.zeros(1).block_until_ready()
-    except Exception:
-        pass
+    enable_compile_cache()
+    require_backend()
+    import jax.numpy as jnp
+    jnp.zeros(1).block_until_ready()
     _jax_warmed = True
 
 

@@ -40,12 +40,14 @@ from typing import Any, Callable, Optional, Sequence
 import numpy as np
 
 from . import config
+from . import error as _ec
 from . import perfvars as _pv
 from . import serialization
 from .buffers import is_wire_snapshot
 from ._runtime import (ANY_SOURCE, FailureDetector, Mailbox, Message,
                        SpmdContext, _Waitable, collective_wait_limit,
-                       deadlock_timeout, set_env, set_process_env)
+                       deadlock_timeout, enable_compile_cache, set_env,
+                       set_process_env)
 from .error import (AbortError, CollectiveMismatchError, DeadlockError,
                     MPIError, ProcFailedError)
 
@@ -2033,6 +2035,9 @@ class ProcContext(SpmdContext):
         self._choke_high = config.load().send_highwater_bytes
         self._grow_lock = threading.Lock()
         self._spawned_procs: list = []
+        # unbound local chips Comm_spawn children may take (read from the
+        # launcher's TPU_MPI_FREE_CHIPS on first spawn; per root process)
+        self._free_chips: Optional[list] = None
         self._cid_counter = itertools.count(0)
         self.mailboxes = [
             Mailbox(self) if r == local_rank else _RemoteMailbox(self, r)
@@ -2656,10 +2661,32 @@ class ProcContext(SpmdContext):
                             base_addrs=list(self.addrs),
                             advertise=cfg.coordinator_advertise or None)
         pkg_parent = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        # A rank process bound to a chip (launcher.chip_env) must not hand
+        # that binding down: a child that touched JAX would ask libtpu for
+        # the chip its parent holds and hang. Children draw from the chips
+        # the launcher left unbound, or the spawn is refused.
+        chip_envs: list = [{}] * n
+        if os.environ.get("TPU_VISIBLE_CHIPS"):
+            from .launcher import chip_env
+            with self._grow_lock:
+                if self._free_chips is None:
+                    self._free_chips = [
+                        c for c in os.environ.get(
+                            "TPU_MPI_FREE_CHIPS", "").split(",") if c]
+                if len(self._free_chips) < n:
+                    raise MPIError(
+                        f"Comm_spawn of {n} process(es) needs {n} free "
+                        f"chip(s), this job has {len(self._free_chips)}: "
+                        f"every chip the launcher knew of is bound to a "
+                        f"rank process (list spare chips in "
+                        f"TPU_VISIBLE_CHIPS, or run under --sim)",
+                        code=_ec.ERR_SPAWN)
+                chip_envs = [chip_env(self._free_chips.pop(0))
+                             for _ in range(n)]
         procs = []
         try:
             for i in range(n):
-                env = dict(os.environ)
+                env = dict(os.environ, **chip_envs[i])
                 old_pp = env.get("PYTHONPATH", "")
                 env["PYTHONPATH"] = (pkg_parent
                                      + (os.pathsep + old_pp if old_pp else ""))
@@ -2779,6 +2806,7 @@ def proc_attach() -> tuple[ProcContext, int]:
     coordinator for the address map, and bind this process as its rank."""
     from ._native import NativeTransport
 
+    enable_compile_cache()
     rank = int(os.environ["TPU_MPI_PROC_RANK"])
     size = int(os.environ["TPU_MPI_PROC_SIZE"])
     coord = os.environ["TPU_MPI_PROC_COORD"]

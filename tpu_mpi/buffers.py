@@ -64,17 +64,73 @@ def is_jax_array(x: Any) -> bool:
     return type(x).__module__.startswith("jax") and hasattr(x, "dtype")
 
 
+def _device_dtype(dtype: Any, device: Any = None):
+    """The dtype a device array of declared ``dtype`` gets, or a typed error
+    when the device cannot hold it faithfully. With ``jax_enable_x64`` off
+    (the default outside the test suite) jax narrows 64-bit input to 32
+    bits; the byte-level paths (file I/O, RMA windows, datatype extents)
+    size everything from the declared dtype, so a narrowed operand would
+    corrupt them silently. With it on, a TPU still has no 64-bit floating
+    type: float64 comes back changed (measured on the v5e, PR 21: neither a
+    round trip nor x + x is bit-exact) and complex128 aborts the compiler;
+    int64/uint64 are exact."""
+    import jax
+    canon = jax.dtypes.canonicalize_dtype(dtype)
+    if canon.itemsize != np.dtype(dtype).itemsize:
+        raise MPIError(
+            f"device buffer of {np.dtype(dtype)} would be narrowed to "
+            f"{canon}: enable 64-bit device arrays "
+            f"(jax.config.update('jax_enable_x64', True) or JAX_ENABLE_X64=1) "
+            f"or convert the operand to a 32-bit dtype first",
+            code=_ec.ERR_TYPE)
+    if canon.kind in "fc" and canon.itemsize >= 8 and (
+            getattr(device, "platform", None)
+            or jax.default_backend()) == "tpu":
+        raise MPIError(
+            f"a TPU cannot hold {canon} exactly (it has no 64-bit floating "
+            f"type): keep the operand on the host as a numpy array, or "
+            f"convert it to float32/complex64 first", code=_ec.ERR_TYPE)
+    return canon
+
+
+def on_sharding(x: Any, sharding: Any):
+    """``x`` (a jax.Array, or host data) as a jax.Array on ``sharding`` —
+    a device-to-device copy when a collective's operand or result sits on
+    another rank's chip, an upload for host data, and nothing at all when it
+    already is there (the single-chip and CPU-sim default-placement case)."""
+    if is_jax_array(x) and x.sharding == sharding:
+        return x
+    import jax
+    return jax.device_put(x, sharding)
+
+
+def _onto(src: Any, like: Any):
+    """Any array-like ``src`` as a jax.Array of ``like``'s dtype on
+    ``like``'s device(s); host data goes straight there."""
+    if not is_jax_array(src):
+        src = np.asarray(src, dtype=like.dtype)
+    elif src.dtype != like.dtype:
+        src = src.astype(like.dtype)
+    return on_sharding(src, like.sharding)
+
+
 class DeviceBuffer:
     """A mutable cell holding a device-resident jax.Array.
 
     The analog of passing a CuArray to MPI.jl (src/cuda.jl:26-28): device data
     is a first-class communication operand. Mutation rebinds via functional
     updates, so the mutating API (Recv!, Allreduce! with a recv buffer, …)
-    works identically for host and device arrays.
+    works identically for host and device arrays. The cell stays on the
+    device it was created on (``device=comm.device`` binds it to the calling
+    rank's chip): whatever is written into it is moved there.
     """
 
     def __init__(self, value: Any, dtype: Any = None, device: Any = None):
         import jax.numpy as jnp
+        # Python scalars and lists declare no dtype and take jax's default
+        want = dtype if dtype is not None else getattr(value, "dtype", None)
+        if want is not None:
+            dtype = _device_dtype(want, device)
         arr = jnp.asarray(value, dtype=dtype)
         if device is not None:
             import jax
@@ -83,9 +139,11 @@ class DeviceBuffer:
 
     # -- constructors mirroring ArrayType{T}(undef, dims) test usage ---------
     @classmethod
-    def empty(cls, shape: Any, dtype: Any = np.float64) -> "DeviceBuffer":
+    def empty(cls, shape: Any, dtype: Any = np.float64,
+              device: Any = None) -> "DeviceBuffer":
         import jax.numpy as jnp
-        return cls(jnp.zeros(shape, dtype=dtype))
+        return cls(jnp.zeros(shape, dtype=_device_dtype(dtype, device)),
+                   device=device)
 
     @property
     def shape(self):
@@ -110,6 +168,8 @@ class DeviceBuffer:
         return self.value[idx]
 
     def __setitem__(self, idx, val):
+        if is_jax_array(val):
+            val = on_sharding(val, self.value.sharding)
         self.value = self.value.at[idx].set(val)
 
     def setflat(self, src: Any, count: Optional[int] = None) -> None:
@@ -120,15 +180,15 @@ class DeviceBuffer:
         # host-path collectives (the combined result is handed straight back).
         if (is_jax_array(src) and src.dtype == v.dtype and src.shape == v.shape
                 and (count is None or count == v.size)):
-            self.value = src
+            self.value = on_sharding(src, v.sharding)
             return
         import jax.numpy as jnp
         n = (count if count is not None
              else int(np.prod(np.shape(src), dtype=np.int64)))
         if n == v.size and v.shape == tuple(np.shape(src)):
-            self.value = jnp.asarray(src, dtype=v.dtype)
+            self.value = _onto(src, v)
         else:
-            flat = jnp.ravel(jnp.asarray(src, dtype=v.dtype))
+            flat = jnp.ravel(_onto(src, v))
             out = jnp.ravel(v).at[:n].set(flat[:n])
             self.value = out.reshape(v.shape)
 
@@ -137,7 +197,7 @@ class DeviceBuffer:
 
     def fill(self, v: Any) -> None:
         import jax.numpy as jnp
-        self.value = jnp.full(self.value.shape, v, dtype=self.value.dtype)
+        self.value = jnp.full_like(self.value, v)
 
     def __repr__(self) -> str:
         return f"DeviceBuffer({self.value!r})"
@@ -288,10 +348,12 @@ def resolve_attached(attached, addr: int, who: str):
 def clone_like(x: Any, value: Any) -> Any:
     """An operand of the same registry kind as x holding ``value``."""
     if isinstance(x, DeviceBuffer):
-        return DeviceBuffer(value)
+        out = DeviceBuffer(value)
+        out.value = on_sharding(out.value, x.value.sharding)
+        return out
     if is_jax_array(x):
         import jax.numpy as jnp
-        return jnp.asarray(value)
+        return on_sharding(jnp.asarray(value), x.sharding)
     return np.array(value, copy=True)
 
 

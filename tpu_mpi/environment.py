@@ -74,6 +74,9 @@ def Init_thread(required: ThreadLevel,
         serve._set_current(serve.attach(session or None))
     env = current_env()
     if env is None:
+        # no launcher warmed the backend for this rank (a rank process or a
+        # standalone world of one): TPU_MPI_BACKEND=tpu is enforced here
+        _runtime.require_backend()
         if os.environ.get("TPU_MPI_PROC_RANK") is not None:
             # Launched as one process of a multi-process world
             # (tpurun --procs): rendezvous over the native transport.

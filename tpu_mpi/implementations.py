@@ -145,7 +145,7 @@ def platform_probe() -> dict:
         "generation": tpu_generation(),
         "device_count": device_count(),
         "ici_topology": (list(ici_topology()) if ici_topology() else None),
-        "capabilities": capabilities(),
+        "capabilities": (capabilities() if tpu_generation() else None),
     }
     try:
         import jax
@@ -165,10 +165,14 @@ def platform_probe() -> dict:
 
 
 def capabilities(generation: Optional[str] = None) -> dict[str, float]:
-    """Capability row for a generation (default: the local chip; a modest
-    v5e row when the generation is unknown so ratios stay computable)."""
+    """Capability row for a generation (default: the local chip). A device
+    that is not in the table is an error, not a default: a ratio against
+    another chip's peak is not a measurement."""
     gen = generation or tpu_generation()
-    return dict(CAPABILITIES.get(gen or "", CAPABILITIES["v5e"]))
+    if gen not in CAPABILITIES:
+        raise KeyError(f"no capability row for TPU generation {gen!r}; "
+                       f"known: {', '.join(sorted(CAPABILITIES))}")
+    return dict(CAPABILITIES[gen])
 
 
 MPI_LIBRARY = "tpu_mpi"

@@ -61,7 +61,7 @@ def moe_dispatch_combine(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
     static-shape MoE contract. Returns (t, d).
     """
     t, d = tokens.shape
-    n = lax.axis_size(axis) if hasattr(lax, "axis_size") else lax.psum(1, axis)
+    n = lax.axis_size(axis)
 
     # position of each token within its expert's capacity window
     onehot = jax.nn.one_hot(expert_idx, n, dtype=jnp.int32)       # (t, n)

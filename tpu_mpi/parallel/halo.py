@@ -26,7 +26,7 @@ def halo_exchange(x: jnp.ndarray, *, axes: Sequence[str], halo: int = 1,
     offset = x.ndim - len(axes)
     for d, axis in enumerate(axes):
         dim = offset + d
-        n = lax.axis_size(axis) if hasattr(lax, "axis_size") else lax.psum(1, axis)
+        n = lax.axis_size(axis)
         fwd = [(i, (i + 1) % n) for i in range(n)] if periodic else \
             [(i, i + 1) for i in range(n - 1)]
         bwd = [(i, (i - 1) % n) for i in range(n)] if periodic else \

@@ -28,7 +28,7 @@ def pipeline_forward(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
     pipelines behave. Returns (m, ...) outputs on every rank (valid on the
     last stage).
     """
-    n = lax.axis_size(axis) if hasattr(lax, "axis_size") else lax.psum(1, axis)
+    n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     m = microbatches.shape[0]
     fwd = [(i, (i + 1) % n) for i in range(n)]

@@ -29,7 +29,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     local attention output block (same shape as q).
     """
     b, h, t, d = q.shape
-    n = lax.axis_size(axis) if hasattr(lax, "axis_size") else lax.psum(1, axis)
+    n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     scale = (d ** -0.5) if scale is None else scale
     q = q * scale

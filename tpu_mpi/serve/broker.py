@@ -562,7 +562,7 @@ class _ProcsPool:
     START_TIMEOUT = 300.0
 
     def __init__(self, nranks: int, shard: Optional[CidShard] = None,
-                 on_failure=None, sim: Optional[int] = 1):
+                 on_failure=None, sim: Optional[int] = None):
         self.nranks = int(nranks)
         self.shard = shard or CidShard()
         self.ctx = _BrokerCtx(self.nranks, self.shard)
@@ -570,7 +570,7 @@ class _ProcsPool:
         self.failed: set = set()
         self.retired: set = set()
         self.base_comm: Any = None
-        self.sim = sim                       # CPU-sim chips per worker; None = real
+        self.sim = sim                       # CPU-sim devices per worker
         self._on_failure = on_failure
         self._dispatch_lock = locksmith.make_lock("procs.dispatch")
         self._comms: Dict[Any, Any] = {}
@@ -1129,8 +1129,19 @@ class Broker:
                                    else shard)
         self.shard = shard
         if backend == "procs":
+            from ..launcher import sim_selected
+            if not sim_selected():
+                # worker processes would each ask libtpu for every chip on
+                # the host; the engine is host code and one process can
+                # drive all chips, so on hardware the pool is rank threads
+                raise MPIError(
+                    "serve backend 'procs' runs on the CPU-sim substrate "
+                    "only (tpurun --sim / TPU_MPI_BACKEND=cpu-sim / "
+                    "JAX_PLATFORMS=cpu): its worker processes are bound to "
+                    "no chip. On a TPU host use backend='threads'",
+                    code=_ec.ERR_UNSUPPORTED_OPERATION)
             self.pool = _ProcsPool(nranks, shard=shard,
-                                   on_failure=self.on_rank_failure)
+                                   on_failure=self.on_rank_failure, sim=1)
         elif backend == "threads":
             self.pool = _ThreadPool(nranks, shard=shard)
         else:

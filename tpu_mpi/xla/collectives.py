@@ -41,9 +41,7 @@ def rank(axis: str):
 
 def size(axis: str) -> int:
     """Static size of a mesh axis (Comm_size analog, src/comm.jl:66-70)."""
-    import jax
-    return jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size") else \
-        jax.lax.psum(1, axis)
+    return _lax().axis_size(axis)
 
 
 def barrier(axis: Axis):
@@ -81,16 +79,15 @@ def _gather_reduce(x: Any, op: Op, axis: str):
 
 def _fold_gathered(g: Any, op: Op):
     """Left fold over the leading (per-rank) axis of a gathered array —
-    fused Pallas kernel when gated on, chained combine otherwise. Both are
-    the same rank-ordered left fold, so results are bit-identical."""
+    fused Pallas kernel where ``collective._fused_reduce_candidate`` selects
+    it, chained combine otherwise. Both are the same rank-ordered left fold,
+    so results are bit-identical; a selected kernel that fails to lower
+    raises."""
     streams = [g[i] for i in range(g.shape[0])]
     from ..collective import _fused_reduce_candidate
     fused = _fused_reduce_candidate(op, streams)
     if fused is not None:
-        try:
-            return fused(*streams)
-        except Exception:
-            pass                         # Mosaic/interpret failure → chained
+        return fused(*streams)
     acc = streams[0]
     for s in streams[1:]:
         acc = op(acc, s)

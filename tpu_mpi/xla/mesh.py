@@ -26,8 +26,8 @@ def make_mesh(axes: Union[Mapping[str, int], Sequence[int]],
     """Build a Mesh from {axis: size} (or a shape plus names).
 
     Uses ``mesh_utils.create_device_mesh`` when the device count matches the
-    full grid so TPU ICI topology is respected; otherwise lays out the given
-    devices in C order.
+    full grid so TPU ICI topology is respected (a shape the topology cannot
+    host raises); a subset of the devices is laid out in C order.
     """
     import jax
     from jax.sharding import Mesh
@@ -48,11 +48,7 @@ def make_mesh(axes: Union[Mapping[str, int], Sequence[int]],
         raise ValueError(f"mesh {dict(zip(names, shape))} needs {n} devices, "
                          f"have {len(devices)}")
     if n == len(devices) and devices == jax.devices():
-        try:
-            dev_array = mesh_utils.create_device_mesh(shape)
-            return Mesh(dev_array, names)
-        except Exception:
-            pass
+        return Mesh(mesh_utils.create_device_mesh(shape), names)
     dev_array = np.array(devices[:n]).reshape(shape)
     return Mesh(dev_array, names)
 

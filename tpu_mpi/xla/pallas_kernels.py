@@ -8,15 +8,22 @@ collectives and neighbor transfers written directly against the ICI with
 ``pltpu.make_async_remote_copy`` (remote DMA) + semaphores, and a fused
 ring-attention kernel as the long-context demo SURVEY.md §5 calls for.
 
-All kernels run under ``jax.shard_map`` over a 1-d mesh axis. On real TPU
-slices they compile via Mosaic; off-TPU they execute under the Pallas TPU
-*interpret machine* (``pltpu.InterpretParams``), which simulates per-device
-VMEM/semaphores/RDMA on CPU — the same CPU-sim substrate the rest of the
-test suite uses.
+All kernels run under ``jax.shard_map`` over a 1-d mesh axis. On a TPU
+backend they compile via Mosaic; on the CPU backend (or when the caller
+passes ``interpret=True``) they execute under the Pallas TPU *interpret
+machine* (``pltpu.InterpretParams``), which simulates per-device
+VMEM/semaphores/RDMA — the CPU-sim substrate the test suite uses. No other
+backend is supported and nothing selects the interpreter silently.
 
-Layout contract: kernels operate on 2-d ``(rows, 128)`` f32/bf16 tiles (the
-TPU-native layout); the public wrappers flatten/pad arbitrary operands in
-and slice them back out, so callers see plain MPI semantics.
+Layout contract: kernels operate on 2-d ``(rows, 128)`` tiles whose row
+count is aligned to the dtype's native sublane tile (8 rows of 32-bit, 16
+of 16-bit, 32 of 8-bit elements); the public wrappers flatten/pad arbitrary
+operands in and slice them back out, so callers see plain MPI semantics.
+
+The ring kernels hold their whole operand in VMEM, so their size is bounded
+by VMEM, not HBM: a call whose working set exceeds :data:`VMEM_LIMIT_BYTES`
+raises ``ValueError`` before tracing the kernel (use the XLA collectives in
+``tpu_mpi.xla.collectives`` for HBM-sized operands).
 """
 
 from __future__ import annotations
@@ -26,7 +33,20 @@ import math
 from typing import Any, Callable, Optional, Sequence
 
 LANE = 128      # TPU lane width: minor-most dim of every tile
-SUBLANE = 8     # f32 sublane multiple for the second-minor dim
+SUBLANE = 8     # sublane multiple of a 32-bit tile (second-minor dim)
+
+# Mosaic's scoped-VMEM default is 16 MiB; kernels whose whole-operand
+# working set needs more ask for it explicitly, up to this cap (under the
+# v5e's 128 MiB of physical VMEM, leaving room for Mosaic's own temporaries).
+VMEM_DEFAULT_BYTES = 16 * 1024 * 1024
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+
+def _sublane(dtype) -> int:
+    """Rows of the native (sublane, 128) tile: sub-32-bit dtypes pack along
+    the sublane axis, so bf16 tiles are (16, 128) and int8 tiles (32, 128)."""
+    import numpy as np
+    return SUBLANE * max(1, 4 // np.dtype(dtype).itemsize)
 
 
 def _pl():
@@ -40,59 +60,71 @@ def _pltpu():
 
 
 def _interpret(interpret: Optional[bool]):
-    """Interpret-machine params off-TPU, Mosaic compilation on TPU."""
+    """The TPU interpret machine when the caller asks for it or the backend
+    is the CPU; Mosaic compilation otherwise. Never a silent choice on an
+    accelerator: a kernel that cannot compile there raises."""
     import jax
-    pltpu = _pltpu()
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if not interpret:
-        return False
-    params = getattr(pltpu, "InterpretParams", None)
-    if params is None:
-        # jax < 0.5 has no TPU interpret machine; the generic Pallas
-        # interpreter still executes LOCAL kernels (no semaphores/RDMA)
-        return True
-    return params()
+        interpret = jax.default_backend() == "cpu"
+    return _pltpu().InterpretParams() if interpret else False
 
 
-def _compiler_params(collective_id: Optional[int],
-                     vmem_limit_bytes: Optional[int] = None):
+def _compiler_params(collective_id: Optional[int], vmem_bytes: int = 0,
+                     what: str = "kernel"):
     """Mosaic accepts a collective_id ONLY when the kernel actually uses the
     barrier semaphore — at n=1 the ring loops never trace a barrier, so the
-    id must be omitted or compilation fails (found by the real-chip Mosaic
-    smoke, benchmarks/pallas_mosaic_smoke.py; interpret mode accepts both).
-    ``vmem_limit_bytes`` lifts Mosaic's 16 MB scoped-VMEM default for
-    kernels whose working set legitimately needs more (ring attention at
-    4096-row blocks)."""
-    pltpu = _pltpu()
+    id must be omitted or compilation fails (interpret mode accepts both).
+    ``vmem_bytes`` is the kernel's whole-operand VMEM working set: above the
+    16 MiB scoped default it is requested explicitly, above
+    :data:`VMEM_LIMIT_BYTES` the call is refused."""
     kw = {}
     if collective_id is not None:
         kw["collective_id"] = collective_id
-    if vmem_limit_bytes is not None:
-        kw["vmem_limit_bytes"] = vmem_limit_bytes
-    # renamed TPUCompilerParams -> CompilerParams across jax 0.5
-    params = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    return params(**kw)
+    if vmem_bytes > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"{what}: operands need {vmem_bytes / 2**20:.1f} MiB of VMEM "
+            f"(whole-operand blocks + scratch); the limit is "
+            f"{VMEM_LIMIT_BYTES // 2**20} MiB (VMEM_LIMIT_BYTES) — split "
+            f"the operand or use the XLA collective in tpu_mpi.xla")
+    # headroom for Mosaic's own temporaries (operand-sized vector values
+    # spilled around the combine) on top of the declared buffers
+    want = 2 * vmem_bytes
+    if want > VMEM_DEFAULT_BYTES:
+        kw["vmem_limit_bytes"] = min(want, VMEM_LIMIT_BYTES)
+    return _pltpu().CompilerParams(**kw)
+
+
+def _nbytes(shape, dtype) -> int:
+    import numpy as np
+    return int(np.prod(shape)) * np.dtype(dtype).itemsize
+
+
+def _rows(idx, chunk: int):
+    """Dynamic row-slice of chunk ``idx``; chunk counts are sublane-tile
+    multiples, and Mosaic needs to be told so for a traced ``idx``."""
+    pl = _pl()
+    if isinstance(idx, int):
+        return pl.ds(idx * chunk, chunk)
+    return pl.ds(pl.multiple_of(idx * chunk, chunk), chunk)
 
 
 # ---------------------------------------------------------------------------
 # layout: arbitrary array <-> (rows, LANE) tile padded for n ring chunks
 # ---------------------------------------------------------------------------
 
-def _tile_rows(count: int, n: int) -> int:
+def _tile_rows(count: int, n: int, sublane: int = SUBLANE) -> int:
     """Rows of the (rows, LANE) tile holding `count` elements, padded so the
-    row count splits into n equal SUBLANE-aligned ring chunks."""
+    row count splits into n equal sublane-tile-aligned ring chunks."""
     rows = -(-count // LANE)
     chunk = -(-rows // n)
-    chunk = -(-chunk // SUBLANE) * SUBLANE
+    chunk = -(-chunk // sublane) * sublane
     return chunk * n
 
 
 def _to_tile(x, n: int):
     import jax.numpy as jnp
     flat = x.reshape(-1)
-    rows = _tile_rows(flat.size, n)
+    rows = _tile_rows(flat.size, n, _sublane(flat.dtype))
     pad = rows * LANE - flat.size
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
@@ -105,8 +137,8 @@ def _from_tile(tile, shape, size: int):
 
 def _to_block_tile(x, n: int):
     """Per-rank-block layout: x (size divisible by n) viewed as n equal
-    blocks, each padded independently to a SUBLANE-aligned (rows_b, LANE)
-    tile, concatenated to (n*rows_b, LANE). Unlike _to_tile (end-padding),
+    blocks, each padded independently to a sublane-tile-aligned (rows_b,
+    LANE) tile, concatenated to (n*rows_b, LANE). Unlike _to_tile (end-padding),
     block boundaries land exactly on chunk boundaries — what Reduce_scatter
     and Alltoall semantics need (rank i's block = x[i*per:(i+1)*per])."""
     import jax.numpy as jnp
@@ -115,7 +147,8 @@ def _to_block_tile(x, n: int):
         raise ValueError(f"size {flat.size} not divisible by {n} ranks")
     per = flat.size // n
     rows = -(-per // LANE)
-    rows_b = -(-rows // SUBLANE) * SUBLANE
+    sub = _sublane(flat.dtype)
+    rows_b = -(-rows // sub) * sub
     blocks = flat.reshape(n, per)
     pad = rows_b * LANE - per
     if pad:
@@ -147,7 +180,7 @@ def _ring_allgather_kernel(n: int, chunk: int, axis: str, local_ref, out_ref,
     import jax
     pl, pltpu = _pl(), _pltpu()
     my = jax.lax.axis_index(axis)
-    out_ref[pl.ds(my * chunk, chunk), :] = local_ref[:]
+    out_ref[_rows(my, chunk), :] = local_ref[:]
     comm_ref[0] = local_ref[:]
     for step in range(n - 1):
         src_dev = (my - step - 1) % n
@@ -163,7 +196,7 @@ def _ring_allgather_kernel(n: int, chunk: int, axis: str, local_ref, out_ref,
         )
         rdma.start()
         rdma.wait()
-        out_ref[pl.ds(src_dev * chunk, chunk), :] = comm_ref[r]
+        out_ref[_rows(src_dev, chunk), :] = comm_ref[r]
 
 
 def ring_allgather(x, *, axis: str = "x", interpret: Optional[bool] = None):
@@ -187,7 +220,9 @@ def ring_allgather(x, *, axis: str = "x", interpret: Optional[bool] = None):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=_interpret(interpret),
-        compiler_params=_compiler_params(0 if n > 1 else None),
+        compiler_params=_compiler_params(
+            0 if n > 1 else None,
+            _nbytes(((n + 3) * rows, LANE), tile.dtype), "ring_allgather"),
     )(tile)
     per = out.reshape(n, rows * LANE)[:, : x.size]
     return per.reshape((n,) + tuple(x.shape))
@@ -224,7 +259,7 @@ def _ring_allreduce_kernel(n: int, chunk: int, combine: Callable, axis: str,
     def ring_step(step, src_slice_idx, accumulate):
         s, r = step % 2, (step + 1) % 2
         _neighbor_barrier(my, n)
-        comm_ref[s] = out_ref[pl.ds(src_slice_idx * chunk, chunk), :]
+        comm_ref[s] = out_ref[_rows(src_slice_idx, chunk), :]
         rdma = pltpu.make_async_remote_copy(
             src_ref=comm_ref.at[s],
             dst_ref=comm_ref.at[r],
@@ -236,9 +271,9 @@ def _ring_allreduce_kernel(n: int, chunk: int, combine: Callable, axis: str,
         rdma.start()
         rdma.wait()
         recv_idx = (src_slice_idx - 1) % n
-        cur = out_ref[pl.ds(recv_idx * chunk, chunk), :]
+        cur = out_ref[_rows(recv_idx, chunk), :]
         new = combine(cur, comm_ref[r]) if accumulate else comm_ref[r]
-        out_ref[pl.ds(recv_idx * chunk, chunk), :] = new
+        out_ref[_rows(recv_idx, chunk), :] = new
         return recv_idx
 
     # reduce-scatter: after n-1 steps rank owns the fully reduced chunk
@@ -278,7 +313,9 @@ def ring_allreduce(x, op: Any = "sum", *, axis: str = "x",
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=_interpret(interpret),
-        compiler_params=_compiler_params(1),   # n>1 guaranteed (early return)
+        compiler_params=_compiler_params(      # n>1 guaranteed (early return)
+            1, _nbytes((2 * rows + 2 * chunk, LANE), tile.dtype),
+            "ring_allreduce"),
     )(tile)
     return _from_tile(out, x.shape, x.size)
 
@@ -301,7 +338,7 @@ def _ring_reduce_scatter_kernel(n: int, chunk: int, combine: Callable,
     for step in range(n - 1):
         s, r = step % 2, (step + 1) % 2
         _neighbor_barrier(my, n)
-        comm_ref[s] = acc_ref[pl.ds(idx * chunk, chunk), :]
+        comm_ref[s] = acc_ref[_rows(idx, chunk), :]
         rdma = pltpu.make_async_remote_copy(
             src_ref=comm_ref.at[s],
             dst_ref=comm_ref.at[r],
@@ -313,9 +350,9 @@ def _ring_reduce_scatter_kernel(n: int, chunk: int, combine: Callable,
         rdma.start()
         rdma.wait()
         idx = (idx - 1) % n
-        acc_ref[pl.ds(idx * chunk, chunk), :] = combine(
-            acc_ref[pl.ds(idx * chunk, chunk), :], comm_ref[r])
-    out_ref[:] = acc_ref[pl.ds(my * chunk, chunk), :]
+        acc_ref[_rows(idx, chunk), :] = combine(
+            acc_ref[_rows(idx, chunk), :], comm_ref[r])
+    out_ref[:] = acc_ref[_rows(my, chunk), :]
 
 
 def ring_reduce_scatter(x, op: Any = "sum", *, axis: str = "x",
@@ -345,7 +382,9 @@ def ring_reduce_scatter(x, op: Any = "sum", *, axis: str = "x",
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=_interpret(interpret),
-        compiler_params=_compiler_params(4),   # n>1 guaranteed (early return)
+        compiler_params=_compiler_params(      # n>1 guaranteed (early return)
+            4, _nbytes(((2 * n + 3) * rows_b, LANE), tile.dtype),
+            "ring_reduce_scatter"),
     )(tile)
     return out.reshape(-1)[:per]
 
@@ -360,7 +399,7 @@ def _alltoall_kernel(n: int, chunk: int, axis: str, local_ref, out_ref,
     import jax
     pl, pltpu = _pl(), _pltpu()
     my = jax.lax.axis_index(axis)
-    out_ref[pl.ds(my * chunk, chunk), :] = local_ref[pl.ds(my * chunk, chunk), :]
+    out_ref[_rows(my, chunk), :] = local_ref[_rows(my, chunk), :]
     # one all-pairs barrier: every peer must have entered the kernel (its
     # out_ref allocated) before anyone's direct Put lands
     bar = pltpu.get_barrier_semaphore()
@@ -374,8 +413,8 @@ def _alltoall_kernel(n: int, chunk: int, axis: str, local_ref, out_ref,
     for k in range(1, n):
         dst = (my + k) % n
         rdma = pltpu.make_async_remote_copy(
-            src_ref=local_ref.at[pl.ds(dst * chunk, chunk), :],
-            dst_ref=out_ref.at[pl.ds(my * chunk, chunk), :],
+            src_ref=local_ref.at[_rows(dst, chunk), :],
+            dst_ref=out_ref.at[_rows(my, chunk), :],
             send_sem=send_sem.at[k - 1],
             recv_sem=recv_sem.at[k - 1],
             device_id=dst,
@@ -409,7 +448,9 @@ def pairwise_alltoall(x, *, axis: str = "x", interpret: Optional[bool] = None):
             pltpu.SemaphoreType.DMA((n - 1,)),
         ],
         interpret=_interpret(interpret),
-        compiler_params=_compiler_params(5),   # n>1 guaranteed (early return)
+        compiler_params=_compiler_params(      # n>1 guaranteed (early return)
+            5, _nbytes((2 * n * rows_b, LANE), tile.dtype),
+            "pairwise_alltoall"),
     )(tile)
     blocks = out.reshape(n, rows_b * LANE)[:, :per]
     return blocks.reshape(-1)
@@ -486,7 +527,9 @@ def collective_permute(x, perm: Sequence[int], *, axis: str = "x",
             pltpu.SemaphoreType.DMA(()),
         ],
         interpret=_interpret(interpret),
-        compiler_params=_compiler_params(2 if n > 1 else None),
+        compiler_params=_compiler_params(
+            2 if n > 1 else None, _nbytes((3 * rows, LANE), tile.dtype),
+            "collective_permute"),
     )(tile)
     return _from_tile(out, x.shape, x.size)
 
@@ -593,8 +636,10 @@ def ring_attention(q, k, v, *, axis: str = "x", causal: bool = False,
     pl, pltpu = _pl(), _pltpu()
     n = jax.lax.axis_size(axis)
     t, d = q.shape
-    if t % SUBLANE:
-        raise ValueError(f"local seq len {t} must be a multiple of {SUBLANE}")
+    sub = _sublane(q.dtype)
+    if t % sub:
+        raise ValueError(f"local seq len {t} must be a multiple of {sub} "
+                         f"for {q.dtype} operands")
     pad = (-d) % LANE
     if pad:
         z = jnp.zeros((t, pad), q.dtype)
@@ -607,6 +652,12 @@ def ring_attention(q, k, v, *, axis: str = "x", causal: bool = False,
     bq = t if t <= 1024 else 512
     kern = functools.partial(_ring_attention_kernel, n, scale, axis, causal,
                              bq)
+    # q/k/v/out blocks + the K/V double buffer, the f32 accumulator, the
+    # lane-padded (t, 1) softmax state, and one score panel with its exp
+    vmem = (_nbytes(((4 + 4) * t, dp), q.dtype)
+            + _nbytes((t, dp), jnp.float32)
+            + _nbytes((2 * t, LANE), jnp.float32)
+            + _nbytes((3 * bq, t), jnp.float32))
     out = pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((t, dp), q.dtype),
@@ -621,12 +672,8 @@ def ring_attention(q, k, v, *, axis: str = "x", causal: bool = False,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=_interpret(interpret),
-        compiler_params=_compiler_params(
-            3 if n > 1 else None,
-            # the double-buffered K/V + f32 online-softmax state + one
-            # score panel legitimately exceed Mosaic's 16 MB scoped
-            # default at 2048+ rows; cap well under the chip's VMEM
-            vmem_limit_bytes=96 * 1024 * 1024 if t > 1024 else None),
+        compiler_params=_compiler_params(3 if n > 1 else None, vmem,
+                                         "ring_attention"),
     )(q, k, v)
     return out[:, :d] if pad else out
 
@@ -642,6 +689,12 @@ def ring_attention(q, k, v, *, axis: str = "x", causal: bool = False,
 # under the 16 MiB scoped-VMEM default, and big enough that the per-block
 # grid overhead amortizes. Multiple of 16 so bf16 (16, 128) tiling divides.
 _FUSED_BLOCK_ROWS = 512
+
+# Element types the fused fold is selected for on TPU (collective.
+# _fused_reduce_candidate): the ones Mosaic compiled on the v5e in the PR 21
+# chip run. Anything else takes the chained XLA fold by selection — a dtype
+# listed here that fails to lower is an error, not a fallback.
+FUSED_DTYPES = frozenset({"float32", "bfloat16", "int32"})
 
 
 def _fused_reduce_kernel(nin: int, combine: Callable, *refs):
