@@ -631,14 +631,59 @@ class CollectiveChannel(_Waitable):
         # per-rank next-round counters + live per-round rendezvous slots
         self.rank_round = [0] * size
         self.rounds: dict[int, dict] = {}
+        self.cid: Any = None    # set by whoever keys this channel
 
     def _round_state(self, rnd: int) -> dict:
         st = self.rounds.get(rnd)
         if st is None:
             st = self.rounds[rnd] = {
                 "contribs": [_EMPTY] * self.size, "arrived": 0,
-                "results": None, "picked": 0, "opname": None}
+                "results": None, "picked": 0, "opname": None,
+                # stamped by the last arriver for the waiters to cut their
+                # wait by: its own deposit, and "results published"
+                "t_dep": 0.0, "t_pub": 0.0}
         return st
+
+    def _acquire(self, rank: int, opname: str):
+        """Take the channel's lock; returns this thread's open pvar op scope
+        (None: pvars and tracing both off, one TLS read). With a scope, what
+        the op did before it first got here is its ``front_door``, the
+        acquire itself is ``lock``, and the round it is about to run (a
+        rank's counter moves on its own thread only, so it is read before
+        the lock) decides whether the op publishes its span tree."""
+        sc = _pv.scope()
+        if sc is None:
+            self.cond.acquire()
+            return None
+        first = not sc.spans
+        if first:
+            _pv.enter_channel(sc, self.cid, self.rank_round[rank], rank,
+                              opname)
+        t_in = _pv.monotonic()
+        self.cond.acquire()
+        if first:
+            sc.spans.append(("front_door", sc.t0, t_in))
+        sc.spans.append(("lock", t_in, _pv.monotonic()))
+        return sc
+
+    @staticmethod
+    def _waited(sc, st: dict, t0: float, t1: float) -> None:
+        """A waiter's one wait, cut at the last arriver's two stamps:
+        waiting for a late peer (``rdv_skew``), for its combine
+        (``rdv_fold``), and for this thread to run again once results were
+        published (``rdv_wake``). The three tile the wait; ``op_end`` adds
+        them up as ``rendezvous``, the span tree draws that as their
+        parent. Phases in ``sc.spans`` never overlap."""
+        dep, pub = st["t_dep"], st["t_pub"]
+        if not dep:             # 0.0: the last arriver held no op scope
+            sc.spans.append(("rendezvous", t0, t1))
+            return
+        if dep < t0:            # a batched round that was complete before
+            dep = t0            # this rank turned to wait for it
+        if pub < dep:
+            pub = dep
+        sc.spans += (("rdv_skew", t0, dep), ("rdv_fold", dep, pub),
+                     ("rdv_wake", pub, t1))
 
     def run(self, rank: int, contrib: Any, combine: Callable[[list[Any]], Sequence[Any]],
             opname: str, plan=None, unlocked_fold: bool = False) -> Any:
@@ -654,7 +699,11 @@ class CollectiveChannel(_Waitable):
         # round k — so nothing else can mutate the round slot while the lock
         # is down, and waiters, P2P progress and other communicators never
         # contend with a long fold for the condvar.
-        self.cond.acquire()
+        #
+        # pvar phase spans: the last arriver's combine is the fold (its
+        # DISPATCH, on device operands), every other rank's block is the
+        # rendezvous (see _acquire and _waited for the rest).
+        sc = self._acquire(rank, opname)
         try:
             rnd = self.rank_round[rank]
             self.rank_round[rank] += 1
@@ -669,13 +718,11 @@ class CollectiveChannel(_Waitable):
                 raise err
             st["contribs"][rank] = contrib
             st["arrived"] += 1
-            # pvar phase spans: last arriver's combine is the fold; every
-            # other rank's block below is the rendezvous. One TLS read when
-            # no scope is open (pvars and tracing both off).
-            sc = _pv.scope()
             if st["arrived"] == self.size:
                 contribs = list(st["contribs"])
-                t0 = _pv.monotonic() if sc is not None else 0.0
+                if sc is not None:
+                    t0 = st["t_dep"] = _pv.monotonic()
+                    sc.last = True
                 try:
                     if unlocked_fold:
                         self.cond.release()
@@ -689,7 +736,8 @@ class CollectiveChannel(_Waitable):
                     self.ctx.fail(e)
                     raise
                 if sc is not None:
-                    sc.spans.append(("fold", t0, _pv.monotonic()))
+                    t1 = st["t_pub"] = _pv.monotonic()
+                    sc.spans.append(("fold", t0, t1))
                 if len(results) != self.size:
                     err = MPIError(f"combine for {opname} returned {len(results)} "
                                    f"results for {self.size} ranks")
@@ -704,7 +752,7 @@ class CollectiveChannel(_Waitable):
                                f"collective {opname}",
                                limit=collective_wait_limit(opname))
                 if sc is not None:
-                    sc.spans.append(("rendezvous", t0, _pv.monotonic()))
+                    self._waited(sc, st, t0, _pv.monotonic())
             res = st["results"][rank]
             st["picked"] += 1
             if st["picked"] == self.size:
@@ -735,9 +783,8 @@ class CollectiveChannel(_Waitable):
             contrib, combine, opname, ufold = ops[0]
             return [self.run(rank, contrib, combine, opname,
                              unlocked_fold=ufold)]
-        sc = _pv.scope()
         deposited = []          # (rnd, st, opname) in Start order
-        self.cond.acquire()
+        sc = self._acquire(rank, ops[0][2])
         try:
             fold_pending = False
             for contrib, combine, opname, ufold in ops:
@@ -756,7 +803,9 @@ class CollectiveChannel(_Waitable):
                 st["arrived"] += 1
                 if st["arrived"] == self.size:
                     contribs = list(st["contribs"])
-                    t0 = _pv.monotonic() if sc is not None else 0.0
+                    if sc is not None:
+                        t0 = st["t_dep"] = _pv.monotonic()
+                        sc.last = True
                     try:
                         if ufold:
                             # safe for the same reason as in run(): this
@@ -774,7 +823,8 @@ class CollectiveChannel(_Waitable):
                         self.ctx.fail(e)
                         raise
                     if sc is not None:
-                        sc.spans.append(("fold", t0, _pv.monotonic()))
+                        t1 = st["t_pub"] = _pv.monotonic()
+                        sc.spans.append(("fold", t0, t1))
                     if len(results) != self.size:
                         err = MPIError(
                             f"combine for {opname} returned {len(results)} "
@@ -795,8 +845,7 @@ class CollectiveChannel(_Waitable):
                                    f"collective {opname}",
                                    limit=collective_wait_limit(opname))
                     if sc is not None:
-                        sc.spans.append(
-                            ("rendezvous", t0, _pv.monotonic()))
+                        self._waited(sc, st, t0, _pv.monotonic())
                 out.append(st["results"][rank])
                 st["picked"] += 1
                 if st["picked"] == self.size:

@@ -172,16 +172,22 @@ class DeviceBuffer:
             val = on_sharding(val, self.value.sharding)
         self.value = self.value.at[idx].set(val)
 
-    def setflat(self, src: Any, count: Optional[int] = None) -> None:
-        """Assign the first ``count`` flat elements from src."""
+    def setflat(self, src: Any, count: Optional[int] = None) -> bool:
+        """Assign the first ``count`` flat elements from src. Returns True
+        where that enqueued a copy of a whole jax array from another
+        device (the collectives count those; the compare is made once)."""
         v = self.value
         # Fast path: full replacement by an identically-shaped jax array is a
         # pure rebind — no device dispatch at all. This is the hot lane of the
         # host-path collectives (the combined result is handed straight back).
         if (is_jax_array(src) and src.dtype == v.dtype and src.shape == v.shape
                 and (count is None or count == v.size)):
-            self.value = on_sharding(src, v.sharding)
-            return
+            if src.sharding == v.sharding:
+                self.value = src
+                return False
+            import jax
+            self.value = jax.device_put(src, v.sharding)
+            return True
         import jax.numpy as jnp
         n = (count if count is not None
              else int(np.prod(np.shape(src), dtype=np.int64)))
@@ -191,6 +197,7 @@ class DeviceBuffer:
             flat = jnp.ravel(_onto(src, v))
             out = jnp.ravel(v).at[:n].set(flat[:n])
             self.value = out.reshape(v.shape)
+        return False
 
     def copy(self) -> "DeviceBuffer":
         return DeviceBuffer(self.value)
@@ -278,7 +285,7 @@ def write_flat(dest: Any, src: Any, count: Optional[int] = None) -> Any:
     dest: numpy array (strided views fine) or DeviceBuffer. Returns dest.
     """
     if isinstance(dest, DeviceBuffer):
-        dest.setflat(src, count)
+        dest.setflat(src, count)    # (who counts copies calls it directly)
         return dest
     if isinstance(dest, np.ndarray):
         srcarr = np.asarray(src)

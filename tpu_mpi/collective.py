@@ -247,8 +247,40 @@ def _colocated(arrs: Sequence[Any]) -> Optional[Sequence[Any]]:
     placement): every operand is already there and nothing moves."""
     if not arrs or not all(is_jax_array(a) for a in arrs):
         return None
-    home = arrs[0].sharding
-    return [on_sharding(a, home) for a in arrs]
+    return _colocate(arrs, arrs[0].sharding)
+
+
+def _colocate(arrs: Sequence[Any], home: Any) -> list:
+    """``arrs`` on ``home``, each that is elsewhere by a copy enqueued here.
+    Inside an op scope the copies between chips are counted; where the op
+    publishes its span tree the enqueueing is its ``colocate`` span (a child
+    of the fold's dispatch) and the watcher stamps when the last copy had
+    arrived (:func:`_watch_fold`)."""
+    sc = _pv.scope()
+    if sc is None:
+        return [on_sharding(a, home) for a in arrs]
+    t0 = _pv.monotonic() if sc.tree else 0.0
+    out = []
+    for a in arrs:
+        if a.sharding == home:
+            out.append(a)
+        else:
+            _pv.note_moved(sc, True, a.nbytes)
+            out.append(on_sharding(a, home))
+    if sc.tree:
+        sc.nested = (sc.nested or []) + [("colocate", t0, _pv.monotonic())]
+    return out
+
+
+def _watch_fold(operands: Sequence[Any], out: Any) -> None:
+    """The device's end of a fold whose operands crossed chips (see
+    ``perfvars.watch``): from the start of their ``colocate`` span to when
+    they had all arrived, and to when the fold's output was ready."""
+    sc = _pv.scope()
+    if sc is not None and sc.tree and sc.moved_in is not None:
+        t0 = sc.nested[-1][1]       # the start of their ``colocate``
+        _pv.watch(sc, t0, ("copy_in.done", list(operands)),
+                  ("fold.done", out))
 
 
 def _concat(parts: Sequence[Any], home: Any = None) -> Any:
@@ -318,7 +350,9 @@ def _jitted_fold(arrs: Sequence[Any], op: Op, mode: str):
         # schedule) where the gate selects it, else the chained XLA fold
         fused = _fused_reduce_candidate(op, arrs) if mode == "reduce" else None
         jitted = jax.jit(fused or fold)
-        out = jitted(*arrs)
+        with _pv.setup_span("jitted_fold.compile",
+                            function=(fused or fold).__name__, mode=mode):
+            out = jitted(*arrs)
     with _fold_lock:
         _fold_compiled[key] = jitted
         while len(_fold_compiled) > _FOLD_CAP:
@@ -367,7 +401,10 @@ def _reduce_arrays(arrs: Sequence[Any], op: Op,
     dev = _colocated(arrs)
     if dev is not None:
         out = _jitted_fold(dev, op, "reduce")
-        return functools.reduce(op, dev) if out is _NOT_JITTABLE else out
+        if out is _NOT_JITTABLE:
+            out = functools.reduce(op, dev)
+        _watch_fold(dev, out)
+        return out
     if schedule is not None and len(arrs) > 1:
         out = _chunked_fold(arrs, op, schedule)
         if out is not None:
@@ -1265,58 +1302,65 @@ def _auto_hot_run(args: tuple) -> Any:
 
 
 def _reduce_family(args, has_root: bool, mode: str, name: str) -> Any:
-    sendbuf, recvbuf, count, op, root, comm, alloc = _parse_reduce_args(args, has_root, name)
-    rank, size = comm.rank(), comm.size()
-    scalar_in = np.isscalar(sendbuf) or isinstance(sendbuf, (int, float, complex, bool, np.generic))
-    inplace = isinstance(sendbuf, _InPlace)
-    if inplace:
-        if _is_none(recvbuf):
-            raise MPIError(f"IN_PLACE {name} needs a buffer")
-        sendbuf = recvbuf
-    if count is None:
-        count = element_count(sendbuf)
-    assert_minlength(sendbuf, count)
-    if recvbuf is not None and not _is_none(recvbuf) and not inplace:
-        assert_minlength(recvbuf, count)
-    if mode == "reduce":
-        # Zero-copy contribution: the reduce fold's distributed output is
-        # always FRESH data (for n >= 2 the fold allocates; for n == 1 every
-        # consumer below copies or self-assigns), and every rank is blocked
-        # in the rendezvous until the fold has run — so the live buffer is
-        # safe to expose and the to_wire snapshot copy is pure overhead.
-        # Scan/Exscan keep the snapshot: Exscan hands rank 0's contribution
-        # to rank 1 AS-IS, aliasing rank 0's buffer after it returns.
-        payload = wire_view(sendbuf, count)
-    else:
-        payload = to_wire(sendbuf, count)
-
-    # auto-arm (ISSUE 11): a repeated same-signature plain Allreduce is
-    # promoted onto the registered persistent path; the armed runner skips
-    # plan lookup AND bandit exploration (auto-armed plans never explore —
-    # the explored variant would fork the call off its registered opname
-    # lockstep). Under tracing the gate only returns a trace model.
-    _model = None
-    if mode == "reduce" and not has_root and name == "Allreduce" \
-            and not scalar_in:
-        _runner, _model = _auto_arm_gate(comm, args, sendbuf, recvbuf, op,
-                                         count, payload, alloc)
-        if _runner is not None:
-            return _runner()
-
-    cplan = _reduce_plan(comm, name, mode, op, count, payload)
-    if mode == "reduce" and _tune_online.state() is not None:
-        cplan = _explore_reduce_variant(comm, cplan, op, count, payload)
-    # Own the pvar op scope across BOTH the rendezvous (_run) and the
-    # result consumption below, so the copy-out into the user's recvbuf
-    # lands in the same phase breakdown as the channel's rendezvous/fold
-    # spans (the inner _run sees the open scope and defers finalization).
-    sc = _pv.op_begin() if (_pv.enabled() or _ev.enabled()) else None
-    # while tracing, stamp the contribution buffer's identity into the
-    # signature (copy — cplan.sig may be plan-cache shared) so the R302
-    # pass can see a stale donated result fed back into a reduction
-    sig = dict(cplan.sig, bufid=_ev.buf_id(sendbuf)) if _ev.enabled() \
-        else cplan.sig
+    """One reduce-family call inside ONE pvar op scope, open from the entry
+    (this one's, or ``Allreduce``'s, which then owns it) to the return:
+    argument parsing, the plan and the auto-arm gate are its front door, and
+    the copy-out into the user's recvbuf lands in the same phase breakdown
+    as the channel's rendezvous/fold spans (the inner ``_run`` sees the open
+    scope and defers finalization). The body leaves what the op was in
+    ``sc.meta``; whoever opened the scope closes it."""
+    own = _pv.op_begin() if (_pv.enabled() or _ev.enabled()) else None
+    sc = own or _pv.scope()
     try:
+        sendbuf, recvbuf, count, op, root, comm, alloc = _parse_reduce_args(args, has_root, name)
+        rank, size = comm.rank(), comm.size()
+        scalar_in = np.isscalar(sendbuf) or isinstance(sendbuf, (int, float, complex, bool, np.generic))
+        inplace = isinstance(sendbuf, _InPlace)
+        if inplace:
+            if _is_none(recvbuf):
+                raise MPIError(f"IN_PLACE {name} needs a buffer")
+            sendbuf = recvbuf
+        if count is None:
+            count = element_count(sendbuf)
+        assert_minlength(sendbuf, count)
+        if recvbuf is not None and not _is_none(recvbuf) and not inplace:
+            assert_minlength(recvbuf, count)
+        if mode == "reduce":
+            # Zero-copy contribution: the reduce fold's distributed output is
+            # always FRESH data (for n >= 2 the fold allocates; for n == 1 every
+            # consumer below copies or self-assigns), and every rank is blocked
+            # in the rendezvous until the fold has run — so the live buffer is
+            # safe to expose and the to_wire snapshot copy is pure overhead.
+            # Scan/Exscan keep the snapshot: Exscan hands rank 0's contribution
+            # to rank 1 AS-IS, aliasing rank 0's buffer after it returns.
+            payload = wire_view(sendbuf, count)
+        else:
+            payload = to_wire(sendbuf, count)
+
+        # auto-arm (ISSUE 11): a repeated same-signature plain Allreduce is
+        # promoted onto the registered persistent path; the armed runner skips
+        # plan lookup AND bandit exploration (auto-armed plans never explore —
+        # the explored variant would fork the call off its registered opname
+        # lockstep). Under tracing the gate only returns a trace model.
+        _model = None
+        if mode == "reduce" and not has_root and name == "Allreduce" \
+                and not scalar_in:
+            _runner, _model = _auto_arm_gate(comm, args, sendbuf, recvbuf, op,
+                                             count, payload, alloc)
+            if _runner is not None:
+                return _runner()
+
+        cplan = _reduce_plan(comm, name, mode, op, count, payload)
+        if mode == "reduce" and _tune_online.state() is not None:
+            cplan = _explore_reduce_variant(comm, cplan, op, count, payload)
+        if sc is not None:
+            sc.meta = (name.lower(), cplan.sig.get("algo"),
+                       cplan.sig.get("dtype"), _pv.payload_nbytes(payload))
+        # while tracing, stamp the contribution buffer's identity into the
+        # signature (copy — cplan.sig may be plan-cache shared) so the R302
+        # pass can see a stale donated result fed back into a reduction
+        sig = dict(cplan.sig, bufid=_ev.buf_id(sendbuf)) if _ev.enabled() \
+            else cplan.sig
         if has_root:
             result = _run_rooted(comm, root, payload, cplan.combine,
                                  cplan.opname, plan=cplan.hint, _sig=sig)
@@ -1351,14 +1395,25 @@ def _reduce_family(args, has_root: bool, mode: str, name: str) -> Any:
             write_flat(target, result, count)
         else:
             t0 = _pv.monotonic()
-            write_flat(target, result, count)
+            # the result lives where the fold ran, the rank's DeviceBuffer on
+            # its own chip: a copy-out between the two is counted
+            if isinstance(target, DeviceBuffer):
+                if target.setflat(result, count):
+                    _pv.note_moved(sc, False, result.nbytes)
+            else:
+                write_flat(target, result, count)
             sc.spans.append(("copy", t0, _pv.monotonic()))
+            _watch_copyout(sc, t0, target)
         return target
     finally:
-        if sc is not None:
-            _pv.op_end(sc, comm, coll=name.lower(), algo=cplan.sig.get("algo"),
-                       dtype=cplan.sig.get("dtype"),
-                       nbytes=_pv.payload_nbytes(payload))
+        if own is not None:
+            _pv.op_end(own, args[-1] if args else None)
+
+
+def _watch_copyout(sc, t0: float, tgt: Any) -> None:
+    """The device's end of a copy-out between chips (``perfvars.watch``)."""
+    if sc.tree and sc.moved_out is not None:
+        _pv.watch(sc, t0, ("copy_out.done", tgt.value))
 
 
 def _shape_result(result: Any, like: Any, count: int) -> Any:
@@ -1384,11 +1439,20 @@ def Allreduce(*args) -> Any:
     (src/collective.jl:691-738). Deterministic rank-ordered reduction. A
     repeated identical call auto-arms onto the registered persistent path
     (ISSUE-11) and repeat hits dispatch through the front door below."""
-    if len(args) >= 3:
-        out = _auto_hot_run(args)
-        if out is not _AUTO_MISS:
-            return out
-    return _reduce_family(args, has_root=False, mode="reduce", name="Allreduce")
+    # the pvar op scope opens HERE, so the front door of either lane is in
+    # it; a nested call (the nonblocking worker inside an outer op) gets
+    # None and the outer owner's scope collects its phases
+    sc = _pv.op_begin() if (_pv.enabled() or _ev.enabled()) else None
+    try:
+        if len(args) >= 3:
+            out = _auto_hot_run(args)
+            if out is not _AUTO_MISS:
+                return out
+        return _reduce_family(args, has_root=False, mode="reduce",
+                              name="Allreduce")
+    finally:
+        if sc is not None:
+            _pv.op_end(sc, args[-1] if args else None)
 
 
 def Scan(*args) -> Any:
@@ -1801,10 +1865,12 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
 
     if not _traceable(plain_fold, *([sds] * size)):
         return None                 # host-only / untraceable op: no lane
-    plain = jax.jit(plain_fold).lower(*([sds] * size)).compile()
+    with _pv.setup_span("fold.compile", function="plain_fold"):
+        plain = jax.jit(plain_fold).lower(*([sds] * size)).compile()
     if donate:
-        donated = jax.jit(chain, donate_argnums=(0,)) \
-            .lower(sds, *([sds] * size)).compile()
+        with _pv.setup_span("fold.compile", function="chain"):
+            donated = jax.jit(chain, donate_argnums=(0,)) \
+                .lower(sds, *([sds] * size)).compile()
         ring = [jnp.zeros((count,), dt, device=home),
                 jnp.zeros((count,), dt, device=home)]
     state = {"k": 0}
@@ -1817,21 +1883,20 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
             is_jax_array(c) and tuple(c.shape) == (count,) and c.dtype == dt
             for c in cs)
         if good:
-            cs = [on_sharding(c, home) for c in cs]
-            if not donate:
-                # copy-out contract (auto-armed lane): the AOT chain still
-                # skips per-round trace/lower work, but every round's output
-                # is a fresh array — no slot is ever re-donated under a
-                # result the user may still hold (the R302 hazard).
-                return [plain(*cs)] * n
-            slot = ring[k & 1]
-            # an operand aliasing the accumulator (a rank fed a previous
-            # result straight back) can't be donated over — fold fresh
+            cs = _colocate(cs, home)
+            slot = ring[k & 1] if donate else None
+            # copy-out contract (auto-armed lane, ``donate=False``): the
+            # AOT chain still skips per-round trace/lower work, but every
+            # round's output is a fresh array — no slot is ever re-donated
+            # under a result the user may still hold (the R302 hazard).
+            # Likewise an operand aliasing the accumulator (a rank fed a
+            # previous result straight back) can't be donated over
             if slot is not None and not any(c is slot for c in cs):
-                out = donated(slot, *cs)
-                ring[k & 1] = out
-                return [out] * n
-            return [plain(*cs)] * n
+                out = ring[k & 1] = donated(slot, *cs)
+            else:
+                out = plain(*cs)
+            _watch_fold(cs, out)
+            return [out] * n
         # a peer contributed a host / reshaped payload this round: generic
         total = _reduce_arrays(list(cs), op)
         return [total] * n
@@ -1841,6 +1906,13 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
 
 def _register_allreduce(comm: Comm, args,
                         donate: bool = True) -> Optional[PlanRegistration]:
+    """:func:`_bind_allreduce` under the ``plan.register`` set-up span."""
+    with _pv.setup_span("plan.register", cid=str(getattr(comm, "cid", None))):
+        return _bind_allreduce(comm, args, donate)
+
+
+def _bind_allreduce(comm: Comm, args,
+                    donate: bool = True) -> Optional[PlanRegistration]:
     """Build the registered-buffer fast path of one ``Allreduce_init``
     signature (the ISSUE-6 tentpole), or None when the operands are not
     eligible (every round then takes the generic worker path).
@@ -2009,10 +2081,14 @@ def _register_allreduce(comm: Comm, args,
                 v = tgt.value
                 if is_jax_array(res) and res.size == v.size \
                         and res.dtype == v.dtype:
-                    tgt.setflat(res if res.shape == v.shape
-                                else res.reshape(v.shape))
+                    moved = tgt.setflat(res if res.shape == v.shape
+                                        else res.reshape(v.shape))
                 else:
-                    tgt.setflat(res, count)
+                    moved = tgt.setflat(res, count)
+                if moved:           # the result came from another chip
+                    sc = _pv.scope()
+                    if sc is not None:
+                        _pv.note_moved(sc, False, res.nbytes)
                 return tgt
     else:
         return None
@@ -2033,12 +2109,19 @@ def _register_allreduce(comm: Comm, args,
     runkw = {"unlocked_fold": True} if thread_tier else {}
     pv_nbytes = _pv.payload_nbytes(payload)
 
+    pv_meta = ("allreduce", sig.get("algo"), sig.get("dtype"), pv_nbytes)
+
     def run_round():
         # the fast-armed Wait: one rendezvous round trip on THIS thread.
         # _ordered_run is unnecessary by construction — arming required an
         # idle nonblocking worker, and any later submission on this comm
         # demotes the armed round before it gets here.
-        sc = _pv.op_begin() if _pv.enabled() else None
+        # Under ``Allreduce`` the op scope is the caller's (open since ITS
+        # entry: the front door), which closes it; else it is ours
+        own = _pv.op_begin() if _pv.enabled() else None
+        sc = own or _pv.scope()
+        if sc is not None:
+            sc.lane, sc.meta = "armed", pv_meta
         try:
             res = channel.run(rank, contrib(), combine, opname,
                               plan=hint, **runkw)
@@ -2047,11 +2130,12 @@ def _register_allreduce(comm: Comm, args,
             t0 = _pv.monotonic()
             val = copyout(res)
             sc.spans.append(("copy", t0, _pv.monotonic()))
+            if sc.moved_out is not None:
+                _watch_copyout(sc, t0, val)
             return val
         finally:
-            if sc is not None:
-                _pv.op_end(sc, comm, coll="allreduce", algo=sig.get("algo"),
-                           dtype=sig.get("dtype"), nbytes=pv_nbytes)
+            if own is not None:
+                _pv.op_end(own, comm)
 
     # batched-submission hook (ISSUE 11): the pieces Waitall needs to
     # deposit K armed rounds through ONE rendezvous wakeup on the thread

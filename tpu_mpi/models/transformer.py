@@ -122,12 +122,18 @@ def transformer_forward(cfg: TransformerConfig, params: dict,
     else:
         positions = jnp.arange(t)
 
-    x = params["embed"][tokens]                                   # (b, t, d)
-    for layer in params["layers"]:
-        x = _attn_ffn_block(cfg, layer, x, positions,
-                            tp_axis=tp_axis, sp_axis=sp_axis)
-    x = _rms_norm(x, params["ln_f"])
-    return (x @ params["embed"].T).astype(jnp.float32)            # (b, t, V)
+    # named scopes (embed, layer_<i>/attn, layer_<i>/mlp, head_loss) reach
+    # every op's metadata, forward and backward: a device trace is read by
+    # them (PERF.md section 3, "train step")
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]                               # (b, t, d)
+    for i, layer in enumerate(params["layers"]):
+        with jax.named_scope(f"layer_{i}"):
+            x = _attn_ffn_block(cfg, layer, x, positions,
+                                tp_axis=tp_axis, sp_axis=sp_axis)
+    with jax.named_scope("head_loss"):
+        x = _rms_norm(x, params["ln_f"])
+        return (x @ params["embed"].T).astype(jnp.float32)        # (b, t, V)
 
 
 def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
@@ -135,13 +141,28 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
                     sp_axis: Optional[str]) -> jnp.ndarray:
     """One transformer layer (pre-norm attention + FFN), tp/sp aware —
     shared by the flat forward and the pipelined 4-axis stage."""
-    b, t, _ = x.shape
-    h = cfg.n_heads
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
-    h_local = h // tp
-    dh = cfg.head_dim
+    h_local = cfg.n_heads // tp
 
-    # -- attention --
+    with jax.named_scope("attn"):
+        x = x + _attn(cfg, layer, x, positions, h_local,
+                      tp_axis=tp_axis, sp_axis=sp_axis)
+    with jax.named_scope("mlp"):
+        y = _rms_norm(x, layer["ln2"])
+        if tp_axis is not None:
+            hmid = jax.nn.gelu(column_parallel(y, layer["w_in"], axis=tp_axis))
+            x = x + row_parallel(hmid, layer["w_out"], axis=tp_axis)
+        else:
+            x = x + jax.nn.gelu(y @ layer["w_in"]) @ layer["w_out"]
+    return x
+
+
+def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
+          positions: jnp.ndarray, h_local: int, *, tp_axis: Optional[str],
+          sp_axis: Optional[str]) -> jnp.ndarray:
+    """The attention half of a layer: what is added to the residual."""
+    b, t, _ = x.shape
+    dh = cfg.head_dim
     y = _rms_norm(x, layer["ln1"])
     if tp_axis is not None:
         qkv = column_parallel(y, layer["w_qkv"], axis=tp_axis)
@@ -163,18 +184,8 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
         o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
     o = o.transpose(0, 2, 1, 3).reshape(b, t, h_local * dh)
     if tp_axis is not None:
-        x = x + row_parallel(o, layer["w_proj"], axis=tp_axis)
-    else:
-        x = x + o @ layer["w_proj"]
-
-    # -- feed-forward --
-    y = _rms_norm(x, layer["ln2"])
-    if tp_axis is not None:
-        hmid = jax.nn.gelu(column_parallel(y, layer["w_in"], axis=tp_axis))
-        x = x + row_parallel(hmid, layer["w_out"], axis=tp_axis)
-    else:
-        x = x + jax.nn.gelu(y @ layer["w_in"]) @ layer["w_out"]
-    return x
+        return row_parallel(o, layer["w_proj"], axis=tp_axis)
+    return o @ layer["w_proj"]
 
 
 def _xent(logits, labels):
@@ -203,15 +214,18 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
         def loss_fn(p):
             logits = transformer_forward(cfg, p, tokens, tp_axis=tp_axis,
                                          sp_axis=sp_axis)
-            return _xent(logits, labels)
+            with jax.named_scope("head_loss"):
+                return _xent(logits, labels)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         # dp/sp shards saw different tokens: sum their param grads. The tp
         # direction needs no reduction — the f/g operators already produced
         # tp-correct grads (sharded params local, replicated params invariant).
-        grads = jax.tree_util.tree_map(lambda g: lax.psum(g, reduce_axes), grads)
-        params = jax.tree_util.tree_map(lambda p, g: (p - lr * g).astype(p.dtype),
-                                        params, grads)
+        with jax.named_scope("optimizer"):
+            grads = jax.tree_util.tree_map(
+                lambda g: lax.psum(g, reduce_axes), grads)
+            params = jax.tree_util.tree_map(
+                lambda p, g: (p - lr * g).astype(p.dtype), params, grads)
         loss = lax.pmean(loss, reduce_axes)
         return params, loss
 

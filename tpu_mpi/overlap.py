@@ -660,6 +660,8 @@ def _flush_group(cid: Any, group: list) -> None:
     ops = [(p["contrib"](), p["combine"], p["opname"],
             bool(p["runkw"].get("unlocked_fold"))) for p in parts]
     sc = _pv.op_begin() if _pv.enabled() else None
+    if sc is not None:
+        sc.lane = "armed"
     try:
         results = channel.run_batch(rank, ops)
         for r, p, res in zip(group, parts, results):

@@ -7,11 +7,29 @@ pieces:
 
 - **Per-comm counters** keyed ``(world rank, cid)``: bytes sent/received,
   op counts per ``(collective, algorithm, dtype)``, time blocked in the
-  Wait family, host-path phase time split rendezvous / fold / copy,
-  chunk-pipeline overlap inputs, RMA epoch counts, and per-collective
-  latency histograms (log2-µs buckets, ``config.pvars_hist_bins`` wide).
-  Plan-cache hits/misses ride along at snapshot time from
-  ``overlap.plans.stats()``.
+  Wait family, host-path phase time (``phase_s``: front_door / lock /
+  rendezvous, split into rdv_skew / rdv_fold / rdv_wake / fold / copy),
+  bytes and copies moved between chips (``xchip_bytes``,
+  ``xchip_copies``), chunk-pipeline overlap inputs, RMA epoch counts, and
+  per-collective latency histograms (log2-µs buckets,
+  ``config.pvars_hist_bins`` wide). Plan-cache hits/misses ride along at
+  snapshot time from ``overlap.plans.stats()``, and the wall time spent
+  registering plans and compiling folds as ``arming_s``.
+  ``fold`` and ``copy`` on device operands are DISPATCH times: the host
+  seconds it took to enqueue the fold (its operand copies included) and
+  the copy-out, not the seconds the device worked. The device's end of
+  them is the watcher's (below) or a ``jax.profiler`` trace's.
+- **The op span tree** (docs/observability.md "Op spans"): with
+  ``trace_sample > 0`` the op scope opened here is published whole, one
+  tree per op, into ``tracectx``'s buffer: under a serve-tier request
+  context as children of the request span, and on a plain SPMD rank thread
+  under a root of its own keyed ``(cid, round, rank)``; the round decides,
+  alike on every rank, whether the op is sampled (the channel's door,
+  :func:`enter_channel`). From there to its end a sampled op also holds one
+  ``jax.profiler.TraceAnnotation("tpu_mpi:<coll>")`` carrying ``mono_ns``,
+  so a device profile and the spans share a clock, and one watcher thread
+  stamps when its copies between chips and the fold they feed were done on
+  the device (``copy_in.done``, ``fold.done``, ``copy_out.done``).
 - **Timed spans** on the event IR: when tracing is on, the op scope opened
   here stamps the recorded :class:`~tpu_mpi.analyze.events.Event` with
   ``t_start``/``t_end`` and the phase spans the channels observed, which
@@ -24,13 +42,17 @@ pieces:
 Overhead discipline (the ``analyze.events.enabled()`` contract): every hot
 hook front-loads :func:`enabled` — one tuple compare against
 ``config.GENERATION`` — so a ``TPU_MPI_PVARS=0`` run pays a single
-predictable branch per operation; the committed
-``benchmarks/results/overhead-pvars-cpusim.json`` artifact pins that.
+predictable branch per operation. What the default ``pvars = 1`` costs is
+read on the chip, not on a CPU: the benchmark's untraced runs pay it
+(``coll_latency_p50`` of ``osu-allreduce-4r1c.small-reuse`` in
+``PERF_LEDGER.jsonl``), and PERF.md sections 5 and 6 give the traced run's
+reading beside the untraced one, which is what the span tree adds.
 
 Span-attribution caveat: phase spans collect into a thread-local op scope,
 so a BLOCKING collective that routes through the nonblocking worker (only
 when that comm has outstanding ``I*`` ops) keeps its counters but loses its
-per-phase spans — the worker thread owns no scope for it.
+per-phase spans — the worker thread owns no scope for it (PERF.md
+section 7 lists it among what the measurement cannot see).
 """
 
 from __future__ import annotations
@@ -48,7 +70,11 @@ monotonic = time.monotonic
 
 PHASES = ("rendezvous", "fold", "copy",
           # hierarchical-composite sub-phases (backend._run_hier_*)
-          "intra_fold", "inter_exchange", "allgather")
+          "intra_fold", "inter_exchange", "allgather",
+          # the thread tier's call, from its entry to the device
+          # (_runtime.CollectiveChannel): the front door up to the channel,
+          # the channel's condvar, and a waiter's rendezvous cut in three
+          "front_door", "lock", "rdv_skew", "rdv_fold", "rdv_wake")
 
 _UNSET = object()
 _enabled_cache: Tuple[Any, bool] = (_UNSET, False)
@@ -65,6 +91,7 @@ class _TLS(threading.local):
     # class-attribute defaults: fresh threads read these without the
     # AttributeError/getattr-default dance on the hot path
     scope = None                      # the open _OpScope of this thread
+    setup = None                      # id of the open setup_span, if any
     acct = None                       # (store_gen, {key: CommPvars}) cache
     wait_owned = False                # a wait-time owner is on the stack
 
@@ -121,7 +148,7 @@ class CommPvars:
                  "hist", "pipe_ops", "pipe_chunks", "pipe_fold_ns",
                  "pipe_wait_ns", "explore_calls", "explore_explored",
                  "table_swaps", "last_swap_gen", "batch_flushes",
-                 "batch_ops")
+                 "batch_ops", "xchip_bytes", "xchip_copies")
 
     def __init__(self, rank: int, cid: int):
         self.rank = rank
@@ -157,6 +184,10 @@ class CommPvars:
         # they carried — occupancy = ops / flushes
         self.batch_flushes = 0
         self.batch_ops = 0
+        # bytes and copies this rank enqueued whose source and destination
+        # devices differ (operands to the folding chip, results back)
+        self.xchip_bytes = 0
+        self.xchip_copies = 0
 
     def snapshot(self) -> dict:
         bins = max(4, int(config.load().pvars_hist_bins))
@@ -166,6 +197,8 @@ class CommPvars:
             "bytes_sent": self.bytes_sent, "bytes_recv": self.bytes_recv,
             "sends": self.sends, "recvs": self.recvs,
             "wait_s": self.wait_ns / 1e9,
+            "xchip_bytes": self.xchip_bytes,
+            "xchip_copies": self.xchip_copies,
             "ops": {"|".join(k): v for k, v in sorted(self.ops.items())},
             "times": [{"coll": c, "algo": a, "nbytes": b, "count": t[0],
                        "total_s": t[1] / 1e9, "min_s": t[2] / 1e9,
@@ -241,13 +274,29 @@ def _acct(comm: Any = None, cid: Optional[int] = None,
 # ---------------------------------------------------------------------------
 
 class _OpScope:
-    __slots__ = ("t0", "spans", "ev", "trace")
+    """One op's scope. Class-level defaults, so opening one stores two
+    attributes; what only a sampled span tree needs is set by whoever
+    needs it."""
+
+    ev: Any = None            # the trace Event of this op, if any
+    trace: Any = None         # the request TraceCtx, when sampled
+    nested: Any = None        # spans inside a phase (``colocate``)
+    tree = False              # publish this op's own span tree
+    ann: Any = None           # the live profiler annotation, if any
+    t_ann = 0.0               # ... had begun by then
+    cid: Any = None
+    round: Any = None         # the channel's round (first, if many)
+    rank = -1                 # rank on the op's communicator
+    last = False              # this rank was the round's last arriver
+    lane = "legacy"           # "armed": the registered round ran it
+    meta: Any = None          # (coll, algo, dtype, nbytes) for op_end
+    moved_in: Any = None      # [bytes, copies] colocate moved ...
+    moved_out: Any = None     # ... and copy-out, between chips
 
     def __init__(self):
         self.t0 = monotonic()
+        # phases of this op, disjoint: (name, t0, t1)
         self.spans: List[Tuple[str, float, float]] = []
-        self.ev: Any = None           # the trace Event of this op, if any
-        self.trace: Any = None        # the request TraceCtx, when sampled
 
 
 def scope() -> Optional[_OpScope]:
@@ -258,13 +307,14 @@ def scope() -> Optional[_OpScope]:
 
 def op_begin() -> Optional[_OpScope]:
     """Open an op scope on this thread. Returns None when one is already
-    open — the outermost owner finalizes (``_reduce_family`` wraps ``_run``
-    so the copy-out phase lands inside the same scope)."""
+    open — the outermost owner finalizes (``Allreduce`` opens it on entry,
+    so the front door, the rendezvous and the copy-out land in one phase
+    breakdown; whoever runs under it fetches it with :func:`scope`)."""
     if _tls.scope is not None:
         return None
     sc = _OpScope()
     if _tc.enabled():
-        # request tracing: adopt the TraceCtx the serve-tier rank worker
+        # span sampling is on: attach the op to the request trace context
         # bound to this thread, so the op's phase spans become children of
         # the request span (one tuple compare when sampling is off)
         sc.trace = _tc.current()
@@ -272,12 +322,37 @@ def op_begin() -> Optional[_OpScope]:
     return sc
 
 
+def enter_channel(sc: _OpScope, cid: Any, rnd: int, rank: int,
+                  opname: str) -> None:
+    """The op in ``sc`` is at its channel's door, about to run round
+    ``rnd`` of communicator ``cid`` as ``rank``. On an SPMD rank thread (no
+    request context) that round decides, alike on every rank, whether the
+    op publishes its span tree; if so it holds one profiler annotation from
+    here to its end, which carries the round and the monotonic clock."""
+    if sc.trace is not None or not _tc.keep_round(rnd):
+        return
+    sc.tree, sc.cid, sc.round, sc.rank = True, cid, rnd, rank
+    import jax
+    # one clock with a device profile: (profiler time, monotonic time)
+    # pairs, from the annotation's start and the stat it carries. The
+    # annotation begins between two clock reads, ``mono_ns`` and ``t_ann``:
+    # what a reader holds the aligned clocks to
+    sc.ann = jax.profiler.TraceAnnotation(
+        "tpu_mpi:" + opname.split("@", 1)[0].lower(), cid=str(cid),
+        round=rnd, rank=rank, mono_ns=int(monotonic() * 1e9))
+    sc.ann.__enter__()
+    sc.t_ann = monotonic()
+
+
 def op_end(sc: _OpScope, comm: Any = None, coll: Optional[str] = None,
            algo: Optional[str] = None, dtype: Optional[str] = None,
            nbytes: Optional[int] = None) -> None:
     """Close the scope: stamp the op's trace event (t_start/t_end/phases)
-    and fold duration + spans into the per-comm counters."""
+    and fold duration + spans into the per-comm counters. Without ``coll``
+    the op is described by ``sc.meta``, which the code that ran it left."""
     _tls.scope = None
+    if coll is None and sc.meta is not None:
+        coll, algo, dtype, nbytes = sc.meta
     shim = _shim_map()
     if shim and coll is not None:
         # test/debug latency shim (config.tune_shim): the sleep lands
@@ -288,13 +363,25 @@ def op_end(sc: _OpScope, comm: Any = None, coll: Optional[str] = None,
         if pause:
             time.sleep(pause)
     t1 = monotonic()
+    if sc.ann is not None:
+        sc.ann.__exit__(None, None, None)
     ev = sc.ev
     if ev is not None:
         ev.t_start = sc.t0
         ev.t_end = t1
         if sc.spans:
-            ev.phases = list(sc.spans)
-    if sc.trace is not None:
+            # the event IR keeps a waiter's wait whole, as ``rendezvous``
+            ev.phases = [("rendezvous", s0, sc.spans[i + 2][2])
+                         if name == _tc.RDV_PARTS[0] else (name, s0, s1)
+                         for i, (name, s0, s1) in enumerate(sc.spans)
+                         if name not in _tc.RDV_PARTS[1:]]
+    if sc.tree:
+        _tc.emit_op(coll or "op", sc.cid, sc.round, sc.rank, nbytes, sc.lane,
+                    sc.last, sc.t0, t1, sc.t_ann,
+                    tuple(sc.spans + sc.nested if sc.nested else sc.spans),
+                    tuple(sc.moved_in) if sc.moved_in else None,
+                    tuple(sc.moved_out) if sc.moved_out else None)
+    elif sc.trace is not None:
         # per-rank request span: the op bracket parents under the request
         # context, and each measured phase nests under the op span
         from ._runtime import current_env
@@ -314,8 +401,10 @@ def op_end(sc: _OpScope, comm: Any = None, coll: Optional[str] = None,
     bins = max(4, int(config.load().pvars_hist_bins))
     dur_ns = int((t1 - sc.t0) * 1e9)
     key = (coll, algo or "star", -1 if nbytes is None else int(nbytes))
+    phase_ns = acct.phase_ns
+    okey = (coll, algo or "star", dtype or "?")
+    idx = (dur_ns // 1000).bit_length()   # log2 bucket of the µs latency
     with _store_lock:
-        okey = (coll, algo or "star", dtype or "?")
         acct.ops[okey] = acct.ops.get(okey, 0) + 1
         t = acct.times.get(key)
         if t is None:
@@ -328,13 +417,138 @@ def op_end(sc: _OpScope, comm: Any = None, coll: Optional[str] = None,
             if dur_ns > t[3]:
                 t[3] = dur_ns
         for name, s0, s1 in sc.spans:
-            if name in acct.phase_ns:
-                acct.phase_ns[name] += int((s1 - s0) * 1e9)
+            if name in phase_ns:
+                ns = int((s1 - s0) * 1e9)
+                phase_ns[name] += ns
+                if name in _tc.RDV_PARTS:    # they tile one rendezvous wait
+                    phase_ns["rendezvous"] += ns
+        for moved in (sc.moved_in, sc.moved_out):
+            if moved is not None:
+                acct.xchip_bytes += moved[0]
+                acct.xchip_copies += moved[1]
         hist = acct.hist.get(coll)
         if hist is None:
             hist = acct.hist[coll] = [0] * bins
-        idx = (dur_ns // 1000).bit_length()   # log2 bucket of the µs latency
         hist[min(idx, len(hist) - 1)] += 1
+
+
+def note_moved(sc: _OpScope, inward: bool, nbytes: int) -> None:
+    """One copy between chips that this op enqueued: an operand to the
+    folding chip (``inward``) or the result to the rank's own."""
+    moved = sc.moved_in if inward else sc.moved_out
+    if moved is None:
+        moved = [0, 0]
+        if inward:
+            sc.moved_in = moved
+        else:
+            sc.moved_out = moved
+    moved[0] += int(nbytes)
+    moved[1] += 1
+
+
+# -- arming: plan registration and fold compiles ----------------------------
+#
+# Rare (once per signature and rank), so they are timed whenever pvars are
+# on: the wall time under them is the snapshot's ``arming_s`` (the union of
+# the top-level brackets over all threads: four ranks register at once),
+# and with span sampling on each is a span in tracectx's buffer.
+
+_arming: List[Tuple[float, float]] = []
+_ARMING_CAP = 4096
+
+
+class setup_span:
+    """``with setup_span("plan.register", cid=...)``: time one piece of
+    arming. Nested ones (``fold.compile`` under ``plan.register``) are its
+    children and add nothing to ``arming_s``."""
+
+    __slots__ = ("name", "attrs", "on", "t0", "sid", "parent")
+
+    def __init__(self, name: str, **attrs: Any):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self) -> "setup_span":
+        self.on = enabled()
+        if self.on:
+            self.parent = _tls.setup
+            self.sid = _tls.setup = _tc.new_id()
+            self.t0 = monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if not self.on:
+            return False
+        t1 = monotonic()
+        _tls.setup = self.parent
+        if self.parent is None:
+            with _store_lock:
+                if len(_arming) < _ARMING_CAP:
+                    _arming.append((self.t0, t1))
+        if _tc.enabled():
+            from ._runtime import current_env
+            env = current_env()
+            who = f"rank {env[1]}" if env is not None else "rank ?"
+            _tc.emit_setup_span(self.name, self.t0, t1, who, self.sid,
+                                self.parent, **self.attrs)
+        return False
+
+
+def arming_seconds() -> float:
+    """Wall seconds under a top-level set-up span so far, on any thread."""
+    with _store_lock:
+        spans = sorted(_arming)
+    total, end = 0.0, float("-inf")
+    for s0, s1 in spans:
+        if s1 > end:
+            total += s1 - max(s0, end)
+            end = s1
+    return total
+
+
+# -- the device's end of a copy between chips --------------------------------
+#
+# A host span around an asynchronous copy times the enqueue. An op that
+# publishes its span tree and enqueued copies between chips hands the arrays
+# to ONE watcher thread, which waits for them in the order they came
+# (``block_until_ready`` releases the GIL) and publishes when they were
+# done. The rank threads never wait for it. It holds the arrays alive
+# meanwhile, which is what ``trace_sample`` bounds: watching EVERY round of
+# the large-message star cost the four-chip cell 29% of its bandwidth (the
+# folding chip's memory fills a round sooner; 16.2 against 22.8 GB/s), one
+# round in 8 nothing that can be read (PERF.md section 6, PR 23).
+
+_watch_q: Any = None
+
+
+def _watch_loop(q: Any) -> None:
+    import jax
+    while True:
+        cid, rnd, rank, t0, stages = q.get()
+        try:
+            for name, arrays in stages:
+                jax.block_until_ready(arrays)
+                _tc.emit_round_span(name, cid, rnd, rank, t0, monotonic())
+        except RuntimeError:    # the array was donated or deleted meanwhile:
+            pass                # this round's remaining stages go unstamped
+        del stages
+
+
+def watch(sc: _OpScope, t0: float, *stages: Tuple[str, Any]) -> None:
+    """Hand ``(span name, arrays)`` stages of the op in ``sc`` (one that
+    publishes its tree) to the watcher: each span runs from ``t0`` (the
+    dispatch) to the moment its arrays, and those of the stages before it,
+    were ready."""
+    global _watch_q
+    q = _watch_q
+    if q is None:
+        with _store_lock:
+            q = _watch_q
+            if q is None:
+                import queue
+                q = _watch_q = queue.SimpleQueue()
+                threading.Thread(target=_watch_loop, args=(q,), daemon=True,
+                                 name="tpu_mpi-span-watcher").start()
+    q.put((sc.cid, sc.round, sc.rank, t0, stages))
 
 
 # -- test/debug latency shim (config.tune_shim) ------------------------------
@@ -790,6 +1004,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
     return {"schema": 1, "kind": "tpu_mpi-pvars", "level": level(),
             "topology": _topology_stamp(),
             "comms": comms, "plan_cache": plans.stats(),
+            "arming_s": arming_seconds(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
             "serve_frame": serve_frame_snapshot(),
@@ -829,6 +1044,7 @@ def reset() -> None:
         _front_door.clear()
         _front_door_gauges.clear()
         _locks.clear()
+        _arming.clear()
         _store_gen += 1
 
 
