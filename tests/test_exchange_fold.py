@@ -1,0 +1,284 @@
+"""The armed Allreduce of ranks that each sit on a device of their own
+(PR 24): one executable over those devices (all-to-all, rank-ordered fold
+of a slice, all-gather) instead of the star through rank 0's. The result
+must be the star's, bit for bit, and on every rank's own device; ranks that
+share a device keep the star. On the CPU-sim mesh (8 devices, rank i on
+device i); nothing here is a timing."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import tpu_mpi as MPI
+from tpu_mpi import SpmdContext, config, perfvars
+from tpu_mpi.testing import run_spmd
+
+N, CALLS = 4, 10
+
+
+@pytest.fixture(autouse=True)
+def _clean(monkeypatch):
+    monkeypatch.delenv("TPU_MPI_PVARS", raising=False)
+    config.load(refresh=True)
+    perfvars.pcontrol(1)
+    perfvars.reset()
+    yield
+    perfvars.reset()
+
+
+def _operand(rank, count, dtype, seed=0):
+    """Values whose fold depends on the order: magnitudes over seven
+    decades for floats (a + b + c is not a + (b + c)), any bits for ints."""
+    rng = np.random.default_rng(1000 * seed + rank)
+    if np.dtype(dtype).kind in "iu":
+        return rng.integers(-2**31, 2**31 - 1, count).astype(dtype)
+    x = rng.uniform(0.5, 2.0, count) * 10.0 ** rng.integers(-3, 4, count)
+    x = np.where(rng.random(count) < 0.5, -x, x)
+    return x.astype(dtype) if np.dtype(dtype).kind != "c" \
+        else (x + 1j * x[::-1]).astype(dtype)
+
+
+def _left_fold(fn, xs):
+    acc = xs[0]
+    for x in xs[1:]:
+        acc = fn(acc, x)
+    return acc
+
+
+def _folds(cid=None):
+    return sum(c["ingraph_folds"] for c in perfvars.snapshot()["comms"]
+               if cid is None or c["cid"] == cid)
+
+
+OPS = {
+    "sum": (MPI.SUM, np.add, np.float32),
+    "prod": (MPI.PROD, np.multiply, np.float32),
+    "max": (MPI.MAX, np.maximum, np.float32),
+    "bxor": (MPI.BXOR, np.bitwise_xor, np.int32),
+    # a user's traceable operator: no ufunc, so nothing says it acts per
+    # element, and the executable folds whole operands after an all-gather
+    "user-sub": (MPI.Op(lambda a, b: a - b, commutative=False),
+                 np.subtract, np.float32),
+}
+
+
+@pytest.mark.parametrize("count", [1024, 1023, 3],
+                         ids=["count-1024", "count-1023", "count-3"])
+@pytest.mark.parametrize("name", sorted(OPS))
+def test_armed_result_is_the_rank_ordered_left_fold(name, count):
+    op, fn, dtype = OPS[name]
+    got = {}
+
+    def body():
+        comm = MPI.COMM_WORLD
+        r, dev = comm.rank(), comm.device
+        send = MPI.DeviceBuffer(_operand(r, count, dtype), device=dev)
+        recv = MPI.DeviceBuffer(jnp.zeros(count, dtype, device=dev),
+                                device=dev)
+        for _ in range(CALLS):
+            MPI.Allreduce(send, recv, op, comm)
+        got[r] = (np.asarray(recv.value), recv.value.devices() == {dev})
+
+    run_spmd(body, N)
+    want = _left_fold(fn, [_operand(r, count, dtype) for r in range(N)])
+    if dtype is np.float32 and name != "max" and count > 1000:
+        # among a thousand elements some show the order of the fold
+        back = _left_fold(fn, [_operand(r, count, dtype)
+                               for r in reversed(range(N))])
+        assert want.tobytes() != back.tobytes()
+    for r in range(N):
+        out, home = got[r]
+        assert out.dtype == want.dtype and out.tobytes() == want.tobytes()
+        assert home, f"rank {r}'s result is not on its own device"
+    assert CALLS - 4 <= _folds(cid=0) < CALLS
+
+
+def test_split_half_folds_over_its_own_two_devices():
+    count, got = 512, {}
+
+    def body():
+        world = MPI.COMM_WORLD
+        wr = world.rank()
+        comm = MPI.Comm_split(world, wr % 2, wr)    # {0, 2} and {1, 3}
+        dev = comm.device
+        send = MPI.DeviceBuffer(_operand(wr, count, np.float32), device=dev)
+        recv = MPI.DeviceBuffer(jnp.zeros(count, jnp.float32, device=dev),
+                                device=dev)
+        for _ in range(CALLS):
+            MPI.Allreduce(send, recv, MPI.SUM, comm)
+        got[wr] = (np.asarray(recv.value), recv.value.devices(), dev)
+
+    run_spmd(body, N)
+    for wr in range(N):
+        out, where, dev = got[wr]
+        want = _left_fold(np.add, [_operand(w, count, np.float32)
+                                   for w in (wr % 2, wr % 2 + 2)])
+        assert out.tobytes() == want.tobytes()
+        assert where == {dev} and dev == jax.devices()[wr]
+    assert _folds() >= 2 * (CALLS - 4)
+
+
+@pytest.mark.parametrize("case", ["nine-ranks-eight-devices",
+                                  "every-rank-on-one-device",
+                                  "buffers-on-one-device",
+                                  "complex-operands"])
+def test_ranks_that_share_a_device_register_the_star(monkeypatch, case):
+    n = 9 if case == "nine-ranks-eight-devices" else N
+    dtype = np.complex64 if case == "complex-operands" else np.float32
+    if case == "every-rank-on-one-device":      # a one-chip host
+        monkeypatch.setattr(SpmdContext, "device_for",
+                            lambda self, rank: jax.devices()[0])
+    count, got = 256, {}
+
+    def body():
+        comm = MPI.COMM_WORLD
+        r = comm.rank()
+        dev = jax.devices()[0] if case == "buffers-on-one-device" \
+            else comm.device
+        send = MPI.DeviceBuffer(_operand(r, count, dtype), device=dev)
+        recv = MPI.DeviceBuffer(jnp.zeros(count, dtype, device=dev),
+                                device=dev)
+        for _ in range(CALLS):
+            MPI.Allreduce(send, recv, MPI.SUM, comm)
+        got[r] = (np.asarray(recv.value), recv.value.devices() == {dev})
+
+    run_spmd(body, n)
+    want = _left_fold(np.add, [_operand(r, count, dtype) for r in range(n)])
+    for r in range(n):
+        assert got[r][0].tobytes() == want.tobytes() and got[r][1]
+    assert _folds() == 0
+    from tpu_mpi.overlap import plans
+    assert plans.stats()["auto"]["hits"] > 0        # and it did arm
+
+
+@pytest.mark.parametrize("stray", ["host-array", "another-device"])
+def test_a_round_with_a_stray_contribution_falls_back(stray):
+    """Rank 2 contributes, on some rounds, what the executable over the
+    devices cannot take: those rounds fold generically, and are right."""
+    count, got = 512, {r: [] for r in range(N)}
+
+    def body():
+        comm = MPI.COMM_WORLD
+        r, dev = comm.rank(), comm.device
+        mine = [_operand(r, count, np.float32, seed=k) for k in range(CALLS)]
+        recv = MPI.DeviceBuffer(jnp.zeros(count, jnp.float32, device=dev),
+                                device=dev)
+        if r == 2 and stray == "host-array":    # numpy all the way
+            send, recv = mine[0].copy(), np.zeros(count, np.float32)
+        else:
+            send = MPI.DeviceBuffer(mine[0], device=dev)
+        for k in range(CALLS):
+            if isinstance(send, np.ndarray):
+                send[:] = mine[k]
+            elif r == 2 and k == CALLS - 2:     # once, late: on device 7
+                send.value = jax.device_put(mine[k], jax.devices()[7])
+            else:
+                send.value = jax.device_put(mine[k], dev)
+            MPI.Allreduce(send, recv, MPI.SUM, comm)
+            got[r].append(np.asarray(getattr(recv, "value", recv)).copy())
+
+    run_spmd(body, N)
+    for k in range(CALLS):
+        want = _left_fold(np.add, [_operand(r, count, np.float32, seed=k)
+                                   for r in range(N)])
+        for r in range(N):
+            assert got[r][k].tobytes() == want.tobytes(), (r, k)
+    if stray == "host-array":
+        assert _folds() == 0
+    else:
+        assert 0 < _folds() < CALLS
+
+
+def test_two_communicators_launch_over_the_same_devices_at_once():
+    """Ranks 0-3 and ranks 8-11 of a 12-rank job sit on devices 0-3 (rank r
+    on device r % 8) and fold on a communicator each: two executables over
+    the same devices, launched from unrelated threads, a few hundred times.
+    Unordered launches could reach two devices in opposite orders and
+    wait for each other for good; the job's time limit would say so."""
+    rounds, count, got = 300, 64, {}
+
+    def body():
+        world = MPI.COMM_WORLD
+        wr = world.rank()
+        comm = MPI.Comm_split(world, wr // 4, wr)
+        dev = comm.device
+        send = MPI.DeviceBuffer(_operand(wr, count, np.float32), device=dev)
+        recv = MPI.DeviceBuffer(jnp.zeros(count, jnp.float32, device=dev),
+                                device=dev)
+        for _ in range(rounds):
+            MPI.Allreduce(send, recv, MPI.SUM, comm)
+        got[wr] = (np.asarray(recv.value), recv.value.devices() == {dev})
+
+    run_spmd(body, 12, timeout=100.0)
+    for wr in range(12):
+        want = _left_fold(np.add, [_operand(w, count, np.float32)
+                                   for w in range(wr // 4 * 4,
+                                                  wr // 4 * 4 + 4)])
+        assert got[wr][0].tobytes() == want.tobytes() and got[wr][1]
+    assert _folds() >= 3 * (rounds - 4)
+
+
+def test_floats_cross_as_integers_where_the_compiler_sums_to_move(
+        monkeypatch):
+    """On the CPU the compiler never builds an all-gather from an
+    all-reduce; told that it did, the executable is compiled again with
+    floats crossing as integers, and is still the left fold, signs of zero
+    included."""
+    from tpu_mpi import collective
+    texts = []
+    monkeypatch.setattr(collective, "_sums_to_move",
+                        lambda hlo: texts.append(hlo) or True)
+    count, got = 7, {}
+    zeros = np.array([-0.0, 0.0, -0.0, 1.0, -1.0, 0.0, -0.0], np.float32)
+
+    def body():
+        comm = MPI.COMM_WORLD
+        r, dev = comm.rank(), comm.device
+        send = MPI.DeviceBuffer(zeros * (r + 1), device=dev)
+        recv = MPI.DeviceBuffer(jnp.ones(count, jnp.float32, device=dev),
+                                device=dev)
+        for _ in range(CALLS):
+            MPI.Allreduce(send, recv, MPI.SUM, comm)
+        got[r] = np.asarray(recv.value)
+
+    run_spmd(body, N)
+    want = _left_fold(np.add, [zeros * (r + 1) for r in range(N)])
+    assert np.signbit(want).tolist() == np.signbit(zeros).tolist()
+    assert all(got[r].tobytes() == want.tobytes() for r in range(N))
+    assert len(texts) == 1 and _folds() >= CALLS - 4
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    from jax.experimental import topologies
+    try:
+        return list(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices)
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.mark.parametrize("count", [2**28, 2], ids=["1GiB", "8B"])
+def test_compiled_for_the_v5e_only_integers_are_summed_to_move(
+        v5e_2x2, count):
+    """The exchange at the benchmark's size and at 8 B, compiled for four
+    described v5e chips: at 1 GiB nothing but an all-to-all and an
+    all-gather crosses chips and no operand is converted (each conversion
+    is a 1 GiB copy there); at 8 B the compiler gathers by an all-reduce
+    over zero padding, which only integers may take."""
+    import re
+    from tpu_mpi import collective
+    run, _ = collective._exchange_fold(MPI.SUM, count, np.dtype(np.float32),
+                                       v5e_2x2)
+    hlo = run.as_text()
+    crossing = set(re.findall(
+        r"= (\S+) (all-to-all|all-gather|all-reduce|reduce-scatter|"
+        r"collective-permute)[\w-]*\(", hlo))
+    assert {kind for _, kind in crossing} >= {"all-to-all"}
+    for shape, kind in crossing:
+        if kind in ("all-reduce", "reduce-scatter"):
+            assert "f32" not in shape and "u32" in shape, (shape, kind)
+    if count == 2**28:
+        assert {kind for _, kind in crossing} == {"all-to-all", "all-gather"}
+        assert "bitcast-convert" not in hlo

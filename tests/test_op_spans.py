@@ -124,8 +124,9 @@ def test_armed_rounds_have_one_tree_per_rank(monkeypatch):
             assert op["t0"] < op["t_ann"] <= door["t1"]
             assert "copyout" in names
             if r == last[0]:
+                # one launch over the ranks' chips: nothing is copied
                 assert names.count("fold_dispatch") == 1
-                assert "colocate" in names and "rendezvous" not in names
+                assert "colocate" not in names and "rendezvous" not in names
             else:
                 assert "fold_dispatch" not in names
                 (wait,) = [k for k in kids if k["name"] == "rendezvous"]
@@ -203,11 +204,16 @@ def test_xchip_bytes_count_copies_between_devices(monkeypatch, own_chip):
     comms = [c for c in perfvars.snapshot()["comms"] if c["cid"] == 0]
     moved = sum(c["xchip_bytes"] for c in comms)
     copies = sum(c["xchip_copies"] for c in comms)
-    if own_chip:        # the star: n-1 operands in, n-1 results out, a round
+    folds = sum(c["ingraph_folds"] for c in comms)
+    if own_chip:
+        # 2 (n-1) payloads cross chips a round by either route: the star's
+        # copies (n-1 operands in, n-1 results out) until the streak arms,
+        # then inside the one executable over the chips, which copies nothing
         assert moved == CALLS * (N - 1) * 2 * NBYTES
-        assert copies == CALLS * (N - 1) * 2
-    else:
-        assert moved == 0 and copies == 0
+        assert CALLS - 4 <= folds < CALLS
+        assert copies == (CALLS - folds) * (N - 1) * 2
+    else:       # buffers on one device: the star, and nothing to move
+        assert moved == 0 and copies == 0 and folds == 0
 
 
 def test_watcher_stamps_the_device_end_across_devices(monkeypatch):
@@ -223,22 +229,36 @@ def test_watcher_stamps_the_device_end_across_devices(monkeypatch):
             op = by_id[s["parent"]]
             assert op["name"] == "op" and op["round"] == s["round"]
             assert op["t0"] <= s["t0"] <= s["t1"]
+    # the star's copies belong to the calls before the streak armed; an
+    # armed round has none, one ``fold.done`` and every rank's result home
     moved = [s for s in spans if s["name"] == "colocate"]
-    assert all(s["bytes_moved"] == (N - 1) * NBYTES and s["copies"] == N - 1
-               for s in moved)
+    assert moved and all(by_id[s["trace"]]["lane"] == "legacy"
+                         and s["bytes_moved"] == (N - 1) * NBYTES
+                         and s["copies"] == N - 1 for s in moved)
+    armed = {(op["cid"], op["round"]) for op in spans
+             if op["name"] == "op" and op["lane"] == "armed"}
+    assert len(armed) >= CALLS - 4
+    for name, per_round in (("fold.done", 1), ("copy_out.done", N)):
+        got = [(s["cid"], s["round"]) for s in spans if s["name"] == name]
+        assert all(got.count(r) == per_round for r in armed), name
+    folds = sum(c["ingraph_folds"] for c in perfvars.snapshot()["comms"])
+    assert folds == len(armed)
 
 
 def test_plan_register_once_per_signature(monkeypatch):
+    from tpu_mpi import collective
+    monkeypatch.setattr(collective, "_exchange_compiled", type(
+        collective._exchange_compiled)())   # as in a process's first job
     _sample(monkeypatch, 1)
     _job(dup=True)
     setup = [s for s in tracectx.drain() if s["trace"].startswith("setup:")]
     regs = [s for s in setup if s["name"] == "plan.register"]
     assert sorted(s["who"] for s in regs) == [f"rank {r}" for r in range(N)]
     by_id = {s["span"]: s for s in setup}
-    compiles = [s for s in setup if s["name"] == "fold.compile"]
-    assert compiles and all(by_id[s["parent"]]["name"] == "plan.register"
-                            and s["function"] == "plain_fold"
-                            for s in compiles)
+    # four ranks register, one of them compiles the fold over their chips
+    (compiled,) = [s for s in setup if s["name"] == "fold.compile"]
+    assert by_id[compiled["parent"]]["name"] == "plan.register"
+    assert compiled["function"] == "exchange_fold"
     arming = perfvars.snapshot()["arming_s"]
     assert 0 < arming <= sum(s["t1"] - s["t0"] for s in setup
                              if s["parent"] is None) + 1e-9

@@ -222,6 +222,15 @@ _FOLD_CAP = 64
 _fold_compiled: "OrderedDict[Any, Any]" = OrderedDict()
 _fold_seen: "OrderedDict[Any, None]" = OrderedDict()
 _fold_lock = threading.Lock()
+# The fold executables that span the ranks' chips (_exchange_fold), one per
+# (fn, count, dtype, devices) and process: any rank is a round's last
+# arriver, so every rank's plan of a signature holds the same one.
+_exchange_compiled: "OrderedDict[Any, list]" = OrderedDict()
+# A multi-device executable is enqueued device by device. Two of them
+# enqueued at once from two threads (two communicators' last arrivers) could
+# reach two chips in opposite orders, and their collectives would wait for
+# each other: held around the enqueue only, never for the device's work.
+_exchange_launch = threading.Lock()
 
 
 def _traceable(fold, *avals) -> bool:
@@ -243,8 +252,12 @@ def _colocated(arrs: Sequence[Any]) -> Optional[Sequence[Any]]:
     not all jax arrays. Each rank's buffer lives on its own chip
     (``Comm.device``) and XLA refuses a computation whose arguments span
     devices: the fold runs where rank 0's contribution lives and the others
-    arrive by device-to-device copy. One chip (or CPU-sim default
-    placement): every operand is already there and nothing moves."""
+    arrive by device-to-device copy (a star; every rank's copy-out then
+    moves the result home). One chip (or CPU-sim default placement): every
+    operand is already there and nothing moves. What still takes this route
+    across chips: an Allreduce before its streak arms, Reduce, Scan and the
+    registered lane of ranks that share a chip. The armed Allreduce of
+    ranks on chips of their own does not (:func:`_exchange_fold`)."""
     if not arrs or not all(is_jax_array(a) for a in arrs):
         return None
     return _colocate(arrs, arrs[0].sharding)
@@ -1410,10 +1423,12 @@ def _reduce_family(args, has_root: bool, mode: str, name: str) -> Any:
             _pv.op_end(own, args[-1] if args else None)
 
 
-def _watch_copyout(sc, t0: float, tgt: Any) -> None:
-    """The device's end of a copy-out between chips (``perfvars.watch``)."""
-    if sc.tree and sc.moved_out is not None:
-        _pv.watch(sc, t0, ("copy_out.done", tgt.value))
+def _watch_copyout(sc, t0: float, tgt: Any, across: bool = False) -> None:
+    """The device's end of a copy-out between chips (``perfvars.watch``),
+    or, ``across``, of a result that the fold over the ranks' chips left on
+    this rank's own: when it was there."""
+    if sc.tree and (across or sc.moved_out is not None):
+        _pv.watch(sc, t0, ("copy_out.done", getattr(tgt, "value", tgt)))
 
 
 def _shape_result(result: Any, like: Any, count: int) -> Any:
@@ -1824,6 +1839,17 @@ def _comm_of(args) -> Comm:
 # per-call setup entirely — the training-loop shape.
 # ---------------------------------------------------------------------------
 
+def _left_chain(op: Op):
+    """``op`` over any number of operands as the rank-ordered left chain:
+    what every fold of the registered device lane computes."""
+    def plain_fold(*xs):
+        acc = xs[0]
+        for x in xs[1:]:
+            acc = op.fn(acc, x)
+        return acc
+    return plain_fold
+
+
 def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
                             device: Any, donate: bool = True):
     """The donated-accumulator fold executable for the registered device
@@ -1837,7 +1863,11 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
     until round k+2's fold re-donates that slot — the persistent in-place
     contract documented in docs/performance.md. Operands living on other
     chips are copied to ``device`` each round; every rank's copy-out moves
-    the result to its own chip. Returns the combine closure, or None when
+    the result to its own chip: a star. It is what ranks that share a chip
+    register (one chip; more ranks than chips, where ``device_for`` wraps),
+    and a rank whose operand is not on its own chip; ranks with a chip each
+    register :func:`_exchange_combine` instead, chosen once, at plan
+    creation, and not per round. Returns the combine closure, or None when
     the op can't trace (the caller then declines the device registration
     and the generic path applies); a fold that traces and then fails to
     compile raises."""
@@ -1857,12 +1887,7 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
             acc = op.fn(acc, x)
         return acc
 
-    def plain_fold(*xs):
-        acc = xs[0]
-        for x in xs[1:]:
-            acc = op.fn(acc, x)
-        return acc
-
+    plain_fold = _left_chain(op)
     if not _traceable(plain_fold, *([sds] * size)):
         return None                 # host-only / untraceable op: no lane
     with _pv.setup_span("fold.compile", function="plain_fold"):
@@ -1902,6 +1927,162 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
         return [total] * n
 
     return combine
+
+
+def _exchange_combine(op: Op, count: int, dtype: Any,
+                      devices: Sequence[Any]):
+    """The combine of the registered device lane where every rank sits on
+    a chip of its own (``devices``, in rank order): the rank-ordered left
+    fold as the ONE executable over those chips (:func:`_exchange_fold`),
+    dispatched once by the round's last arriver. Each contribution, already
+    on its rank's chip, is one shard of a global array (no copy); every
+    rank gets its own shard of the output back, on its own chip, so its
+    copy-out rebinds (``DeviceBuffer.setflat``'s fast path) and no
+    ``device_put`` follows. Nothing is donated: every round's output is
+    fresh, which keeps a persistent handle's result valid for longer than
+    its contract asks.
+
+    A round in which some rank contributed anything but a jax array of the
+    registered count and dtype on that rank's chip takes the generic
+    ``_reduce_arrays`` fold, star and all. Returns None when the op can't
+    trace, as :func:`_registered_device_fold` does."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    count = int(count)
+    dt = np.dtype(dtype)
+    size = len(devices)
+    homes = [SingleDeviceSharding(d) for d in devices]
+
+    if not _traceable(_left_chain(op),
+                      *([jax.ShapeDtypeStruct((count,), dt)] * size)):
+        return None
+    run, sharding = _exchange_fold(op, count, dt, devices)
+    crossed = 2 * (size - 1) * count * dt.itemsize
+
+    def combine(cs, rt=None):
+        if len(cs) != size or not all(
+                is_jax_array(c) and c.shape == (count,) and c.dtype == dt
+                and c.sharding == h for c, h in zip(cs, homes)):
+            return [_reduce_arrays(list(cs), op)] * len(cs)
+        sc = _pv.scope()
+        t0 = _pv.monotonic() if sc is not None and sc.tree else 0.0
+        whole = jax.make_array_from_single_device_arrays(
+            (size * count,), sharding, cs)
+        with _exchange_launch:
+            out = run(whole)
+        if sc is not None:
+            _pv.note_exchanged(sc, crossed)
+            if sc.tree:         # ``copy_in.done``: the operands were ready
+                _pv.watch(sc, t0, ("copy_in.done", cs), ("fold.done", out))
+        mine = {s.device: s.data for s in out.addressable_shards}
+        return [mine[d] for d in devices]
+
+    return combine
+
+
+def _across_chips(devices: Sequence[Any], rank: int, mine: Any) -> bool:
+    """Whether ``rank`` registers the fold over the ranks' chips: every rank
+    of ``devices`` (in rank order) has one of its own, and this rank's
+    operand ``mine`` lives on its (a rank that keeps its data elsewhere
+    could never take part in a round of it). A complex operand has no
+    integer of its width to cross as where the compiler sums to move (see
+    :func:`_exchange_fold`): it keeps the star."""
+    return (len(devices) > 1 and len(set(devices)) == len(devices)
+            and mine.dtype.kind != "c"
+            and mine.devices() == {devices[rank]})
+
+
+def _sums_to_move(hlo: str) -> bool:
+    """Whether a compiled exchange (its HLO text) moves operands by adding
+    them to zeros somewhere: the only arithmetic the fold itself asks for
+    is on the chip, so any reducing collective is the compiler's way of
+    moving, and not exact for every float."""
+    return "all-reduce" in hlo or "reduce-scatter" in hlo
+
+
+def _exchange_fold(op: Op, count: int, dt: Any, devices: Sequence[Any]):
+    """``(executable, sharding)`` of the rank-ordered fold over ranks that
+    each sit on a chip of their own: one ``shard_map`` over a 1-D mesh of
+    ``devices`` in rank order, taking the global ``(size * count,)`` array
+    whose shard r IS rank r's operand and returning one whose every shard
+    is the whole result.
+
+    Inside, for an operator known to act per element: an all-to-all, so
+    that chip r holds slice r of every rank's operand (the last slice
+    padded in-graph where ``size`` does not divide the count); the same
+    left chain (:func:`_left_chain`) over those slices, in rank order;
+    an all-gather of the folded slices. An elementwise left fold is separable
+    by slice, so this is bit-identical to the star's fold, for floats too,
+    where ``psum``, ``psum_scatter`` or a ring sum in another order and are
+    a different result. Each chip sends and receives (size-1)/size of a
+    payload twice, instead of size-1 payloads in and as many out through
+    one chip. For a user operator, which may couple elements: an all-gather
+    of the whole operands and the chain on every chip, in-graph and
+    bit-identical as well. XLA may build an all-gather from an all-reduce
+    over zero padding (on the v5e it does where a slice misses the chip's
+    tiling), and ``-0.0 + 0.0`` is ``0.0``: a program in which the compiler
+    left such a sum (:func:`_sums_to_move`) is compiled again with floats
+    crossing as unsigned integers of their width. Not as the rule: each
+    conversion is a copy through HBM there, 10 of 35 ms at 1 GiB.
+
+    Compiled once per signature and process under the ``fold.compile``
+    set-up span, whichever rank's registration comes first; the others
+    wait for it and share it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from .operators import is_elementwise
+    from .xla import collectives as _xc
+    size = len(devices)
+    key = (op.fn, count, str(dt), tuple(devices))
+    with _fold_lock:
+        entry = _exchange_compiled.get(key)
+        if entry is None:
+            entry = _exchange_compiled[key] = [threading.Lock(), None]
+            while len(_exchange_compiled) > _FOLD_CAP:
+                _exchange_compiled.popitem(last=False)
+    with entry[0]:
+        if entry[1] is not None:
+            return entry[1]
+        fold = _left_chain(op)
+        sliced = is_elementwise(op) and count > 0
+        each = -(-count // size)        # a slice's elements
+        sharding = NamedSharding(Mesh(np.array(devices), ("rank",)),
+                                 P("rank"))
+        whole = jax.ShapeDtypeStruct((size * count,), dt, sharding=sharding)
+
+        def compiled(as_bits: bool):
+            def crossing(move, x):
+                if not as_bits:
+                    return move(x)
+                bits = jax.lax.bitcast_convert_type(
+                    x, jnp.dtype(f"uint{8 * x.dtype.itemsize}"))
+                return jax.lax.bitcast_convert_type(move(bits), x.dtype)
+
+            def exchange_fold(x):
+                if sliced:
+                    flat = crossing(lambda v: _xc.alltoall(
+                        jnp.pad(v, (0, each * size - count)), axis="rank"), x)
+                    xs = [flat[i * each:(i + 1) * each] for i in range(size)]
+                else:
+                    xs = crossing(lambda v: _xc.allgather(v, axis="rank"), x)
+                acc = fold(*xs)
+                if sliced:
+                    acc = crossing(lambda v: _xc.allgather(
+                        v, axis="rank", tiled=True), acc)[:count]
+                return acc
+
+            return jax.jit(jax.shard_map(
+                exchange_fold, mesh=sharding.mesh, in_specs=P("rank"),
+                out_specs=P("rank"))).lower(whole).compile()
+
+        with _pv.setup_span("fold.compile", function="exchange_fold"):
+            run = compiled(False)
+            if jnp.issubdtype(dt, jnp.floating) \
+                    and _sums_to_move(run.as_text()):
+                run = compiled(True)
+        entry[1] = (run, sharding)
+    return entry[1]
 
 
 def _register_allreduce(comm: Comm, args,
@@ -1985,6 +2166,7 @@ def _bind_allreduce(comm: Comm, args,
     size, rank = comm.size(), comm.rank()
     channel = comm.channel()
     thread_tier = isinstance(channel, _ThreadChannel)
+    across = False      # the device lane's ranks have a chip each
 
     from .operators import is_elementwise
     sendview = pinned_wire_view(sendbuf, count)
@@ -2057,9 +2239,11 @@ def _bind_allreduce(comm: Comm, args,
         # ---- device lane: donated-accumulator fold, thread tier only ----
         payload = to_wire(sendbuf, count)
         cplan = _reduce_plan(comm, "Allreduce", "reduce", op, count, payload)
-        combine = _registered_device_fold(
-            op, count, payload.dtype, size,
-            ctx.device_for(comm.world_rank_of(0)), donate=donate)
+        devices = [ctx.device_for(comm.world_rank_of(r)) for r in range(size)]
+        across = _across_chips(devices, rank, payload)
+        combine = _exchange_combine(op, count, payload.dtype, devices) \
+            if across else _registered_device_fold(
+                op, count, payload.dtype, size, devices[0], donate=donate)
         if combine is None:
             return None
         contrib = lambda: to_wire(sendbuf, count)   # rebind-aware snapshot
@@ -2130,8 +2314,7 @@ def _bind_allreduce(comm: Comm, args,
             t0 = _pv.monotonic()
             val = copyout(res)
             sc.spans.append(("copy", t0, _pv.monotonic()))
-            if sc.moved_out is not None:
-                _watch_copyout(sc, t0, val)
+            _watch_copyout(sc, t0, val, across)
             return val
         finally:
             if own is not None:

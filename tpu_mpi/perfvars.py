@@ -10,7 +10,8 @@ pieces:
   Wait family, host-path phase time (``phase_s``: front_door / lock /
   rendezvous, split into rdv_skew / rdv_fold / rdv_wake / fold / copy),
   bytes and copies moved between chips (``xchip_bytes``,
-  ``xchip_copies``), chunk-pipeline overlap inputs, RMA epoch counts, and
+  ``xchip_copies``) and the rounds folded by the one executable over the
+  ranks' chips, which moves its bytes itself (``ingraph_folds``), chunk-pipeline overlap inputs, RMA epoch counts, and
   per-collective latency histograms (log2-µs buckets,
   ``config.pvars_hist_bins`` wide). Plan-cache hits/misses ride along at
   snapshot time from ``overlap.plans.stats()``, and the wall time spent
@@ -148,7 +149,7 @@ class CommPvars:
                  "hist", "pipe_ops", "pipe_chunks", "pipe_fold_ns",
                  "pipe_wait_ns", "explore_calls", "explore_explored",
                  "table_swaps", "last_swap_gen", "batch_flushes",
-                 "batch_ops", "xchip_bytes", "xchip_copies")
+                 "batch_ops", "xchip_bytes", "xchip_copies", "ingraph_folds")
 
     def __init__(self, rank: int, cid: int):
         self.rank = rank
@@ -184,10 +185,14 @@ class CommPvars:
         # they carried — occupancy = ops / flushes
         self.batch_flushes = 0
         self.batch_ops = 0
-        # bytes and copies this rank enqueued whose source and destination
-        # devices differ (operands to the folding chip, results back)
+        # bytes whose source and destination devices differ, and the copies
+        # this rank enqueued to move them (operands to the folding chip,
+        # results back); the rounds this rank, as last arriver, folded with
+        # the executable over the ranks' chips instead: their bytes cross
+        # inside it, by no copy
         self.xchip_bytes = 0
         self.xchip_copies = 0
+        self.ingraph_folds = 0
 
     def snapshot(self) -> dict:
         bins = max(4, int(config.load().pvars_hist_bins))
@@ -199,6 +204,7 @@ class CommPvars:
             "wait_s": self.wait_ns / 1e9,
             "xchip_bytes": self.xchip_bytes,
             "xchip_copies": self.xchip_copies,
+            "ingraph_folds": self.ingraph_folds,
             "ops": {"|".join(k): v for k, v in sorted(self.ops.items())},
             "times": [{"coll": c, "algo": a, "nbytes": b, "count": t[0],
                        "total_s": t[1] / 1e9, "min_s": t[2] / 1e9,
@@ -292,6 +298,7 @@ class _OpScope:
     meta: Any = None          # (coll, algo, dtype, nbytes) for op_end
     moved_in: Any = None      # [bytes, copies] colocate moved ...
     moved_out: Any = None     # ... and copy-out, between chips
+    exchanged: Any = None     # [bytes, rounds] the fold over chips moved
 
     def __init__(self):
         self.t0 = monotonic()
@@ -426,6 +433,9 @@ def op_end(sc: _OpScope, comm: Any = None, coll: Optional[str] = None,
             if moved is not None:
                 acct.xchip_bytes += moved[0]
                 acct.xchip_copies += moved[1]
+        if sc.exchanged is not None:
+            acct.xchip_bytes += sc.exchanged[0]
+            acct.ingraph_folds += sc.exchanged[1]
         hist = acct.hist.get(coll)
         if hist is None:
             hist = acct.hist[coll] = [0] * bins
@@ -444,6 +454,16 @@ def note_moved(sc: _OpScope, inward: bool, nbytes: int) -> None:
             sc.moved_out = moved
     moved[0] += int(nbytes)
     moved[1] += 1
+
+
+def note_exchanged(sc: _OpScope, nbytes: int) -> None:
+    """A round of this op (a batched flush holds several) was folded by
+    the one executable over the ranks' chips: ``nbytes`` crossed chips
+    inside it, and no copy was enqueued."""
+    if sc.exchanged is None:
+        sc.exchanged = [0, 0]
+    sc.exchanged[0] += int(nbytes)
+    sc.exchanged[1] += 1
 
 
 # -- arming: plan registration and fold compiles ----------------------------
