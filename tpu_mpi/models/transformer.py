@@ -13,6 +13,7 @@ write with Allreduce!/Sendrecv!/Alltoall! by hand.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -21,6 +22,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.dp import allreduce_grads
+from ..parallel.ep import moe_dropless
 from ..parallel.ring import ring_attention
 from ..parallel.tp import column_parallel, row_parallel
 
@@ -34,6 +36,21 @@ class TransformerConfig:
     d_ff: int = 256
     max_seq: int = 512
     dtype: Any = jnp.float32
+    # What follows describes a public architecture's block as data; the
+    # defaults are the flagship's (RMSNorm eps 1e-6, tanh-GELU MLP, tied
+    # head), whose program they leave as it was.
+    norm_eps: float = 1e-6
+    qk_norm: bool = False       # RMSNorm over the whole q and k vectors,
+    #                             learned scale, before the split into heads
+    n_experts: int = 0          # > 0: each layer's FFN is n_experts gated ones,
+    experts_per_tok: int = 1    # out(silu(gate(x)) * in(x)), of width d_ff; a
+    #                             token's top experts_per_tok by router softmax
+    #                             (float32) run it, weights not renormalised,
+    #                             no token dropped
+    router_aux_coef: float = 0.0    # x the load-balancing loss, added to the loss
+    tie_embeddings: bool = True     # False: an `lm_head` of its own
+    remat_attn: bool = False    # recompute a layer's attention in the backward
+    #                             pass and keep no [b, h, s, s] scores for it
 
     @property
     def head_dim(self) -> int:
@@ -46,21 +63,36 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
 
     keys = jax.random.split(key, 2 + 4 * cfg.n_layers)
     d, f = cfg.d_model, cfg.d_ff
+    experts = (cfg.n_experts,) if cfg.n_experts else ()
     params = {
         "embed": dense(keys[0], (cfg.vocab, d), d ** -0.5),
         "ln_f": jnp.ones((d,), cfg.dtype),
         "layers": [],
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(keys[1], (d, cfg.vocab), d ** -0.5)
     for i in range(cfg.n_layers):
         k = keys[2 + 4 * i: 6 + 4 * i]
-        params["layers"].append({
+        layer = {
             "ln1": jnp.ones((d,), cfg.dtype),
             "w_qkv": dense(k[0], (d, 3 * d), d ** -0.5),
             "w_proj": dense(k[1], (d, d), (2 * d * cfg.n_layers) ** -0.5),
             "ln2": jnp.ones((d,), cfg.dtype),
-            "w_in": dense(k[2], (d, f), d ** -0.5),
-            "w_out": dense(k[3], (f, d), (2 * f * cfg.n_layers) ** -0.5),
-        })
+            "w_in": dense(k[2], experts + (d, f), d ** -0.5),
+            "w_out": dense(k[3], experts + (f, d),
+                           (2 * f * cfg.n_layers) ** -0.5),
+        }
+        # the leaves a public block adds draw from keys of their own, so
+        # the ones above are the flagship's whatever else is configured
+        if cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((d,), cfg.dtype)
+            layer["k_norm"] = jnp.ones((d,), cfg.dtype)
+        if cfg.n_experts:
+            layer["w_gate"] = dense(jax.random.fold_in(k[2], 1),
+                                    experts + (d, f), d ** -0.5)
+            layer["w_router"] = dense(jax.random.fold_in(k[2], 2),
+                                      (d, cfg.n_experts), d ** -0.5)
+        params["layers"].append(layer)
     return params
 
 
@@ -71,19 +103,25 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
     col = P(None, tp_axis)
     row = P(tp_axis, None)
     rep = P()
-    return {
-        "embed": rep,
-        "ln_f": rep,
-        "layers": [{
-            "ln1": rep, "w_qkv": col, "w_proj": row,
-            "ln2": rep, "w_in": col, "w_out": row,
-        } for _ in range(cfg.n_layers)],
-    }
+    # every rank holds every expert whole (a layer with experts runs at
+    # tp 1 only: `_attn_ffn_block`)
+    layer = {"ln1": rep, "w_qkv": col, "w_proj": row, "ln2": rep,
+             "w_in": rep if cfg.n_experts else col,
+             "w_out": rep if cfg.n_experts else row}
+    if cfg.qk_norm:
+        layer.update(q_norm=P(tp_axis), k_norm=P(tp_axis))
+    if cfg.n_experts:
+        layer.update(w_gate=rep, w_router=rep)
+    specs = {"embed": rep, "ln_f": rep,
+             "layers": [dict(layer) for _ in range(cfg.n_layers)]}
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = rep
+    return specs
 
 
-def _rms_norm(x, scale):
+def _rms_norm(x, scale, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
+    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
 def _rope(x, positions):
@@ -107,6 +145,14 @@ def transformer_forward(cfg: TransformerConfig, params: dict,
     ``sp_axis`` name live mesh axes; with both None this is a plain
     single-device forward (the driver's single-chip entry).
     """
+    return _forward(cfg, params, tokens, tp_axis=tp_axis, sp_axis=sp_axis)[0]
+
+
+def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
+             tp_axis: Optional[str] = None, sp_axis: Optional[str] = None):
+    """(logits, routed): `routed` holds, for each layer with experts, its
+    router's (summed probabilities [n_experts] float32, token-slots per
+    expert [n_experts] int32) over this block's tokens; empty otherwise."""
     b, t = tokens.shape
     d, h = cfg.d_model, cfg.n_heads
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
@@ -127,43 +173,112 @@ def transformer_forward(cfg: TransformerConfig, params: dict,
     # them (PERF.md section 3, "train step")
     with jax.named_scope("embed"):
         x = params["embed"][tokens]                               # (b, t, d)
+    routed = []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer_{i}"):
-            x = _attn_ffn_block(cfg, layer, x, positions,
-                                tp_axis=tp_axis, sp_axis=sp_axis)
+            x, sent = _attn_ffn_block(cfg, layer, x, positions,
+                                      tp_axis=tp_axis, sp_axis=sp_axis)
+        if sent is not None:
+            routed.append(sent)
     with jax.named_scope("head_loss"):
-        x = _rms_norm(x, params["ln_f"])
-        return (x @ params["embed"].T).astype(jnp.float32)        # (b, t, V)
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return (x @ head).astype(jnp.float32), routed             # (b, t, V)
 
 
 def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
                     positions: jnp.ndarray, *, tp_axis: Optional[str],
-                    sp_axis: Optional[str]) -> jnp.ndarray:
+                    sp_axis: Optional[str]):
     """One transformer layer (pre-norm attention + FFN), tp/sp aware —
-    shared by the flat forward and the pipelined 4-axis stage."""
+    shared by the flat forward and the pipelined 4-axis stage. Returns the
+    layer's output and what its router sent where (None without experts)."""
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
     h_local = cfg.n_heads // tp
 
+    attn = functools.partial(_attn, cfg, h_local=h_local, tp_axis=tp_axis,
+                             sp_axis=sp_axis)
+    if cfg.remat_attn:
+        attn = jax.checkpoint(attn)
     with jax.named_scope("attn"):
-        x = x + _attn(cfg, layer, x, positions, h_local,
-                      tp_axis=tp_axis, sp_axis=sp_axis)
+        x = x + attn(layer, x, positions)
+    sent = None
     with jax.named_scope("mlp"):
-        y = _rms_norm(x, layer["ln2"])
-        if tp_axis is not None:
+        y = _rms_norm(x, layer["ln2"], cfg.norm_eps)
+        if cfg.n_experts:
+            if tp > 1:
+                # sharding an expert's width over tp needs the sums of the
+                # rows' and the weights' cotangents that `parallel/tp.py`'s
+                # operators make, and under `check_vma` they count twice
+                # (PERF.md section 7): not offered until a cell measures it
+                raise NotImplementedError(
+                    "a layer with experts runs at tp 1; shard its tokens "
+                    "over dp or sp")
+            out, sent = _expert_ffn(cfg, layer, y)
+            x = x + out
+        elif tp_axis is not None:
             hmid = jax.nn.gelu(column_parallel(y, layer["w_in"], axis=tp_axis))
             x = x + row_parallel(hmid, layer["w_out"], axis=tp_axis)
         else:
             x = x + jax.nn.gelu(y @ layer["w_in"]) @ layer["w_out"]
-    return x
+    return x, sent
+
+
+def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
+    """The routed FFN of one layer: what is added to the residual, and the
+    router's (summed probabilities, token-slots) per expert. Router logits
+    from the layer's dtype accumulate in float32 and the softmax is over all
+    experts in float32; a token's top `experts_per_tok` probabilities weigh
+    its experts' outputs as they are (not renormalised). Every token-slot is
+    computed: `parallel.ep.moe_dropless` sorts the slots by expert and the
+    experts run as grouped matrix multiplications over the row groups."""
+    b, t, d = y.shape
+    rows = y.reshape(b * t, d)
+    with jax.named_scope("router"):
+        logits = jnp.dot(rows, layer["w_router"],
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, chosen = lax.top_k(probs, cfg.experts_per_tok)
+
+    def experts(xs, sizes):
+        h = jax.nn.silu(lax.ragged_dot(xs, layer["w_gate"], sizes)) * \
+            lax.ragged_dot(xs, layer["w_in"], sizes)
+        return lax.ragged_dot(h, layer["w_out"], sizes)
+
+    out, slots = moe_dropless(rows, chosen, weights.astype(rows.dtype),
+                              experts, cfg.n_experts)
+    return out.reshape(b, t, d), (probs.sum(axis=0), slots)
+
+
+def load_balancing_loss(routed: list, n_tokens: int) -> jnp.ndarray:
+    """The auxiliary loss of `transformers`' `load_balancing_loss_func` over
+    all layers' routers together: n_experts x sum over experts of (token-
+    slots routed there / tokens) x (mean router probability), the means
+    over layers x tokens. Balanced routing gives `experts_per_tok`."""
+    prob_sum = sum(p for p, _c in routed)
+    slots = sum(c for _p, c in routed)
+    rows = len(routed) * n_tokens
+    n_experts = prob_sum.shape[0]
+    return n_experts * jnp.sum(
+        (slots.astype(jnp.float32) / rows) * (prob_sum / rows))
+
+
+def transformer_expert_counts(cfg: TransformerConfig, params: dict,
+                              tokens: jnp.ndarray) -> jnp.ndarray:
+    """[n_layers, n_experts] int32: the token-slots each layer's router
+    sends to each expert for this batch. Every row sums to tokens x
+    `experts_per_tok`: nothing is dropped. A forward pass of its own, for a
+    caller to run outside whatever it times."""
+    _logits, routed = _forward(cfg, params, tokens)
+    return jnp.stack([slots for _probs, slots in routed])
 
 
 def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
-          positions: jnp.ndarray, h_local: int, *, tp_axis: Optional[str],
+          positions: jnp.ndarray, *, h_local: int, tp_axis: Optional[str],
           sp_axis: Optional[str]) -> jnp.ndarray:
     """The attention half of a layer: what is added to the residual."""
     b, t, _ = x.shape
     dh = cfg.head_dim
-    y = _rms_norm(x, layer["ln1"])
+    y = _rms_norm(x, layer["ln1"], cfg.norm_eps)
     if tp_axis is not None:
         qkv = column_parallel(y, layer["w_qkv"], axis=tp_axis)
     else:
@@ -173,6 +288,9 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     # forward equals the single-device one.
     qkv = qkv.reshape(b, t, h_local, 3, dh).transpose(0, 2, 1, 3, 4)
     q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    if cfg.qk_norm:
+        q = _whole_vector_norm(cfg, q, layer["q_norm"], tp_axis)
+        k = _whole_vector_norm(cfg, k, layer["k_norm"], tp_axis)
     q = _rope(q, positions)
     k = _rope(k, positions)
     if sp_axis is not None:
@@ -188,6 +306,19 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     return o @ layer["w_proj"]
 
 
+def _whole_vector_norm(cfg: TransformerConfig, x: jnp.ndarray,
+                       scale: jnp.ndarray, tp_axis: Optional[str]):
+    """RMSNorm of q or k over the whole d_model-wide vector, before it is
+    cut into heads: x is (b, heads_local, t, head_dim), `scale` this rank's
+    columns of the learned scale, in the order [head][head_dim]."""
+    h, dh = x.shape[1], x.shape[3]
+    ss = jnp.sum(jnp.square(x.astype(jnp.float32)), axis=(1, 3), keepdims=True)
+    if tp_axis is not None:
+        ss = lax.psum(ss, tp_axis)
+    x = (x * lax.rsqrt(ss / cfg.d_model + cfg.norm_eps)).astype(x.dtype)
+    return x * scale.reshape(h, 1, dh)
+
+
 def _xent(logits, labels):
     logp = jax.nn.log_softmax(logits, axis=-1)
     ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
@@ -196,12 +327,18 @@ def _xent(logits, labels):
 
 def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
                            dp_axis: str = "dp", tp_axis: str = "tp",
-                           sp_axis: str = "sp"):
+                           sp_axis: str = "sp", donate: bool = False):
     """Build the jitted DP×TP×SP train step over ``mesh``.
 
     Returns (step, param_specs): ``step(params, tokens, labels) -> (params,
     loss)`` where tokens/labels are global (batch, seq) arrays sharded
-    (batch→dp, seq→sp) by shard_map, and params follow param_specs.
+    (batch→dp, seq→sp) by shard_map, and params follow param_specs. With
+    experts the loss is the cross-entropy plus `router_aux_coef` x the
+    load-balancing loss of each data shard's own tokens. ``donate`` gives
+    the step its `params` argument's buffers for the new parameters: the
+    caller's old tree is gone after the call. (Compiled for a v5e chip the
+    benchmark's OLMoE step holds 10.5 GB with it and 11.6 GB without:
+    PERF.md section 6, PR 25.)
     """
     specs = transformer_param_specs(cfg, tp_axis)
     axis_names = set(mesh.axis_names)
@@ -212,10 +349,15 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
 
     def local_step(params, tokens, labels):
         def loss_fn(p):
-            logits = transformer_forward(cfg, p, tokens, tp_axis=tp_axis,
-                                         sp_axis=sp_axis)
+            logits, routed = _forward(cfg, p, tokens, tp_axis=tp_axis,
+                                      sp_axis=sp_axis)
             with jax.named_scope("head_loss"):
-                return _xent(logits, labels)
+                loss = _xent(logits, labels)
+            if routed and cfg.router_aux_coef:
+                with jax.named_scope("aux_loss"):
+                    loss = loss + cfg.router_aux_coef * load_balancing_loss(
+                        routed, tokens.size)
+            return loss
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         # dp/sp shards saw different tokens: sum their param grads. The tp
@@ -233,7 +375,7 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
     step = jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, data_spec, data_spec),
-        out_specs=(specs, P())))
+        out_specs=(specs, P())), donate_argnums=(0,) if donate else ())
     return step, specs
 
 
@@ -521,8 +663,8 @@ def transformer_4d_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2,
             def stage_fn(sp_, x):
                 for i in range(sp_["w_qkv"].shape[0]):     # local layers
                     layer = {k: v[i] for k, v in sp_.items()}
-                    x = _attn_ffn_block(cfg, layer, x, positions,
-                                        tp_axis=tp_axis, sp_axis=sp_axis)
+                    x, _ = _attn_ffn_block(cfg, layer, x, positions,
+                                           tp_axis=tp_axis, sp_axis=sp_axis)
                 return x
 
             acts = pipeline_forward(stage_fn, stage, e, axis=pp_axis)

@@ -16,6 +16,6 @@ from .dp import allreduce_grads, pmean_tree
 from .tp import all_gather_output, column_parallel, row_parallel, tp_identity_fwd_psum_bwd, tp_psum_fwd_identity_bwd
 from .ring import ring_attention
 from .ulysses import heads_to_seq, seq_to_heads
-from .ep import moe_dispatch_combine
+from .ep import moe_dispatch_combine, moe_dropless
 from .pp import pipeline_forward
 from .halo import halo_exchange

@@ -7,10 +7,16 @@ shapes, so variable counts become a fixed per-expert *capacity* with masking
 one ``lax.all_to_all`` ships token buffers to their experts and one ships
 results back.
 
-Two realizations live here:
+Three realizations live here:
 
-- :func:`moe_dispatch_combine` — the jit/shard_map path for training steps
-  (static shapes, capacity masking, ``lax.all_to_all``);
+- :func:`moe_dropless` — top-k routing in which every token-slot is computed
+  (the layer of the public sparse-expert models, `models/transformer.py`):
+  the slots are sorted by expert and the experts run over the row groups
+  (grouped matrix multiplications); over an ``ep`` axis the row groups
+  travel by ``lax.all_to_all`` in buffers sized for the worst case;
+- :func:`moe_dispatch_combine` — top-1, rank == expert, tokens over a fixed
+  capacity dropped (static shapes, capacity masking, ``lax.all_to_all``):
+  the pipelined demo's (`transformer_pp_moe_*`);
 - :func:`moe_host_dispatch_combine` — the host-path decode-step variant
   used by the inference engine (``tpu_mpi.infer``): true variable counts
   over :func:`tpu_mpi.Alltoallv` on an ``ep`` communicator, which routes
@@ -22,8 +28,9 @@ Two realizations live here:
 
 from __future__ import annotations
 
+import functools
 import threading
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +55,123 @@ def _count_exchange_bufs(cid: int, n: int):
     if key not in cache:
         cache[key] = (np.zeros(n, np.int64), np.zeros(n, np.int64))
     return cache[key]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of_slots(tokens, order, inverse, k: int):
+    """tokens[order // k]: row i is the token of the i-th slot in expert
+    order. The gradient is read back by slot (a gather through `inverse`
+    and a sum over a token's k slots), so neither direction scatters."""
+    return tokens[order // k]
+
+
+def _rows_of_slots_fwd(tokens, order, inverse, k):
+    return tokens[order // k], inverse
+
+
+def _rows_of_slots_bwd(k, inverse, g):
+    return g[inverse].reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
+
+
+_rows_of_slots.defvjp(_rows_of_slots_fwd, _rows_of_slots_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(rows, perm, inverse):
+    """rows[perm] for a permutation and its inverse: the gradient is
+    g[inverse], a gather again."""
+    return rows[perm]
+
+
+def _permute_rows_fwd(rows, perm, inverse):
+    return rows[perm], inverse
+
+
+def _permute_rows_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def moe_dropless(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
+                 weights: jnp.ndarray,
+                 expert_fn: Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray],
+                 n_experts: int, *, axis: Optional[str] = None):
+    """Top-k Mixture-of-Experts dispatch/combine that drops nothing.
+
+    tokens: (t, d) local tokens; expert_idx: (t, k) each token's experts
+    (global ids, distinct per token); weights: (t, k) what each expert's
+    output is multiplied by. expert_fn(rows, group_sizes): the experts held
+    here applied to rows sorted by expert, group_sizes[e] rows for expert e
+    (`lax.ragged_dot`'s arguments); rows past the groups' sum are padding.
+    Returns ((t, d) sum over k of weights x expert(token), (n_experts,)
+    int32 token-slots of these tokens per expert). The experts always
+    process exactly t x k rows in all.
+
+    With ``axis``, inside shard_map: the tokens and the experts are both
+    sharded over it, rank r holding experts [r x n_experts/n, (r+1) x
+    n_experts/n). Every rank's row groups go to their experts' ranks and
+    come back by ``lax.all_to_all``, in buffers of t x k rows per peer (what
+    one peer receives when every slot picks its experts). No model calls it
+    with an axis yet: `transformer_train_step` has no ``ep`` axis, and this
+    path has run on virtual CPU devices only (tests/test_moe_layer.py).
+    """
+    t, k = expert_idx.shape
+    with jax.named_scope("dispatch"):
+        flat = expert_idx.reshape(t * k)        # slot s: token s // k
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+        sizes = jnp.sum(flat[:, None] == jnp.arange(n_experts)[None, :],
+                        axis=0, dtype=jnp.int32)
+        rows = _rows_of_slots(tokens, order, inverse, k)
+    if axis is None:
+        with jax.named_scope("experts"):
+            out = expert_fn(rows, sizes)
+    else:
+        out = _over_expert_ranks(rows, sizes, expert_fn, axis)
+    with jax.named_scope("combine"):
+        back = _permute_rows(out, inverse, order).reshape(t, k, -1)
+        return jnp.sum(back * weights[..., None], axis=1), sizes
+
+
+def _over_expert_ranks(rows: jnp.ndarray, sizes: jnp.ndarray,
+                       expert_fn: Callable, axis: str) -> jnp.ndarray:
+    """`expert_fn` over rows sorted by global expert, the experts sharded
+    over ``axis``: ship each rank's row groups to their experts' ranks, run
+    the local experts over what arrived, ship the results back."""
+    n = lax.axis_size(axis)
+    m, d = rows.shape                           # m = t x k slots
+    local = sizes.shape[0] // n                 # experts per rank
+    with jax.named_scope("dispatch"):
+        to_rank = sizes.reshape(n, local)       # [destination, its expert]
+        per_rank = to_rank.sum(axis=1)          # my rows for each rank
+        last = jnp.cumsum(per_rank)
+        first = last - per_rank
+        j = jnp.arange(m, dtype=jnp.int32)
+        # send[r, j] = the j-th of my rows for rank r's experts
+        src = first[:, None] + j[None, :]
+        live = j[None, :] < per_rank[:, None]
+        send = jnp.where(live[..., None], rows[jnp.clip(src, 0, m - 1)], 0)
+        recv = lax.all_to_all(send, axis, 0, 0, tiled=True)     # [source, j]
+        counts = lax.all_to_all(to_rank, axis, 0, 0, tiled=True)
+        # a source's rows arrive sorted by my expert; find each row's expert
+        # (or `local` for padding) and sort all sources' rows together
+        ends = jnp.cumsum(counts, axis=1)                       # (n, local)
+        expert = jnp.sum(j[None, :, None] >= ends[:, None, :], axis=-1)
+        by_expert = jnp.argsort(expert.reshape(n * m), stable=True)
+        undo = jnp.argsort(by_expert)
+        arrived = recv.reshape(n * m, d)[by_expert]
+        groups = counts.sum(axis=0).astype(jnp.int32)
+    with jax.named_scope("experts"):
+        done = expert_fn(arrived, groups)
+        done = jnp.where((jnp.arange(n * m) < groups.sum())[:, None], done, 0)
+    with jax.named_scope("combine"):
+        back = lax.all_to_all(done[undo].reshape(n, m, -1), axis, 0, 0,
+                              tiled=True)
+        rank = jnp.sum(j[:, None] >= last[None, :], axis=-1)    # row i's expert's
+        return back[rank, j - first[rank]]
 
 
 def moe_dispatch_combine(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
