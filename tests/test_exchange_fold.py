@@ -284,15 +284,25 @@ def test_compiled_for_the_v5e_only_integers_are_summed_to_move(
         assert "bitcast-convert" not in hlo
 
 
-def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(v5e_2x2):
+@pytest.mark.parametrize("attention", ["plain", "fused"])
+def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(
+        v5e_2x2, monkeypatch, attention):
     """The benchmark's OLMoE step (yardstick/configs/olmoe-1b-7b-1c.json:
     published widths, depth 4, batch 2 x 4096, parameters donated) compiled
-    for one described v5e chip: with attention recomputed it needs 10.5 GB
-    of the chip's 16 (15.4 GB with the scores of four layers kept, PR 25),
-    and the experts' grouped multiplications are the compiler's own kernel,
-    not a dense product over all 64 experts."""
+    for one described v5e chip. With the plain attention (what this CPU
+    backend selects) recomputed in the backward pass it needs 10.5 GB of
+    the chip's 16 (15.4 GB with the scores of four layers kept, PR 25);
+    with the fused kernel selected as on a TPU (PR 26) nothing is
+    recomputed, no [b, h, s, s] buffer exists, it needs 11.4 GB, and the
+    kernel's calls carry their layer's `attn` scope, forward and backward.
+    The experts' grouped multiplications are the compiler's own kernel, not
+    a dense product over all 64 experts."""
     import json
     import os
+    import re
+    from tpu_mpi.parallel import ring
+    if attention == "fused":
+        monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
     from jax.sharding import NamedSharding, PartitionSpec as P
     from tpu_mpi import xla
     from tpu_mpi.models.transformer import (TransformerConfig,
@@ -320,7 +330,35 @@ def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(v5e_2x2):
     held = m.argument_size_in_bytes + m.output_size_in_bytes \
         - m.alias_size_in_bytes + m.temp_size_in_bytes
     assert m.alias_size_in_bytes > 3.7e9        # the parameters are reused
-    assert 9e9 < held < 12e9, held
     hlo = compiled.as_text()
+    calls = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*causal_attention[^\"]*)\"", hlo)
+    if attention == "plain":
+        assert 9e9 < held < 11e9, held
+        assert "bf16[2,16,4096,4096]" in hlo and not calls
+    else:
+        assert 10.5e9 < held < 12.5e9, held
+        assert ",4096,4096]" not in hlo
+        assert all("/attn/" in c for c in calls)
+        where = sorted(("transpose(" in c, int(re.search(
+            r"jvp\(layer_(\d+)\)", c).group(1))) for c in calls)
+        assert where == [(backward, i) for backward in (False, True)
+                         for i in range(cfg.n_layers)]
     assert hlo.count("ragged-dot") >= 9 * cfg.n_layers
     assert "bf16[64,8192," not in hlo           # no [experts, tokens, ..] product
+
+
+def test_compiled_for_the_v5e_the_attention_kernel_at_the_flagships_shape(
+        v5e_2x2):
+    """The fused causal attention, forward and backward, at batch 8 x 16
+    heads x seq 1024 x head 64 in bfloat16 (half a lane tile wide: the
+    narrowest head the kernel's contract admits) lowers through Mosaic."""
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import pallas_kernels as pk
+    x = jax.ShapeDtypeStruct((8, 16, 1024, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e_2x2[0]))
+    hlo = jax.jit(jax.grad(lambda q, k, v: pk.causal_attention(
+        q, k, v, interpret=False).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(x, x, x).compile().as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert ",1024,1024]" not in hlo

@@ -23,7 +23,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.dp import allreduce_grads
 from ..parallel.ep import moe_dropless
-from ..parallel.ring import ring_attention
+from ..parallel.ring import (fused_attention_selected, local_attention,
+                             ring_attention, warm_kernel_imports)
 from ..parallel.tp import column_parallel, row_parallel
 
 
@@ -49,8 +50,10 @@ class TransformerConfig:
     #                             no token dropped
     router_aux_coef: float = 0.0    # x the load-balancing loss, added to the loss
     tie_embeddings: bool = True     # False: an `lm_head` of its own
-    remat_attn: bool = False    # recompute a layer's attention in the backward
-    #                             pass and keep no [b, h, s, s] scores for it
+    remat_attn: bool = False    # keep no [b, h, s, s] scores for the backward
+    #                             pass: where the plain attention runs, it is
+    #                             recomputed there (`jax.checkpoint`); the
+    #                             fused kernel keeps none as it is
 
     @property
     def head_dim(self) -> int:
@@ -198,7 +201,13 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     attn = functools.partial(_attn, cfg, h_local=h_local, tp_axis=tp_axis,
                              sp_axis=sp_axis)
     if cfg.remat_attn:
-        attn = jax.checkpoint(attn)
+        # the fused kernel (a ring of one, where it is selected) keeps no
+        # [b, h, s, s] scores as it is; the plain attention is recomputed
+        b, t, _ = x.shape
+        alone = sp_axis is None or lax.axis_size(sp_axis) == 1
+        if not (alone and fused_attention_selected(
+                (b, h_local, t, cfg.head_dim), x.dtype)):
+            attn = jax.checkpoint(attn)
     with jax.named_scope("attn"):
         x = x + attn(layer, x, positions)
     sent = None
@@ -296,10 +305,7 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     if sp_axis is not None:
         o = ring_attention(q, k, v, axis=sp_axis, causal=True)
     else:
-        s = jnp.einsum("bhqd,bhkd->bhqk", q * dh ** -0.5, k)
-        mask = jnp.tril(jnp.ones((t, t), dtype=bool))
-        s = jnp.where(mask, s, -1e30)
-        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+        o = local_attention(q, k, v)
     o = o.transpose(0, 2, 1, 3).reshape(b, t, h_local * dh)
     if tp_axis is not None:
         return row_parallel(o, layer["w_proj"], axis=tp_axis)
@@ -346,6 +352,7 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
         if a not in axis_names:
             raise ValueError(f"mesh is missing axis {a!r}")
     reduce_axes = (dp_axis, sp_axis)
+    warm_kernel_imports()       # off the first trace's path (set-up time)
 
     def local_step(params, tokens, labels):
         def loss_fn(p):
@@ -434,10 +441,7 @@ def _pp_moe_stage(cfg: TransformerConfig, n_experts: int, ep_axis: str,
         qkv = qkv.transpose(0, 2, 1, 3, 4)
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
         q, k = _rope(q, positions), _rope(k, positions)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q * dh ** -0.5, k)
-        mask = jnp.tril(jnp.ones((t, t), dtype=bool))
-        s = jnp.where(mask, s, -1e30)
-        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+        o = local_attention(q, k, v)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
         x = x + o @ stage_params["w_proj"][i]
 

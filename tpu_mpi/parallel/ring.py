@@ -6,17 +6,78 @@ src/topology.jl:155-164). TPU realization: each rank holds a sequence block of
 Q/K/V; K/V blocks rotate around the 'sp' mesh axis with ``lax.ppermute`` while
 a flash-style online softmax accumulates — n_ring steps of compute overlapped
 with neighbor DMA on the ICI ring, memory O(block²) instead of O(seq²).
+
+A ring of one is :func:`local_attention`, the attention of a block with
+itself: on a TPU, at a shape inside its contract, the fused Pallas kernel
+``xla.pallas_kernels.causal_attention`` (blockwise, forward and backward, no
+[b, h, t, t] tensor in HBM); everywhere else the plain einsum / softmax /
+einsum. A ring of n > 1 keeps its XLA online softmax per step.
 """
 
 from __future__ import annotations
 
+import threading
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import perfvars
+
 NEG_INF = -1e30
+
+
+def _kernel_backend() -> Optional[str]:
+    """How the fused attention kernel would run here: "mosaic" on a TPU,
+    None elsewhere. (The tests patch this to "interpret" for the Pallas
+    interpret machine, which is far too slow to be chosen.)"""
+    return "mosaic" if jax.default_backend() == "tpu" else None
+
+
+def warm_kernel_imports() -> None:
+    """Where the fused kernel can be selected, start importing Pallas on a
+    thread: the import costs 0.8 s (it pulls in the GPU and Mosaic dialects)
+    and would otherwise be paid inside the first trace of a step. A builder
+    of a step calls this; the trace then finds the modules there, or waits
+    on the import lock for what is left."""
+    if _kernel_backend() is not None:
+        from ..xla import pallas_kernels as pk
+        threading.Thread(target=pk.load, name="tpu_mpi-pallas-import",
+                         daemon=True).start()
+
+
+def fused_attention_selected(shape: tuple, dtype) -> bool:
+    """Whether :func:`local_attention` runs the fused kernel for (batch,
+    heads, t, head_dim) operands of ``dtype``: decided from the backend and
+    the kernel's contract (``pallas_kernels.causal_attention_blocks``,
+    ``ATTN_DTYPES``), never by trying it and catching the failure: once
+    selected, a kernel that does not lower is an error."""
+    from ..xla import pallas_kernels as pk
+    return (_kernel_backend() is not None
+            and str(jnp.dtype(dtype)) in pk.ATTN_DTYPES
+            and pk.causal_attention_blocks(shape[2], shape[3]) is not None)
+
+
+def local_attention(q: jnp.ndarray, k: jnp.ndarray,
+                    v: jnp.ndarray) -> jnp.ndarray:
+    """Causal attention of a (batch, heads, t, head_dim) block with itself,
+    scaled by head_dim ** -0.5. Each call built into a traced program counts
+    in ``perfvars.snapshot()["attn_lowerings"]`` as ``fused`` or ``plain``."""
+    t, dh = q.shape[2:]
+    if fused_attention_selected(q.shape, q.dtype):
+        from ..xla import pallas_kernels as pk
+        perfvars.note_attn_lowering("fused")
+        return pk.causal_attention(
+            q, k, v, interpret=_kernel_backend() == "interpret")
+    perfvars.note_attn_lowering("plain")
+    # stays in the input dtype: an f32 upcast here runs the attention
+    # matmuls on the slow MXU path and cost 13% of a full bf16 train step
+    # (benchmarks/flagship_probe)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q * dh ** -0.5, k)
+    mask = jnp.tril(jnp.ones((t, t), dtype=bool))
+    s = jnp.where(mask, s, NEG_INF)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
 
 
 def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -31,22 +92,10 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     b, h, t, d = q.shape
     n = lax.axis_size(axis)
     my = lax.axis_index(axis)
+    if n == 1 and causal and scale is None:
+        return local_attention(q, k, v)
     scale = (d ** -0.5) if scale is None else scale
     q = q * scale
-
-    if n == 1:
-        # ring of one = plain local attention: skip the online-softmax
-        # machinery so XLA fuses the whole block, and stay in the input
-        # dtype (an f32 upcast here runs the attention matmuls on the slow
-        # MXU path and cost 13% of a full bf16 train step, measured by
-        # benchmarks/flagship_probe)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
-        if causal:
-            qi = jnp.arange(t)[:, None]
-            ki = jnp.arange(t)[None, :]
-            s = jnp.where(qi >= ki, s, jnp.asarray(NEG_INF, s.dtype))
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", p, v).astype(q.dtype)
 
     acc = jnp.zeros_like(q, dtype=jnp.float32)
     m = jnp.full((b, h, t, 1), NEG_INF, dtype=jnp.float32)   # running max

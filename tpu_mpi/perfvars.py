@@ -14,8 +14,10 @@ pieces:
   ranks' chips, which moves its bytes itself (``ingraph_folds``), chunk-pipeline overlap inputs, RMA epoch counts, and
   per-collective latency histograms (log2-µs buckets,
   ``config.pvars_hist_bins`` wide). Plan-cache hits/misses ride along at
-  snapshot time from ``overlap.plans.stats()``, and the wall time spent
-  registering plans and compiling folds as ``arming_s``.
+  snapshot time from ``overlap.plans.stats()``, the wall time spent
+  registering plans and compiling folds as ``arming_s``, and the attention
+  calls built into traced programs as the fused kernel or the plain path
+  as ``attn_lowerings``.
   ``fold`` and ``copy`` on device operands are DISPATCH times: the host
   seconds it took to enqueue the fold (its operand copies included) and
   the copy-out, not the seconds the device worked. The device's end of
@@ -525,6 +527,22 @@ def arming_seconds() -> float:
     return total
 
 
+# -- which attention a traced program got ------------------------------------
+#
+# `parallel.ring.local_attention` chooses between the fused kernel and the
+# plain einsum path while a program is traced; each call counts here, so a
+# run can say which one its compiled steps hold.
+
+_attn_lowerings = {"fused": 0, "plain": 0}
+
+
+def note_attn_lowering(kind: str) -> None:
+    """One attention call was built into a traced program as the ``fused``
+    kernel or as the ``plain`` path."""
+    with _store_lock:
+        _attn_lowerings[kind] += 1
+
+
 # -- the device's end of a copy between chips --------------------------------
 #
 # A host span around an asynchronous copy times the enqueue. An op that
@@ -1025,6 +1043,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "topology": _topology_stamp(),
             "comms": comms, "plan_cache": plans.stats(),
             "arming_s": arming_seconds(),
+            "attn_lowerings": dict(_attn_lowerings),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
             "serve_frame": serve_frame_snapshot(),
@@ -1065,6 +1084,7 @@ def reset() -> None:
         _front_door_gauges.clear()
         _locks.clear()
         _arming.clear()
+        _attn_lowerings.update(fused=0, plain=0)
         _store_gen += 1
 
 

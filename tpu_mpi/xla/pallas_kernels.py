@@ -6,7 +6,10 @@ generation), this module supplies the *custom-kernel* tier the reference
 reaches by linking libmpi's hand-written algorithms (SURVEY.md §2.4): ring
 collectives and neighbor transfers written directly against the ICI with
 ``pltpu.make_async_remote_copy`` (remote DMA) + semaphores, and a fused
-ring-attention kernel as the long-context demo SURVEY.md §5 calls for.
+ring-attention kernel as the long-context demo SURVEY.md §5 calls for. Two
+kernels are local (no remote DMA): the fused multi-operand fold of the host
+path, and ``causal_attention``, the train step's attention with a backward
+pass of its own.
 
 All kernels run under ``jax.shard_map`` over a 1-d mesh axis. On a TPU
 backend they compile via Mosaic; on the CPU backend (or when the caller
@@ -59,6 +62,13 @@ def _pltpu():
     return pltpu
 
 
+def load() -> None:
+    """Import Pallas and its TPU dialect now (0.8 s, most of it dialects the
+    TPU never uses): for a caller that wants it off a later trace's path."""
+    _pl()
+    _pltpu()
+
+
 def _interpret(interpret: Optional[bool]):
     """The TPU interpret machine when the caller asks for it or the backend
     is the CPU; Mosaic compilation otherwise. Never a silent choice on an
@@ -70,14 +80,18 @@ def _interpret(interpret: Optional[bool]):
 
 
 def _compiler_params(collective_id: Optional[int], vmem_bytes: int = 0,
-                     what: str = "kernel"):
+                     what: str = "kernel",
+                     semantics: Optional[tuple] = None):
     """Mosaic accepts a collective_id ONLY when the kernel actually uses the
     barrier semaphore — at n=1 the ring loops never trace a barrier, so the
     id must be omitted or compilation fails (interpret mode accepts both).
     ``vmem_bytes`` is the kernel's whole-operand VMEM working set: above the
     16 MiB scoped default it is requested explicitly, above
-    :data:`VMEM_LIMIT_BYTES` the call is refused."""
+    :data:`VMEM_LIMIT_BYTES` the call is refused. ``semantics`` names each
+    grid axis "parallel" or "arbitrary" (a reduction: it runs in order)."""
     kw = {}
+    if semantics is not None:
+        kw["dimension_semantics"] = semantics
     if collective_id is not None:
         kw["collective_id"] = collective_id
     if vmem_bytes > VMEM_LIMIT_BYTES:
@@ -627,6 +641,11 @@ def ring_attention(q, k, v, *, axis: str = "x", causal: bool = False,
     (ppermute-based); the substrate demo SURVEY.md §5 requires. q/k/v:
     (T_local, d) with d ≤ 128-padded; vmap for batch/heads.
 
+    Not what a train step runs: this kernel exists for the rotation of K/V
+    over a ring of devices, holds its whole operands in VMEM and is forward
+    only (no VJP). The local attention of a block with itself, gridded over
+    HBM-sized operands and differentiable, is :func:`causal_attention`.
+
     Precision follows the input dtype: pass bfloat16 operands for the bf16
     MXU path (float32 softmax state and accumulation — standard TPU
     flash-attention numerics, ~4x f32 matmul throughput on v5e); float32
@@ -676,6 +695,351 @@ def ring_attention(q, k, v, *, axis: str = "x", causal: bool = False,
                                          "ring_attention"),
     )(q, k, v)
     return out[:, :d] if pad else out
+
+
+# ---------------------------------------------------------------------------
+# fused causal attention of a block with itself (the train step's local
+# attention: no [b, h, t, t] tensor reaches HBM, forward or backward)
+# ---------------------------------------------------------------------------
+
+# Operand types the kernel is selected for (parallel.ring.local_attention):
+# the ones Mosaic compiles for the v5e. One listed here that fails to lower
+# is an error, not a fallback.
+ATTN_DTYPES = frozenset({"float32", "bfloat16"})
+_ATTN_BLOCKS = (512, 256, 128)      # widest first; all multiples of LANE
+_MASKED = -1e30     # the plain path's value for a future key
+
+
+def causal_attention_blocks(t: int, dh: int) -> Optional[tuple]:
+    """(query block, key block) of the kernel for sequence length ``t`` and
+    head dimension ``dh``, or None where its contract does not hold: ``t`` a
+    multiple of a block, ``dh`` 64 or a multiple of 128 (a head is the MXU's
+    contraction and the minor dimension of every operand block), and the
+    backward pass's working set, which holds one head's whole dq, inside
+    :data:`VMEM_LIMIT_BYTES`."""
+    if dh != 64 and dh % LANE:
+        return None
+    block = next((b for b in _ATTN_BLOCKS if t % b == 0), None)
+    if block is None:
+        return None
+    if _attn_bwd_vmem(t, dh, block, block, 4) > VMEM_LIMIT_BYTES:
+        return None
+    return block, block
+
+
+def _attn_fwd_vmem(dh: int, bq: int, bk: int, itemsize: int) -> int:
+    """The forward kernel's VMEM: q, k, v, o blocks (double-buffered by the
+    grid pipeline), the log-sum-exp row, the float32 running max, sum and
+    accumulator, and a block of scores with its exponentials."""
+    dl = max(dh, LANE)
+    return (2 * (2 * bq + 2 * bk) * dl * itemsize + 2 * SUBLANE * bq * 4
+            + (3 * LANE + dl) * bq * 4 + 3 * bq * bk * 4)
+
+
+def _attn_bwd_vmem(t: int, dh: int, bq: int, bk: int, itemsize: int) -> int:
+    """The backward kernel's VMEM: q, do, k, v, dk, dv blocks and one head's
+    whole dq (double-buffered), the float32 dq, dk, dv accumulators, the
+    log-sum-exp and row-sum rows, and five blocks of scores (s, p, dp, ds
+    and the transposed ds)."""
+    dl = max(dh, LANE)
+    return (2 * (2 * bq + 4 * bk + t) * dl * itemsize
+            + (t + 2 * bk) * dl * 4 + 4 * SUBLANE * bq * 4 + 5 * bq * bk * 4)
+
+
+def _lanes(x, n: int):
+    """A (rows, LANE) lane-replicated column as (rows, n)."""
+    import jax.numpy as jnp
+    if n % LANE == 0:
+        return x if n == LANE else jnp.tile(x, (1, n // LANE))
+    return x[:, :n]
+
+
+def _attn_precision(dtype):
+    """bf16 operands take the MXU's bf16 path with float32 accumulation;
+    float32 operands keep full precision (Mosaic's default would run them
+    as bf16 passes)."""
+    import jax
+    import numpy as np
+    return (jax.lax.Precision.HIGHEST if np.dtype(dtype) == np.float32
+            else jax.lax.Precision.DEFAULT)
+
+
+def _causal_pair(qi, ki, bq: int, bk: int):
+    """(below, crosses) of the pair (query block qi, key block ki): wholly
+    below the diagonal (every key seen by every query, no mask needed), or
+    on it (masked by position). A pair that is neither lies wholly above
+    the diagonal and runs nothing."""
+    import jax.numpy as jnp
+    below = ki * bk + (bk - 1) <= qi * bq
+    crosses = jnp.logical_and(ki * bk <= qi * bq + (bq - 1),
+                              jnp.logical_not(below))
+    return below, crosses
+
+
+def _attn_fwd_kernel(scale: float, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                     m_ref, l_ref, acc_ref):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    pl = _pl()
+    bq, dh = q_ref.shape[2:]
+    bk = k_ref.shape[2]
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    prec = _attn_precision(q_ref.dtype)
+
+    @pl.when(ki == 0)
+    def _first_key_block():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def pair(on_diagonal: bool):
+        v = v_ref[0, 0]
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec) * scale
+        if on_diagonal:
+            rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            s = jnp.where(rows >= cols, s, np.float32(_MASKED))
+        # key block 0 runs first and holds key 0, which every query sees:
+        # from then on the running max is finite and a masked score's
+        # exponential is exactly 0
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_next, bk))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, dh) + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec)
+
+    below, crosses = _causal_pair(qi, ki, bq, bk)
+    pl.when(below)(lambda: pair(False))
+    pl.when(crosses)(lambda: pair(True))
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _last_key_block():
+        l = l_ref[...]
+        o_ref[0, 0] = (acc_ref[...] * _lanes(1.0 / l, dh)).astype(o_ref.dtype)
+        # the rows' log-sum-exp leaves as one row along the lanes, which is
+        # how the backward pass reads it: [b, h, 1, t], nothing replicated
+        lse_ref[0, 0] = jnp.transpose(m_ref[...] + jnp.log(l))[:1, :]
+
+
+def _attn_bwd_kernel(scale: float, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                     di_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+    """One (key block, query block) pair of the backward pass, transposed:
+    scores are [keys, queries], so a query's log-sum-exp and row-sum are
+    rows along the lanes and four of the five products need no transpose.
+    dk and dv accumulate over the query blocks (the inner grid axis); dq of
+    the whole head stays in VMEM over both axes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    pl = _pl()
+    bq, dh = q_ref.shape[2:]
+    bk = k_ref.shape[2]
+    kj, qi = pl.program_id(2), pl.program_id(3)
+    prec = _attn_precision(q_ref.dtype)
+    nt = (((1,), (1,)), ((), ()))
+    nn = (((1,), (0,)), ((), ()))
+
+    @pl.when(jnp.logical_and(kj == 0, qi == 0))
+    def _first_pair():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    @pl.when(qi == 0)
+    def _first_query_block():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    def pair(on_diagonal: bool):
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        s = jax.lax.dot_general(k, q, nt, preferred_element_type=jnp.float32,
+                                precision=prec) * scale         # (bk, bq)
+        if on_diagonal:
+            keys = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+            rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            s = jnp.where(rows >= keys, s, np.float32(_MASKED))
+        p = jnp.exp(s - lse_ref[0, 0])
+        dv_acc[...] += jax.lax.dot_general(
+            p.astype(do.dtype), do, nn, preferred_element_type=jnp.float32,
+            precision=prec)
+        dp = jax.lax.dot_general(v, do, nt, preferred_element_type=jnp.float32,
+                                 precision=prec)
+        ds = (p * (dp - di_ref[0, 0]) * scale).astype(q.dtype)
+        dk_acc[...] += jax.lax.dot_general(
+            ds, q, nn, preferred_element_type=jnp.float32, precision=prec)
+        at = pl.ds(pl.multiple_of(qi * bq, bq), bq)
+        dq_acc[at, :] += jax.lax.dot_general(
+            ds, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec)
+
+    below, crosses = _causal_pair(qi, kj, bq, bk)
+    pl.when(below)(lambda: pair(False))
+    pl.when(crosses)(lambda: pair(True))
+
+    last_q = qi == pl.num_programs(3) - 1
+
+    @pl.when(last_q)
+    def _last_query_block():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(last_q, kj == pl.num_programs(2) - 1))
+    def _last_pair():
+        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _varying_like(x, shape, dtype):
+    """An output's shape that varies over the mesh axes ``x`` varies over
+    (what `shard_map` asks of a kernel's outputs under `check_vma`)."""
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(x).vma)
+
+
+def _attn_forward(q, k, v, bq: int, bk: int, interpret: Optional[bool]):
+    """(o, log-sum-exp [b, h, 1, t] float32) of causal attention."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    pl, pltpu = _pl(), _pltpu()
+    b, h, t, dh = q.shape
+
+    # index arithmetic stays int32 under jax_enable_x64 too (Mosaic has no
+    # 64-bit scalars): `lax.div` and an int32 zero, not `//` and a literal
+    zero = np.int32(0)
+
+    def key_block(bi, hi, qi, ki):
+        # a skipped pair asks for the block already there: no copy
+        last = jax.lax.div(qi * bq + (bq - 1), np.int32(bk))
+        return bi, hi, jnp.minimum(ki, last), zero
+
+    q_spec = pl.BlockSpec((1, 1, bq, dh),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, zero))
+    kv_spec = pl.BlockSpec((1, 1, bk, dh), key_block)
+    return pl.pallas_call(
+        functools.partial(_attn_fwd_kernel, dh ** -0.5),
+        grid=(b, h, t // bq, t // bk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, pl.BlockSpec(
+            (1, 1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, zero, qi))],
+        out_shape=[_varying_like(q, q.shape, q.dtype),
+                   _varying_like(q, (b, h, 1, t), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, LANE), jnp.float32),    # running max
+                        pltpu.VMEM((bq, LANE), jnp.float32),    # running sum
+                        pltpu.VMEM((bq, dh), jnp.float32)],
+        interpret=_interpret(interpret),
+        compiler_params=_compiler_params(
+            None, _attn_fwd_vmem(dh, bq, bk, q.dtype.itemsize),
+            "causal_attention",
+            ("parallel", "parallel", "parallel", "arbitrary")),
+        name="causal_attention_fwd",
+    )(q, k, v)
+
+
+def _attn_backward(q, k, v, o, lse, do, bq: int, bk: int,
+                   interpret: Optional[bool]):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    pl, pltpu = _pl(), _pltpu()
+    b, h, t, dh = q.shape
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+
+    zero = np.int32(0)          # int32 index arithmetic, as in the forward
+
+    def first(kj, qi):
+        # a skipped pair asks for the key block's first query block
+        return jnp.maximum(qi, jax.lax.div(kj * bk, np.int32(bq)))
+
+    q_spec = pl.BlockSpec((1, 1, bq, dh),
+                          lambda bi, hi, kj, qi: (bi, hi, first(kj, qi), zero))
+    kv_spec = pl.BlockSpec((1, 1, bk, dh),
+                           lambda bi, hi, kj, qi: (bi, hi, kj, zero))
+    row_spec = pl.BlockSpec((1, 1, 1, bq),
+                            lambda bi, hi, kj, qi: (bi, hi, zero, first(kj, qi)))
+    head_spec = pl.BlockSpec((1, 1, t, dh),
+                             lambda bi, hi, kj, qi: (bi, hi, zero, zero))
+    return pl.pallas_call(
+        functools.partial(_attn_bwd_kernel, dh ** -0.5),
+        grid=(b, h, t // bk, t // bq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[head_spec, kv_spec, kv_spec],
+        out_shape=[_varying_like(q, q.shape, q.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((t, dh), jnp.float32),
+                        pltpu.VMEM((bk, dh), jnp.float32),
+                        pltpu.VMEM((bk, dh), jnp.float32)],
+        interpret=_interpret(interpret),
+        compiler_params=_compiler_params(
+            None, _attn_bwd_vmem(t, dh, bq, bk, q.dtype.itemsize),
+            "causal_attention",
+            ("parallel", "parallel", "arbitrary", "arbitrary")),
+        name="causal_attention_bwd",
+    )(q, k, v, do, lse, di[:, :, None, :])
+
+
+@functools.lru_cache(maxsize=None)
+def _causal_attention_fn(bq: int, bk: int, interpret: Optional[bool]):
+    """The differentiable kernel at one block size, jitted once: every layer
+    of a step that calls it shares one lowering."""
+    import jax
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return _attn_forward(q, k, v, bq, bk, interpret)[0]
+
+    def fwd(q, k, v):
+        o, lse = _attn_forward(q, k, v, bq, bk, interpret)
+        return o, (q, k, v, o, lse)
+
+    def bwd(kept, do):
+        return _attn_backward(*kept, do, bq, bk, interpret)
+
+    attend.defvjp(fwd, bwd)
+    return jax.jit(attend)
+
+
+def causal_attention(q, k, v, *, block_q: Optional[int] = None,
+                     block_k: Optional[int] = None,
+                     interpret: Optional[bool] = None):
+    """softmax(q k^T / sqrt(dh), keys <= query) v of (batch, heads, t, dh)
+    operands, blockwise: the grid walks (batch, head, query block, key block)
+    with a float32 running max and sum (online softmax), bf16 operands enter
+    the MXU with float32 accumulation (float32 operands at full precision),
+    scores stay float32 through the softmax and the probabilities are
+    rounded to the operand dtype only as a product's operand. Key blocks
+    wholly above the diagonal run nothing, the ones on it mask by position.
+
+    Differentiable: the forward pass keeps o and the rows' log-sum-exp
+    ([b, h, 1, t] float32: a row along the lanes, as the backward reads it);
+    the backward pass is one kernel over (key block, query block) pairs that
+    recomputes a pair's probabilities from q, k and the log-sum-exp,
+    accumulates dk and dv over the query blocks and one head's dq in VMEM.
+    No [b, h, t, t] tensor is written to HBM in either direction.
+
+    Blocks default to :func:`causal_attention_blocks`; a shape outside the
+    kernel's contract raises. :func:`ring_attention` above is the other
+    attention kernel here: it rotates K/V over a ring of devices, holds whole
+    operands in VMEM and has no backward pass; this one is local and is what
+    a train step runs."""
+    t, dh = q.shape[2:]
+    if block_q is None or block_k is None:
+        blocks = causal_attention_blocks(t, dh)
+        if blocks is None:
+            raise ValueError(
+                f"causal_attention: (t, dh) = ({t}, {dh}) is outside the "
+                f"kernel's contract (t a multiple of {_ATTN_BLOCKS[-1]}, dh "
+                f"64 or a multiple of {LANE}, one head's dq in VMEM)")
+        block_q, block_k = block_q or blocks[0], block_k or blocks[1]
+    if t % block_q or t % block_k:
+        raise ValueError(f"causal_attention: blocks ({block_q}, {block_k}) "
+                         f"do not divide t = {t}")
+    if not (q.shape == k.shape == v.shape and q.dtype == k.dtype == v.dtype):
+        raise ValueError("causal_attention: q, k, v differ in shape or dtype")
+    return _causal_attention_fn(block_q, block_k, interpret)(q, k, v)
 
 
 # ---------------------------------------------------------------------------
