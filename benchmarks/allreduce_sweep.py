@@ -12,8 +12,8 @@ hardware allows:
   ``pvars_phase`` block (rendezvous/fold/copy seconds + rendezvous share)
   at the largest swept size.
 - ``ingraph`` — K-chained
-  in-jit Allreduce folds (+ the fused-kernel ``allreduce_fused`` variant
-  and reducescatter/allgather, all on the same size ladder), adaptive-slope
+  in-jit Allreduce folds (+ the donated-accumulator variant and
+  reducescatter/allgather, all on the same size ladder), adaptive-slope
   timed so the per-call dispatch floor cancels; the lane that answers the north-star
   question of what the collectives cost where they actually run (inside
   compiled XLA code). The record also carries a ``ceiling_control`` block —
@@ -58,7 +58,7 @@ REPEATS = 3
 
 def bench_host(nranks: int, sizes: list[int], use_device: bool,
                persistent: bool = False) -> list[dict]:
-    # chained honest-execution protocol shared with bench.py — see
+    # chained honest-execution protocol — see
     # common.host_allreduce_times (VERDICT r2 weak #1). persistent=True is
     # the registered-buffer lane (ISSUE-6): one Allreduce_init outside the
     # timed loop, Start/Wait per op against the plan-pinned buffers.
@@ -189,7 +189,7 @@ def _bench_in_graph(sizes: list[int], fn_of_mesh, max_iters: int = 10 ** 9,
     n = len(devs)
     rows = []
     for nbytes in sizes:
-        # MPI Allreduce semantics (same as bench.py's in-graph path): every
+        # MPI Allreduce semantics: every
         # rank contributes nbytes, so the sharded global operand is n*nbytes
         per_elems = max(1, nbytes // 4)
         cnt = per_elems * n
@@ -250,8 +250,6 @@ def bench_ingraph(nranks: int, sizes: list[int],
                    "hbm_model_binds": r["hbm_model_binds"],
                    "traffic_model": r["traffic_model"],
                    "k": r["k"], "slope_spread": r["slope_spread"]}
-            if "fused" in r:
-                row["fused"] = r["fused"]
             rows.append(row)
             print(f"ingraph:{variant} {r['bytes']:>11d} B  "
                   f"{r['per_fold_us']:>10.1f} us/fold  "
@@ -471,8 +469,7 @@ def main() -> None:
         # stop at three spot sizes).
         sub = sizes[::2] + ([sizes[-1]] if (len(sizes) - 1) % 2 else [])
         ig = bench_ingraph(args.ranks, sub,
-                           variants=("allreduce", "allreduce_fused",
-                                     "allreduce_donated",
+                           variants=("allreduce", "allreduce_donated",
                                      "reducescatter", "allgather"))
         record["lanes"]["ingraph"] = ig.pop("allreduce", [])
         for variant, rows in ig.items():

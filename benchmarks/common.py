@@ -35,8 +35,8 @@ def iters_for(nbytes: int) -> tuple[int, int]:
 def host_allreduce_times(n_elems: int, nranks: int, use_device: bool,
                          warmup: int, iters: int, repeats: int,
                          persistent: bool = False) -> list[list[float]]:
-    """Honest-execution host-path Allreduce timing, shared by ``bench.py``
-    and ``allreduce_sweep.py`` (VERDICT r2 weak #1: the round-2 protocol
+    """Honest-execution host-path Allreduce timing of
+    ``allreduce_sweep.py`` (VERDICT r2 weak #1: the round-2 protocol
     measured async dispatch and reported >HBM-peak bandwidth).
 
     Iterations chain data-dependently — rank 0 feeds the combined result
@@ -136,8 +136,8 @@ def time_chain(step, force, warmup: int, iters: int, repeats: int) -> float:
     """Best per-op seconds over ``repeats`` blocks of ``iters`` chained ops;
     each block ends in a forcing readback that ``force(ops)`` must assert
     against the closed-form chain value (unexecuted or wrong work fails the
-    bench instead of timing as fast). Shared by
-    bench.py's control rows and benchmarks/overhead_probe.py."""
+    bench instead of timing as fast). Used by
+    benchmarks/overhead_probe.py."""
     ops = 0
     for _ in range(warmup):
         step()
@@ -155,8 +155,8 @@ def time_chain(step, force, warmup: int, iters: int, repeats: int) -> float:
 
 
 def gen_of(device) -> str:
-    """TPU generation key for a jax device (canonical copy — bench.py and
-    mfu_probe.py delegate here so a new generation is added once). A device
+    """TPU generation key for a jax device (canonical copy —
+    mfu_probe.py delegates here so a new generation is added once). A device
     the capability table does not know is an error: a ratio against some
     other chip's peak is not a measurement."""
     from tpu_mpi.implementations import CAPABILITIES
@@ -245,30 +245,11 @@ def adaptive_slope(time_of: Callable[[int], float], rtt: float,
             "slopes_us": [round(s * 1e6, 2) for s in slopes]}
 
 
-def _fused_fold_impl():
-    """``pallas_kernels.fused_multi_reduce`` as a fold combine, when it can
-    run here: on a real TPU (Mosaic), or anywhere when the ``fused_fold``
-    config knob is "interp" (test-only — the interpreter is slow). Returns
-    None when the chained XLA fold should be used instead, which is the
-    fallback path the CPU-sim CI smoke exercises."""
-    import jax
-    from tpu_mpi import config
-    mode = config.load().fused_fold
-    if mode == "off":
-        return None
-    if mode != "interp" and jax.default_backend() != "tpu":
-        return None
-    from tpu_mpi.xla import pallas_kernels as pk
-    return lambda streams: pk.fused_multi_reduce(streams, "sum")
-
-
 # Human-readable HBM traffic model per in-graph variant, stated beside
 # hbm_model_binds in every row (ISSUE-1 satellite): what one fold reads and
 # writes, hence what "implied HBM" divides by.
 _TRAFFIC_MODELS = {
     "allreduce": "(n+1)*bytes: n operand-stream reads + 1 result write",
-    "allreduce_fused": "(n+1)*bytes: n streams read once in a single fused "
-                       "pass + 1 result write",
     "allreduce_donated": "(n+1)*bytes: n operand-stream reads + 1 result "
                          "write aliased into the donated accumulator",
     "reducescatter": "(n+1)/n*bytes: n shard-slice reads + 1 shard write",
@@ -291,12 +272,6 @@ def ingraph_collective_slope(variant: str, n_elems: int, nranks: int,
     - ``allreduce``       — the same rank-ordered left fold the host path's
       ``collective._jitted_fold`` compiles (nranks operand reads + 1 result
       write of the payload; roofline algbw = HBM/(nranks+1));
-    - ``allreduce_fused`` — identical fold semantics, combined by the
-      single-pass Pallas ``fused_multi_reduce`` kernel on TPU (the ISSUE-1
-      tentpole); off-TPU the kernel is not selected, the lane runs the
-      chained fold and the row records ``fused: false`` (the path the
-      CPU-sim CI smoke checks). A selected kernel that fails to compile
-      fails the lane;
     - ``allreduce_donated`` — the registered host lane's fold compilation
       (ISSUE-6): ONE AOT executable with ``donate_argnums`` on the
       accumulator, called K times from the host with each result chained
@@ -326,25 +301,15 @@ def ingraph_collective_slope(variant: str, n_elems: int, nranks: int,
     opfn = MPI.SUM.fn
     shard = max(1, n_elems // nranks)
     nbytes = n_elems * 4
-    fused_used = False
-    if variant in ("allreduce", "allreduce_fused", "allreduce_donated"):
+    if variant in ("allreduce", "allreduce_donated"):
         peer_elems, acc_elems = n_elems, n_elems
         traffic = (nranks + 1) * nbytes
 
-        def chained_fold(acc, peers, jf):
+        def one_fold(acc, peers, jf):
             a = acc
             for o in peers:
                 a = opfn(a, o + jf)       # +j%2: iteration-dep., no LICM
             return a
-
-        one_fold = chained_fold
-        if variant == "allreduce_fused":
-            fused = _fused_fold_impl()
-            if fused is not None:
-                def one_fold(acc, peers, jf):
-                    # same rank-ordered left fold, single kernel pass
-                    return fused((acc,) + tuple(o + jf for o in peers))
-                fused_used = True
 
         def expect_of(k):                 # closed-form value after k folds
             return float(1 + (nranks - 1) * (k + k // 2))
@@ -436,8 +401,7 @@ def ingraph_collective_slope(variant: str, n_elems: int, nranks: int,
     # keep the closed-form chain value float32-EXACT at the largest k the
     # slope can evaluate (2*k_cap): 1 + (nranks-1)*(2k + k) must stay under
     # 2^24, or the readback assert fires spuriously at high rank counts
-    if variant in ("allreduce", "allreduce_fused", "allreduce_donated",
-                   "reducescatter"):
+    if variant in ("allreduce", "allreduce_donated", "reducescatter"):
         k_cap = min(k_cap, ((1 << 24) - 2) // (3 * max(1, nranks - 1)))
     sl = adaptive_slope(time_of, rtt, k_cap=k_cap)
     per_fold = sl["per_step_s"]
@@ -465,8 +429,6 @@ def ingraph_collective_slope(variant: str, n_elems: int, nranks: int,
         "hbm_model_binds": bool(implied <= 1.05 * hbm_spec),
         "algbw_gbps": round(nbytes / per_fold / 1e9, 3),
     }
-    if variant == "allreduce_fused":
-        out["fused"] = fused_used
     if variant == "allreduce_donated":
         out["donated"] = True
     return out

@@ -26,8 +26,8 @@ fold execution and MPI machinery:
                              roofline for the Allreduce fold, replacing the
                              spec-sheet 819 GB/s in the breakdown model.
   G. ``mpi_allreduce``     — the full MPI.Allreduce device lane, 4 rank
-                             threads (exactly bench.py's headline protocol,
-                             shared impl in benchmarks/common.py).
+                             threads (the chained protocol of
+                             benchmarks/common.py).
 
 Every chain is data-dependent (op k+1 consumes op k's output) and every timed
 block ends with a one-element readback asserted against the closed-form chain

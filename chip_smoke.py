@@ -6,8 +6,8 @@ script starts no child that needs it):
 
 1. host   — ``tpu_mpi.launcher.main(["-n", "4", <script>])`` (the ``tpurun``
    entry, rank threads): ``DeviceBuffer`` Float32[2^26] on ``comm.device``
-   through Allreduce (eager first call, the compiled ``_jitted_fold`` with
-   the fused Pallas kernel, the auto-armed registered lane), a hand-armed
+   through Allreduce (eager first call, the compiled ``_jitted_fold``, the
+   auto-armed registered lane: one left chain, compiled by XLA), a hand-armed
    ``Allreduce_init``/``Start``/``Wait`` (donated fold), Bcast,
    Reduce_scatter, Alltoall, a Sendrecv ring, one Win Put/Get epoch, Barrier;
 2. ingraph — ``xla.allreduce/allgather/reduce_scatter/alltoall/sendrecv``
@@ -67,7 +67,6 @@ FULL = {
                   d_ff=4096, max_seq=1024, batch=8, steps=4, lr=0.01),
     "ring": 250_000,              # per-device elements of the ring kernels
     "attn": (2048, 128),          # per-device (seq, head_dim), bf16 causal
-    "fused": 1 << 26,             # 4 x [2^26] fused fold, f32 and bf16
     "max_new": 8,
 }
 # Toy sizes for the tier-1 CPU test only.
@@ -78,7 +77,6 @@ TINY = {
                   max_seq=32, batch=4, steps=3, lr=0.02),
     "ring": 1000,                 # the interpreter stalls on larger rings
     "attn": (32, 64),
-    "fused": 1 << 12,
     "max_new": 4,
 }
 
@@ -187,12 +185,11 @@ def _host_rank(outdir: str, n: int, win_n: int) -> None:
             key = (MPI.SUM.fn, "reduce", size, "float32", ((n,),) * size)
             fold = collective._fold_compiled.get(key)
             assert fold is not None and fold is not collective._NOT_JITTABLE
-            if dev.platform == "tpu":
-                text = fold.lower(*[jax.ShapeDtypeStruct(
-                    (n,), jnp.float32)] * size).as_text()
-                assert "tpu_custom_call" in text, \
-                    "the compiled fold is not the Mosaic fused kernel"
-            facts["fold"] = "fused" if dev.platform == "tpu" else "traced"
+            text = fold.lower(*[jax.ShapeDtypeStruct(
+                (n,), jnp.float32)] * size).as_text()
+            assert "custom_call" not in text, \
+                "the compiled fold is not the plain left chain"
+            facts["fold"] = "chain"
 
         # -- hand-armed persistent Allreduce: the donated fold --------------
         t0 = time.perf_counter()
@@ -516,20 +513,6 @@ def leg_kernels(sz: dict, platform: str) -> dict:
     # of O(1) outputs is 4e-3, the probabilities' rounding adds the rest
     assert err < 2e-2, f"ring_attention max abs err {err}"
     facts["attention_max_abs_err"] = err
-
-    # -- the fused multi-operand fold: 4 streams, f32 and bf16 -----------------
-    nf = sz["fused"]
-    for dtype in (jnp.float32, jnp.bfloat16):
-        tag = jnp.dtype(dtype).name
-        xs = [((jnp.arange(nf, dtype=jnp.int32) % 7) + s).astype(dtype)
-              for s in range(NRANKS)]
-        got = timed(f"fused_multi_reduce[{tag}]", jax.jit(
-            lambda *a: pk.fused_multi_reduce(a, MPI.SUM,
-                                             interpret=interpret)), *xs)
-        want = jax.jit(lambda *a: ((a[0] + a[1]) + a[2]) + a[3])(*xs)
-        assert bool(jnp.array_equal(got, want)), f"fused fold [{tag}]"
-        assert float(got[nf - 1]) == float(4 * ((nf - 1) % 7) + 6)
-        del xs, got, want
     return facts
 
 
@@ -588,9 +571,7 @@ def main(argv: list) -> int:
     args = ap.parse_args(argv)
 
     want = "cpu" if args.tiny_cpu else "tpu"
-    if args.tiny_cpu:
-        os.environ["TPU_MPI_FUSED_FOLD"] = "interp"
-    else:
+    if not args.tiny_cpu:
         os.environ["TPU_MPI_BACKEND"] = "tpu"
     from tpu_mpi import _native
     from tpu_mpi._runtime import enable_compile_cache

@@ -92,3 +92,19 @@ def AT(request):
 @pytest.fixture
 def nprocs():
     return int(os.environ.get("TPU_MPI_TEST_NPROCS", tpu_mpi.testing.DEFAULT_NPROCS))
+
+
+@pytest.fixture
+def no_folds_cached():
+    """The legacy lane's fold caches empty before and after: a signature's
+    first encounter folds eagerly and its second compiles, and what a test
+    compiles there is its own."""
+    from tpu_mpi import collective
+
+    def clear():
+        with collective._fold_lock:
+            collective._fold_compiled.clear()
+            collective._fold_seen.clear()
+    clear()
+    yield
+    clear()

@@ -24,27 +24,12 @@ sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402
 
 
-@pytest.fixture
-def fused_interp(monkeypatch):
-    """The fused fold through the interpreter, as ``--tiny-cpu`` sets it."""
-    monkeypatch.setenv("TPU_MPI_FUSED_FOLD", "interp")
-    config.load(refresh=True)
-    with collective._fold_lock:
-        collective._fold_compiled.clear()
-        collective._fold_seen.clear()
-    yield
-    monkeypatch.undo()
-    config.load(refresh=True)
-    with collective._fold_lock:
-        collective._fold_compiled.clear()
-        collective._fold_seen.clear()
-
-
-def test_leg_host(fused_interp):
+def test_leg_host(no_folds_cached):
     facts = chip_smoke.leg_host(chip_smoke.TINY, "cpu")
     # rank i's operands and results lived on device i
     assert facts["rank_devices"] == [0, 1, 2, 3]
-    assert facts["fold"] == "traced"
+    # the legacy lane's compiled fold is XLA's own: no custom call
+    assert facts["fold"] == "chain"
 
 
 def test_leg_ingraph():
@@ -58,6 +43,11 @@ def test_leg_kernels():
     assert facts["interpret"] is True and facts["n"] == 8
     assert facts["oversize"] == "ValueError"
     assert "ring_allreduce[bfloat16]" in facts["seconds"]
+    # the five ring kernels in two element types and the ring attention:
+    # every kernel the leg compiles is one the program can still select
+    assert {k.split("[")[0] for k in facts["seconds"]} == {
+        "ring_allgather", "ring_allreduce", "ring_reduce_scatter",
+        "pairwise_alltoall", "collective_permute", "ring_attention"}
 
 
 def test_leg_serve():
@@ -92,10 +82,11 @@ def test_backend_tpu_is_enforced(monkeypatch):
         config.load(refresh=True)
 
 
-def test_fold_compile_failure_propagates(monkeypatch, fused_interp):
+def test_fold_compile_failure_propagates(monkeypatch, no_folds_cached):
     """A user operator that cannot be traced is the one documented reason a
-    device fold is declined; a candidate that traces and then fails is the
-    device's failure and must surface."""
+    device fold is declined; a chain that traces and then fails to compile
+    is the device's failure and must surface."""
+    import jax
     import jax.numpy as jnp
 
     arrs = [jnp.arange(8, dtype=jnp.float32) + r for r in range(3)]
@@ -104,12 +95,16 @@ def test_fold_compile_failure_propagates(monkeypatch, fused_interp):
         out = collective._reduce_arrays(arrs, host_only)
     np.testing.assert_array_equal(np.asarray(out), 3 * np.arange(8.0) + 3)
 
-    def broken(*a, **k):
-        raise RuntimeError("Mosaic failed to compile TPU kernel")
-    from tpu_mpi.xla import pallas_kernels as pk
-    monkeypatch.setattr(pk, "fused_multi_reduce", broken)
+    def broken(fn, **k):
+        assert fn.__name__ == "plain_fold", fn
+
+        def compiled(*xs):
+            raise RuntimeError("the compiler refused the fold")
+        return compiled
     collective._reduce_arrays(arrs, MPI.SUM)        # first encounter: eager
-    with pytest.raises(RuntimeError, match="Mosaic failed"):
+    # the chain has traced (``_traceable``) by the time it is compiled
+    monkeypatch.setattr(jax, "jit", broken)
+    with pytest.raises(RuntimeError, match="refused the fold"):
         collective._reduce_arrays(arrs, MPI.SUM)
 
 

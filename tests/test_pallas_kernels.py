@@ -163,93 +163,3 @@ def test_ring_attention_causal():
     p /= p.sum(axis=1, keepdims=True)
     expect = p @ v
     np.testing.assert_allclose(np.asarray(out), expect, rtol=2e-4, atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# fused multi-operand reduction (the host-path fold kernel; local, no mesh)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("op,npop", [
-    ("sum", np.add.reduce), ("max", np.maximum.reduce),
-    ("prod", np.multiply.reduce), ("min", np.minimum.reduce)])
-def test_fused_multi_reduce_matches_chained(op, npop):
-    rng = np.random.RandomState(7)
-    arrs = [rng.randn(96).astype(np.float32) for _ in range(5)]
-    out = pk.fused_multi_reduce([jnp.asarray(a) for a in arrs], op,
-                                interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), npop(np.stack(arrs)))
-
-
-def test_fused_multi_reduce_multiblock_grid():
-    # rows > block_rows exercises the pipelined grid path AND the pad-to-
-    # block-multiple branch (40 rows @ block 16 -> padded 48, grid 3); the
-    # pad region must be sliced away, so the result stays exact.
-    rng = np.random.RandomState(8)
-    n_elems = 40 * 128 - 37                       # non-tile-aligned too
-    arrs = [rng.randn(n_elems).astype(np.float32) for _ in range(4)]
-    out = pk.fused_multi_reduce([jnp.asarray(a) for a in arrs], "sum",
-                                interpret=True, block_rows=16)
-    np.testing.assert_array_equal(np.asarray(out), np.add.reduce(np.stack(arrs)))
-
-
-def test_fused_multi_reduce_bf16_and_2d():
-    arrs = [(np.arange(24, dtype=np.float32) + i).reshape(4, 6)
-            for i in range(3)]
-    jarrs = [jnp.asarray(a, dtype=jnp.bfloat16) for a in arrs]
-    out = pk.fused_multi_reduce(jarrs, "max", interpret=True)
-    assert out.dtype == jnp.bfloat16 and out.shape == (4, 6)
-    np.testing.assert_array_equal(np.asarray(out, dtype=np.float32),
-                                  np.maximum.reduce(np.stack(arrs)))
-
-
-def test_fused_multi_reduce_op_objects_and_single():
-    import tpu_mpi as MPI
-    arrs = [jnp.arange(32, dtype=jnp.float32) + i for i in range(3)]
-    out = pk.fused_multi_reduce(arrs, MPI.SUM, interpret=True)
-    np.testing.assert_array_equal(
-        np.asarray(out), np.add.reduce(np.stack([np.asarray(a) for a in arrs])))
-    assert pk.fused_multi_reduce([arrs[0]], "sum") is arrs[0]
-
-
-def test_allreduce_host_path_takes_fused_fold(monkeypatch):
-    """End-to-end: MPI.Allreduce over device operands routes through the
-    fused kernel when TPU_MPI_FUSED_FOLD=interp, bit-identical to the
-    chained fold, and the kernel actually traces (spy counter)."""
-    import tpu_mpi as MPI
-    from tpu_mpi import collective, config
-
-    monkeypatch.setenv("TPU_MPI_FUSED_FOLD", "interp")
-    config.load(refresh=True)
-    with collective._fold_lock:
-        collective._fold_compiled.clear()
-        collective._fold_seen.clear()
-    calls = {"n": 0}
-    orig = pk.fused_multi_reduce
-
-    def spy(*a, **k):
-        calls["n"] += 1
-        return orig(*a, **k)
-    monkeypatch.setattr(pk, "fused_multi_reduce", spy)
-
-    def body():
-        comm = MPI.COMM_WORLD
-        r = MPI.Comm_rank(comm)
-        x = jnp.arange(64, dtype=jnp.float32) + r
-        out1 = MPI.Allreduce(x, MPI.SUM, comm)     # first encounter: eager
-        out2 = MPI.Allreduce(x, MPI.SUM, comm)     # second: compiled fused
-        want = np.add.reduce(np.stack(
-            [np.arange(64, dtype=np.float32) + k
-             for k in range(MPI.Comm_size(comm))]))
-        np.testing.assert_array_equal(np.asarray(out1), want)
-        np.testing.assert_array_equal(np.asarray(out2), want)
-        return True
-
-    try:
-        assert MPI.spmd_run(body, 2) == [True, True]
-        assert calls["n"] >= 1, "fused kernel never traced"
-    finally:
-        monkeypatch.undo()
-        config.load(refresh=True)
-        with collective._fold_lock:
-            collective._fold_compiled.clear()
-            collective._fold_seen.clear()

@@ -45,8 +45,7 @@ from . import perfvars as _pv
 from . import tune_online as _tune_online
 from .analyze import events as _ev
 from .error import CollectiveMismatchError, MPIError
-from .operators import (BAND, BOR, BXOR, MAX, MIN, PROD, SUM, Op,
-                        as_op)
+from .operators import Op, as_op
 from .overlap import (ChunkSchedule, CollectivePlan, PersistentCollRequest,
                       PlanRegistration, demote_fast_armed as _demote_fast_armed,
                       plans as _plans, progress_begin, progress_note,
@@ -207,11 +206,6 @@ def _wire_nbytes(payload: Any) -> Optional[int]:
 
 _NOT_JITTABLE = object()
 
-# Operators the fused Pallas fold is selected for: the predefined
-# elementwise arithmetic ops, whose combine Mosaic is known to lower. Any
-# other (user) operator takes the chained XLA fold.
-_FUSED_OPS = (SUM, PROD, MIN, MAX, BAND, BOR, BXOR)
-
 # Compiled-fold caches, keyed by the *underlying fn* so that as_op() wrapping
 # the same user function in a fresh Op each call still hits. Bounded LRU:
 # compiled executables are retained for at most _FOLD_CAP distinct
@@ -310,6 +304,17 @@ def _concat(parts: Sequence[Any], home: Any = None) -> Any:
     return jnp.concatenate([on_sharding(p, sh).reshape(-1) for p in parts])
 
 
+def _left_chain(op: Op):
+    """``op`` over any number of operands as the rank-ordered left chain:
+    what every device fold of the host path computes."""
+    def plain_fold(*xs):
+        acc = xs[0]
+        for x in xs[1:]:
+            acc = op.fn(acc, x)
+        return acc
+    return plain_fold
+
+
 def _jitted_fold(arrs: Sequence[Any], op: Op, mode: str):
     """One-dispatch combine for co-located device arrays: the whole
     rank-ordered fold is compiled into a single XLA computation (fused: one
@@ -346,11 +351,7 @@ def _jitted_fold(arrs: Sequence[Any], op: Op, mode: str):
     import jax
 
     if mode == "reduce":
-        def fold(*xs):
-            acc = xs[0]
-            for x in xs[1:]:
-                acc = op.fn(acc, x)
-            return acc
+        fold = _left_chain(op)
     else:  # scan: all inclusive prefixes
         def fold(*xs):
             outs = [xs[0]]
@@ -359,46 +360,15 @@ def _jitted_fold(arrs: Sequence[Any], op: Op, mode: str):
             return tuple(outs)
     jitted = out = _NOT_JITTABLE
     if _traceable(fold, *arrs):
-        # the Pallas single-pass kernel (same left fold, explicit HBM
-        # schedule) where the gate selects it, else the chained XLA fold
-        fused = _fused_reduce_candidate(op, arrs) if mode == "reduce" else None
-        jitted = jax.jit(fused or fold)
+        jitted = jax.jit(fold)
         with _pv.setup_span("jitted_fold.compile",
-                            function=(fused or fold).__name__, mode=mode):
+                            function=fold.__name__, mode=mode):
             out = jitted(*arrs)
     with _fold_lock:
         _fold_compiled[key] = jitted
         while len(_fold_compiled) > _FOLD_CAP:
             _fold_compiled.popitem(last=False)
     return out
-
-
-def _fused_reduce_candidate(op: Op, arrs: Sequence[Any]):
-    """The Pallas fused multi-operand fold for mode="reduce" (the ISSUE-1
-    tentpole): one traversal reads all nranks HBM streams and writes one
-    output, replacing the chained elementwise fold. Selected from what can
-    be observed — the ``fused_fold`` gate, the backend, and the kernel's
-    contract (same-shape streams, a predefined elementwise operator, a
-    dtype Mosaic compiles: ``pallas_kernels.FUSED_DTYPES``) — never by
-    trying it and catching the failure: once selected, a kernel that does
-    not lower is an error."""
-    from . import config
-    mode = config.load().fused_fold
-    if mode == "off":
-        return None
-    if len({(a.shape, str(a.dtype)) for a in arrs}) != 1:
-        return None                 # kernel folds same-shape streams only
-    import jax
-    if mode != "interp" and jax.default_backend() != "tpu":
-        return None                 # interpret machine is test-only slow
-
-    from .xla import pallas_kernels as pk
-    if op not in _FUSED_OPS or str(arrs[0].dtype) not in pk.FUSED_DTYPES:
-        return None                 # chained XLA fold, by selection
-
-    def fused(*xs):
-        return pk.fused_multi_reduce(xs, op)
-    return fused
 
 
 def _reduce_arrays(arrs: Sequence[Any], op: Op,
@@ -1839,17 +1809,6 @@ def _comm_of(args) -> Comm:
 # per-call setup entirely — the training-loop shape.
 # ---------------------------------------------------------------------------
 
-def _left_chain(op: Op):
-    """``op`` over any number of operands as the rank-ordered left chain:
-    what every fold of the registered device lane computes."""
-    def plain_fold(*xs):
-        acc = xs[0]
-        for x in xs[1:]:
-            acc = op.fn(acc, x)
-        return acc
-    return plain_fold
-
-
 def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
                             device: Any, donate: bool = True):
     """The donated-accumulator fold executable for the registered device
@@ -1879,15 +1838,13 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
     home = SingleDeviceSharding(device)
     sds = jax.ShapeDtypeStruct((count,), dt, sharding=home)
 
+    plain_fold = _left_chain(op)
+
     def chain(acc, *xs):
         # the .set() seeds the donated buffer; the fold is then the same
         # rank-ordered left chain as _jitted_fold — bitwise-identical
-        acc = acc.at[:].set(xs[0])
-        for x in xs[1:]:
-            acc = op.fn(acc, x)
-        return acc
+        return plain_fold(acc.at[:].set(xs[0]), *xs[1:])
 
-    plain_fold = _left_chain(op)
     if not _traceable(plain_fold, *([sds] * size)):
         return None                 # host-only / untraceable op: no lane
     with _pv.setup_span("fold.compile", function="plain_fold"):

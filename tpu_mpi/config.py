@@ -79,12 +79,6 @@ class Config:
     # per-(sender, dest, cid) sequence number and fail loudly on any
     # reordering/duplication/loss at delivery.
     debug_sequence_check: bool = False
-    # fused multi-operand reduction fold (xla.pallas_kernels
-    # .fused_multi_reduce) in the collective fold paths: "auto" = Pallas
-    # kernel on real TPU, chained XLA fold elsewhere; "off" = always the
-    # chained XLA fold; "interp" = force the kernel through the Pallas
-    # interpreter off-TPU too (test/debug only — orders of magnitude slow).
-    fused_fold: str = "auto"
     # communication-event tracing (tpu_mpi.analyze, docs/analysis.md):
     # record per-rank event ring buffers consumed by the cross-rank trace
     # verifier, the RMA race detector, and the DeadlockError dump of
@@ -382,7 +376,6 @@ _ENV_MAP = {
     "strict": "TPU_MPI_STRICT",
     "send_highwater_bytes": "TPU_MPI_SEND_HIGHWATER_BYTES",
     "debug_sequence_check": "TPU_MPI_DEBUG_SEQUENCE",
-    "fused_fold": "TPU_MPI_FUSED_FOLD",
     "trace": "TPU_MPI_TRACE",
     "trace_buffer": "TPU_MPI_TRACE_BUFFER",
     "trace_sample": "TPU_MPI_TRACE_SAMPLE",

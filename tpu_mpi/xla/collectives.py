@@ -65,12 +65,10 @@ def _replicate(x: Any, axis: str):
 
 
 def _gather_reduce(x: Any, op: Op, axis: str):
-    """Generic rank-ordered reduction: all_gather + combine.
-    The combine is the single-pass Pallas fused fold when the ``fused_fold``
-    config gate allows it (one traversal over all n gathered streams — the
-    ISSUE-1 tentpole kernel), else an unrolled chained fold. The unroll is
-    static (axis size is known at trace time) and XLA fuses it; this is the
-    custom-op path (SURVEY.md: 'custom ops are strictly easier on TPU')."""
+    """Generic rank-ordered reduction: all_gather + an unrolled chained
+    fold. The unroll is static (axis size is known at trace time) and XLA
+    fuses it; this is the custom-op path (SURVEY.md: 'custom ops are
+    strictly easier on TPU')."""
     lax = _lax()
     g = lax.all_gather(x, axis)          # (n, ...)
     acc = _fold_gathered(g, op)
@@ -78,19 +76,11 @@ def _gather_reduce(x: Any, op: Op, axis: str):
 
 
 def _fold_gathered(g: Any, op: Op):
-    """Left fold over the leading (per-rank) axis of a gathered array —
-    fused Pallas kernel where ``collective._fused_reduce_candidate`` selects
-    it, chained combine otherwise. Both are the same rank-ordered left fold,
-    so results are bit-identical; a selected kernel that fails to lower
-    raises."""
-    streams = [g[i] for i in range(g.shape[0])]
-    from ..collective import _fused_reduce_candidate
-    fused = _fused_reduce_candidate(op, streams)
-    if fused is not None:
-        return fused(*streams)
-    acc = streams[0]
-    for s in streams[1:]:
-        acc = op(acc, s)
+    """Rank-ordered left fold over the leading (per-rank) axis of a
+    gathered array."""
+    acc = g[0]
+    for i in range(1, g.shape[0]):
+        acc = op(acc, g[i])
     return acc
 
 

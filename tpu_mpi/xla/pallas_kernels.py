@@ -6,10 +6,9 @@ generation), this module supplies the *custom-kernel* tier the reference
 reaches by linking libmpi's hand-written algorithms (SURVEY.md §2.4): ring
 collectives and neighbor transfers written directly against the ICI with
 ``pltpu.make_async_remote_copy`` (remote DMA) + semaphores, and a fused
-ring-attention kernel as the long-context demo SURVEY.md §5 calls for. Two
-kernels are local (no remote DMA): the fused multi-operand fold of the host
-path, and ``causal_attention``, the train step's attention with a backward
-pass of its own.
+ring-attention kernel as the long-context demo SURVEY.md §5 calls for. One
+kernel is local (no remote DMA): ``causal_attention``, the train step's
+attention with a backward pass of its own.
 
 All kernels run under ``jax.shard_map`` over a 1-d mesh axis. On a TPU
 backend they compile via Mosaic; on the CPU backend (or when the caller
@@ -1040,87 +1039,3 @@ def causal_attention(q, k, v, *, block_q: Optional[int] = None,
     if not (q.shape == k.shape == v.shape and q.dtype == k.dtype == v.dtype):
         raise ValueError("causal_attention: q, k, v differ in shape or dtype")
     return _causal_attention_fn(block_q, block_k, interpret)(q, k, v)
-
-
-# ---------------------------------------------------------------------------
-# fused multi-operand reduction (the host-path Allreduce fold, single-pass:
-# read all n HBM streams once, write the result once)
-# ---------------------------------------------------------------------------
-
-# Rows per grid step. 512 rows x 128 lanes x 4 B = 256 KiB of VMEM per
-# operand block: at 5 operands + the output that is ~1.5 MiB resident plus
-# the same again in flight (Pallas double-buffers every grid operand), far
-# under the 16 MiB scoped-VMEM default, and big enough that the per-block
-# grid overhead amortizes. Multiple of 16 so bf16 (16, 128) tiling divides.
-_FUSED_BLOCK_ROWS = 512
-
-# Element types the fused fold is selected for on TPU (collective.
-# _fused_reduce_candidate): the ones Mosaic compiled on the v5e in the PR 21
-# chip run. Anything else takes the chained XLA fold by selection — a dtype
-# listed here that fails to lower is an error, not a fallback.
-FUSED_DTYPES = frozenset({"float32", "bfloat16", "int32"})
-
-
-def _fused_reduce_kernel(nin: int, combine: Callable, *refs):
-    ins, out_ref = refs[:nin], refs[nin]
-    acc = ins[0][...]
-    for r in ins[1:]:
-        acc = combine(acc, r[...])    # left fold: bit-identical to the
-    out_ref[...] = acc                # chained XLA fold's rank order
-
-
-def fused_multi_reduce(arrs: Sequence[Any], op: Any = "sum", *,
-                       interpret: Optional[bool] = None,
-                       block_rows: int = _FUSED_BLOCK_ROWS):
-    """Single-pass fused elementwise reduction over ``n`` same-shape operand
-    streams: one traversal reads a VMEM-sized block of EVERY stream, folds
-    them in rank order, and writes one output block — ``(n+1)·payload`` of
-    HBM traffic with no intermediate materialization. The chained XLA fold
-    this replaces (``collective._jitted_fold``) leaves the same traffic
-    model to XLA's fusion heuristics; here the schedule is explicit.
-
-    Pipelining: the 1-d grid walks row-blocks of the ``(rows, LANE)`` tiles
-    and Pallas's grid machinery double-buffers every operand's HBM→VMEM
-    copy — while block ``i`` is being reduced, block ``i+1`` of all ``n``
-    streams is in flight (the make_async_copy/scratch-slot pattern of the
-    ring kernels, supplied by the BlockSpec pipeline).
-
-    Unlike the ring kernels this is a LOCAL kernel (no remote DMA, no
-    barrier semaphore — so no ``collective_id``): it accelerates the
-    rendezvous fold of the host path and the gather-reduce tail of the
-    in-graph custom-op path. The left fold keeps results bit-identical to
-    the eager rank-ordered reduction at every dtype."""
-    import jax
-    import jax.numpy as jnp
-    pl = _pl()
-    arrs = list(arrs)
-    n = len(arrs)
-    if n == 0:
-        raise ValueError("fused_multi_reduce needs at least one operand")
-    if n == 1:
-        return arrs[0]
-    combine = _combine_fn(op)
-    shape, size = arrs[0].shape, arrs[0].size
-    tiles = [_to_tile(a, 1) for a in arrs]
-    rows = tiles[0].shape[0]
-    if rows <= block_rows:
-        block_rows = rows             # one block: whole-array fold
-    else:
-        padded = -(-rows // block_rows) * block_rows
-        if padded != rows:            # grid blocks must tile the rows
-            z = jnp.zeros((padded - rows, LANE), tiles[0].dtype)
-            tiles = [jnp.concatenate([t, z]) for t in tiles]
-            rows = padded
-    grid = rows // block_rows
-    kern = functools.partial(_fused_reduce_kernel, n, combine)
-    spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    out = pl.pallas_call(
-        kern,
-        grid=(grid,),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), tiles[0].dtype),
-        in_specs=[spec] * n,
-        out_specs=spec,
-        interpret=_interpret(interpret),
-        compiler_params=_compiler_params(None),
-    )(*tiles)
-    return _from_tile(out, shape, size)
