@@ -14,8 +14,11 @@ script starts no child that needs it):
    under ``jit(shard_map)`` at Float32[2^26] per device, and
    ``transformer_train_step`` at the widest configuration the repo runs
    (benchmarks/flagship_probe.py), a few steps on a fixed batch;
-3. kernels — every public kernel of ``tpu_mpi.xla.pallas_kernels`` compiled
-   by Mosaic, numerics against the XLA collective or a jnp reference;
+3. kernels — the ring kernels of ``tpu_mpi.xla.pallas_kernels`` and the
+   experts' grouped product compiled by Mosaic, numerics against the XLA
+   collective, ``lax.ragged_dot`` (values and both gradients) or a jnp
+   reference; an OLMoE-shaped expert layer takes the kernel's route and
+   compiles to the kernels alone;
 4. serve  — ``serve.Broker(nranks=4, infer=True)`` answering three
    ``session.generate`` calls. The engine is host numpy by design (ROADMAP
    S3): this leg proves the broker, the event front door and the native
@@ -67,6 +70,9 @@ FULL = {
                   d_ff=4096, max_seq=1024, batch=8, steps=4, lr=0.01),
     "ring": 250_000,              # per-device elements of the ring kernels
     "attn": (2048, 128),          # per-device (seq, head_dim), bf16 causal
+    "grouped": (8192, 2048, 1024, 16),  # rows, k, n, groups of one product
+    # an expert layer at OLMoE's widths: d_model, d_ff, experts, top, seq
+    "expert_layer": (2048, 1024, 64, 8, 4096),
     "max_new": 8,
 }
 # Toy sizes for the tier-1 CPU test only.
@@ -77,6 +83,8 @@ TINY = {
                   max_seq=32, batch=4, steps=3, lr=0.02),
     "ring": 1000,                 # the interpreter stalls on larger rings
     "attn": (32, 64),
+    "grouped": (256, 128, 128, 5),
+    "expert_layer": (128, 256, 4, 2, 64),
     "max_new": 4,
 }
 
@@ -513,7 +521,91 @@ def leg_kernels(sz: dict, platform: str) -> dict:
     # of O(1) outputs is 4e-3, the probabilities' rounding adds the rest
     assert err < 2e-2, f"ring_attention max abs err {err}"
     facts["attention_max_abs_err"] = err
+
+    # -- the experts' grouped product against lax.ragged_dot -------------------
+    # one product inside the kernel's contract, values and both gradients;
+    # an uneven split with an empty group and a group smaller than a tile
+    m, kdim, ndim, g = sz["grouped"]
+    sizes = np.zeros(g, np.int64)
+    sizes[1:] = np.arange(1, g) ** 2
+    sizes = sizes * (m - 40) // sizes.sum()
+    sizes[1] += m - sizes.sum()
+    sizes = jnp.asarray(sizes, jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    rows, weights, dout = (
+        jax.random.normal(kk, shape, jnp.float32).astype(jnp.bfloat16)
+        for kk, shape in zip(keys, [(m, kdim), (g, kdim, ndim), (m, ndim)]))
+    weights = (weights.astype(jnp.float32) * kdim ** -0.5).astype(jnp.bfloat16)
+
+    def with_grads(product):
+        def run(rows, weights, dout):
+            out, vjp = jax.vjp(lambda r, w: product(r, w, sizes), rows,
+                               weights)
+            return (out,) + vjp(dout)
+        return jax.jit(run)
+
+    got = timed("grouped_matmul[bfloat16]", with_grads(
+        lambda r, w, s: pk.grouped_matmul(r, w, s, interpret=interpret)),
+        rows, weights, dout)
+    want = with_grads(jax.lax.ragged_dot)(rows, weights, dout)
+    facts["grouped_matmul_rel_err"] = {}
+    for name, a, b in zip(("out", "d_lhs", "d_rhs"), got, want):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        # both round a float32 sum to bf16 once; the sums' orders differ
+        assert rel < 1e-2, f"grouped_matmul {name} off by {rel}"
+        facts["grouped_matmul_rel_err"][name] = rel
+    # which route the program itself gives an expert layer here, and what
+    # its compiled forward and backward hold
+    route, calls = _expert_layer_route(sz["expert_layer"])
+    facts["grouped_matmul"] = route
+    facts["expert_layer_custom_calls"] = calls
+    if not interpret:
+        # at OLMoE's widths on the chip: the kernel in all nine products
+        assert (route, calls) == ("kernel", 9), (route, calls)
     return facts
+
+
+def _expert_layer_route(sizes: tuple) -> tuple:
+    """Compile one expert layer (d_model, d_ff, experts, top-k, seq; batch
+    2), forward and backward, from shapes. Returns the route its three
+    products took by the program's own counter (``kernel`` or
+    ``ragged_dot``) and the number of Mosaic custom calls in its optimized
+    HLO. On the kernel's route nothing of XLA's own grouped product is left:
+    no `ragged-dot` instruction, no transposing copy of `w_gate`, `w_in` or
+    `w_out`."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from tpu_mpi import perfvars
+    from tpu_mpi.models import transformer as tf
+    d, f, experts, top, seq = sizes
+    cfg = tf.TransformerConfig(
+        vocab=512, d_model=d, n_heads=d // 128, n_layers=1, d_ff=f,
+        max_seq=seq, dtype=jnp.bfloat16, norm_eps=1e-5, qk_norm=True,
+        n_experts=experts, experts_per_tok=top, router_aux_coef=0.01,
+        tie_embeddings=False)
+    layer = jax.eval_shape(lambda k: tf.transformer_init(k, cfg),
+                           jax.random.key(0))["layers"][0]
+    y = jax.ShapeDtypeStruct((2, seq, cfg.d_model), cfg.dtype)
+
+    def summed(layer, y):
+        out, _routed = tf._expert_ffn(cfg, layer, y)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+    before = perfvars.snapshot()["gmm_lowerings"]
+    text = jax.jit(jax.value_and_grad(summed, argnums=(0, 1))).lower(
+        layer, y).compile().as_text()
+    after = perfvars.snapshot()["gmm_lowerings"]
+    took = {k: after[k] - before[k] for k in after}
+    assert sorted(took.values()) == [0, 3], took    # three products, one route
+    route = max(took, key=took.get)
+    if route == "kernel":
+        assert "ragged-dot" not in text, "the expert layer still holds " \
+            "XLA's ragged-dot"
+        copies = re.findall(
+            r"copy\([^\n]*op_name=\"[^\"]*w_(?:gate|in|out)", text)
+        assert not copies, f"{len(copies)} copies of the experts' weights"
+    return route, text.count("tpu_custom_call")
 
 
 # ---------------------------------------------------------------------------

@@ -47,7 +47,13 @@ def test_leg_kernels():
     # every kernel the leg compiles is one the program can still select
     assert {k.split("[")[0] for k in facts["seconds"]} == {
         "ring_allgather", "ring_allreduce", "ring_reduce_scatter",
-        "pairwise_alltoall", "collective_permute", "ring_attention"}
+        "pairwise_alltoall", "collective_permute", "ring_attention",
+        "grouped_matmul"}
+    # the grouped product ran (interpreted) against lax.ragged_dot; on the
+    # CPU backend the program itself would select `lax.ragged_dot`
+    assert set(facts["grouped_matmul_rel_err"]) == {"out", "d_lhs", "d_rhs"}
+    assert facts["grouped_matmul"] == "ragged_dot"
+    assert facts["expert_layer_custom_calls"] == 0
 
 
 def test_leg_serve():

@@ -21,8 +21,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..parallel import ring
 from ..parallel.dp import allreduce_grads
-from ..parallel.ep import moe_dropless
+from ..parallel.ep import grouped_products, moe_dropless
 from ..parallel.ring import (fused_attention_selected, local_attention,
                              ring_attention, warm_kernel_imports)
 from ..parallel.tp import column_parallel, row_parallel
@@ -177,16 +178,35 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
     with jax.named_scope("embed"):
         x = params["embed"][tokens]                               # (b, t, d)
     routed = []
+    block = _block_traced_once(cfg, tp_axis, sp_axis, ring._kernel_backend())
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer_{i}"):
-            x, sent = _attn_ffn_block(cfg, layer, x, positions,
-                                      tp_axis=tp_axis, sp_axis=sp_axis)
+            x, sent = block(layer, x, positions)
         if sent is not None:
             routed.append(sent)
     with jax.named_scope("head_loss"):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         return (x @ head).astype(jnp.float32), routed             # (b, t, V)
+
+
+@functools.lru_cache(maxsize=None)
+def _block_traced_once(cfg: TransformerConfig, tp_axis: Optional[str],
+                       sp_axis: Optional[str], kernels: Optional[str]):
+    """`_attn_ffn_block` behind a `jax.jit` of its own. A model's layers
+    have one shape, and they are unrolled in Python: jitted, layers 2..n of a
+    program (and a second program over the same shapes) find layer 1's
+    trace, its linearization and its transpose where JAX keeps them, every
+    kernel body in it is traced once, and the lowered module holds one
+    function a direction, called n times (the compiler inlines the calls,
+    and each inlined op's name gains its call's `layer_<i>`). That is what
+    keeps a step's trace, which is set-up time, from growing with depth
+    (PERF.md, Set-up). ``kernels`` is what `ring._kernel_backend` says: the
+    kernels are selected inside the trace, so it is part of the key."""
+    def block(layer, x, positions):
+        return _attn_ffn_block(cfg, layer, x, positions, tp_axis=tp_axis,
+                               sp_axis=sp_axis)
+    return jax.jit(block)
 
 
 def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
@@ -239,7 +259,11 @@ def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
     experts in float32; a token's top `experts_per_tok` probabilities weigh
     its experts' outputs as they are (not renormalised). Every token-slot is
     computed: `parallel.ep.moe_dropless` sorts the slots by expert and the
-    experts run as grouped matrix multiplications over the row groups."""
+    experts run as three grouped matrix multiplications over the row groups
+    (`parallel.ep.grouped_products`): on a TPU, at widths that are multiples
+    of 128 and a slot count that is a multiple of 128, the grouped Pallas
+    kernel (forward and both backward products); anywhere else
+    `lax.ragged_dot`."""
     b, t, d = y.shape
     rows = y.reshape(b * t, d)
     with jax.named_scope("router"):
@@ -249,9 +273,10 @@ def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
         weights, chosen = lax.top_k(probs, cfg.experts_per_tok)
 
     def experts(xs, sizes):
-        h = jax.nn.silu(lax.ragged_dot(xs, layer["w_gate"], sizes)) * \
-            lax.ragged_dot(xs, layer["w_in"], sizes)
-        return lax.ragged_dot(h, layer["w_out"], sizes)
+        product = grouped_products(sizes)
+        h = jax.nn.silu(product(xs, layer["w_gate"])) * \
+            product(xs, layer["w_in"])
+        return product(h, layer["w_out"])
 
     out, slots = moe_dropless(rows, chosen, weights.astype(rows.dtype),
                               experts, cfg.n_experts)

@@ -12,8 +12,10 @@ Three realizations live here:
 - :func:`moe_dropless` — top-k routing in which every token-slot is computed
   (the layer of the public sparse-expert models, `models/transformer.py`):
   the slots are sorted by expert and the experts run over the row groups
-  (grouped matrix multiplications); over an ``ep`` axis the row groups
-  travel by ``lax.all_to_all`` in buffers sized for the worst case;
+  (:func:`grouped_products`: the grouped Pallas kernel where the backend and
+  the shape allow it, `lax.ragged_dot` elsewhere); over an ``ep`` axis the
+  row groups travel by ``lax.all_to_all`` in buffers sized for the worst
+  case;
 - :func:`moe_dispatch_combine` — top-1, rank == expert, tokens over a fixed
   capacity dropped (static shapes, capacity masking, ``lax.all_to_all``):
   the pipelined demo's (`transformer_pp_moe_*`);
@@ -37,6 +39,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import perfvars
+from . import ring
+
 # Per-thread persistent count-exchange buffers, keyed by (cid, n). The
 # count Alltoall has a FIXED signature (n int64 per rank, same comm)
 # every decode step — reusing the same buffer objects is what lets the
@@ -55,6 +60,64 @@ def _count_exchange_bufs(cid: int, n: int):
     if key not in cache:
         cache[key] = (np.zeros(n, np.int64), np.zeros(n, np.int64))
     return cache[key]
+
+
+def _grouped_kernel_blocks(rows_shape: tuple, weights_shape: tuple, dtype):
+    """The grouped kernel's (row tile, column tile) for ``[m, k]`` rows and
+    ``[g, k, n]`` weights of ``dtype`` where it is selected, else None:
+    decided, as `ring.fused_attention_selected` decides, from the backend
+    (the same rule, `ring._kernel_backend`) and the kernel's contract
+    (``pallas_kernels.grouped_matmul_blocks``, ``GROUPED_DTYPES``), never by
+    trying it: once selected, a kernel that does not lower is an error."""
+    from ..xla import pallas_kernels as pk
+    dtype = jnp.dtype(dtype)
+    if ring._kernel_backend() is None or str(dtype) not in pk.GROUPED_DTYPES:
+        return None
+    return pk.grouped_matmul_blocks(rows_shape[0], *weights_shape[1:],
+                                    dtype.itemsize)
+
+
+def grouped_matmul_selected(rows_shape: tuple, weights_shape: tuple,
+                            dtype) -> bool:
+    """Whether a product of :func:`grouped_products` runs the grouped
+    kernel for ``[m, k]`` rows and ``[g, k, n]`` weights of ``dtype``."""
+    return _grouped_kernel_blocks(rows_shape, weights_shape, dtype) is not None
+
+
+def grouped_products(sizes: jnp.ndarray) -> Callable:
+    """``product(rows, weights)`` over rows sorted by group, ``sizes[e]`` of
+    them in group e, in order: row i of ``rows[m, k]`` times the matrix of
+    ``weights[g, k, n]`` whose group it falls in; rows past the groups' sum
+    come out zero (`lax.ragged_dot`'s meaning). On a TPU, at a shape inside
+    its contract, the Pallas kernel ``xla.pallas_kernels.grouped_matmul``
+    (forward, and in the backward pass the rows' and the weights' gradients,
+    no weight transposed in HBM); everywhere else `lax.ragged_dot`.
+
+    The kernel's walk over the groups depends on the sizes alone: it is
+    computed at the first product that takes the kernel and kept here for
+    the ones that follow, so an expert layer computes it once for its three
+    products and their six gradients. Each product counts, where this
+    choice is made (once for every trace of the layer that holds it), in
+    ``perfvars.snapshot()["gmm_lowerings"]`` as ``kernel`` or
+    ``ragged_dot``."""
+    walks = {}
+
+    def product(rows: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
+        blocks = None if rows.dtype != weights.dtype else \
+            _grouped_kernel_blocks(rows.shape, weights.shape, rows.dtype)
+        if blocks is None:
+            perfvars.note_gmm_lowering("ragged_dot")
+            return lax.ragged_dot(rows, weights, sizes)
+        from ..xla import pallas_kernels as pk
+        perfvars.note_gmm_lowering("kernel")
+        walk = rows.shape[0], blocks[0]     # what a walk depends on
+        if walk not in walks:
+            walks[walk] = pk.grouped_matmul_visits(sizes, *walk)
+        return pk.grouped_matmul(
+            rows, weights, sizes, visits=walks[walk], block_m=blocks[0],
+            block_c=blocks[1],
+            interpret=ring._kernel_backend() == "interpret")
+    return product
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -104,7 +167,9 @@ def moe_dropless(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
     (global ids, distinct per token); weights: (t, k) what each expert's
     output is multiplied by. expert_fn(rows, group_sizes): the experts held
     here applied to rows sorted by expert, group_sizes[e] rows for expert e
-    (`lax.ragged_dot`'s arguments); rows past the groups' sum are padding.
+    (what :func:`grouped_products` takes, which is what an expert_fn
+    multiplies with); rows past the groups' sum are padding, and what
+    expert_fn returns for them is not read.
     Returns ((t, d) sum over k of weights x expert(token), (n_experts,)
     int32 token-slots of these tokens per expert). The experts always
     process exactly t x k rows in all.

@@ -15,9 +15,10 @@ pieces:
   per-collective latency histograms (log2-µs buckets,
   ``config.pvars_hist_bins`` wide). Plan-cache hits/misses ride along at
   snapshot time from ``overlap.plans.stats()``, the wall time spent
-  registering plans and compiling folds as ``arming_s``, and the attention
+  registering plans and compiling folds as ``arming_s``, the attention
   calls built into traced programs as the fused kernel or the plain path
-  as ``attn_lowerings``.
+  as ``attn_lowerings``, and the experts' grouped multiplications as the
+  grouped kernel or `lax.ragged_dot` as ``gmm_lowerings``.
   ``fold`` and ``copy`` on device operands are DISPATCH times: the host
   seconds it took to enqueue the fold (its operand copies included) and
   the copy-out, not the seconds the device worked. The device's end of
@@ -543,6 +544,19 @@ def note_attn_lowering(kind: str) -> None:
         _attn_lowerings[kind] += 1
 
 
+# `parallel.ep.grouped_products` likewise: the grouped Pallas kernel or
+# `lax.ragged_dot`, one count per product where the selection is made.
+
+_gmm_lowerings = {"kernel": 0, "ragged_dot": 0}
+
+
+def note_gmm_lowering(kind: str) -> None:
+    """One grouped multiplication was traced as the ``kernel`` or as
+    ``ragged_dot``."""
+    with _store_lock:
+        _gmm_lowerings[kind] += 1
+
+
 # -- the device's end of a copy between chips --------------------------------
 #
 # A host span around an asynchronous copy times the enqueue. An op that
@@ -1044,6 +1058,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "comms": comms, "plan_cache": plans.stats(),
             "arming_s": arming_seconds(),
             "attn_lowerings": dict(_attn_lowerings),
+            "gmm_lowerings": dict(_gmm_lowerings),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
             "serve_frame": serve_frame_snapshot(),
@@ -1085,6 +1100,7 @@ def reset() -> None:
         _locks.clear()
         _arming.clear()
         _attn_lowerings.update(fused=0, plain=0)
+        _gmm_lowerings.update(kernel=0, ragged_dot=0)
         _store_gen += 1
 
 

@@ -6,11 +6,16 @@ generation), this module supplies the *custom-kernel* tier the reference
 reaches by linking libmpi's hand-written algorithms (SURVEY.md §2.4): ring
 collectives and neighbor transfers written directly against the ICI with
 ``pltpu.make_async_remote_copy`` (remote DMA) + semaphores, and a fused
-ring-attention kernel as the long-context demo SURVEY.md §5 calls for. One
-kernel is local (no remote DMA): ``causal_attention``, the train step's
-attention with a backward pass of its own.
+ring-attention kernel as the long-context demo SURVEY.md §5 calls for. Two
+kernels are local (no remote DMA) and differentiable, and are what a train
+step on one chip runs: ``causal_attention``, its attention, and
+``grouped_matmul``, the products of its sparse-expert layers (rows sorted by
+expert times each row's expert's matrix; forward, the rows' gradient and the
+weights' gradient). Both are gridded over blocks in HBM, so neither is
+bounded by VMEM as the ring kernels are.
 
-All kernels run under ``jax.shard_map`` over a 1-d mesh axis. On a TPU
+The ring kernels run under ``jax.shard_map`` over a 1-d mesh axis, the local
+ones anywhere. On a TPU
 backend they compile via Mosaic; on the CPU backend (or when the caller
 passes ``interpret=True``) they execute under the Pallas TPU *interpret
 machine* (``pltpu.InterpretParams``), which simulates per-device
@@ -899,6 +904,20 @@ def _varying_like(x, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(x).vma)
 
 
+def _vary_together(*xs):
+    """The operands, each made to vary over every mesh axis any of them
+    varies over: a kernel's operands and results then have one type under
+    `shard_map`, and a replicated operand's gradient is summed over those
+    axes by the cast's own transpose, as it is for any XLA operation."""
+    import jax
+    axes = set().union(*(jax.typeof(x).vma for x in xs))
+    out = []
+    for x in xs:
+        missing = tuple(sorted(axes - set(jax.typeof(x).vma)))
+        out.append(jax.lax.pcast(x, missing, to="varying") if missing else x)
+    return out
+
+
 def _attn_forward(q, k, v, bq: int, bk: int, interpret: Optional[bool]):
     """(o, log-sum-exp [b, h, 1, t] float32) of causal attention."""
     import jax
@@ -1039,3 +1058,429 @@ def causal_attention(q, k, v, *, block_q: Optional[int] = None,
     if not (q.shape == k.shape == v.shape and q.dtype == k.dtype == v.dtype):
         raise ValueError("causal_attention: q, k, v differ in shape or dtype")
     return _causal_attention_fn(block_q, block_k, interpret)(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# grouped matrix multiplication (a sparse-expert layer's products: rows
+# sorted by group, one matrix a group; forward and both backward products,
+# no weight transposed in HBM)
+# ---------------------------------------------------------------------------
+
+# Operand types the kernel is selected for (parallel.ep.grouped_products), as
+# ATTN_DTYPES above: one listed here that fails to lower is an error.
+GROUPED_DTYPES = frozenset({"float32", "bfloat16"})
+_GROUPED_ROW_TILES = (512, 256, 128)    # widest first
+_GROUPED_SLICE_ROWS = 128   # what a tile shared by groups is multiplied in
+# column tiles, widest first: a dimension no wider than one is taken whole
+_GROUPED_COL_TILES = (2048, 1024, 512, 256, 128)
+
+
+def _grouped_vmem(tm: int, k: int, n: int, tc: int, itemsize: int) -> int:
+    """The largest VMEM working set of the three kernels at row tile ``tm``
+    and column tile ``tc``: operand and result blocks double-buffered by the
+    grid pipeline, the float32 product (and, for the weights' gradient, its
+    accumulator and the row block transposed)."""
+    tk, tn = min(k, tc), min(n, tc)
+    fwd = 2 * (tm * k + k * tn + tm * tn) * itemsize + tm * tn * 4
+    dlhs = 2 * (tm * n + tk * n + tm * tk) * itemsize + tm * tk * 4
+    drhs = (2 * (tm * tk + tm * tn + tk * tn) + tm * tk) * itemsize \
+        + 2 * tk * tn * 4
+    return max(fwd, dlhs, drhs)
+
+
+def grouped_matmul_blocks(m: int, k: int, n: int,
+                          itemsize: int) -> Optional[tuple]:
+    """(row tile, column tile) of the grouped kernels for ``[m, k]`` rows and
+    ``[g, k, n]`` matrices of ``itemsize`` bytes an element, or None where
+    their contract does not hold: ``m`` a multiple of a row tile, ``k`` and
+    ``n`` multiples of 128 (each is a contraction in one of the three
+    products and a block's minor dimension in another), and the blocks of
+    all three inside :data:`VMEM_LIMIT_BYTES` with the headroom
+    :func:`_compiler_params` asks for. The contraction is always taken
+    whole, so a group's matrix stays in VMEM over the group's row tiles; the
+    other dimension is cut to the column tile only where VMEM forces it."""
+    if k % LANE or n % LANE or min(m, k, n) <= 0:
+        return None
+    tm = next((t for t in _GROUPED_ROW_TILES if m % t == 0), None)
+    if tm is None:
+        return None
+    for tc in _GROUPED_COL_TILES:
+        if any(d > tc and d % tc for d in (k, n)):
+            continue
+        if 2 * _grouped_vmem(tm, k, n, tc, itemsize) <= VMEM_LIMIT_BYTES:
+            return tm, tc
+    return None
+
+
+def grouped_matmul_visits(group_sizes, m: int, block_m: int):
+    """The grouped kernels' walk over ``m`` rows sorted by group, computed
+    in the traced program from ``group_sizes`` and handed to them by scalar
+    prefetch: (offsets[g + 2], group[v], tile[v], matrix[v], visits[1]), all
+    int32. It depends on the sizes, ``m`` and the row tile alone: a layer
+    computes it once and gives it to every product over those rows
+    (:func:`grouped_matmul`'s ``visits``), forward and backward.
+
+    A visit is one (group, row tile) pair; a row tile that spans several
+    groups is visited once by each, in order, so the visits of one group
+    and the visits of one tile are both consecutive. The rows past the
+    groups' sum are group ``g``, whose product is zero; an empty group is
+    visited once (its weight gradient has to be written); the static length
+    is m / block_m + g: every group but the first may start inside a tile),
+    and the entries from ``visits[0]`` on repeat the last (group g, last
+    tile): a kernel does nothing there and no block moves. ``matrix[v]`` is
+    the group whose matrix a product needs at visit v: the visit's own where
+    it has rows, else the last one before it that had (an empty group's
+    matrix is never copied in).
+
+    Written with few, plain operations (comparisons against a [visits,
+    groups] grid, not `repeat` or a scan) and jitted on its own: tracing is
+    what it costs, and a second program over the same shapes finds the
+    trace there."""
+    return _group_visits_fn(m, block_m)(group_sizes)
+
+
+@functools.lru_cache(maxsize=None)
+def _group_visits_fn(m: int, tm: int):
+    import jax
+    return jax.jit(lambda sizes: _visits_of(m, tm, sizes))
+
+
+def _visits_of(m: int, tm: int, sizes):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    i32 = jnp.int32
+    g = sizes.shape[0]
+    tiles_m = m // tm
+    length = tiles_m + g
+    sizes = sizes.astype(i32)
+    rest = jnp.maximum(m - jnp.sum(sizes, dtype=i32), 0)
+    sizes = jnp.concatenate([sizes, rest[None]])            # g + 1
+    ends = jnp.cumsum(sizes, dtype=i32)
+    first = jnp.minimum(jax.lax.div(ends - sizes, i32(tm)), tiles_m - 1)
+    last = jax.lax.div(jnp.maximum(ends - 1, 0), i32(tm))
+    # an empty group is visited once, empty rows past the sum never
+    alone = np.array([1] * g + [0], np.int32)
+    spanned = jnp.where(sizes > 0, last - first + 1, alone)
+    ended = jnp.cumsum(spanned, dtype=i32)      # a group's last visit + 1
+    v = jnp.arange(length, dtype=i32)
+    group = jnp.minimum(jnp.sum(v[:, None] >= ended[None, :], axis=1,
+                                dtype=i32), g)
+    within = v - (ended - spanned)[group]
+    tile = jnp.minimum(first[group] + within, tiles_m - 1)
+    ids = jnp.arange(g, dtype=i32)
+    held = jnp.logical_and(sizes[None, :g] > 0,
+                           ids[None, :] <= group[:, None])
+    matrix = jnp.max(jnp.where(held, ids[None, :], 0), axis=1)
+    offsets = jnp.concatenate([jnp.zeros(1, i32), ends])
+    return offsets, group, tile, matrix, ended[g:]
+
+
+def _visit(g: int, tm: int, v, offs_ref, group_ref, tile_ref, visits_ref):
+    """What visit ``v`` is: (live: not padding; group, clamped to a real
+    one; row0 of its tile; lo, hi: the group's rows; real: a group with
+    rows, not the rest)."""
+    import jax.numpy as jnp
+    import numpy as np
+    raw = group_ref[v]
+    lo, hi = offs_ref[raw], offs_ref[raw + 1]
+    live = v < visits_ref[0]
+    real = jnp.logical_and(raw < g, hi > lo)
+    return (live, jnp.minimum(raw, np.int32(g - 1)), tile_ref[v] * tm, lo,
+            hi, real)
+
+
+def _for_slices_of_group(tm: int, row0, lo, hi, body) -> None:
+    """``body(where in the tile, its first row among all rows)`` for each
+    slice of _GROUPED_SLICE_ROWS rows of the tile at ``row0`` that holds a
+    row of [lo, hi), and for no other: a visit of a tile that its group
+    shares multiplies only those, so a boundary between groups costs a
+    slice of needless products and not a tile's. One loop with traced
+    bounds, so the body is traced (and compiled) once."""
+    import jax
+    import jax.numpy as jnp
+    pl = _pl()
+    rows = min(_GROUPED_SLICE_ROWS, tm)
+    first = jax.lax.div(jnp.maximum(lo - row0, 0), jnp.int32(rows))
+    stop = jax.lax.div(jnp.minimum(hi - row0, tm) + (rows - 1),
+                       jnp.int32(rows))
+
+    def one(j, carry):
+        at = pl.ds(pl.multiple_of(j * rows, rows), rows)
+        body(at, row0 + j * rows)
+        return carry
+    jax.lax.fori_loop(first, stop, one, jnp.int32(0))
+
+
+def _rows_of_group(shape, row0, lo, hi):
+    """[rows, cols] bool: the rows of the tile at ``row0`` that lie in
+    [lo, hi)."""
+    import jax
+    import jax.numpy as jnp
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return jnp.logical_and(rows >= lo, rows < hi)
+
+
+def _gmm_kernel(g: int, transposed: bool, offs_ref, group_ref, tile_ref,
+                _matrix_ref, visits_ref, lhs_ref, rhs_ref, out_ref):
+    """One visit of rows x matrix: the tile's rows times the group's matrix
+    (or, ``transposed``, its transpose: the contraction runs over the
+    block's minor dimension and nothing is re-laid), stored over the
+    group's rows of the tile only. A tile that is not one group's alone is
+    zeroed at its first visit, so the rows past the groups' sum come out
+    zero."""
+    import jax
+    import jax.numpy as jnp
+    pl = _pl()
+    v = pl.program_id(1)
+    tm = lhs_ref.shape[0]
+    live, _group, row0, lo, hi, real = _visit(
+        g, tm, v, offs_ref, group_ref, tile_ref, visits_ref)
+    first = jnp.logical_or(v == 0,
+                           tile_ref[jnp.maximum(v - 1, 0)] != tile_ref[v])
+    whole = jnp.logical_and(jnp.logical_and(lo <= row0, hi >= row0 + tm),
+                            real)
+    dims = (((1,), (1 if transposed else 0,)), ((), ()))
+
+    def product(rows=slice(None)):      # a slice, or a pl.ds of a loop
+        return jax.lax.dot_general(
+            lhs_ref[rows, :], rhs_ref[...], dims,
+            preferred_element_type=jnp.float32,
+            precision=_attn_precision(lhs_ref.dtype)).astype(out_ref.dtype)
+
+    @pl.when(jnp.logical_and(live, whole))
+    def _inside_one_group():
+        out_ref[...] = product()
+
+    @pl.when(jnp.logical_and(live, jnp.logical_and(first,
+                                                   jnp.logical_not(whole))))
+    def _first_visit_of_a_shared_tile():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_and(real,
+                                                   jnp.logical_not(whole))))
+    def _shared_tile():
+        def a_slice(at, r0):
+            kept = out_ref[at, :]
+            out_ref[at, :] = jnp.where(
+                _rows_of_group(kept.shape, r0, lo, hi), product(at), kept)
+        _for_slices_of_group(tm, row0, lo, hi, a_slice)
+
+
+def _tgmm_kernel(g: int, offs_ref, group_ref, tile_ref, _matrix_ref,
+                 visits_ref, lhs_ref, dout_ref, out_ref, acc_ref):
+    """One visit of the weights' gradient: rows^T x d out of the tile's rows
+    that lie in the visit's group, accumulated in float32 over the group's
+    visits and written once, at its last (an empty group's: zero)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    pl = _pl()
+    v = pl.program_id(2)
+    tm = lhs_ref.shape[0]
+    live, group, row0, lo, hi, real = _visit(
+        g, tm, v, offs_ref, group_ref, tile_ref, visits_ref)
+    top = np.int32(g - 1)
+    before = jnp.minimum(group_ref[jnp.maximum(v - 1, 0)], top)
+    after = jnp.minimum(group_ref[jnp.minimum(v + 1,
+                                              pl.num_programs(2) - 1)], top)
+    whole = jnp.logical_and(lo <= row0, hi >= row0 + tm)
+    prec = _attn_precision(lhs_ref.dtype)
+    tn = (((0,), (0,)), ((), ()))
+
+    @pl.when(jnp.logical_and(live, jnp.logical_or(v == 0, before != group)))
+    def _first_visit_of_the_group():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_and(real, whole)))
+    def _inside_one_group():
+        acc_ref[...] += jax.lax.dot_general(
+            lhs_ref[...], dout_ref[...], tn,
+            preferred_element_type=jnp.float32, precision=prec)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_and(real,
+                                                   jnp.logical_not(whole))))
+    def _shared_tile():
+        def a_slice(at, r0):
+            lhs, dout = lhs_ref[at, :], dout_ref[at, :]
+            lhs = jnp.where(_rows_of_group(lhs.shape, r0, lo, hi), lhs,
+                            jnp.zeros_like(lhs))
+            dout = jnp.where(_rows_of_group(dout.shape, r0, lo, hi), dout,
+                             jnp.zeros_like(dout))
+            acc_ref[...] += jax.lax.dot_general(
+                lhs, dout, tn, preferred_element_type=jnp.float32,
+                precision=prec)
+        _for_slices_of_group(tm, row0, lo, hi, a_slice)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_or(
+        v == visits_ref[0] - 1, after != group)))
+    def _last_visit_of_the_group():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _gmm_call(lhs, rhs, visits, tm: int, tc: int, transposed: bool,
+              interpret: Optional[bool]):
+    """rows x matrix of each row's group: ``lhs[m, k] x rhs[g, k, n]``, or
+    ``transposed`` ``lhs[m, n] x rhs[g, k, n]^T`` (the rows' gradient), the
+    contraction whole. Grid (column tile, visit): consecutive visits of one
+    group ask for the same block of ``rhs`` and the pipeline skips the
+    copy."""
+    import numpy as np
+    pl, pltpu = _pl(), _pltpu()
+    m, c = lhs.shape
+    g = rhs.shape[0]
+    cols = rhs.shape[1] if transposed else rhs.shape[2]
+    tn = min(cols, tc)
+    zero = np.int32(0)
+
+    def rows_at(ni, v, offs, group, tile, matrix, visits):
+        return tile[v], zero
+
+    def matrix_at(ni, v, offs, group, tile, matrix, visits):
+        return (matrix[v], ni, zero) if transposed else (matrix[v], zero, ni)
+
+    def out_at(ni, v, offs, group, tile, matrix, visits):
+        return tile[v], ni
+
+    name = "grouped_matmul_dlhs" if transposed else "grouped_matmul_fwd"
+    k, n = (cols, c) if transposed else (c, cols)
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, g, transposed),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(cols // tn, visits[1].shape[0]),
+            in_specs=[pl.BlockSpec((tm, c), rows_at),
+                      pl.BlockSpec((None, tn, c) if transposed
+                                   else (None, c, tn), matrix_at)],
+            out_specs=pl.BlockSpec((tm, tn), out_at)),
+        out_shape=_varying_like(lhs, (m, cols), lhs.dtype),
+        interpret=_interpret(interpret),
+        compiler_params=_compiler_params(
+            None, _grouped_vmem(tm, k, n, tc, lhs.dtype.itemsize),
+            "grouped_matmul", ("parallel", "arbitrary")),
+        name=name,
+    )(*visits, lhs, rhs)
+
+
+def _tgmm_call(lhs, dout, g: int, visits, tm: int, tc: int,
+               interpret: Optional[bool]):
+    """The weights' gradient ``[g, k, n]``: for each group ``lhs[rows of
+    g]^T x dout[rows of g]``. Grid (k tile, n tile, visit): the visits are
+    the reduction, one float32 accumulator per block of a group."""
+    import jax.numpy as jnp
+    import numpy as np
+    pl, pltpu = _pl(), _pltpu()
+    m, k = lhs.shape
+    n = dout.shape[1]
+    tk, tn = min(k, tc), min(n, tc)
+    top = np.int32(g - 1)
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(k // tk, n // tn, visits[1].shape[0]),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda ki, ni, v, offs, group, tile,
+                             matrix, visits: (tile[v], ki)),
+                pl.BlockSpec((tm, tn), lambda ki, ni, v, offs, group, tile,
+                             matrix, visits: (tile[v], ni))],
+            out_specs=pl.BlockSpec(
+                (None, tk, tn), lambda ki, ni, v, offs, group, tile, matrix,
+                visits: (jnp.minimum(group[v], top), ki, ni)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        out_shape=_varying_like(lhs, (g, k, n), lhs.dtype),
+        interpret=_interpret(interpret),
+        compiler_params=_compiler_params(
+            None, _grouped_vmem(tm, k, n, tc, lhs.dtype.itemsize),
+            "grouped_matmul", ("parallel", "parallel", "arbitrary")),
+        name="grouped_matmul_drhs",
+    )(*visits, lhs, dout)
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_matmul_fn(tm: int, tc: int, interpret: Optional[bool]):
+    """The differentiable grouped product at one tiling, over rows whose
+    walk is given. Its forward and its backward are each jitted once,
+    outside the `custom_vjp`: every product of a program with the same
+    shapes (and the primal and the forward rule of each) shares one trace
+    of the kernel's body and one lowering, which is what a kernel costs at
+    set-up (PERF.md, Set-up)."""
+    import jax
+
+    @jax.jit
+    def forward(lhs, rhs, visits):
+        return _gmm_call(lhs, rhs, visits, tm, tc, False, interpret)
+
+    @jax.jit
+    def backward(lhs, rhs, visits, dout):
+        return (_gmm_call(dout, rhs, visits, tm, tc, True, interpret),
+                _tgmm_call(lhs, dout, rhs.shape[0], visits, tm, tc,
+                           interpret))
+
+    @jax.custom_vjp
+    def grouped(lhs, rhs, visits):
+        return forward(lhs, rhs, visits)
+
+    def fwd(lhs, rhs, visits):
+        return forward(lhs, rhs, visits), (lhs, rhs, visits)
+
+    def bwd(kept, dout):
+        return backward(*kept, dout) + (None,)
+
+    grouped.defvjp(fwd, bwd)
+    return grouped
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, visits=None,
+                   block_m: Optional[int] = None,
+                   block_c: Optional[int] = None,
+                   interpret: Optional[bool] = None):
+    """``lax.ragged_dot(lhs, rhs, group_sizes)``: row i of ``lhs[m, k]``
+    times the matrix of ``rhs[g, k, n]`` whose group it falls in, the first
+    ``group_sizes[0]`` rows in group 0 and so on; rows past the groups' sum
+    come out zero; an empty group is legal. bf16 operands enter the MXU with
+    float32 accumulation (float32 operands at full precision) and the result
+    is rounded once, to the operands' dtype.
+
+    The walk over (group, row tile) pairs is computed in the traced program
+    from ``group_sizes`` (:func:`grouped_matmul_visits`; ``visits`` is that
+    walk where the caller has it already, for these sizes, these rows and
+    this row tile) and prefetched as scalars;
+    the grid is (column tile, visit) with the contraction whole, so a
+    group's matrix is copied into VMEM once per call and column tile
+    however many row tiles the group has, and a tile that several groups
+    share is visited once by each: only its 128-row slices that hold a row
+    of the visiting group are multiplied, the others' rows masked at the
+    store.
+
+    Differentiable: d lhs is the same kernel contracting over the matrices'
+    last axis (no transposed copy of a weight in HBM), d rhs the transposed
+    kernel, one float32 accumulator per group and block over the group's
+    visits (an empty group's gradient zero).
+
+    Tiles default to :func:`grouped_matmul_blocks`; a shape outside the
+    kernels' contract raises."""
+    m, k = lhs.shape
+    g, k2, n = rhs.shape
+    if k != k2 or lhs.dtype != rhs.dtype or group_sizes.shape != (g,):
+        raise ValueError("grouped_matmul: lhs [m, k], rhs [g, k, n] of one "
+                         "dtype and group_sizes [g] are needed, not "
+                         f"{lhs.shape} {lhs.dtype}, {rhs.shape} {rhs.dtype},"
+                         f" {group_sizes.shape}")
+    if block_m is None or block_c is None:
+        blocks = grouped_matmul_blocks(m, k, n, lhs.dtype.itemsize)
+        if blocks is None:
+            raise ValueError(
+                f"grouped_matmul: (m, k, n) = ({m}, {k}, {n}) is outside "
+                f"the kernels' contract (m a multiple of "
+                f"{_GROUPED_ROW_TILES[-1]}, k and n multiples of {LANE}, "
+                "the blocks in VMEM)")
+        block_m, block_c = block_m or blocks[0], block_c or blocks[1]
+    if m % block_m or any(d > block_c and d % block_c for d in (k, n)):
+        raise ValueError(f"grouped_matmul: tiles ({block_m}, {block_c}) do "
+                         f"not divide (m, k, n) = ({m}, {k}, {n})")
+    if visits is None:
+        visits = grouped_matmul_visits(group_sizes, m, block_m)
+    lhs, rhs, *visits = _vary_together(lhs, rhs, *visits)
+    return _grouped_matmul_fn(block_m, block_c, interpret)(lhs, rhs,
+                                                           tuple(visits))
