@@ -73,6 +73,8 @@ FULL = {
     "grouped": (8192, 2048, 1024, 16),  # rows, k, n, groups of one product
     # an expert layer at OLMoE's widths: d_model, d_ff, experts, top, seq
     "expert_layer": (2048, 1024, 64, 8, 4096),
+    # heads, key/value heads, seq, head_dim, window of one attention block
+    "window_attn": (16, 2, 4096, 128, 128),
     "max_new": 8,
 }
 # Toy sizes for the tier-1 CPU test only.
@@ -85,6 +87,7 @@ TINY = {
     "attn": (32, 64),
     "grouped": (256, 128, 128, 5),
     "expert_layer": (128, 256, 4, 2, 64),
+    "window_attn": (4, 2, 256, 128, 100),
     "max_new": 4,
 }
 
@@ -555,6 +558,36 @@ def leg_kernels(sz: dict, platform: str) -> dict:
         # both round a float32 sum to bf16 once; the sums' orders differ
         assert rel < 1e-2, f"grouped_matmul {name} off by {rel}"
         facts["grouped_matmul_rel_err"][name] = rel
+    # -- the local attention kernel under a window, grouped heads ---------------
+    # one block as a layer kind with a window has it (query head j reads
+    # key/value head j // group), forward and backward, against the plain
+    # path (`parallel.ring.plain_attention`)
+    from tpu_mpi.parallel import ring
+    h, hk, t, dh, window = sz["window_attn"]
+    keys = jax.random.split(jax.random.PRNGKey(2), 4)
+    q, k, v, do = (
+        jax.random.normal(kk, (1, n, t, dh), jnp.float32).astype(jnp.bfloat16)
+        for kk, n in zip(keys, (h, hk, hk, h)))
+
+    def attn_with_grads(attend):
+        def run(q, k, v, do):
+            out, vjp = jax.vjp(attend, q, k, v)
+            return (out,) + vjp(do)
+        return jax.jit(run)
+
+    got = timed("causal_attention[window, grouped heads]", attn_with_grads(
+        lambda q, k, v: pk.causal_attention(q, k, v, window=window,
+                                            interpret=interpret)), q, k, v, do)
+    want = attn_with_grads(
+        lambda q, k, v: ring.plain_attention(q, k, v, window))(q, k, v, do)
+    facts["window_attention_rel_err"] = {}
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        # bf16 on both sides; the plain path rounds its probabilities and
+        # sums a group's dk and dv in another order
+        assert rel < 3e-2, f"causal_attention[window] {name} off by {rel}"
+        facts["window_attention_rel_err"][name] = rel
     # which route the program itself gives an expert layer here, and what
     # its compiled forward and backward hold
     route, calls = _expert_layer_route(sz["expert_layer"])

@@ -385,3 +385,98 @@ def test_compiled_for_the_v5e_the_attention_kernel_at_the_flagships_shape(
         (0, 1, 2))).lower(x, x, x).compile().as_text()
     assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
     assert ",1024,1024]" not in hlo
+
+
+def test_compiled_for_the_v5e_the_layer_kind_step_fits_one_chip(
+        v5e_2x2, monkeypatch):
+    """The benchmark's K-EXAONE share (yardstick/configs/
+    k-exaone-236b-a23b-1c.json: published widths, layers 0-4, 8 of 128
+    experts held, batch 1 x 8192, parameters donated) compiled for one
+    described v5e chip with the kernels selected as on a TPU: 2.504 B
+    parameters, inside the chip's memory with the recomputation the file
+    states (18.1 GB with none), no [b, h, t, t] buffer, the fused attention
+    kernel forward and backward in every layer under its `attn` scope, the
+    grouped kernel in the held experts' products under `mlp/experts`, and
+    one kernel body a kind: two attention pairs (window, full), one set of
+    grouped products a weight shape."""
+    import json
+    import os
+    import re
+    from tpu_mpi.parallel import ring
+    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from tpu_mpi import xla
+    from tpu_mpi.models.transformer import (TransformerConfig,
+                                            transformer_init,
+                                            transformer_train_step)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "yardstick", "configs",
+                           "k-exaone-236b-a23b-1c.json")) as f:
+        conf = json.load(f)
+    fields = dict(conf["model"], max_seq=8192)
+    fields["dtype"] = jnp.dtype(fields["dtype"])
+    cfg = TransformerConfig(**fields)
+    mesh = xla.make_mesh(dict(conf["mesh"]), devices=v5e_2x2[:1])
+    step, specs = transformer_train_step(cfg, mesh, lr=conf["lr"], donate=True)
+    shapes = jax.eval_shape(lambda k: transformer_init(k, cfg),
+                            jax.random.key(0))
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 2_504_068_352
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        shapes, specs)
+    tok = jax.ShapeDtypeStruct((1, 8192), jnp.int32,
+                               sharding=NamedSharding(mesh, P("dp", "sp")))
+    lowered = step.lower(params, tok, tok)
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert m.alias_size_in_bytes > 5.0e9        # the parameters are reused
+    assert 10e9 < held < 15e9, held
+    hlo = compiled.as_text()
+    assert ",8192,8192]" not in hlo             # no [.., t, t] scores
+    calls = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*causal_attention_(\w+)[^\"]*)\"", hlo)
+    assert all("/attn/" in name for name, _d in calls)
+    layers = sorted((d, int(re.search(r"layer_(\d+)", name).group(1)))
+                    for name, d in calls)
+    assert {(d, i) for d, i in layers} == {
+        (d, i) for d in ("fwd", "bwd") for i in range(cfg.n_layers)}
+    grouped = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                         r"op_name=\"([^\"]*grouped_matmul_(\w+)/[^\"]*)\"", hlo)
+    assert grouped and "ragged-dot" not in hlo
+    # (a recomputed FFN half nests its scopes under `checkpoint`)
+    assert all({"mlp", "experts"} <= set(name.split("/"))
+               for name, _kind in grouped)
+    assert {kind for _n, kind in grouped} == {"fwd", "dlhs", "drhs"}
+    assert "bf16[128,8192," not in hlo and "bf16[8,65536," not in hlo
+    kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
+    assert sorted(set(kernels)) == [
+        "causal_attention_bwd", "causal_attention_fwd", "grouped_matmul_dlhs",
+        "grouped_matmul_drhs", "grouped_matmul_fwd"]
+    assert kernels.count("causal_attention_fwd") == \
+        kernels.count("causal_attention_bwd") == 2, kernels
+
+
+def test_compiled_for_the_v5e_the_attention_kernel_with_a_window_and_groups(
+        v5e_2x2):
+    """The fused causal attention, forward and backward, at the drawn
+    model's shape (64 query heads reading 8 key/value heads, seq 8192, head
+    128, bfloat16) lowers through Mosaic with a window of 128 and without
+    one; dk and dv come back with the key/value heads' shape."""
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import pallas_kernels as pk
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((1, 64, 8192, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16, sharding=one)
+    for window in (128, 0):
+        compiled = jax.jit(jax.grad(lambda q, k, v: pk.causal_attention(
+            q, k, v, window=window, interpret=False).astype(
+                jnp.float32).sum(), (0, 1, 2))).lower(q, kv, kv).compile()
+        hlo = compiled.as_text()
+        assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+        assert ",8192,8192]" not in hlo
+        shapes = [o.shape for o in jax.tree.leaves(compiled.out_info)]
+        assert shapes == [(1, 64, 8192, 128), (1, 8, 8192, 128),
+                          (1, 8, 8192, 128)]

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +23,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel import ring
 from ..parallel.dp import allreduce_grads
-from ..parallel.ep import grouped_products, moe_dropless
+from ..parallel.ep import (grouped_products, held_row_buffer, moe_dropless,
+                           moe_dropless_held)
 from ..parallel.ring import (fused_attention_selected, local_attention,
                              ring_attention, warm_kernel_imports)
 from ..parallel.tp import column_parallel, row_parallel
@@ -55,10 +56,76 @@ class TransformerConfig:
     #                             pass: where the plain attention runs, it is
     #                             recomputed there (`jax.checkpoint`); the
     #                             fused kernel keeps none as it is
+    # A stack whose layers differ, as data: one entry a layer in each list
+    # (an empty list: every layer alike, as above). A layer's *kind* is what
+    # its program depends on (`layer_kind`); layers of one kind share a trace.
+    d_head: int = 0             # a head's width; 0: d_model // n_heads
+    n_kv_heads: int = 0         # > 0: grouped-query attention with `w_q`,
+    #                             `w_k`, `w_v` of their own; query head j reads
+    #                             key/value head j // (n_heads / n_kv_heads).
+    #                             0: n_heads of each, packed in `w_qkv`
+    qk_norm_heads: bool = False     # RMSNorm of q and of k over each head's
+    #                             values, one learned scale [head_dim] each
+    rope_theta: float = 10000.0
+    attn_windows: tuple = ()    # a layer's window: query p sees keys p - w + 1
+    #                             .. p; 0: every key up to p
+    rope_full_layers: bool = True   # False: a layer with window 0 rotates
+    #                             nothing (positions come from its neighbours)
+    ffn_kinds: tuple = ()       # "dense" | "sparse" a layer; empty: sparse
+    #                             everywhere if n_experts else dense
+    d_ff_dense: int = 0         # a dense layer's width beside experts; 0: d_ff
+    dense_gated: bool = False   # dense FFN out(silu(gate(x)) * in(x)), not
+    #                             out(gelu(in(x)))
+    n_shared_experts: int = 0   # a gated FFN of width n_shared_experts x d_ff
+    #                             that every token runs, beside the routed ones
+    router_score: str = "softmax"   # | "sigmoid": scores of the router's
+    #                             logits, float32, the top experts_per_tok win
+    router_renorm: bool = False     # the chosen scores divided by their sum
+    router_scale: float = 1.0   # x the weights of the routed experts' outputs
+    experts_held: tuple = ()    # (first, count): this rank holds experts
+    #                             [first, first + count) of the n_experts the
+    #                             router scores, and computes their part of
+    #                             the layer (`parallel.ep.moe_dropless_held`);
+    #                             empty: all of them
+    remat_layers: tuple = ()    # a layer's recomputation in the backward
+    #                             pass: "" none, "ffn" its FFN half
+
+    def __post_init__(self):
+        for name in ("attn_windows", "ffn_kinds", "experts_held",
+                     "remat_layers"):
+            value = tuple(getattr(self, name))
+            object.__setattr__(self, name, value)   # a JSON list is welcome
+            if value and name != "experts_held" and len(value) != self.n_layers:
+                raise ValueError(f"{name} has {len(value)} entries for "
+                                 f"{self.n_layers} layers")
+        if set(self.remat_layers) - {"", "ffn"}:
+            raise ValueError(f"remat_layers={self.remat_layers}: a layer "
+                             f"recomputes \"\" (nothing) or \"ffn\"")
+        if self.n_kv_heads and self.n_heads % self.n_kv_heads:
+            raise ValueError(f"n_heads={self.n_heads} is no multiple of "
+                             f"n_kv_heads={self.n_kv_heads}")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def n_experts_here(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    def layer_kind(self, i: int) -> "LayerKind":
+        sparse = self.ffn_kinds[i] == "sparse" if self.ffn_kinds \
+            else bool(self.n_experts)
+        return LayerKind(self.attn_windows[i] if self.attn_windows else 0,
+                         sparse,
+                         self.remat_layers[i] if self.remat_layers else "")
+
+
+class LayerKind(NamedTuple):
+    """What a layer's program depends on beyond the model's config."""
+    window: int         # 0: full causal attention
+    sparse: bool        # routed experts (and shared ones), else a dense FFN
+    remat: str          # "" or "ffn"
 
 
 def transformer_init(key, cfg: TransformerConfig) -> dict:
@@ -66,8 +133,8 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(cfg.dtype)
 
     keys = jax.random.split(key, 2 + 4 * cfg.n_layers)
-    d, f = cfg.d_model, cfg.d_ff
-    experts = (cfg.n_experts,) if cfg.n_experts else ()
+    d = cfg.d_model
+    hd = cfg.n_heads * cfg.head_dim         # d_model unless d_head says so
     params = {
         "embed": dense(keys[0], (cfg.vocab, d), d ** -0.5),
         "ln_f": jnp.ones((d,), cfg.dtype),
@@ -77,25 +144,47 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         params["lm_head"] = dense(keys[1], (d, cfg.vocab), d ** -0.5)
     for i in range(cfg.n_layers):
         k = keys[2 + 4 * i: 6 + 4 * i]
-        layer = {
-            "ln1": jnp.ones((d,), cfg.dtype),
-            "w_qkv": dense(k[0], (d, 3 * d), d ** -0.5),
-            "w_proj": dense(k[1], (d, d), (2 * d * cfg.n_layers) ** -0.5),
+        sparse = cfg.layer_kind(i).sparse
+        experts = (cfg.n_experts_here,) if sparse else ()
+        f = cfg.d_ff_dense or cfg.d_ff if cfg.n_experts and not sparse \
+            else cfg.d_ff
+        layer = {"ln1": jnp.ones((d,), cfg.dtype)}
+        # the leaves a public block adds draw from keys of their own, so
+        # the flagship's are the flagship's whatever else is configured
+        if cfg.n_kv_heads:
+            kv = cfg.n_kv_heads * cfg.head_dim
+            layer["w_q"] = dense(jax.random.fold_in(k[0], 1), (d, hd), d ** -0.5)
+            layer["w_k"] = dense(jax.random.fold_in(k[0], 2), (d, kv), d ** -0.5)
+            layer["w_v"] = dense(jax.random.fold_in(k[0], 3), (d, kv), d ** -0.5)
+        else:
+            layer["w_qkv"] = dense(k[0], (d, 3 * hd), d ** -0.5)
+        layer.update({
+            "w_proj": dense(k[1], (hd, d), (2 * hd * cfg.n_layers) ** -0.5),
             "ln2": jnp.ones((d,), cfg.dtype),
             "w_in": dense(k[2], experts + (d, f), d ** -0.5),
             "w_out": dense(k[3], experts + (f, d),
                            (2 * f * cfg.n_layers) ** -0.5),
-        }
-        # the leaves a public block adds draw from keys of their own, so
-        # the ones above are the flagship's whatever else is configured
+        })
         if cfg.qk_norm:
             layer["q_norm"] = jnp.ones((d,), cfg.dtype)
             layer["k_norm"] = jnp.ones((d,), cfg.dtype)
-        if cfg.n_experts:
+        if cfg.qk_norm_heads:
+            layer["q_norm"] = jnp.ones((cfg.head_dim,), cfg.dtype)
+            layer["k_norm"] = jnp.ones((cfg.head_dim,), cfg.dtype)
+        if sparse or cfg.dense_gated:
             layer["w_gate"] = dense(jax.random.fold_in(k[2], 1),
                                     experts + (d, f), d ** -0.5)
+        if sparse:
             layer["w_router"] = dense(jax.random.fold_in(k[2], 2),
                                       (d, cfg.n_experts), d ** -0.5)
+        if sparse and cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * f
+            layer["w_shared_gate"] = dense(jax.random.fold_in(k[2], 3),
+                                           (d, fs), d ** -0.5)
+            layer["w_shared_in"] = dense(jax.random.fold_in(k[2], 4),
+                                         (d, fs), d ** -0.5)
+            layer["w_shared_out"] = dense(jax.random.fold_in(k[3], 1), (fs, d),
+                                          (2 * fs * cfg.n_layers) ** -0.5)
         params["layers"].append(layer)
     return params
 
@@ -107,17 +196,32 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
     col = P(None, tp_axis)
     row = P(tp_axis, None)
     rep = P()
-    # every rank holds every expert whole (a layer with experts runs at
-    # tp 1 only: `_attn_ffn_block`)
-    layer = {"ln1": rep, "w_qkv": col, "w_proj": row, "ln2": rep,
-             "w_in": rep if cfg.n_experts else col,
-             "w_out": rep if cfg.n_experts else row}
-    if cfg.qk_norm:
-        layer.update(q_norm=P(tp_axis), k_norm=P(tp_axis))
-    if cfg.n_experts:
-        layer.update(w_gate=rep, w_router=rep)
+
+    def layer(i):
+        sparse = cfg.layer_kind(i).sparse
+        # every rank holds every expert it holds whole (a layer with
+        # experts runs at tp 1 only: `_attn_ffn_block`)
+        out = {"ln1": rep, "w_proj": row, "ln2": rep,
+               "w_in": rep if sparse else col,
+               "w_out": rep if sparse else row}
+        if cfg.n_kv_heads:
+            out.update(w_q=col, w_k=col, w_v=col)
+        else:
+            out["w_qkv"] = col
+        if cfg.qk_norm:
+            out.update(q_norm=P(tp_axis), k_norm=P(tp_axis))
+        if cfg.qk_norm_heads:
+            out.update(q_norm=rep, k_norm=rep)
+        if sparse:
+            out.update(w_gate=rep, w_router=rep)
+            if cfg.n_shared_experts:
+                out.update(w_shared_gate=rep, w_shared_in=rep,
+                           w_shared_out=rep)
+        elif cfg.dense_gated:
+            out["w_gate"] = col
+        return out
     specs = {"embed": rep, "ln_f": rep,
-             "layers": [dict(layer) for _ in range(cfg.n_layers)]}
+             "layers": [layer(i) for i in range(cfg.n_layers)]}
     if not cfg.tie_embeddings:
         specs["lm_head"] = rep
     return specs
@@ -128,11 +232,11 @@ def _rms_norm(x, scale, eps: float = 1e-6):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
-def _rope(x, positions):
+def _rope(x, positions, theta: float = 10000.0):
     """Rotary embeddings; positions are *global* so sequence shards agree."""
     b, h, t, dh = x.shape
     half = dh // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[:, None].astype(jnp.float32) * freqs[None, :]   # (t, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :half], x[..., half:]
@@ -156,7 +260,10 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
              tp_axis: Optional[str] = None, sp_axis: Optional[str] = None):
     """(logits, routed): `routed` holds, for each layer with experts, its
     router's (summed probabilities [n_experts] float32, token-slots per
-    expert [n_experts] int32) over this block's tokens; empty otherwise."""
+    expert [n_experts] int32) over this block's tokens, and where the rank
+    holds a share of the experts a third entry, what the held layer did
+    (`parallel.ep.moe_dropless_held`'s [rows computed, rows gathered,
+    further buffers ran]); empty otherwise."""
     b, t = tokens.shape
     d, h = cfg.d_model, cfg.n_heads
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
@@ -178,8 +285,9 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
     with jax.named_scope("embed"):
         x = params["embed"][tokens]                               # (b, t, d)
     routed = []
-    block = _block_traced_once(cfg, tp_axis, sp_axis, ring._kernel_backend())
     for i, layer in enumerate(params["layers"]):
+        block = _block_traced_once(cfg, cfg.layer_kind(i), tp_axis, sp_axis,
+                                   ring._kernel_backend())
         with jax.named_scope(f"layer_{i}"):
             x, sent = block(layer, x, positions)
         if sent is not None:
@@ -191,35 +299,39 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
 
 
 @functools.lru_cache(maxsize=None)
-def _block_traced_once(cfg: TransformerConfig, tp_axis: Optional[str],
-                       sp_axis: Optional[str], kernels: Optional[str]):
-    """`_attn_ffn_block` behind a `jax.jit` of its own. A model's layers
-    have one shape, and they are unrolled in Python: jitted, layers 2..n of a
-    program (and a second program over the same shapes) find layer 1's
-    trace, its linearization and its transpose where JAX keeps them, every
-    kernel body in it is traced once, and the lowered module holds one
-    function a direction, called n times (the compiler inlines the calls,
+def _block_traced_once(cfg: TransformerConfig, kind: LayerKind,
+                       tp_axis: Optional[str], sp_axis: Optional[str],
+                       kernels: Optional[str]):
+    """`_attn_ffn_block` behind a `jax.jit` of its own, one for each kind of
+    layer the model has. The layers of a kind have one shape, and they are
+    unrolled in Python: jitted, layers 2..n of a kind in a program (and a
+    second program over the same shapes) find the first one's trace, its
+    linearization and its transpose where JAX keeps them, every kernel body
+    in it is traced once, and the lowered module holds one function a kind
+    and direction, called once a layer (the compiler inlines the calls,
     and each inlined op's name gains its call's `layer_<i>`). That is what
     keeps a step's trace, which is set-up time, from growing with depth
     (PERF.md, Set-up). ``kernels`` is what `ring._kernel_backend` says: the
     kernels are selected inside the trace, so it is part of the key."""
     def block(layer, x, positions):
         return _attn_ffn_block(cfg, layer, x, positions, tp_axis=tp_axis,
-                               sp_axis=sp_axis)
+                               sp_axis=sp_axis, kind=kind)
     return jax.jit(block)
 
 
 def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
                     positions: jnp.ndarray, *, tp_axis: Optional[str],
-                    sp_axis: Optional[str]):
+                    sp_axis: Optional[str], kind: Optional[LayerKind] = None):
     """One transformer layer (pre-norm attention + FFN), tp/sp aware —
-    shared by the flat forward and the pipelined 4-axis stage. Returns the
-    layer's output and what its router sent where (None without experts)."""
+    shared by the flat forward and the pipelined 4-axis stage. ``kind`` is
+    the layer's (`cfg.layer_kind(i)`; None: layer 0's). Returns the layer's
+    output and what its router sent where (None without experts)."""
+    kind = cfg.layer_kind(0) if kind is None else kind
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
     h_local = cfg.n_heads // tp
 
     attn = functools.partial(_attn, cfg, h_local=h_local, tp_axis=tp_axis,
-                             sp_axis=sp_axis)
+                             sp_axis=sp_axis, window=kind.window)
     if cfg.remat_attn:
         # the fused kernel (a ring of one, where it is selected) keeps no
         # [b, h, s, s] scores as it is; the plain attention is recomputed
@@ -230,47 +342,79 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
             attn = jax.checkpoint(attn)
     with jax.named_scope("attn"):
         x = x + attn(layer, x, positions)
-    sent = None
-    with jax.named_scope("mlp"):
+    if kind.sparse and tp > 1:
+        # sharding an expert's width over tp needs the sums of the
+        # rows' and the weights' cotangents that `parallel/tp.py`'s
+        # operators make, and under `check_vma` they count twice
+        # (PERF.md section 7): not offered until a cell measures it
+        raise NotImplementedError(
+            "a layer with experts runs at tp 1; shard its tokens "
+            "over dp or sp")
+
+    def ffn(layer, x):
+        """What the FFN half adds to the residual, and the router's word."""
         y = _rms_norm(x, layer["ln2"], cfg.norm_eps)
-        if cfg.n_experts:
-            if tp > 1:
-                # sharding an expert's width over tp needs the sums of the
-                # rows' and the weights' cotangents that `parallel/tp.py`'s
-                # operators make, and under `check_vma` they count twice
-                # (PERF.md section 7): not offered until a cell measures it
-                raise NotImplementedError(
-                    "a layer with experts runs at tp 1; shard its tokens "
-                    "over dp or sp")
-            out, sent = _expert_ffn(cfg, layer, y)
-            x = x + out
-        elif tp_axis is not None:
-            hmid = jax.nn.gelu(column_parallel(y, layer["w_in"], axis=tp_axis))
-            x = x + row_parallel(hmid, layer["w_out"], axis=tp_axis)
-        else:
-            x = x + jax.nn.gelu(y @ layer["w_in"]) @ layer["w_out"]
+        if kind.sparse:
+            return _expert_ffn(cfg, layer, y)
+        with jax.named_scope("dense"):
+            if cfg.dense_gated:
+                if tp_axis is not None:
+                    hmid = jax.nn.silu(column_parallel(
+                        y, layer["w_gate"], axis=tp_axis)) * column_parallel(
+                            y, layer["w_in"], axis=tp_axis)
+                    return row_parallel(hmid, layer["w_out"],
+                                        axis=tp_axis), None
+                return _gated_ffn(y, layer["w_gate"], layer["w_in"],
+                                  layer["w_out"]), None
+            if tp_axis is not None:
+                hmid = jax.nn.gelu(column_parallel(y, layer["w_in"],
+                                                   axis=tp_axis))
+                return row_parallel(hmid, layer["w_out"], axis=tp_axis), None
+            return jax.nn.gelu(y @ layer["w_in"]) @ layer["w_out"], None
+    if kind.remat == "ffn":
+        ffn = jax.checkpoint(ffn)
+    with jax.named_scope("mlp"):
+        out, sent = ffn(layer, x)
+        x = x + out
     return x, sent
+
+
+def _gated_ffn(y, w_gate, w_in, w_out):
+    """out(silu(gate(y)) * in(y)): a dense gated FFN, and a shared expert."""
+    return (jax.nn.silu(y @ w_gate) * (y @ w_in)) @ w_out
 
 
 def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
     """The routed FFN of one layer: what is added to the residual, and the
-    router's (summed probabilities, token-slots) per expert. Router logits
-    from the layer's dtype accumulate in float32 and the softmax is over all
-    experts in float32; a token's top `experts_per_tok` probabilities weigh
-    its experts' outputs as they are (not renormalised). Every token-slot is
-    computed: `parallel.ep.moe_dropless` sorts the slots by expert and the
-    experts run as three grouped matrix multiplications over the row groups
-    (`parallel.ep.grouped_products`): on a TPU, at widths that are multiples
-    of 128 and a slot count that is a multiple of 128, the grouped Pallas
-    kernel (forward and both backward products); anywhere else
-    `lax.ragged_dot`."""
+    router's (summed scores, token-slots) per expert. Router logits from the
+    layer's dtype accumulate in float32 and the scores (`router_score`: a
+    softmax over all experts, or each logit's sigmoid) are float32; a
+    token's top `experts_per_tok` scores weigh its experts' outputs, as
+    they are or (`router_renorm`) divided by their sum, x `router_scale`.
+    Every token-slot is computed: `parallel.ep.moe_dropless` sorts the slots
+    by expert and the experts run as three grouped matrix multiplications
+    over the row groups (`parallel.ep.grouped_products`): on a TPU, at
+    widths that are multiples of 128 and a slot count that is a multiple of
+    128, the grouped Pallas kernel (forward and both backward products);
+    anywhere else `lax.ragged_dot`. Where the rank holds a share of the
+    experts (`experts_held`) it adds their part of the sum alone
+    (`parallel.ep.moe_dropless_held`), and a third entry says what that
+    took. A shared expert (`n_shared_experts`) is a gated FFN every token
+    runs, added beside."""
     b, t, d = y.shape
     rows = y.reshape(b * t, d)
     with jax.named_scope("router"):
         logits = jnp.dot(rows, layer["w_router"],
                          preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
+        if cfg.router_score == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
         weights, chosen = lax.top_k(probs, cfg.experts_per_tok)
+        if cfg.router_renorm:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if cfg.router_scale != 1.0:
+            weights = weights * cfg.router_scale
 
     def experts(xs, sizes):
         product = grouped_products(sizes)
@@ -278,9 +422,23 @@ def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
             product(xs, layer["w_in"])
         return product(h, layer["w_out"])
 
-    out, slots = moe_dropless(rows, chosen, weights.astype(rows.dtype),
-                              experts, cfg.n_experts)
-    return out.reshape(b, t, d), (probs.sum(axis=0), slots)
+    weights = weights.astype(rows.dtype)
+    if cfg.experts_held:
+        first, held = cfg.experts_held
+        out, slots, did = moe_dropless_held(
+            rows, chosen, weights, experts, cfg.n_experts, first, held,
+            buffer_rows=held_row_buffer(b * t * cfg.experts_per_tok,
+                                        cfg.n_experts, held))
+        sent = (probs.sum(axis=0), slots, did)
+    else:
+        out, slots = moe_dropless(rows, chosen, weights, experts,
+                                  cfg.n_experts)
+        sent = (probs.sum(axis=0), slots)
+    if cfg.n_shared_experts:
+        with jax.named_scope("shared"):
+            out = out + _gated_ffn(rows, layer["w_shared_gate"],
+                                   layer["w_shared_in"], layer["w_shared_out"])
+    return out.reshape(b, t, d), sent
 
 
 def load_balancing_loss(routed: list, n_tokens: int) -> jnp.ndarray:
@@ -288,8 +446,8 @@ def load_balancing_loss(routed: list, n_tokens: int) -> jnp.ndarray:
     all layers' routers together: n_experts x sum over experts of (token-
     slots routed there / tokens) x (mean router probability), the means
     over layers x tokens. Balanced routing gives `experts_per_tok`."""
-    prob_sum = sum(p for p, _c in routed)
-    slots = sum(c for _p, c in routed)
+    prob_sum = sum(r[0] for r in routed)
+    slots = sum(r[1] for r in routed)
     rows = len(routed) * n_tokens
     n_experts = prob_sum.shape[0]
     return n_experts * jnp.sum(
@@ -303,34 +461,61 @@ def transformer_expert_counts(cfg: TransformerConfig, params: dict,
     `experts_per_tok`: nothing is dropped. A forward pass of its own, for a
     caller to run outside whatever it times."""
     _logits, routed = _forward(cfg, params, tokens)
-    return jnp.stack([slots for _probs, slots in routed])
+    return jnp.stack([r[1] for r in routed])
+
+
+def transformer_held_counts(cfg: TransformerConfig, params: dict,
+                            tokens: jnp.ndarray):
+    """What the layers that hold a share of the experts (`experts_held`) did
+    with this batch, one row a layer with experts: ([layers, n_experts]
+    int32 token-slots the router sent to each of all its experts, [layers,
+    3] int32 rows the held experts computed, rows the layer gathered for
+    them, whether the further buffers ran). Nothing was dropped where rows
+    computed equals the slots of the held experts. A forward pass of its
+    own, as `transformer_expert_counts`."""
+    _logits, routed = _forward(cfg, params, tokens)
+    return (jnp.stack([r[1] for r in routed]),
+            jnp.stack([r[2] for r in routed]))
 
 
 def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
           positions: jnp.ndarray, *, h_local: int, tp_axis: Optional[str],
-          sp_axis: Optional[str]) -> jnp.ndarray:
+          sp_axis: Optional[str], window: int = 0) -> jnp.ndarray:
     """The attention half of a layer: what is added to the residual."""
     b, t, _ = x.shape
     dh = cfg.head_dim
     y = _rms_norm(x, layer["ln1"], cfg.norm_eps)
-    if tp_axis is not None:
-        qkv = column_parallel(y, layer["w_qkv"], axis=tp_axis)
+    if cfg.n_kv_heads:
+        if tp_axis is not None and lax.axis_size(tp_axis) > 1:
+            raise NotImplementedError(
+                "grouped-query attention runs at tp 1 (its key/value heads "
+                "are not yet cut over tp)")
+        q, k, v = (
+            (y @ layer[w]).reshape(b, t, -1, dh).transpose(0, 2, 1, 3)
+            for w in ("w_q", "w_k", "w_v"))
     else:
-        qkv = y @ layer["w_qkv"]                              # (b, t, 3d/tp)
-    # w_qkv columns are packed per head ([head][q|k|v][dh]) so a
-    # contiguous tp column shard holds whole heads and the sharded
-    # forward equals the single-device one.
-    qkv = qkv.reshape(b, t, h_local, 3, dh).transpose(0, 2, 1, 3, 4)
-    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        if tp_axis is not None:
+            qkv = column_parallel(y, layer["w_qkv"], axis=tp_axis)
+        else:
+            qkv = y @ layer["w_qkv"]                          # (b, t, 3d/tp)
+        # w_qkv columns are packed per head ([head][q|k|v][dh]) so a
+        # contiguous tp column shard holds whole heads and the sharded
+        # forward equals the single-device one.
+        qkv = qkv.reshape(b, t, h_local, 3, dh).transpose(0, 2, 1, 3, 4)
+        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
     if cfg.qk_norm:
         q = _whole_vector_norm(cfg, q, layer["q_norm"], tp_axis)
         k = _whole_vector_norm(cfg, k, layer["k_norm"], tp_axis)
-    q = _rope(q, positions)
-    k = _rope(k, positions)
+    if cfg.qk_norm_heads:
+        q = _rms_norm(q, layer["q_norm"], cfg.norm_eps)
+        k = _rms_norm(k, layer["k_norm"], cfg.norm_eps)
+    if window or cfg.rope_full_layers:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
     if sp_axis is not None:
-        o = ring_attention(q, k, v, axis=sp_axis, causal=True)
+        o = ring_attention(q, k, v, axis=sp_axis, causal=True, window=window)
     else:
-        o = local_attention(q, k, v)
+        o = local_attention(q, k, v, window)
     o = o.transpose(0, 2, 1, 3).reshape(b, t, h_local * dh)
     if tp_axis is not None:
         return row_parallel(o, layer["w_proj"], axis=tp_axis)

@@ -7,7 +7,7 @@ shapes, so variable counts become a fixed per-expert *capacity* with masking
 one ``lax.all_to_all`` ships token buffers to their experts and one ships
 results back.
 
-Three realizations live here:
+Four realizations live here:
 
 - :func:`moe_dropless` — top-k routing in which every token-slot is computed
   (the layer of the public sparse-expert models, `models/transformer.py`):
@@ -16,6 +16,10 @@ Three realizations live here:
   the shape allow it, `lax.ragged_dot` elsewhere); over an ``ep`` axis the
   row groups travel by ``lax.all_to_all`` in buffers sized for the worst
   case;
+- :func:`moe_dropless_held` — the same layer on a rank that holds a few of
+  the experts and is given no exchange to run (one chip's share of an
+  expert-parallel layer): the part of the result its experts give, from a
+  row buffer that follows the slots that arrive, nothing dropped;
 - :func:`moe_dispatch_combine` — top-1, rank == expert, tokens over a fixed
   capacity dropped (static shapes, capacity masking, ``lax.all_to_all``):
   the pipelined demo's (`transformer_pp_moe_*`);
@@ -199,6 +203,112 @@ def moe_dropless(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
     with jax.named_scope("combine"):
         back = _permute_rows(out, inverse, order).reshape(t, k, -1)
         return jnp.sum(back * weights[..., None], axis=1), sizes
+
+
+def _zeros_varying_like(x):
+    """Zeros of x's shape and dtype that vary over the mesh axes x varies
+    over: what a `scan`'s carry and a `cond`'s other branch must be typed as
+    under `shard_map`."""
+    zeros = jnp.zeros(x.shape, x.dtype)
+    axes = tuple(sorted(set(jax.typeof(x).vma) - set(jax.typeof(zeros).vma)))
+    return lax.pcast(zeros, axes, to="varying") if axes else zeros
+
+
+HELD_ROWS_FACTOR = 2.0      # the held experts' row buffer, x the rows a
+#                             balanced router sends (more arrive: further
+#                             buffers run; nothing is dropped)
+
+
+def held_row_buffer(slots: int, n_experts: int, held: int) -> int:
+    """Rows of :func:`moe_dropless_held`'s buffer for ``slots`` token-slots
+    routed over ``n_experts`` of which ``held`` are here:
+    :data:`HELD_ROWS_FACTOR` x the rows a balanced router sends, rounded up
+    to a multiple of 128 (the grouped kernel's contract), no more than all
+    slots."""
+    rows = -(-int(HELD_ROWS_FACTOR * slots * held / n_experts) // 128) * 128
+    return max(128, min(rows, -(-slots // 128) * 128))
+
+
+def moe_dropless_held(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
+                      weights: jnp.ndarray, expert_fn: Callable,
+                      n_experts: int, first: int, held: int, *,
+                      buffer_rows: int):
+    """:func:`moe_dropless` on a rank that holds experts ``[first, first +
+    held)`` of ``n_experts`` and runs no exchange: the sum over a token's
+    chosen experts *that are held here* of weights x expert(token). Slots
+    routed elsewhere add nothing (their experts' ranks add them). Same
+    ``expert_fn(rows, group_sizes)``, over ``held`` groups.
+
+    Static shapes without a capacity: the held experts' slots are sorted
+    first and served from a buffer of ``buffer_rows`` rows (what
+    :func:`held_row_buffer` gives); the rows of a token's slots are added
+    into the token's place (one scatter-add of ``buffer_rows`` rows, not a
+    gather over all t x k slots). Where more slots arrive than the buffer
+    holds, the rest is served in further buffers of the same size, behind a
+    ``lax.cond`` that the common case does not enter (recomputed in the
+    backward pass, so they keep nothing): no slot of a held expert is
+    dropped at any imbalance.
+
+    Returns ((t, d) out, (n_experts,) int32 token-slots per expert of these
+    tokens, over all experts, and (3,) int32 [rows the experts computed,
+    rows gathered, 1 if the further buffers ran])."""
+    t, k = expert_idx.shape
+    d = tokens.shape[-1]
+    slots = t * k
+    rows_max = -(-slots // buffer_rows) * buffer_rows
+    with jax.named_scope("dispatch"):
+        flat = expert_idx.reshape(slots)
+        local = flat - first
+        here = jnp.logical_and(local >= 0, local < held)
+        order = jnp.argsort(jnp.where(here, local, held),
+                            stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, rows_max - slots))
+        sizes = jnp.sum(flat[:, None] == jnp.arange(n_experts)[None, :],
+                        axis=0, dtype=jnp.int32)
+        ends = jnp.cumsum(sizes[first:first + held])
+        arrived = ends[-1]
+    flat_w = weights.reshape(slots)
+
+    def serve(n):
+        """What rows [n x buffer_rows, (n + 1) x buffer_rows) of the held
+        slots, in expert order, add to their tokens; and their count."""
+        lo = n * buffer_rows
+        with jax.named_scope("dispatch"):
+            slot = lax.dynamic_slice(order, (lo,), (buffer_rows,))
+            live = lo + jnp.arange(buffer_rows, dtype=jnp.int32) < arrived
+            token = slot // k
+            rows = tokens[token]
+            part = jnp.clip(ends - lo, 0, buffer_rows)
+            part = part - jnp.concatenate([part[:1] * 0, part[:-1]])
+        with jax.named_scope("experts"):
+            out = expert_fn(rows, part.astype(jnp.int32))
+        with jax.named_scope("combine"):
+            w = jnp.where(live, flat_w[slot], 0).astype(jnp.float32)
+            out = jnp.where(live[:, None], out.astype(jnp.float32), 0)
+            return jnp.zeros((t, d), jnp.float32).at[token].add(
+                out * w[:, None]), part.sum().astype(jnp.int32)
+
+    acc, computed = serve(0)
+    further = rows_max // buffer_rows - 1
+    if further:
+        def rest():
+            def body(carry, n):
+                add, rows = jax.checkpoint(serve)(n)
+                return (carry[0] + add, carry[1] + rows), None
+            return lax.scan(body, nothing(),
+                            jnp.arange(1, further + 1, dtype=jnp.int32))[0]
+
+        def nothing():
+            return _zeros_varying_like(acc), _zeros_varying_like(computed)
+        spilled = arrived > buffer_rows
+        more, more_rows = lax.cond(spilled, rest, nothing)
+        acc, computed = acc + more, computed + more_rows
+    else:
+        spilled = jnp.bool_(False)
+    did = jnp.stack([computed.astype(jnp.int32),
+                     buffer_rows * (1 + further * spilled.astype(jnp.int32)),
+                     spilled.astype(jnp.int32)])
+    return acc.astype(tokens.dtype), sizes, did
 
 
 def _over_expert_ranks(rows: jnp.ndarray, sizes: jnp.ndarray,

@@ -11,7 +11,7 @@ A ring of one is :func:`local_attention`, the attention of a block with
 itself: on a TPU, at a shape inside its contract, the fused Pallas kernel
 ``xla.pallas_kernels.causal_attention`` (blockwise, forward and backward, no
 [b, h, t, t] tensor in HBM); everywhere else the plain einsum / softmax /
-einsum. A ring of n > 1 keeps its XLA online softmax per step.
+einsum. Both take a window and fewer key/value heads than query heads. A ring of n > 1 keeps its XLA online softmax per step.
 """
 
 from __future__ import annotations
@@ -49,51 +49,77 @@ def warm_kernel_imports() -> None:
 
 def fused_attention_selected(shape: tuple, dtype) -> bool:
     """Whether :func:`local_attention` runs the fused kernel for (batch,
-    heads, t, head_dim) operands of ``dtype``: decided from the backend and
+    heads, t, head_dim) queries of ``dtype``: decided from the backend and
     the kernel's contract (``pallas_kernels.causal_attention_blocks``,
     ``ATTN_DTYPES``), never by trying it and catching the failure: once
-    selected, a kernel that does not lower is an error."""
+    selected, a kernel that does not lower is an error. (A window, and how
+    many heads the keys and values have, are not part of the contract: any
+    window, any divisor of the queries' heads.)"""
     from ..xla import pallas_kernels as pk
     return (_kernel_backend() is not None
             and str(jnp.dtype(dtype)) in pk.ATTN_DTYPES
             and pk.causal_attention_blocks(shape[2], shape[3]) is not None)
 
 
-def local_attention(q: jnp.ndarray, k: jnp.ndarray,
-                    v: jnp.ndarray) -> jnp.ndarray:
+def local_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    window: int = 0) -> jnp.ndarray:
     """Causal attention of a (batch, heads, t, head_dim) block with itself,
-    scaled by head_dim ** -0.5. Each call built into a traced program counts
-    in ``perfvars.snapshot()["attn_lowerings"]`` as ``fused`` or ``plain``."""
-    t, dh = q.shape[2:]
+    scaled by head_dim ** -0.5. ``window`` > 0: a query sees its last
+    ``window`` keys, itself included. k and v may hold fewer heads than q:
+    query head j reads key/value head j // (heads / key-value heads). Each
+    call built into a traced program counts in
+    ``perfvars.snapshot()["attn_lowerings"]`` as ``fused`` or ``plain``."""
     if fused_attention_selected(q.shape, q.dtype):
         from ..xla import pallas_kernels as pk
-        perfvars.note_attn_lowering("fused")
+        perfvars.note_attn_lowering("fused", window)
         return pk.causal_attention(
-            q, k, v, interpret=_kernel_backend() == "interpret")
-    perfvars.note_attn_lowering("plain")
+            q, k, v, window=window, interpret=_kernel_backend() == "interpret")
+    perfvars.note_attn_lowering("plain", window)
+    return plain_attention(q, k, v, window)
+
+
+def plain_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    window: int = 0) -> jnp.ndarray:
+    """:func:`local_attention`'s meaning as einsum / softmax / einsum: what
+    runs off the kernel's backend and contract, and what the kernel is held
+    against (tests, `chip_smoke.py`). Writes [b, h, t, t] scores."""
+    t, dh = q.shape[2:]
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     # stays in the input dtype: an f32 upcast here runs the attention
     # matmuls on the slow MXU path and cost 13% of a full bf16 train step
     # (benchmarks/flagship_probe)
     s = jnp.einsum("bhqd,bhkd->bhqk", q * dh ** -0.5, k)
     mask = jnp.tril(jnp.ones((t, t), dtype=bool))
+    if window:
+        mask = jnp.logical_and(mask, jnp.triu(jnp.ones((t, t), dtype=bool),
+                                              1 - window))
     s = jnp.where(mask, s, NEG_INF)
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
 
 
 def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                    axis: str = "sp", causal: bool = True,
-                   scale: Optional[float] = None) -> jnp.ndarray:
+                   scale: Optional[float] = None,
+                   window: int = 0) -> jnp.ndarray:
     """Blockwise-exact attention over a sequence-sharded axis.
 
     q, k, v: (batch, heads, block_len, head_dim) — the local sequence block.
     Block b of the global sequence lives on rank b of ``axis``. Returns the
-    local attention output block (same shape as q).
+    local attention output block (same shape as q). A ``window`` or fewer
+    key/value heads than query heads are :func:`local_attention`'s, a ring
+    of one; a longer ring has neither yet.
     """
     b, h, t, d = q.shape
     n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     if n == 1 and causal and scale is None:
-        return local_attention(q, k, v)
+        return local_attention(q, k, v, window)
+    if window or k.shape[1] != h:
+        raise NotImplementedError(
+            "ring attention over more than one sequence shard takes no "
+            "window and as many key/value heads as query heads")
     scale = (d ** -0.5) if scale is None else scale
     q = q * scale
 

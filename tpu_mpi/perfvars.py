@@ -535,13 +535,27 @@ def arming_seconds() -> float:
 # run can say which one its compiled steps hold.
 
 _attn_lowerings = {"fused": 0, "plain": 0}
+# the same calls by the attention's kind, for a model whose layers differ:
+# {"full" | "window": {"fused": n, "plain": n}}
+_attn_by_kind: Dict[str, Dict[str, int]] = {}
 
 
-def note_attn_lowering(kind: str) -> None:
+def note_attn_lowering(kind: str, window: int = 0) -> None:
     """One attention call was built into a traced program as the ``fused``
-    kernel or as the ``plain`` path."""
+    kernel or as the ``plain`` path; ``window`` > 0 says it was a windowed
+    one."""
     with _store_lock:
         _attn_lowerings[kind] += 1
+        by = _attn_by_kind.setdefault("window" if window else "full",
+                                      {"fused": 0, "plain": 0})
+        by[kind] += 1
+
+
+def _attn_kinds() -> dict:
+    """{"full" | "window": "fused" | "plain" | "mixed"} of the attention
+    kinds traced so far (caller holds the store lock)."""
+    return {k: ("fused" if not n["plain"] else "plain" if not n["fused"]
+                else "mixed") for k, n in sorted(_attn_by_kind.items())}
 
 
 # `parallel.ep.grouped_products` likewise: the grouped Pallas kernel or
@@ -1058,6 +1072,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "comms": comms, "plan_cache": plans.stats(),
             "arming_s": arming_seconds(),
             "attn_lowerings": dict(_attn_lowerings),
+            "attn_kinds": _attn_kinds(),
             "gmm_lowerings": dict(_gmm_lowerings),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
@@ -1100,6 +1115,7 @@ def reset() -> None:
         _locks.clear()
         _arming.clear()
         _attn_lowerings.update(fused=0, plain=0)
+        _attn_by_kind.clear()
         _gmm_lowerings.update(kernel=0, ragged_dot=0)
         _store_gen += 1
 

@@ -720,7 +720,8 @@ def causal_attention_blocks(t: int, dh: int) -> Optional[tuple]:
     multiple of a block, ``dh`` 64 or a multiple of 128 (a head is the MXU's
     contraction and the minor dimension of every operand block), and the
     backward pass's working set, which holds one head's whole dq, inside
-    :data:`VMEM_LIMIT_BYTES`."""
+    :data:`VMEM_LIMIT_BYTES`. A windowed call takes the same blocks (sweep:
+    PERF.md)."""
     if dh != 64 and dh % LANE:
         return None
     block = next((b for b in _ATTN_BLOCKS if t % b == 0), None)
@@ -768,31 +769,87 @@ def _attn_precision(dtype):
             else jax.lax.Precision.DEFAULT)
 
 
-def _causal_pair(qi, ki, bq: int, bk: int):
+def _causal_pair(qi, ki, bq: int, bk: int, window: int = 0):
     """(below, crosses) of the pair (query block qi, key block ki): wholly
     below the diagonal (every key seen by every query, no mask needed), or
     on it (masked by position). A pair that is neither lies wholly above
-    the diagonal and runs nothing."""
+    the diagonal and runs nothing. Under a ``window`` a second rule: a pair
+    whose every key lies before every query's window runs nothing either,
+    and one is unmasked only if every key is inside every query's window."""
     import jax.numpy as jnp
     below = ki * bk + (bk - 1) <= qi * bq
-    crosses = jnp.logical_and(ki * bk <= qi * bq + (bq - 1),
-                              jnp.logical_not(below))
-    return below, crosses
+    seen = ki * bk <= qi * bq + (bq - 1)
+    if window:
+        below = jnp.logical_and(
+            below, ki * bk >= qi * bq + (bq - 1) - (window - 1))
+        seen = jnp.logical_and(
+            seen, ki * bk + (bk - 1) >= qi * bq - (window - 1))
+    return below, jnp.logical_and(seen, jnp.logical_not(below))
 
 
-def _attn_fwd_kernel(scale: float, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                     m_ref, l_ref, acc_ref):
+def _visible(rows, cols, window: int):
+    """The mask of a pair on the band: key <= query, and under a window
+    query - key < window."""
+    import jax.numpy as jnp
+    if not window:
+        return rows >= cols
+    return jnp.logical_and(rows >= cols, rows - cols < window)
+
+
+def _first_key_block(qi, bq: int, bk: int, window: int):
+    """The first key block a query block's walk visits under a window: the
+    block of its first query's first key."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    return jax.lax.div(jnp.maximum(qi * bq - (window - 1), 0), np.int32(bk))
+
+
+def _first_query_block(kj, bq: int, bk: int, window: int):
+    """The first query block a key block's walk visits in the backward
+    pass under a window: the block of its first key. (Without a window a
+    walk is over all blocks and the ones above the diagonal are skipped
+    where they stand.)"""
+    import jax
+    import numpy as np
+    return jax.lax.div(kj * bk, np.int32(bq))
+
+
+def causal_attention_walk(t: int, bq: int, bk: int, window: int = 0) -> tuple:
+    """(key blocks a query block's walk is long, query blocks a key block's
+    walk is long, pairs of blocks that run) for one head: the two inner
+    grid axes, forward and backward, and what a count of the kernel's
+    products as executed multiplies by. Without a window a walk is over all
+    blocks (the ones above the diagonal run nothing); under one it starts
+    at the band and is as long as the band's widest crossing."""
+    nq, nk = t // bq, t // bk
+
+    def runs(qi, ki):
+        seen = ki * bk <= qi * bq + (bq - 1)
+        return seen and (not window
+                         or ki * bk + (bk - 1) >= qi * bq - (window - 1))
+    pairs = sum(runs(qi, ki) for qi in range(nq) for ki in range(nk))
+    if not window:
+        return nk, nq, pairs
+    span_k = max(sum(runs(qi, ki) for ki in range(nk)) for qi in range(nq))
+    span_q = max(sum(runs(qi, kj) for qi in range(nq)) for kj in range(nk))
+    return span_k, span_q, pairs
+
+
+def _attn_fwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, o_ref,
+                     lse_ref, m_ref, l_ref, acc_ref):
     import jax
     import jax.numpy as jnp
     import numpy as np
     pl = _pl()
     bq, dh = q_ref.shape[2:]
     bk = k_ref.shape[2]
-    qi, ki = pl.program_id(2), pl.program_id(3)
+    qi, step = pl.program_id(2), pl.program_id(3)
+    ki = step + _first_key_block(qi, bq, bk, window) if window else step
     prec = _attn_precision(q_ref.dtype)
 
-    @pl.when(ki == 0)
-    def _first_key_block():
+    @pl.when(step == 0)
+    def _first_step():
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -805,10 +862,14 @@ def _attn_fwd_kernel(scale: float, q_ref, k_ref, v_ref, o_ref, lse_ref,
         if on_diagonal:
             rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(rows >= cols, s, np.float32(_MASKED))
+            s = jnp.where(_visible(rows, cols, window), s,
+                          np.float32(_MASKED))
         # key block 0 runs first and holds key 0, which every query sees:
         # from then on the running max is finite and a masked score's
-        # exponential is exactly 0
+        # exponential is exactly 0. (Under a window a row may see nothing
+        # of its walk's first block: its sum then holds exp(0) a key until
+        # the block with the query's own key multiplies it by exp(-1e30 -
+        # max) = 0, which every row's walk reaches.)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_next, bk))
@@ -819,12 +880,12 @@ def _attn_fwd_kernel(scale: float, q_ref, k_ref, v_ref, o_ref, lse_ref,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)
 
-    below, crosses = _causal_pair(qi, ki, bq, bk)
+    below, crosses = _causal_pair(qi, ki, bq, bk, window)
     pl.when(below)(lambda: pair(False))
     pl.when(crosses)(lambda: pair(True))
 
-    @pl.when(ki == pl.num_programs(3) - 1)
-    def _last_key_block():
+    @pl.when(step == pl.num_programs(3) - 1)
+    def _last_step():
         l = l_ref[...]
         o_ref[0, 0] = (acc_ref[...] * _lanes(1.0 / l, dh)).astype(o_ref.dtype)
         # the rows' log-sum-exp leaves as one row along the lanes, which is
@@ -832,30 +893,34 @@ def _attn_fwd_kernel(scale: float, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = jnp.transpose(m_ref[...] + jnp.log(l))[:1, :]
 
 
-def _attn_bwd_kernel(scale: float, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                     di_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+def _attn_bwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, do_ref,
+                     lse_ref, di_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
+                     dv_acc):
     """One (key block, query block) pair of the backward pass, transposed:
     scores are [keys, queries], so a query's log-sum-exp and row-sum are
     rows along the lanes and four of the five products need no transpose.
     dk and dv accumulate over the query blocks (the inner grid axis); dq of
-    the whole head stays in VMEM over both axes."""
+    the whole head stays in VMEM over both axes. Under a window a key
+    block's walk starts at its own first query block and may run past the
+    last one: such a step runs nothing."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     pl = _pl()
     bq, dh = q_ref.shape[2:]
     bk = k_ref.shape[2]
-    kj, qi = pl.program_id(2), pl.program_id(3)
+    kj, step = pl.program_id(2), pl.program_id(3)
+    qi = step + _first_query_block(kj, bq, bk, window) if window else step
     prec = _attn_precision(q_ref.dtype)
     nt = (((1,), (1,)), ((), ()))
     nn = (((1,), (0,)), ((), ()))
 
-    @pl.when(jnp.logical_and(kj == 0, qi == 0))
+    @pl.when(jnp.logical_and(kj == 0, step == 0))
     def _first_pair():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
-    @pl.when(qi == 0)
-    def _first_query_block():
+    @pl.when(step == 0)
+    def _first_step():
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
@@ -866,7 +931,8 @@ def _attn_bwd_kernel(scale: float, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if on_diagonal:
             keys = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
             rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
-            s = jnp.where(rows >= keys, s, np.float32(_MASKED))
+            s = jnp.where(_visible(rows, keys, window), s,
+                          np.float32(_MASKED))
         p = jnp.exp(s - lse_ref[0, 0])
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, nn, preferred_element_type=jnp.float32,
@@ -881,11 +947,15 @@ def _attn_bwd_kernel(scale: float, q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)
 
-    below, crosses = _causal_pair(qi, kj, bq, bk)
+    below, crosses = _causal_pair(qi, kj, bq, bk, window)
+    if window:      # a walk's steps past the last query block
+        inside = qi * bq < dq_acc.shape[0]
+        below = jnp.logical_and(below, inside)
+        crosses = jnp.logical_and(crosses, inside)
     pl.when(below)(lambda: pair(False))
     pl.when(crosses)(lambda: pair(True))
 
-    last_q = qi == pl.num_programs(3) - 1
+    last_q = step == pl.num_programs(3) - 1
 
     @pl.when(last_q)
     def _last_query_block():
@@ -918,32 +988,40 @@ def _vary_together(*xs):
     return out
 
 
-def _attn_forward(q, k, v, bq: int, bk: int, interpret: Optional[bool]):
-    """(o, log-sum-exp [b, h, 1, t] float32) of causal attention."""
+def _attn_forward(q, k, v, bq: int, bk: int, interpret: Optional[bool],
+                  window: int = 0):
+    """(o, log-sum-exp [b, h, 1, t] float32) of causal attention; k and v
+    may hold fewer heads than q (query head j reads head j // group)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     pl, pltpu = _pl(), _pltpu()
     b, h, t, dh = q.shape
+    group = h // k.shape[1]
+    span = causal_attention_walk(t, bq, bk, window)[0]
 
     # index arithmetic stays int32 under jax_enable_x64 too (Mosaic has no
     # 64-bit scalars): `lax.div` and an int32 zero, not `//` and a literal
     zero = np.int32(0)
 
-    def key_block(bi, hi, qi, ki):
+    def kv_head(hi):
+        return hi if group == 1 else jax.lax.div(hi, np.int32(group))
+
+    def key_block(bi, hi, qi, step):
         # a skipped pair asks for the block already there: no copy
         last = jax.lax.div(qi * bq + (bq - 1), np.int32(bk))
-        return bi, hi, jnp.minimum(ki, last), zero
+        ki = step + _first_key_block(qi, bq, bk, window) if window else step
+        return bi, kv_head(hi), jnp.minimum(ki, last), zero
 
     q_spec = pl.BlockSpec((1, 1, bq, dh),
-                          lambda bi, hi, qi, ki: (bi, hi, qi, zero))
+                          lambda bi, hi, qi, step: (bi, hi, qi, zero))
     kv_spec = pl.BlockSpec((1, 1, bk, dh), key_block)
     return pl.pallas_call(
-        functools.partial(_attn_fwd_kernel, dh ** -0.5),
-        grid=(b, h, t // bq, t // bk),
+        functools.partial(_attn_fwd_kernel, dh ** -0.5, window),
+        grid=(b, h, t // bq, span),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, pl.BlockSpec(
-            (1, 1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, zero, qi))],
+            (1, 1, 1, bq), lambda bi, hi, qi, step: (bi, hi, zero, qi))],
         out_shape=[_varying_like(q, q.shape, q.dtype),
                    _varying_like(q, (b, h, 1, t), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, LANE), jnp.float32),    # running max
@@ -959,68 +1037,92 @@ def _attn_forward(q, k, v, bq: int, bk: int, interpret: Optional[bool]):
 
 
 def _attn_backward(q, k, v, o, lse, do, bq: int, bk: int,
-                   interpret: Optional[bool]):
+                   interpret: Optional[bool], window: int = 0):
+    """(dq, dk, dv). Where k and v hold fewer heads than q the kernel
+    writes every query head's dk and dv (in float32) and the group's are
+    summed here: one pass over [b, h, t, dh] beside five products over it."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     pl, pltpu = _pl(), _pltpu()
     b, h, t, dh = q.shape
+    hk = k.shape[1]
+    group = h // hk
+    span = causal_attention_walk(t, bq, bk, window)[1]
     di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
 
     zero = np.int32(0)          # int32 index arithmetic, as in the forward
 
-    def first(kj, qi):
-        # a skipped pair asks for the key block's first query block
-        return jnp.maximum(qi, jax.lax.div(kj * bk, np.int32(bq)))
+    def kv_head(hi):
+        return hi if group == 1 else jax.lax.div(hi, np.int32(group))
 
-    q_spec = pl.BlockSpec((1, 1, bq, dh),
-                          lambda bi, hi, kj, qi: (bi, hi, first(kj, qi), zero))
-    kv_spec = pl.BlockSpec((1, 1, bk, dh),
-                           lambda bi, hi, kj, qi: (bi, hi, kj, zero))
-    row_spec = pl.BlockSpec((1, 1, 1, bq),
-                            lambda bi, hi, kj, qi: (bi, hi, zero, first(kj, qi)))
+    def query_block(kj, step):
+        # a skipped pair asks for a block the walk has had or will have
+        first = jax.lax.div(kj * bk, np.int32(bq))
+        if not window:
+            return jnp.maximum(step, first)
+        return jnp.minimum(first + step, np.int32(t // bq - 1))
+
+    q_spec = pl.BlockSpec(
+        (1, 1, bq, dh),
+        lambda bi, hi, kj, step: (bi, hi, query_block(kj, step), zero))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, dh), lambda bi, hi, kj, step: (bi, kv_head(hi), kj, zero))
+    dkv_spec = pl.BlockSpec((1, 1, bk, dh),
+                            lambda bi, hi, kj, step: (bi, hi, kj, zero))
+    row_spec = pl.BlockSpec(
+        (1, 1, 1, bq),
+        lambda bi, hi, kj, step: (bi, hi, zero, query_block(kj, step)))
     head_spec = pl.BlockSpec((1, 1, t, dh),
-                             lambda bi, hi, kj, qi: (bi, hi, zero, zero))
-    return pl.pallas_call(
-        functools.partial(_attn_bwd_kernel, dh ** -0.5),
-        grid=(b, h, t // bk, t // bq),
+                             lambda bi, hi, kj, step: (bi, hi, zero, zero))
+    dkv = _varying_like(q, q.shape, q.dtype if group == 1 else jnp.float32)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_attn_bwd_kernel, dh ** -0.5, window),
+        grid=(b, h, t // bk, span),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[head_spec, kv_spec, kv_spec],
-        out_shape=[_varying_like(q, q.shape, q.dtype)] * 3,
+        out_specs=[head_spec, dkv_spec, dkv_spec],
+        out_shape=[_varying_like(q, q.shape, q.dtype), dkv, dkv],
         scratch_shapes=[pltpu.VMEM((t, dh), jnp.float32),
                         pltpu.VMEM((bk, dh), jnp.float32),
                         pltpu.VMEM((bk, dh), jnp.float32)],
         interpret=_interpret(interpret),
         compiler_params=_compiler_params(
-            None, _attn_bwd_vmem(t, dh, bq, bk, q.dtype.itemsize),
+            None, _attn_bwd_vmem(t, dh, bq, bk, 4 if group > 1
+                                 else q.dtype.itemsize),
             "causal_attention",
             ("parallel", "parallel", "arbitrary", "arbitrary")),
         name="causal_attention_bwd",
     )(q, k, v, do, lse, di[:, :, None, :])
+    if group > 1:
+        dk, dv = (g.reshape(b, hk, group, t, dh).sum(axis=2).astype(k.dtype)
+                  for g in (dk, dv))
+    return dq, dk, dv
 
 
 @functools.lru_cache(maxsize=None)
-def _causal_attention_fn(bq: int, bk: int, interpret: Optional[bool]):
-    """The differentiable kernel at one block size, jitted once: every layer
-    of a step that calls it shares one lowering."""
+def _causal_attention_fn(bq: int, bk: int, interpret: Optional[bool],
+                         window: int = 0):
+    """The differentiable kernel at one block size and window, jitted once:
+    every layer of a step that calls it shares one lowering."""
     import jax
 
     @jax.custom_vjp
     def attend(q, k, v):
-        return _attn_forward(q, k, v, bq, bk, interpret)[0]
+        return _attn_forward(q, k, v, bq, bk, interpret, window)[0]
 
     def fwd(q, k, v):
-        o, lse = _attn_forward(q, k, v, bq, bk, interpret)
+        o, lse = _attn_forward(q, k, v, bq, bk, interpret, window)
         return o, (q, k, v, o, lse)
 
     def bwd(kept, do):
-        return _attn_backward(*kept, do, bq, bk, interpret)
+        return _attn_backward(*kept, do, bq, bk, interpret, window)
 
     attend.defvjp(fwd, bwd)
     return jax.jit(attend)
 
 
-def causal_attention(q, k, v, *, block_q: Optional[int] = None,
+def causal_attention(q, k, v, *, window: int = 0,
+                     block_q: Optional[int] = None,
                      block_k: Optional[int] = None,
                      interpret: Optional[bool] = None):
     """softmax(q k^T / sqrt(dh), keys <= query) v of (batch, heads, t, dh)
@@ -1030,6 +1132,15 @@ def causal_attention(q, k, v, *, block_q: Optional[int] = None,
     scores stay float32 through the softmax and the probabilities are
     rounded to the operand dtype only as a product's operand. Key blocks
     wholly above the diagonal run nothing, the ones on it mask by position.
+
+    ``window`` > 0: a query at position p sees keys p - window + 1 .. p.
+    Key blocks wholly before a query block's window run nothing either, in
+    both directions, and are not grid steps: a block's walk starts at the
+    band and is as long as the band's widest crossing
+    (:func:`causal_attention_walk`). k and v may hold fewer heads than q, a
+    divisor of its count: query head j reads key/value head j // (heads /
+    key-value heads), by the blocks' index maps alone (nothing is repeated
+    in HBM), and its dk and dv are the sums over the group's query heads.
 
     Differentiable: the forward pass keeps o and the rows' log-sum-exp
     ([b, h, 1, t] float32: a row along the lanes, as the backward reads it);
@@ -1055,9 +1166,14 @@ def causal_attention(q, k, v, *, block_q: Optional[int] = None,
     if t % block_q or t % block_k:
         raise ValueError(f"causal_attention: blocks ({block_q}, {block_k}) "
                          f"do not divide t = {t}")
-    if not (q.shape == k.shape == v.shape and q.dtype == k.dtype == v.dtype):
-        raise ValueError("causal_attention: q, k, v differ in shape or dtype")
-    return _causal_attention_fn(block_q, block_k, interpret)(q, k, v)
+    if not (k.shape == v.shape and q.dtype == k.dtype == v.dtype
+            and q.shape[:1] + q.shape[2:] == k.shape[:1] + k.shape[2:]
+            and q.shape[1] % k.shape[1] == 0):
+        raise ValueError("causal_attention: q, k, v differ in dtype or in "
+                         "shape beyond a whole number of query heads a "
+                         "key/value head")
+    return _causal_attention_fn(block_q, block_k, interpret,
+                                int(window))(q, k, v)
 
 
 # ---------------------------------------------------------------------------
