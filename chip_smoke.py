@@ -18,7 +18,9 @@ script starts no child that needs it):
    experts' grouped product compiled by Mosaic, numerics against the XLA
    collective, ``lax.ragged_dot`` (values and both gradients) or a jnp
    reference; an OLMoE-shaped expert layer takes the kernel's route and
-   compiles to the kernels alone;
+   compiles to the kernels alone, and its rows' way into expert order and
+   back (`parallel.ep.moe_dropless`) agrees with the plain form, forward
+   and backward, the four passes timed;
 4. serve  — ``serve.Broker(nranks=4, infer=True)`` answering three
    ``session.generate`` calls. The engine is host numpy by design (ROADMAP
    S3): this leg proves the broker, the event front door and the native
@@ -73,6 +75,8 @@ FULL = {
     "grouped": (8192, 2048, 1024, 16),  # rows, k, n, groups of one product
     # an expert layer at OLMoE's widths: d_model, d_ff, experts, top, seq
     "expert_layer": (2048, 1024, 64, 8, 4096),
+    # a layer's row movement at OLMoE's shape: tokens, d_model, experts, top
+    "expert_rows": (8192, 2048, 64, 8),
     # heads, key/value heads, seq, head_dim, window of one attention block
     "window_attn": (16, 2, 4096, 128, 128),
     "max_new": 8,
@@ -87,6 +91,7 @@ TINY = {
     "attn": (32, 64),
     "grouped": (256, 128, 128, 5),
     "expert_layer": (128, 256, 4, 2, 64),
+    "expert_rows": (64, 128, 4, 2),
     "window_attn": (4, 2, 256, 128, 100),
     "max_new": 4,
 }
@@ -596,7 +601,79 @@ def leg_kernels(sz: dict, platform: str) -> dict:
     if not interpret:
         # at OLMoE's widths on the chip: the kernel in all nine products
         assert (route, calls) == ("kernel", 9), (route, calls)
+    facts["expert_rows"] = _expert_rows_way(sz["expert_rows"],
+                                            on_chip=not interpret)
     return facts
+
+
+def _expert_rows_way(sizes: tuple, on_chip: bool) -> dict:
+    """One layer's row movement (tokens, d_model, experts, top-k; bfloat16):
+    `parallel.ep.moe_dropless` around experts that only weigh their rows,
+    value and the gradients to tokens and weights against the plain form
+    (tokens[order // k], the rows permuted back, times weights, summed),
+    and each of the four passes alone: milliseconds and GB/s over the
+    bytes XLA's pass moves (R = slots x d x 2 bytes: a copy into expert
+    order reads and writes R; a sum back writes the rows it gathered,
+    reads them again and writes the tokens, 3.125 R at top-8). Off the
+    chip the passes run once and their times are not reported."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from tpu_mpi.parallel import ep
+    t, d, experts, k = sizes
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    tokens, c = (jax.random.normal(kk, (t, d), jnp.float32).astype(jnp.bfloat16)
+                 for kk in keys[:2])
+    weights, idx = lax.top_k(jax.nn.softmax(
+        2.0 * jax.random.normal(keys[2], (t, experts))), k)
+    weights, idx = weights.astype(jnp.bfloat16), idx.astype(jnp.int32)
+
+    def weigh(rows, _sizes, scale):
+        return rows * scale[:, None]
+
+    def plain(tokens, weights):
+        flat = idx.reshape(t * k)
+        order = jnp.argsort(flat, stable=True)
+        back = tokens[order // k][jnp.argsort(order)].reshape(t, k, d)
+        return jnp.sum(back * weights[..., None], axis=1)
+
+    def both(layer):
+        def total(tokens, weights):
+            y = layer(tokens, weights)
+            return jnp.sum(y.astype(jnp.float32) * c.astype(jnp.float32)), y
+        return jax.jit(jax.grad(total, argnums=(0, 1), has_aux=True))
+    (gt, gw), y = both(lambda tokens, weights: ep.moe_dropless(
+        tokens, idx, weights, weigh, experts)[0])(tokens, weights)
+    (wt, ww), want = both(plain)(tokens, weights)
+    out = {"rel_err": {}, "passes": {}}
+    for name, a, b in zip(("out", "d_tokens", "d_weights"), (y, gt, gw),
+                          (want, wt, ww)):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        # bf16 on both sides; the plain form rounds every product before it
+        # sums, the layer sums in float32 and rounds once
+        assert rel < 3e-2, f"moe_dropless {name} off by {rel}"
+        out["rel_err"][name] = rel
+    order, _scale = ep._by_expert(weights, idx.reshape(t * k))
+    inverse = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
+    rows = jax.random.normal(keys[3], (t * k, d),
+                             jnp.float32).astype(jnp.bfloat16)
+    r = t * k * d * 2
+    for name, fn, arg, nbytes in (
+            ("dispatch forward", ep._rows_of_tokens, tokens, 2 * r),
+            ("combine forward", ep._tokens_of_rows, rows, (3 + 1 / k) * r),
+            ("combine backward", ep._rows_of_tokens, c, 2 * r),
+            ("dispatch backward", ep._tokens_of_rows, rows, (3 + 1 / k) * r)):
+        run = jax.jit(fn)
+        jax.block_until_ready(run(arg, order, inverse))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            got = run(arg, order, inverse)
+        jax.block_until_ready(got)
+        ms = (time.perf_counter() - t0) / 5 * 1e3
+        out["passes"][name] = {"ms": round(ms, 3), "GB/s": round(
+            nbytes / ms / 1e6, 1)} if on_chip else "not measured"
+    return out
 
 
 def _expert_layer_route(sizes: tuple) -> tuple:

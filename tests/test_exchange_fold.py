@@ -293,8 +293,10 @@ def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(
     backend selects) recomputed in the backward pass it needs 10.5 GB of
     the chip's 16 (15.4 GB with the scores of four layers kept, PR 25);
     with the fused kernel selected as on a TPU (PR 26) nothing is
-    recomputed, no [b, h, s, s] buffer exists, it needs 10.3 GB (11.4 GB
-    while the experts' weights were re-laid for `ragged-dot`), and the
+    recomputed, no [b, h, s, s] buffer exists, it needs 9.4 GB (11.4 GB
+    while the experts' weights were re-laid for `ragged-dot`, 10.2 GB while
+    every layer kept its experts' permuted output for the router weights'
+    gradient, until PR 31), and the
     kernel's calls carry their layer's `attn` scope, forward and backward.
     The experts' grouped multiplications are never a dense product over all
     64 experts: on the plain side the compiler's own `ragged-dot` kernel,
@@ -343,7 +345,7 @@ def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(
         assert 9e9 < held < 11e9, held
         assert "bf16[2,16,4096,4096]" in hlo and not calls
     else:
-        assert 9.5e9 < held < 11.5e9, held
+        assert 8.9e9 < held < 9.9e9, held
         assert ",4096,4096]" not in hlo
         assert all("/attn/" in c for c in calls)
         where = sorted(("transpose(" in c, int(re.search(

@@ -13,6 +13,7 @@ renormalised weights, no QK-norm, a bfloat16 router softmax, dropped tokens)
 misses the loss by over 1e-3: a hundred times the tolerance."""
 
 import dataclasses
+import functools
 import os
 import sys
 import zlib
@@ -274,10 +275,10 @@ def test_dropping_over_capacity_would_miss_the_reference():
 def layer_over_ep(n: int, rows, idx, weights, w):
     """`moe_dropless` with tokens and experts sharded over `n` devices."""
     def experts_of(w_in, w_gate, w_out):
-        def run(xs, sizes):
+        def run(xs, sizes, scale):
             hid = jax.nn.silu(lax.ragged_dot(xs, w_gate, sizes)) * \
                 lax.ragged_dot(xs, w_in, sizes)
-            return lax.ragged_dot(hid, w_out, sizes)
+            return lax.ragged_dot(hid * scale[:, None], w_out, sizes)
         return run
     if n == 1:
         return moe_dropless(rows, idx, weights, experts_of(*w), CFG.n_experts)
@@ -481,3 +482,151 @@ def test_the_default_config_is_the_flagship_bit_for_bit(dtype):
     np.testing.assert_array_equal(
         jax.jit(lambda p: transformer_forward(cfg, p, tokens))(params),
         jax.jit(lambda p: _flagship_forward_before(cfg, p, tokens))(before))
+
+
+# -- a row's way into expert order and back (PR 31) ----------------------------
+#
+# `moe_dropless` against the plain form it replaced, written out with no
+# custom gradient: tokens[order // k], the experts' rows permuted back
+# (out[inverse]) as [t, k, d], times the weights, summed over k. Float32
+# agrees to 1e-6 of the largest entry (the same products, sums in another
+# order); in bfloat16 the two differ by their roundings (the plain form
+# rounds the [t, k, d] product and the gradient's rows before they are
+# summed, `moe_dropless` accumulates in float32 and rounds once, but rounds
+# weight x hidden before the last product), so each must lie within
+# bfloat16's rounding of the float32 answer through a chain of three
+# products: 2^-5 of the largest entry (the worst case below reads 2^-5.8).
+
+WAY_E, WAY_D, WAY_F, WAY_T = 8, 32, 48, 40
+WAY_TOL = {"float32": 1e-6, "bfloat16": 2.0 ** -5}
+ROUTERS = ("spread", "leaves experts empty", "all to the same")
+
+
+def way_experts(w_gate, w_in, w_out):
+    def run(xs, sizes, scale):
+        hid = jax.nn.silu(lax.ragged_dot(xs, w_gate, sizes)) * \
+            lax.ragged_dot(xs, w_in, sizes)
+        return lax.ragged_dot(hid * scale[:, None].astype(hid.dtype),
+                              w_out, sizes)
+    return run
+
+
+def plain_way(tokens, idx, weights, w):
+    t, k = idx.shape
+    flat = idx.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.sum(flat[:, None] == jnp.arange(WAY_E)[None, :], axis=0,
+                    dtype=jnp.int32)
+    out = way_experts(*w)(tokens[order // k], sizes,
+                          jnp.ones(t * k, tokens.dtype))
+    back = out[inverse].reshape(t, k, -1)
+    return jnp.sum(back * weights[..., None], axis=1)
+
+
+def new_way(tokens, idx, weights, w, n: int = 1):
+    if n == 1:
+        return moe_dropless(tokens, idx, weights, way_experts(*w), WAY_E)[0]
+    mesh = xla.make_mesh({"ep": n}, devices=jax.devices()[:n])
+
+    def local(tokens, idx, weights, *w):
+        return moe_dropless(tokens, idx, weights, way_experts(*w), WAY_E,
+                            axis="ep")[0]
+    return jax.shard_map(local, mesh=mesh, in_specs=(P("ep"),) * 6,
+                         out_specs=P("ep"))(tokens, idx, weights, *w)
+
+
+def way_inputs(dtype: str, k: int, router: str):
+    key = jax.random.key(zlib.crc32(f"{dtype}/{k}/{router}".encode()))
+    fold = lambda i: jax.random.fold_in(key, i)
+    tokens = jax.random.normal(fold(0), (WAY_T, WAY_D))
+    logits = jax.random.normal(fold(1), (WAY_T, WAY_E))
+    if router == "leaves experts empty":        # experts 2, 5 and 7 get none
+        logits = logits.at[:, jnp.array([2, 5, 7])].set(-1e9) \
+            if k <= WAY_E - 3 else logits
+    elif router == "all to the same":           # every token the same k
+        logits = logits + 1e3 * (jnp.arange(WAY_E) < k)
+    weights, idx = lax.top_k(jax.nn.softmax(logits), k)
+    w = tuple(0.3 * jax.random.normal(fold(2 + i), s) for i, s in enumerate(
+        [(WAY_E, WAY_D, WAY_F), (WAY_E, WAY_D, WAY_F), (WAY_E, WAY_F, WAY_D)]))
+    cast = lambda a: a.astype(jnp.dtype(dtype))
+    return cast(tokens), idx.astype(jnp.int32), cast(weights), \
+        tuple(cast(m) for m in w)
+
+
+@functools.lru_cache(maxsize=None)
+def ways(dtype: str, k: int, router: str, n: int = 1) -> dict:
+    """{what: (moe_dropless's, the plain form's, the plain form's in
+    float32)} for the value and the three gradients of sum(value x c)."""
+    tokens, idx, weights, w = way_inputs(dtype, k, router)
+    c = jax.random.normal(jax.random.key(5), (WAY_T, WAY_D))
+
+    def both(way, tokens, weights, w, **kw):
+        def total(tokens, weights, w):
+            y = way(tokens, idx, weights, w, **kw)
+            return jnp.sum(y.astype(jnp.float32) * c), y
+        grads, y = jax.jit(jax.grad(total, argnums=(0, 1, 2),
+                                    has_aux=True))(tokens, weights, w)
+        return dict(zip(("value", "tokens", "weights", "matrices"),
+                        (y,) + grads))
+    f32 = lambda a: jax.tree.map(lambda x: x.astype(jnp.float32), a)
+    got = both(new_way, tokens, weights, w, n=n)
+    want = both(plain_way, tokens, weights, w)
+    exact = both(plain_way, f32(tokens), f32(weights), f32(w))
+    return {what: (got[what], want[what], exact[what]) for what in got}
+
+
+def assert_the_same_way(got, want, exact, dtype):
+    for g, w, e in zip(*(jax.tree.leaves(a) for a in (got, want, exact))):
+        g, w, e = (np.asarray(a, np.float32) for a in (g, w, e))
+        assert g.shape == e.shape
+        size = max(np.abs(e).max(), 1e-30)
+        if dtype == "float32":
+            np.testing.assert_allclose(g, w, atol=WAY_TOL[dtype] * size,
+                                       rtol=0)
+        else:       # both within the dtype's rounding of the exact answer
+            assert np.abs(g - e).max() <= WAY_TOL[dtype] * size
+            assert np.abs(w - e).max() <= WAY_TOL[dtype] * size
+
+
+@pytest.mark.parametrize("what", ["value", "tokens", "weights", "matrices"])
+@pytest.mark.parametrize("router", ROUTERS)
+@pytest.mark.parametrize("k", [1, 2, 8], ids=["top1", "top2", "top8"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_rows_way_equals_the_plain_form(dtype, k, router, what):
+    assert_the_same_way(*ways(dtype, k, router)[what], dtype)
+
+
+@pytest.mark.parametrize("what", ["value", "tokens", "weights", "matrices"])
+@pytest.mark.parametrize("n", [2, 4])
+def test_a_rows_way_over_an_ep_axis_equals_the_plain_form(n, what):
+    got, want, exact = ways("float32", 2, "spread", n)[what]
+    assert_the_same_way(got, want, exact, "float32")
+
+
+def test_the_routers_of_these_cases_do_what_they_say():
+    for k in (1, 2, 8):
+        counts = {r: np.bincount(np.asarray(way_inputs("float32", k, r)[1])
+                                 .ravel(), minlength=WAY_E) for r in ROUTERS}
+        assert (counts["spread"] > 0).all()
+        assert (counts["all to the same"] > 0).sum() == k
+        if k <= WAY_E - 3:
+            assert (counts["leaves experts empty"][[2, 5, 7]] == 0).all()
+
+
+def test_the_backward_pass_keeps_the_rows_and_no_other_slots_by_d_array():
+    """What `jax.vjp` keeps of one layer: of arrays as large as the slots'
+    rows ([t x k, d], or the same as [t, k, d]) only the experts' input
+    rows, which their weights' gradient needs; the experts' output is not
+    kept, nor its permutation, nor a broadcast of the weights. The plain
+    form keeps the permuted output besides."""
+    tokens, idx, weights, w = way_inputs("float32", 2, "spread")
+
+    def kept(way):
+        _y, back = jax.vjp(lambda tokens, weights, w:
+                           way(tokens, idx, weights, w), tokens, weights, w)
+        big = [a.shape for a in jax.tree.leaves(back)
+               if hasattr(a, "shape") and a.size == WAY_T * 2 * WAY_D]
+        return sorted(big)
+    assert kept(new_way) == [(WAY_T * 2, WAY_D)]
+    assert len(kept(plain_way)) >= 2

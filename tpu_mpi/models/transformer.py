@@ -379,6 +379,37 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     return x, sent
 
 
+@jax.custom_vjp
+def _weighed_silu_gate(gate, up, scale):
+    """``scale[:, None] x silu(gate) x up``: an expert's hidden activation
+    times its row's router weight, the product in float32 and rounded once.
+    The gradient is written out so that ``d gate``, ``d up`` and the
+    weight's ``d scale`` (a row-wise dot, summed in float32) come from one
+    pass over ``gate``, ``up`` and the cotangent; left to autodiff the dot is
+    a pass of its own that reads all three again (0.57 ms a layer at
+    OLMoE's shape on the v5e, PERF.md PR 31)."""
+    h = jax.nn.silu(gate) * up
+    return (h.astype(jnp.float32) *
+            scale[:, None].astype(jnp.float32)).astype(h.dtype)
+
+
+def _weighed_silu_gate_bwd(kept, d):
+    gate, up, scale = (a.astype(jnp.float32) for a in kept)
+    d = d.astype(jnp.float32)
+    sig = jax.nn.sigmoid(gate)
+    act = gate * sig                                # silu(gate)
+    d_h = d * scale[:, None]
+    d_gate = d_h * up * (sig * (1.0 + gate * (1.0 - sig)))
+    d_scale = jnp.sum(d * (act * up), axis=-1)
+    return tuple(g.astype(a.dtype) for g, a in
+                 zip((d_gate, d_h * act, d_scale), kept))
+
+
+_weighed_silu_gate.defvjp(
+    lambda gate, up, scale: (_weighed_silu_gate(gate, up, scale),
+                             (gate, up, scale)), _weighed_silu_gate_bwd)
+
+
 def _gated_ffn(y, w_gate, w_in, w_out):
     """out(silu(gate(y)) * in(y)): a dense gated FFN, and a shared expert."""
     return (jax.nn.silu(y @ w_gate) * (y @ w_in)) @ w_out
@@ -396,11 +427,15 @@ def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
     over the row groups (`parallel.ep.grouped_products`): on a TPU, at
     widths that are multiples of 128 and a slot count that is a multiple of
     128, the grouped Pallas kernel (forward and both backward products);
-    anywhere else `lax.ragged_dot`. Where the rank holds a share of the
-    experts (`experts_held`) it adds their part of the sum alone
-    (`parallel.ep.moe_dropless_held`), and a third entry says what that
-    took. A shared expert (`n_shared_experts`) is a gated FFN every token
-    runs, added beside."""
+    anywhere else `lax.ragged_dot`. `moe_dropless` hands the experts each
+    row's router weight and they multiply it into the hidden activation
+    (`_weighed_silu_gate`: `d_ff` wide, in the pass that computes
+    silu(gate) x in anyway), so the sum back over a token's experts is a
+    plain float32 sum and its gradient a plain copy. Where the rank holds a
+    share of the experts (`experts_held`) it adds their part of the sum
+    alone (`parallel.ep.moe_dropless_held`, which weighs the rows itself),
+    and a third entry says what that took. A shared expert
+    (`n_shared_experts`) is a gated FFN every token runs, added beside."""
     b, t, d = y.shape
     rows = y.reshape(b * t, d)
     with jax.named_scope("router"):
@@ -416,10 +451,13 @@ def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
         if cfg.router_scale != 1.0:
             weights = weights * cfg.router_scale
 
-    def experts(xs, sizes):
+    def experts(xs, sizes, scale=None):
         product = grouped_products(sizes)
-        h = jax.nn.silu(product(xs, layer["w_gate"])) * \
-            product(xs, layer["w_in"])
+        gate, up = product(xs, layer["w_gate"]), product(xs, layer["w_in"])
+        if scale is None:
+            h = jax.nn.silu(gate) * up
+        else:           # the rows' weights, where the rows are narrowest
+            h = _weighed_silu_gate(gate, up, scale)
         return product(h, layer["w_out"])
 
     weights = weights.astype(rows.dtype)

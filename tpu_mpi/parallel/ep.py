@@ -13,9 +13,14 @@ Four realizations live here:
   (the layer of the public sparse-expert models, `models/transformer.py`):
   the slots are sorted by expert and the experts run over the row groups
   (:func:`grouped_products`: the grouped Pallas kernel where the backend and
-  the shape allow it, `lax.ragged_dot` elsewhere); over an ``ep`` axis the
-  row groups travel by ``lax.all_to_all`` in buffers sized for the worst
-  case;
+  the shape allow it, `lax.ragged_dot` elsewhere). A row's way: every token
+  is copied to its k slots' places in expert order (one gather from the
+  ``[t, d]`` array), the experts multiply each row by its router weight
+  where the row is narrowest, and the k rows of a token are read back and
+  summed in float32, rounded once; the two maps are each other's
+  transposes and each other's gradients, and the weights travel as the
+  payload of the sort. Over an ``ep`` axis the row groups travel by
+  ``lax.all_to_all`` in buffers sized for the worst case;
 - :func:`moe_dropless_held` — the same layer on a rank that holds a few of
   the experts and is given no exchange to run (one chip's share of an
   expert-parallel layer): the part of the result its experts give, from a
@@ -34,7 +39,6 @@ Four realizations live here:
 
 from __future__ import annotations
 
-import functools
 import threading
 from typing import Callable, Optional
 
@@ -124,59 +128,106 @@ def grouped_products(sizes: jnp.ndarray) -> Callable:
     return product
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_of_slots(tokens, order, inverse, k: int):
-    """tokens[order // k]: row i is the token of the i-th slot in expert
-    order. The gradient is read back by slot (a gather through `inverse`
-    and a sum over a token's k slots), so neither direction scatters."""
-    return tokens[order // k]
-
-
-def _rows_of_slots_fwd(tokens, order, inverse, k):
-    return tokens[order // k], inverse
-
-
-def _rows_of_slots_bwd(k, inverse, g):
-    return g[inverse].reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
-
-
-_rows_of_slots.defvjp(_rows_of_slots_fwd, _rows_of_slots_bwd)
+@jax.custom_vjp
+def _rows_of_tokens(tokens, order, inverse):
+    """``rows[i] = tokens[order[i] // k]``: every token copied to the places
+    of its k slots in expert order (``order[S]`` the slots sorted by expert,
+    ``inverse[t, k]`` each slot's place there). One gather whose source is
+    the ``[t, d]`` array; its gradient is :func:`_tokens_of_rows`, the
+    transposed map, so neither direction scatters."""
+    return tokens[order // inverse.shape[1]]
 
 
 @jax.custom_vjp
-def _permute_rows(rows, perm, inverse):
-    """rows[perm] for a permutation and its inverse: the gradient is
-    g[inverse], a gather again."""
-    return rows[perm]
+def _tokens_of_rows(rows, order, inverse):
+    """``tokens[t] = sum_j rows[inverse[t, j]]``: the k rows of a token's
+    slots read back from expert order and summed, in float32, rounded once
+    to the rows' dtype. The transpose of :func:`_rows_of_tokens`, which is
+    its gradient."""
+    return jnp.sum(rows[inverse].astype(jnp.float32),
+                   axis=1).astype(rows.dtype)
 
 
-def _permute_rows_fwd(rows, perm, inverse):
-    return rows[perm], inverse
+def _rows_of_tokens_bwd(kept, g):
+    with jax.named_scope("dispatch"):
+        return _tokens_of_rows(g, *kept), None, None
 
 
-def _permute_rows_bwd(inverse, g):
-    return g[inverse], None, None
+def _tokens_of_rows_bwd(kept, g):
+    with jax.named_scope("combine"):
+        return _rows_of_tokens(g, *kept), None, None
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+_rows_of_tokens.defvjp(
+    lambda tokens, order, inverse: (_rows_of_tokens(tokens, order, inverse),
+                                    (order, inverse)), _rows_of_tokens_bwd)
+_tokens_of_rows.defvjp(
+    lambda rows, order, inverse: (_tokens_of_rows(rows, order, inverse),
+                                  (order, inverse)), _tokens_of_rows_bwd)
+
+
+@jax.custom_vjp
+def _by_expert(weights, flat):
+    """The slots sorted by expert, stably: ``(order[S], scale[S])`` with
+    ``order`` the slots' numbers and ``scale[i] = weights.flat[order[i]]``
+    each slot's weight at its row's place, both the payload of one
+    key-value sort. The weights' gradient comes back by a sort on
+    ``order``. An ``[S]`` vector is moved by a sort, never by a gather or
+    a scatter: on the chip a sort of 65536 pairs takes 0.05 ms, a gather or
+    scatter of as many scalars 0.3-0.5 (PERF.md, PR 31)."""
+    slots = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    return lax.sort((flat, slots, weights.reshape(-1)), num_keys=1,
+                    is_stable=True)[1:]
+
+
+def _by_expert_bwd(kept, g):
+    order, shape = kept
+    with jax.named_scope("dispatch"):
+        return lax.sort((order, g[1]), num_keys=1)[1].reshape(shape), None
+
+
+def _by_expert_fwd(weights, flat):
+    order, scale = _by_expert(weights, flat)
+    return (order, scale), (order, weights.shape)
+
+
+_by_expert.defvjp(_by_expert_fwd, _by_expert_bwd)
 
 
 def moe_dropless(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
                  weights: jnp.ndarray,
-                 expert_fn: Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray],
+                 expert_fn: Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray],
+                                     jnp.ndarray],
                  n_experts: int, *, axis: Optional[str] = None):
     """Top-k Mixture-of-Experts dispatch/combine that drops nothing.
 
     tokens: (t, d) local tokens; expert_idx: (t, k) each token's experts
     (global ids, distinct per token); weights: (t, k) what each expert's
-    output is multiplied by. expert_fn(rows, group_sizes): the experts held
-    here applied to rows sorted by expert, group_sizes[e] rows for expert e
-    (what :func:`grouped_products` takes, which is what an expert_fn
-    multiplies with); rows past the groups' sum are padding, and what
+    output is multiplied by. expert_fn(rows, group_sizes, scale): the
+    experts held here applied to rows sorted by expert, group_sizes[e] rows
+    for expert e (what :func:`grouped_products` takes, which is what an
+    expert_fn multiplies with), **each row's result times scale[i]**, the
+    weight of the row's slot: the experts multiply wherever a row is
+    narrowest (the model: on the hidden activation, inside a pass that
+    exists), so no ``[t x k, d]`` array is written for the weights' sake in
+    either direction. Rows past the groups' sum are padding, and what
     expert_fn returns for them is not read.
     Returns ((t, d) sum over k of weights x expert(token), (n_experts,)
     int32 token-slots of these tokens per expert). The experts always
     process exactly t x k rows in all.
+
+    How a row moves (scopes ``dispatch`` / ``experts`` / ``combine``, which
+    the gradients keep): the slots are sorted by expert with their weights
+    as the sort's payload (:func:`_by_expert`); every token is copied to its
+    slots' places (:func:`_rows_of_tokens`: a gather whose source is the
+    ``[t, d]`` array, small enough for XLA to keep on the chip); the
+    experts' rows are read back through ``inverse`` and summed per token in
+    float32, rounded once (:func:`_tokens_of_rows`). The two maps are
+    transposes: the combine's gradient is the first (the token's cotangent
+    copied to its slots: nothing is scaled, permuted or kept for it), the
+    dispatch's gradient the second. The weights' gradient is whatever
+    expert_fn's own differentiation gives for ``scale``, carried back to
+    slot order by a sort.
 
     With ``axis``, inside shard_map: the tokens and the experts are both
     sharded over it, rank r holding experts [r x n_experts/n, (r+1) x
@@ -189,20 +240,18 @@ def moe_dropless(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
     t, k = expert_idx.shape
     with jax.named_scope("dispatch"):
         flat = expert_idx.reshape(t * k)        # slot s: token s // k
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+        order, scale = _by_expert(weights, flat)
+        inverse = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
         sizes = jnp.sum(flat[:, None] == jnp.arange(n_experts)[None, :],
                         axis=0, dtype=jnp.int32)
-        rows = _rows_of_slots(tokens, order, inverse, k)
+        rows = _rows_of_tokens(tokens, order, inverse)
     if axis is None:
         with jax.named_scope("experts"):
-            out = expert_fn(rows, sizes)
+            out = expert_fn(rows, sizes, scale)
     else:
-        out = _over_expert_ranks(rows, sizes, expert_fn, axis)
+        out = _over_expert_ranks(rows, scale, sizes, expert_fn, axis)
     with jax.named_scope("combine"):
-        back = _permute_rows(out, inverse, order).reshape(t, k, -1)
-        return jnp.sum(back * weights[..., None], axis=1), sizes
+        return _tokens_of_rows(out, order, inverse), sizes
 
 
 def _zeros_varying_like(x):
@@ -311,11 +360,13 @@ def moe_dropless_held(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
     return acc.astype(tokens.dtype), sizes, did
 
 
-def _over_expert_ranks(rows: jnp.ndarray, sizes: jnp.ndarray,
-                       expert_fn: Callable, axis: str) -> jnp.ndarray:
+def _over_expert_ranks(rows: jnp.ndarray, scale: jnp.ndarray,
+                       sizes: jnp.ndarray, expert_fn: Callable,
+                       axis: str) -> jnp.ndarray:
     """`expert_fn` over rows sorted by global expert, the experts sharded
-    over ``axis``: ship each rank's row groups to their experts' ranks, run
-    the local experts over what arrived, ship the results back."""
+    over ``axis``: ship each rank's row groups, and each row's weight with
+    it, to their experts' ranks, run the local experts over what arrived,
+    ship the results back."""
     n = lax.axis_size(axis)
     m, d = rows.shape                           # m = t x k slots
     local = sizes.shape[0] // n                 # experts per rank
@@ -328,8 +379,11 @@ def _over_expert_ranks(rows: jnp.ndarray, sizes: jnp.ndarray,
         # send[r, j] = the j-th of my rows for rank r's experts
         src = first[:, None] + j[None, :]
         live = j[None, :] < per_rank[:, None]
-        send = jnp.where(live[..., None], rows[jnp.clip(src, 0, m - 1)], 0)
+        src = jnp.clip(src, 0, m - 1)
+        send = jnp.where(live[..., None], rows[src], 0)
         recv = lax.all_to_all(send, axis, 0, 0, tiled=True)     # [source, j]
+        their = lax.all_to_all(jnp.where(live, scale[src], 0), axis, 0, 0,
+                               tiled=True)
         counts = lax.all_to_all(to_rank, axis, 0, 0, tiled=True)
         # a source's rows arrive sorted by my expert; find each row's expert
         # (or `local` for padding) and sort all sources' rows together
@@ -338,9 +392,10 @@ def _over_expert_ranks(rows: jnp.ndarray, sizes: jnp.ndarray,
         by_expert = jnp.argsort(expert.reshape(n * m), stable=True)
         undo = jnp.argsort(by_expert)
         arrived = recv.reshape(n * m, d)[by_expert]
+        weighing = their.reshape(n * m)[by_expert]
         groups = counts.sum(axis=0).astype(jnp.int32)
     with jax.named_scope("experts"):
-        done = expert_fn(arrived, groups)
+        done = expert_fn(arrived, groups, weighing)
         done = jnp.where((jnp.arange(n * m) < groups.sum())[:, None], done, 0)
     with jax.named_scope("combine"):
         back = lax.all_to_all(done[undo].reshape(n, m, -1), axis, 0, 0,
