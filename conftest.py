@@ -1,0 +1,28 @@
+"""Two accepted tests of the benchmark's own suite (`yardstick/tests`, outside
+tier-1) assert an accident of their day: that THEIR entries are the last of
+`BENCHMARK.json`'s `per_layer`. The benchmark's contract has every later PR
+append its entries at the end of that list and edit no file the benchmark
+has, so the next PR that adds a per-layer metric falsifies the line and may
+not repair it. `yardstick/conftest.py` (PR 30) expects the first to fail,
+`test_grouped_matmul_share.py::test_the_flagship_reports_no_such_metric`;
+PR 30's own `test_lm_kinds_train_step.py::test_the_accepted_metrics_stand`
+has the same line (`names[-17:] == mine`) and PR 32's append falsified it.
+It is expected to fail here, strictly, until a `benchmark` PR drops the line;
+a hook in `yardstick/` would be an edit to a file the benchmark has. What the
+test is there for is asserted again, by name and by position from the end,
+in `yardstick/tests/test_lm_latent_train_step.py::
+test_the_accepted_metrics_stand`."""
+
+import pytest
+
+LAST_ENTRIES_TEST = ("yardstick/tests/test_lm_kinds_train_step.py::"
+                     "test_the_accepted_metrics_stand")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid.endswith(LAST_ENTRIES_TEST):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="asserts its entries are per_layer's last; later PRs "
+                       "append after them"))

@@ -482,3 +482,109 @@ def test_compiled_for_the_v5e_the_attention_kernel_with_a_window_and_groups(
         shapes = [o.shape for o in jax.tree.leaves(compiled.out_info)]
         assert shapes == [(1, 64, 8192, 128), (1, 8, 8192, 128),
                           (1, 8, 8192, 128)]
+
+
+def test_compiled_for_the_v5e_the_attention_kernel_with_two_term_scores(
+        v5e_2x2):
+    """The fused causal attention, forward and backward, at the latent
+    layer's shape (64 heads of 128 unrotated + 64 rotated score dimensions
+    beside 128-wide values, ONE rotary key for all heads, seq 4096,
+    bfloat16) lowers through Mosaic: the triple (128 + 64, 128) is inside
+    the contract as it stands, the shared key enters the kernels with its
+    one head, and its gradient comes back with that shape."""
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import pallas_kernels as pk
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def shape(heads, width):
+        return jax.ShapeDtypeStruct((1, heads, 4096, width), jnp.bfloat16,
+                                    sharding=one)
+    operands = (shape(64, 128), shape(64, 128), shape(64, 128),
+                shape(64, 64), shape(1, 64))
+    assert pk.causal_attention_blocks(4096, 128, 64, 128) == (512, 512)
+    lowered = jax.jit(jax.grad(
+        lambda q, k, v, q2, k2: pk.causal_attention(
+            q, k, v, rope=(q2, k2), interpret=False).astype(
+                jnp.float32).sum(), (0, 1, 2, 3, 4))).lower(*operands)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert ",4096,4096]" not in hlo
+    # both kernels read the one-head key: nothing broadcast to 64 heads
+    kernels = [line for line in hlo.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert all("bf16[1,1,4096,64]" in line for line in kernels)
+    shapes = [o.shape for o in jax.tree.leaves(compiled.out_info)]
+    assert shapes == [(1, 64, 4096, 128)] * 3 + [(1, 64, 4096, 64),
+                                                 (1, 1, 4096, 64)]
+
+
+def test_compiled_for_the_v5e_the_latent_step_fits_one_chip(v5e_2x2,
+                                                            monkeypatch):
+    """The benchmark's latent-attention share (yardstick/configs/
+    openpangu-ultra-moe-718b-1c.json: published widths, layer 0 and four
+    sparse layers, 64 of 128 heads and 8 of 256 experts held, batch 1 x
+    4096, parameters donated) compiled for one described v5e chip with the
+    kernels selected as on a TPU: 2.958 B parameters, inside the 14.9 GB
+    the configuration's rule allows with the recomputation the file states,
+    no [b, h, t, t] buffer, the two-term attention kernel forward and
+    backward in every layer under its `attn` scope reading the ONE rotary
+    key a token, the grouped kernel in the held experts' products, and one
+    kernel body a kind."""
+    import json
+    import os
+    import re
+    from tpu_mpi.parallel import ring
+    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from tpu_mpi import xla
+    from tpu_mpi.models.transformer import (TransformerConfig,
+                                            transformer_init,
+                                            transformer_train_step)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "yardstick", "configs",
+                           "openpangu-ultra-moe-718b-1c.json")) as f:
+        conf = json.load(f)
+    fields = dict(conf["model"], max_seq=4096)
+    fields["dtype"] = jnp.dtype(fields["dtype"])
+    cfg = TransformerConfig(**fields)
+    mesh = xla.make_mesh(dict(conf["mesh"]), devices=v5e_2x2[:1])
+    step, specs = transformer_train_step(cfg, mesh, lr=conf["lr"], donate=True)
+    shapes = jax.eval_shape(lambda k: transformer_init(k, cfg),
+                            jax.random.key(0))
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 2_958_302_720
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        shapes, specs)
+    tok = jax.ShapeDtypeStruct((1, 4096), jnp.int32,
+                               sharding=NamedSharding(mesh, P("dp", "sp")))
+    lowered = step.lower(params, tok, tok)
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert m.alias_size_in_bytes > 5.9e9        # the parameters are reused
+    assert 12e9 < held < 14.9e9, held
+    hlo = compiled.as_text()
+    assert ",4096,4096]" not in hlo             # no [.., t, t] scores
+    calls = re.findall(r"(?m)^(.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*causal_attention_(\w+)[^\"]*)\".*)$",
+                       hlo)
+    assert all("/attn/" in name for _line, name, _d in calls)
+    assert {(d, int(re.search(r"layer_(\d+)", name).group(1)))
+            for _line, name, d in calls} == {
+        (d, i) for d in ("fwd", "bwd") for i in range(cfg.n_layers)}
+    # the kernels read the one-head rotary key: nothing broadcast to heads
+    assert all("bf16[1,1,4096,64]" in line for line, _n, _d in calls)
+    grouped = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                         r"op_name=\"([^\"]*grouped_matmul_(\w+)/[^\"]*)\"", hlo)
+    assert grouped and "ragged-dot" not in hlo
+    assert {kind for _n, kind in grouped} == {"fwd", "dlhs", "drhs"}
+    kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
+    assert sorted(set(kernels)) == [
+        "causal_attention_bwd", "causal_attention_fwd", "grouped_matmul_dlhs",
+        "grouped_matmul_drhs", "grouped_matmul_fwd"]
+    # two kinds of layer (dense, sparse), ONE attention kind: one pair
+    assert kernels.count("causal_attention_fwd") == \
+        kernels.count("causal_attention_bwd") == 1, kernels

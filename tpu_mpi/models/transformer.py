@@ -89,15 +89,54 @@ class TransformerConfig:
     #                             empty: all of them
     remat_layers: tuple = ()    # a layer's recomputation in the backward
     #                             pass: "" none, "ffn" its FFN half
+    # Latent attention: queries and keys/values are up-projected, a head at
+    # a time, from two narrow normed latents; a head's scores are the sum
+    # of an unrotated product (`d_head` wide) and a rotated one (`d_rope`
+    # wide) whose key is ONE vector a token that all heads share, scaled by
+    # (d_head + d_rope) ** -0.5.
+    kv_latent: int = 0          # > 0: the key/value latent's width; leaves
+    #                             `w_dkv` [d, kv_latent + d_rope] (the latent
+    #                             and the shared rotary key), `kv_latent_norm`,
+    #                             `w_ukv` [kv_latent, heads x (d_head + d_value)]
+    q_latent: int = 0           # the query latent's width: `w_dq`,
+    #                             `q_latent_norm`, `w_uq` [q_latent, heads x
+    #                             (d_head + d_rope)]
+    d_rope: int = 0             # the rotated part's width; RoPE turns it alone
+    d_value: int = 0            # a value head's width; 0: head_dim
+    heads_held: tuple = ()      # (first, count): this rank holds heads
+    #                             [first, first + count) of n_heads and adds
+    #                             their part of the output projection's sum
+    #                             (latent attention: every head has its own
+    #                             keys and values, and which heads these are
+    #                             is the weights' business: `count` shapes the
+    #                             program); empty: all of them
+    norm_out: bool = False      # sandwich norm: x + RMSNorm(sublayer(
+    #                             RMSNorm(x))), scales `ln1_out`, `ln2_out`
 
     def __post_init__(self):
         for name in ("attn_windows", "ffn_kinds", "experts_held",
-                     "remat_layers"):
+                     "remat_layers", "heads_held"):
             value = tuple(getattr(self, name))
             object.__setattr__(self, name, value)   # a JSON list is welcome
-            if value and name != "experts_held" and len(value) != self.n_layers:
+            if value and not name.endswith("_held") \
+                    and len(value) != self.n_layers:
                 raise ValueError(f"{name} has {len(value)} entries for "
                                  f"{self.n_layers} layers")
+        if self.kv_latent and not (self.q_latent and self.d_rope
+                                   and self.d_head):
+            raise ValueError("latent attention names kv_latent, q_latent, "
+                             "d_rope and d_head together")
+        if self.kv_latent and (self.n_kv_heads or self.qk_norm
+                               or self.qk_norm_heads or any(self.attn_windows)):
+            raise ValueError("latent attention has a key and a value a head, "
+                             "norms on its latents alone and no window")
+        if self.heads_held and not (
+                self.kv_latent and 0 <= self.heads_held[0]
+                and 0 < self.heads_held[1]
+                and sum(self.heads_held) <= self.n_heads):
+            raise ValueError(f"heads_held={self.heads_held}: a share "
+                             f"(first, count) of n_heads={self.n_heads}, of "
+                             f"latent attention's heads")
         if set(self.remat_layers) - {"", "ffn"}:
             raise ValueError(f"remat_layers={self.remat_layers}: a layer "
                              f"recomputes \"\" (nothing) or \"ffn\"")
@@ -112,6 +151,14 @@ class TransformerConfig:
     @property
     def n_experts_here(self) -> int:
         return self.experts_held[1] if self.experts_held else self.n_experts
+
+    @property
+    def n_heads_here(self) -> int:
+        return self.heads_held[1] if self.heads_held else self.n_heads
+
+    @property
+    def value_dim(self) -> int:
+        return self.d_value or self.head_dim
 
     def layer_kind(self, i: int) -> "LayerKind":
         sparse = self.ffn_kinds[i] == "sparse" if self.ffn_kinds \
@@ -135,6 +182,8 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
     keys = jax.random.split(key, 2 + 4 * cfg.n_layers)
     d = cfg.d_model
     hd = cfg.n_heads * cfg.head_dim         # d_model unless d_head says so
+    if cfg.kv_latent:
+        hd = cfg.n_heads_here * cfg.value_dim
     params = {
         "embed": dense(keys[0], (cfg.vocab, d), d ** -0.5),
         "ln_f": jnp.ones((d,), cfg.dtype),
@@ -151,7 +200,18 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         layer = {"ln1": jnp.ones((d,), cfg.dtype)}
         # the leaves a public block adds draw from keys of their own, so
         # the flagship's are the flagship's whatever else is configured
-        if cfg.n_kv_heads:
+        if cfg.kv_latent:
+            h, cq, ckv = cfg.n_heads_here, cfg.q_latent, cfg.kv_latent
+            for n, (name, shape) in enumerate((
+                    ("w_dq", (d, cq)),
+                    ("w_uq", (cq, h * (cfg.head_dim + cfg.d_rope))),
+                    ("w_dkv", (d, ckv + cfg.d_rope)),
+                    ("w_ukv", (ckv, h * (cfg.head_dim + cfg.value_dim))))):
+                layer[name] = dense(jax.random.fold_in(k[0], 4 + n), shape,
+                                    shape[0] ** -0.5)
+            layer["q_latent_norm"] = jnp.ones((cq,), cfg.dtype)
+            layer["kv_latent_norm"] = jnp.ones((ckv,), cfg.dtype)
+        elif cfg.n_kv_heads:
             kv = cfg.n_kv_heads * cfg.head_dim
             layer["w_q"] = dense(jax.random.fold_in(k[0], 1), (d, hd), d ** -0.5)
             layer["w_k"] = dense(jax.random.fold_in(k[0], 2), (d, kv), d ** -0.5)
@@ -165,6 +225,9 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
             "w_out": dense(k[3], experts + (f, d),
                            (2 * f * cfg.n_layers) ** -0.5),
         })
+        if cfg.norm_out:
+            layer["ln1_out"] = jnp.ones((d,), cfg.dtype)
+            layer["ln2_out"] = jnp.ones((d,), cfg.dtype)
         if cfg.qk_norm:
             layer["q_norm"] = jnp.ones((d,), cfg.dtype)
             layer["k_norm"] = jnp.ones((d,), cfg.dtype)
@@ -204,10 +267,15 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
         out = {"ln1": rep, "w_proj": row, "ln2": rep,
                "w_in": rep if sparse else col,
                "w_out": rep if sparse else row}
-        if cfg.n_kv_heads:
+        if cfg.kv_latent:     # whole on every rank (tp 1 only: `_latent_attn`)
+            out.update(w_dq=rep, w_uq=rep, w_dkv=rep, w_ukv=rep, w_proj=rep,
+                       q_latent_norm=rep, kv_latent_norm=rep)
+        elif cfg.n_kv_heads:
             out.update(w_q=col, w_k=col, w_v=col)
         else:
             out["w_qkv"] = col
+        if cfg.norm_out:
+            out.update(ln1_out=rep, ln2_out=rep)
         if cfg.qk_norm:
             out.update(q_norm=P(tp_axis), k_norm=P(tp_axis))
         if cfg.qk_norm_heads:
@@ -325,10 +393,13 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     """One transformer layer (pre-norm attention + FFN), tp/sp aware —
     shared by the flat forward and the pipelined 4-axis stage. ``kind`` is
     the layer's (`cfg.layer_kind(i)`; None: layer 0's). Returns the layer's
-    output and what its router sent where (None without experts)."""
+    output and what its router sent where (None without experts). With
+    `norm_out` each half's output is normed before it joins the residual
+    (scope `norm_out`); where the rank holds a share of the heads or of the
+    experts that output is a partial sum, and is normed as it stands."""
     kind = cfg.layer_kind(0) if kind is None else kind
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
-    h_local = cfg.n_heads // tp
+    h_local = cfg.n_heads_here // tp
 
     attn = functools.partial(_attn, cfg, h_local=h_local, tp_axis=tp_axis,
                              sp_axis=sp_axis, window=kind.window)
@@ -338,10 +409,17 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
         b, t, _ = x.shape
         alone = sp_axis is None or lax.axis_size(sp_axis) == 1
         if not (alone and fused_attention_selected(
-                (b, h_local, t, cfg.head_dim), x.dtype)):
+                (b, h_local, t, cfg.head_dim), x.dtype, cfg.d_rope,
+                cfg.value_dim)):
             attn = jax.checkpoint(attn)
+
+    def normed(out, scale):
+        if not cfg.norm_out:
+            return out
+        with jax.named_scope("norm_out"):
+            return _rms_norm(out, layer[scale], cfg.norm_eps)
     with jax.named_scope("attn"):
-        x = x + attn(layer, x, positions)
+        x = x + normed(attn(layer, x, positions), "ln1_out")
     if kind.sparse and tp > 1:
         # sharding an expert's width over tp needs the sums of the
         # rows' and the weights' cotangents that `parallel/tp.py`'s
@@ -375,7 +453,7 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
         ffn = jax.checkpoint(ffn)
     with jax.named_scope("mlp"):
         out, sent = ffn(layer, x)
-        x = x + out
+        x = x + normed(out, "ln2_out")
     return x, sent
 
 
@@ -466,7 +544,7 @@ def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
         out, slots, did = moe_dropless_held(
             rows, chosen, weights, experts, cfg.n_experts, first, held,
             buffer_rows=held_row_buffer(b * t * cfg.experts_per_tok,
-                                        cfg.n_experts, held))
+                                        cfg.n_experts, held, b * t))
         sent = (probs.sum(axis=0), slots, did)
     else:
         out, slots = moe_dropless(rows, chosen, weights, experts,
@@ -523,6 +601,9 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     b, t, _ = x.shape
     dh = cfg.head_dim
     y = _rms_norm(x, layer["ln1"], cfg.norm_eps)
+    if cfg.kv_latent:
+        return _latent_attn(cfg, layer, y, positions, tp_axis=tp_axis,
+                            sp_axis=sp_axis)
     if cfg.n_kv_heads:
         if tp_axis is not None and lax.axis_size(tp_axis) > 1:
             raise NotImplementedError(
@@ -558,6 +639,55 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     if tp_axis is not None:
         return row_parallel(o, layer["w_proj"], axis=tp_axis)
     return o @ layer["w_proj"]
+
+
+def _latent_attn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray,
+                 positions: jnp.ndarray, *, tp_axis: Optional[str],
+                 sp_axis: Optional[str]) -> jnp.ndarray:
+    """Latent attention of the normed input ``y``, in its up-projected form
+    (what training computes; scoring against the latent itself is a serving
+    rewrite). Queries: a `q_latent`-wide normed latent, up-projected a head
+    at a time into an unrotated part (`d_head`) and a rotated one
+    (`d_rope`). Keys and values: `w_dkv` gives a `kv_latent`-wide latent and
+    ONE `d_rope`-wide rotary key a token; the normed latent is up-projected
+    a head at a time into an unrotated key (`d_head`) and a value
+    (`d_value`). RoPE turns the rotated parts alone; head j scores (q_j k_j^T
+    + q_rope_j k_rope^T) x (d_head + d_rope) ** -0.5 (`local_attention`: the
+    shared key is read through the kernel's index map, never broadcast).
+    The heads here (`heads_held`, or all) add their part of the output
+    projection. Scopes: `q_latent`, `kv_latent` (down, norm, up), `rope`,
+    `out`."""
+    if tp_axis is not None and lax.axis_size(tp_axis) > 1:
+        raise NotImplementedError(
+            "latent attention runs at tp 1: a rank's heads are `heads_held`, "
+            "and the exchange that sums their parts is not built")
+    b, t, _ = y.shape
+    h, dh, dr, dv = cfg.n_heads_here, cfg.head_dim, cfg.d_rope, cfg.value_dim
+
+    def heads(x, width):        # (b, t, h x width) -> (b, h, t, width)
+        return x.reshape(b, t, h, width).transpose(0, 2, 1, 3)
+    with jax.named_scope("q_latent"):
+        c_q = _rms_norm(y @ layer["w_dq"], layer["q_latent_norm"],
+                        cfg.norm_eps)
+        q = heads(c_q @ layer["w_uq"], dh + dr)
+        q, q_rope = q[..., :dh], q[..., dh:]
+    with jax.named_scope("kv_latent"):
+        down = y @ layer["w_dkv"]
+        c_kv = _rms_norm(down[..., :cfg.kv_latent], layer["kv_latent_norm"],
+                         cfg.norm_eps)
+        k_rope = down[:, None, :, cfg.kv_latent:]       # one head for all
+        kv = heads(c_kv @ layer["w_ukv"], dh + dv)
+        k, v = kv[..., :dh], kv[..., dh:]
+    with jax.named_scope("rope"):
+        q_rope = _rope(q_rope, positions, cfg.rope_theta)
+        k_rope = _rope(k_rope, positions, cfg.rope_theta)
+    if sp_axis is not None:
+        o = ring_attention(q, k, v, axis=sp_axis, causal=True,
+                           rope=(q_rope, k_rope))
+    else:
+        o = local_attention(q, k, v, rope=(q_rope, k_rope))
+    with jax.named_scope("out"):
+        return o.transpose(0, 2, 1, 3).reshape(b, t, h * dv) @ layer["w_proj"]
 
 
 def _whole_vector_norm(cfg: TransformerConfig, x: jnp.ndarray,

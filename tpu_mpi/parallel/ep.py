@@ -268,13 +268,19 @@ HELD_ROWS_FACTOR = 2.0      # the held experts' row buffer, x the rows a
 #                             buffers run; nothing is dropped)
 
 
-def held_row_buffer(slots: int, n_experts: int, held: int) -> int:
+def held_row_buffer(slots: int, n_experts: int, held: int,
+                    tokens: int) -> int:
     """Rows of :func:`moe_dropless_held`'s buffer for ``slots`` token-slots
-    routed over ``n_experts`` of which ``held`` are here:
-    :data:`HELD_ROWS_FACTOR` x the rows a balanced router sends, rounded up
-    to a multiple of 128 (the grouped kernel's contract), no more than all
-    slots."""
-    rows = -(-int(HELD_ROWS_FACTOR * slots * held / n_experts) // 128) * 128
+    of ``tokens`` tokens routed over ``n_experts`` of which ``held`` are
+    here: :data:`HELD_ROWS_FACTOR` x the rows a balanced router sends, and
+    no fewer than half that factor x the tokens (one expert can be sent a
+    slot of every token, so with the factor at 2 a single hot expert alone
+    never spills: a small share of many experts has few balanced rows and
+    a skewed router sends it several times those, PERF.md PR 32); rounded
+    up to a multiple of 128 (the grouped kernel's contract), no more than
+    all slots."""
+    want = HELD_ROWS_FACTOR * max(slots * held / n_experts, tokens / 2)
+    rows = -(-int(want) // 128) * 128
     return max(128, min(rows, -(-slots // 128) * 128))
 
 

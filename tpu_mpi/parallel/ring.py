@@ -11,7 +11,10 @@ A ring of one is :func:`local_attention`, the attention of a block with
 itself: on a TPU, at a shape inside its contract, the fused Pallas kernel
 ``xla.pallas_kernels.causal_attention`` (blockwise, forward and backward, no
 [b, h, t, t] tensor in HBM); everywhere else the plain einsum / softmax /
-einsum. Both take a window and fewer key/value heads than query heads. A ring of n > 1 keeps its XLA online softmax per step.
+einsum. Both take a window, fewer key/value heads than query heads, and
+scores that are the sum of two products (a head's unrotated part beside a
+rotated one whose key all heads may share) with values of a width of their
+own. A ring of n > 1 keeps its XLA online softmax per step.
 """
 
 from __future__ import annotations
@@ -47,39 +50,50 @@ def warm_kernel_imports() -> None:
                          daemon=True).start()
 
 
-def fused_attention_selected(shape: tuple, dtype) -> bool:
+def fused_attention_selected(shape: tuple, dtype, rope_dim: int = 0,
+                             value_dim: int = 0) -> bool:
     """Whether :func:`local_attention` runs the fused kernel for (batch,
     heads, t, head_dim) queries of ``dtype``: decided from the backend and
     the kernel's contract (``pallas_kernels.causal_attention_blocks``,
     ``ATTN_DTYPES``), never by trying it and catching the failure: once
     selected, a kernel that does not lower is an error. (A window, and how
     many heads the keys and values have, are not part of the contract: any
-    window, any divisor of the queries' heads.)"""
+    window, any divisor of the queries' heads. The width of a second term
+    of the scores, ``rope_dim``, and of the values where it is their own,
+    ``value_dim``, are.)"""
     from ..xla import pallas_kernels as pk
     return (_kernel_backend() is not None
             and str(jnp.dtype(dtype)) in pk.ATTN_DTYPES
-            and pk.causal_attention_blocks(shape[2], shape[3]) is not None)
+            and pk.causal_attention_blocks(shape[2], shape[3], rope_dim,
+                                           value_dim) is not None)
 
 
 def local_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                    window: int = 0) -> jnp.ndarray:
+                    window: int = 0, rope: tuple = ()) -> jnp.ndarray:
     """Causal attention of a (batch, heads, t, head_dim) block with itself,
     scaled by head_dim ** -0.5. ``window`` > 0: a query sees its last
     ``window`` keys, itself included. k and v may hold fewer heads than q:
-    query head j reads key/value head j // (heads / key-value heads). Each
-    call built into a traced program counts in
-    ``perfvars.snapshot()["attn_lowerings"]`` as ``fused`` or ``plain``."""
-    if fused_attention_selected(q.shape, q.dtype):
+    query head j reads key/value head j // (heads / key-value heads).
+
+    ``rope`` = (q_rope, k_rope): the scores are (q k^T + q_rope k_rope^T) x
+    (head_dim + rope width) ** -0.5, a head's unrotated part beside its
+    rotated one (latent attention); `k_rope` may hold one head that every
+    query head reads, and v a width of its own. Each call built into a
+    traced program counts in ``perfvars.snapshot()["attn_lowerings"]`` as
+    ``fused`` or ``plain``."""
+    rope_dim = rope[0].shape[3] if rope else 0
+    if fused_attention_selected(q.shape, q.dtype, rope_dim, v.shape[3]):
         from ..xla import pallas_kernels as pk
-        perfvars.note_attn_lowering("fused", window)
+        perfvars.note_attn_lowering("fused", window, bool(rope))
         return pk.causal_attention(
-            q, k, v, window=window, interpret=_kernel_backend() == "interpret")
-    perfvars.note_attn_lowering("plain", window)
-    return plain_attention(q, k, v, window)
+            q, k, v, window=window, rope=rope,
+            interpret=_kernel_backend() == "interpret")
+    perfvars.note_attn_lowering("plain", window, bool(rope))
+    return plain_attention(q, k, v, window, rope)
 
 
 def plain_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                    window: int = 0) -> jnp.ndarray:
+                    window: int = 0, rope: tuple = ()) -> jnp.ndarray:
     """:func:`local_attention`'s meaning as einsum / softmax / einsum: what
     runs off the kernel's backend and contract, and what the kernel is held
     against (tests, `chip_smoke.py`). Writes [b, h, t, t] scores."""
@@ -87,6 +101,12 @@ def plain_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     group = q.shape[1] // k.shape[1]
     if group > 1:
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    if rope:        # one product over the two parts side by side
+        q2, k2 = rope
+        dh += q2.shape[3]
+        q = jnp.concatenate([q, q2], axis=-1)
+        k = jnp.concatenate(
+            [k, jnp.repeat(k2, q.shape[1] // k2.shape[1], axis=1)], axis=-1)
     # stays in the input dtype: an f32 upcast here runs the attention
     # matmuls on the slow MXU path and cost 13% of a full bf16 train step
     # (benchmarks/flagship_probe)
@@ -102,24 +122,26 @@ def plain_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                    axis: str = "sp", causal: bool = True,
                    scale: Optional[float] = None,
-                   window: int = 0) -> jnp.ndarray:
+                   window: int = 0, rope: tuple = ()) -> jnp.ndarray:
     """Blockwise-exact attention over a sequence-sharded axis.
 
     q, k, v: (batch, heads, block_len, head_dim) — the local sequence block.
     Block b of the global sequence lives on rank b of ``axis``. Returns the
-    local attention output block (same shape as q). A ``window`` or fewer
-    key/value heads than query heads are :func:`local_attention`'s, a ring
-    of one; a longer ring has neither yet.
+    local attention output block (same shape as q). A ``window``, fewer
+    key/value heads than query heads or a second term of the scores
+    (``rope``) are :func:`local_attention`'s, a ring of one; a longer ring
+    has none of them yet.
     """
     b, h, t, d = q.shape
     n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     if n == 1 and causal and scale is None:
-        return local_attention(q, k, v, window)
-    if window or k.shape[1] != h:
+        return local_attention(q, k, v, window, rope)
+    if window or k.shape[1] != h or rope:
         raise NotImplementedError(
             "ring attention over more than one sequence shard takes no "
-            "window and as many key/value heads as query heads")
+            "window, as many key/value heads as query heads and scores of "
+            "one product")
     scale = (d ** -0.5) if scale is None else scale
     q = q * scale
 

@@ -536,23 +536,26 @@ def arming_seconds() -> float:
 
 _attn_lowerings = {"fused": 0, "plain": 0}
 # the same calls by the attention's kind, for a model whose layers differ:
-# {"full" | "window": {"fused": n, "plain": n}}
+# {"full" | "window" | "latent": {"fused": n, "plain": n}}
 _attn_by_kind: Dict[str, Dict[str, int]] = {}
 
 
-def note_attn_lowering(kind: str, window: int = 0) -> None:
+def note_attn_lowering(kind: str, window: int = 0,
+                       latent: bool = False) -> None:
     """One attention call was built into a traced program as the ``fused``
     kernel or as the ``plain`` path; ``window`` > 0 says it was a windowed
-    one."""
+    one, ``latent`` one whose scores are the sum of two products (latent
+    attention's unrotated and rotated parts)."""
     with _store_lock:
         _attn_lowerings[kind] += 1
-        by = _attn_by_kind.setdefault("window" if window else "full",
-                                      {"fused": 0, "plain": 0})
+        by = _attn_by_kind.setdefault(
+            "latent" if latent else "window" if window else "full",
+            {"fused": 0, "plain": 0})
         by[kind] += 1
 
 
 def _attn_kinds() -> dict:
-    """{"full" | "window": "fused" | "plain" | "mixed"} of the attention
+    """{"full" | "window" | "latent": "fused" | "plain" | "mixed"} of the attention
     kinds traced so far (caller holds the store lock)."""
     return {k: ("fused" if not n["plain"] else "plain" if not n["fused"]
                 else "mixed") for k, n in sorted(_attn_by_kind.items())}

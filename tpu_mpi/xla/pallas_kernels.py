@@ -714,41 +714,55 @@ _ATTN_BLOCKS = (512, 256, 128)      # widest first; all multiples of LANE
 _MASKED = -1e30     # the plain path's value for a future key
 
 
-def causal_attention_blocks(t: int, dh: int) -> Optional[tuple]:
+def causal_attention_blocks(t: int, dh: int, rope: int = 0,
+                            dv: int = 0) -> Optional[tuple]:
     """(query block, key block) of the kernel for sequence length ``t`` and
     head dimension ``dh``, or None where its contract does not hold: ``t`` a
     multiple of a block, ``dh`` 64 or a multiple of 128 (a head is the MXU's
     contraction and the minor dimension of every operand block), and the
     backward pass's working set, which holds one head's whole dq, inside
     :data:`VMEM_LIMIT_BYTES`. A windowed call takes the same blocks (sweep:
-    PERF.md)."""
-    if dh != 64 and dh % LANE:
+    PERF.md). ``rope`` > 0: the scores' second term is that wide, and ``dv``
+    > 0 the values are (0: ``dh``); each is held to ``dh``'s rule, so the
+    contract knows the triple (128 + 64, 128) as it stands."""
+    if any(w != 64 and w % LANE for w in (dh, rope or dh, dv or dh)):
         return None
     block = next((b for b in _ATTN_BLOCKS if t % b == 0), None)
     if block is None:
         return None
-    if _attn_bwd_vmem(t, dh, block, block, 4) > VMEM_LIMIT_BYTES:
+    if _attn_bwd_vmem(t, dh, block, block, 4, rope, dv) > VMEM_LIMIT_BYTES:
         return None
     return block, block
 
 
-def _attn_fwd_vmem(dh: int, bq: int, bk: int, itemsize: int) -> int:
+def _attn_fwd_vmem(dh: int, bq: int, bk: int, itemsize: int,
+                   rope: int = 0, dv: int = 0) -> int:
     """The forward kernel's VMEM: q, k, v, o blocks (double-buffered by the
     grid pipeline), the log-sum-exp row, the float32 running max, sum and
-    accumulator, and a block of scores with its exponentials."""
-    dl = max(dh, LANE)
-    return (2 * (2 * bq + 2 * bk) * dl * itemsize + 2 * SUBLANE * bq * 4
-            + (3 * LANE + dl) * bq * 4 + 3 * bq * bk * 4)
+    accumulator, and a block of scores with its exponentials; with a second
+    term of the scores, its q and k blocks too."""
+    dl, vl = max(dh, LANE), max(dv or dh, LANE)
+    second = 2 * (bq + bk) * max(rope, LANE) * itemsize if rope else 0
+    return (2 * ((bq + bk) * dl + (bq + bk) * vl) * itemsize + second
+            + 2 * SUBLANE * bq * 4 + (3 * LANE + vl) * bq * 4
+            + 3 * bq * bk * 4)
 
 
-def _attn_bwd_vmem(t: int, dh: int, bq: int, bk: int, itemsize: int) -> int:
+def _attn_bwd_vmem(t: int, dh: int, bq: int, bk: int, itemsize: int,
+                   rope: int = 0, dv: int = 0) -> int:
     """The backward kernel's VMEM: q, do, k, v, dk, dv blocks and one head's
     whole dq (double-buffered), the float32 dq, dk, dv accumulators, the
     log-sum-exp and row-sum rows, and five blocks of scores (s, p, dp, ds
-    and the transposed ds)."""
-    dl = max(dh, LANE)
-    return (2 * (2 * bq + 4 * bk + t) * dl * itemsize
-            + (t + 2 * bk) * dl * 4 + 4 * SUBLANE * bq * 4 + 5 * bq * bk * 4)
+    and the transposed ds); with a second term of the scores, what q, k, dq
+    and dk take again at its width."""
+    dl, vl = max(dh, LANE), max(dv or dh, LANE)
+    out = (2 * ((bq + 2 * bk + t) * dl + (bq + 2 * bk) * vl) * itemsize
+           + ((t + bk) * dl + bk * vl) * 4 + 4 * SUBLANE * bq * 4
+           + 5 * bq * bk * 4)
+    if rope:
+        rl = max(rope, LANE)
+        out += 2 * (bq + 2 * bk + t) * rl * itemsize + (t + bk) * rl * 4
+    return out
 
 
 def _lanes(x, n: int):
@@ -836,13 +850,17 @@ def causal_attention_walk(t: int, bq: int, bk: int, window: int = 0) -> tuple:
     return span_k, span_q, pairs
 
 
-def _attn_fwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, o_ref,
-                     lse_ref, m_ref, l_ref, acc_ref):
+def _attn_fwd_kernel(scale: float, window: int, second: bool, q_ref, k_ref,
+                     v_ref, *refs):
+    """``second``: the scores are the sum of two products, and the refs of
+    the second one's queries and keys follow v's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     pl = _pl()
-    bq, dh = q_ref.shape[2:]
+    q2_ref, k2_ref = refs[:2] if second else (None, None)
+    o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[-5:]
+    bq, dv = o_ref.shape[2:]
     bk = k_ref.shape[2]
     qi, step = pl.program_id(2), pl.program_id(3)
     ki = step + _first_key_block(qi, bq, bk, window) if window else step
@@ -858,7 +876,12 @@ def _attn_fwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, o_ref,
         v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec) * scale
+            preferred_element_type=jnp.float32, precision=prec)
+        if second:
+            s = s + jax.lax.dot_general(
+                q2_ref[0, 0], k2_ref[0, 0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec)
+        s = s * scale
         if on_diagonal:
             rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -876,7 +899,7 @@ def _attn_fwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_next)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_next
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, dh) + jax.lax.dot_general(
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, dv) + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)
 
@@ -887,27 +910,33 @@ def _attn_fwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, o_ref,
     @pl.when(step == pl.num_programs(3) - 1)
     def _last_step():
         l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] * _lanes(1.0 / l, dh)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] * _lanes(1.0 / l, dv)).astype(o_ref.dtype)
         # the rows' log-sum-exp leaves as one row along the lanes, which is
         # how the backward pass reads it: [b, h, 1, t], nothing replicated
         lse_ref[0, 0] = jnp.transpose(m_ref[...] + jnp.log(l))[:1, :]
 
 
-def _attn_bwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, do_ref,
-                     lse_ref, di_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
-                     dv_acc):
+def _attn_bwd_kernel(scale: float, window: int, second: bool, q_ref, k_ref,
+                     v_ref, do_ref, lse_ref, di_ref, *refs):
     """One (key block, query block) pair of the backward pass, transposed:
     scores are [keys, queries], so a query's log-sum-exp and row-sum are
     rows along the lanes and four of the five products need no transpose.
     dk and dv accumulate over the query blocks (the inner grid axis); dq of
     the whole head stays in VMEM over both axes. Under a window a key
     block's walk starts at its own first query block and may run past the
-    last one: such a step runs nothing."""
+    last one: such a step runs nothing. ``second``: the scores are the sum
+    of two products; the second one's q and k refs follow `di_ref`, its dq
+    and dk the first one's outputs, and its accumulators theirs."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     pl = _pl()
-    bq, dh = q_ref.shape[2:]
+    if second:
+        (q2_ref, k2_ref, dq_ref, dk_ref, dv_ref, dq2_ref, dk2_ref,
+         dq_acc, dk_acc, dv_acc, dq2_acc, dk2_acc) = refs
+    else:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    bq = q_ref.shape[2]
     bk = k_ref.shape[2]
     kj, step = pl.program_id(2), pl.program_id(3)
     qi = step + _first_query_block(kj, bq, bk, window) if window else step
@@ -918,16 +947,25 @@ def _attn_bwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, do_ref,
     @pl.when(jnp.logical_and(kj == 0, step == 0))
     def _first_pair():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+        if second:
+            dq2_acc[...] = jnp.zeros(dq2_acc.shape, jnp.float32)
 
     @pl.when(step == 0)
     def _first_step():
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+        if second:
+            dk2_acc[...] = jnp.zeros(dk2_acc.shape, jnp.float32)
 
     def pair(on_diagonal: bool):
         q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         s = jax.lax.dot_general(k, q, nt, preferred_element_type=jnp.float32,
-                                precision=prec) * scale         # (bk, bq)
+                                precision=prec)                 # (bk, bq)
+        if second:
+            q2, k2 = q2_ref[0, 0], k2_ref[0, 0]
+            s = s + jax.lax.dot_general(
+                k2, q2, nt, preferred_element_type=jnp.float32, precision=prec)
+        s = s * scale
         if on_diagonal:
             keys = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
             rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
@@ -946,6 +984,12 @@ def _attn_bwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, do_ref,
         dq_acc[at, :] += jax.lax.dot_general(
             ds, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)
+        if second:
+            dk2_acc[...] += jax.lax.dot_general(
+                ds, q2, nn, preferred_element_type=jnp.float32, precision=prec)
+            dq2_acc[at, :] += jax.lax.dot_general(
+                ds, k2, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec)
 
     below, crosses = _causal_pair(qi, kj, bq, bk, window)
     if window:      # a walk's steps past the last query block
@@ -961,10 +1005,14 @@ def _attn_bwd_kernel(scale: float, window: int, q_ref, k_ref, v_ref, do_ref,
     def _last_query_block():
         dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        if second:
+            dk2_ref[0, 0] = dk2_acc[...].astype(dk2_ref.dtype)
 
     @pl.when(jnp.logical_and(last_q, kj == pl.num_programs(2) - 1))
     def _last_pair():
         dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+        if second:
+            dq2_ref[0, 0] = dq2_acc[...].astype(dq2_ref.dtype)
 
 
 def _varying_like(x, shape, dtype):
@@ -989,14 +1037,19 @@ def _vary_together(*xs):
 
 
 def _attn_forward(q, k, v, bq: int, bk: int, interpret: Optional[bool],
-                  window: int = 0):
+                  window: int = 0, second: tuple = ()):
     """(o, log-sum-exp [b, h, 1, t] float32) of causal attention; k and v
-    may hold fewer heads than q (query head j reads head j // group)."""
+    may hold fewer heads than q (query head j reads head j // group).
+    ``second`` = (q2, k2): the scores' second product, its keys' heads a
+    divisor of the queries' of their own (one key for all heads: the index
+    map reads head 0 for each, nothing is repeated in HBM)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     pl, pltpu = _pl(), _pltpu()
     b, h, t, dh = q.shape
+    dv = v.shape[3]
+    rope = second[0].shape[3] if second else 0
     group = h // k.shape[1]
     span = causal_attention_walk(t, bq, bk, window)[0]
 
@@ -1007,45 +1060,57 @@ def _attn_forward(q, k, v, bq: int, bk: int, interpret: Optional[bool],
     def kv_head(hi):
         return hi if group == 1 else jax.lax.div(hi, np.int32(group))
 
-    def key_block(bi, hi, qi, step):
+    def key_block(bi, hi, qi, step, head=kv_head):
         # a skipped pair asks for the block already there: no copy
         last = jax.lax.div(qi * bq + (bq - 1), np.int32(bk))
         ki = step + _first_key_block(qi, bq, bk, window) if window else step
-        return bi, kv_head(hi), jnp.minimum(ki, last), zero
+        return bi, head(hi), jnp.minimum(ki, last), zero
 
-    q_spec = pl.BlockSpec((1, 1, bq, dh),
-                          lambda bi, hi, qi, step: (bi, hi, qi, zero))
-    kv_spec = pl.BlockSpec((1, 1, bk, dh), key_block)
+    def q_spec(width):
+        return pl.BlockSpec((1, 1, bq, width),
+                            lambda bi, hi, qi, step: (bi, hi, qi, zero))
+    in_specs = [q_spec(dh), pl.BlockSpec((1, 1, bk, dh), key_block),
+                pl.BlockSpec((1, 1, bk, dv), key_block)]
+    if second:
+        group2 = np.int32(h // second[1].shape[1])
+        in_specs += [q_spec(rope), pl.BlockSpec(
+            (1, 1, bk, rope), functools.partial(
+                key_block, head=lambda hi: jax.lax.div(hi, group2)))]
     return pl.pallas_call(
-        functools.partial(_attn_fwd_kernel, dh ** -0.5, window),
+        functools.partial(_attn_fwd_kernel, (dh + rope) ** -0.5, window,
+                          bool(second)),
         grid=(b, h, t // bq, span),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, pl.BlockSpec(
+        in_specs=in_specs,
+        out_specs=[q_spec(dv), pl.BlockSpec(
             (1, 1, 1, bq), lambda bi, hi, qi, step: (bi, hi, zero, qi))],
-        out_shape=[_varying_like(q, q.shape, q.dtype),
+        out_shape=[_varying_like(q, (b, h, t, dv), q.dtype),
                    _varying_like(q, (b, h, 1, t), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, LANE), jnp.float32),    # running max
                         pltpu.VMEM((bq, LANE), jnp.float32),    # running sum
-                        pltpu.VMEM((bq, dh), jnp.float32)],
+                        pltpu.VMEM((bq, dv), jnp.float32)],
         interpret=_interpret(interpret),
         compiler_params=_compiler_params(
-            None, _attn_fwd_vmem(dh, bq, bk, q.dtype.itemsize),
+            None, _attn_fwd_vmem(dh, bq, bk, q.dtype.itemsize, rope, dv),
             "causal_attention",
             ("parallel", "parallel", "parallel", "arbitrary")),
         name="causal_attention_fwd",
-    )(q, k, v)
+    )(q, k, v, *second)
 
 
 def _attn_backward(q, k, v, o, lse, do, bq: int, bk: int,
-                   interpret: Optional[bool], window: int = 0):
-    """(dq, dk, dv). Where k and v hold fewer heads than q the kernel
-    writes every query head's dk and dv (in float32) and the group's are
-    summed here: one pass over [b, h, t, dh] beside five products over it."""
+                   interpret: Optional[bool], window: int = 0,
+                   second: tuple = ()):
+    """(dq, dk, dv), and with ``second`` = (q2, k2) their (dq2, dk2) too.
+    Where k and v hold fewer heads than q the kernel writes every query
+    head's dk and dv (in float32) and the group's are summed here: one pass
+    over [b, h, t, dh] beside five products over it; so it is for k2."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     pl, pltpu = _pl(), _pltpu()
     b, h, t, dh = q.shape
+    dv = v.shape[3]
+    rope = second[0].shape[3] if second else 0
     hk = k.shape[1]
     group = h // hk
     span = causal_attention_walk(t, bq, bk, window)[1]
@@ -1063,65 +1128,112 @@ def _attn_backward(q, k, v, o, lse, do, bq: int, bk: int,
             return jnp.maximum(step, first)
         return jnp.minimum(first + step, np.int32(t // bq - 1))
 
-    q_spec = pl.BlockSpec(
-        (1, 1, bq, dh),
-        lambda bi, hi, kj, step: (bi, hi, query_block(kj, step), zero))
-    kv_spec = pl.BlockSpec(
-        (1, 1, bk, dh), lambda bi, hi, kj, step: (bi, kv_head(hi), kj, zero))
-    dkv_spec = pl.BlockSpec((1, 1, bk, dh),
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bq, width),
+            lambda bi, hi, kj, step: (bi, hi, query_block(kj, step), zero))
+
+    def kv_spec(width, head=kv_head):
+        return pl.BlockSpec(
+            (1, 1, bk, width),
+            lambda bi, hi, kj, step: (bi, head(hi), kj, zero))
+
+    def dkv_spec(width):
+        return pl.BlockSpec((1, 1, bk, width),
                             lambda bi, hi, kj, step: (bi, hi, kj, zero))
     row_spec = pl.BlockSpec(
         (1, 1, 1, bq),
         lambda bi, hi, kj, step: (bi, hi, zero, query_block(kj, step)))
-    head_spec = pl.BlockSpec((1, 1, t, dh),
-                             lambda bi, hi, kj, step: (bi, hi, zero, zero))
-    dkv = _varying_like(q, q.shape, q.dtype if group == 1 else jnp.float32)
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_attn_bwd_kernel, dh ** -0.5, window),
+
+    def head_spec(width):
+        return pl.BlockSpec((1, 1, t, width),
+                            lambda bi, hi, kj, step: (bi, hi, zero, zero))
+
+    def like(width, dtype):
+        return _varying_like(q, (b, h, t, width), dtype)
+    summed = q.dtype if group == 1 else jnp.float32
+    in_specs = [q_spec(dh), kv_spec(dh), kv_spec(dv), q_spec(dv), row_spec,
+                row_spec]
+    out_specs = [head_spec(dh), dkv_spec(dh), dkv_spec(dv)]
+    out_shape = [like(dh, q.dtype), like(dh, summed), like(dv, summed)]
+    scratch = [pltpu.VMEM((t, dh), jnp.float32),
+               pltpu.VMEM((bk, dh), jnp.float32),
+               pltpu.VMEM((bk, dv), jnp.float32)]
+    if second:
+        h2 = second[1].shape[1]
+        group2 = np.int32(h // h2)
+        summed2 = q.dtype if h2 == h else jnp.float32
+        in_specs += [q_spec(rope), kv_spec(
+            rope, lambda hi: jax.lax.div(hi, group2))]
+        out_specs += [head_spec(rope), dkv_spec(rope)]
+        out_shape += [like(rope, q.dtype), like(rope, summed2)]
+        scratch += [pltpu.VMEM((t, rope), jnp.float32),
+                    pltpu.VMEM((bk, rope), jnp.float32)]
+    grads = pl.pallas_call(
+        functools.partial(_attn_bwd_kernel, (dh + rope) ** -0.5, window,
+                          bool(second)),
         grid=(b, h, t // bk, span),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[head_spec, dkv_spec, dkv_spec],
-        out_shape=[_varying_like(q, q.shape, q.dtype), dkv, dkv],
-        scratch_shapes=[pltpu.VMEM((t, dh), jnp.float32),
-                        pltpu.VMEM((bk, dh), jnp.float32),
-                        pltpu.VMEM((bk, dh), jnp.float32)],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=_interpret(interpret),
         compiler_params=_compiler_params(
             None, _attn_bwd_vmem(t, dh, bq, bk, 4 if group > 1
-                                 else q.dtype.itemsize),
+                                 else q.dtype.itemsize, rope, dv),
             "causal_attention",
             ("parallel", "parallel", "arbitrary", "arbitrary")),
         name="causal_attention_bwd",
-    )(q, k, v, do, lse, di[:, :, None, :])
-    if group > 1:
-        dk, dv = (g.reshape(b, hk, group, t, dh).sum(axis=2).astype(k.dtype)
-                  for g in (dk, dv))
-    return dq, dk, dv
+    )(q, k, v, do, lse, di[:, :, None, :], *second)
+
+    def group_sum(g, like_k):
+        """The sum over a key head's query heads (written in float32)."""
+        hk, width = like_k.shape[1], g.shape[3]
+        if hk == h:
+            return g
+        return g.reshape(b, hk, h // hk, t, width).sum(axis=2).astype(
+            like_k.dtype)
+    dq, dk, dv_ = grads[:3]
+    out = (dq, group_sum(dk, k), group_sum(dv_, v))
+    if second:
+        out += (grads[3], group_sum(grads[4], second[1]))
+    return out
 
 
 @functools.lru_cache(maxsize=None)
 def _causal_attention_fn(bq: int, bk: int, interpret: Optional[bool],
-                         window: int = 0):
+                         window: int = 0, second: bool = False):
     """The differentiable kernel at one block size and window, jitted once:
-    every layer of a step that calls it shares one lowering."""
+    every layer of a step that calls it shares one lowering. ``second``: the
+    function of five operands (q, k, v, q2, k2) whose scores are the sum of
+    two products; only a program that calls it traces that body."""
     import jax
 
-    @jax.custom_vjp
-    def attend(q, k, v):
-        return _attn_forward(q, k, v, bq, bk, interpret, window)[0]
+    def forward(q, k, v, *second):
+        return _attn_forward(q, k, v, bq, bk, interpret, window, second)
 
-    def fwd(q, k, v):
-        o, lse = _attn_forward(q, k, v, bq, bk, interpret, window)
-        return o, (q, k, v, o, lse)
+    def fwd(*operands):
+        o, lse = forward(*operands)
+        return o, (operands, o, lse)
 
     def bwd(kept, do):
-        return _attn_backward(*kept, do, bq, bk, interpret, window)
+        (q, k, v, *second), o, lse = kept
+        return _attn_backward(q, k, v, o, lse, do, bq, bk, interpret, window,
+                              tuple(second))
 
+    if second:
+        @jax.custom_vjp
+        def attend(q, k, v, q2, k2):
+            return forward(q, k, v, q2, k2)[0]
+    else:
+        @jax.custom_vjp
+        def attend(q, k, v):
+            return forward(q, k, v)[0]
     attend.defvjp(fwd, bwd)
     return jax.jit(attend)
 
 
-def causal_attention(q, k, v, *, window: int = 0,
+def causal_attention(q, k, v, *, window: int = 0, rope: Optional[tuple] = None,
                      block_q: Optional[int] = None,
                      block_k: Optional[int] = None,
                      interpret: Optional[bool] = None):
@@ -1149,31 +1261,49 @@ def causal_attention(q, k, v, *, window: int = 0,
     accumulates dk and dv over the query blocks and one head's dq in VMEM.
     No [b, h, t, t] tensor is written to HBM in either direction.
 
+    ``rope`` = (q2, k2), (batch, heads, t, d2) queries and (batch, a
+    divisor of heads, t, d2) keys: the scores are (q k^T + q2 k2^T) /
+    sqrt(dh + d2), a head's rotated part beside its unrotated one; with one
+    k2 head every query head reads it, again by the index map, and its dk2
+    is the sum over them. v may then be of a width of its own.
+
     Blocks default to :func:`causal_attention_blocks`; a shape outside the
     kernel's contract raises. :func:`ring_attention` above is the other
     attention kernel here: it rotates K/V over a ring of devices, holds whole
     operands in VMEM and has no backward pass; this one is local and is what
     a train step runs."""
     t, dh = q.shape[2:]
+    rope = tuple(rope or ())
+    d2 = rope[0].shape[3] if rope else 0
     if block_q is None or block_k is None:
-        blocks = causal_attention_blocks(t, dh)
+        blocks = causal_attention_blocks(t, dh, d2, v.shape[3])
         if blocks is None:
             raise ValueError(
-                f"causal_attention: (t, dh) = ({t}, {dh}) is outside the "
+                f"causal_attention: (t, dh, second term, values) = ({t}, "
+                f"{dh}, {d2}, {v.shape[3]}) is outside the "
                 f"kernel's contract (t a multiple of {_ATTN_BLOCKS[-1]}, dh "
                 f"64 or a multiple of {LANE}, one head's dq in VMEM)")
         block_q, block_k = block_q or blocks[0], block_k or blocks[1]
     if t % block_q or t % block_k:
         raise ValueError(f"causal_attention: blocks ({block_q}, {block_k}) "
                          f"do not divide t = {t}")
-    if not (k.shape == v.shape and q.dtype == k.dtype == v.dtype
+    if not (k.shape[:3] == v.shape[:3] and q.dtype == k.dtype == v.dtype
+            and (bool(rope) or k.shape == v.shape)
             and q.shape[:1] + q.shape[2:] == k.shape[:1] + k.shape[2:]
             and q.shape[1] % k.shape[1] == 0):
         raise ValueError("causal_attention: q, k, v differ in dtype or in "
                          "shape beyond a whole number of query heads a "
                          "key/value head")
-    return _causal_attention_fn(block_q, block_k, interpret,
-                                int(window))(q, k, v)
+    if rope and not (
+            rope[0].shape[:3] == q.shape[:3] and rope[0].dtype == q.dtype
+            == rope[1].dtype and rope[1].shape[3] == d2
+            and rope[1].shape[:1] + rope[1].shape[2:3] == q.shape[:1] + (t,)
+            and q.shape[1] % rope[1].shape[1] == 0):
+        raise ValueError("causal_attention: the second term's queries are "
+                         "not q's in all but width, or its keys not theirs "
+                         "in all but a whole number of heads a key head")
+    return _causal_attention_fn(block_q, block_k, interpret, int(window),
+                                bool(rope))(q, k, v, *rope)
 
 
 # ---------------------------------------------------------------------------
