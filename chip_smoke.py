@@ -73,6 +73,7 @@ FULL = {
     "ring": 250_000,              # per-device elements of the ring kernels
     "attn": (2048, 128),          # per-device (seq, head_dim), bf16 causal
     "grouped": (8192, 2048, 1024, 16),  # rows, k, n, groups of one product
+    "row_sums": (4096, 4096, 7680),     # rows summed into places x width
     # an expert layer at OLMoE's widths: d_model, d_ff, experts, top, seq
     "expert_layer": (2048, 1024, 64, 8, 4096),
     # a layer's row movement at OLMoE's shape: tokens, d_model, experts, top
@@ -90,6 +91,7 @@ TINY = {
     "ring": 1000,                 # the interpreter stalls on larger rings
     "attn": (32, 64),
     "grouped": (256, 128, 128, 5),
+    "row_sums": (256, 256, 128),
     "expert_layer": (128, 256, 4, 2, 64),
     "expert_rows": (64, 128, 4, 2),
     "window_attn": (4, 2, 256, 128, 100),
@@ -563,6 +565,29 @@ def leg_kernels(sz: dict, platform: str) -> dict:
         # both round a float32 sum to bf16 once; the sums' orders differ
         assert rel < 1e-2, f"grouped_matmul {name} off by {rel}"
         facts["grouped_matmul_rel_err"][name] = rel
+    # -- rows summed into indexed places ----------------------------------------
+    # a held expert layer's combine at its widest (PR 33): weighed bf16 rows,
+    # the last quarter masked out, sorted by place and summed in float32 by
+    # `grouped_row_sums` (the weights' gradient kernel, a one-hot that
+    # carries the weight as its left operand), against XLA's scatter-add
+    from tpu_mpi.parallel import ep
+    m, places, width = sz["row_sums"]
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    rows = jax.random.normal(keys[0], (m, width),
+                             jnp.float32).astype(jnp.bfloat16)
+    place = jax.random.randint(keys[1], (m,), 0, places, jnp.int32)
+    scale = jax.random.uniform(keys[2], (m,), jnp.float32)
+    live = jnp.arange(m) < 3 * m // 4
+    got = timed("grouped_row_sums[float32]", jax.jit(
+        lambda lhs, moved, counts: pk.grouped_row_sums(
+            lhs, moved, counts, interpret=interpret)),
+        *ep._by_place(rows, place, places, scale, live)).reshape(places,
+                                                                 width)
+    want = jnp.zeros((places, width), jnp.float32).at[place].add(
+        jnp.where(live[:, None], rows.astype(jnp.float32) * scale[:, None], 0))
+    rel = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    assert rel < 1e-5, f"grouped_row_sums off by {rel}"     # float32 both
+    facts["row_sums_rel_err"] = rel
     # -- the local attention kernel under a window, grouped heads ---------------
     # one block as a layer kind with a window has it (query head j reads
     # key/value head j // group), forward and backward, against the plain

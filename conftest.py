@@ -15,13 +15,23 @@ test_the_accepted_metrics_stand`."""
 
 import pytest
 
-LAST_ENTRIES_TEST = ("yardstick/tests/test_lm_kinds_train_step.py::"
-                     "test_the_accepted_metrics_stand")
+# (PR 33 appends three entries more, two of them to the openPangu cell:
+# PR 32's own `names[-n:] == MINE`, and its line that the cell reports
+# exactly its fifteen, are falsified in their turn; both are asserted again,
+# by position from the end and with the three added, in
+# `yardstick/tests/test_row_sum_product_share.py`.)
+LAST_ENTRIES_TESTS = (
+    "yardstick/tests/test_lm_kinds_train_step.py::"
+    "test_the_accepted_metrics_stand",
+    "yardstick/tests/test_lm_latent_train_step.py::"
+    "test_the_accepted_metrics_stand",
+    "yardstick/tests/test_lm_latent_train_step.py::"
+    "test_the_cell_reports_what_the_issue_names")
 
 
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid.endswith(LAST_ENTRIES_TEST):
+        if item.nodeid.endswith(LAST_ENTRIES_TESTS):
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,
                 reason="asserts its entries are per_layer's last; later PRs "

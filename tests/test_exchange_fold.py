@@ -366,11 +366,42 @@ def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(
         # lowered to defines each distinct kernel once, 3 kinds x 2 weight
         # shapes beside attention's two, and calls it from every layer
         kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
+        # and since PR 33 the embedding's gradient, its rows summed into
+        # their places as a product: one kernel more, no scatter under
+        # `embed`
         assert sorted(kernels) == sorted(
-            ["causal_attention_fwd", "causal_attention_bwd"]
+            ["causal_attention_fwd", "causal_attention_bwd",
+             "grouped_row_sums"]
             + ["grouped_matmul_fwd", "grouped_matmul_dlhs",
                "grouped_matmul_drhs"] * 2), kernels
+        assert _row_scatters(hlo) == []
     assert "bf16[64,8192," not in hlo           # no [experts, tokens, ..] product
+
+
+def _row_scatters(hlo: str) -> list:
+    """The `op_name`s of the compiled scatters that lie under `dispatch`,
+    `combine` or `embed`."""
+    import re
+    return [name for name in re.findall(
+        r"(?m)^.* scatter\(.*op_name=\"([^\"]*)\"", hlo)
+        if re.search(r"\b(dispatch|combine|embed)\b", name)]
+
+
+def _no_row_is_scattered(hlo: str, cfg) -> None:
+    """A held model's compiled step (PR 33): the rows of `combine`, of the
+    transpose of `dispatch`'s gather and of the embedding's gradient are
+    summed into their places by the product (`grouped_row_sums`, under
+    those scopes: the first buffer's two a sparse layer, the further
+    buffers' two, the embedding's one) and by no scatter."""
+    import re
+    sums = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                      r"op_name=\"([^\"]*grouped_row_sums[^\"]*)\"", hlo)
+    sparse = sum(cfg.layer_kind(i).sparse for i in range(cfg.n_layers))
+    where = [re.findall(r"\b(dispatch|combine|embed)\b", name)[-1]
+             for name in sums]
+    assert sorted(where) == sorted(
+        ["combine", "dispatch"] * 2 * sparse + ["embed"]), where
+    assert _row_scatters(hlo) == []
 
 
 def test_compiled_for_the_v5e_the_attention_kernel_at_the_flagships_shape(
@@ -456,9 +487,10 @@ def test_compiled_for_the_v5e_the_layer_kind_step_fits_one_chip(
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
     assert sorted(set(kernels)) == [
         "causal_attention_bwd", "causal_attention_fwd", "grouped_matmul_dlhs",
-        "grouped_matmul_drhs", "grouped_matmul_fwd"]
+        "grouped_matmul_drhs", "grouped_matmul_fwd", "grouped_row_sums"]
     assert kernels.count("causal_attention_fwd") == \
         kernels.count("causal_attention_bwd") == 2, kernels
+    _no_row_is_scattered(hlo, cfg)
 
 
 def test_compiled_for_the_v5e_the_attention_kernel_with_a_window_and_groups(
@@ -584,7 +616,8 @@ def test_compiled_for_the_v5e_the_latent_step_fits_one_chip(v5e_2x2,
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
     assert sorted(set(kernels)) == [
         "causal_attention_bwd", "causal_attention_fwd", "grouped_matmul_dlhs",
-        "grouped_matmul_drhs", "grouped_matmul_fwd"]
+        "grouped_matmul_drhs", "grouped_matmul_fwd", "grouped_row_sums"]
+    _no_row_is_scattered(hlo, cfg)
     # two kinds of layer (dense, sparse), ONE attention kind: one pair
     assert kernels.count("causal_attention_fwd") == \
         kernels.count("causal_attention_bwd") == 1, kernels

@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 from ..parallel import ring
 from ..parallel.dp import allreduce_grads
 from ..parallel.ep import (grouped_products, held_row_buffer, moe_dropless,
-                           moe_dropless_held)
+                           moe_dropless_held, rows_at)
 from ..parallel.ring import (fused_attention_selected, local_attention,
                              ring_attention, warm_kernel_imports)
 from ..parallel.tp import column_parallel, row_parallel
@@ -351,7 +351,7 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
     # every op's metadata, forward and backward: a device trace is read by
     # them (PERF.md section 3, "train step")
     with jax.named_scope("embed"):
-        x = params["embed"][tokens]                               # (b, t, d)
+        x = rows_at(params["embed"], tokens, scope="embed")      # (b, t, d)
     routed = []
     for i, layer in enumerate(params["layers"]):
         block = _block_traced_once(cfg, cfg.layer_kind(i), tp_axis, sp_axis,

@@ -24,7 +24,11 @@ Four realizations live here:
 - :func:`moe_dropless_held` — the same layer on a rank that holds a few of
   the experts and is given no exchange to run (one chip's share of an
   expert-parallel layer): the part of the result its experts give, from a
-  row buffer that follows the slots that arrive, nothing dropped;
+  row buffer that follows the slots that arrive, nothing dropped; its rows
+  are read by :func:`rows_at` and summed into their tokens' places by
+  :func:`sum_rows`, which is also how the model's embedding is read and
+  its gradient summed: a product with a 0/1 matrix on the MXU where the
+  grouped kernels are selected, XLA's scatter-add elsewhere;
 - :func:`moe_dispatch_combine` — top-1, rank == expert, tokens over a fixed
   capacity dropped (static shapes, capacity masking, ``lax.all_to_all``):
   the pipelined demo's (`transformer_pp_moe_*`);
@@ -39,6 +43,7 @@ Four realizations live here:
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Callable, Optional
 
@@ -254,6 +259,183 @@ def moe_dropless(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
         return _tokens_of_rows(out, order, inverse), sizes
 
 
+# -- rows summed into indexed places ------------------------------------------
+#
+# out[p] = sum over the rows i with place[i] == p of scale[i] x rows[i]: the
+# held layer's combine, the transpose of its dispatch gather, the embedding's
+# gradient. XLA's scatter-add runs it one to two orders under what the memory
+# allows (2.7-8 G elements a second at the held cells' shapes: PERF.md
+# section 6, PR 33), so where the grouped kernels are selected it is a
+# product with a 0/1 matrix on the MXU: the rows are sorted by place (a sort
+# of the places, one gather of the rows), a block of 128 places then owns a
+# run of rows, and the sum of a block is onehot[its rows, 128]^T x its rows,
+# accumulated in float32 (`pallas_kernels.grouped_row_sums`: the kernel of
+# the experts' weights' gradient). The sum and the gather `source[place]` are
+# each other's transposes and, there, each other's gradients, as
+# `_rows_of_tokens` and `_tokens_of_rows` are.
+
+def row_sum_selected(rows_shape: tuple, places: int, dtype) -> bool:
+    """Whether ``rows[m, d]`` of ``dtype`` (float32 where they are weighed)
+    are summed into ``places`` places by the product on the MXU and not by
+    XLA's scatter-add: decided where :func:`grouped_matmul_selected`
+    decides, from the backend and the kernel's contract (blocks of 128
+    places, rows a multiple of a row tile, the width of 128), never by
+    trying it."""
+    from ..xla import pallas_kernels as pk
+    dtype = jnp.dtype(dtype)
+    return (ring._kernel_backend() is not None and places % pk.LANE == 0
+            and str(dtype) in pk.GROUPED_DTYPES
+            and pk.grouped_row_sums_blocks(*rows_shape,
+                                           dtype.itemsize) is not None)
+
+
+def _by_place(rows, place, places: int, scale, live):
+    """The product's operands: (the one-hot ``[m, 128]`` of each row's place
+    within its block of 128 places, carrying the row's float32 ``scale``
+    where there is one, in the order of the places; ``rows`` in that order,
+    float32 where they are weighed; the rows in each block). A row that is
+    not ``live`` comes last, under a place past all, and with it a row
+    whose place lies outside [-places, places): indexing wraps a negative
+    place and drops an update out of range. One key-value sort of ``[m]``
+    vectors, one gather of the rows, a comparison grid."""
+    key = place.astype(jnp.int32)
+    key = jnp.where(key < 0, key + places, key)
+    inside = jnp.logical_and(key >= 0, key < places)
+    key = jnp.where(inside if live is None else jnp.logical_and(inside, live),
+                    key, places)
+    key, order, *scale = lax.sort(
+        (key, jnp.arange(key.shape[0], dtype=jnp.int32))
+        + (() if scale is None else (scale.astype(jnp.float32),)),
+        num_keys=1, is_stable=True)
+    blocks = jnp.arange(places // 128, dtype=jnp.int32)
+    sizes = jnp.sum(key[:, None] // 128 == blocks[None, :], axis=0,
+                    dtype=jnp.int32)
+    hot = (key % 128)[:, None] == jnp.arange(128, dtype=jnp.int32)[None, :]
+    if not scale:
+        return hot.astype(rows.dtype), rows[order], sizes
+    return (jnp.where(hot, scale[0][:, None], 0),
+            rows[order].astype(jnp.float32), sizes)
+
+
+@functools.lru_cache(maxsize=None)
+def _summed(places: int, dtype, interpret: bool):
+    """The sum as the product (the form's own words are above): float32
+    products where a scale weighs the rows, the rows' own dtype into the
+    MXU where none does; float32 accumulation, one rounding to ``dtype``.
+    Jitted once a destination: the sums of a program that share their
+    shapes (a layer's forward pass and its recomputation, its first buffer
+    and the further ones, two kinds of sparse layer: 13 calls of three
+    shapes in the K-EXAONE step) share one trace, which is set-up time
+    (PERF.md, Set-up)."""
+    from ..xla import pallas_kernels as pk
+
+    @jax.jit
+    def summed(rows, place, scale, live):
+        return pk.grouped_row_sums(
+            *_by_place(rows, place, places, scale, live), out_dtype=dtype,
+            interpret=interpret).reshape(places, rows.shape[1])
+    return summed
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _sum_rows(rows, place, scale, live, places: int, dtype, scope: str):
+    return _summed(places, dtype, ring._kernel_backend() == "interpret")(
+        rows, place, scale, live)
+
+
+def _sum_rows_bwd(places, dtype, scope, kept, g):
+    rows, place, scale, live = kept
+    with jax.named_scope(scope):
+        back = _rows_at(g, place, places, scope).astype(jnp.float32)
+        if live is not None:
+            back = jnp.where(live[:, None], back, 0)
+        if scale is None:
+            return back.astype(rows.dtype), None, None, None
+        d_scale = jnp.sum(back * rows.astype(jnp.float32), axis=1)
+        return ((back * scale.astype(jnp.float32)[:, None]).astype(
+            rows.dtype), None, d_scale.astype(scale.dtype), None)
+
+
+_sum_rows.defvjp(lambda rows, place, scale, live, *static: (
+    _sum_rows(rows, place, scale, live, *static),
+    (rows, place, scale, live)), _sum_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rows_at(source, place, places: int, scope: str):
+    return source[place]
+
+
+def _rows_at_bwd(places, scope, place, g):
+    with jax.named_scope(scope):
+        return _sum_rows(g, place, None, None, places, g.dtype, scope), None
+
+
+_rows_at.defvjp(lambda source, place, places, scope: (source[place], place),
+                _rows_at_bwd)
+
+
+def sum_rows(rows: jnp.ndarray, place: jnp.ndarray, places: int, *,
+             scale: Optional[jnp.ndarray] = None,
+             live: Optional[jnp.ndarray] = None, dtype=None,
+             scope: str = "sum_rows") -> jnp.ndarray:
+    """``out[p] = sum over the rows i with place[i] == p (and live[i]) of
+    scale[i] x rows[i]``: ``rows[m, d]`` summed into ``[places, d]`` of
+    ``dtype`` (the rows' where None), float32 products and a float32 sum,
+    rounded once. Places nobody is sent to come out zero; a row that is not
+    ``live`` adds nothing and its place is not read.
+
+    Where :func:`row_sum_selected` says so it is the product on the MXU
+    described above, with a gradient of its own (the rows' is the gather
+    ``g[place] x scale``, the scale's the rows' dot with it; ``scope`` names
+    their ops); everywhere else ``jnp.zeros(..).at[place].add(..)``, XLA's
+    scatter-add, left to JAX's differentiation. Each call built into a
+    traced program counts in ``perfvars.snapshot()["row_sum_lowerings"]`` as
+    ``product`` or ``scatter``."""
+    dtype = jnp.dtype(rows.dtype if dtype is None else dtype)
+    weighed = jnp.float32 if scale is not None else rows.dtype
+    if row_sum_selected(rows.shape, places, weighed):
+        perfvars.note_row_sum_lowering("product")
+        rows, place, *rest = _vary_together(rows, place, scale, live)
+        return _sum_rows(rows, place, *rest, places, dtype, scope)
+    perfvars.note_row_sum_lowering("scatter")
+    if scale is not None:
+        rows = rows.astype(jnp.float32) * scale.astype(jnp.float32)[:, None]
+    if live is not None:
+        rows = jnp.where(live[:, None], rows, 0)
+    return jnp.zeros((places, rows.shape[1]),
+                     jnp.promote_types(rows.dtype, jnp.float32)
+                     ).at[place].add(rows).astype(dtype)
+
+
+def rows_at(source: jnp.ndarray, place: jnp.ndarray, *,
+            scope: str = "rows_at") -> jnp.ndarray:
+    """``source[place]``: the rows of ``source[places, d]`` at ``place`` (of
+    any shape), the transpose of :func:`sum_rows`. Where the product form
+    is selected for its gradient (these rows of the source's dtype summed
+    back into its places; ``scope`` names the gradient's ops) the gradient
+    is that sum; everywhere else the indexing is left to JAX, whose
+    transpose is XLA's scatter-add. Counted as :func:`sum_rows` counts."""
+    if row_sum_selected((place.size,) + source.shape[1:], source.shape[0],
+                        source.dtype):
+        perfvars.note_row_sum_lowering("product")
+        source, flat = _vary_together(source, place.reshape(-1))
+        return _rows_at(source, flat, source.shape[0], scope).reshape(
+            place.shape + source.shape[1:])
+    perfvars.note_row_sum_lowering("scatter")
+    return source[place]
+
+
+def _vary_together(*xs):
+    """The arrays among ``xs`` (a None stays), each made to vary over every
+    mesh axis any of them varies over: what a custom gradient's operands
+    need under `shard_map`, where a replicated operand's gradient is then
+    summed over those axes by the cast's own transpose."""
+    from ..xla import pallas_kernels as pk
+    some = pk._vary_together(*(x for x in xs if x is not None))
+    return [None if x is None else some.pop(0) for x in xs]
+
+
 def _zeros_varying_like(x):
     """Zeros of x's shape and dtype that vary over the mesh axes x varies
     over: what a `scan`'s carry and a `cond`'s other branch must be typed as
@@ -277,8 +459,9 @@ def held_row_buffer(slots: int, n_experts: int, held: int,
     slot of every token, so with the factor at 2 a single hot expert alone
     never spills: a small share of many experts has few balanced rows and
     a skewed router sends it several times those, PERF.md PR 32); rounded
-    up to a multiple of 128 (the grouped kernel's contract), no more than
-    all slots."""
+    up to a multiple of 128 (the contract of the grouped kernels: the
+    experts' products, and the sums of the buffer's rows into their tokens'
+    places and back, :func:`sum_rows`), no more than all slots."""
     want = HELD_ROWS_FACTOR * max(slots * held / n_experts, tokens / 2)
     rows = -(-int(want) // 128) * 128
     return max(128, min(rows, -(-slots // 128) * 128))
@@ -295,34 +478,36 @@ def moe_dropless_held(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
     ``expert_fn(rows, group_sizes)``, over ``held`` groups.
 
     Static shapes without a capacity: the held experts' slots are sorted
-    first and served from a buffer of ``buffer_rows`` rows (what
-    :func:`held_row_buffer` gives); the rows of a token's slots are added
-    into the token's place (one scatter-add of ``buffer_rows`` rows, not a
-    gather over all t x k slots). Where more slots arrive than the buffer
-    holds, the rest is served in further buffers of the same size, behind a
-    ``lax.cond`` that the common case does not enter (recomputed in the
-    backward pass, so they keep nothing): no slot of a held expert is
-    dropped at any imbalance.
+    first, their weights the sort's payload (:func:`_by_expert`), and served
+    from a buffer of ``buffer_rows`` rows (what :func:`held_row_buffer`
+    gives): the buffer's tokens are read by :func:`rows_at` and the
+    experts' rows, weighed, are summed into their tokens' places by
+    :func:`sum_rows` (``buffer_rows`` rows in float32, not a gather over
+    all t x k slots), which where the grouped kernels are selected is a
+    product on the MXU in both directions and XLA's scatter-add elsewhere;
+    no vector of the layer is moved by a scatter. Where more slots arrive
+    than the buffer holds, the rest is served in further buffers of the
+    same size, behind a ``lax.cond`` that the common case does not enter
+    (recomputed in the backward pass, so they keep nothing): no slot of a
+    held expert is dropped at any imbalance.
 
     Returns ((t, d) out, (n_experts,) int32 token-slots per expert of these
     tokens, over all experts, and (3,) int32 [rows the experts computed,
     rows gathered, 1 if the further buffers ran])."""
     t, k = expert_idx.shape
-    d = tokens.shape[-1]
     slots = t * k
     rows_max = -(-slots // buffer_rows) * buffer_rows
     with jax.named_scope("dispatch"):
         flat = expert_idx.reshape(slots)
         local = flat - first
         here = jnp.logical_and(local >= 0, local < held)
-        order = jnp.argsort(jnp.where(here, local, held),
-                            stable=True).astype(jnp.int32)
+        order, scale = _by_expert(weights, jnp.where(here, local, held))
         order = jnp.pad(order, (0, rows_max - slots))
+        scale = jnp.pad(scale, (0, rows_max - slots))
         sizes = jnp.sum(flat[:, None] == jnp.arange(n_experts)[None, :],
                         axis=0, dtype=jnp.int32)
         ends = jnp.cumsum(sizes[first:first + held])
         arrived = ends[-1]
-    flat_w = weights.reshape(slots)
 
     def serve(n):
         """What rows [n x buffer_rows, (n + 1) x buffer_rows) of the held
@@ -330,18 +515,18 @@ def moe_dropless_held(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
         lo = n * buffer_rows
         with jax.named_scope("dispatch"):
             slot = lax.dynamic_slice(order, (lo,), (buffer_rows,))
+            w = lax.dynamic_slice(scale, (lo,), (buffer_rows,))
             live = lo + jnp.arange(buffer_rows, dtype=jnp.int32) < arrived
             token = slot // k
-            rows = tokens[token]
+            rows = rows_at(tokens, token, scope="dispatch")
             part = jnp.clip(ends - lo, 0, buffer_rows)
             part = part - jnp.concatenate([part[:1] * 0, part[:-1]])
         with jax.named_scope("experts"):
             out = expert_fn(rows, part.astype(jnp.int32))
         with jax.named_scope("combine"):
-            w = jnp.where(live, flat_w[slot], 0).astype(jnp.float32)
-            out = jnp.where(live[:, None], out.astype(jnp.float32), 0)
-            return jnp.zeros((t, d), jnp.float32).at[token].add(
-                out * w[:, None]), part.sum().astype(jnp.int32)
+            return sum_rows(out, token, t, scale=w, live=live,
+                            dtype=jnp.float32, scope="combine"), \
+                part.sum().astype(jnp.int32)
 
     acc, computed = serve(0)
     further = rows_max // buffer_rows - 1

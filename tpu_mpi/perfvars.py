@@ -17,8 +17,10 @@ pieces:
   snapshot time from ``overlap.plans.stats()``, the wall time spent
   registering plans and compiling folds as ``arming_s``, the attention
   calls built into traced programs as the fused kernel or the plain path
-  as ``attn_lowerings``, and the experts' grouped multiplications as the
-  grouped kernel or `lax.ragged_dot` as ``gmm_lowerings``.
+  as ``attn_lowerings``, the experts' grouped multiplications as the
+  grouped kernel or `lax.ragged_dot` as ``gmm_lowerings``, and the sums of
+  rows into indexed places as the product on the MXU or XLA's scatter-add
+  as ``row_sum_lowerings``.
   ``fold`` and ``copy`` on device operands are DISPATCH times: the host
   seconds it took to enqueue the fold (its operand copies included) and
   the copy-out, not the seconds the device worked. The device's end of
@@ -574,6 +576,21 @@ def note_gmm_lowering(kind: str) -> None:
         _gmm_lowerings[kind] += 1
 
 
+# `parallel.ep.sum_rows` / `rows_at` likewise: rows summed into indexed places
+# (a held expert layer's combine, the transpose of its dispatch gather, the
+# embedding's gradient) as the product on the MXU or as XLA's scatter-add,
+# one count per call where the selection is made.
+
+_row_sum_lowerings = {"product": 0, "scatter": 0}
+
+
+def note_row_sum_lowering(kind: str) -> None:
+    """One sum of rows into places (or the gather whose gradient it is) was
+    traced as the ``product`` or as the ``scatter``."""
+    with _store_lock:
+        _row_sum_lowerings[kind] += 1
+
+
 # -- the device's end of a copy between chips --------------------------------
 #
 # A host span around an asynchronous copy times the enqueue. An op that
@@ -1077,6 +1094,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "attn_lowerings": dict(_attn_lowerings),
             "attn_kinds": _attn_kinds(),
             "gmm_lowerings": dict(_gmm_lowerings),
+            "row_sum_lowerings": dict(_row_sum_lowerings),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
             "serve_frame": serve_frame_snapshot(),
@@ -1120,6 +1138,7 @@ def reset() -> None:
         _attn_lowerings.update(fused=0, plain=0)
         _attn_by_kind.clear()
         _gmm_lowerings.update(kernel=0, ragged_dot=0)
+        _row_sum_lowerings.update(product=0, scatter=0)
         _store_gen += 1
 
 

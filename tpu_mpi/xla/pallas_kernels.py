@@ -1609,10 +1609,13 @@ def _gmm_call(lhs, rhs, visits, tm: int, tc: int, transposed: bool,
 
 
 def _tgmm_call(lhs, dout, g: int, visits, tm: int, tc: int,
-               interpret: Optional[bool]):
+               interpret: Optional[bool], out_dtype=None,
+               name: str = "grouped_matmul_drhs"):
     """The weights' gradient ``[g, k, n]``: for each group ``lhs[rows of
     g]^T x dout[rows of g]``. Grid (k tile, n tile, visit): the visits are
-    the reduction, one float32 accumulator per block of a group."""
+    the reduction, one float32 accumulator per block of a group. (Its other
+    caller, :func:`grouped_row_sums`, names the result's dtype and the
+    call.)"""
     import jax.numpy as jnp
     import numpy as np
     pl, pltpu = _pl(), _pltpu()
@@ -1634,12 +1637,12 @@ def _tgmm_call(lhs, dout, g: int, visits, tm: int, tc: int,
                 (None, tk, tn), lambda ki, ni, v, offs, group, tile, matrix,
                 visits: (jnp.minimum(group[v], top), ki, ni)),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
-        out_shape=_varying_like(lhs, (g, k, n), lhs.dtype),
+        out_shape=_varying_like(lhs, (g, k, n), out_dtype or lhs.dtype),
         interpret=_interpret(interpret),
         compiler_params=_compiler_params(
             None, _grouped_vmem(tm, k, n, tc, lhs.dtype.itemsize),
             "grouped_matmul", ("parallel", "parallel", "arbitrary")),
-        name="grouped_matmul_drhs",
+        name=name,
     )(*visits, lhs, dout)
 
 
@@ -1730,3 +1733,77 @@ def grouped_matmul(lhs, rhs, group_sizes, *, visits=None,
     lhs, rhs, *visits = _vary_together(lhs, rhs, *visits)
     return _grouped_matmul_fn(block_m, block_c, interpret)(lhs, rhs,
                                                            tuple(visits))
+
+
+# ---------------------------------------------------------------------------
+# rows summed into indexed places (a held expert layer's combine, the
+# transpose of a row gather, an embedding's gradient): the weights' gradient
+# kernel above with a one-hot as its left operand
+# ---------------------------------------------------------------------------
+
+# column tiles of the sum, widest first: the widest that divides the rows'
+# width (7680 = 4 x 1920, 6144 = 3 x 2048); a width no wider than one whole
+_ROW_SUM_COL_TILES = tuple(range(2048, 0, -LANE))
+
+
+def grouped_row_sums_blocks(m: int, n: int,
+                            itemsize: int) -> Optional[tuple]:
+    """(row tile, column tile) of :func:`grouped_row_sums` for ``[m, 128]``
+    times ``[m, n]`` operands of ``itemsize`` bytes an element, or None
+    where the kernel's contract does not hold (``m`` a multiple of a row
+    tile, ``n`` of 128, the blocks in VMEM as
+    :func:`grouped_matmul_blocks` counts them)."""
+    tm = next((t for t in _GROUPED_ROW_TILES if m % t == 0), None)
+    if n % LANE or min(m, n) <= 0 or tm is None:
+        return None
+    tc = next((c for c in _ROW_SUM_COL_TILES
+               if n % c == 0 and 2 * _grouped_vmem(tm, LANE, n, c, itemsize)
+               <= VMEM_LIMIT_BYTES), None)
+    return tc and (tm, tc)
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_row_sums_fn(tm: int, tc: int, interpret: Optional[bool],
+                         out_dtype: str):
+    """The sum at one tiling and result type, jitted once: every sum of a
+    program with the same shapes shares one trace of the kernel's body and
+    one lowering (what a kernel costs at set-up: PERF.md, Set-up)."""
+    import jax
+
+    @jax.jit
+    def sums(lhs, rows, visits):
+        return _tgmm_call(lhs, rows, visits[0].shape[0] - 2, visits, tm, tc,
+                          interpret, out_dtype, "grouped_row_sums")
+    return sums
+
+
+def grouped_row_sums(lhs, rows, group_sizes, *, out_dtype=None,
+                     interpret: Optional[bool] = None):
+    """``[g, 128, n]``: for each group of rows, ``lhs[its rows]^T x
+    rows[its rows]``, the first ``group_sizes[0]`` rows of ``lhs[m, 128]``
+    and ``rows[m, n]`` in group 0 and so on, accumulated in float32 and
+    rounded once to ``out_dtype`` (the operands' where None); rows past the
+    groups' sum are not read, an empty group's result is zero. With ``lhs``
+    the one-hot of each row's place within its group's 128 places (times a
+    weight, where the rows are weighed) this sums rows sorted by place into
+    their places: ``parallel.ep.sum_rows``. It is the kernel of
+    :func:`grouped_matmul`'s weights' gradient, walked the same way
+    (:func:`grouped_matmul_visits`); bf16 operands enter the MXU as they
+    are, float32 ones at full precision. Tiles are
+    :func:`grouped_row_sums_blocks`'s; a shape outside the contract raises."""
+    import numpy as np
+    m, k = lhs.shape
+    n = rows.shape[1]
+    blocks = grouped_row_sums_blocks(m, n, lhs.dtype.itemsize)
+    if rows.shape[0] != m or lhs.dtype != rows.dtype or k != LANE \
+            or blocks is None:
+        raise ValueError("grouped_row_sums: lhs [m, 128] and rows [m, n] of "
+                         "one dtype, m a multiple of "
+                         f"{_GROUPED_ROW_TILES[-1]} and n of {LANE}, are "
+                         f"needed, not {lhs.shape} {lhs.dtype}, {rows.shape} "
+                         f"{rows.dtype}")
+    visits = grouped_matmul_visits(group_sizes, m, blocks[0])
+    lhs, rows, *visits = _vary_together(lhs, rows, *visits)
+    return _grouped_row_sums_fn(
+        *blocks, interpret,
+        str(np.dtype(out_dtype or lhs.dtype)))(lhs, rows, tuple(visits))
