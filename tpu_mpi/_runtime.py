@@ -1320,6 +1320,7 @@ def _warm_jax_backend() -> None:
     enable_compile_cache()
     require_backend()
     import jax.numpy as jnp
+    _pv.listen_builds()     # every build of the run from here on is counted
     jnp.zeros(1).block_until_ready()
     _jax_warmed = True
 

@@ -77,6 +77,8 @@ def Init_thread(required: ThreadLevel,
         # no launcher warmed the backend for this rank (a rank process or a
         # standalone world of one): TPU_MPI_BACKEND=tpu is enforced here
         _runtime.require_backend()
+        from . import perfvars
+        perfvars.listen_builds()    # a no-op while jax is not imported
         if os.environ.get("TPU_MPI_PROC_RANK") is not None:
             # Launched as one process of a multi-process world
             # (tpurun --procs): rendezvous over the native transport.

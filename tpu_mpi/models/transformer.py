@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from .. import perfvars
 from ..parallel import ring
 from ..parallel.dp import allreduce_grads
 from ..parallel.ep import (grouped_products, held_row_buffer, moe_dropless,
@@ -757,6 +758,7 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
         return params, loss
 
     data_spec = P(dp_axis, sp_axis)
+    perfvars.note_step_fun(local_step.__name__)     # before anything is built
     step = jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, data_spec, data_spec),
@@ -956,6 +958,7 @@ def transformer_pp_moe_train_step(cfg: TransformerConfig, mesh,
         return params, loss
 
     data_spec = P(dp_axis, None)
+    perfvars.note_step_fun(local_step.__name__)
     step = jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, data_spec, data_spec),
@@ -1077,6 +1080,7 @@ def transformer_4d_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2,
         return params, loss
 
     data_spec = P(dp_axis, sp_axis)
+    perfvars.note_step_fun(local_step.__name__)
     step = jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, data_spec, data_spec),

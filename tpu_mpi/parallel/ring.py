@@ -46,7 +46,13 @@ def warm_kernel_imports() -> None:
     on the import lock for what is left."""
     if _kernel_backend() is not None:
         from ..xla import pallas_kernels as pk
-        threading.Thread(target=pk.load, name="tpu_mpi-pallas-import",
+
+        def load():     # set-up, but no arming: a span alone, in no pvar
+            t0 = perfvars.monotonic()
+            pk.load()
+            perfvars.publish_setup_span("kernels.import", t0,
+                                        perfvars.monotonic())
+        threading.Thread(target=load, name="tpu_mpi-pallas-import",
                          daemon=True).start()
 
 

@@ -20,7 +20,10 @@ pieces:
   as ``attn_lowerings``, the experts' grouped multiplications as the
   grouped kernel or `lax.ragged_dot` as ``gmm_lowerings``, and the sums of
   rows into indexed places as the product on the MXU or XLA's scatter-add
-  as ``row_sum_lowerings``.
+  as ``row_sum_lowerings``, and what JAX traced, lowered, compiled and read
+  from its persistent cache, by function, with the Pallas kernels built
+  under those traces, as the family ``build`` (:func:`listen_builds`;
+  docs/observability.md "Set-up spans").
   ``fold`` and ``copy`` on device operands are DISPATCH times: the host
   seconds it took to enqueue the fold (its operand copies included) and
   the copy-out, not the seconds the device worked. The device's end of
@@ -65,6 +68,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -98,6 +102,8 @@ class _TLS(threading.local):
     # AttributeError/getattr-default dance on the hot path
     scope = None                      # the open _OpScope of this thread
     setup = None                      # id of the open setup_span, if any
+    tracing = 0                       # JAX traces open on this thread
+    cache_read = None                 # the open compile's "hit" | "miss"
     acct = None                       # (store_gen, {key: CommPvars}) cache
     wait_owned = False                # a wait-time owner is on the stack
 
@@ -482,6 +488,19 @@ _arming: List[Tuple[float, float]] = []
 _ARMING_CAP = 4096
 
 
+def publish_setup_span(name: str, t0: float, t1: float,
+                       sid: Optional[str] = None, **attrs: Any) -> None:
+    """With span sampling on, one span of the ``setup:`` trace from this
+    thread, child of the :class:`setup_span` open on it: a span alone, in no
+    pvar (JAX's ``build.*``, the Pallas import's ``kernels.import``)."""
+    if _tc.enabled():
+        from ._runtime import current_env
+        env = current_env()
+        who = f"rank {env[1]}" if env is not None else "rank ?"
+        _tc.emit_setup_span(name, t0, t1, who, sid or _tc.new_id(),
+                            _tls.setup, **attrs)
+
+
 class setup_span:
     """``with setup_span("plan.register", cid=...)``: time one piece of
     arming. Nested ones (``fold.compile`` under ``plan.register``) are its
@@ -509,12 +528,7 @@ class setup_span:
             with _store_lock:
                 if len(_arming) < _ARMING_CAP:
                     _arming.append((self.t0, t1))
-        if _tc.enabled():
-            from ._runtime import current_env
-            env = current_env()
-            who = f"rank {env[1]}" if env is not None else "rank ?"
-            _tc.emit_setup_span(self.name, self.t0, t1, who, self.sid,
-                                self.parent, **self.attrs)
+        publish_setup_span(self.name, self.t0, t1, self.sid, **self.attrs)
         return False
 
 
@@ -589,6 +603,189 @@ def note_row_sum_lowering(kind: str) -> None:
     traced as the ``product`` or as the ``scatter``."""
     with _store_lock:
         _row_sum_lowerings[kind] += 1
+
+
+# -- build: what JAX traced, lowered, compiled and read from its cache --------
+#
+# JAX times these boundaries itself, inside its own context managers, and
+# hands each time to whoever listens (``jax.monitoring``), with the
+# function's name and with no frame of ours on the traced call stack: a
+# Mosaic kernel's serialized body carries that stack and is part of the
+# persistent cache's key, so a timing wrapper around a lowering misses the
+# cache that a plain run hits. :func:`listen_builds` is the one listener;
+# the family ``build`` of :func:`snapshot` is what it keeps.
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+#: the phases of a build, and where each one's [n, s] pair starts in a row
+_BUILD_PHASES = {_TRACE_EVENT: 0,
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration": 2,
+                 "/jax/core/compile/backend_compile_duration": 4}
+_BUILD_NAMES = ("trace", "lower", "compile")
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "misses"}
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_SECONDS = {"/jax/compilation_cache/cache_retrieval_time_sec": "load_s",
+                  "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
+_BUILD_FUN_CAP = 256                    # names kept in ``by_fun`` ...
+BUILD_REST = "(others)"                 # ... and the key the rest sum under
+
+_build_listening = False
+_wall_to_mono = 0.0                     # JAX stamps time.time(); spans don't
+_build_total = [0, 0.0, 0, 0.0, 0, 0.0]         # [n, s] x trace, lower, compile
+_build_cache = {"hits": 0, "misses": 0, "load_s": 0.0, "saved_s": 0.0}
+_build_by_fun: Dict[str, List[float]] = {}      # name -> a row like the total
+_build_step: set = set()
+_build_kernels: Dict[str, int] = {}
+
+
+def _build_fun(name: Any) -> str:
+    """One key for the three phases of one program: JAX names the trace by
+    the function (``local_step``) and the lowered module and the compile by
+    the module (``jit(local_step)``; ``jit_local_step`` in older ones)."""
+    name = str(name)
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]
+    return name[4:] if name.startswith("jit_") else name
+
+
+def _on_build_enter(event: str, _start: float, **_kw: Any) -> None:
+    """JAX's scalar at a timed block's entry: traces nest (a jitted function
+    called under a trace is traced inside it), and only the outermost one's
+    seconds may go into the total."""
+    if event == _TRACE_EVENT:
+        _tls.tracing += 1
+
+
+def _on_build_span(event: str, start: float, end: float, **kw: Any) -> None:
+    """A trace, a lowering or a backend compile has ended on this thread,
+    from ``start`` to ``end`` on JAX's ``time.time()``."""
+    at = _BUILD_PHASES.get(event)
+    if at is None:
+        return
+    outermost, cache = True, None
+    if at == 0:
+        _tls.tracing = depth = max(0, _tls.tracing - 1)
+        outermost = depth == 0
+    elif at == 4:
+        cache, _tls.cache_read = _tls.cache_read or "off", None
+    if not enabled():
+        return
+    fun, secs = _build_fun(kw.get("fun_name", "?")), end - start
+    with _store_lock:
+        row = _build_by_fun.get(fun)
+        if row is None:
+            if len(_build_by_fun) >= _BUILD_FUN_CAP:
+                fun = BUILD_REST
+            row = _build_by_fun.setdefault(fun, [0, 0.0, 0, 0.0, 0, 0.0])
+        row[at] += 1
+        row[at + 1] += secs
+        if outermost:
+            _build_total[at] += 1
+            _build_total[at + 1] += secs
+    if _tc.enabled():
+        attrs = {"fun": fun}
+        if at == 4:
+            attrs["cache"] = cache
+        publish_setup_span("build." + _BUILD_NAMES[at // 2],
+                           start + _wall_to_mono, end + _wall_to_mono, **attrs)
+
+
+def _on_cache_event(event: str, **_kw: Any) -> None:
+    """The persistent cache was asked for the executable that this thread
+    is compiling, had it (a hit), or was given it to keep (a miss)."""
+    key = _CACHE_EVENTS.get(event)
+    if key == "hits":
+        _tls.cache_read = "hit"
+    elif event == _CACHE_ASKED:
+        _tls.cache_read = "miss"
+    if key is not None and enabled():
+        with _store_lock:
+            _build_cache[key] += 1
+
+
+def _on_cache_seconds(event: str, secs: float, **_kw: Any) -> None:
+    key = _CACHE_SECONDS.get(event)
+    if key is not None and enabled():
+        with _store_lock:
+            _build_cache[key] += secs
+
+
+def listen_builds() -> bool:
+    """Register, once, the listener behind the family ``build``. Called
+    where the program first touches JAX (the step builders of
+    ``models/transformer.py``, the thread tier's start, ``Init``); a process
+    that has not imported jax is left without it, and so is one with pvars
+    off. Returns whether the listener is there."""
+    global _build_listening, _wall_to_mono
+    if _build_listening:
+        return True
+    if "jax" not in sys.modules or not enabled():
+        return False
+    from jax import monitoring
+    with _store_lock:
+        if _build_listening:
+            return True
+        _build_listening = True
+        # one offset for the run: JAX's stamps are wall-clock readings
+        _wall_to_mono = monotonic() - time.time()
+    monitoring.register_scalar_listener(_on_build_enter)
+    monitoring.register_event_time_span_listener(_on_build_span)
+    monitoring.register_event_listener(_on_cache_event)
+    monitoring.register_event_duration_secs_listener(_on_cache_seconds)
+    return True
+
+
+def note_step_fun(name: str) -> None:
+    """A step builder names the function it is about to jit: ``build.step``
+    is how a reader finds the step's rows in ``by_fun``. A step builder is
+    also where such a program first touches JAX, so the listener is
+    registered here."""
+    listen_builds()
+    if enabled():
+        with _store_lock:
+            _build_step.add(name)
+
+
+def note_kernel_build(name: str) -> None:
+    """One ``pallas_call`` was built under a trace: its body traced now, and
+    lowered by Mosaic when the program around it is."""
+    if enabled():
+        with _store_lock:
+            _build_kernels[name] = _build_kernels.get(name, 0) + 1
+
+
+def _build_pairs(row: List[float]) -> dict:
+    return {name: {"n": row[2 * i], "s": row[2 * i + 1]}
+            for i, name in enumerate(_BUILD_NAMES)}
+
+
+def build_snapshot() -> dict:
+    """The ``build`` family of :func:`snapshot`, empty until something was
+    built or noted:
+
+    - ``trace`` / ``lower`` / ``compile``: ``{"n", "s"}``, events and
+      seconds of JAX's ``jaxpr_trace_duration``,
+      ``jaxpr_to_mlir_module_duration`` and ``backend_compile_duration``. A
+      trace inside another counts under its own name in ``by_fun`` and not
+      again here. ``compile`` is the backend's compile OR the persistent
+      cache's read in its place: a hit's retrieval lies inside it.
+    - ``cache``: ``hits`` and ``misses`` (executables read from the
+      persistent cache, and compiled and written to it), ``load_s`` the
+      seconds the reads took, ``saved_s`` the compile seconds they stood for.
+    - ``by_fun``: the three pairs by function (``jit(f)`` and ``f`` meet
+      under ``f``), a nested trace's seconds in its callers' too; the first
+      256 names, the rest summed under :data:`BUILD_REST`.
+    - ``step``: the names the step builders gave their jitted functions.
+    - ``kernels``: ``pallas_call``s built under a trace, by their name."""
+    with _store_lock:
+        if not (_build_by_fun or _build_step or _build_kernels
+                or any(_build_cache.values())):
+            return {}
+        return {**_build_pairs(_build_total), "cache": dict(_build_cache),
+                "by_fun": {f: _build_pairs(r)
+                           for f, r in sorted(_build_by_fun.items())},
+                "step": sorted(_build_step),
+                "kernels": dict(sorted(_build_kernels.items()))}
 
 
 # -- the device's end of a copy between chips --------------------------------
@@ -1095,6 +1292,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "attn_kinds": _attn_kinds(),
             "gmm_lowerings": dict(_gmm_lowerings),
             "row_sum_lowerings": dict(_row_sum_lowerings),
+            "build": build_snapshot(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
             "serve_frame": serve_frame_snapshot(),
@@ -1139,6 +1337,11 @@ def reset() -> None:
         _attn_by_kind.clear()
         _gmm_lowerings.update(kernel=0, ragged_dot=0)
         _row_sum_lowerings.update(product=0, scatter=0)
+        _build_total[:] = [0, 0.0, 0, 0.0, 0, 0.0]
+        _build_cache.update(hits=0, misses=0, load_s=0.0, saved_s=0.0)
+        _build_by_fun.clear()
+        _build_step.clear()
+        _build_kernels.clear()
         _store_gen += 1
 
 

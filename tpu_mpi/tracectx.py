@@ -322,7 +322,9 @@ def emit_round_span(name: str, cid: Any, rnd: Any, rank: int, t0: float,
 def emit_setup_span(name: str, t0: float, t1: float, who: str, sid: str,
                     parent: Optional[str] = None, **extra: Any) -> None:
     """Publish a set-up span (``plan.register``, ``fold.compile``,
-    ``jitted_fold.compile``) under the id its children already name."""
+    ``jitted_fold.compile``, ``kernels.import``, and JAX's own
+    ``build.trace`` / ``build.lower`` / ``build.compile`` beneath whichever
+    is open) under the id its children already name."""
     rec = {"trace": f"setup:{who}", "span": sid, "parent": parent,
            "name": name, "who": who, "t0": t0, "t1": t1, "status": "ok"}
     if extra:

@@ -39,6 +39,8 @@ import functools
 import math
 from typing import Any, Callable, Optional, Sequence
 
+from .. import perfvars
+
 LANE = 128      # TPU lane width: minor-most dim of every tile
 SUBLANE = 8     # sublane multiple of a 32-bit tile (second-minor dim)
 
@@ -1076,6 +1078,7 @@ def _attn_forward(q, k, v, bq: int, bk: int, interpret: Optional[bool],
         in_specs += [q_spec(rope), pl.BlockSpec(
             (1, 1, bk, rope), functools.partial(
                 key_block, head=lambda hi: jax.lax.div(hi, group2)))]
+    perfvars.note_kernel_build("causal_attention_fwd")
     return pl.pallas_call(
         functools.partial(_attn_fwd_kernel, (dh + rope) ** -0.5, window,
                           bool(second)),
@@ -1169,6 +1172,7 @@ def _attn_backward(q, k, v, o, lse, do, bq: int, bk: int,
         out_shape += [like(rope, q.dtype), like(rope, summed2)]
         scratch += [pltpu.VMEM((t, rope), jnp.float32),
                     pltpu.VMEM((bk, rope), jnp.float32)]
+    perfvars.note_kernel_build("causal_attention_bwd")
     grads = pl.pallas_call(
         functools.partial(_attn_bwd_kernel, (dh + rope) ** -0.5, window,
                           bool(second)),
@@ -1590,6 +1594,7 @@ def _gmm_call(lhs, rhs, visits, tm: int, tc: int, transposed: bool,
 
     name = "grouped_matmul_dlhs" if transposed else "grouped_matmul_fwd"
     k, n = (cols, c) if transposed else (c, cols)
+    perfvars.note_kernel_build(name)
     return pl.pallas_call(
         functools.partial(_gmm_kernel, g, transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1623,6 +1628,7 @@ def _tgmm_call(lhs, dout, g: int, visits, tm: int, tc: int,
     n = dout.shape[1]
     tk, tn = min(k, tc), min(n, tc)
     top = np.int32(g - 1)
+    perfvars.note_kernel_build(name)
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, g),
         grid_spec=pltpu.PrefetchScalarGridSpec(
