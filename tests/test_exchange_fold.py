@@ -369,9 +369,11 @@ def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(
         # and since PR 33 the embedding's gradient, its rows summed into
         # their places as a product: one kernel more, no scatter under
         # `embed`
+        # and since PR 36 the whole-vector norm of q and of k with their
+        # rotation, on the cut heads of 128 (one shape for both: a pair)
         assert sorted(kernels) == sorted(
             ["causal_attention_fwd", "causal_attention_bwd",
-             "grouped_row_sums"]
+             "grouped_row_sums", "norm_rope_fwd", "norm_rope_bwd"]
             + ["grouped_matmul_fwd", "grouped_matmul_dlhs",
                "grouped_matmul_drhs"] * 2), kernels
         assert _row_scatters(hlo) == []
@@ -468,7 +470,8 @@ def test_compiled_for_the_v5e_the_layer_kind_step_fits_one_chip(
     assert m.alias_size_in_bytes > 5.0e9        # the parameters are reused
     assert 10e9 < held < 15e9, held
     hlo = compiled.as_text()
-    assert ",8192,8192]" not in hlo             # no [.., t, t] scores
+    # no [.., heads, t, t] scores (q's token-major row is [1, t, 64 x 128])
+    assert not re.search(r"\[\d+,\d+,8192,8192\]", hlo)
     calls = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
                        r"op_name=\"([^\"]*causal_attention_(\w+)[^\"]*)\"", hlo)
     assert all("/attn/" in name for name, _d in calls)
@@ -487,9 +490,14 @@ def test_compiled_for_the_v5e_the_layer_kind_step_fits_one_chip(
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
     assert sorted(set(kernels)) == [
         "causal_attention_bwd", "causal_attention_fwd", "grouped_matmul_dlhs",
-        "grouped_matmul_drhs", "grouped_matmul_fwd", "grouped_row_sums"]
+        "grouped_matmul_drhs", "grouped_matmul_fwd", "grouped_row_sums",
+        "norm_rope_bwd", "norm_rope_fwd"]
     assert kernels.count("causal_attention_fwd") == \
         kernels.count("causal_attention_bwd") == 2, kernels
+    # the window layers norm and rotate q's 64 heads and k's 8: two shapes,
+    # a pair each; the full layer rotates nothing and norms as it did
+    assert kernels.count("norm_rope_fwd") == \
+        kernels.count("norm_rope_bwd") == 2, kernels
     _no_row_is_scattered(hlo, cfg)
 
 
@@ -514,6 +522,89 @@ def test_compiled_for_the_v5e_the_attention_kernel_with_a_window_and_groups(
         shapes = [o.shape for o in jax.tree.leaves(compiled.out_info)]
         assert shapes == [(1, 64, 8192, 128), (1, 8, 8192, 128),
                           (1, 8, 8192, 128)]
+
+
+ROPE_ROWS = {      # (batch, tokens, heads, parts) of a train cell's row
+    "flagship packed q|k|v of 64": (8, 1024, 16, ((64, True), (64, True),
+                                                  (64, False))),
+    "openPangu q row, 128 | 64 rotated": (1, 4096, 64, ((128, False),
+                                                        (64, True))),
+    "a grouped-query row of 128-wide heads": (1, 8192, 8, ((128, True),)),
+}
+ROPE_HEADS = {     # heads that are cut before they are normed and rotated
+    "OLMoE q or k, the whole vector's norm": ((2, 16, 4096, 128), (16, 128),
+                                              2048),
+    "K-EXAONE q, 64 heads, a norm a head": ((1, 64, 8192, 128), (128,), 128),
+    "K-EXAONE k, 8 heads, a norm a head": ((1, 8, 8192, 128), (128,), 128),
+}
+
+
+@pytest.mark.parametrize("heads", sorted(ROPE_HEADS))
+def test_compiled_for_the_v5e_cut_heads_are_normed_and_rotated_by_one_kernel(
+        v5e_2x2, heads):
+    """`pallas_kernels.norm_rope` at a train cell's [batch, heads, tokens,
+    128], bfloat16: the cell's norm of q and k and the rotation, one kernel
+    forward, one backward, and no other pass over the heads; the scale's
+    gradient is the sum of the blocks' partial sums."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import pallas_kernels as pk
+    one = SingleDeviceSharding(v5e_2x2[0])
+    shape, scale_shape, denom = ROPE_HEADS[heads]
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    table = jax.ShapeDtypeStruct((shape[2], 128), jnp.float32, sharding=one)
+    scale = jax.ShapeDtypeStruct(scale_shape, jnp.bfloat16, sharding=one)
+
+    def both(x, scale, cos, sin, cotangent):
+        out, back = jax.vjp(lambda x, scale: pk.norm_rope(
+            x, scale, cos, sin, eps=1e-6, denom=denom, interpret=False),
+            x, scale)
+        return out, back(cotangent)
+    lowered = jax.jit(both).lower(x, scale, table, table, x)
+    assert set(re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())) \
+        == {"norm_rope_fwd", "norm_rope_bwd"}
+    hlo = lowered.compile().as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    # (beside them: the sum of the scale's partial sums and its rounding)
+    assert not re.search(r" (?:transpose|concatenate|pad)\(", hlo)
+    assert len(re.findall(r" fusion\(", hlo)) <= 2
+
+
+@pytest.mark.parametrize("row", sorted(ROPE_ROWS))
+def test_compiled_for_the_v5e_the_rotation_is_one_kernel_each_way(v5e_2x2,
+                                                                  row):
+    """`pallas_kernels.rope_heads` at a train cell's shape, bfloat16, lowers
+    through Mosaic forward and backward (lane rotations, 64-lane pieces
+    joined and cut): two kernels and no other computation, the
+    parts come out [batch, heads, tokens, width] and the row's gradient
+    token-major."""
+    import re
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import pallas_kernels as pk
+    one = SingleDeviceSharding(v5e_2x2[0])
+    b, t, heads, parts = ROPE_ROWS[row]
+    lanes = pk.rope_heads_plan(parts)[0]
+    width = heads * sum(w for w, _turned in parts)
+    operands = (
+        jax.ShapeDtypeStruct((b, t, width), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((t, lanes), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((t, lanes), jnp.float32, sharding=one),
+        tuple(jax.ShapeDtypeStruct((b, heads, t, w), jnp.bfloat16,
+                                   sharding=one) for w, _turned in parts))
+
+    def both(x, cos, sin, cotangents):
+        outs, back = jax.vjp(lambda x: pk.rope_heads(
+            x, cos, sin, heads, parts, interpret=False), x)
+        return outs, back(cotangents)[0]
+    compiled = jax.jit(both).lower(*operands).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    # (a 64-wide part that is this program's own argument or result is
+    # copied between the layout a kernel is handed and the entry's)
+    assert not re.search(r" (?:fusion|transpose|concatenate|pad)\(", hlo)
+    outs, d_row = compiled.out_info
+    assert [o.shape for o in outs] == [(b, heads, t, w) for w, _t in parts]
+    assert d_row.shape == (b, t, width) and d_row.dtype == jnp.bfloat16
 
 
 def test_compiled_for_the_v5e_the_attention_kernel_with_two_term_scores(
@@ -616,8 +707,12 @@ def test_compiled_for_the_v5e_the_latent_step_fits_one_chip(v5e_2x2,
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
     assert sorted(set(kernels)) == [
         "causal_attention_bwd", "causal_attention_fwd", "grouped_matmul_dlhs",
-        "grouped_matmul_drhs", "grouped_matmul_fwd", "grouped_row_sums"]
+        "grouped_matmul_drhs", "grouped_matmul_fwd", "grouped_row_sums",
+        "rope_heads_bwd", "rope_heads_fwd"]
     _no_row_is_scattered(hlo, cfg)
-    # two kinds of layer (dense, sparse), ONE attention kind: one pair
+    # two kinds of layer (dense, sparse), ONE attention kind: one pair, and
+    # one pair for the query row's [128 unrotated | 64 rotated] heads
     assert kernels.count("causal_attention_fwd") == \
-        kernels.count("causal_attention_bwd") == 1, kernels
+        kernels.count("causal_attention_bwd") == \
+        kernels.count("rope_heads_fwd") == \
+        kernels.count("rope_heads_bwd") == 1, kernels

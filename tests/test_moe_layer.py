@@ -453,8 +453,23 @@ def _flagship_forward_before(cfg, params, tokens):
     return (x @ params["embed"].T).astype(jnp.float32)
 
 
+FLAGSHIP_JIT_ATOL = {"float32": 1e-5, "bfloat16": 0.0}
+
+
+def _cut_then_halves(row, positions, theta, heads, parts):
+    """`tf._rope_heads` for the packed [head][q|k|v] row as the flagship had
+    it before PR 36: the cut into heads first, then the rotation as two
+    half-width products, concatenated (`tf._rope_halves`)."""
+    assert [turned for _w, turned in parts] == [True, True, False]
+    b, t, _ = row.shape
+    qkv = row.reshape(b, t, heads, 3, -1).transpose(0, 2, 1, 3, 4)
+    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    return (tf._rope_halves(q, positions, theta),
+            tf._rope_halves(k, positions, theta), v)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_the_default_config_is_the_flagship_bit_for_bit(dtype):
+def test_the_default_config_is_the_flagship_bit_for_bit(dtype, monkeypatch):
     cfg = TransformerConfig(vocab=128, d_model=32, n_heads=4, n_layers=3,
                             d_ff=64, max_seq=16, dtype=jnp.dtype(dtype))
     key = jax.random.key(7)
@@ -465,23 +480,49 @@ def test_the_default_config_is_the_flagship_bit_for_bit(dtype):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
     tokens = jax.random.randint(jax.random.key(8), (2, 16), 0, cfg.vocab)
+    labels = jnp.roll(tokens, -1, axis=1)
+
+    def programs():
+        return (jax.make_jaxpr(jax.grad(lambda p: tf._xent(
+                    tf._forward(cfg, p, tokens)[0], labels)))(params),
+                jax.make_jaxpr(jax.grad(lambda p: tf._xent(
+                    _flagship_forward_before(cfg, p, tokens), labels)))(before))
     # the layers share one jitted trace since PR 29 (`_block_traced_once`);
     # with that jit taken away the layer is what it was, op for op
     with jax.disable_jit():
+        # The rotation is its own function since PR 36 (x cos2 + swap(x) sin2
+        # on the row, before the cut, with the inverse rotation for a
+        # backward): the forward's numbers are the frozen copy's, bit for
+        # bit (x1 cos - x2 sin and x1 cos + x2 (-sin) round alike, and so do
+        # the two orders of the second half's sum), the program is not.
         np.testing.assert_array_equal(
             transformer_forward(cfg, params, tokens),
             _flagship_forward_before(cfg, before, tokens))
-        # the same program, not only the same numbers: equation for equation
-        labels = jnp.roll(tokens, -1, axis=1)
-        now = jax.make_jaxpr(jax.grad(lambda p: tf._xent(
-            tf._forward(cfg, p, tokens)[0], labels)))(params)
-        was = jax.make_jaxpr(jax.grad(lambda p: tf._xent(
-            _flagship_forward_before(cfg, p, tokens), labels)))(before)
-    assert str(now) == str(was)
+        now, was = programs()
+        assert str(now) != str(was)
+        # With the old order and form back in that one place, everything
+        # else is the same program, not only the same numbers: equation for
+        # equation
+        monkeypatch.setattr(tf, "_rope_heads", _cut_then_halves)
+        now, was = programs()
+        assert str(now) == str(was)
+
+    def jitted():
+        tf._block_traced_once.cache_clear()     # the layer as `tf` has it now
+        return (jax.jit(lambda p: transformer_forward(cfg, p, tokens))(params),
+                jax.jit(lambda p: _flagship_forward_before(cfg, p, tokens))(
+                    before))
     # and jitted as a step jits it, the shared trace changes no number
-    np.testing.assert_array_equal(
-        jax.jit(lambda p: transformer_forward(cfg, p, tokens))(params),
-        jax.jit(lambda p: _flagship_forward_before(cfg, p, tokens))(before))
+    np.testing.assert_array_equal(*jitted())
+    monkeypatch.undo()
+    # Compiled, the new form is a rounding away from the old one: XLA's CPU
+    # backend contracts a product and a sum into one fused multiply-add,
+    # and of x2 cos + x1 sin it does not keep the product it kept of
+    # x1 sin + x2 cos. Float32 round-off through three layers; bfloat16's
+    # one rounding of the float32 result hides it at this size.
+    now, was = jitted()
+    np.testing.assert_allclose(now, was, rtol=0, atol=FLAGSHIP_JIT_ATOL[dtype])
+    tf._block_traced_once.cache_clear()
 
 
 # -- a row's way into expert order and back (PR 31) ----------------------------

@@ -18,6 +18,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -301,16 +302,109 @@ def _rms_norm(x, scale, eps: float = 1e-6):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
-def _rope(x, positions, theta: float = 10000.0):
-    """Rotary embeddings; positions are *global* so sequence shards agree."""
-    b, h, t, dh = x.shape
-    half = dh // 2
+def _rope_table(positions, theta: float, width: int, lane_in_head):
+    """(cos, sin) [t, lanes] float32 for lanes whose place within a rotary
+    head of ``width`` is ``lane_in_head`` (static; < 0: the lane passes): each
+    angle stands twice, once for either half of the head, and ``sin``
+    carries the first half's minus sign; a passing lane reads cos 1, sin 0.
+    Positions are *global*, so sequence shards agree."""
+    half = width // 2
+    lane_in_head = np.asarray(lane_in_head)
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    per_lane = jnp.where(lane_in_head >= 0, freqs[lane_in_head % half], 0.0)
+    ang = positions[:, None].astype(jnp.float32) * per_lane[None, :]
+    sign = np.where(lane_in_head < half, -1.0, 1.0).astype(np.float32)
+    return jnp.cos(ang), jnp.sin(ang) * sign
+
+
+@jax.custom_vjp
+def _turn(x, cos, sin):
+    """``x cos + swap(x) sin`` over the last axis, one rotary head: `swap`
+    exchanges the head's halves (a flip of its [2, half] view: no half-width
+    slice, no concatenate). Float32 inside, rounded to x's type once. The
+    transpose of a rotation is the rotation by the negative angle, so the
+    gradient is this function again with `sin` negated: one elementwise pass
+    that keeps the tables alone."""
+    *lead, width = x.shape
+    swapped = jnp.flip(x.reshape(*lead, 2, width // 2), -2).reshape(x.shape)
+    return (x.astype(jnp.float32) * cos
+            + swapped.astype(jnp.float32) * sin).astype(x.dtype)
+
+
+_turn.defvjp(lambda x, cos, sin: (_turn(x, cos, sin), (cos, sin)),
+             lambda kept, d: (_turn(d, kept[0], -kept[1]), None, None))
+
+
+def _rope_halves(x, positions, theta: float = 10000.0):
+    """Rotary embeddings as two half-width products, concatenated, and
+    whatever autodiff makes of that: what `_rope` was, and what a head of
+    odd width (whose last value passes) still takes."""
+    half = x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[:, None].astype(jnp.float32) * freqs[None, :]   # (t, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    out = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if x.shape[-1] % 2:
+        out.append(x[..., 2 * half:].astype(jnp.float32))
+    return jnp.concatenate(out, axis=-1).astype(x.dtype)
+
+
+def _rope_kernel(dtype) -> Optional[str]:
+    """How a rotation's kernel would run for operands of ``dtype``
+    (`ring._kernel_backend`'s word), or None where none is selected."""
+    from ..xla import pallas_kernels as pk
+    return ring._kernel_backend() if str(dtype) in pk.ROPE_DTYPES else None
+
+
+def _rope(x, positions, theta: float = 10000.0):
+    """Rotary embeddings of x [..., t, width], each last axis one head;
+    positions are *global* so sequence shards agree. x cos2 + swap(x) sin2
+    with the inverse rotation for a backward (`_turn`), as plain `jnp`: what
+    a small operand takes (latent attention's one shared rotary key) and
+    what the kernels' contracts refuse (`_rope_heads`, `_norm_and_rope`).
+    The form counts in ``perfvars.snapshot()["rope_forms"]``: `dense`, or
+    `halves` where the width is odd."""
+    width = x.shape[-1]
+    if width % 2:
+        perfvars.note_rope_form("halves")
+        return _rope_halves(x, positions, theta)
+    perfvars.note_rope_form("dense")
+    return _turn(x, *_rope_table(positions, theta, width, np.arange(width)))
+
+
+def _cut_heads(row, heads: int):
+    """(b, t, heads x width) -> (b, heads, t, width)."""
+    b, t, _ = row.shape
+    return row.reshape(b, t, heads, -1).transpose(0, 2, 1, 3)
+
+
+def _rope_heads(row, positions, theta: float, heads: int, parts: tuple):
+    """A token-major row [b, t, heads x period], every head the ``parts`` =
+    ((width, rotated), ..) side by side (a packed projection's q | k | v, a
+    latent query's unrotated | rotated), as one [b, heads, t, width] array a
+    part with the rotated ones turned. Where the backend and the pattern
+    select it (`ring._kernel_backend`, `pallas_kernels.rope_heads_blocks`:
+    widths of 64 or multiples of 128, a rotary width of 64 or 128) that is
+    one kernel each way, `pallas_kernels.rope_heads`: the rotation runs on
+    the row, where it is 128 lanes dense, and the cut into heads is the
+    kernel's write; elsewhere the cut, then `_rope` on each rotated part."""
+    from ..xla import pallas_kernels as pk
+    t = row.shape[1]
+    turned = [w for w, rotated in parts if rotated]
+    how = _rope_kernel(row.dtype)
+    if how is not None and turned and pk.rope_heads_blocks(t, heads, parts):
+        for _ in turned:
+            perfvars.note_rope_form("dense")
+        cos, sin = _rope_table(positions, theta, turned[0],
+                               pk.rope_heads_lanes(parts))
+        return pk.rope_heads(row, cos, sin, heads, parts,
+                             interpret=how == "interpret")
+    ends = np.cumsum([w for w, _rotated in parts])
+    return tuple(
+        _rope(part, positions, theta) if rotated else part
+        for part, (_w, rotated) in zip(
+            jnp.split(_cut_heads(row, heads), ends[:-1], axis=-1), parts))
 
 
 def transformer_forward(cfg: TransformerConfig, params: dict,
@@ -605,14 +699,25 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     if cfg.kv_latent:
         return _latent_attn(cfg, layer, y, positions, tp_axis=tp_axis,
                             sp_axis=sp_axis)
+    # A projection's product is a token-major row, [b, t, heads x width]:
+    # 128 lanes dense whatever a head's width. Where nothing stands between
+    # it and the rotation, q and k are rotated there and the cut into the
+    # [b, heads, t, width] that the attention wants is the same pass
+    # (`_rope_heads`). A norm of q and k stands between: the heads are cut
+    # first, as they were, then normed and rotated where they are
+    # (`_norm_and_rope`: one pass where a kernel is selected).
+    rotate = bool(window or cfg.rope_full_layers)
+    normed = cfg.qk_norm or cfg.qk_norm_heads
     if cfg.n_kv_heads:
         if tp_axis is not None and lax.axis_size(tp_axis) > 1:
             raise NotImplementedError(
                 "grouped-query attention runs at tp 1 (its key/value heads "
                 "are not yet cut over tp)")
-        q, k, v = (
-            (y @ layer[w]).reshape(b, t, -1, dh).transpose(0, 2, 1, 3)
-            for w in ("w_q", "w_k", "w_v"))
+        q, k, v = (y @ layer[w] for w in ("w_q", "w_k", "w_v"))
+        q, k, v = (_rope_heads(
+            a, positions, cfg.rope_theta, a.shape[2] // dh,
+            ((dh, rotated and rotate and not normed),))[0]
+            for a, rotated in ((q, True), (k, True), (v, False)))
     else:
         if tp_axis is not None:
             qkv = column_parallel(y, layer["w_qkv"], axis=tp_axis)
@@ -621,17 +726,13 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
         # w_qkv columns are packed per head ([head][q|k|v][dh]) so a
         # contiguous tp column shard holds whole heads and the sharded
         # forward equals the single-device one.
-        qkv = qkv.reshape(b, t, h_local, 3, dh).transpose(0, 2, 1, 3, 4)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-    if cfg.qk_norm:
-        q = _whole_vector_norm(cfg, q, layer["q_norm"], tp_axis)
-        k = _whole_vector_norm(cfg, k, layer["k_norm"], tp_axis)
-    if cfg.qk_norm_heads:
-        q = _rms_norm(q, layer["q_norm"], cfg.norm_eps)
-        k = _rms_norm(k, layer["k_norm"], cfg.norm_eps)
-    if window or cfg.rope_full_layers:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q, k, v = _rope_heads(
+            qkv, positions, cfg.rope_theta, h_local,
+            ((dh, rotate and not normed),) * 2 + ((dh, False),))
+    if normed:
+        q, k = (_norm_and_rope(cfg, a, layer[n], positions if rotate else None,
+                               tp_axis) for a, n in ((q, "q_norm"),
+                                                     (k, "k_norm")))
     if sp_axis is not None:
         o = ring_attention(q, k, v, axis=sp_axis, causal=True, window=window)
     else:
@@ -665,22 +766,20 @@ def _latent_attn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray,
     b, t, _ = y.shape
     h, dh, dr, dv = cfg.n_heads_here, cfg.head_dim, cfg.d_rope, cfg.value_dim
 
-    def heads(x, width):        # (b, t, h x width) -> (b, h, t, width)
-        return x.reshape(b, t, h, width).transpose(0, 2, 1, 3)
     with jax.named_scope("q_latent"):
         c_q = _rms_norm(y @ layer["w_dq"], layer["q_latent_norm"],
                         cfg.norm_eps)
-        q = heads(c_q @ layer["w_uq"], dh + dr)
-        q, q_rope = q[..., :dh], q[..., dh:]
+        q_row = c_q @ layer["w_uq"]     # (b, t, h x [unrotated | rotated])
     with jax.named_scope("kv_latent"):
         down = y @ layer["w_dkv"]
         c_kv = _rms_norm(down[..., :cfg.kv_latent], layer["kv_latent_norm"],
                          cfg.norm_eps)
         k_rope = down[:, None, :, cfg.kv_latent:]       # one head for all
-        kv = heads(c_kv @ layer["w_ukv"], dh + dv)
+        kv = _cut_heads(c_kv @ layer["w_ukv"], h)
         k, v = kv[..., :dh], kv[..., dh:]
-    with jax.named_scope("rope"):
-        q_rope = _rope(q_rope, positions, cfg.rope_theta)
+    with jax.named_scope("rope"):       # and the query row's cut into heads
+        q, q_rope = _rope_heads(q_row, positions, cfg.rope_theta, h,
+                                ((dh, False), (dr, True)))
         k_rope = _rope(k_rope, positions, cfg.rope_theta)
     if sp_axis is not None:
         o = ring_attention(q, k, v, axis=sp_axis, causal=True,
@@ -702,6 +801,38 @@ def _whole_vector_norm(cfg: TransformerConfig, x: jnp.ndarray,
         ss = lax.psum(ss, tp_axis)
     x = (x * lax.rsqrt(ss / cfg.d_model + cfg.norm_eps)).astype(x.dtype)
     return x * scale.reshape(h, 1, dh)
+
+
+def _norm_and_rope(cfg: TransformerConfig, x: jnp.ndarray, scale: jnp.ndarray,
+                   positions: Optional[jnp.ndarray], tp_axis: Optional[str]):
+    """q or k, cut into heads (b, heads_local, t, head_dim): normed by the
+    model's norm of q and k (`qk_norm`: the whole vector's; `qk_norm_heads`:
+    each head's) and, unless ``positions`` is None, rotated. Where the
+    backend and the shape select it (heads of 128, one of the two norms, a
+    whole-vector norm on one tp rank: it sums over the heads that are
+    here) norm and rotation are ONE kernel each way,
+    `pallas_kernels.norm_rope`: the norm's arithmetic as it stands below,
+    float32 inside and rounded where it rounds; elsewhere the norm, then
+    `_rope`."""
+    from ..xla import pallas_kernels as pk
+    b, h, t, dh = x.shape
+    how = _rope_kernel(x.dtype)
+    alone = tp_axis is None or lax.axis_size(tp_axis) == 1
+    if positions is not None and how is not None \
+            and cfg.qk_norm != cfg.qk_norm_heads \
+            and (alone or not cfg.qk_norm) and pk.norm_rope_blocks(
+                b * h, t, dh, x.dtype.itemsize, h if cfg.qk_norm else 0):
+        perfvars.note_rope_form("dense")
+        cos, sin = _rope_table(positions, cfg.rope_theta, dh, np.arange(dh))
+        return pk.norm_rope(
+            x, scale.reshape(h, dh) if cfg.qk_norm else scale, cos, sin,
+            eps=cfg.norm_eps, denom=cfg.d_model if cfg.qk_norm else dh,
+            interpret=how == "interpret")
+    if cfg.qk_norm:
+        x = _whole_vector_norm(cfg, x, scale, tp_axis)
+    if cfg.qk_norm_heads:
+        x = _rms_norm(x, scale, cfg.norm_eps)
+    return x if positions is None else _rope(x, positions, cfg.rope_theta)
 
 
 def _xent(logits, labels):
@@ -817,10 +948,8 @@ def _pp_moe_stage(cfg: TransformerConfig, n_experts: int, ep_axis: str,
     for i in range(L_local):
         # -- attention (heads local: this config spends its devices on pp/ep)
         y = _rms_norm(x, stage_params["ln1"][i])
-        qkv = (y @ stage_params["w_qkv"][i]).reshape(b, t, h, 3, dh)
-        qkv = qkv.transpose(0, 2, 1, 3, 4)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        q, k = _rope(q, positions), _rope(k, positions)
+        q, k, v = _rope_heads(y @ stage_params["w_qkv"][i], positions,
+                              10000.0, h, ((dh, True), (dh, True), (dh, False)))
         o = local_attention(q, k, v)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
         x = x + o @ stage_params["w_proj"][i]

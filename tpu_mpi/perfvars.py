@@ -20,7 +20,8 @@ pieces:
   as ``attn_lowerings``, the experts' grouped multiplications as the
   grouped kernel or `lax.ragged_dot` as ``gmm_lowerings``, and the sums of
   rows into indexed places as the product on the MXU or XLA's scatter-add
-  as ``row_sum_lowerings``, and what JAX traced, lowered, compiled and read
+  as ``row_sum_lowerings``, the rotary embeddings by their form as
+  ``rope_forms``, and what JAX traced, lowered, compiled and read
   from its persistent cache, by function, with the Pallas kernels built
   under those traces, as the family ``build`` (:func:`listen_builds`;
   docs/observability.md "Set-up spans").
@@ -603,6 +604,22 @@ def note_row_sum_lowering(kind: str) -> None:
     traced as the ``product`` or as the ``scatter``."""
     with _store_lock:
         _row_sum_lowerings[kind] += 1
+
+
+# `models.transformer._rope` / `_rope_heads` / `_norm_and_rope` likewise: each
+# rotary embedding built into a traced program, by its form: `dense` (x cos +
+# swap(x) sin with its own backward: a kernel where one is selected, the
+# same arithmetic as plain `jnp` elsewhere) or `halves` (two half-width
+# products concatenated, differentiated by autodiff: a head of odd width).
+
+_rope_forms = {"dense": 0, "halves": 0}
+
+
+def note_rope_form(form: str) -> None:
+    """One rotary embedding was traced in the ``dense`` or the ``halves``
+    form."""
+    with _store_lock:
+        _rope_forms[form] += 1
 
 
 # -- build: what JAX traced, lowered, compiled and read from its cache --------
@@ -1292,6 +1309,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "attn_kinds": _attn_kinds(),
             "gmm_lowerings": dict(_gmm_lowerings),
             "row_sum_lowerings": dict(_row_sum_lowerings),
+            "rope_forms": dict(_rope_forms),
             "build": build_snapshot(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
@@ -1337,6 +1355,7 @@ def reset() -> None:
         _attn_by_kind.clear()
         _gmm_lowerings.update(kernel=0, ragged_dot=0)
         _row_sum_lowerings.update(product=0, scatter=0)
+        _rope_forms.update(dense=0, halves=0)
         _build_total[:] = [0, 0.0, 0, 0.0, 0, 0.0]
         _build_cache.update(hits=0, misses=0, load_s=0.0, saved_s=0.0)
         _build_by_fun.clear()

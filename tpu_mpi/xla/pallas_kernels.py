@@ -6,13 +6,16 @@ generation), this module supplies the *custom-kernel* tier the reference
 reaches by linking libmpi's hand-written algorithms (SURVEY.md §2.4): ring
 collectives and neighbor transfers written directly against the ICI with
 ``pltpu.make_async_remote_copy`` (remote DMA) + semaphores, and a fused
-ring-attention kernel as the long-context demo SURVEY.md §5 calls for. Two
-kernels are local (no remote DMA) and differentiable, and are what a train
-step on one chip runs: ``causal_attention``, its attention, and
+ring-attention kernel as the long-context demo SURVEY.md §5 calls for. The
+other kernels are local (no remote DMA) and differentiable, and are what a
+train step on one chip runs: ``causal_attention``, its attention,
 ``grouped_matmul``, the products of its sparse-expert layers (rows sorted by
 expert times each row's expert's matrix; forward, the rows' gradient and the
-weights' gradient). Both are gridded over blocks in HBM, so neither is
-bounded by VMEM as the ring kernels are.
+weights' gradient), ``grouped_row_sums``, rows summed into indexed places,
+``rope_heads``, the rotary embedding of a projection's token-major row with
+its cut into heads, and ``norm_rope``, the norm of q and k with the rotation
+after it on heads that are cut. All are gridded over blocks in HBM, so none
+is bounded by VMEM as the ring kernels are.
 
 The ring kernels run under ``jax.shard_map`` over a 1-d mesh axis, the local
 ones anywhere. On a TPU
@@ -1813,3 +1816,438 @@ def grouped_row_sums(lhs, rows, group_sizes, *, out_dtype=None,
     return _grouped_row_sums_fn(
         *blocks, interpret,
         str(np.dtype(out_dtype or lhs.dtype)))(lhs, rows, tuple(visits))
+
+
+# ---------------------------------------------------------------------------
+# rotary embeddings on token-major rows, and the cut into heads (what stands
+# between a projection's product and the attention kernel: one pass each way)
+# ---------------------------------------------------------------------------
+
+# Operand types these kernels are selected for (models.transformer's
+# `_rope_heads` and `_norm_and_rope`), as ATTN_DTYPES above.
+ROPE_DTYPES = frozenset({"float32", "bfloat16"})
+_ROPE_SLOT = LANE // 2          # parts are placed and moved in 64-lane slots
+_ROPE_ROW_TILES = (512, 256, 128)       # tokens a block, widest first
+_ROPE_BLOCK_LANES = 1536        # a block's share of a row, at most
+
+
+def rope_heads_plan(parts: tuple) -> Optional[tuple]:
+    """(lanes of a group, heads of a group, slots) for a row of heads that
+    are each the ``parts`` side by side, ``parts`` = ((width, rotated), ..):
+    a *group* is the fewest whole heads that fill whole 128-lane tiles, and
+    ``slots[s]`` = (head in the group, part, 64-lane slot of the part) says
+    whose values lanes [64 s, 64 s + 64) of a group are. None where the
+    kernel's contract does not hold: every width 64 or a multiple of 128,
+    the rotated parts of one width, 64 or 128, and a rotated 128 on a tile
+    of its own (its two halves are one tile's lanes)."""
+    widths = [w for w, _turned in parts]
+    turned = {w for w, t in parts if t}
+    if not widths or any(w != _ROPE_SLOT and w % LANE for w in widths) \
+            or len(turned) != 1 or not turned <= {_ROPE_SLOT, LANE}:
+        return None
+    period = sum(widths)
+    lanes = math.lcm(period, LANE)
+    slots = []
+    for g in range(lanes // period):
+        for p, (w, t) in enumerate(parts):
+            if t and w == LANE and (len(slots) * _ROPE_SLOT) % LANE:
+                return None
+            slots += [(g, p, j) for j in range(w // _ROPE_SLOT)]
+    return lanes, lanes // period, tuple(slots)
+
+
+def rope_heads_lanes(parts: tuple):
+    """For every lane of a group of ``parts`` (:func:`rope_heads_plan`), its
+    place within its rotary head, or -1 where the lane's part passes: what
+    the caller builds :func:`rope_heads`'s tables from."""
+    import numpy as np
+    at = np.arange(_ROPE_SLOT)
+    return np.concatenate([
+        _ROPE_SLOT * j + at if parts[p][1] else np.full(_ROPE_SLOT, -1)
+        for _g, p, j in rope_heads_plan(parts)[2]])
+
+
+def rope_heads_blocks(t: int, heads: int, parts: tuple) -> Optional[tuple]:
+    """(tokens a block, groups a block) of :func:`rope_heads` for ``heads``
+    heads of ``parts`` over ``t`` tokens, or None where its contract does not
+    hold (:func:`rope_heads_plan`'s, ``t`` a multiple of a row tile, whole
+    groups of heads)."""
+    plan = rope_heads_plan(tuple(parts))
+    tt = next((r for r in _ROPE_ROW_TILES if t % r == 0), None)
+    if plan is None or tt is None or heads % plan[1]:
+        return None
+    groups = heads // plan[1]
+    m = max(m for m in range(1, groups + 1)
+            if groups % m == 0 and (m == 1 or m * plan[0] <= _ROPE_BLOCK_LANES))
+    return tt, m
+
+
+def _rope_heads_vmem(tt: int, m: int, lanes: int, itemsize: int) -> int:
+    """A grid step's blocks, double-buffered: the row's share, the parts'
+    (a 64-wide part's block is stored 128 lanes wide), the two tables."""
+    return 2 * (3 * tt * m * lanes * itemsize + 2 * tt * lanes * 4)
+
+
+def _rope_turn(x, cos, sin, width: int, keep):
+    """``x cos + swap(x) sin`` on one [rows, 128] float32 tile whose lanes
+    are rotary heads of ``width`` (64: two of them; 128: one): ``swap``
+    exchanges a head's halves, a lane rotation (and for two heads a tile a
+    select between the two directions). ``keep`` = (first, second): which
+    64-lane half of the tile is a rotated part's; the other passes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    pltpu = _pltpu()
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    half = width // 2
+    # int32 shifts under jax_enable_x64 too (Mosaic has no 64-bit scalars)
+    swapped = pltpu.roll(x, np.int32(half), 1)
+    if width < LANE:
+        swapped = jnp.where((lane & np.int32(width - 1)) < np.int32(half),
+                            pltpu.roll(x, np.int32(LANE - half), 1), swapped)
+    y = x * cos + swapped * sin
+    if all(keep):
+        return y
+    return jnp.where((lane < np.int32(_ROPE_SLOT)) == keep[0], y, x)
+
+
+def _rope_tiles(parts: tuple, slots: tuple, tiles: list, cos_ref, sin_ref):
+    """A group's float32 tiles, the rotated parts' lanes turned."""
+    width = next(w for w, t in parts if t)
+    out = []
+    for c, x in enumerate(tiles):
+        keep = tuple(parts[slots[2 * c + i][1]][1] for i in (0, 1))
+        if any(keep):
+            at = slice(c * LANE, (c + 1) * LANE)
+            x = _rope_turn(x, cos_ref[:, at], sin_ref[:, at], width, keep)
+        out.append(x)
+    return out
+
+
+def _rope_slot(tile, second: bool):
+    """A tile's first or second 64 lanes, as a [rows, 64] value."""
+    if second:
+        import numpy as np
+        tile = _pltpu().roll(tile, np.int32(_ROPE_SLOT), 1)
+    return tile[:, :_ROPE_SLOT]
+
+
+def _rope_heads_fwd_kernel(parts: tuple, plan: tuple, m: int, x_ref, cos_ref,
+                           sin_ref, *out_refs):
+    """One block: ``m`` groups of a row's heads over a tile of tokens. Each
+    group's tiles are read, turned in float32 and written to their parts'
+    places in the [batch, heads, tokens, width] results."""
+    import jax.numpy as jnp
+    lanes, per, slots = plan
+    for gi in range(m):
+        tiles = _rope_tiles(parts, slots, [
+            x_ref[0, :, gi * lanes + c * LANE:gi * lanes + (c + 1) * LANE]
+            .astype(jnp.float32) for c in range(lanes // LANE)],
+            cos_ref, sin_ref)
+        for s, (g, p, j) in enumerate(slots):
+            ref, head = out_refs[p], gi * per + g
+            if parts[p][0] == _ROPE_SLOT:
+                ref[0, head] = _rope_slot(tiles[s // 2], s % 2).astype(ref.dtype)
+            elif j % 2 == 0:        # a 128-lane piece: a tile, or two halves
+                piece = tiles[s // 2] if s % 2 == 0 else jnp.concatenate(
+                    [_rope_slot(tiles[s // 2], True),
+                     _rope_slot(tiles[s // 2 + 1], False)], axis=1)
+                ref[0, head, :, j // 2 * LANE:(j // 2 + 1) * LANE] = \
+                    piece.astype(ref.dtype)
+
+
+def _rope_heads_bwd_kernel(parts: tuple, plan: tuple, m: int, *refs):
+    """The transpose: the parts' cotangents are read from their places,
+    joined into the row's tiles and turned by the negative angle (the caller
+    hands ``sin`` negated: a rotation's transpose is its inverse)."""
+    import jax.numpy as jnp
+    lanes, per, slots = plan
+    *part_refs, cos_ref, sin_ref, x_ref = refs
+
+    def slot(gi, s):
+        g, p, j = slots[s]
+        ref, head = part_refs[p], gi * per + g
+        if parts[p][0] == _ROPE_SLOT:
+            return ref[0, head].astype(jnp.float32)
+        tile = ref[0, head, :, j // 2 * LANE:(j // 2 + 1) * LANE]
+        return _rope_slot(tile.astype(jnp.float32), j % 2)
+
+    for gi in range(m):
+        tiles = []
+        for c in range(lanes // LANE):
+            g, p, j = slots[2 * c]
+            if parts[p][0] != _ROPE_SLOT and j % 2 == 0:    # a tile as it is
+                tiles.append(part_refs[p][
+                    0, gi * per + g, :, j // 2 * LANE:(j // 2 + 1) * LANE]
+                    .astype(jnp.float32))
+            else:
+                tiles.append(jnp.concatenate(
+                    [slot(gi, 2 * c), slot(gi, 2 * c + 1)], axis=1))
+        for c, tile in enumerate(_rope_tiles(parts, slots, tiles, cos_ref,
+                                             sin_ref)):
+            x_ref[0, :, gi * lanes + c * LANE:gi * lanes + (c + 1) * LANE] = \
+                tile.astype(x_ref.dtype)
+
+
+def _rope_heads_call(back: bool, operands, cos, sin, heads: int, parts: tuple,
+                     tt: int, m: int, interpret: Optional[bool]):
+    """The forward kernel over (row, tables) or the backward one over (the
+    parts' cotangents, tables). The grid walks (token tile, batch, block of
+    groups): the tables' block changes with the first alone, so it is copied
+    in once a token tile."""
+    import numpy as np
+    pl = _pl()
+    plan = rope_heads_plan(parts)
+    lanes, per, _slots = plan
+    like = operands[0]
+    b, t = like.shape[0], like.shape[2 if back else 1]
+    zero = np.int32(0)          # int32 index arithmetic, as in the attention
+    row = pl.BlockSpec((1, tt, m * lanes), lambda ti, bi, gi: (bi, ti, gi))
+    table = pl.BlockSpec((tt, lanes), lambda ti, bi, gi: (ti, zero))
+    cut = [pl.BlockSpec((1, m * per, tt, w),
+                        lambda ti, bi, gi: (bi, gi, ti, zero))
+           for w, _turned in parts]
+    whole = _varying_like(like, (b, t, heads * (lanes // per)), like.dtype)
+    pieces = [_varying_like(like, (b, heads, t, w), like.dtype)
+              for w, _turned in parts]
+    name = "rope_heads_bwd" if back else "rope_heads_fwd"
+    perfvars.note_kernel_build(name)
+    return pl.pallas_call(
+        functools.partial(_rope_heads_bwd_kernel if back
+                          else _rope_heads_fwd_kernel, parts, plan, m),
+        grid=(t // tt, b, heads // (m * per)),
+        in_specs=(cut if back else [row]) + [table, table],
+        out_specs=row if back else cut,
+        out_shape=whole if back else pieces,
+        interpret=_interpret(interpret),
+        compiler_params=_compiler_params(
+            None, _rope_heads_vmem(tt, m, lanes, like.dtype.itemsize),
+            "rope_heads", ("parallel", "parallel", "parallel")),
+        name=name,
+    )(*operands, cos, sin)
+
+
+@functools.lru_cache(maxsize=None)
+def _rope_heads_fn(heads: int, parts: tuple, tt: int, m: int,
+                   interpret: Optional[bool]):
+    """The differentiable pair at one pattern and tiling, jitted once: the
+    layers of a step share one trace and one lowering a direction."""
+    import jax
+
+    @jax.custom_vjp
+    def turn_and_cut(row, cos, sin):
+        return tuple(_rope_heads_call(False, (row,), cos, sin, heads, parts,
+                                      tt, m, interpret))
+
+    def fwd(row, cos, sin):
+        return turn_and_cut(row, cos, sin), (cos, sin)
+
+    def bwd(tables, cotangents):
+        cos, sin = tables
+        return _rope_heads_call(True, tuple(cotangents), cos, -sin, heads,
+                                parts, tt, m, interpret), None, None
+
+    turn_and_cut.defvjp(fwd, bwd)
+    return jax.jit(turn_and_cut)
+
+
+def rope_heads(row, cos, sin, heads: int, parts: Sequence[tuple], *,
+               interpret: Optional[bool] = None) -> tuple:
+    """A token-major ``row`` [batch, tokens, heads x sum of widths], each
+    head the ``parts`` = ((width, rotated), ..) side by side, as one
+    [batch, heads, tokens, width] array a part (the attention kernel's
+    operands), the rotated parts turned on the way: ``x cos + swap(x) sin``
+    in float32, rounded once, where ``swap`` exchanges the halves of a rotary
+    head and ``cos`` / ``sin`` [tokens, lanes of a group] float32 hold each
+    angle twice, ``sin`` with the first half's sign folded in (1 and 0 on a
+    part that passes: its values come through bit for bit). One pass: the
+    row is read where it is 128 lanes dense and every part written once;
+    the transpose (``custom_vjp``) reads the parts' cotangents and writes
+    the row's, turned by the negative angle. A pattern outside
+    :func:`rope_heads_blocks`'s contract raises."""
+    parts = tuple((int(w), bool(t)) for w, t in parts)
+    b, t, width = row.shape
+    blocks = rope_heads_blocks(t, heads, parts)
+    if blocks is None or width != heads * sum(w for w, _ in parts):
+        raise ValueError(
+            f"rope_heads: a row {row.shape} of {heads} heads of {parts} is "
+            f"outside the kernel's contract (widths 64 or multiples of "
+            f"{LANE}, one rotary width of 64 or {LANE}, tokens a multiple of "
+            f"{_ROPE_ROW_TILES[-1]}, whole groups of heads)")
+    lanes = rope_heads_plan(parts)[0]
+    if cos.shape != (t, lanes) or sin.shape != cos.shape:
+        raise ValueError(f"rope_heads: tables {cos.shape}, {sin.shape} for "
+                         f"{t} tokens and groups of {lanes} lanes")
+    row, cos, sin = _vary_together(row, cos, sin)
+    return _rope_heads_fn(heads, parts, *blocks, interpret)(row, cos, sin)
+
+
+# ---------------------------------------------------------------------------
+# the norm of q and k and the rotation after it, on heads that are cut (where
+# a norm stands between the projection and the rotation: one pass each way)
+# ---------------------------------------------------------------------------
+
+def norm_rope_blocks(n: int, t: int, width: int, itemsize: int,
+                     together: int = 0) -> Optional[tuple]:
+    """(tokens a block, heads a block) of :func:`norm_rope` for ``n`` heads
+    of ``width`` over ``t`` tokens, or None where its contract does not hold
+    (``width`` 128: a head is a tile's lanes; ``t`` a multiple of a row
+    tile). ``together`` > 0: that many heads (one token's: the norm sums
+    over them) stand in one block."""
+    tt = next((r for r in _ROPE_ROW_TILES if t % r == 0), None)
+    if width != LANE or tt is None or n <= 0 or (together and n % together):
+        return None
+    if together:
+        while tt > _ROPE_ROW_TILES[-1] and \
+                together * tt * width * itemsize > 4 * 2 ** 20:
+            tt //= 2
+        return tt, together
+    return tt, max(m for m in range(1, n + 1)
+                   if n % m == 0 and m * width <= _ROPE_BLOCK_LANES)
+
+
+def _norm_rope_kernel(eps: float, denom: float, whole: bool, back: bool,
+                      *refs):
+    """One block of heads [heads, tokens, 128]: RMS-normed as the model's
+    norms are (x rsqrt(sum of squares / denom + eps) rounded to x's type,
+    times the learned scale, rounded), over each head or (``whole``) over
+    all the block's heads, one token's whole vector, then turned. ``back``:
+    the transpose. The cotangent is turned by the negative angle (the caller
+    hands ``sin`` negated) and carried through the scale and the norm, whose
+    inverse rms is computed again from x: dx = r gu - r^3 x sum(gu x) /
+    denom; the scale's gradient, summed over the block's tokens, is the
+    second result."""
+    import jax.numpy as jnp
+    from jax.lax import rsqrt
+    f32 = jnp.float32
+    if back:
+        d_ref, x_ref, scale_ref, cos_ref, sin_ref, out_ref, dscale_ref = refs
+    else:
+        x_ref, scale_ref, cos_ref, sin_ref, out_ref = refs
+    heads, dtype = x_ref.shape[0], x_ref.dtype
+
+    def squares(i):
+        xf = x_ref[i].astype(f32)
+        return jnp.sum(xf * xf, axis=1, keepdims=True)
+
+    def inverse_rms(ss):
+        return rsqrt(ss * f32(1.0 / denom) + f32(eps))
+    shared = inverse_rms(sum(squares(i) for i in range(heads))) \
+        if whole else None
+
+    def normed(i):
+        """(x, inverse rms, x normed and rounded, the scale's row)."""
+        xf = x_ref[i].astype(f32)
+        r = shared if whole else inverse_rms(squares(i))
+        row = i if whole else 0
+        return (xf, r, (xf * r).astype(dtype).astype(f32),
+                scale_ref[row:row + 1, :].astype(f32))
+
+    def turned(x):
+        return _rope_turn(x, cos_ref[...], sin_ref[...], LANE, (True, True))
+    if not back:
+        for i in range(heads):
+            _xf, _r, u, scale = normed(i)
+            out_ref[i] = turned((u * scale).astype(dtype).astype(f32)
+                                ).astype(dtype)
+        return
+    dots, dscale = [], []
+    for i in range(heads):          # gu parked in the result's block
+        xf, _r, u, scale = normed(i)
+        gw = turned(d_ref[i].astype(f32)).astype(dtype).astype(f32)
+        dscale.append(jnp.sum(gw * u, axis=0, keepdims=True))
+        gu = (gw * scale).astype(dtype)
+        out_ref[i] = gu
+        dots.append(jnp.sum(gu.astype(f32) * xf, axis=1, keepdims=True))
+    if whole:
+        dots = [sum(dots)] * heads
+        dscale_ref[0, 0] = jnp.concatenate(dscale, axis=0)
+    else:
+        dscale_ref[0, 0] = sum(dscale)
+    for i in range(heads):
+        xf, r, _u, _scale = normed(i)
+        out_ref[i] = (r * out_ref[i].astype(f32) - (r * r * r) * f32(
+            1.0 / denom) * dots[i] * xf).astype(dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _norm_rope_fn(tt: int, m: int, eps: float, denom: float, whole: bool,
+                  interpret: Optional[bool]):
+    """The differentiable norm and rotation at one tiling, jitted once: the
+    layers of a step share one trace and one lowering a direction."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    pl = _pl()
+    zero = np.int32(0)
+
+    def call(back, x, *rest):
+        n, t, width = x.shape
+        rows = rest[1 if back else 0].shape[0]
+        heads = pl.BlockSpec((m, tt, width), lambda ti, ni: (ni, ti, zero))
+        scale = pl.BlockSpec((rows, width), lambda ti, ni: (zero, zero))
+        table = pl.BlockSpec((tt, width), lambda ti, ni: (ti, zero))
+        out_specs, out_shape = heads, _varying_like(x, x.shape, x.dtype)
+        if back:        # and every block's part of the scale's gradient
+            out_specs = [heads, pl.BlockSpec(
+                (1, 1, rows, width), lambda ti, ni: (ti, ni, zero, zero))]
+            out_shape = [out_shape, _varying_like(
+                x, (t // tt, n // m, rows, width), jnp.float32)]
+        name = "norm_rope_bwd" if back else "norm_rope_fwd"
+        perfvars.note_kernel_build(name)
+        return pl.pallas_call(
+            functools.partial(_norm_rope_kernel, eps, denom, whole, back),
+            grid=(t // tt, n // m),
+            in_specs=[heads] * (2 if back else 1) + [scale, table, table],
+            out_specs=out_specs, out_shape=out_shape,
+            interpret=_interpret(interpret),
+            compiler_params=_compiler_params(
+                None, _rope_heads_vmem(tt, m, width, x.dtype.itemsize),
+                "norm_rope", ("parallel", "parallel")),
+            name=name)(x, *rest)
+
+    @jax.custom_vjp
+    def norm_and_turn(x, scale, cos, sin):
+        return call(False, x, scale, cos, sin)
+
+    def bwd(kept, d):
+        x, scale, cos, sin = kept
+        dx, dscale = call(True, d, x, scale, cos, -sin)
+        return (dx, dscale.sum(axis=(0, 1)).astype(scale.dtype), None, None)
+    norm_and_turn.defvjp(
+        lambda *operands: (norm_and_turn(*operands), operands), bwd)
+    return jax.jit(norm_and_turn)
+
+
+def norm_rope(x, scale, cos, sin, *, eps: float, denom: float,
+              interpret: Optional[bool] = None):
+    """The norm of q or k and the rotary embedding after it in one pass, for
+    heads that are cut already: ``x`` [..., tokens, 128], every last axis
+    one rotary head, is RMS-normed as the model's norms of q and k are (x
+    rsqrt(sum of squares / ``denom`` + ``eps``), float32 inside and rounded
+    to x's type, times ``scale``, rounded again) and turned (``x cos +
+    swap(x) sin`` in float32, rounded once, with :func:`rope_heads`'s tables
+    [tokens, 128] and its meaning of ``swap``). ``scale`` [128]: the norm
+    is over each head. ``scale`` [heads, 128] with x [batch, heads, tokens,
+    128]: over a token's whole vector, all its heads (they stand in one
+    block). One pass back too (``custom_vjp``): the cotangent is turned by
+    the negative angle and carried through the norm, whose inverse rms is
+    computed again from x (the only residuals are the operands), and the
+    scale's gradient is summed in float32."""
+    import jax.numpy as jnp
+    *lead, t, width = x.shape
+    n = math.prod(lead)
+    scale = jnp.atleast_2d(scale)
+    together = scale.shape[0] if scale.shape[0] > 1 else 0
+    if scale.shape[1] != width or (together and (
+            len(lead) != 2 or lead[1] != together)):
+        raise ValueError(f"norm_rope: a scale {scale.shape} for {x.shape}")
+    blocks = norm_rope_blocks(n, t, width, x.dtype.itemsize, together)
+    if blocks is None or cos.shape != (t, width) or sin.shape != cos.shape:
+        raise ValueError(
+            f"norm_rope: {x.shape} with tables {cos.shape}, {sin.shape} is "
+            f"outside the kernel's contract (heads of {LANE}, tokens a "
+            f"multiple of {_ROPE_ROW_TILES[-1]}, tables [tokens, {LANE}])")
+    operands = _vary_together(x.reshape(n, t, width), scale, cos, sin)
+    return _norm_rope_fn(*blocks, float(eps), float(denom), bool(together),
+                         interpret)(*operands).reshape(x.shape)
