@@ -716,3 +716,73 @@ def test_compiled_for_the_v5e_the_latent_step_fits_one_chip(v5e_2x2,
         kernels.count("causal_attention_bwd") == \
         kernels.count("rope_heads_fwd") == \
         kernels.count("rope_heads_bwd") == 1, kernels
+
+
+def test_compiled_for_the_v5e_the_state_space_step_fits_one_chip(
+        v5e_2x2, monkeypatch):
+    """The benchmark's granite-4.0-h-micro stage (yardstick/configs/
+    granite-4.0-h-micro-1c.json: published widths, layers 0-9, nine
+    state-space layers to one attention layer, the whole 100352-row
+    vocabulary, batch 1 x 8192, parameters donated) compiled for one
+    described v5e chip with the kernels selected as on a TPU: 952 M
+    parameters, at most 14.9 GB with nothing recomputed but the scan's decay
+    matrix (PR 37: 12.81 GB), no [.., t, t] buffer; the fused attention
+    kernel forward and backward in layer 5 alone, under its `attn` scope;
+    the five scopes of a mixer in the state-space layers; one trace of the
+    block a mixer kind, and no kernel but attention's pair and the
+    embedding's row sums."""
+    import json
+    import os
+    import re
+    from tpu_mpi.parallel import ring
+    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from tpu_mpi import xla
+    from tpu_mpi.models import transformer as tf
+    from tpu_mpi.models.transformer import (TransformerConfig,
+                                            transformer_init,
+                                            transformer_train_step)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "yardstick", "configs",
+                           "granite-4.0-h-micro-1c.json")) as f:
+        conf = json.load(f)
+    fields = dict(conf["model"], max_seq=8192)
+    fields["dtype"] = jnp.dtype(fields["dtype"])
+    cfg = TransformerConfig(**fields)
+    mesh = xla.make_mesh(dict(conf["mesh"]), devices=v5e_2x2[:1])
+    tf._block_traced_once.cache_clear()
+    step, specs = transformer_train_step(cfg, mesh, lr=conf["lr"], donate=True)
+    shapes = jax.eval_shape(lambda k: transformer_init(k, cfg),
+                            jax.random.key(0))
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 951_991_232
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        shapes, specs)
+    tok = jax.ShapeDtypeStruct((1, 8192), jnp.int32,
+                               sharding=NamedSharding(mesh, P("dp", "sp")))
+    lowered = step.lower(params, tok, tok)
+    assert tf._block_traced_once.cache_info().currsize == 2
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert m.alias_size_in_bytes > 1.9e9        # the parameters are reused
+    assert 11e9 < held <= 14.9e9, held
+    hlo = compiled.as_text()
+    assert not re.search(r"\[\d+,\d+,8192,8192\]", hlo)     # no [.., t, t]
+    calls = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*causal_attention_(\w+)[^\"]*)\"", hlo)
+    assert sorted((d, int(re.search(r"layer_(\d+)", name).group(1)))
+                  for name, d in calls) == [("bwd", 5), ("fwd", 5)]
+    assert all("/attn/" in name for name, _d in calls)
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for i in (0, 4, 6, 9):
+        for scope in ("in_proj", "conv", "scan", "gate_norm", "out_proj"):
+            assert [n for n in names if f"layer_{i})" in n
+                    and f"/mixer/{scope}/" in n], (i, scope)
+    assert not [n for n in names if "layer_5)" in n and "/mixer/" in n]
+    kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
+    assert sorted(kernels) == ["causal_attention_bwd", "causal_attention_fwd",
+                               "grouped_row_sums"], kernels
+    tf._block_traced_once.cache_clear()

@@ -114,10 +114,28 @@ class TransformerConfig:
     #                             program); empty: all of them
     norm_out: bool = False      # sandwich norm: x + RMSNorm(sublayer(
     #                             RMSNorm(x))), scales `ln1_out`, `ln2_out`
+    # State-space (Mamba-2) layers among the attention ones: such a layer's
+    # first half is `_ssm_mixer` (in-projection, a short causal convolution,
+    # the selective scan of `parallel.ssm`, a gated norm, out-projection)
+    # in place of attention; its FFN half is the model's.
+    mixer_kinds: tuple = ()     # "attention" | "ssm" a layer; empty: attention
+    ssm_expand: int = 2         # the scan runs ssm_expand x d_model wide, =
+    ssm_heads: int = 0          #   ssm_heads heads of
+    ssm_head_dim: int = 0       #   ssm_head_dim each, over a state of
+    ssm_state: int = 0          #   ssm_state a head's value; B and C are one
+    #                             vector a token for all heads (one group)
+    ssm_conv: int = 4           # the convolution's taps: a token and the
+    #                             ssm_conv - 1 before it, with a bias
+    ssm_chunk: int = 256        # tokens a chunk of the scan
+    # Scalars on the residual stream, as data; 1.0 (and 0.0) add no equation.
+    embed_multiplier: float = 1.0       # x the embedding's rows
+    residual_multiplier: float = 1.0    # x each half's output, before the add
+    logits_divisor: float = 1.0         # logits / this
+    attn_scale: float = 0.0     # the scores' scale; 0: head_dim ** -0.5
 
     def __post_init__(self):
         for name in ("attn_windows", "ffn_kinds", "experts_held",
-                     "remat_layers", "heads_held"):
+                     "remat_layers", "heads_held", "mixer_kinds"):
             value = tuple(getattr(self, name))
             object.__setattr__(self, name, value)   # a JSON list is welcome
             if value and not name.endswith("_held") \
@@ -145,6 +163,21 @@ class TransformerConfig:
         if self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError(f"n_heads={self.n_heads} is no multiple of "
                              f"n_kv_heads={self.n_kv_heads}")
+        if set(self.mixer_kinds) - {"attention", "ssm"}:
+            raise ValueError(f"mixer_kinds={self.mixer_kinds}: a layer's "
+                             f"mixer is \"attention\" or \"ssm\"")
+        if "ssm" in self.mixer_kinds and not (
+                self.ssm_state > 0 and self.ssm_conv > 0 and self.ssm_chunk > 0
+                and self.ssm_heads * self.ssm_head_dim
+                == self.ssm_expand * self.d_model):
+            raise ValueError(
+                f"state-space layers are ssm_heads={self.ssm_heads} x "
+                f"ssm_head_dim={self.ssm_head_dim} = ssm_expand="
+                f"{self.ssm_expand} x d_model={self.d_model} wide, with "
+                f"ssm_state, ssm_conv and ssm_chunk above 0")
+        if self.attn_scale and self.kv_latent:
+            raise ValueError("latent attention scales its scores by "
+                             "(d_head + d_rope) ** -0.5: no attn_scale")
 
     @property
     def head_dim(self) -> int:
@@ -162,12 +195,18 @@ class TransformerConfig:
     def value_dim(self) -> int:
         return self.d_value or self.head_dim
 
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
     def layer_kind(self, i: int) -> "LayerKind":
         sparse = self.ffn_kinds[i] == "sparse" if self.ffn_kinds \
             else bool(self.n_experts)
-        return LayerKind(self.attn_windows[i] if self.attn_windows else 0,
-                         sparse,
-                         self.remat_layers[i] if self.remat_layers else "")
+        mixer = self.mixer_kinds[i] if self.mixer_kinds else "attention"
+        window = self.attn_windows[i] if self.attn_windows else 0
+        return LayerKind(0 if mixer == "ssm" else window, sparse,
+                         self.remat_layers[i] if self.remat_layers else "",
+                         mixer)
 
 
 class LayerKind(NamedTuple):
@@ -175,6 +214,7 @@ class LayerKind(NamedTuple):
     window: int         # 0: full causal attention
     sparse: bool        # routed experts (and shared ones), else a dense FFN
     remat: str          # "" or "ffn"
+    mixer: str = "attention"    # or "ssm": a state-space scan in its place
 
 
 def transformer_init(key, cfg: TransformerConfig) -> dict:
@@ -202,7 +242,10 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         layer = {"ln1": jnp.ones((d,), cfg.dtype)}
         # the leaves a public block adds draw from keys of their own, so
         # the flagship's are the flagship's whatever else is configured
-        if cfg.kv_latent:
+        ssm = cfg.layer_kind(i).mixer == "ssm"
+        if ssm:
+            layer.update(_ssm_init(cfg, k[0], k[1], dense))
+        elif cfg.kv_latent:
             h, cq, ckv = cfg.n_heads_here, cfg.q_latent, cfg.kv_latent
             for n, (name, shape) in enumerate((
                     ("w_dq", (d, cq)),
@@ -220,8 +263,10 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
             layer["w_v"] = dense(jax.random.fold_in(k[0], 3), (d, kv), d ** -0.5)
         else:
             layer["w_qkv"] = dense(k[0], (d, 3 * hd), d ** -0.5)
+        if not ssm:
+            layer["w_proj"] = dense(k[1], (hd, d),
+                                    (2 * hd * cfg.n_layers) ** -0.5)
         layer.update({
-            "w_proj": dense(k[1], (hd, d), (2 * hd * cfg.n_layers) ** -0.5),
             "ln2": jnp.ones((d,), cfg.dtype),
             "w_in": dense(k[2], experts + (d, f), d ** -0.5),
             "w_out": dense(k[3], experts + (f, d),
@@ -230,10 +275,10 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         if cfg.norm_out:
             layer["ln1_out"] = jnp.ones((d,), cfg.dtype)
             layer["ln2_out"] = jnp.ones((d,), cfg.dtype)
-        if cfg.qk_norm:
+        if cfg.qk_norm and not ssm:
             layer["q_norm"] = jnp.ones((d,), cfg.dtype)
             layer["k_norm"] = jnp.ones((d,), cfg.dtype)
-        if cfg.qk_norm_heads:
+        if cfg.qk_norm_heads and not ssm:
             layer["q_norm"] = jnp.ones((cfg.head_dim,), cfg.dtype)
             layer["k_norm"] = jnp.ones((cfg.head_dim,), cfg.dtype)
         if sparse or cfg.dense_gated:
@@ -254,6 +299,34 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
     return params
 
 
+def _ssm_init(cfg: TransformerConfig, key, key_out, dense) -> dict:
+    """The leaves of a state-space layer's mixer. `w_ssm_in` [d, z | x B C |
+    dt]: the gate (inner wide), the convolution's channels (inner + 2 x
+    state) and a dt a head; `conv_w` [taps, channels] and `conv_b`;
+    `ssm_norm` the gated norm's scale; `w_ssm_out` [inner, d]. The three
+    leaves of the recurrence itself are float32, as its arithmetic is:
+    `a_log` (A = -exp(a_log); log(1..heads), the family's initialisation),
+    `d_skip` (ones) and `dt_bias` (the inverse softplus of values drawn
+    log-uniformly from [0.001, 0.1])."""
+    d, inner, h = cfg.d_model, cfg.ssm_inner, cfg.ssm_heads
+    channels = inner + 2 * cfg.ssm_state
+    keys = jax.random.split(key, 4)
+    dt = jnp.exp(jax.random.uniform(keys[3], (h,), jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    return {
+        "w_ssm_in": dense(keys[0], (d, inner + channels + h), d ** -0.5),
+        "conv_w": dense(keys[1], (cfg.ssm_conv, channels),
+                        cfg.ssm_conv ** -0.5),
+        "conv_b": dense(keys[2], (channels,), cfg.ssm_conv ** -0.5),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "a_log": jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)),
+        "d_skip": jnp.ones((h,), jnp.float32),
+        "ssm_norm": jnp.ones((inner,), cfg.dtype),
+        "w_ssm_out": dense(key_out, (inner, d),
+                           (2 * inner * cfg.n_layers) ** -0.5),
+    }
+
+
 def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> dict:
     """PartitionSpec pytree matching transformer_init's params: qkv/ffn-in
     column-sharded, proj/ffn-out row-sharded over the tp axis; everything
@@ -269,7 +342,13 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
         out = {"ln1": rep, "w_proj": row, "ln2": rep,
                "w_in": rep if sparse else col,
                "w_out": rep if sparse else row}
-        if cfg.kv_latent:     # whole on every rank (tp 1 only: `_latent_attn`)
+        ssm = cfg.layer_kind(i).mixer == "ssm"
+        if ssm:     # whole on every rank (tp 1 and sp 1 only: `_ssm_mixer`)
+            del out["w_proj"]
+            out.update({name: rep for name in (
+                "w_ssm_in", "conv_w", "conv_b", "dt_bias", "a_log", "d_skip",
+                "ssm_norm", "w_ssm_out")})
+        elif cfg.kv_latent:   # whole on every rank (tp 1 only: `_latent_attn`)
             out.update(w_dq=rep, w_uq=rep, w_dkv=rep, w_ukv=rep, w_proj=rep,
                        q_latent_norm=rep, kv_latent_norm=rep)
         elif cfg.n_kv_heads:
@@ -278,9 +357,9 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
             out["w_qkv"] = col
         if cfg.norm_out:
             out.update(ln1_out=rep, ln2_out=rep)
-        if cfg.qk_norm:
+        if cfg.qk_norm and not ssm:
             out.update(q_norm=P(tp_axis), k_norm=P(tp_axis))
-        if cfg.qk_norm_heads:
+        if cfg.qk_norm_heads and not ssm:
             out.update(q_norm=rep, k_norm=rep)
         if sparse:
             out.update(w_gate=rep, w_router=rep)
@@ -447,6 +526,8 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
     # them (PERF.md section 3, "train step")
     with jax.named_scope("embed"):
         x = rows_at(params["embed"], tokens, scope="embed")      # (b, t, d)
+        if cfg.embed_multiplier != 1.0:
+            x = x * cfg.embed_multiplier
     routed = []
     for i, layer in enumerate(params["layers"]):
         block = _block_traced_once(cfg, cfg.layer_kind(i), tp_axis, sp_axis,
@@ -458,7 +539,10 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
     with jax.named_scope("head_loss"):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        return (x @ head).astype(jnp.float32), routed             # (b, t, V)
+        logits = (x @ head).astype(jnp.float32)                   # (b, t, V)
+        if cfg.logits_divisor != 1.0:
+            logits = logits / cfg.logits_divisor
+        return logits, routed
 
 
 @functools.lru_cache(maxsize=None)
@@ -509,12 +593,20 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
             attn = jax.checkpoint(attn)
 
     def normed(out, scale):
-        if not cfg.norm_out:
-            return out
-        with jax.named_scope("norm_out"):
-            return _rms_norm(out, layer[scale], cfg.norm_eps)
-    with jax.named_scope("attn"):
-        x = x + normed(attn(layer, x, positions), "ln1_out")
+        if cfg.norm_out:
+            with jax.named_scope("norm_out"):
+                out = _rms_norm(out, layer[scale], cfg.norm_eps)
+        if cfg.residual_multiplier != 1.0:
+            out = out * cfg.residual_multiplier
+        return out
+    perfvars.note_mixer_kind(kind.mixer)
+    if kind.mixer == "ssm":
+        with jax.named_scope("mixer"):
+            x = x + normed(_ssm_mixer(cfg, layer, x, tp_axis=tp_axis,
+                                      sp_axis=sp_axis), "ln1_out")
+    else:
+        with jax.named_scope("attn"):
+            x = x + normed(attn(layer, x, positions), "ln1_out")
     if kind.sparse and tp > 1:
         # sharding an expert's width over tp needs the sums of the
         # rows' and the weights' cotangents that `parallel/tp.py`'s
@@ -733,6 +825,8 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
         q, k = (_norm_and_rope(cfg, a, layer[n], positions if rotate else None,
                                tp_axis) for a, n in ((q, "q_norm"),
                                                      (k, "k_norm")))
+    if cfg.attn_scale:      # the attention scales by dh ** -0.5 itself
+        q = q * (cfg.attn_scale * dh ** 0.5)
     if sp_axis is not None:
         o = ring_attention(q, k, v, axis=sp_axis, causal=True, window=window)
     else:
@@ -788,6 +882,49 @@ def _latent_attn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray,
         o = local_attention(q, k, v, rope=(q_rope, k_rope))
     with jax.named_scope("out"):
         return o.transpose(0, 2, 1, 3).reshape(b, t, h * dv) @ layer["w_proj"]
+
+
+def _ssm_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
+               tp_axis: Optional[str], sp_axis: Optional[str]) -> jnp.ndarray:
+    """A state-space (Mamba-2) layer's first half: what is added to the
+    residual. One product gives the gate z, the convolution's channels and
+    a dt a head; the channels pass a causal depthwise convolution
+    (`ssm_conv` taps, with bias) and silu and are cut into x (heads x head
+    width), B and C (one state-wide vector each, for all heads); dt =
+    softplus(dt + dt_bias), A = -exp(a_log); `parallel.ssm.scan` computes
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T, y_t = S_t C_t + D x_t in its
+    chunked form; RMSNorm(y x silu(z)) over the whole inner width (gate
+    first, then norm) and the out-projection. Scopes: `in_proj`, `conv`,
+    `scan`, `gate_norm`, `out_proj`. The state runs along the whole
+    sequence and every head's B and C are the one group's: `sp` > 1 and
+    `tp` > 1 are refused."""
+    for axis in (tp_axis, sp_axis):
+        if axis is not None and lax.axis_size(axis) > 1:
+            raise NotImplementedError(
+                f"a state-space layer runs at tp 1 and sp 1 ({axis!r} has "
+                f"{lax.axis_size(axis)} ranks): its state would cross "
+                f"sequence shards and its heads share one B and C")
+    from ..parallel import ssm      # a program without such a layer pays
+    #                                 no import for it
+    b, t, _ = x.shape
+    inner, h, n = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_state
+    y = _rms_norm(x, layer["ln1"], cfg.norm_eps)
+    with jax.named_scope("in_proj"):
+        z, xbc, dt = jnp.split(y @ layer["w_ssm_in"],
+                               [inner, 2 * inner + 2 * n], axis=-1)
+    with jax.named_scope("conv"):
+        xbc = jax.nn.silu(ssm.causal_conv(xbc, layer["conv_w"],
+                                          layer["conv_b"]))
+        xs, b_in, c_in = jnp.split(xbc, [inner, inner + n], axis=-1)
+    with jax.named_scope("scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
+        o = ssm.scan(xs.reshape(b, t, h, cfg.ssm_head_dim), dt,
+                     -jnp.exp(layer["a_log"]), b_in, c_in, layer["d_skip"],
+                     cfg.ssm_chunk).reshape(b, t, inner)
+    with jax.named_scope("gate_norm"):
+        o = _rms_norm(o * jax.nn.silu(z), layer["ssm_norm"], cfg.norm_eps)
+    with jax.named_scope("out_proj"):
+        return o @ layer["w_ssm_out"]
 
 
 def _whole_vector_norm(cfg: TransformerConfig, x: jnp.ndarray,
@@ -861,6 +998,13 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
     for a in (dp_axis, tp_axis, sp_axis):
         if a not in axis_names:
             raise ValueError(f"mesh is missing axis {a!r}")
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    if "ssm" in cfg.mixer_kinds and (sizes[tp_axis] > 1 or sizes[sp_axis] > 1):
+        raise NotImplementedError(
+            f"a model with state-space layers trains at tp 1 and sp 1 (this "
+            f"mesh has {tp_axis} {sizes[tp_axis]}, {sp_axis} "
+            f"{sizes[sp_axis]}): a layer's state would cross sequence "
+            f"shards; shard its batch over {dp_axis}")
     reduce_axes = (dp_axis, sp_axis)
     warm_kernel_imports()       # off the first trace's path (set-up time)
 

@@ -1,0 +1,119 @@
+"""State-space (Mamba-2) sequence mixing: the selective scan in its chunked
+form, and the short causal convolution in front of it.
+
+The recurrence, a head, with a state ``S`` of [head width, state width]:
+
+    S_t = exp(dt_t A) S_{t-1} + (dt_t x_t) B_t^T        y_t = S_t C_t + D x_t
+
+``A`` < 0 is one scalar a head, ``dt`` > 0 one a head and token, ``B`` and
+``C`` one vector a token that all heads share (one group). :func:`scan` is
+the one "state-space scan of a block" the model calls, as
+``ring.local_attention`` is its attention: it computes the recurrence a
+chunk of ``chunk`` tokens at a time. Inside a chunk the outputs are the
+masked product ``(L o C B^T)(dt x)`` with ``L_ts = exp(sum_{s<r<=t} dt_r A)``
+(s <= t, 0 above the diagonal); each chunk leaves a state, the states run
+through the short recurrence over the chunks, and each chunk reads the state
+before it. All decay arithmetic (the cumulative sums, the exponentials, the
+states) is float32; the products take operands of the input's type and
+accumulate in float32. For the backward pass the inputs and the chunks'
+states are kept and ``L`` (heads x chunks x chunk x chunk float32) is
+computed again: `jax.checkpoint` with a policy that saves the states alone.
+
+The form is chosen from the shapes, never by trying, and counted where it is
+chosen (``perfvars.snapshot()["scan_lowerings"]``): ``chunked`` where the
+sequence is a multiple of the chunk (a sequence shorter than a chunk is one
+chunk), ``padded`` where it is not: the sequence is filled up to the next
+multiple with tokens of ``dt`` = 0, which decay nothing and add nothing, so
+the result is exact, and their outputs are cut off.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from .. import perfvars
+
+STATES = "ssm_chunk_states"     # what the backward pass keeps of `_chunked`
+
+
+def causal_conv(x: jnp.ndarray, w: jnp.ndarray, bias: jnp.ndarray):
+    """Depthwise causal convolution over the sequence: x [b, t, c], w [k, c]
+    (tap k - 1 weighs the token itself, tap 0 the one k - 1 before it), bias
+    [c]; a token sees itself and the k - 1 before it, zeros before the
+    sequence's start. k shifted products summed in float32, rounded once."""
+    k, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    out = bias.astype(jnp.float32)
+    for j in range(k):
+        out = out + padded[:, j:j + t].astype(jnp.float32) * w[j].astype(
+            jnp.float32)
+    return out.astype(x.dtype)
+
+
+def scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
+         c: jnp.ndarray, d: jnp.ndarray, chunk: int = 256) -> jnp.ndarray:
+    """y [batch, t, heads, width] of the recurrence above from x [batch, t,
+    heads, width], dt [batch, t, heads] (> 0), a [heads] (< 0), b and c
+    [batch, t, state] and the skip's d [heads]; dt, a and d float32. Each
+    call built into a traced program counts in
+    ``perfvars.snapshot()["scan_lowerings"]`` as ``chunked`` or ``padded``."""
+    t = x.shape[1]
+    length = min(chunk, t)
+    pad = -t % length
+    perfvars.note_scan_lowering("padded" if pad else "chunked")
+
+    def filled(v):      # up to the next multiple, with tokens of zeros
+        widths = ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)
+        return jnp.pad(v, widths) if pad else v
+    y = _chunked(filled(x), filled(dt), a, filled(b), filled(c),
+                 length)[:, :t]
+    return (y + x.astype(jnp.float32) * d[:, None]).astype(x.dtype)
+
+
+@functools.partial(
+    jax.checkpoint, static_argnums=(5,),
+    policy=jax.checkpoint_policies.save_only_these_names(STATES))
+def _chunked(x, dt, a, b, c, length: int):
+    """The recurrence without its skip term, float32 [batch, t, heads,
+    width], t a multiple of ``length``."""
+    bsz, t, h, p = x.shape
+    n, nc = b.shape[-1], t // length
+    f32, dtype = jnp.float32, x.dtype
+    dt = dt.reshape(bsz, nc, length, h)
+    b, c = (v.reshape(bsz, nc, length, n) for v in (b, c))
+    # seg[.., l] = sum over the chunk's tokens r <= l of dt_r A, a head
+    seg = jnp.cumsum(dt * a, axis=2).transpose(0, 1, 3, 2)  # [b, c, h, l]
+    xdt = x.reshape(bsz, nc, length, h, p).astype(f32) * dt[..., None]
+
+    # inside a chunk: (L o C B^T) (dt x), L masked before its exponential
+    scores = jnp.einsum("bcln,bcsn->bcls", c, b, preferred_element_type=f32)
+    seen = jnp.tril(jnp.ones((length, length), dtype=bool))
+    decay = jnp.exp(jnp.where(seen, seg[..., :, None] - seg[..., None, :],
+                              -jnp.inf))                    # [b, c, h, l, s]
+    y = jnp.einsum("bchls,bcshp->bclhp",
+                   (scores[:, :, None] * decay).astype(dtype),
+                   xdt.astype(dtype), preferred_element_type=f32)
+
+    # the state a chunk adds: its tokens' dt x B^T, each decayed to its end
+    to_end = jnp.exp(seg[..., -1:] - seg).transpose(0, 1, 3, 2)
+    states = checkpoint_name(jnp.einsum(
+        "bcshp,bcsn->bchpn", (xdt * to_end[..., None]).astype(dtype), b,
+        preferred_element_type=f32), STATES)
+    # the recurrence over the chunks, written out: the state before chunk i
+    # is the sum over chunks j < i of state j decayed through chunks j+1..i-1
+    whole = seg[..., -1]                                    # [b, c, h]
+    upto = jnp.cumsum(whole, axis=1)
+    between = (upto - whole)[:, :, None] - upto[:, None]    # [b, i, j, h]
+    earlier = jnp.tril(jnp.ones((nc, nc), dtype=bool), -1)[..., None]
+    carry = jnp.exp(jnp.where(earlier, between, -jnp.inf))
+    before = jnp.einsum("bijh,bjhpn->bihpn", carry, states,
+                        precision=jax.lax.Precision.HIGHEST)
+    # what a chunk reads of the state before it
+    y_before = jnp.einsum("bcln,bchpn->bclhp", c, before.astype(dtype),
+                          preferred_element_type=f32)
+    y = y + y_before * jnp.exp(seg).transpose(0, 1, 3, 2)[..., None]
+    return y.reshape(bsz, t, h, p)

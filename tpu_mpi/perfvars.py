@@ -21,7 +21,9 @@ pieces:
   grouped kernel or `lax.ragged_dot` as ``gmm_lowerings``, and the sums of
   rows into indexed places as the product on the MXU or XLA's scatter-add
   as ``row_sum_lowerings``, the rotary embeddings by their form as
-  ``rope_forms``, and what JAX traced, lowered, compiled and read
+  ``rope_forms``, the layers' mixers by kind as ``mixer_kinds`` and the
+  state-space scans by their form as ``scan_lowerings``, and what JAX
+  traced, lowered, compiled and read
   from its persistent cache, by function, with the Pallas kernels built
   under those traces, as the family ``build`` (:func:`listen_builds`;
   docs/observability.md "Set-up spans").
@@ -620,6 +622,30 @@ def note_rope_form(form: str) -> None:
     form."""
     with _store_lock:
         _rope_forms[form] += 1
+
+
+# `models.transformer._attn_ffn_block` likewise: the mixer of each layer
+# built into a traced program, `attention` or `ssm` (a state-space layer),
+# one count per trace of a layer kind; and `parallel.ssm.scan`: the form the
+# state-space scan took, `chunked`, or `padded` where the sequence is no
+# multiple of the chunk and is filled up to one.
+
+_mixer_kinds = {"attention": 0, "ssm": 0}
+_scan_lowerings = {"chunked": 0, "padded": 0}
+
+
+def note_mixer_kind(kind: str) -> None:
+    """One layer was traced with ``attention`` or a state-space scan
+    (``ssm``) as its mixer."""
+    with _store_lock:
+        _mixer_kinds[kind] += 1
+
+
+def note_scan_lowering(form: str) -> None:
+    """One state-space scan was traced in the ``chunked`` form, or in it
+    after the sequence was ``padded`` to a multiple of the chunk."""
+    with _store_lock:
+        _scan_lowerings[form] += 1
 
 
 # -- build: what JAX traced, lowered, compiled and read from its cache --------
@@ -1310,6 +1336,8 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "gmm_lowerings": dict(_gmm_lowerings),
             "row_sum_lowerings": dict(_row_sum_lowerings),
             "rope_forms": dict(_rope_forms),
+            "mixer_kinds": dict(_mixer_kinds),
+            "scan_lowerings": dict(_scan_lowerings),
             "build": build_snapshot(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
@@ -1356,6 +1384,8 @@ def reset() -> None:
         _gmm_lowerings.update(kernel=0, ragged_dot=0)
         _row_sum_lowerings.update(product=0, scatter=0)
         _rope_forms.update(dense=0, halves=0)
+        _mixer_kinds.update(attention=0, ssm=0)
+        _scan_lowerings.update(chunked=0, padded=0)
         _build_total[:] = [0, 0.0, 0, 0.0, 0, 0.0]
         _build_cache.update(hits=0, misses=0, load_s=0.0, saved_s=0.0)
         _build_by_fun.clear()
