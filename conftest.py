@@ -26,6 +26,11 @@ import pytest
 # their turn; both are asserted again, BY NAME and by no position, in
 # `yardstick/tests/test_build_metrics.py`, whose own assertions no later
 # append can falsify.)
+# (PR 38 appends `scan_kernel_share` to the granite cell: PR 37's line that
+# the cell reports exactly its sixteen, `sorted(names) == sorted(...)`, is
+# falsified in its turn, though its docstring says no append can; every name
+# it lists is asserted again, as a subset, in
+# `yardstick/tests/test_scan_kernel_share.py`.)
 LAST_ENTRIES_TESTS = (
     "yardstick/tests/test_lm_kinds_train_step.py::"
     "test_the_accepted_metrics_stand",
@@ -36,7 +41,9 @@ LAST_ENTRIES_TESTS = (
     "yardstick/tests/test_row_sum_product_share.py::"
     "test_the_entries_follow_what_the_benchmark_had",
     "yardstick/tests/test_row_sum_product_share.py::"
-    "test_the_held_cells_report_them")
+    "test_the_held_cells_report_them",
+    "yardstick/tests/test_lm_ssm_train_step.py::"
+    "test_the_cell_reports_what_the_issue_names")
 
 
 def pytest_collection_modifyitems(items):

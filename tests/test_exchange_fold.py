@@ -725,12 +725,14 @@ def test_compiled_for_the_v5e_the_state_space_step_fits_one_chip(
     state-space layers to one attention layer, the whole 100352-row
     vocabulary, batch 1 x 8192, parameters donated) compiled for one
     described v5e chip with the kernels selected as on a TPU: 952 M
-    parameters, at most 14.9 GB with nothing recomputed but the scan's decay
-    matrix (PR 37: 12.81 GB), no [.., t, t] buffer; the fused attention
-    kernel forward and backward in layer 5 alone, under its `attn` scope;
-    the five scopes of a mixer in the state-space layers; one trace of the
-    block a mixer kind, and no kernel but attention's pair and the
-    embedding's row sums."""
+    parameters, at most 14.9 GB with nothing recomputed (PR 37: 12.81 GB
+    with the scan's decay matrix computed again by XLA; PR 38: 12.92 with
+    the scan's kernel pair, which computes it again in VMEM), no [.., t, t]
+    buffer and no [.., chunk, chunk] one; the fused attention kernel
+    forward and backward in layer 5 alone, under its `attn` scope; the five
+    scopes of a mixer in the state-space layers, the scan's two kernels
+    under `scan` in each; one trace of the block a mixer kind, and no kernel
+    but attention's pair, the scan's pair and the embedding's row sums."""
     import json
     import os
     import re
@@ -782,7 +784,15 @@ def test_compiled_for_the_v5e_the_state_space_step_fits_one_chip(
             assert [n for n in names if f"layer_{i})" in n
                     and f"/mixer/{scope}/" in n], (i, scope)
     assert not [n for n in names if "layer_5)" in n and "/mixer/" in n]
+    assert not re.search(r"\[[\d,]*,256,256\]", hlo)     # no decay matrix
+    scans = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*/mixer/scan/[^\"]*ssm_scan_(\w+)/"
+                       r"[^\"]*)\"", hlo)
+    assert sorted((d, int(re.search(r"layer_(\d+)", name).group(1)))
+                  for name, d in scans) == [
+        (d, i) for d in ("bwd", "fwd") for i in range(10) if i != 5]
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
     assert sorted(kernels) == ["causal_attention_bwd", "causal_attention_fwd",
-                               "grouped_row_sums"], kernels
+                               "grouped_row_sums", "ssm_scan_bwd",
+                               "ssm_scan_fwd"], kernels
     tf._block_traced_once.cache_clear()

@@ -25,6 +25,24 @@ sequence is a multiple of the chunk (a sequence shorter than a chunk is one
 chunk), ``padded`` where it is not: the sequence is filled up to the next
 multiple with tokens of ``dt`` = 0, which decay nothing and add nothing, so
 the result is exact, and their outputs are cut off.
+
+Who computes that form is chosen the same way, after the padding, and
+counted beside it (``["scan_kernel_lowerings"]``, ``kernel`` or ``plain``,
+one count a traced scan). ``kernel``: the Pallas pair of
+``xla/ssm_kernels.py``, forward and one backward (``jax.custom_vjp``), where
+a kernel backend is there (`ring._kernel_backend`: a TPU; the tests' word
+selects the interpret machine) and the shapes fit its tiles: float32 or
+bfloat16, heads 64 wide in a multiple of 8, a state of 128 or 256, a chunk
+of 128 or 256 tokens (granite's cell: 64 heads of 64, state 128, chunk
+256). There ``L``, ``L o C B^T`` and the running state live in VMEM alone,
+the chunks are walked in order with the state carried (backward: in reverse
+with its cotangent), x and y cross HBM as [batch, t, heads x width] rows,
+and the backward pass keeps x, dt, the sums, B, C and the state BEFORE each
+chunk ([batch, chunks, state, heads x width] float32: as many bytes as the
+states above) and computes ``L`` again inside the kernel. ``plain``:
+:func:`_chunked` below, as it stands, everywhere else (the CPU, odd
+widths); it is the fallback and the yardstick that
+`tests/test_ssm_kernel.py` holds the kernels to.
 """
 
 from __future__ import annotations
@@ -36,6 +54,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import perfvars
+from . import ring
 
 STATES = "ssm_chunk_states"     # what the backward pass keeps of `_chunked`
 
@@ -60,7 +79,8 @@ def scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
     heads, width], dt [batch, t, heads] (> 0), a [heads] (< 0), b and c
     [batch, t, state] and the skip's d [heads]; dt, a and d float32. Each
     call built into a traced program counts in
-    ``perfvars.snapshot()["scan_lowerings"]`` as ``chunked`` or ``padded``."""
+    ``perfvars.snapshot()["scan_lowerings"]`` as ``chunked`` or ``padded``,
+    and in ``["scan_kernel_lowerings"]`` as ``kernel`` or ``plain``."""
     t = x.shape[1]
     length = min(chunk, t)
     pad = -t % length
@@ -69,9 +89,29 @@ def scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
     def filled(v):      # up to the next multiple, with tokens of zeros
         widths = ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)
         return jnp.pad(v, widths) if pad else v
+    if scan_kernel_selected(x.shape, x.dtype, b.shape[-1], length):
+        from ..xla import ssm_kernels
+        perfvars.note_scan_kernel_lowering("kernel")
+        return ssm_kernels.ssm_scan(
+            filled(x), filled(dt), a, filled(b), filled(c), d, length=length,
+            interpret=ring._kernel_backend() == "interpret")[:, :t]
+    perfvars.note_scan_kernel_lowering("plain")
     y = _chunked(filled(x), filled(dt), a, filled(b), filled(c),
                  length)[:, :t]
     return (y + x.astype(jnp.float32) * d[:, None]).astype(x.dtype)
+
+
+def scan_kernel_selected(shape: tuple, dtype, state: int, length: int) -> bool:
+    """Whether :func:`scan` runs the Pallas kernel pair for x of ``shape``
+    [batch, t, heads, width] in chunks of ``length`` over a state of
+    ``state``: decided from the backend (`ring._kernel_backend`) and the
+    kernel's contract (`ssm_kernels.ssm_scan_selected`), never by trying it:
+    once selected, a kernel that does not lower is an error."""
+    if ring._kernel_backend() is None:
+        return False
+    from ..xla import ssm_kernels
+    return ssm_kernels.ssm_scan_selected(shape[2], shape[3], state, length,
+                                         jnp.dtype(dtype))
 
 
 @functools.partial(

@@ -22,7 +22,9 @@ pieces:
   rows into indexed places as the product on the MXU or XLA's scatter-add
   as ``row_sum_lowerings``, the rotary embeddings by their form as
   ``rope_forms``, the layers' mixers by kind as ``mixer_kinds`` and the
-  state-space scans by their form as ``scan_lowerings``, and what JAX
+  state-space scans by their form as ``scan_lowerings`` and by who computes
+  them (the Pallas kernels or plain `jnp`) as ``scan_kernel_lowerings``, and
+  what JAX
   traced, lowered, compiled and read
   from its persistent cache, by function, with the Pallas kernels built
   under those traces, as the family ``build`` (:func:`listen_builds`;
@@ -646,6 +648,19 @@ def note_scan_lowering(form: str) -> None:
     after the sequence was ``padded`` to a multiple of the chunk."""
     with _store_lock:
         _scan_lowerings[form] += 1
+
+
+# and who computes that form: the Pallas kernel pair of `xla/ssm_kernels.py`
+# or the plain `jnp` of `parallel.ssm._chunked`, one count a traced scan.
+
+_scan_kernel_lowerings = {"kernel": 0, "plain": 0}
+
+
+def note_scan_kernel_lowering(kind: str) -> None:
+    """One state-space scan was traced as the ``kernel`` or as ``plain``
+    `jnp`."""
+    with _store_lock:
+        _scan_kernel_lowerings[kind] += 1
 
 
 # -- build: what JAX traced, lowered, compiled and read from its cache --------
@@ -1338,6 +1353,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "rope_forms": dict(_rope_forms),
             "mixer_kinds": dict(_mixer_kinds),
             "scan_lowerings": dict(_scan_lowerings),
+            "scan_kernel_lowerings": dict(_scan_kernel_lowerings),
             "build": build_snapshot(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
@@ -1386,6 +1402,7 @@ def reset() -> None:
         _rope_forms.update(dense=0, halves=0)
         _mixer_kinds.update(attention=0, ssm=0)
         _scan_lowerings.update(chunked=0, padded=0)
+        _scan_kernel_lowerings.update(kernel=0, plain=0)
         _build_total[:] = [0, 0.0, 0, 0.0, 0, 0.0]
         _build_cache.update(hits=0, misses=0, load_s=0.0, saved_s=0.0)
         _build_by_fun.clear()
