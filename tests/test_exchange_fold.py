@@ -796,3 +796,30 @@ def test_compiled_for_the_v5e_the_state_space_step_fits_one_chip(
                                "grouped_row_sums", "ssm_scan_bwd",
                                "ssm_scan_fwd"], kernels
     tf._block_traced_once.cache_clear()
+
+
+def test_compiled_for_the_v5e_the_attention_kernel_with_values_wider_than_scores(
+        v5e_2x2):
+    """The fused causal attention, forward and backward, at differential
+    attention's shape in the benchmark's phi-4-mini-flash-reasoning stage (40
+    query heads of 64 reading 20 key heads of 64 and 20 value heads of 128, a
+    pair's values side by side; seq 8192, bfloat16, no second score term)
+    lowers through Mosaic with the window of 512 and without one; dk and dv
+    come back with the key/value heads' shapes."""
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import pallas_kernels as pk
+    one = SingleDeviceSharding(v5e_2x2[0])
+    assert pk.causal_attention_blocks(8192, 64, 0, 128) == (512, 512)
+    q = jax.ShapeDtypeStruct((1, 40, 8192, 64), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((1, 20, 8192, 64), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, 20, 8192, 128), jnp.bfloat16, sharding=one)
+    for window in (512, 0):
+        compiled = jax.jit(jax.grad(lambda q, k, v: pk.causal_attention(
+            q, k, v, window=window, interpret=False).astype(
+                jnp.float32).sum(), (0, 1, 2))).lower(q, k, v).compile()
+        hlo = compiled.as_text()
+        assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+        assert ",8192,8192]" not in hlo
+        shapes = [o.shape for o in jax.tree.leaves(compiled.out_info)]
+        assert shapes == [(1, 40, 8192, 64), (1, 20, 8192, 64),
+                          (1, 20, 8192, 128)]

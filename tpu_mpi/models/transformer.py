@@ -127,6 +127,24 @@ class TransformerConfig:
     ssm_conv: int = 4           # the convolution's taps: a token and the
     #                             ssm_conv - 1 before it, with a bias
     ssm_chunk: int = 256        # tokens a chunk of the scan
+    # A decoder-hybrid-decoder stack: beside the residual stream TWO side
+    # values, each written by one layer and read by later ones. "mamba": a
+    # Mamba-1 layer (`_mamba_mixer`: ssm_expand x d_model wide, ssm_state a
+    # channel, ssm_conv taps, dt through a rank of ssm_dt_rank, chunks of
+    # ssm_chunk), of which layer `memory_from`'s scan output, before its
+    # gate, is the memory; "gmu": a gated memory unit, out(memory x
+    # silu(in(x))), no mixing over the sequence of its own; "cross":
+    # attention with queries of its own over layer `kv_from`'s keys and
+    # values (an "attention" layer with window 0).
+    ssm_dt_rank: int = 0
+    memory_from: int = -1
+    kv_from: int = -1
+    diff_attn: bool = False     # differential attention: n_heads / 2 heads,
+    #                             each the difference of two softmaxes (`_diff_attn`)
+    attn_bias: bool = False     # biases `b_q`, `b_k`, `b_v`, `b_proj` of
+    #                             differential attention (refused without)
+    norm_kind: str = "rms"      # | "layer": LayerNorm (mean and variance), a
+    #                             learned scale and a bias `<norm>_b`
     # Scalars on the residual stream, as data; 1.0 (and 0.0) add no equation.
     embed_multiplier: float = 1.0       # x the embedding's rows
     residual_multiplier: float = 1.0    # x each half's output, before the add
@@ -163,9 +181,9 @@ class TransformerConfig:
         if self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError(f"n_heads={self.n_heads} is no multiple of "
                              f"n_kv_heads={self.n_kv_heads}")
-        if set(self.mixer_kinds) - {"attention", "ssm"}:
+        if set(self.mixer_kinds) - set(MIXERS):
             raise ValueError(f"mixer_kinds={self.mixer_kinds}: a layer's "
-                             f"mixer is \"attention\" or \"ssm\"")
+                             f"mixer is one of {MIXERS}")
         if "ssm" in self.mixer_kinds and not (
                 self.ssm_state > 0 and self.ssm_conv > 0 and self.ssm_chunk > 0
                 and self.ssm_heads * self.ssm_head_dim
@@ -178,6 +196,48 @@ class TransformerConfig:
         if self.attn_scale and self.kv_latent:
             raise ValueError("latent attention scales its scores by "
                              "(d_head + d_rope) ** -0.5: no attn_scale")
+        if self.norm_kind not in ("rms", "layer"):
+            raise ValueError(f"norm_kind={self.norm_kind!r}: \"rms\" or "
+                             f"\"layer\"")
+        if self.diff_attn and not (
+                self.n_kv_heads and self.n_kv_heads % 2 == 0
+                and self.n_heads % 2 == 0 and not self.kv_latent
+                and not self.qk_norm and not self.qk_norm_heads):
+            raise ValueError(
+                f"differential attention pairs its heads: n_heads="
+                f"{self.n_heads} and n_kv_heads={self.n_kv_heads} are even "
+                f"and above 0, with no latent and no norm of q and k")
+        if self.attn_bias and not self.diff_attn:
+            raise ValueError("attn_bias adds its biases in differential "
+                             "attention alone: set diff_attn with it")
+        self._check_side_values()
+
+    def _check_side_values(self):
+        """The layers that write and read the two side values stand where
+        the values exist: the memory is a "mamba" layer's, before every
+        "gmu"; the shared keys and values a full "attention" layer's, before
+        every "cross"."""
+        kinds = self.mixer_kinds
+        if "mamba" in kinds and not (
+                self.ssm_state > 0 and self.ssm_conv > 0 and self.ssm_chunk > 0
+                and self.ssm_dt_rank > 0 and self.ssm_expand > 0):
+            raise ValueError("mamba layers name ssm_state, ssm_conv, ssm_chunk, "
+                             "ssm_dt_rank and ssm_expand above 0")
+        for reader, source, writer in (("gmu", self.memory_from, "mamba"),
+                                       ("cross", self.kv_from, "attention")):
+            first = kinds.index(reader) if reader in kinds else None
+            if first is None and source < 0:
+                continue
+            if not (0 <= source < len(kinds) and kinds[source] == writer
+                    and (first is None or source < first)):
+                raise ValueError(
+                    f"a {reader!r} layer reads what layer {source} wrote: "
+                    f"that is a {writer!r} layer before the first of them")
+        if "cross" in kinds and not (
+                self.diff_attn and self.n_kv_heads
+                and not (self.attn_windows and self.attn_windows[self.kv_from])):
+            raise ValueError("a \"cross\" layer is differential attention "
+                             "over a full (window 0) layer's keys and values")
 
     @property
     def head_dim(self) -> int:
@@ -199,14 +259,22 @@ class TransformerConfig:
     def ssm_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
 
+    @property
+    def mamba_inner(self) -> int:
+        """A Mamba-1 layer's width, the memory's and a gated memory unit's."""
+        return self.ssm_expand * self.d_model
+
     def layer_kind(self, i: int) -> "LayerKind":
         sparse = self.ffn_kinds[i] == "sparse" if self.ffn_kinds \
             else bool(self.n_experts)
         mixer = self.mixer_kinds[i] if self.mixer_kinds else "attention"
         window = self.attn_windows[i] if self.attn_windows else 0
-        return LayerKind(0 if mixer == "ssm" else window, sparse,
+        return LayerKind(window if mixer == "attention" else 0, sparse,
                          self.remat_layers[i] if self.remat_layers else "",
                          mixer)
+
+
+MIXERS = ("attention", "ssm", "mamba", "gmu", "cross")
 
 
 class LayerKind(NamedTuple):
@@ -214,7 +282,7 @@ class LayerKind(NamedTuple):
     window: int         # 0: full causal attention
     sparse: bool        # routed experts (and shared ones), else a dense FFN
     remat: str          # "" or "ffn"
-    mixer: str = "attention"    # or "ssm": a state-space scan in its place
+    mixer: str = "attention"    # or another of `MIXERS` in its place
 
 
 def transformer_init(key, cfg: TransformerConfig) -> dict:
@@ -233,6 +301,8 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[1], (d, cfg.vocab), d ** -0.5)
+    if cfg.norm_kind == "layer":
+        params["ln_f_b"] = dense(jax.random.fold_in(keys[1], 1), (d,), 0.02)
     for i in range(cfg.n_layers):
         k = keys[2 + 4 * i: 6 + 4 * i]
         sparse = cfg.layer_kind(i).sparse
@@ -242,9 +312,17 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         layer = {"ln1": jnp.ones((d,), cfg.dtype)}
         # the leaves a public block adds draw from keys of their own, so
         # the flagship's are the flagship's whatever else is configured
-        ssm = cfg.layer_kind(i).mixer == "ssm"
-        if ssm:
+        mixer = cfg.layer_kind(i).mixer
+        attends = mixer in ("attention", "cross")
+        if mixer == "ssm":
             layer.update(_ssm_init(cfg, k[0], k[1], dense))
+        elif mixer == "mamba":
+            layer.update(_mamba_init(cfg, k[0], k[1], dense))
+        elif mixer == "gmu":
+            inner = cfg.mamba_inner
+            layer["w_gmu_in"] = dense(k[0], (d, inner), d ** -0.5)
+            layer["w_gmu_out"] = dense(k[1], (inner, d),
+                                       (2 * inner * cfg.n_layers) ** -0.5)
         elif cfg.kv_latent:
             h, cq, ckv = cfg.n_heads_here, cfg.q_latent, cfg.kv_latent
             for n, (name, shape) in enumerate((
@@ -259,26 +337,44 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         elif cfg.n_kv_heads:
             kv = cfg.n_kv_heads * cfg.head_dim
             layer["w_q"] = dense(jax.random.fold_in(k[0], 1), (d, hd), d ** -0.5)
-            layer["w_k"] = dense(jax.random.fold_in(k[0], 2), (d, kv), d ** -0.5)
-            layer["w_v"] = dense(jax.random.fold_in(k[0], 3), (d, kv), d ** -0.5)
+            if mixer != "cross":    # a cross layer's are another layer's
+                layer["w_k"] = dense(jax.random.fold_in(k[0], 2), (d, kv),
+                                     d ** -0.5)
+                layer["w_v"] = dense(jax.random.fold_in(k[0], 3), (d, kv),
+                                     d ** -0.5)
         else:
             layer["w_qkv"] = dense(k[0], (d, 3 * hd), d ** -0.5)
-        if not ssm:
+        if attends:
             layer["w_proj"] = dense(k[1], (hd, d),
                                     (2 * hd * cfg.n_layers) ** -0.5)
+        if attends and cfg.attn_bias:
+            for n, w in enumerate(("w_q", "w_k", "w_v", "w_proj")):
+                if w in layer:
+                    layer["b" + w[1:]] = dense(jax.random.fold_in(k[0], 8 + n),
+                                               layer[w].shape[1:], 0.02)
+        if attends and cfg.diff_attn:
+            for n, name in enumerate(("lambda_q1", "lambda_k1", "lambda_q2",
+                                      "lambda_k2")):
+                layer[name] = (0.1 * jax.random.normal(
+                    jax.random.fold_in(k[0], 12 + n), (cfg.head_dim,),
+                    jnp.float32))
+            layer["diff_norm"] = jnp.ones((2 * cfg.head_dim,), cfg.dtype)
         layer.update({
             "ln2": jnp.ones((d,), cfg.dtype),
             "w_in": dense(k[2], experts + (d, f), d ** -0.5),
             "w_out": dense(k[3], experts + (f, d),
                            (2 * f * cfg.n_layers) ** -0.5),
         })
+        if cfg.norm_kind == "layer":
+            layer["ln1_b"] = dense(jax.random.fold_in(k[2], 5), (d,), 0.02)
+            layer["ln2_b"] = dense(jax.random.fold_in(k[2], 6), (d,), 0.02)
         if cfg.norm_out:
             layer["ln1_out"] = jnp.ones((d,), cfg.dtype)
             layer["ln2_out"] = jnp.ones((d,), cfg.dtype)
-        if cfg.qk_norm and not ssm:
+        if cfg.qk_norm and attends:
             layer["q_norm"] = jnp.ones((d,), cfg.dtype)
             layer["k_norm"] = jnp.ones((d,), cfg.dtype)
-        if cfg.qk_norm_heads and not ssm:
+        if cfg.qk_norm_heads and attends:
             layer["q_norm"] = jnp.ones((cfg.head_dim,), cfg.dtype)
             layer["k_norm"] = jnp.ones((cfg.head_dim,), cfg.dtype)
         if sparse or cfg.dense_gated:
@@ -327,6 +423,37 @@ def _ssm_init(cfg: TransformerConfig, key, key_out, dense) -> dict:
     }
 
 
+def _mamba_init(cfg: TransformerConfig, key, key_out, dense) -> dict:
+    """The leaves of a Mamba-1 layer's mixer. `w_ssm_in` [d, x | z] (the
+    convolution's channels and the gate, inner wide each); `conv_w` [taps,
+    inner] and `conv_b`; `w_ssm_x` [inner, dt_low | B | C] (ssm_dt_rank +
+    2 x ssm_state); `w_ssm_dt` [ssm_dt_rank, inner], uniform within
+    ssm_dt_rank ** -0.5; `w_ssm_out` [inner, d]. Float32, as the recurrence's
+    arithmetic: `dt_bias` [inner] (the inverse softplus of values drawn
+    log-uniformly from [0.001, 0.1]), `a_log` [inner, state] (A = -exp(a_log);
+    log(1..state) a channel) and `d_skip` (ones): the family's published
+    initialisation."""
+    d, inner, n, r = cfg.d_model, cfg.mamba_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    keys = jax.random.split(key, 6)
+    dt = jnp.exp(jax.random.uniform(keys[3], (inner,), jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    return {
+        "w_ssm_in": dense(keys[0], (d, 2 * inner), d ** -0.5),
+        "conv_w": dense(keys[1], (cfg.ssm_conv, inner), cfg.ssm_conv ** -0.5),
+        "conv_b": dense(keys[2], (inner,), cfg.ssm_conv ** -0.5),
+        "w_ssm_x": dense(keys[4], (inner, r + 2 * n), inner ** -0.5),
+        "w_ssm_dt": jax.random.uniform(
+            keys[5], (r, inner), jnp.float32, -r ** -0.5,
+            r ** -0.5).astype(cfg.dtype),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "a_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)), (inner, n)),
+        "d_skip": jnp.ones((inner,), jnp.float32),
+        "w_ssm_out": dense(key_out, (inner, d),
+                           (2 * inner * cfg.n_layers) ** -0.5),
+    }
+
+
 def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> dict:
     """PartitionSpec pytree matching transformer_init's params: qkv/ffn-in
     column-sharded, proj/ffn-out row-sharded over the tp axis; everything
@@ -342,24 +469,46 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
         out = {"ln1": rep, "w_proj": row, "ln2": rep,
                "w_in": rep if sparse else col,
                "w_out": rep if sparse else row}
-        ssm = cfg.layer_kind(i).mixer == "ssm"
-        if ssm:     # whole on every rank (tp 1 and sp 1 only: `_ssm_mixer`)
+        mixer = cfg.layer_kind(i).mixer
+        attends = mixer in ("attention", "cross")
+        if mixer == "ssm":  # whole on every rank (tp 1 and sp 1 only: `_ssm_mixer`)
             del out["w_proj"]
             out.update({name: rep for name in (
                 "w_ssm_in", "conv_w", "conv_b", "dt_bias", "a_log", "d_skip",
                 "ssm_norm", "w_ssm_out")})
+        elif mixer == "mamba":      # likewise (`_mamba_mixer`)
+            del out["w_proj"]
+            out.update({name: rep for name in (
+                "w_ssm_in", "conv_w", "conv_b", "w_ssm_x", "w_ssm_dt",
+                "dt_bias", "a_log", "d_skip", "w_ssm_out")})
+        elif mixer == "gmu":
+            del out["w_proj"]
+            out.update(w_gmu_in=rep, w_gmu_out=rep)
         elif cfg.kv_latent:   # whole on every rank (tp 1 only: `_latent_attn`)
             out.update(w_dq=rep, w_uq=rep, w_dkv=rep, w_ukv=rep, w_proj=rep,
                        q_latent_norm=rep, kv_latent_norm=rep)
         elif cfg.n_kv_heads:
-            out.update(w_q=col, w_k=col, w_v=col)
+            # differential attention is whole on every rank (`_diff_attn`)
+            part = rep if cfg.diff_attn else col
+            out.update(w_q=part)
+            if mixer != "cross":
+                out.update(w_k=part, w_v=part)
         else:
             out["w_qkv"] = col
+        if attends and cfg.attn_bias:
+            out.update(b_q=rep, b_proj=rep)
+            if mixer != "cross":
+                out.update(b_k=rep, b_v=rep)
+        if attends and cfg.diff_attn:
+            out.update(w_proj=rep, lambda_q1=rep, lambda_k1=rep,
+                       lambda_q2=rep, lambda_k2=rep, diff_norm=rep)
+        if cfg.norm_kind == "layer":
+            out.update(ln1_b=rep, ln2_b=rep)
         if cfg.norm_out:
             out.update(ln1_out=rep, ln2_out=rep)
-        if cfg.qk_norm and not ssm:
+        if cfg.qk_norm and attends:
             out.update(q_norm=P(tp_axis), k_norm=P(tp_axis))
-        if cfg.qk_norm_heads and not ssm:
+        if cfg.qk_norm_heads and attends:
             out.update(q_norm=rep, k_norm=rep)
         if sparse:
             out.update(w_gate=rep, w_router=rep)
@@ -373,12 +522,32 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
              "layers": [layer(i) for i in range(cfg.n_layers)]}
     if not cfg.tie_embeddings:
         specs["lm_head"] = rep
+    if cfg.norm_kind == "layer":
+        specs["ln_f_b"] = rep
     return specs
 
 
 def _rms_norm(x, scale, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
+
+
+def _layer_norm(x, scale, bias, eps: float):
+    """LayerNorm: the mean taken off, the variance's root divided out
+    (float32 inside, rounded once), a learned scale and a bias."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+    return ((x32 - mean) * jax.lax.rsqrt(var + eps)).astype(x.dtype) \
+        * scale + bias
+
+
+def _norm(cfg: TransformerConfig, x, leaves: dict, name: str):
+    """The model's norm of the stream with the scale `leaves[name]` (and,
+    for a LayerNorm, the bias `leaves[name + "_b"]`)."""
+    if cfg.norm_kind == "layer":
+        return _layer_norm(x, leaves[name], leaves[name + "_b"], cfg.norm_eps)
+    return _rms_norm(x, leaves[name], cfg.norm_eps)
 
 
 def _rope_table(positions, theta: float, width: int, lane_in_head):
@@ -529,15 +698,24 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
         if cfg.embed_multiplier != 1.0:
             x = x * cfg.embed_multiplier
     routed = []
+    side = {}       # the values beside the stream: "memory", "kv"
     for i, layer in enumerate(params["layers"]):
-        block = _block_traced_once(cfg, cfg.layer_kind(i), tp_axis, sp_axis,
+        kind = cfg.layer_kind(i)
+        block = _block_traced_once(cfg, kind, tp_axis, sp_axis,
                                    ring._kernel_backend())
         with jax.named_scope(f"layer_{i}"):
-            x, sent = block(layer, x, positions)
+            x, sent, wrote = block(layer, x, positions,
+                                   _side_read(cfg, kind, i, side))
+        # every layer of a kind hands out what its kind can (one trace a
+        # kind); the one the model names is the one that is read
+        if i == cfg.memory_from:
+            side["memory"] = wrote["memory"]
+        if i == cfg.kv_from:
+            side["kv"] = wrote["kv"]
         if sent is not None:
             routed.append(sent)
     with jax.named_scope("head_loss"):
-        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+        x = _norm(cfg, x, params, "ln_f")
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = (x @ head).astype(jnp.float32)                   # (b, t, V)
         if cfg.logits_divisor != 1.0:
@@ -560,23 +738,48 @@ def _block_traced_once(cfg: TransformerConfig, kind: LayerKind,
     keeps a step's trace, which is set-up time, from growing with depth
     (PERF.md, Set-up). ``kernels`` is what `ring._kernel_backend` says: the
     kernels are selected inside the trace, so it is part of the key."""
-    def block(layer, x, positions):
+    def block(layer, x, positions, side):
         return _attn_ffn_block(cfg, layer, x, positions, tp_axis=tp_axis,
-                               sp_axis=sp_axis, kind=kind)
+                               sp_axis=sp_axis, kind=kind, side=side)
     return jax.jit(block)
+
+
+def _side_read(cfg: TransformerConfig, kind: LayerKind, i: int,
+               side: dict) -> dict:
+    """What layer ``i`` is handed beside the stream, as traced inputs of its
+    kind's one function: a gated memory unit the memory, a cross layer the
+    shared keys and values, a differential attention layer its depth (its
+    `lambda_init` is a function of it, and layers of a kind share a trace).
+    Every other layer nothing: its function's inputs are what they were.
+    Each read counts in ``perfvars.snapshot()["side_values"]``."""
+    out = {}
+    if kind.mixer == "gmu":
+        perfvars.note_side_value("memory")
+        out["memory"] = side["memory"]
+    if kind.mixer == "cross":
+        perfvars.note_side_value("kv")
+        out["kv"] = side["kv"]
+    if cfg.diff_attn and kind.mixer in ("attention", "cross"):
+        out["depth"] = jnp.float32(i)
+    return out
 
 
 def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
                     positions: jnp.ndarray, *, tp_axis: Optional[str],
-                    sp_axis: Optional[str], kind: Optional[LayerKind] = None):
+                    sp_axis: Optional[str], kind: Optional[LayerKind] = None,
+                    side: Optional[dict] = None):
     """One transformer layer (pre-norm attention + FFN), tp/sp aware —
     shared by the flat forward and the pipelined 4-axis stage. ``kind`` is
     the layer's (`cfg.layer_kind(i)`; None: layer 0's). Returns the layer's
-    output and what its router sent where (None without experts). With
-    `norm_out` each half's output is normed before it joins the residual
-    (scope `norm_out`); where the rank holds a share of the heads or of the
-    experts that output is a partial sum, and is normed as it stands."""
+    output, what its router sent where (None without experts) and what it
+    wrote beside the stream (`side`: what it read there, `_side_read`; a
+    "mamba" layer writes `memory`, a differential "attention" layer `kv`,
+    any other nothing). With `norm_out` each half's output is normed before
+    it joins the residual (scope `norm_out`); where the rank holds a share
+    of the heads or of the experts that output is a partial sum, and is
+    normed as it stands."""
     kind = cfg.layer_kind(0) if kind is None else kind
+    side = side or {}
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
     h_local = cfg.n_heads_here // tp
 
@@ -600,10 +803,29 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
             out = out * cfg.residual_multiplier
         return out
     perfvars.note_mixer_kind(kind.mixer)
+    wrote = {}
     if kind.mixer == "ssm":
         with jax.named_scope("mixer"):
             x = x + normed(_ssm_mixer(cfg, layer, x, tp_axis=tp_axis,
                                       sp_axis=sp_axis), "ln1_out")
+    elif kind.mixer in ("mamba", "gmu") or cfg.diff_attn:
+        for axis in (tp_axis, sp_axis):
+            if axis is not None and lax.axis_size(axis) > 1:
+                raise NotImplementedError(
+                    f"a layer of a decoder-hybrid-decoder stack runs at tp 1 "
+                    f"and sp 1 ({axis!r} has {lax.axis_size(axis)} ranks): "
+                    f"its state and its side values are not cut")
+        with jax.named_scope("attn" if kind.mixer in ("attention", "cross")
+                             else "mixer"):
+            if kind.mixer == "mamba":
+                out, wrote = _mamba_mixer(cfg, layer, x)
+            elif kind.mixer == "gmu":
+                out = _gmu_mixer(cfg, layer, x, side["memory"])
+            else:
+                out, wrote = _diff_attn(cfg, layer, x, window=kind.window,
+                                        depth=side["depth"],
+                                        kv=side.get("kv"))
+            x = x + normed(out, "ln1_out")
     else:
         with jax.named_scope("attn"):
             x = x + normed(attn(layer, x, positions), "ln1_out")
@@ -618,7 +840,7 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
 
     def ffn(layer, x):
         """What the FFN half adds to the residual, and the router's word."""
-        y = _rms_norm(x, layer["ln2"], cfg.norm_eps)
+        y = _norm(cfg, x, layer, "ln2")
         if kind.sparse:
             return _expert_ffn(cfg, layer, y)
         with jax.named_scope("dense"):
@@ -641,7 +863,7 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     with jax.named_scope("mlp"):
         out, sent = ffn(layer, x)
         x = x + normed(out, "ln2_out")
-    return x, sent
+    return x, sent, wrote
 
 
 @jax.custom_vjp
@@ -787,7 +1009,7 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     """The attention half of a layer: what is added to the residual."""
     b, t, _ = x.shape
     dh = cfg.head_dim
-    y = _rms_norm(x, layer["ln1"], cfg.norm_eps)
+    y = _norm(cfg, x, layer, "ln1")
     if cfg.kv_latent:
         return _latent_attn(cfg, layer, y, positions, tp_axis=tp_axis,
                             sp_axis=sp_axis)
@@ -908,7 +1130,7 @@ def _ssm_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
     #                                 no import for it
     b, t, _ = x.shape
     inner, h, n = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_state
-    y = _rms_norm(x, layer["ln1"], cfg.norm_eps)
+    y = _norm(cfg, x, layer, "ln1")
     with jax.named_scope("in_proj"):
         z, xbc, dt = jnp.split(y @ layer["w_ssm_in"],
                                [inner, 2 * inner + 2 * n], axis=-1)
@@ -925,6 +1147,109 @@ def _ssm_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
         o = _rms_norm(o * jax.nn.silu(z), layer["ssm_norm"], cfg.norm_eps)
     with jax.named_scope("out_proj"):
         return o @ layer["w_ssm_out"]
+
+
+def _mamba_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray):
+    """A Mamba-1 layer's first half: (what is added to the residual, {"memory":
+    the scan's output}). One product gives x and the gate z, inner =
+    ssm_expand x d_model wide each; x passes a causal depthwise convolution
+    (`ssm_conv` taps, with bias) and silu; from the convolved x one product
+    gives dt's low-rank form, B and C (ssm_state wide, one vector a token for
+    all channels), and dt = softplus(dt_low w_ssm_dt + dt_bias) a channel; A =
+    -exp(a_log) [inner, state]; `parallel.ssm.selective_scan` computes
+    S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c],
+    y_t[c] = sum_n C_t[n] S_t[c, n] + D[c] x_t[c]; then (y x silu(z))
+    w_ssm_out: no norm here. The memory is y, the scan's output with its skip
+    term, BEFORE the gate. Scopes: `in_proj`, `conv`, `x_proj` (with dt's
+    projection and softplus), `scan`, `gate`, `out_proj`."""
+    from ..parallel import ssm      # a program without such a layer pays
+    #                                 no import for it
+    inner, n, r = cfg.mamba_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    y = _norm(cfg, x, layer, "ln1")
+    with jax.named_scope("in_proj"):
+        xs, z = jnp.split(y @ layer["w_ssm_in"], [inner], axis=-1)
+    with jax.named_scope("conv"):
+        xs = jax.nn.silu(ssm.causal_conv(xs, layer["conv_w"],
+                                         layer["conv_b"]))
+    with jax.named_scope("x_proj"):
+        dt, b_in, c_in = jnp.split(xs @ layer["w_ssm_x"], [r, r + n], axis=-1)
+        dt = jax.nn.softplus((dt @ layer["w_ssm_dt"]).astype(jnp.float32)
+                             + layer["dt_bias"])
+    with jax.named_scope("scan"):
+        o = ssm.selective_scan(xs, dt, -jnp.exp(layer["a_log"]), b_in, c_in,
+                               layer["d_skip"], cfg.ssm_chunk)
+    with jax.named_scope("gate"):
+        gated = o * jax.nn.silu(z)
+    with jax.named_scope("out_proj"):
+        return gated @ layer["w_ssm_out"], {"memory": o}
+
+
+def _gmu_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
+               memory: jnp.ndarray) -> jnp.ndarray:
+    """A gated memory unit: out(memory x silu(in(x))), elementwise in the
+    token: a layer with no mixing over the sequence of its own. Scope `gmu`."""
+    y = _norm(cfg, x, layer, "ln1")
+    with jax.named_scope("gmu"):
+        return (memory * jax.nn.silu(y @ layer["w_gmu_in"])) \
+            @ layer["w_gmu_out"]
+
+
+def lambda_init(depth):
+    """Differential attention's `lambda_init` of a layer at ``depth``."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * depth)
+
+
+def _diff_attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
+               window: int, depth, kv: Optional[tuple] = None):
+    """Differential attention without positions: (what is added to the
+    residual, {"kv": the keys and values as the attention read them}). The
+    n_heads query heads of `d_head` are n_heads / 2 differential heads: head
+    i is the difference of two softmaxes, o_i = (softmax(q_i1 k_j1^T) - lambda
+    softmax(q_i2 k_j2^T)) [v_j1 | v_j2], over the key/value PAIR j = i //
+    (n_heads / n_kv_heads), its values both heads' side by side (2 x d_head
+    wide); lambda = exp(lambda_q1 . lambda_k1) - exp(lambda_q2 . lambda_k2) +
+    lambda_init(depth), float32; then RMSNorm over each o_i's 2 x d_head
+    values (`diff_norm`) x (1 - lambda_init), and the output projection.
+    Each softmax is one head of ONE `local_attention` call (the fused kernel
+    where it is selected) with values wider than its scores' heads: `w_q`'s
+    columns are laid out [pair j][softmax s][head r of the pair's n_heads /
+    n_kv_heads] so that the kernel's grouping (query head m reads key/value
+    head m // group) hands query (j, s, r) key 2 j + s; value head 2 j + s is
+    the pair's [v_j1 | v_j2] for either s. The subtraction, the norm and the
+    scale run in float32 under scope `diff`. ``kv``: another layer's keys and
+    values in that layout (a cross layer: queries of its own, no `w_k`,
+    `w_v`)."""
+    b, t, _ = x.shape
+    h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    group = h // hk
+
+    def projected(name):
+        out = y @ layer["w_" + name]
+        return out + layer["b_" + name] if cfg.attn_bias else out
+    y = _norm(cfg, x, layer, "ln1")
+    q = _cut_heads(projected("q"), h)
+    if kv is None:
+        k = _cut_heads(projected("k"), hk)
+        v = jnp.repeat(_cut_heads(projected("v"), hk // 2), 2, axis=1)
+    else:
+        k, v = kv
+    fused = fused_attention_selected(q.shape, q.dtype, 0, v.shape[3])
+    perfvars.note_attn_kind("diff", "fused" if fused else "plain")
+    o = local_attention(q, k, v, window)            # [b, h, t, 2 dh]
+    with jax.named_scope("diff"):
+        f32 = jnp.float32
+        start = lambda_init(depth)
+        lam = jnp.exp(jnp.sum(layer["lambda_q1"].astype(f32)
+                              * layer["lambda_k1"].astype(f32))) \
+            - jnp.exp(jnp.sum(layer["lambda_q2"].astype(f32)
+                              * layer["lambda_k2"].astype(f32))) + start
+        o = o.reshape(b, hk // 2, 2, group, t, 2 * dh).astype(f32)
+        o = _rms_norm(o[:, :, 0] - lam * o[:, :, 1],
+                      layer["diff_norm"].astype(f32), cfg.norm_eps) \
+            * (1.0 - start)
+        o = o.astype(x.dtype).transpose(0, 3, 1, 2, 4).reshape(b, t, h * dh)
+    out = o @ layer["w_proj"]
+    return (out + layer["b_proj"] if cfg.attn_bias else out), {"kv": (k, v)}
 
 
 def _whole_vector_norm(cfg: TransformerConfig, x: jnp.ndarray,
@@ -1005,6 +1330,14 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
             f"mesh has {tp_axis} {sizes[tp_axis]}, {sp_axis} "
             f"{sizes[sp_axis]}): a layer's state would cross sequence "
             f"shards; shard its batch over {dp_axis}")
+    if (cfg.diff_attn or set(cfg.mixer_kinds) & {"mamba", "gmu", "cross"}) \
+            and (sizes[tp_axis] > 1 or sizes[sp_axis] > 1):
+        raise NotImplementedError(
+            f"a decoder-hybrid-decoder stack (Mamba-1 layers, gated memory "
+            f"units, differential and cross attention) trains at tp 1 and sp "
+            f"1 (this mesh has {tp_axis} {sizes[tp_axis]}, {sp_axis} "
+            f"{sizes[sp_axis]}): its state and its side values are not cut; "
+            f"shard its batch over {dp_axis}")
     reduce_axes = (dp_axis, sp_axis)
     warm_kernel_imports()       # off the first trace's path (set-up time)
 
@@ -1321,8 +1654,9 @@ def transformer_4d_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2,
             def stage_fn(sp_, x):
                 for i in range(sp_["w_qkv"].shape[0]):     # local layers
                     layer = {k: v[i] for k, v in sp_.items()}
-                    x, _ = _attn_ffn_block(cfg, layer, x, positions,
-                                           tp_axis=tp_axis, sp_axis=sp_axis)
+                    x, _, _ = _attn_ffn_block(cfg, layer, x, positions,
+                                              tp_axis=tp_axis,
+                                              sp_axis=sp_axis)
                 return x
 
             acts = pipeline_forward(stage_fn, stage, e, axis=pp_axis)

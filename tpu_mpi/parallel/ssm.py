@@ -43,6 +43,23 @@ states above) and computes ``L`` again inside the kernel. ``plain``:
 :func:`_chunked` below, as it stands, everywhere else (the CPU, odd
 widths); it is the fallback and the yardstick that
 `tests/test_ssm_kernel.py` holds the kernels to.
+
+:func:`selective_scan` is the older (Mamba-1) recurrence beside it: one state
+value a CHANNEL and state index, each with a decay of its own,
+
+    S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c]
+    y_t[c] = sum_n C_t[n] S_t[c, n] + D[c] x_t[c]
+
+``A`` [channels, state] < 0, ``dt`` > 0 one a channel and token. A decay that
+differs a channel AND a state index does not factor into a chunk's matrix
+products as one scalar a head does, so this one is the recurrence as it
+stands, on the VPU and the EUP: a `lax.scan` over chunks of ``chunk`` tokens
+that carries the [batch, state, channels] float32 state (channels along the
+lanes), the chunk's tokens a `lax.scan` inside it; for the backward pass the
+state before each chunk is kept ([chunks, batch, state, channels]) and the
+chunk's token states are computed again (`jax.checkpoint` around a chunk).
+Counted as ``perfvars.snapshot()["sel_scan_lowerings"]``: ``chunked`` or
+``padded``, by the same rule as :func:`scan`.
 """
 
 from __future__ import annotations
@@ -51,6 +68,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import perfvars
@@ -157,3 +175,61 @@ def _chunked(x, dt, a, b, c, length: int):
                           preferred_element_type=f32)
     y = y + y_before * jnp.exp(seg).transpose(0, 1, 3, 2)[..., None]
     return y.reshape(bsz, t, h, p)
+
+
+SEL_UNROLL = 16     # tokens of a chunk's inner loop laid out in one body: with
+#                     chunks of 64 the fastest of those tried on the v5e
+#                     (PERF.md section 6, PR 41)
+
+
+def selective_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
+                   b: jnp.ndarray, c: jnp.ndarray, d: jnp.ndarray,
+                   chunk: int = 64) -> jnp.ndarray:
+    """y [batch, t, channels] of the per-channel recurrence above from x and
+    dt [batch, t, channels] (dt > 0, float32), a [channels, state] (< 0), b
+    and c [batch, t, state] and the skip's d [channels]; dt, a and d
+    float32. Decays and state are float32, y is rounded to x's type once.
+    Each call built into a traced program counts in
+    ``perfvars.snapshot()["sel_scan_lowerings"]`` as ``chunked``, or as
+    ``padded`` where t is no multiple of the chunk: it is filled up with
+    tokens of ``dt`` = 0, which decay nothing and add nothing."""
+    t = x.shape[1]
+    length = min(chunk, t)
+    pad = -t % length
+    perfvars.note_sel_scan_lowering("padded" if pad else "chunked")
+
+    def filled(v):
+        return jnp.pad(v, ((0, 0), (0, pad), (0, 0))) if pad else v
+    y = _selective_chunks(filled(x), filled(dt), a, filled(b), filled(c),
+                          length)[:, :t]
+    return (y + x.astype(jnp.float32) * d).astype(x.dtype)
+
+
+def _selective_chunks(x, dt, a, b, c, length: int):
+    """The recurrence without its skip term, float32 [batch, t, channels], t
+    a multiple of ``length``."""
+    bsz, t, ch = x.shape
+    f32 = jnp.float32
+    a_t = a.astype(f32).T                               # [state, channels]
+
+    def by_chunk(v):    # [batch, t, w] -> [chunks, length, batch, w]
+        return jnp.moveaxis(v.reshape(bsz, t // length, length, -1), 0, 2)
+
+    def token(s, at):
+        x_t, dt_t, b_t, c_t = at        # [batch, channels] x 2, [batch, state] x 2
+        dt_t = dt_t.astype(f32)[:, None, :]
+        s = jnp.exp(dt_t * a_t) * s + (dt_t * x_t.astype(f32)[:, None, :]) \
+            * b_t.astype(f32)[:, :, None]
+        return s, jnp.sum(s * c_t.astype(f32)[:, :, None], axis=1)
+
+    @jax.checkpoint     # keeps the state before the chunk, and its inputs
+    def one_chunk(s, inputs):
+        return lax.scan(token, s, inputs, unroll=min(SEL_UNROLL, length))
+    start = jnp.zeros((bsz, a_t.shape[0], ch), f32)
+    varies = tuple(sorted(set().union(*(jax.typeof(v).vma
+                                        for v in (x, dt, a, b, c)))))
+    if varies:      # under `shard_map` the carry varies as the operands do
+        start = lax.pcast(start, varies, to="varying")
+    _, y = lax.scan(one_chunk, start,
+                    tuple(by_chunk(v) for v in (x, dt, b, c)))
+    return jnp.moveaxis(y, 2, 0).reshape(bsz, t, ch)

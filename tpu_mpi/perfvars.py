@@ -23,7 +23,9 @@ pieces:
   as ``row_sum_lowerings``, the rotary embeddings by their form as
   ``rope_forms``, the layers' mixers by kind as ``mixer_kinds`` and the
   state-space scans by their form as ``scan_lowerings`` and by who computes
-  them (the Pallas kernels or plain `jnp`) as ``scan_kernel_lowerings``, and
+  them (the Pallas kernels or plain `jnp`) as ``scan_kernel_lowerings``, the
+  per-channel selective scans likewise as ``sel_scan_lowerings``, the layers
+  that read a value beside the residual stream as ``side_values``, and
   what JAX
   traced, lowered, compiled and read
   from its persistent cache, by function, with the Pallas kernels built
@@ -575,6 +577,14 @@ def note_attn_lowering(kind: str, window: int = 0,
         by[kind] += 1
 
 
+def note_attn_kind(kind: str, how: str) -> None:
+    """One attention call of ``kind`` (beside what `note_attn_lowering`
+    files it under: "diff", differential attention's) was built ``fused`` or
+    ``plain``; `attn_lowerings` has counted the call already."""
+    with _store_lock:
+        _attn_by_kind.setdefault(kind, {"fused": 0, "plain": 0})[how] += 1
+
+
 def _attn_kinds() -> dict:
     """{"full" | "window" | "latent": "fused" | "plain" | "mixed"} of the attention
     kinds traced so far (caller holds the store lock)."""
@@ -632,15 +642,27 @@ def note_rope_form(form: str) -> None:
 # state-space scan took, `chunked`, or `padded` where the sequence is no
 # multiple of the chunk and is filled up to one.
 
-_mixer_kinds = {"attention": 0, "ssm": 0}
+_mixer_kinds = {"attention": 0, "ssm": 0}     # a later kind (`mamba`, `gmu`,
+#                                               `cross`) appears once traced
+# `models.transformer._side_read`: the layers of each traced program that
+# read a value another layer wrote beside the residual stream
+_side_values = {"memory": 0, "kv": 0}
 _scan_lowerings = {"chunked": 0, "padded": 0}
 
 
 def note_mixer_kind(kind: str) -> None:
-    """One layer was traced with ``attention`` or a state-space scan
-    (``ssm``) as its mixer."""
+    """One layer was traced with ``attention``, a state-space scan (``ssm``:
+    Mamba-2; ``mamba``: Mamba-1), a gated memory unit (``gmu``) or ``cross``
+    attention as its mixer."""
     with _store_lock:
-        _mixer_kinds[kind] += 1
+        _mixer_kinds[kind] = _mixer_kinds.get(kind, 0) + 1
+
+
+def note_side_value(name: str) -> None:
+    """One layer was traced reading the ``memory`` (a gated memory unit) or
+    the shared ``kv`` (a cross-attention layer)."""
+    with _store_lock:
+        _side_values[name] += 1
 
 
 def note_scan_lowering(form: str) -> None:
@@ -648,6 +670,19 @@ def note_scan_lowering(form: str) -> None:
     after the sequence was ``padded`` to a multiple of the chunk."""
     with _store_lock:
         _scan_lowerings[form] += 1
+
+
+# `parallel.ssm.selective_scan` (the per-channel, per-state recurrence of a
+# Mamba-1 layer) likewise, by the same two forms.
+
+_sel_scan_lowerings = {"chunked": 0, "padded": 0}
+
+
+def note_sel_scan_lowering(form: str) -> None:
+    """One selective scan was traced in the ``chunked`` form, or in it after
+    the sequence was ``padded`` to a multiple of the chunk."""
+    with _store_lock:
+        _sel_scan_lowerings[form] += 1
 
 
 # and who computes that form: the Pallas kernel pair of `xla/ssm_kernels.py`
@@ -1352,8 +1387,10 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "row_sum_lowerings": dict(_row_sum_lowerings),
             "rope_forms": dict(_rope_forms),
             "mixer_kinds": dict(_mixer_kinds),
+            "side_values": dict(_side_values),
             "scan_lowerings": dict(_scan_lowerings),
             "scan_kernel_lowerings": dict(_scan_kernel_lowerings),
+            "sel_scan_lowerings": dict(_sel_scan_lowerings),
             "build": build_snapshot(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
@@ -1400,9 +1437,12 @@ def reset() -> None:
         _gmm_lowerings.update(kernel=0, ragged_dot=0)
         _row_sum_lowerings.update(product=0, scatter=0)
         _rope_forms.update(dense=0, halves=0)
+        _mixer_kinds.clear()
         _mixer_kinds.update(attention=0, ssm=0)
+        _side_values.update(memory=0, kv=0)
         _scan_lowerings.update(chunked=0, padded=0)
         _scan_kernel_lowerings.update(kernel=0, plain=0)
+        _sel_scan_lowerings.update(chunked=0, padded=0)
         _build_total[:] = [0, 0.0, 0, 0.0, 0, 0.0]
         _build_cache.update(hits=0, misses=0, load_s=0.0, saved_s=0.0)
         _build_by_fun.clear()

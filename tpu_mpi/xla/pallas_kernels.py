@@ -1272,7 +1272,8 @@ def causal_attention(q, k, v, *, window: int = 0, rope: Optional[tuple] = None,
     divisor of heads, t, d2) keys: the scores are (q k^T + q2 k2^T) /
     sqrt(dh + d2), a head's rotated part beside its unrotated one; with one
     k2 head every query head reads it, again by the index map, and its dk2
-    is the sum over them. v may then be of a width of its own.
+    is the sum over them. v may be of a width of its own, with or without a
+    second term (differential attention: a pair's values side by side).
 
     Blocks default to :func:`causal_attention_blocks`; a shape outside the
     kernel's contract raises. :func:`ring_attention` above is the other
@@ -1295,7 +1296,6 @@ def causal_attention(q, k, v, *, window: int = 0, rope: Optional[tuple] = None,
         raise ValueError(f"causal_attention: blocks ({block_q}, {block_k}) "
                          f"do not divide t = {t}")
     if not (k.shape[:3] == v.shape[:3] and q.dtype == k.dtype == v.dtype
-            and (bool(rope) or k.shape == v.shape)
             and q.shape[:1] + q.shape[2:] == k.shape[:1] + k.shape[2:]
             and q.shape[1] % k.shape[1] == 0):
         raise ValueError("causal_attention: q, k, v differ in dtype or in "
