@@ -823,3 +823,31 @@ def test_compiled_for_the_v5e_the_attention_kernel_with_values_wider_than_scores
         shapes = [o.shape for o in jax.tree.leaves(compiled.out_info)]
         assert shapes == [(1, 40, 8192, 64), (1, 20, 8192, 64),
                           (1, 20, 8192, 128)]
+
+
+def test_compiled_for_the_v5e_the_selective_scan_is_one_kernel_each_way(
+        v5e_2x2):
+    """The selective (Mamba-1) scan's kernel pair at the shape of the
+    benchmark's phi-4-mini-flash-reasoning stage (one sequence of 8192
+    tokens, 5120 channels over a state of 16, bfloat16 operands, float32
+    dt, A and D) lowers through Mosaic forward and backward (dynamic lane
+    rotations, sublane folds, the 128 x 128 turns, 17 MB of VMEM scratch):
+    two kernels and no loop of XLA's, and the six gradients come back with
+    their operands' shapes and types."""
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import sel_scan_kernels as sk
+    one = SingleDeviceSharding(v5e_2x2[0])
+    b, t, ch, n = 1, 8192, 5120, sk.SEL_STATE
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    operands = tuple(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one) for shape, dtype in (
+            ((b, t, ch), bf16), ((b, t, ch), f32), ((ch, n), f32),
+            ((b, t, n), bf16), ((b, t, n), bf16), ((ch,), f32)))
+    compiled = jax.jit(jax.grad(
+        lambda *a: sk.sel_scan(*a, interpret=False).astype(f32).sum(),
+        tuple(range(6)))).lower(*operands).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert " while(" not in hlo
+    assert [(o.shape, o.dtype) for o in jax.tree.leaves(compiled.out_info)] \
+        == [(o.shape, o.dtype) for o in operands]

@@ -52,14 +52,14 @@ value a CHANNEL and state index, each with a decay of its own,
 
 ``A`` [channels, state] < 0, ``dt`` > 0 one a channel and token. A decay that
 differs a channel AND a state index does not factor into a chunk's matrix
-products as one scalar a head does, so this one is the recurrence as it
-stands, on the VPU and the EUP: a `lax.scan` over chunks of ``chunk`` tokens
-that carries the [batch, state, channels] float32 state (channels along the
-lanes), the chunk's tokens a `lax.scan` inside it; for the backward pass the
-state before each chunk is kept ([chunks, batch, state, channels]) and the
-chunk's token states are computed again (`jax.checkpoint` around a chunk).
-Counted as ``perfvars.snapshot()["sel_scan_lowerings"]``: ``chunked`` or
-``padded``, by the same rule as :func:`scan`.
+products, so it is the recurrence as it stands, on the VPU and the EUP, the
+[state, channels] float32 state carried token by token. ``kernel`` (a kernel
+backend; float32 or bfloat16, channels in 512s, a state of 16): the Pallas
+pair of ``xla/sel_scan_kernels.py``, which keeps the state BEFORE each block
+of 256 or 128 tokens and holds a block's token states in VMEM alone. ``plain``
+(everywhere else; the tests' yardstick): :func:`_selective_chunks`, `lax.scan`
+over chunks and tokens, `jax.checkpoint` a chunk. Counted as ``chunked`` or
+``padded`` (``sel_scan_lowerings``) and by who (``sel_scan_kernel_lowerings``).
 """
 
 from __future__ import annotations
@@ -192,17 +192,46 @@ def selective_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     Each call built into a traced program counts in
     ``perfvars.snapshot()["sel_scan_lowerings"]`` as ``chunked``, or as
     ``padded`` where t is no multiple of the chunk: it is filled up with
-    tokens of ``dt`` = 0, which decay nothing and add nothing."""
+    tokens of ``dt`` = 0, which decay nothing and add nothing; and in
+    ``["sel_scan_kernel_lowerings"]`` as ``kernel`` or ``plain``. The result
+    does not depend on the chunk. ``kernel``: `sel_scan_kernels.sel_scan`,
+    which fills the sequence up to its own blocks of tokens the same way
+    and whose backward pass keeps the operands and the state before each
+    block, [batch, blocks, state, channels] float32; ``plain``: a
+    `lax.scan` over chunks that keeps the state before each chunk
+    ([chunks, batch, state, channels]) and computes a chunk's token states
+    again (`jax.checkpoint` around a chunk)."""
     t = x.shape[1]
     length = min(chunk, t)
     pad = -t % length
     perfvars.note_sel_scan_lowering("padded" if pad else "chunked")
+
+    if sel_scan_kernel_selected(x.shape, x.dtype, a.shape[-1]):
+        from ..xla import sel_scan_kernels
+        perfvars.note_sel_scan_kernel_lowering("kernel")
+        return sel_scan_kernels.sel_scan(
+            x, dt, a, b, c, d,
+            interpret=ring._kernel_backend() == "interpret")
+    perfvars.note_sel_scan_kernel_lowering("plain")
 
     def filled(v):
         return jnp.pad(v, ((0, 0), (0, pad), (0, 0))) if pad else v
     y = _selective_chunks(filled(x), filled(dt), a, filled(b), filled(c),
                           length)[:, :t]
     return (y + x.astype(jnp.float32) * d).astype(x.dtype)
+
+
+def sel_scan_kernel_selected(shape: tuple, dtype, state: int) -> bool:
+    """Whether :func:`selective_scan` runs the Pallas kernel pair for x of
+    ``shape`` [batch, t, channels] over a state of ``state``: decided from
+    the backend (`ring._kernel_backend`) and the kernel's contract
+    (`sel_scan_kernels.sel_scan_selected`), never by trying it: once
+    selected, a kernel that does not lower is an error."""
+    if ring._kernel_backend() is None:
+        return False
+    from ..xla import sel_scan_kernels
+    return sel_scan_kernels.sel_scan_selected(shape[2], state,
+                                              jnp.dtype(dtype))
 
 
 def _selective_chunks(x, dt, a, b, c, length: int):

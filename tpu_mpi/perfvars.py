@@ -24,7 +24,8 @@ pieces:
   ``rope_forms``, the layers' mixers by kind as ``mixer_kinds`` and the
   state-space scans by their form as ``scan_lowerings`` and by who computes
   them (the Pallas kernels or plain `jnp`) as ``scan_kernel_lowerings``, the
-  per-channel selective scans likewise as ``sel_scan_lowerings``, the layers
+  per-channel selective scans likewise as ``sel_scan_lowerings`` and
+  ``sel_scan_kernel_lowerings``, the layers
   that read a value beside the residual stream as ``side_values``, and
   what JAX
   traced, lowered, compiled and read
@@ -696,6 +697,20 @@ def note_scan_kernel_lowering(kind: str) -> None:
     `jnp`."""
     with _store_lock:
         _scan_kernel_lowerings[kind] += 1
+
+
+# and who computes a selective scan: the Pallas kernel pair of
+# `xla/sel_scan_kernels.py` or the plain `lax.scan`s of
+# `parallel.ssm._selective_chunks`, one count a traced scan.
+
+_sel_scan_kernel_lowerings = {"kernel": 0, "plain": 0}
+
+
+def note_sel_scan_kernel_lowering(kind: str) -> None:
+    """One selective scan was traced as the ``kernel`` or as ``plain``
+    `jnp`."""
+    with _store_lock:
+        _sel_scan_kernel_lowerings[kind] += 1
 
 
 # -- build: what JAX traced, lowered, compiled and read from its cache --------
@@ -1391,6 +1406,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "scan_lowerings": dict(_scan_lowerings),
             "scan_kernel_lowerings": dict(_scan_kernel_lowerings),
             "sel_scan_lowerings": dict(_sel_scan_lowerings),
+            "sel_scan_kernel_lowerings": dict(_sel_scan_kernel_lowerings),
             "build": build_snapshot(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
@@ -1443,6 +1459,7 @@ def reset() -> None:
         _scan_lowerings.update(chunked=0, padded=0)
         _scan_kernel_lowerings.update(kernel=0, plain=0)
         _sel_scan_lowerings.update(chunked=0, padded=0)
+        _sel_scan_kernel_lowerings.update(kernel=0, plain=0)
         _build_total[:] = [0, 0.0, 0, 0.0, 0, 0.0]
         _build_cache.update(hits=0, misses=0, load_s=0.0, saved_s=0.0)
         _build_by_fun.clear()
