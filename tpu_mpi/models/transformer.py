@@ -669,12 +669,27 @@ def transformer_forward(cfg: TransformerConfig, params: dict,
 
 def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
              tp_axis: Optional[str] = None, sp_axis: Optional[str] = None):
-    """(logits, routed): `routed` holds, for each layer with experts, its
-    router's (summed probabilities [n_experts] float32, token-slots per
-    expert [n_experts] int32) over this block's tokens, and where the rank
-    holds a share of the experts a third entry, what the held layer did
-    (`parallel.ep.moe_dropless_held`'s [rows computed, rows gathered,
-    further buffers ran]); empty otherwise."""
+    """(logits, routed): the whole float32 logits [b, t, vocab] of
+    `_trunk`'s stream, and its `routed`."""
+    x, routed = _trunk(cfg, params, tokens, tp_axis=tp_axis, sp_axis=sp_axis)
+    with jax.named_scope("head_loss"):
+        x = _norm(cfg, x, params, "ln_f")
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head).astype(jnp.float32)                   # (b, t, V)
+        if cfg.logits_divisor != 1.0:
+            logits = logits / cfg.logits_divisor
+        return logits, routed
+
+
+def _trunk(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
+           tp_axis: Optional[str] = None, sp_axis: Optional[str] = None):
+    """(x, routed): the stream [b, t, d] after the last layer, before the
+    final norm and the head, and `routed`, which holds, for each layer with
+    experts, its router's (summed probabilities [n_experts] float32,
+    token-slots per expert [n_experts] int32) over this block's tokens, and
+    where the rank holds a share of the experts a third entry, what the
+    held layer did (`parallel.ep.moe_dropless_held`'s [rows computed, rows
+    gathered, further buffers ran]); empty otherwise."""
     b, t = tokens.shape
     d, h = cfg.d_model, cfg.n_heads
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
@@ -714,13 +729,7 @@ def _forward(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
             side["kv"] = wrote["kv"]
         if sent is not None:
             routed.append(sent)
-    with jax.named_scope("head_loss"):
-        x = _norm(cfg, x, params, "ln_f")
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = (x @ head).astype(jnp.float32)                   # (b, t, V)
-        if cfg.logits_divisor != 1.0:
-            logits = logits / cfg.logits_divisor
-        return logits, routed
+    return x, routed
 
 
 @functools.lru_cache(maxsize=None)
@@ -985,7 +994,7 @@ def transformer_expert_counts(cfg: TransformerConfig, params: dict,
     sends to each expert for this batch. Every row sums to tokens x
     `experts_per_tok`: nothing is dropped. A forward pass of its own, for a
     caller to run outside whatever it times."""
-    _logits, routed = _forward(cfg, params, tokens)
+    _x, routed = _trunk(cfg, params, tokens)
     return jnp.stack([r[1] for r in routed])
 
 
@@ -998,7 +1007,7 @@ def transformer_held_counts(cfg: TransformerConfig, params: dict,
     them, whether the further buffers ran). Nothing was dropped where rows
     computed equals the slots of the held experts. A forward pass of its
     own, as `transformer_expert_counts`."""
-    _logits, routed = _forward(cfg, params, tokens)
+    _x, routed = _trunk(cfg, params, tokens)
     return (jnp.stack([r[1] for r in routed]),
             jnp.stack([r[2] for r in routed]))
 
@@ -1303,6 +1312,104 @@ def _xent(logits, labels):
     return -jnp.mean(ll)
 
 
+# `head_loss` cuts its tokens into blocks whose float32 logits stay about
+# under this many bytes (PERF.md section 6, PR 43: what 1 to 8 blocks cost)
+_HEAD_BLOCK_BYTES = 1 << 29
+
+
+def _head_block(tokens: int, vocab: int) -> int:
+    """The tokens of one block of `head_loss`: the fewest equal blocks whose
+    float32 logits [block, vocab] each fit `_HEAD_BLOCK_BYTES`, a block
+    rounded up to whole tiles of 128 tokens (so the last one may be
+    shorter, and under 128 tokens there is one block)."""
+    blocks = -(-tokens * vocab * 4 // _HEAD_BLOCK_BYTES)
+    return min(tokens, -(-tokens // (128 * blocks)) * 128)
+
+
+def head_loss(cfg: TransformerConfig, params: dict, x: jnp.ndarray,
+              labels: jnp.ndarray) -> jnp.ndarray:
+    """The mean cross-entropy of the model's head over `_trunk`'s stream
+    ``x`` [b, t, d] against ``labels`` [b, t]: `_xent` of `_forward`'s
+    logits, computed a block of tokens at a time (`_head_block`) with the
+    gradients of the stream and of the head made in the same pass
+    (`_blocked_xent`), so that no [tokens, vocab] array is held and no
+    logits are computed a second time. A tied head is read, and its
+    gradient written, in the embedding's own [vocab, d] layout."""
+    with jax.named_scope("head_loss"):
+        x = _norm(cfg, x, params, "ln_f").reshape(-1, cfg.d_model)
+        w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+        block = _head_block(x.shape[0], cfg.vocab)
+        perfvars.note_head_loss_lowering("blocked", -(-x.shape[0] // block))
+        # inside `shard_map` the head is the same on every data shard and
+        # the stream is not: said here, so that the sum of the shards'
+        # gradients is this cast's transpose, as it was the product's
+        shards = tuple(jax.typeof(x).vma - jax.typeof(w).vma)
+        if shards:
+            w = lax.pcast(w, shards, to="varying")
+        return _blocked_xent(x, w, labels.reshape(-1), cfg.tie_embeddings,
+                             cfg.logits_divisor, block)
+
+
+def _xent_blocks(x, w, labels, tied: bool, divisor: float, block: int,
+                 grads: bool):
+    """(loss, dx, dw) of `_blocked_xent`, ``dx`` and ``dw`` None unless
+    ``grads``. Unrolled: a block's logits, its softmax and the logits'
+    gradient live between its three products and nowhere else."""
+    n = x.shape[0]
+    vd = 0 if tied else 1       # where the vocabulary stands in w
+    total, dxs, dw = jnp.zeros((), jnp.float32), [], None
+    for lo in range(0, n, block):
+        xb, lb = x[lo:lo + block], labels[lo:lo + block, None]
+        z = lax.dot_general(
+            xb, w, (((1,), (1 - vd,)), ((), ()))).astype(jnp.float32)
+        if divisor != 1.0:
+            z = z / divisor
+        top = jnp.max(z, axis=-1, keepdims=True)
+        e = jnp.exp(z - top)
+        norm = jnp.sum(e, axis=-1, keepdims=True)
+        total += jnp.sum(top + jnp.log(norm)
+                         - jnp.take_along_axis(z, lb, axis=-1))
+        if not grads:
+            continue
+        hot = lb == lax.broadcasted_iota(labels.dtype, z.shape, 1)
+        dl = ((e / norm - hot) * (1.0 / (n * divisor))).astype(x.dtype)
+        dxs.append(lax.dot_general(dl, w, (((1,), (vd,)), ((), ()))))
+        pair = (dl, xb) if tied else (xb, dl)
+        part = lax.dot_general(*pair, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+        dw = part if dw is None else dw + part
+    if not grads:
+        return total / n, None, None
+    return total / n, jnp.concatenate(dxs), dw.astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _blocked_xent(x, w, labels, tied: bool, divisor: float, block: int):
+    """The mean over the tokens of ``x`` [n, d] of the cross-entropy of
+    softmax((x @ head) / divisor) against ``labels`` [n], float32, ``block``
+    tokens at a time; ``w`` is the head, [vocab, d] if ``tied`` and [d,
+    vocab] if not. Differentiated, the forward pass makes both gradients
+    while a block's logits are there (the loss is the last thing a step
+    computes, so its cotangent is a scalar): the products take the
+    operands' dtype and sum in float32, the head's gradient is summed over
+    the blocks in float32 and rounded once, and the backward pass scales
+    what was kept."""
+    return _xent_blocks(x, w, labels, tied, divisor, block, False)[0]
+
+
+def _blocked_xent_fwd(x, w, labels, tied, divisor, block):
+    loss, dx, dw = _xent_blocks(x, w, labels, tied, divisor, block, True)
+    return loss, (dx, dw)
+
+
+def _blocked_xent_bwd(tied, divisor, block, kept, g):
+    return tuple((g * d.astype(jnp.float32)).astype(d.dtype)
+                 for d in kept) + (None,)
+
+
+_blocked_xent.defvjp(_blocked_xent_fwd, _blocked_xent_bwd)
+
+
 def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
                            dp_axis: str = "dp", tp_axis: str = "tp",
                            sp_axis: str = "sp", donate: bool = False):
@@ -1343,10 +1450,9 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
 
     def local_step(params, tokens, labels):
         def loss_fn(p):
-            logits, routed = _forward(cfg, p, tokens, tp_axis=tp_axis,
-                                      sp_axis=sp_axis)
-            with jax.named_scope("head_loss"):
-                loss = _xent(logits, labels)
+            x, routed = _trunk(cfg, p, tokens, tp_axis=tp_axis,
+                               sp_axis=sp_axis)
+            loss = head_loss(cfg, p, x, labels)
             if routed and cfg.router_aux_coef:
                 with jax.named_scope("aux_loss"):
                     loss = loss + cfg.router_aux_coef * load_balancing_loss(
@@ -1536,6 +1642,7 @@ def transformer_pp_moe_train_step(cfg: TransformerConfig, mesh,
             acts = acts.reshape(b, t, cfg.d_model)
             logits = (_rms_norm(acts, p["ln_f"])
                       @ p["embed"].T).astype(jnp.float32)
+            perfvars.note_head_loss_lowering("whole")
             l = _xent(logits, labels)
             # only the last stage's emissions are the real model output
             last = lax.axis_index(pp_axis) == n_pp - 1
@@ -1663,6 +1770,7 @@ def transformer_4d_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2,
             acts = acts.reshape(b, t, cfg.d_model)
             logits = (_rms_norm(acts, p["ln_f"])
                       @ p["embed"].T).astype(jnp.float32)
+            perfvars.note_head_loss_lowering("whole")
             l = _xent(logits, labels)
             # only the last stage's emissions are the real model output
             last = lax.axis_index(pp_axis) == n_pp - 1

@@ -26,7 +26,9 @@ pieces:
   them (the Pallas kernels or plain `jnp`) as ``scan_kernel_lowerings``, the
   per-channel selective scans likewise as ``sel_scan_lowerings`` and
   ``sel_scan_kernel_lowerings``, the layers
-  that read a value beside the residual stream as ``side_values``, and
+  that read a value beside the residual stream as ``side_values``, the
+  vocabulary heads and their losses as ``head_loss_lowerings`` (over blocks
+  of tokens, or over the whole logits) with ``head_loss_blocks``, and
   what JAX
   traced, lowered, compiled and read
   from its persistent cache, by function, with the Pallas kernels built
@@ -711,6 +713,25 @@ def note_sel_scan_kernel_lowering(kind: str) -> None:
     `jnp`."""
     with _store_lock:
         _sel_scan_kernel_lowerings[kind] += 1
+
+
+# `models.transformer.head_loss` (the vocabulary head and its cross-entropy
+# over blocks of tokens, both gradients made in the forward pass) against
+# the ``whole`` float32 logits differentiated by JAX (`_xent`: the two
+# pipelined steps), one count a traced loss, and the traced ``blocked``
+# losses by their number of blocks.
+
+_head_loss_lowerings = {"blocked": 0, "whole": 0}
+_head_loss_blocks: Dict[int, int] = {}
+
+
+def note_head_loss_lowering(kind: str, blocks: int = 1) -> None:
+    """One head and loss was traced ``blocked``, over ``blocks`` blocks of
+    tokens, or over the ``whole`` logits."""
+    with _store_lock:
+        _head_loss_lowerings[kind] += 1
+        if kind == "blocked":
+            _head_loss_blocks[blocks] = _head_loss_blocks.get(blocks, 0) + 1
 
 
 # -- build: what JAX traced, lowered, compiled and read from its cache --------
@@ -1407,6 +1428,9 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "scan_kernel_lowerings": dict(_scan_kernel_lowerings),
             "sel_scan_lowerings": dict(_sel_scan_lowerings),
             "sel_scan_kernel_lowerings": dict(_sel_scan_kernel_lowerings),
+            "head_loss_lowerings": dict(_head_loss_lowerings),
+            "head_loss_blocks": {str(n): c for n, c
+                                 in sorted(_head_loss_blocks.items())},
             "build": build_snapshot(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
@@ -1460,6 +1484,8 @@ def reset() -> None:
         _scan_kernel_lowerings.update(kernel=0, plain=0)
         _sel_scan_lowerings.update(chunked=0, padded=0)
         _sel_scan_kernel_lowerings.update(kernel=0, plain=0)
+        _head_loss_lowerings.update(blocked=0, whole=0)
+        _head_loss_blocks.clear()
         _build_total[:] = [0, 0.0, 0, 0.0, 0, 0.0]
         _build_cache.update(hits=0, misses=0, load_s=0.0, saved_s=0.0)
         _build_by_fun.clear()
