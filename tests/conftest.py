@@ -126,3 +126,30 @@ def no_folds_cached():
     clear()
     yield
     clear()
+
+
+@pytest.fixture
+def kernel_backend():
+    """`tpu_mpi.xla.choice.backend`'s word, set for the time of a trace:
+    "interpret" selects the Pallas kernels on this CPU (the interpret
+    machine), "mosaic" selects them as a TPU would (for a test that lowers
+    for the described chip, or asks a predicate), None selects none, as the
+    CPU does. ``kernel_backend(word)`` sets it until the test ends; ``with
+    kernel_backend(word):`` until the block does. The one place the tests
+    steer the choice of a kernel: what a choice reads at trace time is in
+    `choice.trace_key`, and the caches keyed on it follow the word."""
+    from tpu_mpi.xla import choice
+    at_start = choice.backend
+
+    class set_word:
+        def __init__(self, word):
+            self.kept, choice.backend = choice.backend, lambda: word
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            choice.backend = self.kept
+
+    yield set_word
+    choice.backend = at_start

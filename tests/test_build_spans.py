@@ -20,7 +20,7 @@ from jax import monitoring
 from tpu_mpi import config, perfvars, tracectx, xla
 from tpu_mpi.models.transformer import (TransformerConfig, transformer_init,
                                         transformer_train_step)
-from tpu_mpi.parallel import ring
+from tpu_mpi.xla import choice
 from tpu_mpi.xla import pallas_kernels as pk
 
 PHASES = ("trace", "lower", "compile")
@@ -250,10 +250,11 @@ def test_names_past_the_cap_are_summed_under_one_key(monkeypatch):
     assert _build()["compile"] == {"n": 8, "s": 8.0}
 
 
-def test_the_pallas_import_is_a_span_and_no_arming(monkeypatch):
+def test_the_pallas_import_is_a_span_and_no_arming(monkeypatch,
+                                                   kernel_backend):
     _sample(monkeypatch)
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
-    ring.warm_kernel_imports()
+    kernel_backend("interpret")
+    choice.warm_kernel_imports()
     for t in threading.enumerate():
         if t.name == "tpu_mpi-pallas-import":
             t.join()
@@ -262,7 +263,8 @@ def test_the_pallas_import_is_a_span_and_no_arming(monkeypatch):
     assert perfvars.snapshot()["arming_s"] == 0.0
 
 
-def test_a_steps_kernels_are_counted_where_they_are_built(monkeypatch):
+def test_a_steps_kernels_are_counted_where_they_are_built(monkeypatch,
+                                                          kernel_backend):
     """The count a wrapper notes equals the body traces that
     tests/test_grouped_matmul.py takes by patching the kernels' bodies: a
     four-layer step builds each distinct grouped kernel once (3 kinds x 2
@@ -272,7 +274,7 @@ def test_a_steps_kernels_are_counted_where_they_are_built(monkeypatch):
     rule; the program keeps one."""
     from tpu_mpi.models.transformer import (transformer_expert_counts,
                                             transformer_forward)
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     cfg = TransformerConfig(
         vocab=64, d_model=128, n_heads=2, n_layers=4, d_ff=256, max_seq=128,
         dtype=jnp.float32, norm_eps=1e-5, qk_norm=True, n_experts=4,

@@ -286,7 +286,7 @@ def test_compiled_for_the_v5e_only_integers_are_summed_to_move(
 
 @pytest.mark.parametrize("attention", ["plain", "fused"])
 def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(
-        v5e_2x2, monkeypatch, attention):
+        v5e_2x2, kernel_backend, attention):
     """The benchmark's OLMoE step (yardstick/configs/olmoe-1b-7b-1c.json:
     published widths, depth 4, batch 2 x 4096, parameters donated) compiled
     for one described v5e chip. With the plain attention (what this CPU
@@ -307,9 +307,8 @@ def test_compiled_for_the_v5e_the_expert_step_fits_one_chip(
     import json
     import os
     import re
-    from tpu_mpi.parallel import ring
     if attention == "fused":
-        monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+        kernel_backend("mosaic")
     from jax.sharding import NamedSharding, PartitionSpec as P
     from tpu_mpi import xla
     from tpu_mpi.models.transformer import (TransformerConfig,
@@ -423,7 +422,7 @@ def test_compiled_for_the_v5e_the_attention_kernel_at_the_flagships_shape(
 
 
 def test_compiled_for_the_v5e_the_layer_kind_step_fits_one_chip(
-        v5e_2x2, monkeypatch):
+        v5e_2x2, kernel_backend):
     """The benchmark's K-EXAONE share (yardstick/configs/
     k-exaone-236b-a23b-1c.json: published widths, layers 0-4, 8 of 128
     experts held, batch 1 x 8192, parameters donated) compiled for one
@@ -437,8 +436,7 @@ def test_compiled_for_the_v5e_the_layer_kind_step_fits_one_chip(
     import json
     import os
     import re
-    from tpu_mpi.parallel import ring
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    kernel_backend("mosaic")
     from jax.sharding import NamedSharding, PartitionSpec as P
     from tpu_mpi import xla
     from tpu_mpi.models.transformer import (TransformerConfig,
@@ -643,7 +641,7 @@ def test_compiled_for_the_v5e_the_attention_kernel_with_two_term_scores(
 
 
 def test_compiled_for_the_v5e_the_latent_step_fits_one_chip(v5e_2x2,
-                                                            monkeypatch):
+                                                            kernel_backend):
     """The benchmark's latent-attention share (yardstick/configs/
     openpangu-ultra-moe-718b-1c.json: published widths, layer 0 and four
     sparse layers, 64 of 128 heads and 8 of 256 experts held, batch 1 x
@@ -657,8 +655,7 @@ def test_compiled_for_the_v5e_the_latent_step_fits_one_chip(v5e_2x2,
     import json
     import os
     import re
-    from tpu_mpi.parallel import ring
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    kernel_backend("mosaic")
     from jax.sharding import NamedSharding, PartitionSpec as P
     from tpu_mpi import xla
     from tpu_mpi.models.transformer import (TransformerConfig,
@@ -719,7 +716,7 @@ def test_compiled_for_the_v5e_the_latent_step_fits_one_chip(v5e_2x2,
 
 
 def test_compiled_for_the_v5e_the_state_space_step_fits_one_chip(
-        v5e_2x2, monkeypatch):
+        v5e_2x2, kernel_backend):
     """The benchmark's granite-4.0-h-micro stage (yardstick/configs/
     granite-4.0-h-micro-1c.json: published widths, layers 0-9, nine
     state-space layers to one attention layer, the whole 100352-row
@@ -736,8 +733,7 @@ def test_compiled_for_the_v5e_the_state_space_step_fits_one_chip(
     import json
     import os
     import re
-    from tpu_mpi.parallel import ring
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    kernel_backend("mosaic")
     from jax.sharding import NamedSharding, PartitionSpec as P
     from tpu_mpi import xla
     from tpu_mpi.models import transformer as tf

@@ -103,7 +103,7 @@ def lowerings():
     return dict(perfvars.snapshot()["attn_lowerings"])
 
 
-def test_selection_follows_the_backend_and_the_contract(monkeypatch):
+def test_selection_follows_the_backend_and_the_contract(kernel_backend):
     """On the CPU backend nothing is fused. With the interpret machine asked
     for (a test's patch, never a setting) an eligible shape takes the kernel
     and everything else the plain path; each call counts."""
@@ -113,7 +113,7 @@ def test_selection_follows_the_backend_and_the_contract(monkeypatch):
     ring.local_attention(q, k, v)
     assert lowerings() == {"fused": 0, "plain": 1}
 
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     assert ring.fused_attention_selected(q.shape, q.dtype)
     got = ring.local_attention(q, k, v)
     assert lowerings() == {"fused": 1, "plain": 1}
@@ -131,10 +131,10 @@ def test_selection_follows_the_backend_and_the_contract(monkeypatch):
     assert lowerings() == {"fused": 0, "plain": 0}
 
 
-def test_a_selected_kernel_that_cannot_lower_raises(monkeypatch):
+def test_a_selected_kernel_that_cannot_lower_raises(kernel_backend):
     """Selected as on a TPU while the backend is the CPU: Mosaic cannot
     lower there, and that is an error, not a quiet plain path."""
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    kernel_backend("mosaic")
     q, k, v, _ = operands(256, 64, jnp.float32)
     before = lowerings()
     with pytest.raises(ValueError, match="Only interpret mode is supported"):
@@ -142,8 +142,8 @@ def test_a_selected_kernel_that_cannot_lower_raises(monkeypatch):
     assert lowerings()["fused"] == before["fused"] + 1
 
 
-def test_a_ring_of_one_is_the_local_attention(monkeypatch):
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+def test_a_ring_of_one_is_the_local_attention(kernel_backend):
+    kernel_backend("interpret")
     mesh = xla.make_mesh({"sp": 1}, devices=jax.devices()[:1])
     q, k, v, _ = operands(128, 64, jnp.float32, seed=5)
     perfvars.reset()
@@ -166,7 +166,7 @@ def one_step(cfg, seed=11):
 
 
 @pytest.mark.parametrize("model", ["flagship", "experts"])
-def test_one_train_step_through_the_kernel_is_the_plain_step(monkeypatch,
+def test_one_train_step_through_the_kernel_is_the_plain_step(kernel_backend,
                                                              model):
     """`transformer_train_step` at a toy shape inside the kernel's contract
     (seq 128, head 64), the selection patched to the interpret machine: the
@@ -182,7 +182,7 @@ def test_one_train_step_through_the_kernel_is_the_plain_step(monkeypatch,
     perfvars.reset()
     want_params, want_loss = one_step(cfg)
     assert lowerings() == {"fused": 0, "plain": 1}
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     got_params, got_loss = one_step(cfg)
     assert lowerings() == {"fused": 1, "plain": 1}
     np.testing.assert_allclose(got_loss, want_loss, rtol=1e-6)
@@ -196,7 +196,7 @@ def test_one_train_step_through_the_kernel_is_the_plain_step(monkeypatch,
     assert moved > 1e-3                     # the step did move the leaves
 
 
-def test_remat_attn_wraps_the_plain_path_and_not_the_kernel(monkeypatch):
+def test_remat_attn_wraps_the_plain_path_and_not_the_kernel(kernel_backend):
     """`remat_attn` promises that no [b, h, s, s] scores are kept for the
     backward pass: the plain path is recomputed there, the kernel keeps
     none by construction and is not wrapped."""
@@ -214,7 +214,7 @@ def test_remat_attn_wraps_the_plain_path_and_not_the_kernel(monkeypatch):
     assert text.count("jaxpr=block") == 2 * cfg.n_layers
     assert "[1,2,256,256]" in text
     assert "pallas_call" not in text
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     text = traced()
     assert "remat2[" not in text
     assert "[1,2,256,256]" not in text      # no [b, h, s, s] value

@@ -192,7 +192,7 @@ def lowerings():
     return dict(perfvars.snapshot()["gmm_lowerings"])
 
 
-def test_selection_follows_the_backend_and_the_contract(monkeypatch):
+def test_selection_follows_the_backend_and_the_contract(kernel_backend):
     """On the CPU backend every product is `lax.ragged_dot`. With the
     interpret machine asked for (a test's patch of the one rule both kernels
     share, never a setting) a shape inside the contract takes the kernel and
@@ -204,7 +204,7 @@ def test_selection_follows_the_backend_and_the_contract(monkeypatch):
     np.testing.assert_array_equal(ep.grouped_products(sizes)(lhs, rhs), want)
     assert lowerings() == {"kernel": 0, "ragged_dot": 1}
 
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     q = jnp.zeros((1, 2, 256, 64), jnp.float32)
     assert ring.fused_attention_selected(q.shape, q.dtype)   # one patch, both
     assert ep.grouped_matmul_selected(lhs.shape, rhs.shape, lhs.dtype)
@@ -230,10 +230,10 @@ def test_selection_follows_the_backend_and_the_contract(monkeypatch):
     assert lowerings() == {"kernel": 0, "ragged_dot": 0}
 
 
-def test_a_selected_kernel_that_cannot_lower_raises(monkeypatch):
+def test_a_selected_kernel_that_cannot_lower_raises(kernel_backend):
     """Selected as on a TPU while the backend is the CPU: Mosaic cannot
     lower there, and that is an error, not a quiet `ragged_dot`."""
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    kernel_backend("mosaic")
     lhs, rhs, _, sizes = operands(SPLITS["balanced"], jnp.float32)
     with pytest.raises(ValueError, match="Only interpret mode is supported"):
         jax.block_until_ready(ep.grouped_products(sizes)(lhs, rhs))
@@ -267,7 +267,7 @@ def layer_out_and_grads(dtype):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_an_expert_layer_equals_the_plain_path(monkeypatch, dtype):
+def test_an_expert_layer_equals_the_plain_path(kernel_backend, dtype):
     """With the kernel selected (the interpret machine) one layer's output,
     its token-slots per expert and every gradient equal the `ragged_dot`
     path's within the operand dtype's rounding; all three products count."""
@@ -275,7 +275,7 @@ def test_an_expert_layer_equals_the_plain_path(monkeypatch, dtype):
     perfvars.reset()
     want = layer_out_and_grads(dtype)
     assert lowerings() == {"kernel": 0, "ragged_dot": 3}
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     got = layer_out_and_grads(dtype)
     assert lowerings() == {"kernel": 3, "ragged_dot": 3}
     np.testing.assert_array_equal(got[1], want[1])
@@ -287,7 +287,7 @@ def test_an_expert_layer_equals_the_plain_path(monkeypatch, dtype):
         assert np.abs(g - w).max() <= tol * max(np.abs(w).max(), 1e-6)
 
 
-def test_one_train_step_through_the_kernels_is_the_plain_step(monkeypatch):
+def test_one_train_step_through_the_kernels_is_the_plain_step(kernel_backend):
     """`transformer_train_step` on a 1 x 1 x 1 mesh at a toy shape inside
     both kernels' contracts, the selection patched to the interpret machine:
     the loss and every updated leaf against the plain step's. Under
@@ -319,7 +319,7 @@ def test_one_train_step_through_the_kernels_is_the_plain_step(monkeypatch):
     perfvars.reset()
     want_params, want_loss = one_step()
     assert lowerings() == {"kernel": 0, "ragged_dot": 3}
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     got_params, got_loss = one_step()
     assert lowerings() == {"kernel": 3, "ragged_dot": 3}
     assert perfvars.snapshot()["attn_lowerings"]["fused"] == 1
@@ -335,7 +335,7 @@ def test_one_train_step_through_the_kernels_is_the_plain_step(monkeypatch):
 
 # -- what the kernels cost a step's set-up --------------------------------------
 
-def test_a_program_traces_each_kernel_and_the_walk_once(monkeypatch):
+def test_a_program_traces_each_kernel_and_the_walk_once(monkeypatch, kernel_backend):
     """The set-up guard (PERF.md, Set-up): tracing a kernel's body and
     lowering it is what a kernel costs before the first step, so a step of
     four layers traces each distinct kernel once and the walk over the groups
@@ -348,7 +348,7 @@ def test_a_program_traces_each_kernel_and_the_walk_once(monkeypatch):
     from tpu_mpi.models.transformer import (transformer_expert_counts,
                                             transformer_forward,
                                             transformer_train_step)
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     cfg = TransformerConfig(**{**LAYER.__dict__, "dtype": jnp.float32,
                                "d_ff": 256, "max_seq": 128, "n_layers": 4})
     traced = {"rows x matrix": 0, "weights' gradient": 0, "walk": 0}

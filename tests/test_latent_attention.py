@@ -125,7 +125,7 @@ def test_contract_knows_the_triple():
                             interpret=True)
 
 
-def test_selection_and_the_counters_third_kind(monkeypatch):
+def test_selection_and_the_counters_third_kind(kernel_backend):
     """Off the kernel's backend a latent call is plain; with the interpret
     machine asked for, the triple inside the contract is fused, one outside
     it (a 32-wide rotated part) plain: `attn_kinds["latent"]` says which."""
@@ -135,7 +135,7 @@ def test_selection_and_the_counters_third_kind(monkeypatch):
     ring.local_attention(q, k, v, rope=(q2, k2))
     assert perfvars.snapshot()["attn_kinds"] == {"latent": "plain"}
 
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     perfvars.reset()
     assert ring.fused_attention_selected(q.shape, q.dtype, 64, 128)
     assert not ring.fused_attention_selected(q.shape, q.dtype, 32, 128)

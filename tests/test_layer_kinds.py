@@ -282,7 +282,7 @@ def test_nothing_is_dropped_when_more_slots_arrive_than_the_buffer_holds(
 
 @pytest.mark.parametrize("window", [0, 128, 200], ids=["full", "w128", "w200"])
 @pytest.mark.parametrize("block", [128, 256])
-def test_the_kernel_equals_the_plain_path(monkeypatch, window, block):
+def test_the_kernel_equals_the_plain_path(kernel_backend, window, block):
     """`causal_attention` on the interpret machine, 64 query heads reading 8
     key/value heads as the model has them (at 8 and 2 here), forward and
     gradients, against `ring.plain_attention`. One jitted program
@@ -305,9 +305,9 @@ def test_the_kernel_equals_the_plain_path(monkeypatch, window, block):
     for g, x in zip(got[1], want[1]):
         assert float(jnp.abs(g - x).max()) < 1e-4
     # and `local_attention` selects it where the backend says so
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     assert ring.fused_attention_selected(q.shape, q.dtype)
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: None)
+    kernel_backend(None)
     assert not ring.fused_attention_selected(q.shape, q.dtype)
 
 

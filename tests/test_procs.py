@@ -806,6 +806,53 @@ def test_sendrecv_deadlock_free_under_choke():
     assert "SRDF-OK-0" in res.stdout and "SRDF-OK-1" in res.stdout
 
 
+def test_a_receive_posted_before_the_choke_admits_its_sender():
+    """The order `test_sendrecv_deadlock_free_under_choke` meets under load
+    (its peer's parked Isends arrive after the Sendrecv's receive is
+    posted), by handshake: rank 0 posts its receive while nobody is choked,
+    the queue then goes over the mark and rank 0 chokes rank 1 with that
+    receive pending, and rank 1's blocking Send of the very message rank 0
+    waits for finds itself choked. It tells rank 0 what it holds back, a
+    posted receive matches, and rank 0 unchokes it; before, both waited out
+    the deadlock timeout."""
+    res = _run_procs("""
+        import os, time
+        os.environ["TPU_MPI_SEND_HIGHWATER_BYTES"] = str(2 * 1600)
+        os.environ["TPU_MPI_DEADLOCK_TIMEOUT"] = "30"
+        import numpy as np
+        import tpu_mpi as MPI
+        from tpu_mpi._runtime import require_env
+        MPI.Init()
+        comm = MPI.COMM_WORLD
+        rank = comm.rank()
+        ctx, _ = require_env()
+        if rank == 0:
+            rbuf = np.zeros(4)
+            rreq = MPI.Irecv(rbuf, 1, 3, comm)  # posted: nobody choked yet
+            MPI.Barrier(comm)
+            MPI.Wait(rreq)      # reads rank 1's Isends first: over the mark
+            assert rbuf[0] == 1.0, rbuf
+            buf = np.zeros(200)
+            for i in range(6):
+                MPI.Recv(buf, 1, 77, comm)
+                assert buf[0] == i
+        else:
+            MPI.Barrier(comm)                   # rank 0's receive is posted
+            reqs = [MPI.Isend(np.full(200, float(i)), 0, 77, comm)
+                    for i in range(6)]
+            MPI.Waitall(reqs)
+            deadline = time.monotonic() + 60
+            while ctx.choke_count == 0 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            assert ctx.choke_count > 0, "never choked"
+            MPI.Send(np.full(4, 1.0), 0, 3, comm)
+        print(f"ADMIT-OK-{rank}", flush=True)
+        MPI.Finalize()
+    """, nprocs=2)
+    assert res.returncode == 0, res.stderr + res.stdout
+    assert "ADMIT-OK-0" in res.stdout and "ADMIT-OK-1" in res.stdout
+
+
 def test_pairwise_alltoall_tier():
     """Large Alltoall across processes takes the direct pairwise algorithm
     (one hop per segment) and matches the star tier's semantics exactly."""

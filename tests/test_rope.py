@@ -21,7 +21,6 @@ if ROOT not in sys.path:
 
 from tpu_mpi import perfvars, xla                               # noqa: E402
 from tpu_mpi.models import transformer as tf                    # noqa: E402
-from tpu_mpi.parallel import ring                               # noqa: E402
 from tpu_mpi.xla import pallas_kernels as pk                    # noqa: E402
 
 F32 = jnp.float32
@@ -134,7 +133,8 @@ NORMS = {       # TransformerConfig fields, x's shape, the scale's
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("what", sorted(NORMS))
 def test_the_norm_of_q_and_k_rides_the_rotations_pass(what, dtype,
-                                                      monkeypatch):
+                                                      monkeypatch,
+                                                      kernel_backend):
     """`_norm_and_rope` with the kernel selected (one pass: the norm, the
     scale, the rotation; one pass back, which computes the inverse rms
     again and sums the scale's gradient) against the same function's plain
@@ -156,7 +156,7 @@ def test_the_norm_of_q_and_k_rides_the_rotations_pass(what, dtype,
             lambda x, s: weighed([out(x, s)]), (0, 1))(x, scale)))
     monkeypatch.setattr(tf, "_rope", halves)
     want, (dx_want, ds_want) = both()(x, scale)
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     perfvars.reset()
     got, (dx, ds) = jax.block_until_ready(both()(x, scale))
     built = perfvars.snapshot()
@@ -174,10 +174,10 @@ def test_the_norm_of_q_and_k_rides_the_rotations_pass(what, dtype,
 
 
 def test_without_a_rotation_or_off_the_contract_the_norm_is_the_plain_one(
-        monkeypatch):
+        kernel_backend):
     """A layer that rotates nothing (positions None), heads under 128, or a
     whole-vector norm whose heads are cut over tp: the norm as it was."""
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     cfg = tf.TransformerConfig(vocab=64, d_model=512, n_heads=4, n_layers=1,
                                d_ff=64, max_seq=128, dtype=F32,
                                qk_norm_heads=True, d_head=128)
@@ -227,10 +227,10 @@ PATTERNS = {
 @pytest.mark.parametrize("backend", ["plain", "kernel"])
 @pytest.mark.parametrize("what", sorted(PATTERNS))
 def test_rope_heads_is_the_cut_then_the_halves_form(what, backend, dtype,
-                                                    monkeypatch):
+                                                    kernel_backend):
     heads, parts = PATTERNS[what]
     if backend == "kernel":
-        monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+        kernel_backend("interpret")
     positions = 128 + jnp.arange(128)       # a shard that does not start at 0
     row = jax.random.normal(
         jax.random.key(6), (2, 128, heads * sum(w for w, _t in parts)),
@@ -268,7 +268,7 @@ def test_rope_heads_is_the_cut_then_the_halves_form(what, backend, dtype,
         assert ulps(d_got, d_want) <= 2.0
 
 
-def test_the_kernel_is_selected_by_the_pattern_alone(monkeypatch):
+def test_the_kernel_is_selected_by_the_pattern_alone(kernel_backend):
     """Widths of 64 or multiples of 128, one rotary width of 64 or 128 with
     a 128 on a tile of its own, tokens a multiple of 128, whole groups of
     heads: everything else takes the plain path, and asking the kernel for
@@ -287,7 +287,7 @@ def test_the_kernel_is_selected_by_the_pattern_alone(monkeypatch):
             (128, 4, ((64, True), (128, True))),    # two rotary widths
             (128, 4, ((128, False),))):             # nothing to rotate
         assert pk.rope_heads_blocks(t, heads, parts) is None
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+    kernel_backend("interpret")
     perfvars.reset()
     row = jnp.ones((1, 128, 4 * 32), F32)
     (out,) = tf._rope_heads(row, jnp.arange(128), 1e4, 4, ((32, True),))
@@ -333,14 +333,14 @@ STEPS = {
 @pytest.mark.parametrize("backend", ["plain", "kernel"])
 @pytest.mark.parametrize("what", sorted(STEPS))
 def test_the_backward_of_a_traced_step_pads_and_adds_nothing_for_rope(
-        what, backend, monkeypatch):
+        what, backend, monkeypatch, kernel_backend):
     """Autodiff's transpose of the halves form was two cotangent halves,
     each padded back to full width (`pad`), summed (`add_any`), beside the
     forward's `concatenate`. The step's gradient, read as its jaxpr, holds
     none of the three under the `rope` name (the latent layer's scope) or
     inside the rotation's own functions; the halves form, put back, does."""
     if backend == "kernel":
-        monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+        kernel_backend("interpret")
     cfg = tf.TransformerConfig(**STEPS[what])
     params = jax.eval_shape(lambda k: tf.transformer_init(k, cfg),
                             jax.random.key(0))
@@ -362,7 +362,7 @@ def test_the_backward_of_a_traced_step_pads_and_adds_nothing_for_rope(
     if what == "a latent query row":        # the scope names what is RoPE's
         monkeypatch.setattr(tf, "_rope", lambda x, positions, theta:
                             halves(x, positions, theta))
-        monkeypatch.setattr(ring, "_kernel_backend", lambda: None)
+        kernel_backend(None)
         assert {"pad", "add_any", "concatenate"} <= set(ropes_own())
     tf._block_traced_once.cache_clear()
 

@@ -28,7 +28,7 @@ from tpu_mpi import perfvars, xla                               # noqa: E402
 from tpu_mpi.models import transformer as tf                    # noqa: E402
 from tpu_mpi.models.transformer import (transformer_init,       # noqa: E402
                                         transformer_train_step)
-from tpu_mpi.parallel import ep, ring                           # noqa: E402
+from tpu_mpi.parallel import ep                                 # noqa: E402
 
 F32 = jnp.float32
 SHAPES = {"held": (256, 256, 128), "embedding": (128, 640, 256)}
@@ -69,9 +69,9 @@ def once(fn, *args):
 
 
 @pytest.fixture(params=["plain", "interpret"])
-def backend(request, monkeypatch):
+def backend(request, kernel_backend):
     if request.param == "interpret":
-        monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+        kernel_backend("interpret")
     return request.param
 
 
@@ -168,11 +168,11 @@ def test_a_place_out_of_range_is_indexings(backend):
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * np.abs(want).max())
 
 
-def test_what_selects_the_product(monkeypatch):
+def test_what_selects_the_product(kernel_backend):
     """The backend and the shapes alone: blocks of 128 places, rows in row
     tiles, a width of 128s, float32 or bfloat16."""
     assert not ep.row_sum_selected((256, 128), 256, F32)    # no backend
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    kernel_backend("mosaic")
     assert ep.row_sum_selected((4096, 7680), 19200, jnp.bfloat16)
     assert ep.row_sum_selected((8192, 6144), 8192, F32)
     assert ep.row_sum_selected((8192, 1024), 32768, jnp.bfloat16)
@@ -225,7 +225,7 @@ def scatters_by_scope(jaxpr) -> list:
 
 @pytest.mark.parametrize("model", ["kinds", "latent"])
 def test_a_held_step_with_the_kernels_selected_scatters_no_row(model,
-                                                               monkeypatch):
+                                                               kernel_backend):
     cfg = held_configs()[model]
     mesh = xla.make_mesh({"dp": 1, "tp": 1, "sp": 1},
                          devices=jax.devices()[:1])
@@ -250,7 +250,7 @@ def test_a_held_step_with_the_kernels_selected_scatters_no_row(model,
     assert {m.group(1) for s in plain for m in [ours.search(s)]} == {
         "dispatch", "combine", "embed"}
     assert counted["product"] == 0 and counted["scatter"] >= 1 + 2 * kinds
-    monkeypatch.setattr(ring, "_kernel_backend", lambda: "mosaic")
+    kernel_backend("mosaic")
     left, counted = traced()
     assert left == []
     # one gather and one sum a buffer of a sparse layer kind's trace (the
@@ -259,7 +259,8 @@ def test_a_held_step_with_the_kernels_selected_scatters_no_row(model,
 
 
 @pytest.mark.parametrize("buffers", ["one", "further"])
-def test_a_held_model_on_the_product_is_the_plain_paths(buffers, monkeypatch):
+def test_a_held_model_on_the_product_is_the_plain_paths(buffers, monkeypatch,
+                                                        kernel_backend):
     """The layer-kind model at the test size, float32, the product's path
     on the interpret machine against the plain path: loss and gradient leaf
     by leaf with every held slot in one buffer (the interpret machine's
@@ -285,7 +286,7 @@ def test_a_held_model_on_the_product_is_the_plain_paths(buffers, monkeypatch):
         return out
     with jax.default_matmul_precision("highest"):
         want = run()
-        monkeypatch.setattr(ring, "_kernel_backend", lambda: "interpret")
+        kernel_backend("interpret")
         perfvars.reset()
         got = run()
     counted = perfvars.snapshot()["row_sum_lowerings"]

@@ -130,7 +130,8 @@ def test_the_selective_scan_is_the_recurrence(scanned, chunk, form):
 
 @pytest.mark.parametrize("who, ch, n", [("plain", 16, 4),
                                         ("kernel", 512, 16)])
-def test_the_selective_scan_rounds_its_output_once(who, ch, n):
+def test_the_selective_scan_rounds_its_output_once(who, ch, n,
+                                                   kernel_backend):
     """bfloat16 operands: the decays and the state stay float32, whoever
     computes the scan: the plain form, or the Pallas kernel pair on the
     interpret machine at a shape inside its contract (tier-1 is the only
@@ -140,7 +141,6 @@ def test_the_selective_scan_rounds_its_output_once(who, ch, n):
     7.1e-3 here and a bfloat16 decay 3.6e-3 to 5.1e-3 (PERF.md section 6,
     PR 42: at PR 41's faster decays a bfloat16 state read 2.1e-3 and
     passed)."""
-    from tpu_mpi.parallel import ring
     keys = jax.random.split(jax.random.key(1), 5)
     bsz, t = 1, 96
     x, b, c = (jax.random.normal(k, s).astype(jnp.bfloat16) for k, s in
@@ -148,14 +148,11 @@ def test_the_selective_scan_rounds_its_output_once(who, ch, n):
     dt = jax.nn.softplus(jax.random.normal(keys[3], (bsz, t, ch)) - 1.0)
     a = -jnp.broadcast_to(jnp.arange(1.0, n + 1) * 0.2 / n, (ch, n))
     d = jnp.ones((ch,))
-    kept = ring._kernel_backend
-    ring._kernel_backend = lambda: "interpret" if who == "kernel" else None
     perfvars.reset()
-    try:    # one jitted program, waited for (the interpret machine's rule)
+    with kernel_backend("interpret" if who == "kernel" else None):
+        # one jitted program, waited for (the interpret machine's rule)
         got = jax.block_until_ready(jax.jit(
             lambda *v: ssm.selective_scan(*v, chunk=32))(x, dt, a, b, c, d))
-    finally:
-        ring._kernel_backend = kept
     assert perfvars.snapshot()["sel_scan_kernel_lowerings"][who] == 1
     perfvars.reset()
     assert got.dtype == jnp.bfloat16

@@ -12,9 +12,7 @@ decoder-hybrid-decoder model. Each case is one jitted program, waited for
 before anything else is dispatched (.claude/skills/verify: the interpret
 machine's callbacks)."""
 
-import contextlib
 import dataclasses
-import functools
 import os
 import sys
 
@@ -28,7 +26,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from tpu_mpi import perfvars                                    # noqa: E402
-from tpu_mpi.parallel import ring, ssm                          # noqa: E402
+from tpu_mpi.parallel import ssm                                # noqa: E402
 from tpu_mpi.xla import sel_scan_kernels                        # noqa: E402
 from test_sambay_layer import CFG, SCAN_ARGS, recurrence        # noqa: E402
 
@@ -43,18 +41,6 @@ CASES = {
     "bf16-one-block-of-256": (BF16, 1, 256, TILE, 64),
     "bf16-padded-batch-of-two-two-tiles": (BF16, 2, 200, 2 * TILE, 64),
 }
-
-
-@contextlib.contextmanager
-def backend(name):
-    """`ring._kernel_backend`'s word for the time of a trace: "interpret"
-    selects the kernels on this CPU, None the plain path."""
-    kept = ring._kernel_backend
-    ring._kernel_backend = lambda: name
-    try:
-        yield
-    finally:
-        ring._kernel_backend = kept
 
 
 def operands(dtype, bsz, t, ch, state=N):
@@ -76,8 +62,7 @@ def operands(dtype, bsz, t, ch, state=N):
     return args, w
 
 
-@functools.lru_cache(maxsize=None)
-def scanned(case: str):
+def _scanned(kernel_backend, case: str):
     """(kernel's, `_selective_chunks`', the recurrence's in float32), each
     (y, the six gradients of sum(y w)) from one jitted program."""
     dtype, bsz, t, ch, chunk = CASES[case]
@@ -100,11 +85,24 @@ def scanned(case: str):
         return ssm.selective_scan(*a, chunk=chunk)
     out = []
     for name in ("interpret", None):
-        with backend(name):
+        with kernel_backend(name):
             out.append(jax.block_until_ready(of(scan)(*args)))
     out.append(jax.block_until_ready(of(recurrence)(
         *(v.astype(f32) for v in args))))
     return out
+
+
+_SCANNED = {}    # a case's three, computed once for the tests that read it
+
+
+@pytest.fixture
+def scanned(kernel_backend):
+    """`_scanned` of a case, from `_SCANNED` after its first call."""
+    def cached(*case):
+        if case not in _SCANNED:
+            _SCANNED[case] = _scanned(kernel_backend, *case)
+        return _SCANNED[case]
+    return cached
 
 
 def off_by(got, want) -> float:
@@ -114,7 +112,7 @@ def off_by(got, want) -> float:
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_the_kernel_is_the_plain_scan_and_the_recurrence(case):
+def test_the_kernel_is_the_plain_scan_and_the_recurrence(case, scanned):
     dtype, bsz, t, ch, _chunk = CASES[case]
     (kernel, _), (plain, _), (token_by_token, _) = scanned(case)
     assert kernel.shape == (bsz, t, ch) and kernel.dtype == jnp.dtype(dtype)
@@ -131,7 +129,7 @@ def test_the_kernel_is_the_plain_scan_and_the_recurrence(case):
 
 @pytest.mark.parametrize("name", SCAN_ARGS)
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_the_kernels_gradient_is_the_plain_scans(case, name):
+def test_the_kernels_gradient_is_the_plain_scans(case, name, scanned):
     """x, dt, A, B, C, D: against `jax.grad` of `_selective_chunks` and of
     the recurrence. In float32 as tightly as the plain form is held; in
     bfloat16 each lies as near the float32 recurrence as the plain form's
@@ -152,7 +150,8 @@ def test_the_kernels_gradient_is_the_plain_scans(case, name):
             1e-2, 2.0 * off_by(plain, token_by_token))
 
 
-def test_the_backward_keeps_the_blocks_states_and_no_token_states():
+def test_the_backward_keeps_the_blocks_states_and_no_token_states(
+        kernel_backend):
     """What the backward kernel is handed: the operands (A, B and C with the
     state index in front, D as a row) and the state before each block of
     tokens; nothing [t, state, channels] wide, which it computes again in
@@ -160,7 +159,7 @@ def test_the_backward_keeps_the_blocks_states_and_no_token_states():
     from jax._src.ad_checkpoint import saved_residuals
     bsz, t, ch = 1, 384, TILE
     args, _w = operands(F32, bsz, t, ch)
-    with backend("interpret"):
+    with kernel_backend("interpret"):
         kept = saved_residuals(lambda *a: ssm.selective_scan(*a, chunk=64),
                                *args)
     shapes = [tuple(aval.shape) for aval, _why in kept]
@@ -181,10 +180,11 @@ def test_the_backward_keeps_the_blocks_states_and_no_token_states():
     ("a state of 32", (1, 256, 512), 32, BF16, False),
     ("float16", (1, 256, 512), 16, "float16", False),
 ])
-def test_which_shapes_take_the_kernel(what, shape, state, dtype, taken):
-    with backend("interpret"):
+def test_which_shapes_take_the_kernel(what, shape, state, dtype, taken,
+                                      kernel_backend):
+    with kernel_backend("interpret"):
         assert ssm.sel_scan_kernel_selected(shape, dtype, state) is taken
-    with backend(None):     # the CPU: nothing does
+    with kernel_backend(None):     # the CPU: nothing does
         assert not ssm.sel_scan_kernel_selected(shape, dtype, state)
     if not taken:
         ch = shape[2]
@@ -199,12 +199,12 @@ def test_which_shapes_take_the_kernel(what, shape, state, dtype, taken):
 @pytest.mark.parametrize("ch, state, t, chunk, form", [
     (12, 4, 50, 16, "padded"), (640, 16, 128, 64, "chunked")])
 def test_a_shape_the_kernel_does_not_take_goes_the_plain_way(
-        ch, state, t, chunk, form):
+        ch, state, t, chunk, form, kernel_backend):
     """With the kernels selectable, 12 channels or 640 compute what they
     computed and count `plain`."""
     args, _w = operands(F32, 1, t, ch, state)
     perfvars.reset()
-    with backend("interpret"):
+    with kernel_backend("interpret"):
         got = jax.block_until_ready(
             jax.jit(lambda *a: ssm.selective_scan(*a, chunk=chunk))(*args))
     counted = perfvars.snapshot()
@@ -218,13 +218,14 @@ def test_a_shape_the_kernel_does_not_take_goes_the_plain_way(
     ("interpret", 256, "chunked", "kernel"),
     ("interpret", 200, "padded", "kernel"),
     (None, 256, "chunked", "plain"), (None, 200, "padded", "plain")])
-def test_the_counters_count_once_a_traced_scan(name, t, form, who):
+def test_the_counters_count_once_a_traced_scan(name, t, form, who,
+                                               kernel_backend):
     """`sel_scan_kernel_lowerings` says who computes a traced scan,
     `sel_scan_lowerings` its form, as it did; one count each a trace, none
     for a second call of the traced program, both zeroed by `reset`."""
     args, _w = operands(F32, 1, t, TILE)
     perfvars.reset()
-    with backend(name):
+    with kernel_backend(name):
         scan = jax.jit(lambda *a: ssm.selective_scan(*a, chunk=64))
         scan.lower(*args)
         counted = perfvars.snapshot()
@@ -242,7 +243,7 @@ def test_the_counters_count_once_a_traced_scan(name, t, form, who):
         "kernel": 0, "plain": 0}
 
 
-def test_one_train_step_through_the_kernels_is_the_plain_step():
+def test_one_train_step_through_the_kernels_is_the_plain_step(kernel_backend):
     """`transformer_train_step` on a 1 x 1 x 1 mesh: `test_sambay_layer`'s
     eight-layer decoder-hybrid-decoder stack made wide enough for the
     kernels' contract (three mamba layers of 512 channels over a state of
@@ -272,7 +273,7 @@ def test_one_train_step_through_the_kernels_is_the_plain_step():
     want_params, want_loss = one_step()
     traced = perfvars.snapshot()["sel_scan_kernel_lowerings"]["plain"]
     assert 1 <= traced < 3      # three mamba layers share their traces
-    with backend("interpret"):
+    with kernel_backend("interpret"):
         got_params, got_loss = one_step()
     assert perfvars.snapshot()["sel_scan_kernel_lowerings"] == {
         "kernel": traced, "plain": traced}

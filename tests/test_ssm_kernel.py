@@ -8,8 +8,6 @@ counters. Small shapes (8 heads of 64 over a state of 128): each case is one
 jitted program, waited for before anything else is dispatched
 (.claude/skills/verify: the interpret machine's callbacks)."""
 
-import contextlib
-import functools
 import os
 import sys
 
@@ -22,7 +20,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from tpu_mpi import perfvars                                    # noqa: E402
-from tpu_mpi.parallel import ring, ssm                          # noqa: E402
+from tpu_mpi.parallel import ssm                                # noqa: E402
 from tpu_mpi.xla import ssm_kernels                             # noqa: E402
 from test_ssm_layer import SCAN_ARGS, recurrence                # noqa: E402
 
@@ -42,18 +40,6 @@ CASES = {
 GRADIENTS = ("batch-of-two", "two-tiles-a-chunk", "padded", "bf16")
 
 
-@contextlib.contextmanager
-def backend(name):
-    """`ring._kernel_backend`'s word for the time of a trace: "interpret"
-    selects the kernels on this CPU, None the plain path."""
-    kept = ring._kernel_backend
-    ring._kernel_backend = lambda: name
-    try:
-        yield
-    finally:
-        ring._kernel_backend = kept
-
-
 def operands(dtype, bsz, t, heads=H, width=P, state=N):
     keys = jax.random.split(jax.random.key(t + bsz), 5)
     f32 = jnp.float32
@@ -71,8 +57,7 @@ def operands(dtype, bsz, t, heads=H, width=P, state=N):
     return args, w
 
 
-@functools.lru_cache(maxsize=None)
-def scanned(case: str, grads: bool = False):
+def _scanned(kernel_backend, case: str, grads: bool = False):
     """(kernel's, `_chunked`'s, the recurrence's in float32) values, or the
     three's gradients of sum(y w), for a case; each one jitted program."""
     dtype, bsz, t, chunk = CASES[case]
@@ -89,12 +74,25 @@ def scanned(case: str, grads: bool = False):
         return ssm.scan(*a, chunk)
     out = []
     for name in ("interpret", None):
-        with backend(name):
+        with kernel_backend(name):
             out.append(jax.block_until_ready(of(chunked)(*args)))
     with jax.default_matmul_precision("highest"):
         out.append(jax.block_until_ready(of(recurrence)(
             *(v.astype(f32) for v in args))))
     return out
+
+
+_SCANNED = {}    # a case's three, computed once for the tests that read it
+
+
+@pytest.fixture
+def scanned(kernel_backend):
+    """`_scanned` of a case, from `_SCANNED` after its first call."""
+    def cached(*case):
+        if case not in _SCANNED:
+            _SCANNED[case] = _scanned(kernel_backend, *case)
+        return _SCANNED[case]
+    return cached
 
 
 def off_by(got, want) -> float:
@@ -104,7 +102,7 @@ def off_by(got, want) -> float:
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_the_kernel_is_the_chunked_scan_and_the_recurrence(case):
+def test_the_kernel_is_the_chunked_scan_and_the_recurrence(case, scanned):
     dtype, bsz, t, _chunk = CASES[case]
     kernel, plain, token_by_token = scanned(case)
     assert kernel.shape == (bsz, t, H, P) and kernel.dtype == jnp.dtype(dtype)
@@ -118,7 +116,7 @@ def test_the_kernel_is_the_chunked_scan_and_the_recurrence(case):
 
 @pytest.mark.parametrize("name", SCAN_ARGS)
 @pytest.mark.parametrize("case", GRADIENTS)
-def test_the_kernels_gradient_is_the_chunked_scans(case, name):
+def test_the_kernels_gradient_is_the_chunked_scans(case, name, scanned):
     """x, dt, A, B, C, D: against `jax.grad` of `_chunked` and of the
     recurrence. In bfloat16 each lies as near the float32 recurrence as
     `_chunked`'s does (both round the operands of the same products)."""
@@ -135,14 +133,15 @@ def test_the_kernels_gradient_is_the_chunked_scans(case, name):
             2e-2, 2.0 * off_by(plain, token_by_token))
 
 
-def test_the_backward_keeps_the_states_and_nothing_of_the_decay_matrix():
+def test_the_backward_keeps_the_states_and_nothing_of_the_decay_matrix(
+        kernel_backend):
     """What the backward kernel is handed: the operands (x as rows, dt and
     the sums head-major, B, C, D over the lanes) and the state before each
     chunk; nothing of [.., chunk, chunk], which it computes again."""
     from jax._src.ad_checkpoint import saved_residuals
     bsz, t, chunk = 1, 256, 128
     args, _w = operands(F32, bsz, t)
-    with backend("interpret"):
+    with kernel_backend("interpret"):
         kept = saved_residuals(lambda *a: ssm.scan(*a, chunk), *args)
     shapes = [tuple(aval.shape) for aval, _why in kept]
     assert (bsz, t // chunk, N, H * P) in shapes            # the states
@@ -169,10 +168,11 @@ def test_the_backward_keeps_the_states_and_nothing_of_the_decay_matrix():
     ("a chunk of 512", (1, 512, 8, 64), 128, 512, BF16, False),
     ("float16", (1, 256, 8, 64), 128, 128, "float16", False),
 ])
-def test_which_shapes_take_the_kernel(what, shape, state, chunk, dtype, taken):
-    with backend("interpret"):
+def test_which_shapes_take_the_kernel(what, shape, state, chunk, dtype, taken,
+                                      kernel_backend):
+    with kernel_backend("interpret"):
         assert ssm.scan_kernel_selected(shape, dtype, state, chunk) is taken
-    with backend(None):     # the CPU: nothing does
+    with kernel_backend(None):     # the CPU: nothing does
         assert not ssm.scan_kernel_selected(shape, dtype, state, chunk)
     if not taken:
         x = jnp.zeros(shape, dtype)
@@ -188,12 +188,12 @@ def test_which_shapes_take_the_kernel(what, shape, state, chunk, dtype, taken):
     (48, 128, 256, "chunked"), (64, 96, 192, "chunked"),
     (48, 96, 200, "padded")])
 def test_a_shape_the_kernel_does_not_take_goes_the_plain_way(
-        width, chunk, t, form):
+        width, chunk, t, form, kernel_backend):
     """With the kernels selectable, a head of 48 or a chunk of 96 computes
     what it computed and counts `plain`."""
     args, _w = operands(F32, 1, t, width=width)
     perfvars.reset()
-    with backend("interpret"):
+    with kernel_backend("interpret"):
         got = jax.block_until_ready(
             jax.jit(lambda *a: ssm.scan(*a, chunk))(*args))
     counted = perfvars.snapshot()
@@ -209,13 +209,14 @@ def test_a_shape_the_kernel_does_not_take_goes_the_plain_way(
     ("interpret", 256, "chunked", "kernel"),
     ("interpret", 200, "padded", "kernel"),
     (None, 256, "chunked", "plain"), (None, 200, "padded", "plain")])
-def test_the_counters_count_once_a_traced_scan(name, t, form, who):
+def test_the_counters_count_once_a_traced_scan(name, t, form, who,
+                                               kernel_backend):
     """`scan_kernel_lowerings` says who computes a traced scan,
     `scan_lowerings` its form, as it did; one count each a trace, none for
     a second call of the traced program, both zeroed by `reset`."""
     args, _w = operands(F32, 1, t)
     perfvars.reset()
-    with backend(name):
+    with kernel_backend(name):
         scan = jax.jit(lambda *a: ssm.scan(*a, 128))
         jax.block_until_ready(scan.lower(*args))
         counted = perfvars.snapshot()
@@ -231,7 +232,7 @@ def test_the_counters_count_once_a_traced_scan(name, t, form, who):
         "kernel": 0, "plain": 0}
 
 
-def test_one_train_step_through_the_kernels_is_the_plain_step():
+def test_one_train_step_through_the_kernels_is_the_plain_step(kernel_backend):
     """`transformer_train_step` on a 1 x 1 x 1 mesh at a toy shape inside
     the kernels' contract (two state-space layers of 8 heads of 64 over a
     state of 128, 256 tokens in chunks of 128), the selection patched to
@@ -262,7 +263,7 @@ def test_one_train_step_through_the_kernels_is_the_plain_step():
     want_params, want_loss = one_step()
     assert perfvars.snapshot()["scan_kernel_lowerings"] == {
         "kernel": 0, "plain": 1}        # two layers of a kind: one trace
-    with backend("interpret"):
+    with kernel_backend("interpret"):
         got_params, got_loss = one_step()
     assert perfvars.snapshot()["scan_kernel_lowerings"] == {
         "kernel": 1, "plain": 1}

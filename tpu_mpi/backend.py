@@ -361,7 +361,11 @@ class _RemoteMailbox:
     a receiver whose unexpected queue crosses the high-water mark sends a
     ``choke`` frame; ``post_blocking`` waits while this destination has us
     choked, resuming on its ``unchoke``. Buffered Isend traffic is exempt,
-    mirroring the thread tier."""
+    mirroring the thread tier. A send that waits tells the destination what
+    it holds back (a ``blocked`` frame: the message's envelope), and the
+    destination unchokes a sender whose envelope a posted receive matches:
+    the thread tier's rule that a message a posted receive matches is
+    admitted, whatever the queue holds."""
 
     def __init__(self, ctx: "ProcContext", world_rank: int):
         self.ctx = ctx
@@ -376,8 +380,13 @@ class _RemoteMailbox:
         if self.world_rank in ctx.choked_by:
             from ._runtime import deadlock_timeout
             deadline = time.monotonic() + deadlock_timeout()
-            with ctx._choke_cond:
-                while self.world_rank in ctx.choked_by:
+            # the choke this wait has answered: each one that arrives is
+            # answered once, outside the lock the frame pump delivers under
+            told = None
+            while True:
+                with ctx._choke_cond:
+                    if self.world_rank not in ctx.choked_by:
+                        break
                     ctx.check_failure()
                     if self.world_rank in ctx.failed_ranks:
                         raise ProcFailedError(
@@ -388,7 +397,13 @@ class _RemoteMailbox:
                         raise DeadlockError(
                             f"deadlock suspected: rank {self.world_rank} kept "
                             f"this sender choked >{deadlock_timeout()}s in {what}")
-                    ctx._choke_cond.wait(0.02)
+                    chokes = ctx.choke_count
+                    if chokes == told:
+                        ctx._choke_cond.wait(0.02)
+                        continue
+                told = chokes
+                ctx.send_frame(self.world_rank,
+                               ("blocked", msg.src, msg.tag, msg.cid))
         self.post(msg)
 
     def post(self, msg: Message) -> None:
@@ -2137,6 +2152,25 @@ class ProcContext(SpmdContext):
             self._pending_unchokes |= self._choked_peers
             self._choked_peers = set()
 
+    def _admit_if_awaited(self, src_world: int, src: int, tag: Any,
+                          cid: Any) -> None:
+        """A sender we choked holds back a blocking send of this envelope:
+        unchoke it if a posted receive matches. The pending-recv hook runs
+        when a receive is posted, so it never sees a choke decided AFTER
+        (the queue went over the mark while the receive was pending), and
+        the receiver waited for the message its own choke held back
+        (`test_sendrecv_deadlock_free_under_choke` under load). Under the
+        mailbox lock, as the hook is: a receive posted after this look runs
+        the hook and finds the sender still choked."""
+        mb = self.mailboxes[self.local_rank]
+        held = Message(src, tag, cid, None, 0, None, "typed")
+        with mb.cond:
+            if any(not pr.cancelled and pr.matches(held) for pr in mb.recvs):
+                with self._choke_peers_lock:
+                    if src_world in self._choked_peers:
+                        self._choked_peers.discard(src_world)
+                        self._pending_unchokes.add(src_world)
+
     def _flush_unchokes(self) -> None:
         """Drainer-loop tail: ship queued unchoke frames. A failed unchoke
         fate-shares — the peer would otherwise hang choked until a
@@ -2333,7 +2367,16 @@ class ProcContext(SpmdContext):
                     self._choked_peers.add(src_world)
                     send_choke = True
             if send_choke:
-                self.send_frame(src_world, ("choke",))
+                try:
+                    self.send_frame(src_world, ("choke",))
+                except ConnectionError:
+                    # the sender is gone, and nobody is left to slow down:
+                    # with its messages delivered a rank may finalize and
+                    # exit while this one still reads them. A peer that
+                    # DIED is the failure detector's and the launcher's to
+                    # report, not a refused advisory frame's.
+                    with self._choke_peers_lock:
+                        self._choked_peers.discard(src_world)
 
     def _dispatch(self, src_world: int, item: Any) -> None:
         kind = item[0]
@@ -2360,6 +2403,8 @@ class ProcContext(SpmdContext):
             with self._choke_cond:
                 self.choked_by.discard(src_world)
                 self._choke_cond.notify_all()
+        elif kind == "blocked":
+            self._admit_if_awaited(src_world, *item[1:])
         elif kind == "coll":
             _, cid, rnd, src, opname, contrib = item
             self._proc_channel(cid).deliver_contrib(rnd, src, opname,

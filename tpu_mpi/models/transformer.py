@@ -23,13 +23,14 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from .. import perfvars
-from ..parallel import ring
 from ..parallel.dp import allreduce_grads
 from ..parallel.ep import (grouped_products, held_row_buffer, moe_dropless,
                            moe_dropless_held, rows_at)
 from ..parallel.ring import (fused_attention_selected, local_attention,
-                             ring_attention, warm_kernel_imports)
+                             ring_attention)
 from ..parallel.tp import column_parallel, row_parallel
+from ..xla import choice
+from ..xla import pallas_kernels as pk
 
 
 @dataclasses.dataclass(frozen=True)
@@ -598,13 +599,6 @@ def _rope_halves(x, positions, theta: float = 10000.0):
     return jnp.concatenate(out, axis=-1).astype(x.dtype)
 
 
-def _rope_kernel(dtype) -> Optional[str]:
-    """How a rotation's kernel would run for operands of ``dtype``
-    (`ring._kernel_backend`'s word), or None where none is selected."""
-    from ..xla import pallas_kernels as pk
-    return ring._kernel_backend() if str(dtype) in pk.ROPE_DTYPES else None
-
-
 def _rope(x, positions, theta: float = 10000.0):
     """Rotary embeddings of x [..., t, width], each last axis one head;
     positions are *global* so sequence shards agree. x cos2 + swap(x) sin2
@@ -615,9 +609,9 @@ def _rope(x, positions, theta: float = 10000.0):
     `halves` where the width is odd."""
     width = x.shape[-1]
     if width % 2:
-        perfvars.note_rope_form("halves")
+        perfvars.note("rope_forms", "halves")
         return _rope_halves(x, positions, theta)
-    perfvars.note_rope_form("dense")
+    perfvars.note("rope_forms", "dense")
     return _turn(x, *_rope_table(positions, theta, width, np.arange(width)))
 
 
@@ -632,22 +626,19 @@ def _rope_heads(row, positions, theta: float, heads: int, parts: tuple):
     ((width, rotated), ..) side by side (a packed projection's q | k | v, a
     latent query's unrotated | rotated), as one [b, heads, t, width] array a
     part with the rotated ones turned. Where the backend and the pattern
-    select it (`ring._kernel_backend`, `pallas_kernels.rope_heads_blocks`:
-    widths of 64 or multiples of 128, a rotary width of 64 or 128) that is
-    one kernel each way, `pallas_kernels.rope_heads`: the rotation runs on
-    the row, where it is 128 lanes dense, and the cut into heads is the
-    kernel's write; elsewhere the cut, then `_rope` on each rotated part."""
-    from ..xla import pallas_kernels as pk
-    t = row.shape[1]
+    select it (`xla.choice`, `pallas_kernels.rope_heads_blocks`: widths of
+    64 or multiples of 128, a rotary width of 64 or 128) that is one kernel
+    each way, `pallas_kernels.rope_heads`: the rotation runs on the row,
+    where it is 128 lanes dense, and the cut into heads is the kernel's
+    write; elsewhere the cut, then `_rope` on each rotated part."""
     turned = [w for w, rotated in parts if rotated]
-    how = _rope_kernel(row.dtype)
-    if how is not None and turned and pk.rope_heads_blocks(t, heads, parts):
-        for _ in turned:
-            perfvars.note_rope_form("dense")
+    run = choice.decide(choice.ROPE_HEADS, row.shape[1], heads, parts,
+                        row.dtype, also=bool(turned), count=len(turned))
+    if run:
         cos, sin = _rope_table(positions, theta, turned[0],
                                pk.rope_heads_lanes(parts))
         return pk.rope_heads(row, cos, sin, heads, parts,
-                             interpret=how == "interpret")
+                             interpret=run.interpret)
     ends = np.cumsum([w for w, _rotated in parts])
     return tuple(
         _rope(part, positions, theta) if rotated else part
@@ -717,7 +708,7 @@ def _trunk(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
     for i, layer in enumerate(params["layers"]):
         kind = cfg.layer_kind(i)
         block = _block_traced_once(cfg, kind, tp_axis, sp_axis,
-                                   ring._kernel_backend())
+                                   choice.trace_key())
         with jax.named_scope(f"layer_{i}"):
             x, sent, wrote = block(layer, x, positions,
                                    _side_read(cfg, kind, i, side))
@@ -735,7 +726,7 @@ def _trunk(cfg: TransformerConfig, params: dict, tokens: jnp.ndarray, *,
 @functools.lru_cache(maxsize=None)
 def _block_traced_once(cfg: TransformerConfig, kind: LayerKind,
                        tp_axis: Optional[str], sp_axis: Optional[str],
-                       kernels: Optional[str]):
+                       kernels: tuple):
     """`_attn_ffn_block` behind a `jax.jit` of its own, one for each kind of
     layer the model has. The layers of a kind have one shape, and they are
     unrolled in Python: jitted, layers 2..n of a kind in a program (and a
@@ -745,8 +736,8 @@ def _block_traced_once(cfg: TransformerConfig, kind: LayerKind,
     and direction, called once a layer (the compiler inlines the calls,
     and each inlined op's name gains its call's `layer_<i>`). That is what
     keeps a step's trace, which is set-up time, from growing with depth
-    (PERF.md, Set-up). ``kernels`` is what `ring._kernel_backend` says: the
-    kernels are selected inside the trace, so it is part of the key."""
+    (PERF.md, Set-up). ``kernels`` is `choice.trace_key`: the kernels are
+    selected inside the trace, so what selects them is part of the key."""
     def block(layer, x, positions, side):
         return _attn_ffn_block(cfg, layer, x, positions, tp_axis=tp_axis,
                                sp_axis=sp_axis, kind=kind, side=side)
@@ -763,10 +754,10 @@ def _side_read(cfg: TransformerConfig, kind: LayerKind, i: int,
     Each read counts in ``perfvars.snapshot()["side_values"]``."""
     out = {}
     if kind.mixer == "gmu":
-        perfvars.note_side_value("memory")
+        perfvars.note("side_values", "memory")
         out["memory"] = side["memory"]
     if kind.mixer == "cross":
-        perfvars.note_side_value("kv")
+        perfvars.note("side_values", "kv")
         out["kv"] = side["kv"]
     if cfg.diff_attn and kind.mixer in ("attention", "cross"):
         out["depth"] = jnp.float32(i)
@@ -811,7 +802,7 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
         if cfg.residual_multiplier != 1.0:
             out = out * cfg.residual_multiplier
         return out
-    perfvars.note_mixer_kind(kind.mixer)
+    perfvars.note("mixer_kinds", kind.mixer)
     wrote = {}
     if kind.mixer == "ssm":
         with jax.named_scope("mixer"):
@@ -1243,7 +1234,7 @@ def _diff_attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
     else:
         k, v = kv
     fused = fused_attention_selected(q.shape, q.dtype, 0, v.shape[3])
-    perfvars.note_attn_kind("diff", "fused" if fused else "plain")
+    perfvars.note("attn_kinds", ("diff", "fused" if fused else "plain"))
     o = local_attention(q, k, v, window)            # [b, h, t, 2 dh]
     with jax.named_scope("diff"):
         f32 = jnp.float32
@@ -1285,20 +1276,18 @@ def _norm_and_rope(cfg: TransformerConfig, x: jnp.ndarray, scale: jnp.ndarray,
     `pallas_kernels.norm_rope`: the norm's arithmetic as it stands below,
     float32 inside and rounded where it rounds; elsewhere the norm, then
     `_rope`."""
-    from ..xla import pallas_kernels as pk
     b, h, t, dh = x.shape
-    how = _rope_kernel(x.dtype)
     alone = tp_axis is None or lax.axis_size(tp_axis) == 1
-    if positions is not None and how is not None \
-            and cfg.qk_norm != cfg.qk_norm_heads \
-            and (alone or not cfg.qk_norm) and pk.norm_rope_blocks(
-                b * h, t, dh, x.dtype.itemsize, h if cfg.qk_norm else 0):
-        perfvars.note_rope_form("dense")
+    run = choice.decide(
+        choice.NORM_ROPE, b * h, t, dh, x.dtype, h if cfg.qk_norm else 0,
+        also=positions is not None and cfg.qk_norm != cfg.qk_norm_heads
+        and (alone or not cfg.qk_norm))
+    if run:
         cos, sin = _rope_table(positions, cfg.rope_theta, dh, np.arange(dh))
         return pk.norm_rope(
             x, scale.reshape(h, dh) if cfg.qk_norm else scale, cos, sin,
             eps=cfg.norm_eps, denom=cfg.d_model if cfg.qk_norm else dh,
-            interpret=how == "interpret")
+            interpret=run.interpret)
     if cfg.qk_norm:
         x = _whole_vector_norm(cfg, x, scale, tp_axis)
     if cfg.qk_norm_heads:
@@ -1339,7 +1328,8 @@ def head_loss(cfg: TransformerConfig, params: dict, x: jnp.ndarray,
         x = _norm(cfg, x, params, "ln_f").reshape(-1, cfg.d_model)
         w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
         block = _head_block(x.shape[0], cfg.vocab)
-        perfvars.note_head_loss_lowering("blocked", -(-x.shape[0] // block))
+        perfvars.note("head_loss_lowerings", "blocked")
+        perfvars.note("head_loss_blocks", -(-x.shape[0] // block))
         # inside `shard_map` the head is the same on every data shard and
         # the stream is not: said here, so that the sum of the shards'
         # gradients is this cast's transpose, as it was the product's
@@ -1446,7 +1436,7 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
             f"{sizes[sp_axis]}): its state and its side values are not cut; "
             f"shard its batch over {dp_axis}")
     reduce_axes = (dp_axis, sp_axis)
-    warm_kernel_imports()       # off the first trace's path (set-up time)
+    choice.warm_kernel_imports()    # off the first trace's path (set-up time)
 
     def local_step(params, tokens, labels):
         def loss_fn(p):
@@ -1642,7 +1632,7 @@ def transformer_pp_moe_train_step(cfg: TransformerConfig, mesh,
             acts = acts.reshape(b, t, cfg.d_model)
             logits = (_rms_norm(acts, p["ln_f"])
                       @ p["embed"].T).astype(jnp.float32)
-            perfvars.note_head_loss_lowering("whole")
+            perfvars.note("head_loss_lowerings", "whole")
             l = _xent(logits, labels)
             # only the last stage's emissions are the real model output
             last = lax.axis_index(pp_axis) == n_pp - 1
@@ -1770,7 +1760,7 @@ def transformer_4d_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2,
             acts = acts.reshape(b, t, cfg.d_model)
             logits = (_rms_norm(acts, p["ln_f"])
                       @ p["embed"].T).astype(jnp.float32)
-            perfvars.note_head_loss_lowering("whole")
+            perfvars.note("head_loss_lowerings", "whole")
             l = _xent(logits, labels)
             # only the last stage's emissions are the real model output
             last = lax.axis_index(pp_axis) == n_pp - 1

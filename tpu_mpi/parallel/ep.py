@@ -52,8 +52,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .. import perfvars
-from . import ring
+from ..xla import choice
+from ..xla import pallas_kernels as pk
 
 # Per-thread persistent count-exchange buffers, keyed by (cid, n). The
 # count Alltoall has a FIXED signature (n int64 per rank, same comm)
@@ -75,26 +75,14 @@ def _count_exchange_bufs(cid: int, n: int):
     return cache[key]
 
 
-def _grouped_kernel_blocks(rows_shape: tuple, weights_shape: tuple, dtype):
-    """The grouped kernel's (row tile, column tile) for ``[m, k]`` rows and
-    ``[g, k, n]`` weights of ``dtype`` where it is selected, else None:
-    decided, as `ring.fused_attention_selected` decides, from the backend
-    (the same rule, `ring._kernel_backend`) and the kernel's contract
-    (``pallas_kernels.grouped_matmul_blocks``, ``GROUPED_DTYPES``), never by
-    trying it: once selected, a kernel that does not lower is an error."""
-    from ..xla import pallas_kernels as pk
-    dtype = jnp.dtype(dtype)
-    if ring._kernel_backend() is None or str(dtype) not in pk.GROUPED_DTYPES:
-        return None
-    return pk.grouped_matmul_blocks(rows_shape[0], *weights_shape[1:],
-                                    dtype.itemsize)
-
-
 def grouped_matmul_selected(rows_shape: tuple, weights_shape: tuple,
                             dtype) -> bool:
     """Whether a product of :func:`grouped_products` runs the grouped
-    kernel for ``[m, k]`` rows and ``[g, k, n]`` weights of ``dtype``."""
-    return _grouped_kernel_blocks(rows_shape, weights_shape, dtype) is not None
+    kernel for ``[m, k]`` rows and ``[g, k, n]`` weights of ``dtype``:
+    `xla.choice`'s rule over the kernel's contract,
+    ``pallas_kernels.grouped_matmul_blocks``."""
+    return choice.fit(choice.GROUPED, rows_shape[0], *weights_shape[1:],
+                      dtype) is not None
 
 
 def grouped_products(sizes: jnp.ndarray) -> Callable:
@@ -116,20 +104,16 @@ def grouped_products(sizes: jnp.ndarray) -> Callable:
     walks = {}
 
     def product(rows: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
-        blocks = None if rows.dtype != weights.dtype else \
-            _grouped_kernel_blocks(rows.shape, weights.shape, rows.dtype)
-        if blocks is None:
-            perfvars.note_gmm_lowering("ragged_dot")
+        run = choice.decide(choice.GROUPED, rows.shape[0], *weights.shape[1:],
+                            rows.dtype, also=rows.dtype == weights.dtype)
+        if run is None:
             return lax.ragged_dot(rows, weights, sizes)
-        from ..xla import pallas_kernels as pk
-        perfvars.note_gmm_lowering("kernel")
-        walk = rows.shape[0], blocks[0]     # what a walk depends on
+        walk = rows.shape[0], run.fit[0]    # what a walk depends on
         if walk not in walks:
             walks[walk] = pk.grouped_matmul_visits(sizes, *walk)
         return pk.grouped_matmul(
-            rows, weights, sizes, visits=walks[walk], block_m=blocks[0],
-            block_c=blocks[1],
-            interpret=ring._kernel_backend() == "interpret")
+            rows, weights, sizes, visits=walks[walk], block_m=run.fit[0],
+            block_c=run.fit[1], interpret=run.interpret)
     return product
 
 
@@ -274,19 +258,21 @@ def moe_dropless(tokens: jnp.ndarray, expert_idx: jnp.ndarray,
 # each other's transposes and, there, each other's gradients, as
 # `_rows_of_tokens` and `_tokens_of_rows` are.
 
+def _row_sum_choice(ask: Callable, rows_shape: tuple, places: int, dtype):
+    """`xla.choice`'s answer (``ask``: its `fit`, or its `decide`, which
+    counts) for ``rows[m, d]`` of ``dtype`` summed into ``places`` places:
+    the kernel's contract, ``pallas_kernels.grouped_row_sums_blocks`` (rows
+    a multiple of a row tile, the width of 128), and the places in whole
+    blocks of 128."""
+    return ask(choice.ROW_SUM, *rows_shape, dtype,
+               also=places % pk.LANE == 0)
+
+
 def row_sum_selected(rows_shape: tuple, places: int, dtype) -> bool:
     """Whether ``rows[m, d]`` of ``dtype`` (float32 where they are weighed)
     are summed into ``places`` places by the product on the MXU and not by
-    XLA's scatter-add: decided where :func:`grouped_matmul_selected`
-    decides, from the backend and the kernel's contract (blocks of 128
-    places, rows a multiple of a row tile, the width of 128), never by
-    trying it."""
-    from ..xla import pallas_kernels as pk
-    dtype = jnp.dtype(dtype)
-    return (ring._kernel_backend() is not None and places % pk.LANE == 0
-            and str(dtype) in pk.GROUPED_DTYPES
-            and pk.grouped_row_sums_blocks(*rows_shape,
-                                           dtype.itemsize) is not None)
+    XLA's scatter-add."""
+    return _row_sum_choice(choice.fit, rows_shape, places, dtype) is not None
 
 
 def _by_place(rows, place, places: int, scale, live):
@@ -318,7 +304,7 @@ def _by_place(rows, place, places: int, scale, live):
 
 
 @functools.lru_cache(maxsize=None)
-def _summed(places: int, dtype, interpret: bool):
+def _summed(places: int, dtype, key: tuple):
     """The sum as the product (the form's own words are above): float32
     products where a scale weighs the rows, the rows' own dtype into the
     MXU where none does; float32 accumulation, one rounding to ``dtype``.
@@ -326,8 +312,8 @@ def _summed(places: int, dtype, interpret: bool):
     shapes (a layer's forward pass and its recomputation, its first buffer
     and the further ones, two kinds of sparse layer: 13 calls of three
     shapes in the K-EXAONE step) share one trace, which is set-up time
-    (PERF.md, Set-up)."""
-    from ..xla import pallas_kernels as pk
+    (PERF.md, Set-up). ``key``: `choice.trace_key`, what the trace read."""
+    interpret = choice.interpret()
 
     @jax.jit
     def summed(rows, place, scale, live):
@@ -339,8 +325,8 @@ def _summed(places: int, dtype, interpret: bool):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _sum_rows(rows, place, scale, live, places: int, dtype, scope: str):
-    return _summed(places, dtype, ring._kernel_backend() == "interpret")(
-        rows, place, scale, live)
+    return _summed(places, dtype, choice.trace_key())(rows, place, scale,
+                                                       live)
 
 
 def _sum_rows_bwd(places, dtype, scope, kept, g):
@@ -394,11 +380,9 @@ def sum_rows(rows: jnp.ndarray, place: jnp.ndarray, places: int, *,
     ``product`` or ``scatter``."""
     dtype = jnp.dtype(rows.dtype if dtype is None else dtype)
     weighed = jnp.float32 if scale is not None else rows.dtype
-    if row_sum_selected(rows.shape, places, weighed):
-        perfvars.note_row_sum_lowering("product")
+    if _row_sum_choice(choice.decide, rows.shape, places, weighed):
         rows, place, *rest = _vary_together(rows, place, scale, live)
         return _sum_rows(rows, place, *rest, places, dtype, scope)
-    perfvars.note_row_sum_lowering("scatter")
     if scale is not None:
         rows = rows.astype(jnp.float32) * scale.astype(jnp.float32)[:, None]
     if live is not None:
@@ -416,13 +400,11 @@ def rows_at(source: jnp.ndarray, place: jnp.ndarray, *,
     back into its places; ``scope`` names the gradient's ops) the gradient
     is that sum; everywhere else the indexing is left to JAX, whose
     transpose is XLA's scatter-add. Counted as :func:`sum_rows` counts."""
-    if row_sum_selected((place.size,) + source.shape[1:], source.shape[0],
-                        source.dtype):
-        perfvars.note_row_sum_lowering("product")
+    if _row_sum_choice(choice.decide, (place.size,) + source.shape[1:],
+                       source.shape[0], source.dtype):
         source, flat = _vary_together(source, place.reshape(-1))
         return _rows_at(source, flat, source.shape[0], scope).reshape(
             place.shape + source.shape[1:])
-    perfvars.note_row_sum_lowering("scatter")
     return source[place]
 
 
@@ -431,7 +413,6 @@ def _vary_together(*xs):
     mesh axis any of them varies over: what a custom gradient's operands
     need under `shard_map`, where a replicated operand's gradient is then
     summed over those axes by the cast's own transpose."""
-    from ..xla import pallas_kernels as pk
     some = pk._vary_together(*(x for x in xs if x is not None))
     return [None if x is None else some.pop(0) for x in xs]
 

@@ -19,59 +19,29 @@ own. A ring of n > 1 keeps its XLA online softmax per step.
 
 from __future__ import annotations
 
-import threading
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import perfvars
+from ..xla import choice
+from ..xla import pallas_kernels as pk
 
 NEG_INF = -1e30
-
-
-def _kernel_backend() -> Optional[str]:
-    """How the fused attention kernel would run here: "mosaic" on a TPU,
-    None elsewhere. (The tests patch this to "interpret" for the Pallas
-    interpret machine, which is far too slow to be chosen.)"""
-    return "mosaic" if jax.default_backend() == "tpu" else None
-
-
-def warm_kernel_imports() -> None:
-    """Where the fused kernel can be selected, start importing Pallas on a
-    thread: the import costs 0.8 s (it pulls in the GPU and Mosaic dialects)
-    and would otherwise be paid inside the first trace of a step. A builder
-    of a step calls this; the trace then finds the modules there, or waits
-    on the import lock for what is left."""
-    if _kernel_backend() is not None:
-        from ..xla import pallas_kernels as pk
-
-        def load():     # set-up, but no arming: a span alone, in no pvar
-            t0 = perfvars.monotonic()
-            pk.load()
-            perfvars.publish_setup_span("kernels.import", t0,
-                                        perfvars.monotonic())
-        threading.Thread(target=load, name="tpu_mpi-pallas-import",
-                         daemon=True).start()
 
 
 def fused_attention_selected(shape: tuple, dtype, rope_dim: int = 0,
                              value_dim: int = 0) -> bool:
     """Whether :func:`local_attention` runs the fused kernel for (batch,
-    heads, t, head_dim) queries of ``dtype``: decided from the backend and
-    the kernel's contract (``pallas_kernels.causal_attention_blocks``,
-    ``ATTN_DTYPES``), never by trying it and catching the failure: once
-    selected, a kernel that does not lower is an error. (A window, and how
-    many heads the keys and values have, are not part of the contract: any
-    window, any divisor of the queries' heads. The width of a second term
-    of the scores, ``rope_dim``, and of the values where it is their own,
-    ``value_dim``, are.)"""
-    from ..xla import pallas_kernels as pk
-    return (_kernel_backend() is not None
-            and str(jnp.dtype(dtype)) in pk.ATTN_DTYPES
-            and pk.causal_attention_blocks(shape[2], shape[3], rope_dim,
-                                           value_dim) is not None)
+    heads, t, head_dim) queries of ``dtype``: `xla.choice`'s rule over the
+    kernel's contract, ``pallas_kernels.causal_attention_blocks``. (A
+    window, and how many heads the keys and values have, are not part of
+    the contract: any window, any divisor of the queries' heads. The width
+    of a second term of the scores, ``rope_dim``, and of the values where
+    it is their own, ``value_dim``, are.)"""
+    return choice.fit(choice.ATTENTION, shape[2], shape[3], rope_dim,
+                      value_dim, dtype) is not None
 
 
 def local_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -86,15 +56,15 @@ def local_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     rotated one (latent attention); `k_rope` may hold one head that every
     query head reads, and v a width of its own. Each call built into a
     traced program counts in ``perfvars.snapshot()["attn_lowerings"]`` as
-    ``fused`` or ``plain``."""
+    ``fused`` or ``plain``, and by its kind (``full``, ``window``,
+    ``latent``) in ``["attn_kinds"]``."""
     rope_dim = rope[0].shape[3] if rope else 0
-    if fused_attention_selected(q.shape, q.dtype, rope_dim, v.shape[3]):
-        from ..xla import pallas_kernels as pk
-        perfvars.note_attn_lowering("fused", window, bool(rope))
-        return pk.causal_attention(
-            q, k, v, window=window, rope=rope,
-            interpret=_kernel_backend() == "interpret")
-    perfvars.note_attn_lowering("plain", window, bool(rope))
+    run = choice.decide(
+        choice.ATTENTION, q.shape[2], q.shape[3], rope_dim, v.shape[3],
+        q.dtype, of="latent" if rope else "window" if window else "full")
+    if run:
+        return pk.causal_attention(q, k, v, window=window, rope=rope,
+                                   interpret=run.interpret)
     return plain_attention(q, k, v, window, rope)
 
 

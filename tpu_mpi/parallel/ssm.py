@@ -30,7 +30,7 @@ Who computes that form is chosen the same way, after the padding, and
 counted beside it (``["scan_kernel_lowerings"]``, ``kernel`` or ``plain``,
 one count a traced scan). ``kernel``: the Pallas pair of
 ``xla/ssm_kernels.py``, forward and one backward (``jax.custom_vjp``), where
-a kernel backend is there (`ring._kernel_backend`: a TPU; the tests' word
+a kernel backend is there (`xla.choice.backend`: a TPU; the tests' word
 selects the interpret machine) and the shapes fit its tiles: float32 or
 bfloat16, heads 64 wide in a multiple of 8, a state of 128 or 256, a chunk
 of 128 or 256 tokens (granite's cell: 64 heads of 64, state 128, chunk
@@ -72,7 +72,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import perfvars
-from . import ring
+from ..xla import choice, sel_scan_kernels, ssm_kernels
 
 STATES = "ssm_chunk_states"     # what the backward pass keeps of `_chunked`
 
@@ -102,18 +102,17 @@ def scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
     t = x.shape[1]
     length = min(chunk, t)
     pad = -t % length
-    perfvars.note_scan_lowering("padded" if pad else "chunked")
+    perfvars.note("scan_lowerings", "padded" if pad else "chunked")
 
     def filled(v):      # up to the next multiple, with tokens of zeros
         widths = ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)
         return jnp.pad(v, widths) if pad else v
-    if scan_kernel_selected(x.shape, x.dtype, b.shape[-1], length):
-        from ..xla import ssm_kernels
-        perfvars.note_scan_kernel_lowering("kernel")
+    run = choice.decide(choice.SCAN, *x.shape[2:], b.shape[-1], length,
+                        x.dtype)
+    if run:
         return ssm_kernels.ssm_scan(
             filled(x), filled(dt), a, filled(b), filled(c), d, length=length,
-            interpret=ring._kernel_backend() == "interpret")[:, :t]
-    perfvars.note_scan_kernel_lowering("plain")
+            interpret=run.interpret)[:, :t]
     y = _chunked(filled(x), filled(dt), a, filled(b), filled(c),
                  length)[:, :t]
     return (y + x.astype(jnp.float32) * d[:, None]).astype(x.dtype)
@@ -122,14 +121,10 @@ def scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
 def scan_kernel_selected(shape: tuple, dtype, state: int, length: int) -> bool:
     """Whether :func:`scan` runs the Pallas kernel pair for x of ``shape``
     [batch, t, heads, width] in chunks of ``length`` over a state of
-    ``state``: decided from the backend (`ring._kernel_backend`) and the
-    kernel's contract (`ssm_kernels.ssm_scan_selected`), never by trying it:
-    once selected, a kernel that does not lower is an error."""
-    if ring._kernel_backend() is None:
-        return False
-    from ..xla import ssm_kernels
-    return ssm_kernels.ssm_scan_selected(shape[2], shape[3], state, length,
-                                         jnp.dtype(dtype))
+    ``state``: `xla.choice`'s rule over the kernel's contract,
+    `ssm_kernels.ssm_scan_selected`."""
+    return choice.fit(choice.SCAN, *shape[2:], state, length,
+                      dtype) is not None
 
 
 @functools.partial(
@@ -204,15 +199,12 @@ def selective_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     t = x.shape[1]
     length = min(chunk, t)
     pad = -t % length
-    perfvars.note_sel_scan_lowering("padded" if pad else "chunked")
+    perfvars.note("sel_scan_lowerings", "padded" if pad else "chunked")
 
-    if sel_scan_kernel_selected(x.shape, x.dtype, a.shape[-1]):
-        from ..xla import sel_scan_kernels
-        perfvars.note_sel_scan_kernel_lowering("kernel")
-        return sel_scan_kernels.sel_scan(
-            x, dt, a, b, c, d,
-            interpret=ring._kernel_backend() == "interpret")
-    perfvars.note_sel_scan_kernel_lowering("plain")
+    run = choice.decide(choice.SEL_SCAN, x.shape[2], a.shape[-1], x.dtype)
+    if run:
+        return sel_scan_kernels.sel_scan(x, dt, a, b, c, d,
+                                         interpret=run.interpret)
 
     def filled(v):
         return jnp.pad(v, ((0, 0), (0, pad), (0, 0))) if pad else v
@@ -223,15 +215,9 @@ def selective_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
 
 def sel_scan_kernel_selected(shape: tuple, dtype, state: int) -> bool:
     """Whether :func:`selective_scan` runs the Pallas kernel pair for x of
-    ``shape`` [batch, t, channels] over a state of ``state``: decided from
-    the backend (`ring._kernel_backend`) and the kernel's contract
-    (`sel_scan_kernels.sel_scan_selected`), never by trying it: once
-    selected, a kernel that does not lower is an error."""
-    if ring._kernel_backend() is None:
-        return False
-    from ..xla import sel_scan_kernels
-    return sel_scan_kernels.sel_scan_selected(shape[2], state,
-                                              jnp.dtype(dtype))
+    ``shape`` [batch, t, channels] over a state of ``state``: `xla.choice`'s
+    rule over the kernel's contract, `sel_scan_kernels.sel_scan_selected`."""
+    return choice.fit(choice.SEL_SCAN, shape[2], state, dtype) is not None
 
 
 def _selective_chunks(x, dt, a, b, c, length: int):

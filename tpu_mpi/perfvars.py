@@ -554,184 +554,76 @@ def arming_seconds() -> float:
     return total
 
 
-# -- which attention a traced program got ------------------------------------
+# -- what the traced programs hold ---------------------------------------------
 #
-# `parallel.ring.local_attention` chooses between the fused kernel and the
-# plain einsum path while a program is traced; each call counts here, so a
-# run can say which one its compiled steps hold.
+# While a program is traced the model chooses: a kernel or its plain path
+# (`xla/choice.py`, the one rule), a form, a layer's mixer. Each choice is
+# one :func:`note` where it is made, so a run can say what its compiled
+# steps hold. The families are data: a line here is all this module knows
+# of a mechanism. A tuple: the kinds a snapshot shows at 0 before anything
+# is traced (a kind outside it appears once it is traced). A function: the
+# family is noted under keys of its own and shown as the function makes it.
 
-_attn_lowerings = {"fused": 0, "plain": 0}
-# the same calls by the attention's kind, for a model whose layers differ:
-# {"full" | "window" | "latent": {"fused": n, "plain": n}}
-_attn_by_kind: Dict[str, Dict[str, int]] = {}
+def _how_by_kind(counts: dict) -> dict:
+    """{kind of attention: "fused" | "plain" | "mixed"} from the counts of
+    (kind, how) pairs."""
+    hows: Dict[str, set] = {}
+    for kind, how in counts:
+        hows.setdefault(kind, set()).add(how)
+    return {kind: how.pop() if len(how) == 1 else "mixed"
+            for kind, how in sorted(hows.items())}
 
 
-def note_attn_lowering(kind: str, window: int = 0,
-                       latent: bool = False) -> None:
-    """One attention call was built into a traced program as the ``fused``
-    kernel or as the ``plain`` path; ``window`` > 0 says it was a windowed
-    one, ``latent`` one whose scores are the sum of two products (latent
-    attention's unrotated and rotated parts)."""
+FAMILIES: Dict[str, Any] = {
+    # `parallel.ring.local_attention`: the fused kernel or the einsum path
+    "attn_lowerings": ("fused", "plain"),
+    # the same calls as (kind, how): "full" | "window" | "latent", and
+    # "diff" beside them (`models.transformer._diff_attn`)
+    "attn_kinds": _how_by_kind,
+    # `parallel.ep.grouped_products`, one count a product
+    "gmm_lowerings": ("kernel", "ragged_dot"),
+    # `parallel.ep.sum_rows` / `rows_at`: the product on the MXU or XLA's
+    # scatter-add
+    "row_sum_lowerings": ("product", "scatter"),
+    # `models.transformer._rope` and the two rotation kernels: x cos +
+    # swap(x) sin with its own backward, or two half-width products
+    "rope_forms": ("dense", "halves"),
+    # `_attn_ffn_block`, one count a trace of a layer kind; `mamba`, `gmu`
+    # and `cross` appear once traced
+    "mixer_kinds": ("attention", "ssm"),
+    # `_side_read`: the layers that read a value written beside the stream
+    "side_values": ("memory", "kv"),
+    # `parallel.ssm.scan`: its form (`padded`: filled up to whole chunks)
+    "scan_lowerings": ("chunked", "padded"),
+    # and who computes it: `xla/ssm_kernels.py` or `parallel.ssm._chunked`
+    "scan_kernel_lowerings": ("kernel", "plain"),
+    # `parallel.ssm.selective_scan` likewise: its form
+    "sel_scan_lowerings": ("chunked", "padded"),
+    # and who: `xla/sel_scan_kernels.py` or `_selective_chunks`
+    "sel_scan_kernel_lowerings": ("kernel", "plain"),
+    # `models.transformer.head_loss` over blocks of tokens, or `_xent` of
+    # the whole logits (the two pipelined steps), one count a traced loss
+    "head_loss_lowerings": ("blocked", "whole"),
+    # the traced `blocked` losses by their number of blocks
+    "head_loss_blocks": lambda counts: {str(n): c
+                                        for n, c in sorted(counts.items())},
+}
+
+
+def _no_counts() -> Dict[str, dict]:
+    return {family: dict.fromkeys(kinds, 0) if isinstance(kinds, tuple)
+            else {} for family, kinds in FAMILIES.items()}
+
+
+_counts = _no_counts()
+
+
+def note(family: str, kind: Any, n: int = 1) -> None:
+    """``n`` more of ``kind`` were built into a traced program, in
+    ``family`` of :data:`FAMILIES`."""
     with _store_lock:
-        _attn_lowerings[kind] += 1
-        by = _attn_by_kind.setdefault(
-            "latent" if latent else "window" if window else "full",
-            {"fused": 0, "plain": 0})
-        by[kind] += 1
-
-
-def note_attn_kind(kind: str, how: str) -> None:
-    """One attention call of ``kind`` (beside what `note_attn_lowering`
-    files it under: "diff", differential attention's) was built ``fused`` or
-    ``plain``; `attn_lowerings` has counted the call already."""
-    with _store_lock:
-        _attn_by_kind.setdefault(kind, {"fused": 0, "plain": 0})[how] += 1
-
-
-def _attn_kinds() -> dict:
-    """{"full" | "window" | "latent": "fused" | "plain" | "mixed"} of the attention
-    kinds traced so far (caller holds the store lock)."""
-    return {k: ("fused" if not n["plain"] else "plain" if not n["fused"]
-                else "mixed") for k, n in sorted(_attn_by_kind.items())}
-
-
-# `parallel.ep.grouped_products` likewise: the grouped Pallas kernel or
-# `lax.ragged_dot`, one count per product where the selection is made.
-
-_gmm_lowerings = {"kernel": 0, "ragged_dot": 0}
-
-
-def note_gmm_lowering(kind: str) -> None:
-    """One grouped multiplication was traced as the ``kernel`` or as
-    ``ragged_dot``."""
-    with _store_lock:
-        _gmm_lowerings[kind] += 1
-
-
-# `parallel.ep.sum_rows` / `rows_at` likewise: rows summed into indexed places
-# (a held expert layer's combine, the transpose of its dispatch gather, the
-# embedding's gradient) as the product on the MXU or as XLA's scatter-add,
-# one count per call where the selection is made.
-
-_row_sum_lowerings = {"product": 0, "scatter": 0}
-
-
-def note_row_sum_lowering(kind: str) -> None:
-    """One sum of rows into places (or the gather whose gradient it is) was
-    traced as the ``product`` or as the ``scatter``."""
-    with _store_lock:
-        _row_sum_lowerings[kind] += 1
-
-
-# `models.transformer._rope` / `_rope_heads` / `_norm_and_rope` likewise: each
-# rotary embedding built into a traced program, by its form: `dense` (x cos +
-# swap(x) sin with its own backward: a kernel where one is selected, the
-# same arithmetic as plain `jnp` elsewhere) or `halves` (two half-width
-# products concatenated, differentiated by autodiff: a head of odd width).
-
-_rope_forms = {"dense": 0, "halves": 0}
-
-
-def note_rope_form(form: str) -> None:
-    """One rotary embedding was traced in the ``dense`` or the ``halves``
-    form."""
-    with _store_lock:
-        _rope_forms[form] += 1
-
-
-# `models.transformer._attn_ffn_block` likewise: the mixer of each layer
-# built into a traced program, `attention` or `ssm` (a state-space layer),
-# one count per trace of a layer kind; and `parallel.ssm.scan`: the form the
-# state-space scan took, `chunked`, or `padded` where the sequence is no
-# multiple of the chunk and is filled up to one.
-
-_mixer_kinds = {"attention": 0, "ssm": 0}     # a later kind (`mamba`, `gmu`,
-#                                               `cross`) appears once traced
-# `models.transformer._side_read`: the layers of each traced program that
-# read a value another layer wrote beside the residual stream
-_side_values = {"memory": 0, "kv": 0}
-_scan_lowerings = {"chunked": 0, "padded": 0}
-
-
-def note_mixer_kind(kind: str) -> None:
-    """One layer was traced with ``attention``, a state-space scan (``ssm``:
-    Mamba-2; ``mamba``: Mamba-1), a gated memory unit (``gmu``) or ``cross``
-    attention as its mixer."""
-    with _store_lock:
-        _mixer_kinds[kind] = _mixer_kinds.get(kind, 0) + 1
-
-
-def note_side_value(name: str) -> None:
-    """One layer was traced reading the ``memory`` (a gated memory unit) or
-    the shared ``kv`` (a cross-attention layer)."""
-    with _store_lock:
-        _side_values[name] += 1
-
-
-def note_scan_lowering(form: str) -> None:
-    """One state-space scan was traced in the ``chunked`` form, or in it
-    after the sequence was ``padded`` to a multiple of the chunk."""
-    with _store_lock:
-        _scan_lowerings[form] += 1
-
-
-# `parallel.ssm.selective_scan` (the per-channel, per-state recurrence of a
-# Mamba-1 layer) likewise, by the same two forms.
-
-_sel_scan_lowerings = {"chunked": 0, "padded": 0}
-
-
-def note_sel_scan_lowering(form: str) -> None:
-    """One selective scan was traced in the ``chunked`` form, or in it after
-    the sequence was ``padded`` to a multiple of the chunk."""
-    with _store_lock:
-        _sel_scan_lowerings[form] += 1
-
-
-# and who computes that form: the Pallas kernel pair of `xla/ssm_kernels.py`
-# or the plain `jnp` of `parallel.ssm._chunked`, one count a traced scan.
-
-_scan_kernel_lowerings = {"kernel": 0, "plain": 0}
-
-
-def note_scan_kernel_lowering(kind: str) -> None:
-    """One state-space scan was traced as the ``kernel`` or as ``plain``
-    `jnp`."""
-    with _store_lock:
-        _scan_kernel_lowerings[kind] += 1
-
-
-# and who computes a selective scan: the Pallas kernel pair of
-# `xla/sel_scan_kernels.py` or the plain `lax.scan`s of
-# `parallel.ssm._selective_chunks`, one count a traced scan.
-
-_sel_scan_kernel_lowerings = {"kernel": 0, "plain": 0}
-
-
-def note_sel_scan_kernel_lowering(kind: str) -> None:
-    """One selective scan was traced as the ``kernel`` or as ``plain``
-    `jnp`."""
-    with _store_lock:
-        _sel_scan_kernel_lowerings[kind] += 1
-
-
-# `models.transformer.head_loss` (the vocabulary head and its cross-entropy
-# over blocks of tokens, both gradients made in the forward pass) against
-# the ``whole`` float32 logits differentiated by JAX (`_xent`: the two
-# pipelined steps), one count a traced loss, and the traced ``blocked``
-# losses by their number of blocks.
-
-_head_loss_lowerings = {"blocked": 0, "whole": 0}
-_head_loss_blocks: Dict[int, int] = {}
-
-
-def note_head_loss_lowering(kind: str, blocks: int = 1) -> None:
-    """One head and loss was traced ``blocked``, over ``blocks`` blocks of
-    tokens, or over the ``whole`` logits."""
-    with _store_lock:
-        _head_loss_lowerings[kind] += 1
-        if kind == "blocked":
-            _head_loss_blocks[blocks] = _head_loss_blocks.get(blocks, 0) + 1
+        counts = _counts[family]
+        counts[kind] = counts.get(kind, 0) + n
 
 
 # -- build: what JAX traced, lowered, compiled and read from its cache --------
@@ -1409,6 +1301,9 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
         keys = [k for k in sorted(_store, key=lambda k: (k[0], str(k[1])))
                 if rank is None or k[0] == rank]
         comms = [_store[k].snapshot() for k in keys]
+        traced = {family: dict(_counts[family]) if isinstance(kinds, tuple)
+                  else kinds(_counts[family])
+                  for family, kinds in FAMILIES.items()}
         if reset:
             for k in keys:
                 del _store[k]
@@ -1417,20 +1312,7 @@ def snapshot(rank: Optional[int] = None, reset: bool = False) -> dict:
             "topology": _topology_stamp(),
             "comms": comms, "plan_cache": plans.stats(),
             "arming_s": arming_seconds(),
-            "attn_lowerings": dict(_attn_lowerings),
-            "attn_kinds": _attn_kinds(),
-            "gmm_lowerings": dict(_gmm_lowerings),
-            "row_sum_lowerings": dict(_row_sum_lowerings),
-            "rope_forms": dict(_rope_forms),
-            "mixer_kinds": dict(_mixer_kinds),
-            "side_values": dict(_side_values),
-            "scan_lowerings": dict(_scan_lowerings),
-            "scan_kernel_lowerings": dict(_scan_kernel_lowerings),
-            "sel_scan_lowerings": dict(_sel_scan_lowerings),
-            "sel_scan_kernel_lowerings": dict(_sel_scan_kernel_lowerings),
-            "head_loss_lowerings": dict(_head_loss_lowerings),
-            "head_loss_blocks": {str(n): c for n, c
-                                 in sorted(_head_loss_blocks.items())},
+            **traced,
             "build": build_snapshot(),
             "infer": infer_snapshot(), "train": train_snapshot(),
             "elastic": elastic_snapshot(),
@@ -1472,20 +1354,7 @@ def reset() -> None:
         _front_door_gauges.clear()
         _locks.clear()
         _arming.clear()
-        _attn_lowerings.update(fused=0, plain=0)
-        _attn_by_kind.clear()
-        _gmm_lowerings.update(kernel=0, ragged_dot=0)
-        _row_sum_lowerings.update(product=0, scatter=0)
-        _rope_forms.update(dense=0, halves=0)
-        _mixer_kinds.clear()
-        _mixer_kinds.update(attention=0, ssm=0)
-        _side_values.update(memory=0, kv=0)
-        _scan_lowerings.update(chunked=0, padded=0)
-        _scan_kernel_lowerings.update(kernel=0, plain=0)
-        _sel_scan_lowerings.update(chunked=0, padded=0)
-        _sel_scan_kernel_lowerings.update(kernel=0, plain=0)
-        _head_loss_lowerings.update(blocked=0, whole=0)
-        _head_loss_blocks.clear()
+        _counts.update(_no_counts())
         _build_total[:] = [0, 0.0, 0, 0.0, 0, 0.0]
         _build_cache.update(hits=0, misses=0, load_s=0.0, saved_s=0.0)
         _build_by_fun.clear()

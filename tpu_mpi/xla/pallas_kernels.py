@@ -61,6 +61,25 @@ def _sublane(dtype) -> int:
     return SUBLANE * max(1, 4 // np.dtype(dtype).itemsize)
 
 
+# Operand types the model's kernels take (part of every contract below and
+# in the two scan files): the ones Mosaic compiles for the v5e. One listed
+# here that fails to lower is an error, not a fallback.
+KERNEL_DTYPES = frozenset({"float32", "bfloat16"})
+
+
+def _typed(dtype) -> int:
+    """What a contract makes of its ``dtype``: the bytes of an element where
+    it is one of :data:`KERNEL_DTYPES` (anything numpy makes a dtype of), 0
+    where it is another. None, or an int (the bytes alone), asks about the
+    shapes alone and comes back as it is, None as 1: a kernel's own entry
+    asks so (the interpret machine runs any type), and who sizes a workload."""
+    import numpy as np
+    if dtype is None or isinstance(dtype, int):
+        return dtype or 1
+    dtype = np.dtype(dtype)
+    return dtype.itemsize if str(dtype) in KERNEL_DTYPES else 0
+
+
 def _pl():
     from jax.experimental import pallas as pl
     return pl
@@ -711,25 +730,24 @@ def ring_attention(q, k, v, *, axis: str = "x", causal: bool = False,
 # attention: no [b, h, t, t] tensor reaches HBM, forward or backward)
 # ---------------------------------------------------------------------------
 
-# Operand types the kernel is selected for (parallel.ring.local_attention):
-# the ones Mosaic compiles for the v5e. One listed here that fails to lower
-# is an error, not a fallback.
-ATTN_DTYPES = frozenset({"float32", "bfloat16"})
 _ATTN_BLOCKS = (512, 256, 128)      # widest first; all multiples of LANE
 _MASKED = -1e30     # the plain path's value for a future key
 
 
-def causal_attention_blocks(t: int, dh: int, rope: int = 0,
-                            dv: int = 0) -> Optional[tuple]:
+def causal_attention_blocks(t: int, dh: int, rope: int = 0, dv: int = 0,
+                            dtype=None) -> Optional[tuple]:
     """(query block, key block) of the kernel for sequence length ``t`` and
-    head dimension ``dh``, or None where its contract does not hold: ``t`` a
-    multiple of a block, ``dh`` 64 or a multiple of 128 (a head is the MXU's
-    contraction and the minor dimension of every operand block), and the
-    backward pass's working set, which holds one head's whole dq, inside
+    head dimension ``dh``, or None where its contract does not hold:
+    operands of a kernel's type (``dtype``: :func:`_typed`), ``t`` a multiple
+    of a block, ``dh`` 64 or a multiple of 128 (a head is the MXU's contraction
+    and the minor dimension of every operand block), and the backward
+    pass's working set, which holds one head's whole dq, inside
     :data:`VMEM_LIMIT_BYTES`. A windowed call takes the same blocks (sweep:
     PERF.md). ``rope`` > 0: the scores' second term is that wide, and ``dv``
     > 0 the values are (0: ``dh``); each is held to ``dh``'s rule, so the
     contract knows the triple (128 + 64, 128) as it stands."""
+    if not _typed(dtype):
+        return None
     if any(w != 64 and w % LANE for w in (dh, rope or dh, dv or dh)):
         return None
     block = next((b for b in _ATTN_BLOCKS if t % b == 0), None)
@@ -1319,9 +1337,6 @@ def causal_attention(q, k, v, *, window: int = 0, rope: Optional[tuple] = None,
 # no weight transposed in HBM)
 # ---------------------------------------------------------------------------
 
-# Operand types the kernel is selected for (parallel.ep.grouped_products), as
-# ATTN_DTYPES above: one listed here that fails to lower is an error.
-GROUPED_DTYPES = frozenset({"float32", "bfloat16"})
 _GROUPED_ROW_TILES = (512, 256, 128)    # widest first
 _GROUPED_SLICE_ROWS = 128   # what a tile shared by groups is multiplied in
 # column tiles, widest first: a dimension no wider than one is taken whole
@@ -1341,18 +1356,19 @@ def _grouped_vmem(tm: int, k: int, n: int, tc: int, itemsize: int) -> int:
     return max(fwd, dlhs, drhs)
 
 
-def grouped_matmul_blocks(m: int, k: int, n: int,
-                          itemsize: int) -> Optional[tuple]:
+def grouped_matmul_blocks(m: int, k: int, n: int, dtype) -> Optional[tuple]:
     """(row tile, column tile) of the grouped kernels for ``[m, k]`` rows and
-    ``[g, k, n]`` matrices of ``itemsize`` bytes an element, or None where
-    their contract does not hold: ``m`` a multiple of a row tile, ``k`` and
-    ``n`` multiples of 128 (each is a contraction in one of the three
-    products and a block's minor dimension in another), and the blocks of
-    all three inside :data:`VMEM_LIMIT_BYTES` with the headroom
+    ``[g, k, n]`` matrices of ``dtype``, or None where their contract does
+    not hold: a kernel's type (:func:`_typed`), ``m`` a multiple of a row
+    tile,
+    ``k`` and ``n`` multiples of 128 (each is a contraction in one of the
+    three products and a block's minor dimension in another), and the blocks
+    of all three inside :data:`VMEM_LIMIT_BYTES` with the headroom
     :func:`_compiler_params` asks for. The contraction is always taken
     whole, so a group's matrix stays in VMEM over the group's row tiles; the
     other dimension is cut to the column tile only where VMEM forces it."""
-    if k % LANE or n % LANE or min(m, k, n) <= 0:
+    itemsize = _typed(dtype)
+    if not itemsize or k % LANE or n % LANE or min(m, k, n) <= 0:
         return None
     tm = next((t for t in _GROUPED_ROW_TILES if m % t == 0), None)
     if tm is None:
@@ -1755,15 +1771,15 @@ def grouped_matmul(lhs, rhs, group_sizes, *, visits=None,
 _ROW_SUM_COL_TILES = tuple(range(2048, 0, -LANE))
 
 
-def grouped_row_sums_blocks(m: int, n: int,
-                            itemsize: int) -> Optional[tuple]:
+def grouped_row_sums_blocks(m: int, n: int, dtype) -> Optional[tuple]:
     """(row tile, column tile) of :func:`grouped_row_sums` for ``[m, 128]``
-    times ``[m, n]`` operands of ``itemsize`` bytes an element, or None
-    where the kernel's contract does not hold (``m`` a multiple of a row
-    tile, ``n`` of 128, the blocks in VMEM as
-    :func:`grouped_matmul_blocks` counts them)."""
+    times ``[m, n]`` operands of ``dtype``, or None where the kernel's
+    contract does not hold (a kernel's type, as :func:`grouped_matmul_blocks`
+    takes it; ``m`` a multiple of a row tile, ``n`` of 128, the blocks in
+    VMEM as it counts them)."""
     tm = next((t for t in _GROUPED_ROW_TILES if m % t == 0), None)
-    if n % LANE or min(m, n) <= 0 or tm is None:
+    itemsize = _typed(dtype)
+    if not itemsize or n % LANE or min(m, n) <= 0 or tm is None:
         return None
     tc = next((c for c in _ROW_SUM_COL_TILES
                if n % c == 0 and 2 * _grouped_vmem(tm, LANE, n, c, itemsize)
@@ -1823,9 +1839,6 @@ def grouped_row_sums(lhs, rows, group_sizes, *, out_dtype=None,
 # between a projection's product and the attention kernel: one pass each way)
 # ---------------------------------------------------------------------------
 
-# Operand types these kernels are selected for (models.transformer's
-# `_rope_heads` and `_norm_and_rope`), as ATTN_DTYPES above.
-ROPE_DTYPES = frozenset({"float32", "bfloat16"})
 _ROPE_SLOT = LANE // 2          # parts are placed and moved in 64-lane slots
 _ROPE_ROW_TILES = (512, 256, 128)       # tokens a block, widest first
 _ROPE_BLOCK_LANES = 1536        # a block's share of a row, at most
@@ -1867,14 +1880,17 @@ def rope_heads_lanes(parts: tuple):
         for _g, p, j in rope_heads_plan(parts)[2]])
 
 
-def rope_heads_blocks(t: int, heads: int, parts: tuple) -> Optional[tuple]:
+def rope_heads_blocks(t: int, heads: int, parts: tuple,
+                      dtype=None) -> Optional[tuple]:
     """(tokens a block, groups a block) of :func:`rope_heads` for ``heads``
-    heads of ``parts`` over ``t`` tokens, or None where its contract does not
-    hold (:func:`rope_heads_plan`'s, ``t`` a multiple of a row tile, whole
-    groups of heads)."""
+    heads of ``parts`` of ``dtype`` over ``t`` tokens, or None where its
+    contract does not hold (a kernel's type: :func:`_typed`;
+    :func:`rope_heads_plan`'s, ``t`` a multiple of a row tile, whole groups
+    of heads)."""
     plan = rope_heads_plan(tuple(parts))
     tt = next((r for r in _ROPE_ROW_TILES if t % r == 0), None)
-    if plan is None or tt is None or heads % plan[1]:
+    if not _typed(dtype) or plan is None or tt is None \
+            or heads % plan[1]:
         return None
     groups = heads // plan[1]
     m = max(m for m in range(1, groups + 1)
@@ -2087,15 +2103,17 @@ def rope_heads(row, cos, sin, heads: int, parts: Sequence[tuple], *,
 # a norm stands between the projection and the rotation: one pass each way)
 # ---------------------------------------------------------------------------
 
-def norm_rope_blocks(n: int, t: int, width: int, itemsize: int,
+def norm_rope_blocks(n: int, t: int, width: int, dtype,
                      together: int = 0) -> Optional[tuple]:
     """(tokens a block, heads a block) of :func:`norm_rope` for ``n`` heads
-    of ``width`` over ``t`` tokens, or None where its contract does not hold
-    (``width`` 128: a head is a tile's lanes; ``t`` a multiple of a row
-    tile). ``together`` > 0: that many heads (one token's: the norm sums
-    over them) stand in one block."""
+    of ``width`` and ``dtype`` over ``t`` tokens, or None where its contract
+    does not hold (a kernel's type: :func:`_typed`; ``width`` 128: a head is
+    a tile's lanes; ``t`` a multiple of a row tile). ``together`` > 0: that
+    many heads (one token's: the norm sums over them) stand in one block."""
     tt = next((r for r in _ROPE_ROW_TILES if t % r == 0), None)
-    if width != LANE or tt is None or n <= 0 or (together and n % together):
+    itemsize = _typed(dtype)
+    if not itemsize or width != LANE or tt is None or n <= 0 \
+            or (together and n % together):
         return None
     if together:
         while tt > _ROPE_ROW_TILES[-1] and \

@@ -49,9 +49,9 @@ from typing import Optional
 
 from .. import perfvars
 from .pallas_kernels import (LANE, SUBLANE, _compiler_params, _interpret,
-                             _pl, _pltpu, _vary_together, _varying_like)
+                             _pl, _pltpu, _typed, _vary_together,
+                             _varying_like)
 
-SEL_DTYPES = frozenset({"float32", "bfloat16"})
 SEL_TILE = 512          # channels a grid step: four vregs of lanes
 _SEL_BLOCKS = (256, 128)    # tokens a grid step, the largest that divides
 SEL_STATE = 16          # a Mamba-1 layer's: eight tokens' dB are one 128 x
@@ -60,9 +60,10 @@ _UNROLL = 8             # tokens of the inner loops laid out in one body
 
 
 def sel_scan_selected(channels: int, state: int, dtype) -> bool:
-    """Whether :func:`sel_scan` takes ``channels`` channels over a state of
-    ``state``: the contract, decided from the shapes."""
-    return (str(dtype) in SEL_DTYPES and channels % SEL_TILE == 0
+    """Whether :func:`sel_scan` takes ``channels`` channels of ``dtype`` over
+    a state of ``state``: the contract, decided from the shapes and the
+    type."""
+    return (bool(_typed(dtype)) and channels % SEL_TILE == 0
             and state == SEL_STATE)
 
 

@@ -41,10 +41,9 @@ from typing import Optional
 
 from .. import perfvars
 from .pallas_kernels import (LANE, _attn_precision, _compiler_params,
-                             _interpret, _pl, _pltpu, _vary_together,
+                             _interpret, _pl, _pltpu, _typed, _vary_together,
                              _varying_like)
 
-SCAN_DTYPES = frozenset({"float32", "bfloat16"})
 SCAN_HEAD_WIDTH = LANE // 2     # two heads to a tile of lanes
 SCAN_HEAD_BLOCK = 8             # heads a grid step: a float32 tile's sublanes
 _SCAN_LENGTHS = (128, 256)      # a chunk: one or two tiles of the diagonal
@@ -54,9 +53,9 @@ _SCAN_STATES = (128, 256)       # beyond these Mosaic's own VMEM is not counted
 def ssm_scan_selected(heads: int, width: int, state: int, length: int,
                       dtype) -> bool:
     """Whether :func:`ssm_scan` takes chunks of ``length`` tokens for
-    ``heads`` heads of ``width`` over a state of ``state``: the contract,
-    decided from the shapes."""
-    return (str(dtype) in SCAN_DTYPES and width == SCAN_HEAD_WIDTH
+    ``heads`` heads of ``width`` and ``dtype`` over a state of ``state``:
+    the contract, decided from the shapes and the type."""
+    return (bool(_typed(dtype)) and width == SCAN_HEAD_WIDTH
             and heads % SCAN_HEAD_BLOCK == 0 and state in _SCAN_STATES
             and length in _SCAN_LENGTHS)
 
