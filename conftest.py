@@ -35,6 +35,17 @@ import pytest
 # the cell reports exactly its twenty-four, `sorted(names) == sorted(...)`,
 # is falsified in its turn; every name it lists is asserted again, as a
 # subset, in `yardstick/tests/test_sel_scan_kernel_share.py`.)
+# (PR 45 appends a seventh train cell to `blocked_head_share`'s and
+# `train_tokens_per_s`'s lists: PR 43's two lines that those lists ARE its
+# six cells, `spec == {..., "workloads": TRAIN_CELLS}` and `(NAME in names)
+# == (w["name"] in TRAIN_CELLS)`, are falsified in their turn; the entry,
+# the six cells in it and which cells report it are asserted again, by name
+# and as a subset, in `yardstick/tests/test_lm_gdn_train_step.py`. `per_layer`
+# is held to 128 entries and had 123, so that cell reads the accepted
+# readers under the accepted entries, its name appended to their lists:
+# PR 34's line that the six build entries' lists ARE its cells,
+# `by_name[name] == {..., "workloads": cells}`, is falsified too, and is
+# asserted again in the same file, the cells as a subset.)
 LAST_ENTRIES_TESTS = (
     "yardstick/tests/test_lm_kinds_train_step.py::"
     "test_the_accepted_metrics_stand",
@@ -49,7 +60,13 @@ LAST_ENTRIES_TESTS = (
     "yardstick/tests/test_lm_ssm_train_step.py::"
     "test_the_cell_reports_what_the_issue_names",
     "yardstick/tests/test_lm_sambay_train_step.py::"
-    "test_the_cell_reports_what_the_issue_names")
+    "test_the_cell_reports_what_the_issue_names",
+    "yardstick/tests/test_blocked_head_share.py::"
+    "test_the_entry_by_name",
+    "yardstick/tests/test_blocked_head_share.py::"
+    "test_the_six_train_cells_report_it_and_no_other_cell_does",
+    "yardstick/tests/test_build_metrics.py::"
+    "test_the_six_entries_by_name_and_content")
 
 
 def pytest_collection_modifyitems(items):

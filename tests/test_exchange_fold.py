@@ -794,6 +794,89 @@ def test_compiled_for_the_v5e_the_state_space_step_fits_one_chip(
     tf._block_traced_once.cache_clear()
 
 
+def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
+        v5e_2x2, kernel_backend):
+    """The benchmark's Qwen3-Next-80B-A3B share (yardstick/configs/
+    qwen3-next-80b-a3b-1c.json: published widths, layers 0-7, six delta-rule
+    layers and two gated attention layers at head 256, 64 of 512 experts and
+    an eighth of the vocabulary, batch 1 x 8192, parameters donated)
+    compiled for one described v5e chip with the kernels selected as on a
+    TPU: 1.979 B parameters, at most 14.9 GB with every FFN half recomputed
+    (PR 45: 13.73 GB; 15.46 while the compiler kept float32 copies of z, q,
+    k and the gated output and the convolution's outputs were kept, and
+    20.18 with nothing recomputed); no [.., t, t] buffer: the fused
+    attention kernel at (8192, 256) forward and backward in layers 3 and 7
+    alone; the six scopes of a delta-rule mixer in the other six; one trace
+    of the block a mixer kind; the experts' products on the grouped kernel;
+    and nothing the compiler chose to compute again to fit."""
+    import json
+    import os
+    import re
+    kernel_backend("mosaic")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from tpu_mpi import xla
+    from tpu_mpi.models import transformer as tf
+    from tpu_mpi.models.transformer import (TransformerConfig,
+                                            transformer_init,
+                                            transformer_train_step)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "yardstick", "configs",
+                           "qwen3-next-80b-a3b-1c.json")) as f:
+        conf = json.load(f)
+    fields = dict(conf["model"], max_seq=8192)
+    fields["dtype"] = jnp.dtype(fields["dtype"])
+    cfg = TransformerConfig(**fields)
+    mesh = xla.make_mesh(dict(conf["mesh"]), devices=v5e_2x2[:1])
+    tf._block_traced_once.cache_clear()
+    step, specs = transformer_train_step(cfg, mesh, lr=conf["lr"], donate=True)
+    shapes = jax.eval_shape(lambda k: transformer_init(k, cfg),
+                            jax.random.key(0))
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 1_978_847_360
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        shapes, specs)
+    tok = jax.ShapeDtypeStruct((1, 8192), jnp.int32,
+                               sharding=NamedSharding(mesh, P("dp", "sp")))
+    lowered = step.lower(params, tok, tok)
+    assert tf._block_traced_once.cache_info().currsize == 2
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert m.alias_size_in_bytes > 3.9e9        # the parameters are reused
+    assert 12e9 < held <= 14.9e9, held
+    hlo = compiled.as_text()
+    assert not re.search(r"\[\d+,\d+,8192,8192\]", hlo)     # no [.., t, t]
+    assert ".remat" not in hlo          # the compiler recomputes nothing
+    calls = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*causal_attention_(\w+)[^\"]*)\"", hlo)
+    assert sorted((d, int(re.search(r"layer_(\d+)", name).group(1)))
+                  for name, d in calls) == [("bwd", 3), ("bwd", 7),
+                                            ("fwd", 3), ("fwd", 7)]
+    assert all("/attn/" in name for name, _d in calls)
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for i in (0, 2, 4, 6):
+        for scope in ("in_proj", "conv", "prep", "scan", "gate_norm",
+                      "out_proj"):
+            assert [n for n in names if f"layer_{i})" in n and "/mixer/" in n
+                    and f"/{scope}/" in n], (i, scope)
+        assert not [n for n in names if f"layer_{i})" in n and "/attn/" in n]
+    for i in (3, 7):
+        assert not [n for n in names if f"layer_{i})" in n and "/mixer/" in n]
+        for scope in ("qk_norm", "out_gate"):
+            assert [n for n in names if f"layer_{i})" in n
+                    and f"/attn/{scope}/" in n], (i, scope)
+    assert "ragged-dot" not in hlo
+    kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
+    assert sorted(set(kernels)) == [
+        "causal_attention_bwd", "causal_attention_fwd", "grouped_matmul_dlhs",
+        "grouped_matmul_drhs", "grouped_matmul_fwd", "grouped_row_sums"]
+    assert kernels.count("causal_attention_fwd") == \
+        kernels.count("causal_attention_bwd") == 1, kernels
+    tf._block_traced_once.cache_clear()
+
+
 def test_compiled_for_the_v5e_the_attention_kernel_with_values_wider_than_scores(
         v5e_2x2):
     """The fused causal attention, forward and backward, at differential

@@ -42,6 +42,7 @@ FRESH = {
     "scan_kernel_lowerings": {"kernel": 0, "plain": 0},
     "sel_scan_lowerings": {"chunked": 0, "padded": 0},
     "sel_scan_kernel_lowerings": {"kernel": 0, "plain": 0},
+    "delta_lowerings": {"chunked": 0, "padded": 0},
     "head_loss_lowerings": {"blocked": 0, "whole": 0},
     "head_loss_blocks": {},
 }

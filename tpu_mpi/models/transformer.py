@@ -119,7 +119,7 @@ class TransformerConfig:
     # first half is `_ssm_mixer` (in-projection, a short causal convolution,
     # the selective scan of `parallel.ssm`, a gated norm, out-projection)
     # in place of attention; its FFN half is the model's.
-    mixer_kinds: tuple = ()     # "attention" | "ssm" a layer; empty: attention
+    mixer_kinds: tuple = ()     # one of `MIXERS` a layer; empty: attention
     ssm_expand: int = 2         # the scan runs ssm_expand x d_model wide, =
     ssm_heads: int = 0          #   ssm_heads heads of
     ssm_head_dim: int = 0       #   ssm_head_dim each, over a state of
@@ -151,6 +151,30 @@ class TransformerConfig:
     residual_multiplier: float = 1.0    # x each half's output, before the add
     logits_divisor: float = 1.0         # logits / this
     attn_scale: float = 0.0     # the scores' scale; 0: head_dim ** -0.5
+    # Linear attention with a gated delta rule: a "gdn" layer's first half is
+    # `_gdn_mixer` (in-projections, a short causal convolution without bias,
+    # L2-normed queries and keys, the scan of `parallel.delta`, a norm a head
+    # and then a gate, out-projection) in place of attention.
+    gdn_key_heads: int = 0      # queries and keys: gdn_key_heads heads of
+    gdn_key_dim: int = 0        #   gdn_key_dim; values and the gate:
+    gdn_value_heads: int = 0    #   gdn_value_heads heads (a multiple) of
+    gdn_value_dim: int = 0      #   gdn_value_dim; value head h reads key
+    #                             head h // (value heads / key heads)
+    gdn_conv: int = 4           # the convolution's taps over q, k and v
+    gdn_chunk: int = 64         # tokens a chunk of the scan, a power of two
+    # What follows is data on the attention and expert halves; each adds no
+    # equation at its default.
+    attn_out_gate: bool = False     # `w_q` is twice as wide, [queries | gate]:
+    #                             the attention's output x sigmoid(gate),
+    #                             before the output projection
+    rotary_dim: int = 0         # > 0: RoPE turns the first rotary_dim values
+    #                             of a head (after its norm) and the rest pass
+    norm_unit_offset: bool = False  # RMSNorm scales by 1 + w, float32 inside
+    #                             and rounded once: the stream's norms and the
+    #                             norms of q and k; w starts near 0, where a
+    #                             bfloat16 step moves it
+    shared_expert_gate: bool = False    # the shared expert's output x
+    #                             sigmoid(y w_shared_sigmoid), one a token
 
     def __post_init__(self):
         for name in ("attn_windows", "ffn_kinds", "experts_held",
@@ -211,7 +235,49 @@ class TransformerConfig:
         if self.attn_bias and not self.diff_attn:
             raise ValueError("attn_bias adds its biases in differential "
                              "attention alone: set diff_attn with it")
+        self._check_gdn_and_gates()
         self._check_side_values()
+
+    def _check_gdn_and_gates(self):
+        """A delta-rule layer has its sizes; a rotated share fits its head;
+        the gates and the norm's offset stand where their equations do."""
+        chunk = self.gdn_chunk
+        if "gdn" in self.mixer_kinds and not (
+                self.gdn_key_heads > 0 and self.gdn_key_dim > 0
+                and self.gdn_value_dim > 0 and self.gdn_conv > 0
+                and self.gdn_value_heads >= self.gdn_key_heads
+                and self.gdn_value_heads % self.gdn_key_heads == 0
+                and chunk > 0 and chunk & (chunk - 1) == 0):
+            raise ValueError(
+                f"delta-rule layers name gdn_key_heads={self.gdn_key_heads} of "
+                f"gdn_key_dim={self.gdn_key_dim}, gdn_value_heads="
+                f"{self.gdn_value_heads} (a multiple of the key heads) of "
+                f"gdn_value_dim={self.gdn_value_dim} and gdn_conv above 0, "
+                f"with gdn_chunk={chunk} a power of two")
+        if self.rotary_dim and (
+                self.rotary_dim % 2 or self.rotary_dim > self.head_dim
+                or self.kv_latent or self.diff_attn
+                or not (self.qk_norm or self.qk_norm_heads)):
+            raise ValueError(
+                f"rotary_dim={self.rotary_dim}: an even share of a head of "
+                f"{self.head_dim}, turned after the norm of q and k (set "
+                f"qk_norm or qk_norm_heads; latent attention has d_rope, "
+                f"differential attention no positions)")
+        if self.attn_out_gate and (self.kv_latent or self.diff_attn
+                                   or not self.n_kv_heads):
+            raise ValueError(
+                "attn_out_gate widens a `w_q` of its own (set n_kv_heads); "
+                "latent and differential attention have no such gate")
+        if self.norm_unit_offset and (
+                self.norm_kind != "rms" or self.norm_out or self.qk_norm
+                or self.kv_latent):
+            raise ValueError(
+                "norm_unit_offset is RMSNorm's, of the stream and of each "
+                "head's q and k: no LayerNorm, sandwich norm, whole-vector "
+                "norm of q and k or latent's norms with it")
+        if self.shared_expert_gate and not self.n_shared_experts:
+            raise ValueError("shared_expert_gate gates a shared expert: set "
+                             "n_shared_experts")
 
     def _check_side_values(self):
         """The layers that write and read the two side values stand where
@@ -261,6 +327,13 @@ class TransformerConfig:
         return self.ssm_heads * self.ssm_head_dim
 
     @property
+    def gdn_widths(self) -> tuple:
+        """(queries' and keys' width, values' and the gate's) of a delta-rule
+        layer, all heads side by side."""
+        return (self.gdn_key_heads * self.gdn_key_dim,
+                self.gdn_value_heads * self.gdn_value_dim)
+
+    @property
     def mamba_inner(self) -> int:
         """A Mamba-1 layer's width, the memory's and a gated memory unit's."""
         return self.ssm_expand * self.d_model
@@ -275,7 +348,7 @@ class TransformerConfig:
                          mixer)
 
 
-MIXERS = ("attention", "ssm", "mamba", "gmu", "cross")
+MIXERS = ("attention", "ssm", "mamba", "gmu", "cross", "gdn")
 
 
 class LayerKind(NamedTuple):
@@ -290,6 +363,13 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
     def dense(key, shape, scale):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(cfg.dtype)
 
+    def scale(key, n):
+        """A norm's scale: ones, or under `norm_unit_offset` the w of 1 + w,
+        drawn near 0 and not at it, so that a dropped offset shows."""
+        if cfg.norm_unit_offset:
+            return dense(key, (n,), 0.02)
+        return jnp.ones((n,), cfg.dtype)
+
     keys = jax.random.split(key, 2 + 4 * cfg.n_layers)
     d = cfg.d_model
     hd = cfg.n_heads * cfg.head_dim         # d_model unless d_head says so
@@ -297,7 +377,7 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         hd = cfg.n_heads_here * cfg.value_dim
     params = {
         "embed": dense(keys[0], (cfg.vocab, d), d ** -0.5),
-        "ln_f": jnp.ones((d,), cfg.dtype),
+        "ln_f": scale(jax.random.fold_in(keys[1], 2), d),
         "layers": [],
     }
     if not cfg.tie_embeddings:
@@ -310,7 +390,7 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
         experts = (cfg.n_experts_here,) if sparse else ()
         f = cfg.d_ff_dense or cfg.d_ff if cfg.n_experts and not sparse \
             else cfg.d_ff
-        layer = {"ln1": jnp.ones((d,), cfg.dtype)}
+        layer = {"ln1": scale(jax.random.fold_in(k[0], 20), d)}
         # the leaves a public block adds draw from keys of their own, so
         # the flagship's are the flagship's whatever else is configured
         mixer = cfg.layer_kind(i).mixer
@@ -319,6 +399,8 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
             layer.update(_ssm_init(cfg, k[0], k[1], dense))
         elif mixer == "mamba":
             layer.update(_mamba_init(cfg, k[0], k[1], dense))
+        elif mixer == "gdn":
+            layer.update(_gdn_init(cfg, k[0], k[1], dense))
         elif mixer == "gmu":
             inner = cfg.mamba_inner
             layer["w_gmu_in"] = dense(k[0], (d, inner), d ** -0.5)
@@ -337,7 +419,9 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
             layer["kv_latent_norm"] = jnp.ones((ckv,), cfg.dtype)
         elif cfg.n_kv_heads:
             kv = cfg.n_kv_heads * cfg.head_dim
-            layer["w_q"] = dense(jax.random.fold_in(k[0], 1), (d, hd), d ** -0.5)
+            layer["w_q"] = dense(jax.random.fold_in(k[0], 1),
+                                 (d, hd * (2 if cfg.attn_out_gate else 1)),
+                                 d ** -0.5)    # [queries | gate]
             if mixer != "cross":    # a cross layer's are another layer's
                 layer["w_k"] = dense(jax.random.fold_in(k[0], 2), (d, kv),
                                      d ** -0.5)
@@ -361,7 +445,7 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
                     jnp.float32))
             layer["diff_norm"] = jnp.ones((2 * cfg.head_dim,), cfg.dtype)
         layer.update({
-            "ln2": jnp.ones((d,), cfg.dtype),
+            "ln2": scale(jax.random.fold_in(k[2], 7), d),
             "w_in": dense(k[2], experts + (d, f), d ** -0.5),
             "w_out": dense(k[3], experts + (f, d),
                            (2 * f * cfg.n_layers) ** -0.5),
@@ -376,8 +460,8 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
             layer["q_norm"] = jnp.ones((d,), cfg.dtype)
             layer["k_norm"] = jnp.ones((d,), cfg.dtype)
         if cfg.qk_norm_heads and attends:
-            layer["q_norm"] = jnp.ones((cfg.head_dim,), cfg.dtype)
-            layer["k_norm"] = jnp.ones((cfg.head_dim,), cfg.dtype)
+            layer["q_norm"] = scale(jax.random.fold_in(k[0], 21), cfg.head_dim)
+            layer["k_norm"] = scale(jax.random.fold_in(k[0], 22), cfg.head_dim)
         if sparse or cfg.dense_gated:
             layer["w_gate"] = dense(jax.random.fold_in(k[2], 1),
                                     experts + (d, f), d ** -0.5)
@@ -392,6 +476,9 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
                                          (d, fs), d ** -0.5)
             layer["w_shared_out"] = dense(jax.random.fold_in(k[3], 1), (fs, d),
                                           (2 * fs * cfg.n_layers) ** -0.5)
+        if sparse and cfg.shared_expert_gate:
+            layer["w_shared_sigmoid"] = dense(jax.random.fold_in(k[2], 8),
+                                              (d, 1), d ** -0.5)
         params["layers"].append(layer)
     return params
 
@@ -421,6 +508,31 @@ def _ssm_init(cfg: TransformerConfig, key, key_out, dense) -> dict:
         "ssm_norm": jnp.ones((inner,), cfg.dtype),
         "w_ssm_out": dense(key_out, (inner, d),
                            (2 * inner * cfg.n_layers) ** -0.5),
+    }
+
+
+def _gdn_init(cfg: TransformerConfig, key, key_out, dense) -> dict:
+    """The leaves of a delta-rule layer's mixer. `w_gdn_in` [d, q | k | v |
+    z]: queries and keys (key heads x key width each), values and the gate
+    (value heads x value width each); `w_gdn_ba` [d, b | a]: a write strength
+    and a decay's input a value head; `conv_w` [taps, q | k | v], no bias;
+    `gdn_norm` [value width] the output norm's scale, at one; `w_gdn_out`
+    [values, d]. Float32, as the recurrence's arithmetic: `a_log` (the decay
+    is -exp(a_log) x softplus(a + dt_bias); log of values drawn uniformly
+    from (0, 16)) and `dt_bias` (ones): the family's initialisation."""
+    d, hv = cfg.d_model, cfg.gdn_value_heads
+    kw, vw = cfg.gdn_widths
+    keys = jax.random.split(key, 4)
+    return {
+        "w_gdn_in": dense(keys[0], (d, 2 * kw + 2 * vw), d ** -0.5),
+        "w_gdn_ba": dense(keys[1], (d, 2 * hv), d ** -0.5),
+        "conv_w": dense(keys[2], (cfg.gdn_conv, 2 * kw + vw),
+                        cfg.gdn_conv ** -0.5),
+        "a_log": jnp.log(jax.random.uniform(keys[3], (hv,), jnp.float32,
+                                            1e-3, 16.0)),
+        "dt_bias": jnp.ones((hv,), jnp.float32),
+        "gdn_norm": jnp.ones((cfg.gdn_value_dim,), cfg.dtype),
+        "w_gdn_out": dense(key_out, (vw, d), (2 * vw * cfg.n_layers) ** -0.5),
     }
 
 
@@ -482,6 +594,11 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
             out.update({name: rep for name in (
                 "w_ssm_in", "conv_w", "conv_b", "w_ssm_x", "w_ssm_dt",
                 "dt_bias", "a_log", "d_skip", "w_ssm_out")})
+        elif mixer == "gdn":        # likewise (`_gdn_mixer`)
+            del out["w_proj"]
+            out.update({name: rep for name in (
+                "w_gdn_in", "w_gdn_ba", "conv_w", "a_log", "dt_bias",
+                "gdn_norm", "w_gdn_out")})
         elif mixer == "gmu":
             del out["w_proj"]
             out.update(w_gmu_in=rep, w_gmu_out=rep)
@@ -516,6 +633,8 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
             if cfg.n_shared_experts:
                 out.update(w_shared_gate=rep, w_shared_in=rep,
                            w_shared_out=rep)
+            if cfg.shared_expert_gate:
+                out["w_shared_sigmoid"] = rep
         elif cfg.dense_gated:
             out["w_gate"] = col
         return out
@@ -533,6 +652,14 @@ def _rms_norm(x, scale, eps: float = 1e-6):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
+def _rms_norm_offset(x, w, eps: float = 1e-6):
+    """RMSNorm with the scale 1 + w: float32 inside, rounded once."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)
+            * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
 def _layer_norm(x, scale, bias, eps: float):
     """LayerNorm: the mean taken off, the variance's root divided out
     (float32 inside, rounded once), a learned scale and a bias."""
@@ -548,7 +675,15 @@ def _norm(cfg: TransformerConfig, x, leaves: dict, name: str):
     for a LayerNorm, the bias `leaves[name + "_b"]`)."""
     if cfg.norm_kind == "layer":
         return _layer_norm(x, leaves[name], leaves[name + "_b"], cfg.norm_eps)
-    return _rms_norm(x, leaves[name], cfg.norm_eps)
+    return _scaled_rms(cfg, x, leaves[name])
+
+
+def _scaled_rms(cfg: TransformerConfig, x, scale):
+    """The model's RMSNorm over the last axis: x `scale`, or (`norm_unit_
+    offset`) x (1 + `scale`)."""
+    if cfg.norm_unit_offset:
+        return _rms_norm_offset(x, scale, cfg.norm_eps)
+    return _rms_norm(x, scale, cfg.norm_eps)
 
 
 def _rope_table(positions, theta: float, width: int, lane_in_head):
@@ -808,6 +943,10 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
         with jax.named_scope("mixer"):
             x = x + normed(_ssm_mixer(cfg, layer, x, tp_axis=tp_axis,
                                       sp_axis=sp_axis), "ln1_out")
+    elif kind.mixer == "gdn":
+        with jax.named_scope("mixer"):
+            x = x + normed(_gdn_mixer(cfg, layer, x, tp_axis=tp_axis,
+                                      sp_axis=sp_axis), "ln1_out")
     elif kind.mixer in ("mamba", "gmu") or cfg.diff_attn:
         for axis in (tp_axis, sp_axis):
             if axis is not None and lax.axis_size(axis) > 1:
@@ -922,7 +1061,9 @@ def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
     share of the experts (`experts_held`) it adds their part of the sum
     alone (`parallel.ep.moe_dropless_held`, which weighs the rows itself),
     and a third entry says what that took. A shared expert
-    (`n_shared_experts`) is a gated FFN every token runs, added beside."""
+    (`n_shared_experts`) is a gated FFN every token runs, added beside,
+    under `shared_expert_gate` x sigmoid(y w_shared_sigmoid), one weight a
+    token (float32, scope `shared/shared_gate`)."""
     b, t, d = y.shape
     rows = y.reshape(b * t, d)
     with jax.named_scope("router"):
@@ -961,8 +1102,16 @@ def _expert_ffn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray):
         sent = (probs.sum(axis=0), slots)
     if cfg.n_shared_experts:
         with jax.named_scope("shared"):
-            out = out + _gated_ffn(rows, layer["w_shared_gate"],
-                                   layer["w_shared_in"], layer["w_shared_out"])
+            shared = _gated_ffn(rows, layer["w_shared_gate"],
+                                layer["w_shared_in"], layer["w_shared_out"])
+            if cfg.shared_expert_gate:
+                with jax.named_scope("shared_gate"):
+                    weight = jax.nn.sigmoid(jnp.dot(
+                        rows, layer["w_shared_sigmoid"],
+                        preferred_element_type=jnp.float32))    # [rows, 1]
+                    shared = (shared.astype(jnp.float32)
+                              * weight).astype(shared.dtype)
+            out = out + shared
     return out.reshape(b, t, d), sent
 
 
@@ -1028,6 +1177,8 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
                 "grouped-query attention runs at tp 1 (its key/value heads "
                 "are not yet cut over tp)")
         q, k, v = (y @ layer[w] for w in ("w_q", "w_k", "w_v"))
+        if cfg.attn_out_gate:       # `w_q`: [every head's queries | its gate]
+            q, gate = jnp.split(q, 2, axis=-1)
         q, k, v = (_rope_heads(
             a, positions, cfg.rope_theta, a.shape[2] // dh,
             ((dh, rotated and rotate and not normed),))[0]
@@ -1044,9 +1195,10 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
             qkv, positions, cfg.rope_theta, h_local,
             ((dh, rotate and not normed),) * 2 + ((dh, False),))
     if normed:
-        q, k = (_norm_and_rope(cfg, a, layer[n], positions if rotate else None,
-                               tp_axis) for a, n in ((q, "q_norm"),
-                                                     (k, "k_norm")))
+        with jax.named_scope("qk_norm"):
+            q, k = (_norm_and_rope(cfg, a, layer[n],
+                                   positions if rotate else None, tp_axis)
+                    for a, n in ((q, "q_norm"), (k, "k_norm")))
     if cfg.attn_scale:      # the attention scales by dh ** -0.5 itself
         q = q * (cfg.attn_scale * dh ** 0.5)
     if sp_axis is not None:
@@ -1054,6 +1206,11 @@ def _attn(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
     else:
         o = local_attention(q, k, v, window)
     o = o.transpose(0, 2, 1, 3).reshape(b, t, h_local * dh)
+    if cfg.attn_out_gate:
+        with jax.named_scope("out_gate"):   # recomputed, as `gate_norm` is
+            o = jax.checkpoint(lambda o, gate: (
+                o.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(o.dtype))(o, gate)
     if tp_axis is not None:
         return row_parallel(o, layer["w_proj"], axis=tp_axis)
     return o @ layer["w_proj"]
@@ -1147,6 +1304,94 @@ def _ssm_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
         o = _rms_norm(o * jax.nn.silu(z), layer["ssm_norm"], cfg.norm_eps)
     with jax.named_scope("out_proj"):
         return o @ layer["w_ssm_out"]
+
+
+def _kept_as_rounded(x):
+    """x as it stands, where the backward pass keeps it. The compiler may
+    keep more precision than it is asked for: of a float32 result rounded to
+    bfloat16 and read again as float32 by what is recomputed, it keeps the
+    float32, twice the bytes, until the backward pass (z, q and k and the
+    gated output of a delta-rule layer: 270 MB a layer at 8192 tokens). No
+    arithmetic: a fence the two conversions do not meet across."""
+    return lax.optimization_barrier(x)
+
+
+def _l2_normed(x, eps: float = 1e-6):
+    """x / sqrt(sum x^2 + eps) over the last axis, float32 inside."""
+    x32 = x.astype(jnp.float32)
+    return x32 * lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True)
+                           + eps)
+
+
+def _gdn_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
+               tp_axis: Optional[str], sp_axis: Optional[str]) -> jnp.ndarray:
+    """A delta-rule (linear attention) layer's first half: what is added to
+    the residual. One product gives q | k | v | z (queries and keys of
+    `gdn_key_heads` heads, values and the gate of `gdn_value_heads`), a
+    second b | a, one of each a value head; q, k and v pass a causal
+    depthwise convolution (`gdn_conv` taps, no bias) and silu; each head's q
+    and k are L2-normed (x / sqrt(sum x^2 + 1e-6)) and q scaled by
+    gdn_key_dim ** -0.5; beta = sigmoid(b), g = -exp(a_log) x softplus(a +
+    dt_bias), float32; `parallel.delta.delta_scan` computes S_t = exp(g_t)
+    S_{t-1}, S_t += k_t (beta_t (v_t - S_t^T k_t))^T, o_t = S_t^T q_t in its
+    chunked form, value head h over key head h // (value heads / key heads);
+    then RMSNorm over each head's values (`gdn_norm`, a plain scale) x
+    silu(z): norm first, gate after, where `_ssm_mixer` gates first and
+    norms the whole width; and the out-projection. Scopes: `in_proj`,
+    `conv`, `prep` (the cut into heads, the L2 norms, beta and g), `scan`,
+    `gate_norm`, `out_proj`. The state runs along the whole sequence and
+    the convolution mixes a head's neighbours in time: `sp` > 1 and `tp` >
+    1 are refused."""
+    for axis in (tp_axis, sp_axis):
+        if axis is not None and lax.axis_size(axis) > 1:
+            raise NotImplementedError(
+                f"a delta-rule layer runs at tp 1 and sp 1 ({axis!r} has "
+                f"{lax.axis_size(axis)} ranks): its state would cross "
+                f"sequence shards and its heads are not cut")
+    from ..parallel import delta, ssm   # a program without such a layer
+    #                                     pays no import for it
+    b, t, _ = x.shape
+    hk, hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    kw, vw = cfg.gdn_widths
+    f32 = jnp.float32
+    y = _norm(cfg, x, layer, "ln1")
+    with jax.named_scope("in_proj"):
+        # the convolution's channels and the gate as two products of the one
+        # leaf's columns: cut after ONE product, the backward pass keeps the
+        # 12288-wide row AND its 8192-wide part (128 MB more a layer at 8192
+        # tokens: PERF.md section 6, PR 45)
+        w = layer["w_gdn_in"]
+        qkv, z = y @ w[:, :2 * kw + vw], _kept_as_rounded(
+            y @ w[:, 2 * kw + vw:])
+        beta, a = jnp.split(y @ layer["w_gdn_ba"], 2, axis=-1)
+    def scan_operands(qkv, beta, a, conv_w, a_log, dt_bias):
+        with jax.named_scope("conv"):
+            qkv = jax.nn.silu(ssm.causal_conv(qkv, conv_w, jnp.zeros((), f32)))
+        with jax.named_scope("prep"):
+            q, k, v = jnp.split(qkv, [kw, 2 * kw], axis=-1)
+            q, k = (_l2_normed(part.reshape(b, t, hk, cfg.gdn_key_dim))
+                    for part in (q, k))
+            q = (q * cfg.gdn_key_dim ** -0.5).astype(x.dtype)
+            g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(f32) + dt_bias)
+            return (q, k.astype(x.dtype),
+                    v.reshape(b, t, hv, cfg.gdn_value_dim), g,
+                    jax.nn.sigmoid(beta.astype(f32)))
+    # recomputed in the backward pass from the products as they stand: the
+    # convolution's output before and after silu and q and k before their
+    # norms are 320 MB a layer at 8192 tokens that only elementwise ops read
+    operands = _kept_as_rounded(jax.checkpoint(scan_operands)(
+        qkv, beta, a, layer["conv_w"], layer["a_log"], layer["dt_bias"]))
+    with jax.named_scope("scan"):
+        o = delta.delta_scan(*operands, cfg.gdn_chunk)
+    with jax.named_scope("gate_norm"):
+        # recomputed in the backward pass from o and z as they stand: left
+        # to the compiler, a float32 copy of o is what it keeps
+        o = _kept_as_rounded(jax.checkpoint(lambda o, z, w: (
+            _rms_norm(o, w, cfg.norm_eps).astype(f32)
+            * jax.nn.silu(z.astype(f32))).astype(x.dtype))(
+                o, z.reshape(o.shape), layer["gdn_norm"]))
+    with jax.named_scope("out_proj"):
+        return o.reshape(b, t, vw) @ layer["w_gdn_out"]
 
 
 def _mamba_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray):
@@ -1275,13 +1520,18 @@ def _norm_and_rope(cfg: TransformerConfig, x: jnp.ndarray, scale: jnp.ndarray,
     here) norm and rotation are ONE kernel each way,
     `pallas_kernels.norm_rope`: the norm's arithmetic as it stands below,
     float32 inside and rounded where it rounds; elsewhere the norm, then
-    `_rope`."""
+    `_rope`. The kernel scales by the learned scale itself and turns the
+    whole head: a scale of 1 + w (`norm_unit_offset`) and a rotated share
+    (`rotary_dim`: the head's first values turned, the rest passed) take the
+    plain path. The caller's scope is `qk_norm`."""
     b, h, t, dh = x.shape
     alone = tp_axis is None or lax.axis_size(tp_axis) == 1
+    part = cfg.rotary_dim if 0 < cfg.rotary_dim < dh else 0
     run = choice.decide(
         choice.NORM_ROPE, b * h, t, dh, x.dtype, h if cfg.qk_norm else 0,
         also=positions is not None and cfg.qk_norm != cfg.qk_norm_heads
-        and (alone or not cfg.qk_norm))
+        and (alone or not cfg.qk_norm) and not part
+        and not cfg.norm_unit_offset)
     if run:
         cos, sin = _rope_table(positions, cfg.rope_theta, dh, np.arange(dh))
         return pk.norm_rope(
@@ -1290,8 +1540,16 @@ def _norm_and_rope(cfg: TransformerConfig, x: jnp.ndarray, scale: jnp.ndarray,
             interpret=run.interpret)
     if cfg.qk_norm:
         x = _whole_vector_norm(cfg, x, scale, tp_axis)
+    if part and positions is not None:
+        # recomputed in the backward pass, as `_gdn_mixer`'s `gate_norm` is
+        def normed_and_turned(x, scale):
+            x = _scaled_rms(cfg, x, scale) if cfg.qk_norm_heads else x
+            return jnp.concatenate(
+                [_rope(x[..., :part], positions, cfg.rope_theta),
+                 x[..., part:]], axis=-1)
+        return jax.checkpoint(normed_and_turned)(x, scale)
     if cfg.qk_norm_heads:
-        x = _rms_norm(x, scale, cfg.norm_eps)
+        x = _scaled_rms(cfg, x, scale)
     return x if positions is None else _rope(x, positions, cfg.rope_theta)
 
 
@@ -1427,6 +1685,12 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
             f"mesh has {tp_axis} {sizes[tp_axis]}, {sp_axis} "
             f"{sizes[sp_axis]}): a layer's state would cross sequence "
             f"shards; shard its batch over {dp_axis}")
+    if "gdn" in cfg.mixer_kinds and (sizes[tp_axis] > 1 or sizes[sp_axis] > 1):
+        raise NotImplementedError(
+            f"a model with delta-rule layers trains at tp 1 and sp 1 (this "
+            f"mesh has {tp_axis} {sizes[tp_axis]}, {sp_axis} "
+            f"{sizes[sp_axis]}): a layer's state would cross sequence shards "
+            f"and its heads are not cut; shard its batch over {dp_axis}")
     if (cfg.diff_attn or set(cfg.mixer_kinds) & {"mamba", "gmu", "cross"}) \
             and (sizes[tp_axis] > 1 or sizes[sp_axis] > 1):
         raise NotImplementedError(

@@ -1,0 +1,285 @@
+"""Linear attention with a gated delta rule: the scan of one block.
+
+The recurrence, a value head, with a state ``S`` of [key width, value width]
+(float32), a decay ``g_t`` <= 0 and a write strength ``beta_t`` in (0, 1),
+one of each a value head and token:
+
+    S_t = exp(g_t) S_{t-1}                            the state decays,
+    S_t <- S_t + k_t (beta_t (v_t - S_t^T k_t))^T     is corrected by what it
+                                                      already says of this key,
+    o_t = S_t^T q_t                                   and is read.
+
+Value head h reads key head h // (value heads / key heads). Unlike the
+state-space scans of `parallel/ssm.py` (a decay times the state plus an outer
+product) the written value depends on the state, so a chunk of tokens is no
+masked product of its inputs alone. :func:`delta_recurrence` is the
+recurrence as it stands, a token at a time: the form the tests hold the
+chunks to. :func:`delta_scan` is the one "delta-rule scan of a block" the
+model calls; it computes the recurrence ``chunk`` tokens at a time. With
+``gamma_i`` the sum of the chunk's ``g`` up to token i and ``S`` the state
+before the chunk, the values a chunk writes, ``u_i = beta_i (v_i - (exp(g_i)
+S_{i-1})^T k_i)``, solve
+
+    (I + A) U = beta V - (beta exp(gamma) K) S,
+    A_ij = beta_i exp(gamma_i - gamma_j) (k_i . k_j)  for j < i, else 0,
+
+a unit lower-triangular system a head and chunk. ``T = (I + A)^-1``
+(:func:`_unit_lower_inverse`) gives ``U0 = T (beta V)`` and ``W = T (beta
+exp(gamma) K)`` from the chunk's inputs alone, for all chunks at once; then
+
+    U = U0 - W S                  S' = exp(gamma_last) S + (exp(gamma_last -
+    O = (exp(gamma) Q) S + (Q K^T o decay, j <= i) U        gamma) K)^T U
+
+and only ``S -> S'`` runs in order, one step a chunk (:func:`_state_chain`,
+two products a step, its backward pass written out: two more and the
+cotangents' three). The decay sums, their exponentials, ``A``, ``T`` and the
+state are float32 (``T``'s products at `HIGHEST` precision: the MXU's
+default would round its float32 operands to bfloat16); every other product
+takes operands of the input's type and accumulates in float32; the output
+is rounded once. For the backward pass the inputs and the state before each
+chunk are kept ([chunks, batch, value heads, key width, value width]
+float32) and every [chunk x chunk] array is computed again: `jax.checkpoint`
+with a policy that saves the states alone.
+
+The form is chosen from the shapes, never by trying, and counted where it is
+chosen (``perfvars.snapshot()["delta_lowerings"]``): ``chunked`` where the
+sequence is a multiple of the chunk, ``padded`` where it is not: the
+sequence is filled up to the next multiple with tokens of ``g`` = 0,
+``beta`` = 0 and ``k`` = 0, which decay nothing and write nothing, so the
+result is exact, and their outputs are cut off. Plain XLA everywhere: no
+kernel computes this yet (ROADMAP R3), so `xla.choice` has nothing to
+choose here.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from .. import perfvars
+
+STATES = "delta_chunk_states"   # what the backward pass keeps of `_chunked`
+_EXACT = lax.Precision.HIGHEST
+
+
+def delta_recurrence(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     g: jnp.ndarray, beta: jnp.ndarray) -> jnp.ndarray:
+    """o [batch, t, value heads, value width] float32 of the recurrence
+    above, one token at a time, everything float32: q and k [batch, t, key
+    heads, key width], v [batch, t, value heads, value width], g and beta
+    [batch, t, value heads]."""
+    f32 = jnp.float32
+    rep = v.shape[2] // k.shape[2]
+    q, k = (jnp.repeat(a.astype(f32), rep, axis=2) for a in (q, k))
+
+    def token(s, at):
+        q_t, k_t, v_t, g_t, b_t = at    # [b, h, width] x 3, [b, h] x 2
+        s = s * jnp.exp(g_t)[..., None, None]
+        seen = jnp.einsum("bhde,bhd->bhe", s, k_t, precision=_EXACT)
+        s = s + k_t[..., :, None] * (b_t[..., None] * (v_t - seen))[..., None, :]
+        return s, jnp.einsum("bhde,bhd->bhe", s, q_t, precision=_EXACT)
+    start = jnp.zeros(v.shape[:1] + v.shape[2:3] + (k.shape[3], v.shape[3]),
+                      f32)
+    _, o = lax.scan(token, start, tuple(
+        jnp.moveaxis(a.astype(f32), 1, 0) for a in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def delta_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+               g: jnp.ndarray, beta: jnp.ndarray,
+               chunk: int = 64) -> jnp.ndarray:
+    """o [batch, t, value heads, value width], of v's type, of the
+    recurrence above in its chunked form: q and k [batch, t, key heads, key
+    width] (the caller's norms and scale applied), v [batch, t, value heads,
+    value width], g (<= 0) and beta [batch, t, value heads] float32;
+    ``chunk`` a power of two. Each call built into a traced program counts
+    in ``perfvars.snapshot()["delta_lowerings"]`` as ``chunked`` or
+    ``padded``. The result does not depend on the chunk."""
+    if chunk < 1 or chunk & (chunk - 1):
+        raise ValueError(f"chunk={chunk}: the triangular system is inverted "
+                         f"by halves, so a chunk is a power of two")
+    t = q.shape[1]
+    pad = -t % chunk
+    perfvars.note("delta_lowerings", "padded" if pad else "chunked")
+
+    def filled(a):      # up to the next multiple, with tokens of zeros
+        widths = ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)
+        return jnp.pad(a, widths) if pad else a
+    return _chunked(*(filled(a) for a in (q, k, v, g, beta)), chunk)[:, :t]
+
+
+def _same_block(length: int, size: int):
+    """[length, length] bool: places i and j lie in one block of ``size``."""
+    at = jnp.arange(length) // size
+    return at[:, None] == at[None, :]
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for a strictly lower-triangular ``a`` [..., n, n]
+    float32, n a power of two, by halves: with T_s the inverse of the
+    diagonal blocks of size s alone (T_1 = I) and a_s what ``a`` holds inside
+    a block of 2 s and outside its two blocks of s, T_2s = T_s - T_s a_s T_s
+    (the inverse of [[A, 0], [C, B]] is [[A', 0], [-B' C A', B']]). log2 n
+    rounds of two [n x n] products, each as exact as float32 is: no power
+    of ``a`` is ever formed, so keys that repeat (``a`` near all ones) cost
+    no digits, where the series (I - a)(I + a^2)(I + a^4).. would cancel
+    binomials of 1e17 at n = 64. The gradient is the inverse's own:
+    -T^T dT T^T."""
+    n = a.shape[-1]
+    t = jnp.broadcast_to(jnp.eye(n, dtype=a.dtype), a.shape)
+    size = 1
+    while size < n:
+        between = jnp.logical_and(_same_block(n, 2 * size),
+                                  jnp.logical_not(_same_block(n, size)))
+        t = t - jnp.matmul(t, jnp.matmul(jnp.where(between, a, 0.0), t,
+                                         precision=_EXACT), precision=_EXACT)
+        size *= 2
+    return t
+
+
+def _unit_lower_inverse_bwd(t, d):
+    t_t = jnp.swapaxes(t, -1, -2)
+    return (-jnp.matmul(t_t, jnp.matmul(d, t_t, precision=_EXACT),
+                        precision=_EXACT),)
+
+
+_unit_lower_inverse.defvjp(lambda a: (_unit_lower_inverse(a),) * 2,
+                           _unit_lower_inverse_bwd)
+
+
+def _chain_step(s, at, dtype):
+    """One chunk of the state's recurrence: (the state after it, the values
+    it wrote)."""
+    carry, kd, w, u0 = at
+    u = u0 - jnp.einsum("bhld,bhde->bhle", w, s.astype(dtype),
+                        preferred_element_type=jnp.float32)
+    after = carry[..., None, None] * s + jnp.einsum(
+        "bhld,bhle->bhde", kd, u.astype(dtype),
+        preferred_element_type=jnp.float32)
+    return after, u
+
+
+@jax.custom_vjp
+def _state_chain(carry, kd, w, u0):
+    """The state BEFORE each chunk, [chunks, batch, heads, key width, value
+    width] float32, of S' = carry S + kd^T (u0 - w S) from S = 0: ``carry``
+    [chunks, batch, heads] float32 (a chunk's whole decay), ``kd`` and ``w``
+    [chunks, batch, heads, chunk, key width] of the input's type (the keys
+    decayed to the chunk's end; ``W`` above), ``u0`` [chunks, batch, heads,
+    chunk, value width] float32. The one part of the scan that runs in
+    order. Its backward pass is the same chain run backwards over the kept
+    states: no step of the forward one runs again."""
+    start = jnp.zeros(u0.shape[1:3] + (kd.shape[-1], u0.shape[-1]),
+                      jnp.float32)
+    varies = tuple(sorted(set().union(*(jax.typeof(a).vma
+                                        for a in (carry, kd, w, u0)))))
+    if varies:      # under `shard_map` the carry varies as the operands do
+        start = lax.pcast(start, varies, to="varying")
+
+    def step(s, at):
+        return _chain_step(s, at, kd.dtype)[0], s
+    return lax.scan(step, start, (carry, kd, w, u0))[1]
+
+
+def _state_chain_fwd(carry, kd, w, u0):
+    states = checkpoint_name(_state_chain(carry, kd, w, u0), STATES)
+    return states, (carry, kd, w, u0, states)
+
+
+def _state_chain_bwd(kept, d_states):
+    carry, kd, w, u0, states = kept
+    f32, dtype = jnp.float32, kd.dtype
+
+    def step(d_after, at):
+        c, kd_c, w_c, u0_c, s, d_s = at
+        u = _chain_step(s, (c, kd_c, w_c, u0_c), dtype)[1].astype(dtype)
+        after = d_after.astype(dtype)
+        d_u = jnp.einsum("bhld,bhde->bhle", kd_c, after,
+                         preferred_element_type=f32)
+        d_kd = jnp.einsum("bhle,bhde->bhld", u, after,
+                          preferred_element_type=f32)
+        d_w = -jnp.einsum("bhle,bhde->bhld", d_u.astype(dtype),
+                          s.astype(dtype), preferred_element_type=f32)
+        d_before = c[..., None, None] * d_after + d_s - jnp.einsum(
+            "bhld,bhle->bhde", w_c, d_u.astype(dtype),
+            preferred_element_type=f32)
+        return d_before, (jnp.sum(s * d_after, axis=(-1, -2)),
+                          d_kd.astype(dtype), d_w.astype(w.dtype), d_u)
+    _, grads = lax.scan(step, jnp.zeros_like(d_states[0]),
+                        (carry, kd, w, u0, states, d_states), reverse=True)
+    return grads
+
+
+_state_chain.defvjp(_state_chain_fwd, _state_chain_bwd)
+
+
+def _chunk_parts(q, k, v, g, beta):
+    """What the chunks give the state's chain and the outputs, each from
+    its inputs alone ([batch, chunks, heads, chunk, width]; g and beta
+    [batch, chunks, value heads, chunk] float32): (the whole decay a chunk
+    [batch, chunks, value heads] float32; the keys decayed to the chunk's
+    end, ``W`` and the queries decayed from its start, of the input's type;
+    ``U0`` float32; the masked, decayed scores of the input's type). Every
+    [chunk x chunk] array lives and dies here."""
+    hk, hv, length = k.shape[2], v.shape[2], k.shape[3]
+    f32, dtype = jnp.float32, v.dtype
+
+    def for_values(a):      # a key head's [.., hk, x, y] for each of its value heads
+        return jnp.broadcast_to(
+            a[:, :, :, None], a.shape[:3] + (hv // hk,) + a.shape[3:]).reshape(
+                a.shape[:2] + (hv,) + a.shape[3:])
+    gamma = jnp.cumsum(g, axis=-1)                  # [b, c, hv, l]
+    seen = jnp.tril(jnp.ones((length, length), dtype=bool))
+    decay = jnp.exp(jnp.where(seen, gamma[..., :, None] - gamma[..., None, :],
+                              -jnp.inf))            # [b, c, hv, l, s], s <= l
+    kk, qk = (for_values(jnp.einsum("bchld,bchsd->bchls", a, k,
+                                    preferred_element_type=f32))
+              for a in (k, q))
+    inverse = _unit_lower_inverse(jnp.where(
+        jnp.tril(seen, -1), beta[..., None] * decay * kk, 0.0)).astype(dtype)
+    k, q = for_values(k).astype(f32), for_values(q).astype(f32)
+    w = jnp.einsum("bchls,bchsd->bchld", inverse,
+                   (k * (beta * jnp.exp(gamma))[..., None]).astype(dtype),
+                   preferred_element_type=f32).astype(dtype)
+    u0 = jnp.einsum("bchls,bchse->bchle", inverse,
+                    (v.astype(f32) * beta[..., None]).astype(dtype),
+                    preferred_element_type=f32)
+    kd = (k * jnp.exp(gamma[..., -1:] - gamma)[..., None]).astype(dtype)
+    return (jnp.exp(gamma[..., -1]), kd, w, u0,
+            (q * jnp.exp(gamma)[..., None]).astype(dtype),
+            (qk * decay).astype(dtype))
+
+
+@functools.partial(
+    jax.checkpoint, static_argnums=(5,),
+    policy=jax.checkpoint_policies.save_only_these_names(STATES))
+def _chunked(q, k, v, g, beta, length: int):
+    """The recurrence's outputs [batch, t, value heads, value width], of
+    v's type (rounded here, so that what the caller's backward pass keeps
+    of them is that wide), t a multiple of ``length``."""
+    bsz, t, hk, _dk = k.shape
+    hv, dv = v.shape[2:]
+    nc = t // length
+    f32, dtype = jnp.float32, v.dtype
+
+    def by_chunk(a, heads):     # [b, t, h, w] -> [b, chunks, h, length, w]
+        return a.reshape(bsz, nc, length, heads, -1).transpose(0, 1, 3, 2, 4)
+    carry, kd, w, u0, q_from_start, scores = _chunk_parts(
+        by_chunk(q, hk), by_chunk(k, hk), by_chunk(v, hv),
+        by_chunk(g.astype(f32), hv)[..., 0],
+        by_chunk(beta.astype(f32), hv)[..., 0])
+    states = jnp.moveaxis(_state_chain(*(
+        jnp.moveaxis(a, 1, 0) for a in (carry, kd, w, u0))), 0, 1)
+    before = states.astype(dtype)                   # [b, c, hv, dk, dv]
+    u = u0 - jnp.einsum("bchld,bchde->bchle", w, before,
+                        preferred_element_type=f32)
+    o = jnp.einsum("bchld,bchde->bchle", q_from_start, before,
+                   preferred_element_type=f32) \
+        + jnp.einsum("bchls,bchse->bchle", scores, u.astype(dtype),
+                     preferred_element_type=f32)
+    return o.astype(dtype).transpose(0, 1, 3, 2, 4).reshape(bsz, t, hv, dv)

@@ -25,7 +25,8 @@ pieces:
   state-space scans by their form as ``scan_lowerings`` and by who computes
   them (the Pallas kernels or plain `jnp`) as ``scan_kernel_lowerings``, the
   per-channel selective scans likewise as ``sel_scan_lowerings`` and
-  ``sel_scan_kernel_lowerings``, the layers
+  ``sel_scan_kernel_lowerings``, the delta-rule scans by their form as
+  ``delta_lowerings``, the layers
   that read a value beside the residual stream as ``side_values``, the
   vocabulary heads and their losses as ``head_loss_lowerings`` (over blocks
   of tokens, or over the whole logits) with ``head_loss_blocks``, and
@@ -588,8 +589,8 @@ FAMILIES: Dict[str, Any] = {
     # `models.transformer._rope` and the two rotation kernels: x cos +
     # swap(x) sin with its own backward, or two half-width products
     "rope_forms": ("dense", "halves"),
-    # `_attn_ffn_block`, one count a trace of a layer kind; `mamba`, `gmu`
-    # and `cross` appear once traced
+    # `_attn_ffn_block`, one count a trace of a layer kind; `mamba`, `gmu`,
+    # `cross` and `gdn` appear once traced
     "mixer_kinds": ("attention", "ssm"),
     # `_side_read`: the layers that read a value written beside the stream
     "side_values": ("memory", "kv"),
@@ -601,6 +602,8 @@ FAMILIES: Dict[str, Any] = {
     "sel_scan_lowerings": ("chunked", "padded"),
     # and who: `xla/sel_scan_kernels.py` or `_selective_chunks`
     "sel_scan_kernel_lowerings": ("kernel", "plain"),
+    # `parallel.delta.delta_scan`: its form (no kernel computes it yet)
+    "delta_lowerings": ("chunked", "padded"),
     # `models.transformer.head_loss` over blocks of tokens, or `_xent` of
     # the whole logits (the two pipelined steps), one count a traced loss
     "head_loss_lowerings": ("blocked", "whole"),
