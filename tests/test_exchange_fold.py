@@ -802,12 +802,14 @@ def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
     an eighth of the vocabulary, batch 1 x 8192, parameters donated)
     compiled for one described v5e chip with the kernels selected as on a
     TPU: 1.979 B parameters, at most 14.9 GB with every FFN half recomputed
-    (PR 45: 13.73 GB; 15.46 while the compiler kept float32 copies of z, q,
+    (PR 46: 12.67 GB, the scan's [chunk x chunk] temporaries gone with its
+    loops; PR 45: 13.73 GB; 15.46 while the compiler kept float32 copies of z, q,
     k and the gated output and the convolution's outputs were kept, and
     20.18 with nothing recomputed); no [.., t, t] buffer: the fused
     attention kernel at (8192, 256) forward and backward in layers 3 and 7
-    alone; the six scopes of a delta-rule mixer in the other six; one trace
-    of the block a mixer kind; the experts' products on the grouped kernel;
+    alone; the six scopes of a delta-rule mixer in the other six, the scan
+    the delta-rule kernel pair under `scan` (PR 46) and no loop of XLA's; one
+    trace of the block a mixer kind; the experts' products on the grouped kernel;
     and nothing the compiler chose to compute again to fit."""
     import json
     import os
@@ -870,10 +872,20 @@ def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
     assert "ragged-dot" not in hlo
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
     assert sorted(set(kernels)) == [
-        "causal_attention_bwd", "causal_attention_fwd", "grouped_matmul_dlhs",
-        "grouped_matmul_drhs", "grouped_matmul_fwd", "grouped_row_sums"]
+        "causal_attention_bwd", "causal_attention_fwd", "delta_scan_bwd",
+        "delta_scan_fwd", "grouped_matmul_dlhs", "grouped_matmul_drhs",
+        "grouped_matmul_fwd", "grouped_row_sums"]
     assert kernels.count("causal_attention_fwd") == \
         kernels.count("causal_attention_bwd") == 1, kernels
+    assert kernels.count("delta_scan_fwd") == \
+        kernels.count("delta_scan_bwd") == 1, kernels   # six layers, one trace
+    scans = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*delta_scan_(\w+)/[^\"]*)\"", hlo)
+    assert sorted((d, int(re.search(r"layer_(\d+)", name).group(1)))
+                  for name, d in scans) == sorted(
+        (d, i) for d in ("bwd", "fwd") for i in (0, 1, 2, 4, 5, 6))
+    assert all("/mixer/scan/" in name for name, _d in scans)
+    assert "while" not in "".join(n for n in names if "/mixer/scan/" in n)
     tf._block_traced_once.cache_clear()
 
 

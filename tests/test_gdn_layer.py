@@ -200,6 +200,98 @@ def test_the_inverse_is_float32_under_bfloat16(monkeypatch):
     assert "bf16" not in text
 
 
+def _kernels_traced():
+    """The Pallas kernels' own programs (forward, backward) as traced for
+    bfloat16 operands inside the kernels' contract, with the program around
+    them: ([the `pallas_call` equations], the whole text)."""
+    t = 128
+    keys = jax.random.split(jax.random.key(5), 5)
+    q, k = (tf._l2_normed(jax.random.normal(key, (1, t, 1, 128)))
+            for key in keys[:2])
+    args = (q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+            jax.random.normal(keys[2], (1, t, 2, 128)).astype(jnp.bfloat16),
+            -jax.random.uniform(keys[3], (1, t, 2)),
+            jax.nn.sigmoid(jax.random.normal(keys[4], (1, t, 2))))
+    closed = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(delta.delta_scan(*a, 64).astype(jnp.float32)),
+        argnums=range(5)))(*args)
+    calls = [eqn for eqn in _eqns(closed.jaxpr)
+             if eqn.primitive.name == "pallas_call"]
+    return calls, str(closed)
+
+
+def _eqns(jaxpr):
+    """Every equation of a program, those of its inner programs after the
+    equation that holds them."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield from _eqns(sub)
+
+
+def test_the_kernels_state_and_decay_sums_are_float32_under_bfloat16(
+        kernel_backend):
+    """The guard above reads the plain path by breaking it; the kernel pair
+    is read by type: under bfloat16 operands the sums of g are taken in
+    float32 in front of the kernel, every exponential inside and outside it
+    is of a float32, the state carried in VMEM, the state handed to the
+    backward pass and the tile of scalars are float32, and both kernels run
+    (`delta_kernel_lowerings` counts the trace)."""
+    perfvars.reset()
+    with kernel_backend("interpret"):
+        calls, text = _kernels_traced()
+    assert perfvars.snapshot()["delta_kernel_lowerings"] == {
+        "kernel": 1, "plain": 0}
+    perfvars.reset()
+    assert sorted(eqn.params["name"] for eqn in calls) == [
+        "delta_scan_bwd", "delta_scan_fwd"]
+    assert "cumsum" in text and "bf16[1,2,64,1,2]" not in text
+    for eqn in calls:
+        inner = eqn.params["jaxpr"]
+        scratch = [v.aval for v in inner.invars][-1]
+        assert scratch.shape == (2, 128, 128)
+        assert scratch.dtype == jnp.float32         # the state, its cotangent
+        exps = [e for e in _eqns(inner) if e.primitive.name == "exp"]
+        assert exps and all(e.invars[0].aval.dtype == jnp.float32
+                            for e in exps)
+    forward = next(eqn for eqn in calls
+                   if eqn.params["name"] == "delta_scan_fwd")
+    o, before = (v.aval for v in forward.outvars)
+    assert o.dtype == jnp.bfloat16
+    assert before.shape == (1, 2, 2, 128, 128) and before.dtype == jnp.float32
+    assert forward.invars[3].aval.dtype == jnp.float32      # the scalars
+
+
+def test_the_kernels_inverse_is_float32_under_bfloat16(kernel_backend):
+    """Inside both kernels a product either takes two bfloat16 operands or
+    two float32 operands at `HIGHEST`: the float32 ones are the inverse's
+    two rounds of two (blocks of 16 tokens are solved by substitution, on
+    the VPU, and multiply nothing) and, backward, its gradient's two more;
+    no product rounds a float32 operand."""
+    with kernel_backend("interpret"):
+        calls, _text = _kernels_traced()
+    perfvars.reset()
+    exact = {}
+    for eqn in calls:
+        name = eqn.params["name"]
+        exact[name] = 0
+        for e in _eqns(eqn.params["jaxpr"]):
+            if e.primitive.name != "dot_general":
+                continue
+            types = {v.aval.dtype for v in e.invars}
+            assert len(types) == 1, (name, types)
+            assert e.params["preferred_element_type"] == jnp.float32
+            if types == {jnp.dtype(jnp.float32)}:
+                assert e.params["precision"] == (
+                    jax.lax.Precision.HIGHEST,) * 2, name
+                exact[name] += 1
+            else:
+                assert types == {jnp.dtype(jnp.bfloat16)}, (name, types)
+    assert exact == {"delta_scan_fwd": 4, "delta_scan_bwd": 6}
+
+
 # -- a layer's halves against their equations ------------------------------------
 
 def silu(x):

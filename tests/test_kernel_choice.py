@@ -1,7 +1,7 @@
 """The seam where a kernel or its plain path is chosen (`tpu_mpi/xla/
 choice.py`) and the counters of it (`perfvars.FAMILIES`): what a snapshot
 holds before anything is traced, by the names the yardstick's readers use;
-each of the seven choices counted under its family as the kernel where the
+each of the eight choices counted under its family as the kernel where the
 tests' word selects it and as the plain path where nothing does; each
 contract's own operand types; the rule's parts; and the trace key: a layer
 and a sum of rows traced under one word are traced again under the other.
@@ -21,7 +21,7 @@ if ROOT not in sys.path:
 
 from tpu_mpi import perfvars                                    # noqa: E402
 from tpu_mpi.models import transformer as tf                    # noqa: E402
-from tpu_mpi.parallel import ep, ring, ssm                      # noqa: E402
+from tpu_mpi.parallel import delta, ep, ring, ssm               # noqa: E402
 from tpu_mpi.xla import choice                                  # noqa: E402
 
 F32 = jnp.float32
@@ -43,6 +43,7 @@ FRESH = {
     "sel_scan_lowerings": {"chunked": 0, "padded": 0},
     "sel_scan_kernel_lowerings": {"kernel": 0, "plain": 0},
     "delta_lowerings": {"chunked": 0, "padded": 0},
+    "delta_kernel_lowerings": {"kernel": 0, "plain": 0},
     "head_loss_lowerings": {"blocked": 0, "whole": 0},
     "head_loss_blocks": {},
 }
@@ -128,6 +129,12 @@ def _sel_scan():
                                       bc, jnp.ones((512,), F32))
 
 
+def _delta_scan():
+    qk, v = jnp.zeros((1, 64, 1, 128), F32), jnp.zeros((1, 64, 2, 128), F32)
+    g = jnp.zeros((1, 64, 2), F32)
+    return lambda: delta.delta_scan(qk, qk, v, g, g, 64)
+
+
 # one small call of each choice, at a shape inside its kernel's contract
 CALLS = {
     "attention": (choice.ATTENTION, _attention),
@@ -137,6 +144,7 @@ CALLS = {
     "norm_rope": (choice.NORM_ROPE, _norm_rope),
     "scan": (choice.SCAN, _scan),
     "selective scan": (choice.SEL_SCAN, _sel_scan),
+    "delta scan": (choice.DELTA_SCAN, _delta_scan),
 }
 
 
@@ -170,6 +178,7 @@ CONTRACTS = {
     "norm_rope": (choice.NORM_ROPE, (2, 128, 128, None), 3),
     "scan": (choice.SCAN, (8, 64, 128, 128, None), 4),
     "selective scan": (choice.SEL_SCAN, (512, 16, None), 2),
+    "delta scan": (choice.DELTA_SCAN, (2, 1, 128, 128, 64, None), 5),
 }
 
 

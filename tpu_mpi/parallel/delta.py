@@ -46,9 +46,17 @@ chosen (``perfvars.snapshot()["delta_lowerings"]``): ``chunked`` where the
 sequence is a multiple of the chunk, ``padded`` where it is not: the
 sequence is filled up to the next multiple with tokens of ``g`` = 0,
 ``beta`` = 0 and ``k`` = 0, which decay nothing and write nothing, so the
-result is exact, and their outputs are cut off. Plain XLA everywhere: no
-kernel computes this yet (ROADMAP R3), so `xla.choice` has nothing to
-choose here.
+result is exact, and their outputs are cut off.
+
+Who computes it is chosen by `xla.choice`'s rule and counted beside the form
+(``perfvars.snapshot()["delta_kernel_lowerings"]``): ``kernel`` where a
+kernel backend is there (a TPU; the tests' word) and the contract
+`xla.delta_kernels.delta_scan_selected` takes the operands (two value heads
+a key head, heads of 128, a chunk of 64, float32 or bfloat16): one Pallas
+kernel each way that keeps a chunk's arrays and the state in VMEM, the
+padded form filled with the same zero tokens in front of it; ``plain``
+everywhere else (the CPU, the tests, other shapes): :func:`_chunked`, plain
+XLA, which is also what the tests hold the kernels to.
 """
 
 from __future__ import annotations
@@ -61,6 +69,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import perfvars
+from ..xla import choice, delta_kernels
 
 STATES = "delta_chunk_states"   # what the backward pass keeps of `_chunked`
 _EXACT = lax.Precision.HIGHEST
@@ -98,7 +107,8 @@ def delta_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     value width], g (<= 0) and beta [batch, t, value heads] float32;
     ``chunk`` a power of two. Each call built into a traced program counts
     in ``perfvars.snapshot()["delta_lowerings"]`` as ``chunked`` or
-    ``padded``. The result does not depend on the chunk."""
+    ``padded``, and in ``["delta_kernel_lowerings"]`` as ``kernel`` or
+    ``plain``. The result does not depend on the chunk."""
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"chunk={chunk}: the triangular system is inverted "
                          f"by halves, so a chunk is a power of two")
@@ -109,7 +119,14 @@ def delta_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     def filled(a):      # up to the next multiple, with tokens of zeros
         widths = ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)
         return jnp.pad(a, widths) if pad else a
-    return _chunked(*(filled(a) for a in (q, k, v, g, beta)), chunk)[:, :t]
+    operands = tuple(filled(a) for a in (q, k, v, g, beta))
+    run = choice.decide(choice.DELTA_SCAN, v.shape[2], k.shape[2],
+                        k.shape[3], v.shape[3], chunk, v.dtype,
+                        also=q.dtype == k.dtype == v.dtype)
+    if run:
+        return delta_kernels.delta_scan(*operands,
+                                        interpret=run.interpret)[:, :t]
+    return _chunked(*operands, chunk)[:, :t]
 
 
 def _same_block(length: int, size: int):

@@ -25,8 +25,8 @@ pieces:
   state-space scans by their form as ``scan_lowerings`` and by who computes
   them (the Pallas kernels or plain `jnp`) as ``scan_kernel_lowerings``, the
   per-channel selective scans likewise as ``sel_scan_lowerings`` and
-  ``sel_scan_kernel_lowerings``, the delta-rule scans by their form as
-  ``delta_lowerings``, the layers
+  ``sel_scan_kernel_lowerings``, the delta-rule scans as ``delta_lowerings``
+  and ``delta_kernel_lowerings``, the layers
   that read a value beside the residual stream as ``side_values``, the
   vocabulary heads and their losses as ``head_loss_lowerings`` (over blocks
   of tokens, or over the whole logits) with ``head_loss_blocks``, and
@@ -602,8 +602,10 @@ FAMILIES: Dict[str, Any] = {
     "sel_scan_lowerings": ("chunked", "padded"),
     # and who: `xla/sel_scan_kernels.py` or `_selective_chunks`
     "sel_scan_kernel_lowerings": ("kernel", "plain"),
-    # `parallel.delta.delta_scan`: its form (no kernel computes it yet)
+    # `parallel.delta.delta_scan` likewise: its form
     "delta_lowerings": ("chunked", "padded"),
+    # and who: `xla/delta_kernels.py` or `parallel.delta._chunked`
+    "delta_kernel_lowerings": ("kernel", "plain"),
     # `models.transformer.head_loss` over blocks of tokens, or `_xent` of
     # the whole logits (the two pipelined steps), one count a traced loss
     "head_loss_lowerings": ("blocked", "whole"),

@@ -30,7 +30,7 @@ import jax
 
 from .. import perfvars
 from . import pallas_kernels as pk
-from . import sel_scan_kernels, ssm_kernels
+from . import delta_kernels, sel_scan_kernels, ssm_kernels
 
 
 def backend() -> Optional[str]:
@@ -89,6 +89,9 @@ SCAN = Choice(ssm_kernels.ssm_scan_selected, "scan_kernel_lowerings",
               "kernel", "plain")
 SEL_SCAN = Choice(sel_scan_kernels.sel_scan_selected,
                   "sel_scan_kernel_lowerings", "kernel", "plain")
+# `parallel.delta.delta_scan`
+DELTA_SCAN = Choice(delta_kernels.delta_scan_selected,
+                    "delta_kernel_lowerings", "kernel", "plain")
 
 
 class Run(NamedTuple):
