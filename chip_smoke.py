@@ -17,7 +17,8 @@ script starts no child that needs it):
 3. kernels — the ring kernels of ``tpu_mpi.xla.pallas_kernels`` and the
    experts' grouped product compiled by Mosaic, numerics against the XLA
    collective, ``lax.ragged_dot`` (values and both gradients) or a jnp
-   reference; an OLMoE-shaped expert layer takes the kernel's route and
+   reference, the recurrent mixers' convolution against its plain path
+   (values and three gradients); an OLMoE-shaped expert layer takes the kernel's route and
    compiles to the kernels alone, and its rows' way into expert order and
    back (`parallel.ep.moe_dropless`) agrees with the plain form, forward
    and backward, the four passes timed;
@@ -80,6 +81,9 @@ FULL = {
     "expert_rows": (8192, 2048, 64, 8),
     # heads, key/value heads, seq, head_dim, window of one attention block
     "window_attn": (16, 2, 4096, 128, 128),
+    # a recurrent mixer's convolution: seq, columns of the row it reads,
+    # first column, channels, cuts (tiles of four lane tiles, two blocks)
+    "conv": (2048, 2560, 512, 1536, (1024,)),
     "max_new": 8,
 }
 # Toy sizes for the tier-1 CPU test only.
@@ -95,6 +99,7 @@ TINY = {
     "expert_layer": (128, 256, 4, 2, 64),
     "expert_rows": (64, 128, 4, 2),
     "window_attn": (4, 2, 256, 128, 100),
+    "conv": (256, 448, 128, 256, (128,)),
     "max_new": 4,
 }
 
@@ -618,6 +623,40 @@ def leg_kernels(sz: dict, platform: str) -> dict:
         # sums a group's dk and dv in another order
         assert rel < 3e-2, f"causal_attention[window] {name} off by {rel}"
         facts["window_attention_rel_err"][name] = rel
+    # -- the causal convolution and its silu, read in place and cut ------------
+    # against the plain path in float32 on the same bfloat16 operands. (On
+    # the chip alone does a tap's unaligned load read real VMEM: through a
+    # view of one lane tile of a wider scratch it read the NEXT LANE TILE's
+    # rows, which the interpret machine computed right: PERF.md, PR 47.)
+    from tpu_mpi.parallel import ssm
+    from tpu_mpi.xla import conv_kernels
+    t, columns, start, channels, cuts = sz["conv"]
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    row, taps, bias, dout = (
+        jax.random.normal(kk, shape, jnp.float32).astype(jnp.bfloat16)
+        for kk, shape in zip(keys, ((2, t, columns), (4, channels),
+                                    (channels,), (2, t, channels))))
+
+    def conv_with_grads(conv, dtype):
+        def run(*operands):
+            out, vjp = jax.vjp(lambda *a: jnp.concatenate(conv(*a), -1),
+                               *(o.astype(dtype) for o in operands[:3]))
+            return (out,) + vjp(operands[3].astype(dtype))
+        return jax.jit(run)
+
+    got = timed("conv_silu[in place, cut]", conv_with_grads(
+        lambda x, w, b: conv_kernels.conv_silu(
+            x, w, b, start=start, cuts=cuts, interpret=interpret),
+        jnp.bfloat16), row, taps, bias, dout)
+    want = conv_with_grads(lambda x, w, b: jnp.split(jax.nn.silu(
+        ssm.causal_conv(x[..., start:start + channels], w, b)), cuts, -1),
+        jnp.float32)(row, taps, bias, dout)
+    facts["conv_rel_err"] = {}
+    for name, a, b in zip(("out", "dx", "dw", "dbias"), got, want):
+        a = a.astype(jnp.float32)
+        rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        assert rel < 1e-2, f"conv_silu {name} off by {rel}"  # one rounding
+        facts["conv_rel_err"][name] = rel
     # which route the program itself gives an expert layer here, and what
     # its compiled forward and backward hold
     route, calls = _expert_layer_route(sz["expert_layer"])

@@ -137,9 +137,18 @@ def kernel_backend():
     CPU does. ``kernel_backend(word)`` sets it until the test ends; ``with
     kernel_backend(word):`` until the block does. The one place the tests
     steer the choice of a kernel: what a choice reads at trace time is in
-    `choice.trace_key`, and the caches keyed on it follow the word."""
+    `choice.trace_key`, and the caches keyed on it follow the word.
+
+    The interpret machine's host callbacks are ordered effects, which
+    `jax.checkpoint` refuses in what it may recompute; a Mosaic kernel has
+    none. A delta-rule layer recomputes its convolution
+    (`_gdn_mixer`'s `scan_operands`), so for the time of the fixture the
+    callbacks' effect is one a recomputation may hold: the machine only
+    simulates, and running it twice gives the same values."""
+    from jax._src import callback, effects
     from tpu_mpi.xla import choice
     at_start = choice.backend
+    effects.remat_allowed_effects.add_type(callback.OrderedIOEffect)
 
     class set_word:
         def __init__(self, word):
@@ -153,3 +162,5 @@ def kernel_backend():
 
     yield set_word
     choice.backend = at_start
+    effects.remat_allowed_effects._effect_types.discard(
+        callback.OrderedIOEffect)

@@ -788,10 +788,33 @@ def test_compiled_for_the_v5e_the_state_space_step_fits_one_chip(
                   for name, d in scans) == [
         (d, i) for d in ("bwd", "fwd") for i in range(10) if i != 5]
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
-    assert sorted(kernels) == ["causal_attention_bwd", "causal_attention_fwd",
-                               "grouped_row_sums", "ssm_scan_bwd",
-                               "ssm_scan_fwd"], kernels
+    assert sorted(kernels) == [
+        "causal_attention_bwd", "causal_attention_fwd"] \
+        + 3 * ["conv_silu_bwd"] + 3 * ["conv_silu_fwd"] + [
+        "grouped_row_sums", "ssm_scan_bwd", "ssm_scan_fwd"], kernels
+    _the_convolution_is_its_kernels(hlo, names, range(10), 3, skip=(5,))
     tf._block_traced_once.cache_clear()
+
+
+def _the_convolution_is_its_kernels(hlo: str, names, layers, parts: int,
+                                    again: int = 0, skip=()) -> None:
+    """In a compiled step's text: under each recurrent layer's `mixer/conv`
+    the convolution's kernel a part forward (and ``again`` of them once
+    more, in what the backward pass recomputes) and backward, in no other
+    scope, and nothing of the plain path there: no row filled up in front,
+    no silu of XLA's."""
+    import re
+    calls = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*conv_silu_(\w+)/[^\"]*)\"", hlo)
+    assert all("/mixer/" in name and "/conv/" in name for name, _d in calls)
+    assert sorted((d, int(re.search(r"layer_(\d+)", name).group(1)))
+                  for name, d in calls) == sorted(
+        (d, i) for d, n in (("bwd", parts), ("fwd", parts + again))
+        for i in layers if i not in skip for _ in range(n))
+    under = [n for n in names if "/mixer/" in n and "/conv/" in n]
+    assert not [n for n in under if n.endswith("/logistic")]
+    assert not [n for n in under if n.endswith("/pad")
+                and "transpose(" not in n]
 
 
 def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
@@ -872,9 +895,13 @@ def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
     assert "ragged-dot" not in hlo
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
     assert sorted(set(kernels)) == [
-        "causal_attention_bwd", "causal_attention_fwd", "delta_scan_bwd",
-        "delta_scan_fwd", "grouped_matmul_dlhs", "grouped_matmul_drhs",
-        "grouped_matmul_fwd", "grouped_row_sums"]
+        "causal_attention_bwd", "causal_attention_fwd", "conv_silu_bwd",
+        "conv_silu_fwd", "delta_scan_bwd", "delta_scan_fwd",
+        "grouped_matmul_dlhs", "grouped_matmul_drhs", "grouped_matmul_fwd",
+        "grouped_row_sums"]
+    # q's, k's and v's; forward again for q and k alone, v is kept
+    _the_convolution_is_its_kernels(hlo, names, (0, 1, 2, 4, 5, 6), 3,
+                                    again=2)
     assert kernels.count("causal_attention_fwd") == \
         kernels.count("causal_attention_bwd") == 1, kernels
     assert kernels.count("delta_scan_fwd") == \
@@ -941,4 +968,43 @@ def test_compiled_for_the_v5e_the_selective_scan_is_one_kernel_each_way(
     assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
     assert " while(" not in hlo
     assert [(o.shape, o.dtype) for o in jax.tree.leaves(compiled.out_info)] \
+        == [(o.shape, o.dtype) for o in operands]
+
+
+@pytest.mark.parametrize("what, columns, start, channels, cuts", [
+    ("granite", 8512, 4096, 4352, (4096, 4224)),
+    ("phi", 10240, 0, 5120, ()),
+    ("qwen3-next", 8192, 0, 8192, (2048, 4096))])
+def test_compiled_for_the_v5e_the_convolution_is_one_kernel_each_way_a_part(
+        what, columns, start, channels, cuts, v5e_2x2):
+    """The causal convolution's kernel pair at the shapes of the benchmark's
+    three recurrent stages (one sequence of 8192 tokens, four taps,
+    bfloat16; granite's 4352 channels from column 4096 of a row of 8512,
+    phi's first 5120 of 10240, Qwen3-Next's 8192 cut into q, k and v) lowers
+    through Mosaic forward and backward (unaligned loads of the float32 rows,
+    a second window on x): a kernel a part each way and no loop of XLA's,
+    and the gradients come back with their operands' shapes and types, dx
+    the whole row's."""
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import conv_kernels as ck
+    one = SingleDeviceSharding(v5e_2x2[0])
+    bf16 = jnp.bfloat16
+    operands = tuple(jax.ShapeDtypeStruct(shape, bf16, sharding=one)
+                     for shape in ((1, 8192, columns), (4, channels),
+                                   (channels,)))
+
+    def both(x, w, bias):
+        out, back = jax.vjp(lambda *a: ck.conv_silu(
+            *a, start=start, cuts=cuts, interpret=False), x, w, bias)
+        return out, back(jax.tree.map(jnp.ones_like, out))
+    compiled = jax.jit(both).lower(*operands).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") \
+        == 2 * (len(cuts) + 1)
+    assert " while(" not in hlo
+    out, grads = compiled.out_info
+    widths = [hi - lo for lo, hi in zip((0, *cuts), (*cuts, channels))]
+    assert [(o.shape, o.dtype) for o in jax.tree.leaves(out)] \
+        == [((1, 8192, n), bf16) for n in widths]
+    assert [(o.shape, o.dtype) for o in grads] \
         == [(o.shape, o.dtype) for o in operands]

@@ -532,6 +532,20 @@ def test_eight_layers_are_two_traces_and_counted():
     perfvars.reset()
 
 
+def test_a_delta_layers_conv_scope_holds_the_kernel_where_selected(
+        kernel_backend):
+    """Wide enough for the contract (q, k and v of 128, 128 and 256
+    channels, 128 tokens), the traced gradient holds `conv_silu_fwd` and
+    `conv_silu_bwd` under `mixer/conv` (its forward again in what the
+    backward pass recomputes) and no pad or shifted-slice chain; on the CPU
+    the chain and no kernel."""
+    from test_conv_kernel import check_the_conv_scope
+    check_the_conv_scope(dataclasses.replace(
+        CFG, n_layers=1, mixer_kinds=("gdn",), remat_layers=(), max_seq=128,
+        gdn_key_heads=1, gdn_key_dim=128, gdn_value_heads=2,
+        gdn_value_dim=128, gdn_chunk=64), kernel_backend)
+
+
 # -- what is refused ------------------------------------------------------------
 
 @pytest.mark.parametrize("fields, match", [

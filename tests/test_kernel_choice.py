@@ -1,7 +1,7 @@
 """The seam where a kernel or its plain path is chosen (`tpu_mpi/xla/
 choice.py`) and the counters of it (`perfvars.FAMILIES`): what a snapshot
 holds before anything is traced, by the names the yardstick's readers use;
-each of the eight choices counted under its family as the kernel where the
+each of the nine choices counted under its family as the kernel where the
 tests' word selects it and as the plain path where nothing does; each
 contract's own operand types; the rule's parts; and the trace key: a layer
 and a sum of rows traced under one word are traced again under the other.
@@ -44,6 +44,7 @@ FRESH = {
     "sel_scan_kernel_lowerings": {"kernel": 0, "plain": 0},
     "delta_lowerings": {"chunked": 0, "padded": 0},
     "delta_kernel_lowerings": {"kernel": 0, "plain": 0},
+    "conv_kernel_lowerings": {"kernel": 0, "plain": 0},
     "head_loss_lowerings": {"blocked": 0, "whole": 0},
     "head_loss_blocks": {},
 }
@@ -135,6 +136,12 @@ def _delta_scan():
     return lambda: delta.delta_scan(qk, qk, v, g, g, 64)
 
 
+def _conv():
+    x, w = jnp.zeros((1, 128, 384), F32), jnp.zeros((4, 256), F32)
+    return lambda: ssm.conv_silu(x, w, jnp.zeros((256,), F32), start=128,
+                                 cuts=(128,))
+
+
 # one small call of each choice, at a shape inside its kernel's contract
 CALLS = {
     "attention": (choice.ATTENTION, _attention),
@@ -145,6 +152,7 @@ CALLS = {
     "scan": (choice.SCAN, _scan),
     "selective scan": (choice.SEL_SCAN, _sel_scan),
     "delta scan": (choice.DELTA_SCAN, _delta_scan),
+    "convolution": (choice.CONV, _conv),
 }
 
 
@@ -179,6 +187,7 @@ CONTRACTS = {
     "scan": (choice.SCAN, (8, 64, 128, 128, None), 4),
     "selective scan": (choice.SEL_SCAN, (512, 16, None), 2),
     "delta scan": (choice.DELTA_SCAN, (2, 1, 128, 128, 64, None), 5),
+    "convolution": (choice.CONV, (128, 256, 4, None, 128, (128,)), 3),
 }
 
 

@@ -492,6 +492,18 @@ def test_sixteen_layers_are_five_traces_and_counted():
                                                          "padded": 0}
 
 
+def test_a_mamba_layers_conv_scope_holds_the_kernel_where_selected(
+        kernel_backend):
+    """Wide enough for the contract (the first 128 of the in-projection's
+    256 columns, 128 tokens), the traced gradient holds `conv_silu_fwd` and
+    `conv_silu_bwd` under `mixer/conv` and no pad or shifted-slice chain;
+    on the CPU the chain and no kernel."""
+    from test_conv_kernel import check_the_conv_scope
+    cfg = dataclasses.replace(CFG, d_model=64, max_seq=128, remat_layers=())
+    assert cfg.mamba_inner == 128
+    check_the_conv_scope(cfg, kernel_backend)
+
+
 def test_a_model_without_the_new_kinds_traces_what_it_traced():
     """The flagship and a grouped-query stack, their new fields at their
     defaults or named: the same jaxpr, equation for equation, and the same

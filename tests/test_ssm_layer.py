@@ -372,6 +372,20 @@ def test_ten_layers_of_two_mixers_are_two_traces_and_counted():
     assert perfvars.snapshot()["scan_lowerings"] == {"chunked": 0, "padded": 0}
 
 
+def test_a_state_space_layers_conv_scope_holds_the_kernel_where_selected(
+        kernel_backend):
+    """Wide enough for the contract (512 + 2 x 128 channels from column 512
+    of the in-projection's product, 128 tokens), the traced gradient holds
+    `conv_silu_fwd` and `conv_silu_bwd` under `mixer/conv` and no pad or
+    shifted-slice chain; on the CPU the chain and no kernel."""
+    from test_conv_kernel import check_the_conv_scope
+    check_the_conv_scope(TransformerConfig(
+        vocab=64, d_model=256, n_heads=4, n_layers=1, d_ff=128, max_seq=128,
+        dtype=jnp.float32, mixer_kinds=["ssm"], ssm_expand=2, ssm_heads=8,
+        ssm_head_dim=64, ssm_state=128, ssm_conv=4, ssm_chunk=128,
+        rope_full_layers=False, dense_gated=True), kernel_backend)
+
+
 def test_attention_alone_at_multipliers_of_one_traces_what_it_traced():
     """A model without a state-space layer, its mixers named or not, its
     multipliers 1.0: the same jaxpr, equation for equation; the three

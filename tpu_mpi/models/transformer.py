@@ -1289,12 +1289,12 @@ def _ssm_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
     inner, h, n = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_state
     y = _norm(cfg, x, layer, "ln1")
     with jax.named_scope("in_proj"):
-        z, xbc, dt = jnp.split(y @ layer["w_ssm_in"],
-                               [inner, 2 * inner + 2 * n], axis=-1)
+        proj = y @ layer["w_ssm_in"]
+        z, dt = proj[..., :inner], proj[..., 2 * inner + 2 * n:]
     with jax.named_scope("conv"):
-        xbc = jax.nn.silu(ssm.causal_conv(xbc, layer["conv_w"],
-                                          layer["conv_b"]))
-        xs, b_in, c_in = jnp.split(xbc, [inner, inner + n], axis=-1)
+        xs, b_in, c_in = ssm.conv_silu(
+            proj, layer["conv_w"], layer["conv_b"], start=inner,
+            cuts=(inner, inner + n))
     with jax.named_scope("scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
         o = ssm.scan(xs.reshape(b, t, h, cfg.ssm_head_dim), dt,
@@ -1366,9 +1366,9 @@ def _gdn_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
         beta, a = jnp.split(y @ layer["w_gdn_ba"], 2, axis=-1)
     def scan_operands(qkv, beta, a, conv_w, a_log, dt_bias):
         with jax.named_scope("conv"):
-            qkv = jax.nn.silu(ssm.causal_conv(qkv, conv_w, jnp.zeros((), f32)))
+            parts = ssm.conv_silu(qkv, conv_w, cuts=(kw, 2 * kw))
         with jax.named_scope("prep"):
-            q, k, v = jnp.split(qkv, [kw, 2 * kw], axis=-1)
+            q, k, v = parts
             q, k = (_l2_normed(part.reshape(b, t, hk, cfg.gdn_key_dim))
                     for part in (q, k))
             q = (q * cfg.gdn_key_dim ** -0.5).astype(x.dtype)
@@ -1412,10 +1412,10 @@ def _mamba_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray):
     inner, n, r = cfg.mamba_inner, cfg.ssm_state, cfg.ssm_dt_rank
     y = _norm(cfg, x, layer, "ln1")
     with jax.named_scope("in_proj"):
-        xs, z = jnp.split(y @ layer["w_ssm_in"], [inner], axis=-1)
+        proj = y @ layer["w_ssm_in"]
+        z = proj[..., inner:]
     with jax.named_scope("conv"):
-        xs = jax.nn.silu(ssm.causal_conv(xs, layer["conv_w"],
-                                         layer["conv_b"]))
+        xs = ssm.conv_silu(proj, layer["conv_w"], layer["conv_b"])
     with jax.named_scope("x_proj"):
         dt, b_in, c_in = jnp.split(xs @ layer["w_ssm_x"], [r, r + n], axis=-1)
         dt = jax.nn.softplus((dt @ layer["w_ssm_dt"]).astype(jnp.float32)

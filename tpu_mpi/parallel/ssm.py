@@ -60,6 +60,26 @@ of 256 or 128 tokens and holds a block's token states in VMEM alone. ``plain``
 (everywhere else; the tests' yardstick): :func:`_selective_chunks`, `lax.scan`
 over chunks and tokens, `jax.checkpoint` a chunk. Counted as ``chunked`` or
 ``padded`` (``sel_scan_lowerings``) and by who (``sel_scan_kernel_lowerings``).
+
+:func:`conv_silu` is the convolution in front of either scan (and of the
+delta rule's, `parallel/delta.py`) with the silu behind it, as one call: k
+taps over a token and the k - 1 before it, a channel at a time, and who
+computes it is chosen and counted as the scans' are
+(``["conv_kernel_lowerings"]``, ``kernel`` or ``plain``, one count a traced
+call). ``kernel``: the Pallas pair of ``xla/conv_kernels.py`` where a kernel
+backend is there and the shapes fit: float32 or bfloat16, 2 to 4 taps, the
+channels, their first column in the row they are read from and the places
+the result is cut at in 128s (a tile's lanes), the tokens a multiple of 128
+(a block is the largest of 1024, 512, 256 and 128 that divides them). It
+reads its channels where they stand in a wider row (a mixer's in-projection
+gives the gate and the convolved channels as one product) and writes each
+part the caller cuts as an array of its own, so no copy stands beside it;
+the taps' sum and the silu are float32 with ONE rounding at the store (the
+plain path rounds the sum, then the silu), and the backward pass keeps x, w
+and bias alone: the row and the cotangent read, one row written, dw and
+dbias summed in VMEM. ``plain``: `jax.nn.silu` of :func:`causal_conv`, as it
+stands, everywhere else (the CPU, odd widths, a sequence no block divides);
+what `tests/test_conv_kernel.py` holds the kernels to.
 """
 
 from __future__ import annotations
@@ -72,7 +92,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import perfvars
-from ..xla import choice, sel_scan_kernels, ssm_kernels
+from ..xla import choice, conv_kernels, sel_scan_kernels, ssm_kernels
 
 STATES = "ssm_chunk_states"     # what the backward pass keeps of `_chunked`
 
@@ -89,6 +109,26 @@ def causal_conv(x: jnp.ndarray, w: jnp.ndarray, bias: jnp.ndarray):
         out = out + padded[:, j:j + t].astype(jnp.float32) * w[j].astype(
             jnp.float32)
     return out.astype(x.dtype)
+
+
+def conv_silu(x: jnp.ndarray, w: jnp.ndarray, bias=None, *, start: int = 0,
+              cuts: tuple = ()):
+    """silu(causal_conv(x[..., start:start + channels], w, bias)): x [b, t,
+    columns], w [k, channels], bias [channels] (None: no bias). ``cuts``:
+    the result as the list of its parts, cut at these channels as
+    `jnp.split` cuts. Each call built into a traced program counts in
+    ``perfvars.snapshot()["conv_kernel_lowerings"]`` as ``kernel``
+    (`conv_kernels.conv_silu`) or ``plain``."""
+    (k, width), cuts = w.shape, tuple(cuts)
+    run = choice.decide(choice.CONV, x.shape[1], width, k, x.dtype, start,
+                        cuts)
+    if run:
+        return conv_kernels.conv_silu(x, w, bias, start=start, cuts=cuts,
+                                      interpret=run.interpret)
+    out = jax.nn.silu(causal_conv(
+        x[..., start:start + width], w,
+        jnp.zeros((), jnp.float32) if bias is None else bias))
+    return jnp.split(out, cuts, axis=-1) if cuts else out
 
 
 def scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,

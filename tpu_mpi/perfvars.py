@@ -606,6 +606,9 @@ FAMILIES: Dict[str, Any] = {
     "delta_lowerings": ("chunked", "padded"),
     # and who: `xla/delta_kernels.py` or `parallel.delta._chunked`
     "delta_kernel_lowerings": ("kernel", "plain"),
+    # `parallel.ssm.conv_silu`, a recurrent mixer's convolution and silu:
+    # `xla/conv_kernels.py` or `causal_conv` and `jax.nn.silu`
+    "conv_kernel_lowerings": ("kernel", "plain"),
     # `models.transformer.head_loss` over blocks of tokens, or `_xent` of
     # the whole logits (the two pipelined steps), one count a traced loss
     "head_loss_lowerings": ("blocked", "whole"),

@@ -30,7 +30,7 @@ import jax
 
 from .. import perfvars
 from . import pallas_kernels as pk
-from . import delta_kernels, sel_scan_kernels, ssm_kernels
+from . import conv_kernels, delta_kernels, sel_scan_kernels, ssm_kernels
 
 
 def backend() -> Optional[str]:
@@ -92,6 +92,9 @@ SEL_SCAN = Choice(sel_scan_kernels.sel_scan_selected,
 # `parallel.delta.delta_scan`
 DELTA_SCAN = Choice(delta_kernels.delta_scan_selected,
                     "delta_kernel_lowerings", "kernel", "plain")
+# `parallel.ssm.conv_silu`
+CONV = Choice(conv_kernels.conv_silu_blocks, "conv_kernel_lowerings",
+              "kernel", "plain")
 
 
 class Run(NamedTuple):
