@@ -46,6 +46,13 @@ import pytest
 # PR 34's line that the six build entries' lists ARE its cells,
 # `by_name[name] == {..., "workloads": cells}`, is falsified too, and is
 # asserted again in the same file, the cells as a subset.)
+# (PR 48 appends an eleventh cell to `delta_chunked_share`'s list, whose reader
+# finds the Kimi cell's scans as it finds Qwen3-Next's: PR 45's line that its
+# five new entries' lists ARE its one cell, `by_name[name]["workloads"] ==
+# [CELL]`, is falsified in its turn; the five entries, that cell first in
+# each and this one in `delta_chunked_share` alone, are asserted again in
+# `yardstick/tests/test_lm_kda_train_step.py`. `per_layer` was full at 128:
+# that file's own assertions are by name and as subsets.)
 LAST_ENTRIES_TESTS = (
     "yardstick/tests/test_lm_kinds_train_step.py::"
     "test_the_accepted_metrics_stand",
@@ -66,7 +73,9 @@ LAST_ENTRIES_TESTS = (
     "yardstick/tests/test_blocked_head_share.py::"
     "test_the_six_train_cells_report_it_and_no_other_cell_does",
     "yardstick/tests/test_build_metrics.py::"
-    "test_the_six_entries_by_name_and_content")
+    "test_the_six_entries_by_name_and_content",
+    "yardstick/tests/test_lm_gdn_train_step.py::"
+    "test_the_cell_reports_what_the_issue_names")
 
 
 def pytest_collection_modifyitems(items):

@@ -916,6 +916,117 @@ def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
     tf._block_traced_once.cache_clear()
 
 
+def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
+                                                         kernel_backend):
+    """The benchmark's Kimi-Linear-48B-A3B share (yardstick/configs/
+    kimi-linear-48b-a3b-1c.json: published widths, layers 1-8, six KDA layers
+    whose decay is a number a key channel and two positionless latent
+    attention layers, the dense FFN once and seven expert layers of 32 of 256
+    experts, an eighth of the vocabulary, batch 1 x 8192, parameters donated)
+    compiled for one described v5e chip with the kernels selected as on a
+    TPU: 2.093 B parameters, at most 14.9 GB with every FFN half recomputed
+    and a KDA layer's first half one recomputed function of the stream (PR
+    48: 13.82 GB; 16.6 with the half's products kept, 16.95 while the decayed
+    products' [16, 16, 128] form a pair and channel was an array in HBM); no
+    [.., t, t] buffer and no [.., r, r, key width] one; the two-term attention
+    kernel at (8192, 128 + 64, 128) forward and backward in layers 3 and 7
+    alone, nothing rotated; the seven scopes of a KDA mixer in the other six,
+    the scan on the plain path (the delta kernel's contract refuses a decay a
+    channel) with its state's chain once each way a layer, PR 47's
+    convolution kernel a part; three traces of the block (KDA + dense, KDA +
+    experts, latent + experts); the experts' products on the grouped kernel;
+    and nothing the compiler chose to compute again to fit."""
+    import json
+    import os
+    import re
+    kernel_backend("mosaic")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from tpu_mpi import perfvars, xla
+    from tpu_mpi.models import transformer as tf
+    from tpu_mpi.models.transformer import (TransformerConfig,
+                                            transformer_init,
+                                            transformer_train_step)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "yardstick", "configs",
+                           "kimi-linear-48b-a3b-1c.json")) as f:
+        conf = json.load(f)
+    fields = dict(conf["model"], max_seq=8192)
+    fields["dtype"] = jnp.dtype(fields["dtype"])
+    cfg = TransformerConfig(**fields)
+    mesh = xla.make_mesh(dict(conf["mesh"]), devices=v5e_2x2[:1])
+    tf._block_traced_once.cache_clear()
+    perfvars.reset()
+    step, specs = transformer_train_step(cfg, mesh, lr=conf["lr"], donate=True)
+    shapes = jax.eval_shape(lambda k: transformer_init(k, cfg),
+                            jax.random.key(0))
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 2_092_548_288
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        shapes, specs)
+    tok = jax.ShapeDtypeStruct((1, 8192), jnp.int32,
+                               sharding=NamedSharding(mesh, P("dp", "sp")))
+    lowered = step.lower(params, tok, tok)
+    assert tf._block_traced_once.cache_info().currsize == 3
+    snap = perfvars.snapshot()
+    assert snap["delta_kernel_lowerings"] == {"kernel": 0, "plain": 2}
+    assert snap["delta_decays"] == {"head": 0, "channel": 2}
+    assert snap["attn_kinds"] == {"latent": "fused"}
+    assert snap["rope_forms"] == {"dense": 0, "halves": 0}
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    held = m.argument_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert m.alias_size_in_bytes > 4.1e9        # the parameters are reused
+    assert 12e9 < held <= 14.9e9, held
+    hlo = compiled.as_text()
+    assert not re.search(r"\[\d+,\d+,8192,8192\]", hlo)     # no [.., t, t]
+    assert not re.search(r"\[[\d,]*16,16,128\]", hlo)    # nor [.., r, r, dk]
+    assert ".remat" not in hlo          # the compiler recomputes nothing
+    calls = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*causal_attention_(\w+)[^\"]*)\"", hlo)
+    assert sorted((d, int(re.search(r"layer_(\d+)", name).group(1)))
+                  for name, d in calls) == [("bwd", 3), ("bwd", 7),
+                                            ("fwd", 3), ("fwd", 7)]
+    assert all("/attn/" in name for name, _d in calls)
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for i in (0, 2, 4, 6):
+        for scope in ("in_proj", "conv", "prep", "decay", "scan", "gate_norm",
+                      "out_proj"):
+            assert [n for n in names if f"layer_{i})" in n and "/mixer/" in n
+                    and f"/{scope}/" in n], (i, scope)
+        assert not [n for n in names if f"layer_{i})" in n and "/attn/" in n]
+    for i in (3, 7):
+        assert not [n for n in names if f"layer_{i})" in n and "/mixer/" in n]
+        assert [n for n in names if f"layer_{i})" in n
+                and "/attn/q_proj/" in n], i
+        assert not [n for n in names if f"layer_{i})" in n
+                    and "/attn/q_latent/" in n], i
+    assert [n for n in names if "layer_0)" in n and "/mlp/dense/" in n]
+    assert not [n for n in names if "layer_0)" in n and "/mlp/router/" in n]
+    assert "ragged-dot" not in hlo
+    kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
+    assert sorted(set(kernels)) == [
+        "causal_attention_bwd", "causal_attention_fwd", "conv_silu_bwd",
+        "conv_silu_fwd", "grouped_matmul_dlhs", "grouped_matmul_drhs",
+        "grouped_matmul_fwd", "grouped_row_sums"]
+    # q's, k's and v's; forward again for all three: the half is recomputed
+    _the_convolution_is_its_kernels(hlo, names, (0, 1, 2, 4, 5, 6), 3,
+                                    again=3)
+    assert kernels.count("causal_attention_fwd") == \
+        kernels.count("causal_attention_bwd") == 1, kernels
+    # the state's chain once each way a KDA layer, and no other loop there
+    loops = [re.search(r"layer_(\d+)", n).group(1) + (
+        " bwd" if "transpose(" in n else " fwd")
+        for n in re.findall(r"(?m)^\s*%[\w.\-]+ = [^\n]*? while\([^\n]*"
+                            r"op_name=\"([^\"]*)\"", hlo)
+        if "/mixer/" in n and "/scan/" in n]
+    assert sorted(loops) == sorted(
+        f"{i} {d}" for i in (0, 1, 2, 4, 5, 6) for d in ("bwd", "fwd")), loops
+    tf._block_traced_once.cache_clear()
+    perfvars.reset()
+
+
 def test_compiled_for_the_v5e_the_attention_kernel_with_values_wider_than_scores(
         v5e_2x2):
     """The fused causal attention, forward and backward, at differential

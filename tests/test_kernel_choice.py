@@ -44,6 +44,7 @@ FRESH = {
     "sel_scan_kernel_lowerings": {"kernel": 0, "plain": 0},
     "delta_lowerings": {"chunked": 0, "padded": 0},
     "delta_kernel_lowerings": {"kernel": 0, "plain": 0},
+    "delta_decays": {"head": 0, "channel": 0},
     "conv_kernel_lowerings": {"kernel": 0, "plain": 0},
     "head_loss_lowerings": {"blocked": 0, "whole": 0},
     "head_loss_blocks": {},
@@ -208,6 +209,48 @@ def test_a_contract_holds_its_kernel_to_its_own_operand_types(
     for dtype in (jnp.float16, "float64", jnp.int32):
         assert choice.fit(chosen, *asked(dtype)) is None
     assert choice.fit(chosen, *asked(F32), also=False) is None
+
+
+@pytest.mark.parametrize("operands, fits", [
+    ((32, 16, 128, 128, 64, jnp.bfloat16), True),       # Qwen3-Next's
+    ((32, 16, 128, 128, 64, jnp.bfloat16, 1), True),
+    ((32, 16, 128, 128, 64, jnp.bfloat16, 128), False),     # a vector decay
+    ((32, 32, 128, 128, 64, jnp.bfloat16, 1), False),       # 32 key heads
+    ((32, 32, 128, 128, 64, jnp.bfloat16, 128), False),     # KDA's: both
+], ids=["qwen3-next", "one-a-head", "a-channel", "32-key-heads", "kda"])
+def test_the_delta_kernels_contract_refuses_a_decay_a_channel(
+        operands, fits, kernel_backend):
+    """The kernel pair takes a token's decay as scalars and two value heads
+    a key head: under the `mosaic` word Qwen3-Next's shapes select it, and a
+    decay a key channel or as many key heads as value heads is refused by
+    rule, from the shapes."""
+    kernel_backend("mosaic")
+    assert (choice.fit(choice.DELTA_SCAN, *operands) is not None) is fits
+
+
+def test_a_vector_decays_scan_is_counted_plain_under_the_kernels_word(
+        kernel_backend):
+    """KDA's shapes traced under `mosaic`: no kernel in the program, the call
+    counted `plain` and its decay `channel`; Qwen3-Next's beside it counts
+    `kernel` and `head`."""
+    qk = jnp.zeros((1, 64, 2, 128), jnp.bfloat16)
+    g = jnp.zeros((1, 64, 2, 128), F32)
+    perfvars.reset()
+    with kernel_backend("mosaic"):
+        traced = str(jax.make_jaxpr(
+            lambda: delta.delta_scan(qk, qk, qk, g, g[..., 0], 64))())
+        assert "pallas_call" not in traced
+        assert perfvars.snapshot()["delta_kernel_lowerings"] == {
+            "kernel": 0, "plain": 1}
+        assert perfvars.snapshot()["delta_decays"] == {"head": 0,
+                                                       "channel": 1}
+        traced = str(jax.make_jaxpr(lambda: delta.delta_scan(
+            qk[:, :, :1], qk[:, :, :1], qk, g[..., 0], g[..., 0], 64))())
+        assert "pallas_call" in traced
+    assert perfvars.snapshot()["delta_kernel_lowerings"] == {"kernel": 1,
+                                                             "plain": 1}
+    assert perfvars.snapshot()["delta_decays"] == {"head": 1, "channel": 1}
+    perfvars.reset()
 
 
 def test_the_rule_counts_what_it_decides_and_hands_on_the_flag(

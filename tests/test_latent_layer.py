@@ -365,7 +365,7 @@ def test_a_program_traces_each_layer_kind_once_and_counts_latent_calls():
 
 
 @pytest.mark.parametrize("fields, match", [
-    (dict(q_latent=0), "together"),
+    (dict(d_rope=0), "together"),
     (dict(n_kv_heads=2), "no window"),
     (dict(heads_held=[6, 4]), "heads_held"),
     (dict(heads_held=[0, 0]), "heads_held"),

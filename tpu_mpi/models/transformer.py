@@ -103,8 +103,12 @@ class TransformerConfig:
     #                             `w_ukv` [kv_latent, heads x (d_head + d_value)]
     q_latent: int = 0           # the query latent's width: `w_dq`,
     #                             `q_latent_norm`, `w_uq` [q_latent, heads x
-    #                             (d_head + d_rope)]
+    #                             (d_head + d_rope)]; 0: no query latent, one
+    #                             `w_q` [d, heads x (d_head + d_rope)]
     d_rope: int = 0             # the rotated part's width; RoPE turns it alone
+    #                             (under `rope_full_layers` False nothing
+    #                             turns: the part and the shared key are
+    #                             scored as they stand)
     d_value: int = 0            # a value head's width; 0: head_dim
     heads_held: tuple = ()      # (first, count): this rank holds heads
     #                             [first, first + count) of n_heads and adds
@@ -162,6 +166,10 @@ class TransformerConfig:
     #                             head h // (value heads / key heads)
     gdn_conv: int = 4           # the convolution's taps over q, k and v
     gdn_chunk: int = 64         # tokens a chunk of the scan, a power of two
+    # A "kda" layer is the same rule with a decay a key CHANNEL (`_kda_mixer`;
+    # the gdn_* sizes are its own): the decay and the output's sigmoid gate
+    # each come through a low-rank map, d_model -> kda_rank -> all heads.
+    kda_rank: int = 0
     # What follows is data on the attention and expert halves; each adds no
     # equation at its default.
     attn_out_gate: bool = False     # `w_q` is twice as wide, [queries | gate]:
@@ -185,10 +193,11 @@ class TransformerConfig:
                     and len(value) != self.n_layers:
                 raise ValueError(f"{name} has {len(value)} entries for "
                                  f"{self.n_layers} layers")
-        if self.kv_latent and not (self.q_latent and self.d_rope
-                                   and self.d_head):
-            raise ValueError("latent attention names kv_latent, q_latent, "
-                             "d_rope and d_head together")
+        if self.kv_latent and not (self.d_rope and self.d_head):
+            raise ValueError("latent attention names kv_latent, d_rope and "
+                             "d_head together")
+        if self.q_latent and not self.kv_latent:
+            raise ValueError("q_latent is latent attention's: set kv_latent")
         if self.kv_latent and (self.n_kv_heads or self.qk_norm
                                or self.qk_norm_heads or any(self.attn_windows)):
             raise ValueError("latent attention has a key and a value a head, "
@@ -242,7 +251,10 @@ class TransformerConfig:
         """A delta-rule layer has its sizes; a rotated share fits its head;
         the gates and the norm's offset stand where their equations do."""
         chunk = self.gdn_chunk
-        if "gdn" in self.mixer_kinds and not (
+        if "kda" in self.mixer_kinds and self.kda_rank <= 0:
+            raise ValueError("kda layers name kda_rank above 0, the width of "
+                             "the decay's and the gate's low-rank maps")
+        if {"gdn", "kda"} & set(self.mixer_kinds) and not (
                 self.gdn_key_heads > 0 and self.gdn_key_dim > 0
                 and self.gdn_value_dim > 0 and self.gdn_conv > 0
                 and self.gdn_value_heads >= self.gdn_key_heads
@@ -348,7 +360,7 @@ class TransformerConfig:
                          mixer)
 
 
-MIXERS = ("attention", "ssm", "mamba", "gmu", "cross", "gdn")
+MIXERS = ("attention", "ssm", "mamba", "gmu", "cross", "gdn", "kda")
 
 
 class LayerKind(NamedTuple):
@@ -401,6 +413,8 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
             layer.update(_mamba_init(cfg, k[0], k[1], dense))
         elif mixer == "gdn":
             layer.update(_gdn_init(cfg, k[0], k[1], dense))
+        elif mixer == "kda":
+            layer.update(_kda_init(cfg, k[0], k[1], dense))
         elif mixer == "gmu":
             inner = cfg.mamba_inner
             layer["w_gmu_in"] = dense(k[0], (d, inner), d ** -0.5)
@@ -408,14 +422,17 @@ def transformer_init(key, cfg: TransformerConfig) -> dict:
                                        (2 * inner * cfg.n_layers) ** -0.5)
         elif cfg.kv_latent:
             h, cq, ckv = cfg.n_heads_here, cfg.q_latent, cfg.kv_latent
-            for n, (name, shape) in enumerate((
-                    ("w_dq", (d, cq)),
-                    ("w_uq", (cq, h * (cfg.head_dim + cfg.d_rope))),
-                    ("w_dkv", (d, ckv + cfg.d_rope)),
-                    ("w_ukv", (ckv, h * (cfg.head_dim + cfg.value_dim))))):
-                layer[name] = dense(jax.random.fold_in(k[0], 4 + n), shape,
+            queries = h * (cfg.head_dim + cfg.d_rope)
+            shapes = {"w_dkv": (6, (d, ckv + cfg.d_rope)),
+                      "w_ukv": (7, (ckv, h * (cfg.head_dim + cfg.value_dim)))}
+            if cq:
+                shapes.update(w_dq=(4, (d, cq)), w_uq=(5, (cq, queries)))
+                layer["q_latent_norm"] = jnp.ones((cq,), cfg.dtype)
+            else:           # no query latent: one product
+                shapes["w_q"] = (5, (d, queries))
+            for name, (n, shape) in shapes.items():
+                layer[name] = dense(jax.random.fold_in(k[0], n), shape,
                                     shape[0] ** -0.5)
-            layer["q_latent_norm"] = jnp.ones((cq,), cfg.dtype)
             layer["kv_latent_norm"] = jnp.ones((ckv,), cfg.dtype)
         elif cfg.n_kv_heads:
             kv = cfg.n_kv_heads * cfg.head_dim
@@ -536,6 +553,39 @@ def _gdn_init(cfg: TransformerConfig, key, key_out, dense) -> dict:
     }
 
 
+def _kda_init(cfg: TransformerConfig, key, key_out, dense) -> dict:
+    """The leaves of a "kda" layer's mixer. `w_kda_in` [d, q | k | v] and
+    `conv_w` [taps, q | k | v], no bias, as a delta-rule layer's; `w_kda_low`
+    [d, f | g | b]: the decay's and the gate's kda_rank-wide inputs and a
+    write strength a value head; `w_kda_f` [kda_rank, value heads x key
+    width] and `w_kda_g` [kda_rank, values]: the low-rank maps' second
+    halves; `kda_norm` [value width], at one; `w_kda_out` [values, d].
+    Float32, as the recurrence's arithmetic: `a_log` [value heads] (log of
+    values drawn uniformly from (1, 16)) and `dt_bias` [value heads x key
+    width] (the inverse softplus of values drawn log-uniformly from [0.001,
+    0.1]): a token's decay exp(-exp(a_log) softplus(. + dt_bias)) spans 0.2
+    to 0.999 over the channels."""
+    d, hv, r = cfg.d_model, cfg.gdn_value_heads, cfg.kda_rank
+    kw, vw = cfg.gdn_widths
+    decays = hv * cfg.gdn_key_dim
+    keys = jax.random.split(key, 7)
+    dt = jnp.exp(jax.random.uniform(keys[4], (decays,), jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    return {
+        "w_kda_in": dense(keys[0], (d, 2 * kw + vw), d ** -0.5),
+        "w_kda_low": dense(keys[1], (d, 2 * r + hv), d ** -0.5),
+        "conv_w": dense(keys[2], (cfg.gdn_conv, 2 * kw + vw),
+                        cfg.gdn_conv ** -0.5),
+        "a_log": jnp.log(jax.random.uniform(keys[3], (hv,), jnp.float32,
+                                            1.0, 16.0)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "w_kda_f": dense(keys[5], (r, decays), r ** -0.5),
+        "w_kda_g": dense(keys[6], (r, vw), r ** -0.5),
+        "kda_norm": jnp.ones((cfg.gdn_value_dim,), cfg.dtype),
+        "w_kda_out": dense(key_out, (vw, d), (2 * vw * cfg.n_layers) ** -0.5),
+    }
+
+
 def _mamba_init(cfg: TransformerConfig, key, key_out, dense) -> dict:
     """The leaves of a Mamba-1 layer's mixer. `w_ssm_in` [d, x | z] (the
     convolution's channels and the gate, inner wide each); `conv_w` [taps,
@@ -599,12 +649,20 @@ def transformer_param_specs(cfg: TransformerConfig, tp_axis: Optional[str]) -> d
             out.update({name: rep for name in (
                 "w_gdn_in", "w_gdn_ba", "conv_w", "a_log", "dt_bias",
                 "gdn_norm", "w_gdn_out")})
+        elif mixer == "kda":        # likewise (`_kda_mixer`)
+            del out["w_proj"]
+            out.update({name: rep for name in (
+                "w_kda_in", "w_kda_low", "conv_w", "a_log", "dt_bias",
+                "w_kda_f", "w_kda_g", "kda_norm", "w_kda_out")})
         elif mixer == "gmu":
             del out["w_proj"]
             out.update(w_gmu_in=rep, w_gmu_out=rep)
         elif cfg.kv_latent:   # whole on every rank (tp 1 only: `_latent_attn`)
-            out.update(w_dq=rep, w_uq=rep, w_dkv=rep, w_ukv=rep, w_proj=rep,
-                       q_latent_norm=rep, kv_latent_norm=rep)
+            out.update(w_dkv=rep, w_ukv=rep, w_proj=rep, kv_latent_norm=rep)
+            if cfg.q_latent:
+                out.update(w_dq=rep, w_uq=rep, q_latent_norm=rep)
+            else:
+                out.update(w_q=rep)
         elif cfg.n_kv_heads:
             # differential attention is whole on every rank (`_diff_attn`)
             part = rep if cfg.diff_attn else col
@@ -943,10 +1001,11 @@ def _attn_ffn_block(cfg: TransformerConfig, layer: dict, x: jnp.ndarray,
         with jax.named_scope("mixer"):
             x = x + normed(_ssm_mixer(cfg, layer, x, tp_axis=tp_axis,
                                       sp_axis=sp_axis), "ln1_out")
-    elif kind.mixer == "gdn":
+    elif kind.mixer in ("gdn", "kda"):
+        mixer = _gdn_mixer if kind.mixer == "gdn" else _kda_mixer
         with jax.named_scope("mixer"):
-            x = x + normed(_gdn_mixer(cfg, layer, x, tp_axis=tp_axis,
-                                      sp_axis=sp_axis), "ln1_out")
+            x = x + normed(mixer(cfg, layer, x, tp_axis=tp_axis,
+                                 sp_axis=sp_axis), "ln1_out")
     elif kind.mixer in ("mamba", "gmu") or cfg.diff_attn:
         for axis in (tp_axis, sp_axis):
             if axis is not None and lax.axis_size(axis) > 1:
@@ -1223,15 +1282,19 @@ def _latent_attn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray,
     (what training computes; scoring against the latent itself is a serving
     rewrite). Queries: a `q_latent`-wide normed latent, up-projected a head
     at a time into an unrotated part (`d_head`) and a rotated one
-    (`d_rope`). Keys and values: `w_dkv` gives a `kv_latent`-wide latent and
+    (`d_rope`); with no query latent (`q_latent` 0) one product `w_q` gives
+    the same row. Keys and values: `w_dkv` gives a `kv_latent`-wide latent and
     ONE `d_rope`-wide rotary key a token; the normed latent is up-projected
     a head at a time into an unrotated key (`d_head`) and a value
-    (`d_value`). RoPE turns the rotated parts alone; head j scores (q_j k_j^T
-    + q_rope_j k_rope^T) x (d_head + d_rope) ** -0.5 (`local_attention`: the
-    shared key is read through the kernel's index map, never broadcast).
-    The heads here (`heads_held`, or all) add their part of the output
-    projection. Scopes: `q_latent`, `kv_latent` (down, norm, up), `rope`,
-    `out`."""
+    (`d_value`). RoPE turns the rotated parts alone, and under
+    `rope_full_layers` False nothing (a layer that leaves positions to its
+    neighbours: both parts are scored as they stand); head j scores (q_j
+    k_j^T + q_rope_j k_rope^T) x (d_head + d_rope) ** -0.5
+    (`local_attention`: the shared key is read through the kernel's index
+    map, never broadcast). The heads here (`heads_held`, or all) add their
+    part of the output projection. Scopes: `q_latent` (or `q_proj` where
+    there is no query latent), `kv_latent` (down, norm, up), `rope` (and the
+    query row's cut into heads), `out`."""
     if tp_axis is not None and lax.axis_size(tp_axis) > 1:
         raise NotImplementedError(
             "latent attention runs at tp 1: a rank's heads are `heads_held`, "
@@ -1239,10 +1302,14 @@ def _latent_attn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray,
     b, t, _ = y.shape
     h, dh, dr, dv = cfg.n_heads_here, cfg.head_dim, cfg.d_rope, cfg.value_dim
 
-    with jax.named_scope("q_latent"):
-        c_q = _rms_norm(y @ layer["w_dq"], layer["q_latent_norm"],
-                        cfg.norm_eps)
-        q_row = c_q @ layer["w_uq"]     # (b, t, h x [unrotated | rotated])
+    if cfg.q_latent:
+        with jax.named_scope("q_latent"):
+            c_q = _rms_norm(y @ layer["w_dq"], layer["q_latent_norm"],
+                            cfg.norm_eps)
+            q_row = c_q @ layer["w_uq"]     # (b, t, h x [unrotated | rotated])
+    else:
+        with jax.named_scope("q_proj"):
+            q_row = y @ layer["w_q"]
     with jax.named_scope("kv_latent"):
         down = y @ layer["w_dkv"]
         c_kv = _rms_norm(down[..., :cfg.kv_latent], layer["kv_latent_norm"],
@@ -1251,9 +1318,11 @@ def _latent_attn(cfg: TransformerConfig, layer: dict, y: jnp.ndarray,
         kv = _cut_heads(c_kv @ layer["w_ukv"], h)
         k, v = kv[..., :dh], kv[..., dh:]
     with jax.named_scope("rope"):       # and the query row's cut into heads
+        turns = cfg.rope_full_layers
         q, q_rope = _rope_heads(q_row, positions, cfg.rope_theta, h,
-                                ((dh, False), (dr, True)))
-        k_rope = _rope(k_rope, positions, cfg.rope_theta)
+                                ((dh, False), (dr, turns)))
+        if turns:
+            k_rope = _rope(k_rope, positions, cfg.rope_theta)
     if sp_axis is not None:
         o = ring_attention(q, k, v, axis=sp_axis, causal=True,
                            rope=(q_rope, k_rope))
@@ -1323,6 +1392,30 @@ def _l2_normed(x, eps: float = 1e-6):
                            + eps)
 
 
+def _delta_layer_alone(tp_axis: Optional[str], sp_axis: Optional[str]):
+    """A delta-rule layer's state runs along the whole sequence and its
+    convolution mixes a head's neighbours in time: `sp` > 1 and `tp` > 1 are
+    refused."""
+    for axis in (tp_axis, sp_axis):
+        if axis is not None and lax.axis_size(axis) > 1:
+            raise NotImplementedError(
+                f"a delta-rule layer runs at tp 1 and sp 1 ({axis!r} has "
+                f"{lax.axis_size(axis)} ranks): its state would cross "
+                f"sequence shards and its heads are not cut")
+
+
+def _head_norm_gated(cfg: TransformerConfig, o: jnp.ndarray, scale, gate,
+                     *gate_from):
+    """RMSNorm over each head's values of o [b, t, heads, width] (a plain
+    ``scale``) x ``gate(*gate_from)`` (float32, o's shape): norm first, gate
+    after. Recomputed in the backward pass from o and ``gate_from`` as they
+    stand: left to the compiler, a float32 copy of o is what it keeps."""
+    return _kept_as_rounded(jax.checkpoint(lambda o, *of: (
+        _rms_norm(o, of[-1], cfg.norm_eps).astype(jnp.float32)
+        * gate(*of[:-1]).reshape(o.shape)).astype(o.dtype))(
+            o, *gate_from, scale))
+
+
 def _gdn_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
                tp_axis: Optional[str], sp_axis: Optional[str]) -> jnp.ndarray:
     """A delta-rule (linear attention) layer's first half: what is added to
@@ -1339,15 +1432,9 @@ def _gdn_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
     silu(z): norm first, gate after, where `_ssm_mixer` gates first and
     norms the whole width; and the out-projection. Scopes: `in_proj`,
     `conv`, `prep` (the cut into heads, the L2 norms, beta and g), `scan`,
-    `gate_norm`, `out_proj`. The state runs along the whole sequence and
-    the convolution mixes a head's neighbours in time: `sp` > 1 and `tp` >
-    1 are refused."""
-    for axis in (tp_axis, sp_axis):
-        if axis is not None and lax.axis_size(axis) > 1:
-            raise NotImplementedError(
-                f"a delta-rule layer runs at tp 1 and sp 1 ({axis!r} has "
-                f"{lax.axis_size(axis)} ranks): its state would cross "
-                f"sequence shards and its heads are not cut")
+    `gate_norm`, `out_proj`. `sp` > 1 and `tp` > 1 are refused
+    (`_delta_layer_alone`)."""
+    _delta_layer_alone(tp_axis, sp_axis)
     from ..parallel import delta, ssm   # a program without such a layer
     #                                     pays no import for it
     b, t, _ = x.shape
@@ -1384,14 +1471,77 @@ def _gdn_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
     with jax.named_scope("scan"):
         o = delta.delta_scan(*operands, cfg.gdn_chunk)
     with jax.named_scope("gate_norm"):
-        # recomputed in the backward pass from o and z as they stand: left
-        # to the compiler, a float32 copy of o is what it keeps
-        o = _kept_as_rounded(jax.checkpoint(lambda o, z, w: (
-            _rms_norm(o, w, cfg.norm_eps).astype(f32)
-            * jax.nn.silu(z.astype(f32))).astype(x.dtype))(
-                o, z.reshape(o.shape), layer["gdn_norm"]))
+        o = _head_norm_gated(cfg, o, layer["gdn_norm"],
+                             lambda z: jax.nn.silu(z.astype(f32)),
+                             z.reshape(o.shape))
     with jax.named_scope("out_proj"):
         return o.reshape(b, t, vw) @ layer["w_gdn_out"]
+
+
+def _kda_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
+               tp_axis: Optional[str], sp_axis: Optional[str]) -> jnp.ndarray:
+    """A "kda" layer's first half: the delta rule of `_gdn_mixer` with a
+    decay a key CHANNEL, what is added to the residual. One product gives q
+    | k | v, a second f | g | b: the `kda_rank`-wide inputs of the decay's
+    and of the gate's low-rank maps, and a write strength a value head; q,
+    k and v pass the convolution, silu, the L2 norms and q's scale as a
+    delta-rule layer's (`_gdn_mixer`); beta = sigmoid(b); the decay, one
+    number a value head, token and key channel, is g = -exp(a_log[head]) x
+    softplus(f w_kda_f + dt_bias), float32 (scope `decay`);
+    `parallel.delta.delta_scan` computes S_t = Diag(exp(g_t)) S_{t-1}, S_t +=
+    k_t (beta_t (v_t - S_t^T k_t))^T, o_t = S_t^T q_t in its chunked form;
+    then RMSNorm over each head's values (`kda_norm`, a plain scale) x
+    sigmoid(g w_kda_g): norm first, gate after, a sigmoid where `_gdn_mixer`
+    has silu; and the out-projection. Scopes: `in_proj`, `conv`, `prep`,
+    `decay`, `scan`, `gate_norm`, `out_proj`. The half is ONE function of
+    the stream that the backward pass computes again, keeping of it the
+    state before each chunk alone (`parallel.delta.STATES`: the chain over
+    the chunks runs once each way): the decay is [t, heads x key width]
+    float32, 134 MB a layer at 8192 tokens and 32 heads of 128, the
+    12288-wide row 201 MB and the scan's and the gate's outputs 67 MB each,
+    and with them kept the step of two periods does not fit a chip (16.6 GB
+    compiled for the v5e, 13.8 so: PERF.md section 6, PR 48); the
+    in-projection is the one matrix product that runs again. `sp` > 1 and
+    `tp` > 1 are refused (`_delta_layer_alone`)."""
+    _delta_layer_alone(tp_axis, sp_axis)
+    from ..parallel import delta, ssm
+    b, t, _ = x.shape
+    hv, r, w = cfg.gdn_value_heads, cfg.kda_rank, cfg.gdn_widths[0]
+    f32 = jnp.float32
+
+    def half(x, layer):
+        y = _norm(cfg, x, layer, "ln1")
+        with jax.named_scope("in_proj"):
+            qkv = y @ layer["w_kda_in"]
+            f_in, g_in, beta = jnp.split(y @ layer["w_kda_low"], (r, 2 * r),
+                                         axis=-1)
+        with jax.named_scope("conv"):
+            q, k, v = ssm.conv_silu(qkv, layer["conv_w"], cuts=(w, 2 * w))
+        with jax.named_scope("prep"):
+            q, k = (_l2_normed(part.reshape(b, t, cfg.gdn_key_heads,
+                                            cfg.gdn_key_dim))
+                    for part in (q, k))
+            q = (q * cfg.gdn_key_dim ** -0.5).astype(x.dtype)
+            k, v = k.astype(x.dtype), v.reshape(b, t, hv, cfg.gdn_value_dim)
+            beta = jax.nn.sigmoid(beta.astype(f32))
+        with jax.named_scope("decay"):
+            g = -jnp.exp(layer["a_log"])[:, None] * jax.nn.softplus(
+                jnp.dot(f_in, layer["w_kda_f"], preferred_element_type=f32)
+                + layer["dt_bias"]).reshape(b, t, hv, cfg.gdn_key_dim)
+        with jax.named_scope("scan"):
+            o = delta.delta_scan(q, k, v, g, beta, cfg.gdn_chunk)
+        with jax.named_scope("gate_norm"):
+            o = _head_norm_gated(
+                cfg, o, layer["kda_norm"], lambda g_in, w_g: jax.nn.sigmoid(
+                    jnp.dot(g_in, w_g, preferred_element_type=f32)),
+                g_in, layer["w_kda_g"])
+        with jax.named_scope("out_proj"):
+            return o.reshape(b, t, -1) @ layer["w_kda_out"]
+    mine = ("ln1", "ln1_b", "w_kda_in", "w_kda_low", "conv_w", "a_log",
+            "dt_bias", "w_kda_f", "w_kda_g", "kda_norm", "w_kda_out")
+    return jax.checkpoint(
+        half, policy=jax.checkpoint_policies.save_only_these_names(
+            delta.STATES))(x, {k: layer[k] for k in mine if k in layer})
 
 
 def _mamba_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray):
@@ -1685,7 +1835,8 @@ def transformer_train_step(cfg: TransformerConfig, mesh, lr: float = 1e-2, *,
             f"mesh has {tp_axis} {sizes[tp_axis]}, {sp_axis} "
             f"{sizes[sp_axis]}): a layer's state would cross sequence "
             f"shards; shard its batch over {dp_axis}")
-    if "gdn" in cfg.mixer_kinds and (sizes[tp_axis] > 1 or sizes[sp_axis] > 1):
+    if {"gdn", "kda"} & set(cfg.mixer_kinds) \
+            and (sizes[tp_axis] > 1 or sizes[sp_axis] > 1):
         raise NotImplementedError(
             f"a model with delta-rule layers trains at tp 1 and sp 1 (this "
             f"mesh has {tp_axis} {sizes[tp_axis]}, {sp_axis} "

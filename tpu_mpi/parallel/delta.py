@@ -41,6 +41,16 @@ chunk are kept ([chunks, batch, value heads, key width, value width]
 float32) and every [chunk x chunk] array is computed again: `jax.checkpoint`
 with a policy that saves the states alone.
 
+The decay may also be a vector, one number a value head, token and KEY
+CHANNEL (``g`` [batch, t, value heads, key width]: S_t = Diag(exp(g_t))
+S_{t-1}; counted ``perfvars.snapshot()["delta_decays"]``: ``head`` or
+``channel``). Everything above holds with gamma a vector and the decayed
+[chunk x chunk] forms sum_d k_id k_jd exp(gamma_id - gamma_jd), which are no
+product of K K^T with a matrix: :func:`_decayed_products` computes them by
+halves so that no exponential of a positive number is ever formed; the
+state's carry a chunk is a vector. With every channel alike it is the scalar
+recurrence, to rounding.
+
 The form is chosen from the shapes, never by trying, and counted where it is
 chosen (``perfvars.snapshot()["delta_lowerings"]``): ``chunked`` where the
 sequence is a multiple of the chunk, ``padded`` where it is not: the
@@ -52,7 +62,7 @@ Who computes it is chosen by `xla.choice`'s rule and counted beside the form
 (``perfvars.snapshot()["delta_kernel_lowerings"]``): ``kernel`` where a
 kernel backend is there (a TPU; the tests' word) and the contract
 `xla.delta_kernels.delta_scan_selected` takes the operands (two value heads
-a key head, heads of 128, a chunk of 64, float32 or bfloat16): one Pallas
+a key head, heads of 128, a chunk of 64, a decay a head, float32 or bfloat16): one Pallas
 kernel each way that keeps a chunk's arrays and the state in VMEM, the
 padded form filled with the same zero tokens in front of it; ``plain``
 everywhere else (the CPU, the tests, other shapes): :func:`_chunked`, plain
@@ -79,15 +89,16 @@ def delta_recurrence(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      g: jnp.ndarray, beta: jnp.ndarray) -> jnp.ndarray:
     """o [batch, t, value heads, value width] float32 of the recurrence
     above, one token at a time, everything float32: q and k [batch, t, key
-    heads, key width], v [batch, t, value heads, value width], g and beta
-    [batch, t, value heads]."""
+    heads, key width], v [batch, t, value heads, value width], beta [batch,
+    t, value heads], g the same (a decay a head) or [batch, t, value heads,
+    key width] (a decay a key channel: S_t = Diag(exp(g_t)) S_{t-1})."""
     f32 = jnp.float32
     rep = v.shape[2] // k.shape[2]
     q, k = (jnp.repeat(a.astype(f32), rep, axis=2) for a in (q, k))
 
     def token(s, at):
-        q_t, k_t, v_t, g_t, b_t = at    # [b, h, width] x 3, [b, h] x 2
-        s = s * jnp.exp(g_t)[..., None, None]
+        q_t, k_t, v_t, g_t, b_t = at    # [b, h, width] x 3, [b, h(, dk)], [b, h]
+        s = s * jnp.exp(g_t if g.ndim == 4 else g_t[..., None])[..., None]
         seen = jnp.einsum("bhde,bhd->bhe", s, k_t, precision=_EXACT)
         s = s + k_t[..., :, None] * (b_t[..., None] * (v_t - seen))[..., None, :]
         return s, jnp.einsum("bhde,bhd->bhe", s, q_t, precision=_EXACT)
@@ -104,17 +115,20 @@ def delta_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """o [batch, t, value heads, value width], of v's type, of the
     recurrence above in its chunked form: q and k [batch, t, key heads, key
     width] (the caller's norms and scale applied), v [batch, t, value heads,
-    value width], g (<= 0) and beta [batch, t, value heads] float32;
-    ``chunk`` a power of two. Each call built into a traced program counts
-    in ``perfvars.snapshot()["delta_lowerings"]`` as ``chunked`` or
-    ``padded``, and in ``["delta_kernel_lowerings"]`` as ``kernel`` or
-    ``plain``. The result does not depend on the chunk."""
+    value width], beta [batch, t, value heads] and g (<= 0) the same or
+    [batch, t, value heads, key width], float32; ``chunk`` a power of two.
+    Each call built into a traced program counts in
+    ``perfvars.snapshot()["delta_lowerings"]`` as ``chunked`` or ``padded``,
+    in ``["delta_kernel_lowerings"]`` as ``kernel`` or ``plain`` and in
+    ``["delta_decays"]`` as ``head`` or ``channel``. The result does not
+    depend on the chunk."""
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"chunk={chunk}: the triangular system is inverted "
                          f"by halves, so a chunk is a power of two")
     t = q.shape[1]
     pad = -t % chunk
     perfvars.note("delta_lowerings", "padded" if pad else "chunked")
+    perfvars.note("delta_decays", "channel" if g.ndim == 4 else "head")
 
     def filled(a):      # up to the next multiple, with tokens of zeros
         widths = ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)
@@ -122,6 +136,7 @@ def delta_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     operands = tuple(filled(a) for a in (q, k, v, g, beta))
     run = choice.decide(choice.DELTA_SCAN, v.shape[2], k.shape[2],
                         k.shape[3], v.shape[3], chunk, v.dtype,
+                        g.shape[3] if g.ndim == 4 else 1,
                         also=q.dtype == k.dtype == v.dtype)
     if run:
         return delta_kernels.delta_scan(*operands,
@@ -175,7 +190,7 @@ def _chain_step(s, at, dtype):
     carry, kd, w, u0 = at
     u = u0 - jnp.einsum("bhld,bhde->bhle", w, s.astype(dtype),
                         preferred_element_type=jnp.float32)
-    after = carry[..., None, None] * s + jnp.einsum(
+    after = carry[..., None] * s + jnp.einsum(
         "bhld,bhle->bhde", kd, u.astype(dtype),
         preferred_element_type=jnp.float32)
     return after, u
@@ -184,8 +199,9 @@ def _chain_step(s, at, dtype):
 @jax.custom_vjp
 def _state_chain(carry, kd, w, u0):
     """The state BEFORE each chunk, [chunks, batch, heads, key width, value
-    width] float32, of S' = carry S + kd^T (u0 - w S) from S = 0: ``carry``
-    [chunks, batch, heads] float32 (a chunk's whole decay), ``kd`` and ``w``
+    width] float32, of S' = Diag(carry) S + kd^T (u0 - w S) from S = 0:
+    ``carry`` [chunks, batch, heads, 1 | key width] float32 (a chunk's whole
+    decay, one number a head or one a key channel), ``kd`` and ``w``
     [chunks, batch, heads, chunk, key width] of the input's type (the keys
     decayed to the chunk's end; ``W`` above), ``u0`` [chunks, batch, heads,
     chunk, value width] float32. The one part of the scan that runs in
@@ -222,11 +238,13 @@ def _state_chain_bwd(kept, d_states):
                           preferred_element_type=f32)
         d_w = -jnp.einsum("bhle,bhde->bhld", d_u.astype(dtype),
                           s.astype(dtype), preferred_element_type=f32)
-        d_before = c[..., None, None] * d_after + d_s - jnp.einsum(
+        d_before = c[..., None] * d_after + d_s - jnp.einsum(
             "bhld,bhle->bhde", w_c, d_u.astype(dtype),
             preferred_element_type=f32)
-        return d_before, (jnp.sum(s * d_after, axis=(-1, -2)),
-                          d_kd.astype(dtype), d_w.astype(w.dtype), d_u)
+        d_c = jnp.sum(s * d_after, axis=-1)     # a key channel's
+        if c.shape[-1] == 1:                    # one number a head: all of them
+            d_c = jnp.sum(d_c, axis=-1, keepdims=True)
+        return d_before, (d_c, d_kd.astype(dtype), d_w.astype(w.dtype), d_u)
     _, grads = lax.scan(step, jnp.zeros_like(d_states[0]),
                         (carry, kd, w, u0, states, d_states), reverse=True)
     return grads
@@ -235,41 +253,103 @@ def _state_chain_bwd(kept, d_states):
 _state_chain.defvjp(_state_chain_fwd, _state_chain_bwd)
 
 
+def _for_values(a, hv: int):
+    """A key head's [b, c, hk, x, y] for each of its value heads."""
+    hk = a.shape[2]
+    return jnp.broadcast_to(
+        a[:, :, :, None], a.shape[:3] + (hv // hk,) + a.shape[3:]).reshape(
+            a.shape[:2] + (hv,) + a.shape[3:])
+
+
 def _chunk_parts(q, k, v, g, beta):
     """What the chunks give the state's chain and the outputs, each from
-    its inputs alone ([batch, chunks, heads, chunk, width]; g and beta
-    [batch, chunks, value heads, chunk] float32): (the whole decay a chunk
-    [batch, chunks, value heads] float32; the keys decayed to the chunk's
-    end, ``W`` and the queries decayed from its start, of the input's type;
-    ``U0`` float32; the masked, decayed scores of the input's type). Every
-    [chunk x chunk] array lives and dies here."""
-    hk, hv, length = k.shape[2], v.shape[2], k.shape[3]
+    its inputs alone ([batch, chunks, heads, chunk, width]; beta [batch,
+    chunks, value heads, chunk] and g the same or with the key width behind,
+    float32): (the whole decay a chunk [batch, chunks, value heads, 1 | key
+    width] float32; the keys decayed to the chunk's end, ``W`` and the
+    queries decayed from its start, of the input's type; ``U0`` float32; the
+    masked, decayed scores of the input's type). Every [chunk x chunk] array
+    lives and dies here."""
+    hv, length = v.shape[2], k.shape[3]
     f32, dtype = jnp.float32, v.dtype
-
-    def for_values(a):      # a key head's [.., hk, x, y] for each of its value heads
-        return jnp.broadcast_to(
-            a[:, :, :, None], a.shape[:3] + (hv // hk,) + a.shape[3:]).reshape(
-                a.shape[:2] + (hv,) + a.shape[3:])
-    gamma = jnp.cumsum(g, axis=-1)                  # [b, c, hv, l]
+    gamma = jnp.cumsum(g, axis=3)       # [b, c, hv, l] or [b, c, hv, l, dk]
     seen = jnp.tril(jnp.ones((length, length), dtype=bool))
-    decay = jnp.exp(jnp.where(seen, gamma[..., :, None] - gamma[..., None, :],
-                              -jnp.inf))            # [b, c, hv, l, s], s <= l
-    kk, qk = (for_values(jnp.einsum("bchld,bchsd->bchls", a, k,
-                                    preferred_element_type=f32))
-              for a in (k, q))
-    inverse = _unit_lower_inverse(jnp.where(
-        jnp.tril(seen, -1), beta[..., None] * decay * kk, 0.0)).astype(dtype)
-    k, q = for_values(k).astype(f32), for_values(q).astype(f32)
+    k_v, q_v = (_for_values(a, hv).astype(f32) for a in (k, q))
+    if g.ndim == 5:
+        kk, scores = _decayed_products(k_v, q_v, gamma, dtype)
+        told = beta[..., None] * kk
+    else:
+        decay = jnp.exp(jnp.where(
+            seen, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+        kk, qk = (_for_values(jnp.einsum("bchld,bchsd->bchls", a, k,
+                                         preferred_element_type=f32), hv)
+                  for a in (k, q))            # [b, c, hv, l, s], s <= l
+        told, scores = beta[..., None] * decay * kk, qk * decay
+        gamma = gamma[..., None]        # one number for every key channel
+    last = gamma[..., -1:, :]
+    inverse = _unit_lower_inverse(
+        jnp.where(jnp.tril(seen, -1), told, 0.0)).astype(dtype)
     w = jnp.einsum("bchls,bchsd->bchld", inverse,
-                   (k * (beta * jnp.exp(gamma))[..., None]).astype(dtype),
+                   (k_v * (beta[..., None] * jnp.exp(gamma))).astype(dtype),
                    preferred_element_type=f32).astype(dtype)
     u0 = jnp.einsum("bchls,bchse->bchle", inverse,
                     (v.astype(f32) * beta[..., None]).astype(dtype),
                     preferred_element_type=f32)
-    kd = (k * jnp.exp(gamma[..., -1:] - gamma)[..., None]).astype(dtype)
-    return (jnp.exp(gamma[..., -1]), kd, w, u0,
-            (q * jnp.exp(gamma)[..., None]).astype(dtype),
-            (qk * decay).astype(dtype))
+    kd = (k_v * jnp.exp(last - gamma)).astype(dtype)
+    return (jnp.exp(last[..., 0, :]), kd, w, u0,
+            (q_v * jnp.exp(gamma)).astype(dtype), scores.astype(dtype))
+
+
+def _decayed_products(k, q, gamma, dtype):
+    """(sum_d k_id k_jd exp(gamma_id - gamma_jd), the same of q_i), [batch,
+    chunks, heads, chunk, chunk] float32, for j <= i and zero above, for a
+    decay a key channel (k, q and gamma [batch, chunks, heads, chunk, key
+    width] float32). That is no product of K K^T with anything, and its
+    factors (k_i o exp(gamma_i)) . (k_j o exp(-gamma_j)) overflow float32
+    inside one chunk (gamma reaches -100 at a model's own initial values).
+    No exponential of a positive number is formed here. By halves, as the
+    inverse above: inside a block of 2 s tokens, the rows of its second half
+    against the columns of its first go through the rows' first token n,
+    exp(gamma_i - gamma_n) x exp(gamma_n - gamma_j) with j < n <= i, both
+    factors <= 1, as one product of the two scaled operands (of ``dtype``,
+    accumulated in float32: :func:`_crossed`); the halves are blocks of s,
+    and a block of one token is a pair with itself, exponent 0. log2(chunk)
+    rounds over arrays no larger than the operands: no [r, r, key width]
+    form of a pair and channel exists (written as a masked exponential
+    inside a sum over the channels, the compiler keeps it in HBM: 2.1 GB a
+    layer at 8192 tokens and 32 heads of 128)."""
+    length = k.shape[-2]
+    a = jnp.stack([k, q])                       # both forms at once
+    out = jnp.sum(a * k, axis=-1)[..., None, None]      # [2, .., l, 1, 1]
+    size = 1
+    while size < length:
+        cross = _crossed(a, k, gamma, size, dtype)
+        own = out.reshape(cross.shape[:-2] + (2, size, size))
+        out = jnp.concatenate([
+            jnp.concatenate([own[..., 0, :, :], jnp.zeros_like(cross)], -1),
+            jnp.concatenate([cross, own[..., 1, :, :]], -1)], -2)
+        size *= 2
+    return out[0, ..., 0, :, :], out[1, ..., 0, :, :]
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def _crossed(a, k, gamma, size: int, dtype):
+    """One round of :func:`_decayed_products`: [2, batch, chunks, heads,
+    blocks of 2 ``size``, ``size``, ``size``] float32, the rows of each
+    block's second half against the columns of its first. The scaled
+    operands are as large as half the chunk's inputs and there is a pair a
+    round: each is computed again where the backward pass wants it, and
+    none is kept."""
+    def halves(x):      # [.., l, dk] -> its blocks' ([.., n, size, dk],) x 2
+        x = x.reshape(x.shape[:-2] + (-1, 2, size, x.shape[-1]))
+        return x[..., 0, :, :], x[..., 1, :, :]
+    g_cols, g_rows = halves(gamma)
+    first = g_rows[..., :1, :]                  # the rows' first token
+    return jnp.einsum(
+        "a...rd,...sd->a...rs",
+        (halves(a)[1] * jnp.exp(g_rows - first)).astype(dtype),
+        (halves(k)[0] * jnp.exp(first - g_cols)).astype(dtype),
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(
@@ -286,9 +366,11 @@ def _chunked(q, k, v, g, beta, length: int):
 
     def by_chunk(a, heads):     # [b, t, h, w] -> [b, chunks, h, length, w]
         return a.reshape(bsz, nc, length, heads, -1).transpose(0, 1, 3, 2, 4)
+    by_channel = g.ndim == 4
+    g = by_chunk(g.astype(f32), hv)
     carry, kd, w, u0, q_from_start, scores = _chunk_parts(
         by_chunk(q, hk), by_chunk(k, hk), by_chunk(v, hv),
-        by_chunk(g.astype(f32), hv)[..., 0],
+        g if by_channel else g[..., 0],
         by_chunk(beta.astype(f32), hv)[..., 0])
     states = jnp.moveaxis(_state_chain(*(
         jnp.moveaxis(a, 1, 0) for a in (carry, kd, w, u0))), 0, 1)

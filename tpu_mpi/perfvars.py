@@ -25,8 +25,9 @@ pieces:
   state-space scans by their form as ``scan_lowerings`` and by who computes
   them (the Pallas kernels or plain `jnp`) as ``scan_kernel_lowerings``, the
   per-channel selective scans likewise as ``sel_scan_lowerings`` and
-  ``sel_scan_kernel_lowerings``, the delta-rule scans as ``delta_lowerings``
-  and ``delta_kernel_lowerings``, the layers
+  ``sel_scan_kernel_lowerings``, the delta-rule scans as ``delta_lowerings``,
+  ``delta_kernel_lowerings`` and (a decay a head or a key channel)
+  ``delta_decays``, the layers
   that read a value beside the residual stream as ``side_values``, the
   vocabulary heads and their losses as ``head_loss_lowerings`` (over blocks
   of tokens, or over the whole logits) with ``head_loss_blocks``, and
@@ -590,7 +591,7 @@ FAMILIES: Dict[str, Any] = {
     # swap(x) sin with its own backward, or two half-width products
     "rope_forms": ("dense", "halves"),
     # `_attn_ffn_block`, one count a trace of a layer kind; `mamba`, `gmu`,
-    # `cross` and `gdn` appear once traced
+    # `cross`, `gdn` and `kda` appear once traced
     "mixer_kinds": ("attention", "ssm"),
     # `_side_read`: the layers that read a value written beside the stream
     "side_values": ("memory", "kv"),
@@ -606,6 +607,8 @@ FAMILIES: Dict[str, Any] = {
     "delta_lowerings": ("chunked", "padded"),
     # and who: `xla/delta_kernels.py` or `parallel.delta._chunked`
     "delta_kernel_lowerings": ("kernel", "plain"),
+    # and by its decay: one number a head and token, or one a key channel
+    "delta_decays": ("head", "channel"),
     # `parallel.ssm.conv_silu`, a recurrent mixer's convolution and silu:
     # `xla/conv_kernels.py` or `causal_conv` and `jax.nn.silu`
     "conv_kernel_lowerings": ("kernel", "plain"),

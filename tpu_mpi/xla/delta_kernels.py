@@ -68,14 +68,17 @@ _GAMMA, _BETA, _GROWN, _BETA_GROWN, _TO_END, _WHOLE = range(6)
 
 
 def delta_scan_selected(value_heads: int, key_heads: int, key_width: int,
-                        value_width: int, chunk: int, dtype) -> bool:
+                        value_width: int, chunk: int, dtype,
+                        decay_width: int = 1) -> bool:
     """Whether :func:`delta_scan` takes ``value_heads`` heads of
     ``value_width`` over ``key_heads`` heads of ``key_width`` in chunks of
-    ``chunk`` tokens of ``dtype``: the contract, decided from the shapes and
-    the type."""
+    ``chunk`` tokens of ``dtype``, decayed by ``decay_width`` numbers a head
+    and token (1, or one a key channel): the contract, decided from the
+    shapes and the type. The kernels take a token's decay as scalars in a
+    tile of rows (`_rows`): a decay a channel is refused."""
     return (bool(_typed(dtype)) and value_heads == DELTA_PAIR * key_heads
             and key_width == DELTA_WIDTH and value_width == DELTA_WIDTH
-            and chunk == DELTA_CHUNK)
+            and chunk == DELTA_CHUNK and decay_width == 1)
 
 
 def _rows(g, beta):
