@@ -18,8 +18,10 @@ script starts no child that needs it):
    experts' grouped product compiled by Mosaic, numerics against the XLA
    collective, ``lax.ragged_dot`` (values and both gradients) or a jnp
    reference, the recurrent mixers' convolution against its plain path
-   (values and three gradients); an OLMoE-shaped expert layer takes the kernel's route and
-   compiles to the kernels alone, and its rows' way into expert order and
+   (values and three gradients), the delta-rule scan with a decay a key
+   channel against its plain path (values and five gradients); an
+   OLMoE-shaped expert layer takes the kernel's route and compiles to the
+   kernels alone, and its rows' way into expert order and
    back (`parallel.ep.moe_dropless`) agrees with the plain form, forward
    and backward, the four passes timed;
 4. serve  — ``serve.Broker(nranks=4, infer=True)`` answering three
@@ -84,6 +86,8 @@ FULL = {
     # a recurrent mixer's convolution: seq, columns of the row it reads,
     # first column, channels, cuts (tiles of four lane tiles, two blocks)
     "conv": (2048, 2560, 512, 1536, (1024,)),
+    # the delta-rule scan with a decay a key channel: seq, heads (of 128)
+    "channel_scan": (1024, 4),
     "max_new": 8,
 }
 # Toy sizes for the tier-1 CPU test only.
@@ -100,6 +104,7 @@ TINY = {
     "expert_rows": (64, 128, 4, 2),
     "window_attn": (4, 2, 256, 128, 100),
     "conv": (256, 448, 128, 256, (128,)),
+    "channel_scan": (128, 2),
     "max_new": 4,
 }
 
@@ -657,6 +662,48 @@ def leg_kernels(sz: dict, platform: str) -> dict:
         rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
         assert rel < 1e-2, f"conv_silu {name} off by {rel}"  # one rounding
         facts["conv_rel_err"][name] = rel
+    # -- the delta-rule scan with a decay a key channel -------------------------
+    # against the plain path on the same bfloat16 operands at the Kimi
+    # cell's head shape (heads of 128 in twos, chunks of 64, decays from a
+    # thousandth to 1.6 a token, whose sums pass -100 inside a chunk): o and
+    # the five gradients, each beside its limit. Both round the operands of
+    # the same products; the kernel sums them in another order.
+    from tpu_mpi.parallel import delta
+    from tpu_mpi.xla import delta_kernels
+    t, heads = sz["channel_scan"]
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    wide = (2, t, heads, delta_kernels.DELTA_WIDTH)
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+    bf16 = lambda x: x.astype(jnp.bfloat16)
+    scan_in = (
+        bf16(unit(jax.random.normal(keys[0], wide)) * wide[-1] ** -0.5),
+        bf16(unit(jax.random.normal(keys[1], wide))),
+        bf16(jax.random.normal(keys[2], wide)),
+        -jnp.exp(jax.random.uniform(keys[3], wide, minval=np.log(1e-3),
+                                    maxval=np.log(1.6))),
+        jax.nn.sigmoid(jax.random.normal(keys[4], wide[:3])),
+        bf16(jax.random.normal(keys[5], wide)))
+
+    def scan_with_grads(scan):
+        def run(*operands):
+            out, vjp = jax.vjp(scan, *operands[:5])
+            return (out,) + vjp(operands[5])
+        return jax.jit(run)
+
+    got = timed("delta_scan[a decay a channel]", scan_with_grads(
+        lambda *a: delta_kernels.delta_scan(*a, interpret=interpret)),
+        *scan_in)
+    want = scan_with_grads(lambda *a: delta._chunked(
+        *a, delta_kernels.DELTA_CHUNK))(*scan_in)
+    facts["channel_scan_rel_err"], limit = {}, 3e-2
+    for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert bool(jnp.isfinite(a).all()), f"delta_scan {name} not finite"
+        rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        _log(f"kernels: delta_scan[a decay a channel] {name} off the plain "
+             f"path by {rel:.3e} (limit {limit:.0e})")
+        assert rel < limit, f"delta_scan[a decay a channel] {name} off by {rel}"
+        facts["channel_scan_rel_err"][name] = rel
     # which route the program itself gives an expert layer here, and what
     # its compiled forward and backward hold
     route, calls = _expert_layer_route(sz["expert_layer"])

@@ -49,9 +49,11 @@ def test_leg_kernels():
         "ring_allgather", "ring_allreduce", "ring_reduce_scatter",
         "pairwise_alltoall", "collective_permute", "ring_attention",
         "grouped_matmul", "grouped_row_sums", "causal_attention",
-        "conv_silu"}
+        "conv_silu", "delta_scan"}
     assert facts["row_sums_rel_err"] < 1e-5
     assert set(facts["conv_rel_err"]) == {"out", "dx", "dw", "dbias"}
+    assert set(facts["channel_scan_rel_err"]) == {"o", "dq", "dk", "dv",
+                                                  "dg", "dbeta"}
     assert set(facts["window_attention_rel_err"]) == {"out", "dq", "dk", "dv"}
     # the grouped product ran (interpreted) against lax.ragged_dot; on the
     # CPU backend the program itself would select `lax.ragged_dot`

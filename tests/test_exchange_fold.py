@@ -926,16 +926,20 @@ def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
     compiled for one described v5e chip with the kernels selected as on a
     TPU: 2.093 B parameters, at most 14.9 GB with every FFN half recomputed
     and a KDA layer's first half one recomputed function of the stream (PR
-    48: 13.82 GB; 16.6 with the half's products kept, 16.95 while the decayed
-    products' [16, 16, 128] form a pair and channel was an array in HBM); no
-    [.., t, t] buffer and no [.., r, r, key width] one; the two-term attention
-    kernel at (8192, 128 + 64, 128) forward and backward in layers 3 and 7
-    alone, nothing rotated; the seven scopes of a KDA mixer in the other six,
-    the scan on the plain path (the delta kernel's contract refuses a decay a
-    channel) with its state's chain once each way a layer, PR 47's
-    convolution kernel a part; three traces of the block (KDA + dense, KDA +
-    experts, latent + experts); the experts' products on the grouped kernel;
-    and nothing the compiler chose to compute again to fit."""
+    49: 10.21 GB, the half keeping the scan's states before each chunk AND
+    its output, so that the forward kernel runs once a layer; 9.70 with the
+    states alone and 8.41 with neither, the forward kernel twice a layer
+    either way; PR 48, the scan on the plain path: 13.82 GB; 16.6 with the
+    half's products kept, 16.95 while the decayed products' [16, 16, 128]
+    form a pair and channel was an array in HBM); no [.., t, t] buffer and
+    no [.., r, r, key width] one; the two-term attention kernel at (8192,
+    128 + 64, 128) forward and backward in layers 3 and 7 alone, nothing
+    rotated; the seven scopes of a KDA mixer in the other six, the scan the
+    delta-rule kernel pair for a decay a channel under `scan`, once each way
+    a layer, and no loop of XLA's there; PR 47's convolution kernel a part;
+    three traces of the block (KDA + dense, KDA + experts, latent +
+    experts); the experts' products on the grouped kernel; and nothing the
+    compiler chose to compute again to fit."""
     import json
     import os
     import re
@@ -969,7 +973,7 @@ def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
     lowered = step.lower(params, tok, tok)
     assert tf._block_traced_once.cache_info().currsize == 3
     snap = perfvars.snapshot()
-    assert snap["delta_kernel_lowerings"] == {"kernel": 0, "plain": 2}
+    assert snap["delta_kernel_lowerings"] == {"kernel": 2, "plain": 0}
     assert snap["delta_decays"] == {"head": 0, "channel": 2}
     assert snap["attn_kinds"] == {"latent": "fused"}
     assert snap["rope_forms"] == {"dense": 0, "halves": 0}
@@ -978,7 +982,7 @@ def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
     held = m.argument_size_in_bytes + m.output_size_in_bytes \
         - m.alias_size_in_bytes + m.temp_size_in_bytes
     assert m.alias_size_in_bytes > 4.1e9        # the parameters are reused
-    assert 12e9 < held <= 14.9e9, held
+    assert 9.9e9 < held <= 14.9e9, held     # (under 9.9: o is not kept)
     hlo = compiled.as_text()
     assert not re.search(r"\[\d+,\d+,8192,8192\]", hlo)     # no [.., t, t]
     assert not re.search(r"\[[\d,]*16,16,128\]", hlo)    # nor [.., r, r, dk]
@@ -1008,21 +1012,27 @@ def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
     kernels = re.findall(r"kernel_name = \"(\w+)\"", lowered.as_text())
     assert sorted(set(kernels)) == [
         "causal_attention_bwd", "causal_attention_fwd", "conv_silu_bwd",
-        "conv_silu_fwd", "grouped_matmul_dlhs", "grouped_matmul_drhs",
-        "grouped_matmul_fwd", "grouped_row_sums"]
+        "conv_silu_fwd", "delta_channel_scan_bwd", "delta_channel_scan_fwd",
+        "grouped_matmul_dlhs", "grouped_matmul_drhs", "grouped_matmul_fwd",
+        "grouped_row_sums"]
     # q's, k's and v's; forward again for all three: the half is recomputed
     _the_convolution_is_its_kernels(hlo, names, (0, 1, 2, 4, 5, 6), 3,
                                     again=3)
     assert kernels.count("causal_attention_fwd") == \
         kernels.count("causal_attention_bwd") == 1, kernels
-    # the state's chain once each way a KDA layer, and no other loop there
-    loops = [re.search(r"layer_(\d+)", n).group(1) + (
-        " bwd" if "transpose(" in n else " fwd")
-        for n in re.findall(r"(?m)^\s*%[\w.\-]+ = [^\n]*? while\([^\n]*"
-                            r"op_name=\"([^\"]*)\"", hlo)
-        if "/mixer/" in n and "/scan/" in n]
-    assert sorted(loops) == sorted(
-        f"{i} {d}" for i in (0, 1, 2, 4, 5, 6) for d in ("bwd", "fwd")), loops
+    # the scan's two kernels once each a KDA layer (the recomputed half keeps
+    # the forward one's two outputs: it does not run again), and no loop
+    assert kernels.count("delta_channel_scan_fwd") == \
+        kernels.count("delta_channel_scan_bwd") == 2, kernels   # two traces
+    scans = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*delta_channel_scan_(\w+)/[^\"]*)\"",
+                       hlo)
+    assert sorted((d, int(re.search(r"layer_(\d+)", name).group(1)))
+                  for name, d in scans) == sorted(
+        (d, i) for d in ("bwd", "fwd") for i in (0, 1, 2, 4, 5, 6))
+    assert all("/mixer/" in name and "/scan/" in name for name, _d in scans)
+    assert "while" not in "".join(
+        n for n in names if "/mixer/" in n and "/scan/" in n)
     tf._block_traced_once.cache_clear()
     perfvars.reset()
 

@@ -99,7 +99,8 @@ def test_the_chunked_scan_with_a_vector_decay_is_the_recurrence(
         t, chunk, form):
     """Values and the gradient of each of the five operands, float32, the
     decay's among them a number a channel; the form and the decay's kind
-    are counted where they are chosen, and the scan is the plain path's."""
+    are counted where they are chosen, and the scan is the plain path's
+    (off a kernel backend; `test_with_the_tests_word_..` has the kernels')."""
     args = scan_inputs(t)
     weigh = jax.random.normal(jax.random.key(4), (2, t, H, DH))
     perfvars.reset()
@@ -118,6 +119,36 @@ def test_the_chunked_scan_with_a_vector_decay_is_the_recurrence(
         jax.jit(delta.delta_recurrence)(*args), atol=2e-6)
     for name, a, b in zip("q k v g beta".split(), got_grads, want_grads):
         assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("t, form", [(128, "chunked"), (70, "padded")])
+def test_with_the_tests_word_for_a_kernel_backend_the_scan_is_the_kernels(
+        t, form, kernel_backend):
+    """The same scan at the kernels' shape (heads of 128 in twos, chunks of
+    64) with the tests' word for a kernel backend: counted `kernel`, and the
+    recurrence's values and gradients all the same."""
+    keys = jax.random.split(jax.random.key(3), 6)
+    wide = (1, t, 2, 128)
+    q, k = (tf._l2_normed(jax.random.normal(key, wide)) for key in keys[:2])
+    args = tuple(a.astype(jnp.float32) for a in (
+        q * 128 ** -0.5, k, jax.random.normal(keys[2], wide),
+        -jax.random.uniform(keys[3], wide) * 2.0,
+        jax.nn.sigmoid(jax.random.normal(keys[4], wide[:3]))))
+    weigh = jax.random.normal(keys[5], wide).astype(jnp.float32)
+    perfvars.reset()
+    with kernel_backend("interpret"):
+        got, got_grads = jax.block_until_ready(values_and_grads(
+            lambda *a: delta.delta_scan(*a, 64), args, weigh))
+    snap = perfvars.snapshot()
+    assert snap["delta_lowerings"][form] == 1 == sum(
+        snap["delta_lowerings"].values())
+    assert snap["delta_decays"] == {"head": 0, "channel": 1}
+    assert snap["delta_kernel_lowerings"] == {"kernel": 1, "plain": 0}
+    perfvars.reset()
+    want, want_grads = values_and_grads(delta.delta_recurrence, args, weigh)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    for name, a, b in zip("q k v g beta".split(), got_grads, want_grads):
         np.testing.assert_allclose(a, b, atol=2e-5, err_msg=name)
 
 
@@ -543,23 +574,35 @@ def test_the_references_routing_drops_nothing(both):
 
 # -- traces, counters, and what is refused ---------------------------------------
 
-def test_eight_layers_are_three_traces_and_counted():
+@pytest.mark.parametrize("word, width, who", [
+    (None, DH, "plain"), ("interpret", DH, "plain"),
+    ("interpret", 128, "kernel")])
+def test_eight_layers_are_three_traces_and_counted(word, width, who,
+                                                   kernel_backend):
     """Six KDA layers (one before the dense FFN) and two latent layers: one
     trace a (mixer, FFN) kind, the scan counted once a trace of its kind by
-    its form, by who computes it and by its decay."""
-    cfg = dataclasses.replace(CFG, remat_layers=())
+    its form, by who computes it and by its decay: the plain path off a
+    kernel backend and, with the tests' word for one, at heads of 8; the
+    kernels for a decay a channel at heads of 128 in chunks of 64."""
+    cfg = dataclasses.replace(CFG, remat_layers=(), gdn_key_dim=width,
+                              gdn_value_dim=width,
+                              gdn_chunk=64 if width == 128 else 8)
     perfvars.reset()
     tf._block_traced_once.cache_clear()
     params = transformer_init(jax.random.key(0), cfg)
     tokens = jnp.zeros((1, T), jnp.int32)
-    jax.jit(lambda p: transformer_forward(cfg, p, tokens)).lower(params)
+    with kernel_backend(word):
+        jax.jit(lambda p: transformer_forward(cfg, p, tokens)).lower(params)
     assert tf._block_traced_once.cache_info().currsize == 3
     snap = perfvars.snapshot()
     assert snap["mixer_kinds"]["kda"] == 2 and \
         snap["mixer_kinds"]["attention"] == 1
     assert snap["delta_decays"] == {"head": 0, "channel": 2}
-    assert snap["delta_lowerings"] == {"chunked": 2, "padded": 0}
-    assert snap["delta_kernel_lowerings"] == {"kernel": 0, "plain": 2}
+    assert snap["delta_lowerings"] == (
+        {"chunked": 0, "padded": 2} if width == 128     # 32 tokens of 64
+        else {"chunked": 2, "padded": 0})
+    assert snap["delta_kernel_lowerings"] == {
+        "kernel": 2 * (who == "kernel"), "plain": 2 * (who == "plain")}
     assert snap["attn_kinds"] == {"latent": "plain"}
     assert snap["rope_forms"] == {"dense": 0, "halves": 0}     # nothing turns
     tf._block_traced_once.cache_clear()

@@ -216,40 +216,54 @@ def test_a_contract_holds_its_kernel_to_its_own_operand_types(
     ((32, 16, 128, 128, 64, jnp.bfloat16, 1), True),
     ((32, 16, 128, 128, 64, jnp.bfloat16, 128), False),     # a vector decay
     ((32, 32, 128, 128, 64, jnp.bfloat16, 1), False),       # 32 key heads
-    ((32, 32, 128, 128, 64, jnp.bfloat16, 128), False),     # KDA's: both
-], ids=["qwen3-next", "one-a-head", "a-channel", "32-key-heads", "kda"])
-def test_the_delta_kernels_contract_refuses_a_decay_a_channel(
+    ((32, 32, 128, 128, 64, jnp.bfloat16, 128), True),      # KDA's: both
+    ((31, 31, 128, 128, 64, jnp.bfloat16, 128), False),     # no twos
+    ((32, 32, 128, 128, 64, jnp.bfloat16, 64), False),      # half a key
+], ids=["qwen3-next", "one-a-head", "a-channel", "32-key-heads", "kda",
+        "odd-heads", "half-the-channels"])
+def test_the_delta_kernels_contract_pairs_the_decay_with_the_heads(
         operands, fits, kernel_backend):
-    """The kernel pair takes a token's decay as scalars and two value heads
-    a key head: under the `mosaic` word Qwen3-Next's shapes select it, and a
-    decay a key channel or as many key heads as value heads is refused by
-    rule, from the shapes."""
+    """The contract's two rows: a token's decay as scalars with two value
+    heads a key head (Qwen3-Next's shapes), or a decay a key channel with a
+    key head a value head, the heads in twos (KDA's: PR 49). Under the
+    `mosaic` word those two select a kernel pair, and a decay a channel over
+    two value heads a key head, a decay a head over as many key heads, an
+    odd number of heads or a decay narrower than the key is refused by rule,
+    from the shapes."""
     kernel_backend("mosaic")
     assert (choice.fit(choice.DELTA_SCAN, *operands) is not None) is fits
 
 
-def test_a_vector_decays_scan_is_counted_plain_under_the_kernels_word(
+def test_a_vector_decays_scan_is_counted_by_its_heads_under_the_kernels_word(
         kernel_backend):
-    """KDA's shapes traced under `mosaic`: no kernel in the program, the call
-    counted `plain` and its decay `channel`; Qwen3-Next's beside it counts
-    `kernel` and `head`."""
+    """A decay a channel traced under `mosaic`: over two value heads a key
+    head no kernel is in the program and the call counts `plain`; KDA's
+    shapes (a key head a value head) count `kernel`, and so do Qwen3-Next's
+    beside them, whose decay counts `head`."""
     qk = jnp.zeros((1, 64, 2, 128), jnp.bfloat16)
     g = jnp.zeros((1, 64, 2, 128), F32)
     perfvars.reset()
     with kernel_backend("mosaic"):
-        traced = str(jax.make_jaxpr(
-            lambda: delta.delta_scan(qk, qk, qk, g, g[..., 0], 64))())
+        traced = str(jax.make_jaxpr(lambda: delta.delta_scan(
+            qk[:, :, :1], qk[:, :, :1], qk, g, g[..., 0], 64))())
         assert "pallas_call" not in traced
         assert perfvars.snapshot()["delta_kernel_lowerings"] == {
             "kernel": 0, "plain": 1}
         assert perfvars.snapshot()["delta_decays"] == {"head": 0,
                                                        "channel": 1}
+        traced = str(jax.make_jaxpr(
+            lambda: delta.delta_scan(qk, qk, qk, g, g[..., 0], 64))())
+        assert "pallas_call" in traced and "delta_channel_scan_fwd" in traced
+        assert perfvars.snapshot()["delta_kernel_lowerings"] == {
+            "kernel": 1, "plain": 1}
+        assert perfvars.snapshot()["delta_decays"] == {"head": 0,
+                                                       "channel": 2}
         traced = str(jax.make_jaxpr(lambda: delta.delta_scan(
             qk[:, :, :1], qk[:, :, :1], qk, g[..., 0], g[..., 0], 64))())
         assert "pallas_call" in traced
-    assert perfvars.snapshot()["delta_kernel_lowerings"] == {"kernel": 1,
+    assert perfvars.snapshot()["delta_kernel_lowerings"] == {"kernel": 2,
                                                              "plain": 1}
-    assert perfvars.snapshot()["delta_decays"] == {"head": 1, "channel": 1}
+    assert perfvars.snapshot()["delta_decays"] == {"head": 1, "channel": 2}
     perfvars.reset()
 
 

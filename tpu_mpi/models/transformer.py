@@ -1494,15 +1494,16 @@ def _kda_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
     sigmoid(g w_kda_g): norm first, gate after, a sigmoid where `_gdn_mixer`
     has silu; and the out-projection. Scopes: `in_proj`, `conv`, `prep`,
     `decay`, `scan`, `gate_norm`, `out_proj`. The half is ONE function of
-    the stream that the backward pass computes again, keeping of it the
-    state before each chunk alone (`parallel.delta.STATES`: the chain over
-    the chunks runs once each way): the decay is [t, heads x key width]
-    float32, 134 MB a layer at 8192 tokens and 32 heads of 128, the
-    12288-wide row 201 MB and the scan's and the gate's outputs 67 MB each,
-    and with them kept the step of two periods does not fit a chip (16.6 GB
-    compiled for the v5e, 13.8 so: PERF.md section 6, PR 48); the
-    in-projection is the one matrix product that runs again. `sp` > 1 and
-    `tp` > 1 are refused (`_delta_layer_alone`)."""
+    the stream that the backward pass computes again, keeping of it what
+    the scan names `parallel.delta.KEPT` alone (the state before each
+    chunk, so the chain over the chunks runs once each way; from the
+    kernels the scan's output too, so the forward kernel does): the decay
+    is [t, heads x key width] float32, 134 MB a layer at 8192 tokens and 32
+    heads of 128, the 12288-wide row 201 MB and the scan's and the gate's
+    outputs 67 MB each, and with them kept the step of two periods does not
+    fit a chip (16.6 GB compiled for the v5e, 13.8 so: PERF.md section 6,
+    PR 48); the in-projection is the one matrix product that runs again.
+    `sp` > 1 and `tp` > 1 are refused (`_delta_layer_alone`)."""
     _delta_layer_alone(tp_axis, sp_axis)
     from ..parallel import delta, ssm
     b, t, _ = x.shape
@@ -1541,7 +1542,7 @@ def _kda_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
             "dt_bias", "w_kda_f", "w_kda_g", "kda_norm", "w_kda_out")
     return jax.checkpoint(
         half, policy=jax.checkpoint_policies.save_only_these_names(
-            delta.STATES))(x, {k: layer[k] for k in mine if k in layer})
+            delta.KEPT))(x, {k: layer[k] for k in mine if k in layer})
 
 
 def _mamba_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray):

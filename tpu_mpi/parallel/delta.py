@@ -61,12 +61,33 @@ result is exact, and their outputs are cut off.
 Who computes it is chosen by `xla.choice`'s rule and counted beside the form
 (``perfvars.snapshot()["delta_kernel_lowerings"]``): ``kernel`` where a
 kernel backend is there (a TPU; the tests' word) and the contract
-`xla.delta_kernels.delta_scan_selected` takes the operands (two value heads
-a key head, heads of 128, a chunk of 64, a decay a head, float32 or bfloat16): one Pallas
-kernel each way that keeps a chunk's arrays and the state in VMEM, the
-padded form filled with the same zero tokens in front of it; ``plain``
-everywhere else (the CPU, the tests, other shapes): :func:`_chunked`, plain
-XLA, which is also what the tests hold the kernels to.
+`xla.delta_kernels.delta_scan_selected` takes the operands. The contract has
+two rows, both with heads of 128, a chunk of 64 and float32 or bfloat16:
+
+    a decay a head      two value heads a key head   (Qwen3-Next's layers)
+    a decay a channel   a key head a value head,     (Kimi-Linear's KDA)
+                        the heads in twos
+
+each one Pallas kernel each way that keeps a chunk's arrays and the state in
+VMEM, the padded form filled with the same zero tokens in front of it. The
+two are two bodies behind the one contract that share the inverse, ``W``,
+``U0``, the written values, the outputs and the state's step: inside a
+chunk one ``K K^T`` serves two value heads under a scalar outer difference
+in the first, and two independent heads go through
+:func:`_decayed_products`' six rounds in the second. ``plain`` everywhere
+else (the CPU, the tests, other shapes, a decay a channel with two value
+heads a key head): :func:`_chunked`, plain XLA, which is also what the tests
+hold the kernels to. What a recomputed function around the scan may keep
+of it is named :data:`KEPT` (`jax.checkpoint` with
+``save_only_these_names(KEPT)``: `models.transformer._kda_mixer`'s half), and
+what that is differs by path: on the plain path the state before each chunk
+alone (:func:`_state_chain_fwd`: the chain over the chunks then runs once
+each way and every other array is computed again); from the kernels of a
+decay a channel those states AND the scan's output o (67 MB a layer more at
+8192 tokens and 32 heads of 128), because o is what the caller's backward
+pass reads and with it kept the forward kernel runs once a step, not twice;
+the kernels of a decay a head name nothing (their one caller, `_gdn_mixer`,
+recomputes nothing around the scan).
 """
 
 from __future__ import annotations
@@ -81,7 +102,7 @@ from jax.ad_checkpoint import checkpoint_name
 from .. import perfvars
 from ..xla import choice, delta_kernels
 
-STATES = "delta_chunk_states"   # what the backward pass keeps of `_chunked`
+KEPT = delta_kernels.KEPT     # what is kept of the scan: the docstring's end
 _EXACT = lax.Precision.HIGHEST
 
 
@@ -220,7 +241,7 @@ def _state_chain(carry, kd, w, u0):
 
 
 def _state_chain_fwd(carry, kd, w, u0):
-    states = checkpoint_name(_state_chain(carry, kd, w, u0), STATES)
+    states = checkpoint_name(_state_chain(carry, kd, w, u0), KEPT)
     return states, (carry, kd, w, u0, states)
 
 
@@ -354,7 +375,7 @@ def _crossed(a, k, gamma, size: int, dtype):
 
 @functools.partial(
     jax.checkpoint, static_argnums=(5,),
-    policy=jax.checkpoint_policies.save_only_these_names(STATES))
+    policy=jax.checkpoint_policies.save_only_these_names(KEPT))
 def _chunked(q, k, v, g, beta, length: int):
     """The recurrence's outputs [batch, t, value heads, value width], of
     v's type (rounded here, so that what the caller's backward pass keeps
