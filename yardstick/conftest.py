@@ -1,25 +1,25 @@
-"""One accepted test of the yardstick's suite asserts an accident of its day.
-
-`tests/test_grouped_matmul_share.py::test_the_flagship_reports_no_such_metric`
-(PR 29) ends on `manifest["per_layer"][-1] == <its own entry>`: true while
-that entry was the newest. The benchmark's contract has every later PR
-append its entries at the END of `per_layer` and edit no file the benchmark
-has, so the first PR that adds a per-layer metric (PR 30) falsifies that
-line and may not repair it. Until a `benchmark` PR drops the line, the test
-is expected to fail on it, strictly: once it passes again this marker fails
-the suite and has to go. What the test is there for (the entry itself, and
-that the flagship is not in its `workloads`) is asserted again, by name, in
-`tests/test_lm_kinds_train_step.py::test_the_accepted_metrics_stand`."""
+"""Until PR 50 twelve tests of this suite asserted an accident of their day
+(that THEIR entries were the last of `BENCHMARK.json`'s `per_layer`, or the
+whole of a cell's list), every later append falsified the line, and no PR
+but a `benchmark` one may edit a file the benchmark has: so `/conftest.py`
+marked eleven of them strict expected failures and this file the twelfth.
+PR 50 dropped those lines (every assertion about the manifest is now by name
+and as a subset) and this file's marker with them. `/conftest.py` is outside
+the benchmark's `paths`, a `benchmark` PR may not touch it, and its strict
+markers would now fail the eleven tests for PASSING: the hook below takes
+them off again. It does nothing once a PR that may edit `/conftest.py` has
+emptied `LAST_ENTRIES_TESTS` there; the next `benchmark` PR then cuts this
+file to its docstring."""
 
 import pytest
 
-LAST_ENTRY_TEST = ("test_grouped_matmul_share.py::"
-                   "test_the_flagship_reports_no_such_metric")
+STALE_REASON = "asserts its entries are per_layer's last"
 
 
+@pytest.hookimpl(trylast=True)         # after /conftest.py's hook has marked
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid.endswith(LAST_ENTRY_TEST):
-            item.add_marker(pytest.mark.xfail(
-                strict=True, raises=AssertionError,
-                reason="asserts per_layer[-1]; later PRs append after it"))
+        item.own_markers[:] = [
+            m for m in item.own_markers if not (
+                m.name == "xfail"
+                and STALE_REASON in m.kwargs.get("reason", ""))]

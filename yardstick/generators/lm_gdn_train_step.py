@@ -12,6 +12,24 @@ the token batches as `lm_train_step.py` reads them (`batch`, `seq`, `pool`,
 `block_steps`); token ids are uniform over the vocabulary rows that are
 here, one document a sequence.
 
+**Every seed draws the same work** where the traffic file says so
+(`router_shares`; `tied_routers` below). At random weights a stack of
+delta-rule layers hands a router nearly the same vector for every token, so
+it sends all tokens to the same few of its experts, and how many of those
+this chip holds is the draw of the weights and of every step that trains
+them: the held experts get a sixth to 2.7 times the rows a balanced router
+sends them, the load moves by half the balanced rows within thirty steps, the
+step's time follows the rows, and what arrives over the program's buffer
+(2 x the balanced rows) runs further buffers, a tenth of a step: the
+Kimi cell's rate differs by 3.5% over seeds where two runs of one seed agree
+to 0.005%. A deployment's routers are balanced over its chips; these are
+made so: with `router_shares` n, the held experts' columns of every router,
+as `transformer_init` draws them from the seed, stand at the same place of
+each of the n shares (expert j, j + held, ..., j + (n - 1) held have one
+column), so a token's best expert comes with its n - 1 twins on the other
+chips and every chip gets one row a token, whatever the seed. Without the
+key the routers are as drawn.
+
 One sample per block: (first dispatch -> the block's loss on the host) /
 block_steps; `train_tokens_per_s` = batch x seq / the median. Correctness,
 all of it outside the window: before each of the first `compare_steps` steps
@@ -50,6 +68,19 @@ from yardstick.generators.lm_train_step import build
 from yardstick.harness import annotate
 
 
+def tied_routers(params, held: int, shares: int):
+    """`params` with every router's columns of the first `held` experts
+    repeated over all `shares` shares (see above): [d, held x shares]."""
+    def tied(path, leaf):
+        if getattr(path[-1], "key", None) != "w_router":
+            return leaf
+        if leaf.shape[-1] != held * shares:
+            raise ValueError(f"a router of {leaf.shape[-1]} experts is not "
+                             f"{shares} shares of {held}")
+        return jnp.tile(leaf[..., :held], shares)
+    return jax.tree_util.tree_map_with_path(tied, params)
+
+
 def run(run) -> None:
     from tpu_mpi.models.transformer import (transformer_forward,
                                             transformer_held_counts,
@@ -64,8 +95,14 @@ def run(run) -> None:
 
     # weights and tokens from the seed, on the device, one jitted call each
     key = jax.random.key(run.seed)
-    params = jax.jit(lambda k: transformer_init(k, model),
-                     out_shardings=shard)(jax.random.fold_in(key, 0))
+    shares = int(tr.get("router_shares", 0))
+
+    def make_weights(k):
+        params = transformer_init(k, model)
+        return tied_routers(params, model.experts_held[1], shares) \
+            if shares else params
+    params = jax.jit(make_weights, out_shardings=shard)(
+        jax.random.fold_in(key, 0))
 
     def make_tokens(k):
         tok = jax.random.randint(k, (pool, batch, seq), 0, model.vocab)
@@ -176,7 +213,8 @@ def run(run) -> None:
     run.row(f"train step [{batch} x {seq}] n={q['n']} blocks of {block_steps}  "
             f"per-step q1 {q['q1'] * 1e3:.3f} ms  median {q['median'] * 1e3:.3f} "
             f"ms  q3 {q['q3'] * 1e3:.3f} ms  spread {100 * q['spread']:.2f}%  "
-            f"last loss {last:.4f}")
+            f"last loss {last:.4f}  per-step ms by block "
+            + " ".join(f"{1e3 * t / block_steps:.1f}" for t in times))
     for label, routed, sound in (("before", routed0, sound0),
                                  ("after", routed1, sound1)):
         print(f"routing {label} the window, by layer: every router's "

@@ -7,17 +7,18 @@ on the chip that folds. The fold is bound by memory bandwidth, not compute:
 one add per 4-byte element.
 
 The fold executable is known by the functions the program jits for it
-(whole names: an event is `jit_<function>(<id>)`): the armed lane's
-`plain_fold` and `chain` (collective._registered_device_fold), and the
-generic path's `fused` (the Pallas fused_multi_reduce) and `fold`
-(collective._jitted_fold). A traced run that finds none of them fails: a
-kernel that was renamed or replaced needs a benchmark PR to name it here,
-and must not drop out of the result line unseen. Defined for traffic of one
-size, where every fold moves the same bytes."""
+(whole names: an event is `jit_<function>(<id>)`): `plain_fold`, the
+rank-ordered left chain of `collective._left_chain` that the armed lane and
+the legacy lane's `_jitted_fold` both compile for a reduce, and `chain`, the
+armed lane's donated form (collective._registered_device_fold). A traced run
+that finds neither fails: a fold that was renamed or replaced needs a
+benchmark PR to name it here, and must not drop out of the result line
+unseen. Defined for traffic of one size, where every fold moves the same
+bytes."""
 
 from yardstick import stats
 
-FOLD_FUNCTIONS = ("plain_fold", "chain", "fused", "fold")
+FOLD_FUNCTIONS = ("plain_fold", "chain")
 
 
 def read(run):

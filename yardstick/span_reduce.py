@@ -52,7 +52,7 @@ PARTS = ("front_door", "lock", "rdv_skew", "rdv_fold", "rdv_wake",
 GAP_NAMES = ("outside the program", "front_door", "lock", "fold_dispatch",
              "launch")
 #: the executables that fold (yardstick/layer_metrics/fold_roofline.py)
-FOLD_MODULES = ("jit_plain_fold", "jit_chain", "jit_fused", "jit_fold")
+FOLD_MODULES = ("jit_plain_fold", "jit_chain")
 #: a fold's module event starts this close to the end of the gap before it
 GAP_END_TOL_NS = 5_000.0
 KEY = "span_reduce"

@@ -6,8 +6,7 @@ three OSU cells do not, and a CPU rehearsal of two cells (which function the
 step calls does not depend on the backend: 100 here too, printed without a
 value as every share of lowerings is).
 
-The append falsifies no accepted test that an earlier append had not (the
-cells' exact lists of names are /conftest.py's already)."""
+Its cells are asserted as a subset: a later train cell joins the list."""
 
 import json
 import os
@@ -60,26 +59,33 @@ def test_a_program_without_the_counter_or_without_a_loss_leaves_it_out(
 
 
 def test_the_entry_by_name(manifest):
-    (spec,) = [m for m in manifest["per_layer"] if m["name"] == NAME]
+    (found,) = [m for m in manifest["per_layer"] if m["name"] == NAME]
+    spec = dict(found)
+    assert set(TRAIN_CELLS) <= set(spec.pop("workloads"))
     assert spec == {"name": NAME, "unit": "%", "better": "higher",
                     "source": "program_counter", "layer": "train step",
-                    "moves": "train_tokens_per_s", "workloads": TRAIN_CELLS}
+                    "moves": "train_tokens_per_s"}
     names = [m["name"] for m in manifest["per_layer"]]
     assert len(set(names)) == len(names)
-    assert TRAIN_CELLS == next(m for m in manifest["end_to_end"]
-                               if m["name"] == "train_tokens_per_s")["workloads"]
+    assert set(TRAIN_CELLS) <= set(next(
+        m for m in manifest["end_to_end"]
+        if m["name"] == "train_tokens_per_s")["workloads"])
 
 
 def test_the_six_train_cells_report_it_and_no_other_cell_does(manifest):
+    """The six it was written for, every train cell since, and no OSU cell:
+    it is reported where `train_tokens_per_s` is."""
+    rate = next(m for m in manifest["end_to_end"]
+                if m["name"] == "train_tokens_per_s")["workloads"]
     for w in manifest["workloads"]:
         cell = harness.Cell(manifest, w["name"])
         names = [m["name"] for m in cell.per_layer]
-        assert (NAME in names) == (w["name"] in TRAIN_CELLS), w["name"]
+        assert (NAME in names) == (w["name"] in rate), w["name"]
         assert len(set(names)) == len(names)
         for _spec, mod in cell.readers():
             assert hasattr(mod, "read")
-    assert sum(not w["name"].startswith("osu-") for w in manifest["workloads"]) \
-        == len(TRAIN_CELLS)
+    assert set(TRAIN_CELLS) <= set(rate)
+    assert not any(c.startswith("osu-") for c in rate)
 
 
 @pytest.mark.parametrize("cell", [TRAIN_CELLS[0], TRAIN_CELLS[4]])

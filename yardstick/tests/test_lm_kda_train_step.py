@@ -38,8 +38,14 @@ WIDTHS = {"hidden_size": 2304, "num_attention_heads": 32,
 # cell reads the accepted readers under the accepted entries of the same
 # `moves` (the latent layers' among them: the generator states their facts
 # under the accepted name), its name appended to their `workloads`
-READERS = ["kda_mixer_device_ms", "kda_scan_device_ms", "kda_decay_device_ms",
-           "kda_scan_roofline", "delta_kernel_share", "channel_decay_share"]
+QWEN = "qwen3-next-80b-a3b-1c.gdn-step-b1s8192"
+# the six readers this kind brought: (unit, better, source, cells beside CELL)
+READERS = {"kda_mixer_device_ms": ("ms", "lower", "device_trace", set()),
+           "kda_scan_device_ms": ("ms", "lower", "device_trace", set()),
+           "kda_decay_device_ms": ("ms", "lower", "device_trace", set()),
+           "kda_scan_roofline": ("%", "higher", "device_trace", set()),
+           "delta_kernel_share": ("%", "higher", "program_counter", {QWEN}),
+           "channel_decay_share": ("%", "higher", "program_counter", set())}
 JOINED = ["step_device_ms", "train_mfu", "device_idle_share.train",
           "fused_attn_share", "grouped_matmul_share", "row_sum_product_share",
           "ssm_conv_device_ms", "delta_chunked_share", "held_moe_device_ms",
@@ -175,34 +181,36 @@ def test_the_configuration_against_the_catalog(manifest):
 def test_the_cell_reports_what_the_benchmark_has_room_for(manifest):
     cell = harness.Cell(manifest, CELL)
     by_name = {m["name"]: m for m in cell.per_layer}
-    assert set(JOINED) | {"compiles_in_window",
-                          "backend_start_s"} <= set(by_name)
+    assert set(JOINED) | set(READERS) | {"compiles_in_window",
+                                         "backend_start_s"} <= set(by_name)
     assert len(manifest["per_layer"]) <= 128
-    for name in JOINED:         # appended to the cells the entry had
-        assert by_name[name]["workloads"][0] != CELL, name
-        assert by_name[name]["workloads"].count(CELL) == 1, name
+    for name in JOINED:         # joined the cells the entry had
+        assert {CELL} < set(by_name[name]["workloads"]), name
         assert by_name[name]["moves"] == ("setup_s" if name.startswith(
             ("step_build_s", "kernel_traces", "build_"))
             else "train_tokens_per_s"), name
     for _spec, mod in cell.readers():
         assert hasattr(mod, "read")
-    # the readers this kind brings are files with `read`, and no entry yet
-    names = {m["name"] for m in manifest["per_layer"]}
-    for reader in READERS:
+    # the six readers this kind brought have their entries since PR 50 (PR 48
+    # found `per_layer` full at 128): each by name, unit, `moves` and cells
+    for reader, (unit, better, source, others) in READERS.items():
+        spec = dict(by_name[reader])
+        assert {CELL} | others <= set(spec.pop("workloads")), reader
+        assert spec == {"name": reader, "unit": unit, "better": better,
+                        "source": source, "layer": "train step",
+                        "moves": "train_tokens_per_s"}, reader
         mod = harness.load_module(os.path.join(
             harness.HERE, "layer_metrics", reader + ".py"), "ys_l_" + reader)
         assert hasattr(mod, "read") and mod.__doc__.startswith(reader)
-        assert (reader in names) == (reader in by_name)
-    # the cell reports the rate, with the accepted train cells and after them
+        assert (reader in cells_of(manifest, QWEN)) == (QWEN in others)
+    # the cell reports the rate, with the accepted train cells
     (spec,) = [m for m in manifest["end_to_end"]
                if m["name"] == "train_tokens_per_s"]
     assert set(TRAIN_CELLS) | {CELL} <= set(spec["workloads"])
-    assert spec["workloads"].index(CELL) > max(
-        spec["workloads"].index(c) for c in TRAIN_CELLS)
     assert {m["name"] for m in cell.end_to_end} == {"train_tokens_per_s",
                                                     "setup_s"}
     for w in manifest["workloads"]:
-        names = [m["name"] for m in harness.Cell(manifest, w["name"]).per_layer]
+        names = cells_of(manifest, w["name"])
         assert len(set(names)) == len(names)
     # 11 cells, still one of four chips
     assert len(manifest["workloads"]) >= 11
@@ -210,11 +218,9 @@ def test_the_cell_reports_what_the_benchmark_has_room_for(manifest):
 
 
 def test_the_delta_rule_cells_five_entries_stand(manifest):
-    """PR 45's `test_the_cell_reports_what_the_issue_names`, whose exact
-    lists this cell's append falsifies (/conftest.py:LAST_ENTRIES_TESTS): its
-    five entries, that cell first in each, and this cell appended to the one
-    whose reader finds something here."""
-    qwen = "qwen3-next-80b-a3b-1c.gdn-step-b1s8192"
+    """PR 45's five entries by name, the Qwen3-Next cell among the cells of
+    each and this cell in the one whose reader finds something here; what
+    else that cell reports is `test_lm_gdn_train_step.py`'s to hold."""
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     for name, (unit, source) in {
             "gdn_mixer_device_ms": ("ms", "device_trace"),
@@ -222,43 +228,12 @@ def test_the_delta_rule_cells_five_entries_stand(manifest):
             "gdn_scan_roofline": ("%", "device_trace"),
             "gated_attn_device_ms": ("ms", "device_trace"),
             "delta_chunked_share": ("%", "program_counter")}.items():
-        spec = dict(by_name[name])
-        cells = spec.pop("workloads")
-        assert cells[0] == qwen, name
-        assert (CELL in cells) == (name == "delta_chunked_share"), name
+        spec = by_name[name]
+        assert QWEN in spec["workloads"], name
+        assert (CELL in spec["workloads"]) == (
+            name == "delta_chunked_share"), name
         assert (spec["unit"], spec["source"], spec["layer"], spec["moves"]) \
             == (unit, source, "train step", "train_tokens_per_s"), name
-    names = [m["name"] for m in harness.Cell(manifest, qwen).per_layer]
-    assert len(set(names)) == len(names)
-    # and every other line of that test, which still holds: what that cell
-    # joined, after the cells each entry had; the shares' units; the head's
-    # entry as PR 43 wrote it, in the train cells and in no other
-    joined = [n for n in JOINED if n not in (
-        "delta_chunked_share", "dense_ffn_device_ms", "latent_attn_device_ms",
-        "latent_proj_device_ms", "latent_kernel_roofline")]
-    assert set(joined) | set(cells_of(manifest, qwen)) == set(
-        cells_of(manifest, qwen))
-    for name in joined:
-        assert by_name[name]["workloads"][0] != qwen, name
-        assert by_name[name]["workloads"].index(qwen) \
-            < by_name[name]["workloads"].index(CELL), name
-    for name in ("gdn_scan_roofline", "held_experts_roofline", "train_mfu",
-                 "delta_chunked_share"):
-        assert (by_name[name]["unit"], by_name[name]["better"]) == (
-            "%", "higher"), name
-    for metric in ("train_tokens_per_s", "blocked_head_share"):
-        (spec,) = [m for m in manifest["end_to_end"] + manifest["per_layer"]
-                   if m["name"] == metric]
-        assert spec["workloads"].index(qwen) == max(
-            spec["workloads"].index(c) for c in TRAIN_CELLS), metric
-    head = by_name["blocked_head_share"]
-    assert {k: v for k, v in head.items() if k != "workloads"} == {
-        "name": "blocked_head_share", "unit": "%", "better": "higher",
-        "source": "program_counter", "layer": "train step",
-        "moves": "train_tokens_per_s"}
-    for w in manifest["workloads"]:         # and no cell that does not train
-        assert ("blocked_head_share" in cells_of(manifest, w["name"])) == (
-            w["name"] in head["workloads"]), w["name"]
 
 
 def cells_of(manifest, cell: str) -> list:
@@ -406,7 +381,11 @@ def test_scope_rules(op_name, scope):
     assert scope in kda_scope_reduce.SCOPES
 
 
-def test_the_cell_matches_its_plain_reference():
+def test_the_cell_matches_its_plain_reference(fresh_traces):
+    from tpu_mpi import perfvars
+    # the counters are the process's: another file's rehearsal (the
+    # Qwen3-Next cell's, a decay a head) has counted its kinds in them
+    perfvars.reset()
     run = rehearse(CELL, seconds=0.5)
     r = run.results
     assert r["correct"] and r["failed"] == 0 and r["attempted"] >= 2
@@ -447,6 +426,40 @@ def test_the_cell_matches_its_plain_reference():
     from yardstick.layer_metrics import channel_decay_share, delta_kernel_share
     assert channel_decay_share.read(run) == 100.0
     assert delta_kernel_share.read(run) == 0.0
+
+
+def test_every_router_sends_each_share_one_row_a_token(manifest):
+    """The cell's routers are tied over the 8 shares of 32 experts, so a
+    token's best expert comes with its twins on the other chips and this
+    chip gets one row a token in every layer, whatever the seed; the
+    Qwen3-Next cell, whose traffic names no shares, keeps its routers as
+    drawn."""
+    import jax.numpy as jnp
+    from yardstick.generators.lm_gdn_train_step import tied_routers
+    cell = harness.Cell(manifest, CELL)
+    held, n = cell.config["model"]["experts_held"][1], \
+        cell.config["model"]["n_experts"]
+    assert cell.traffic["router_shares"] * held == n == 256
+    assert "router_shares" not in harness.Cell(manifest, QWEN).traffic
+    w = jnp.arange(3 * 12, dtype=jnp.float32).reshape(3, 12)
+    params = {"embed": w, "layers": [{"w_router": w, "w_in": w},
+                                     {"w_in": w}]}
+    tied = tied_routers(params, 4, 3)
+    assert (tied["layers"][0]["w_router"] ==
+            jnp.concatenate([w[:, :4]] * 3, axis=1)).all()
+    assert tied["embed"] is w and tied["layers"][1]["w_in"] is w
+    assert tied["layers"][0]["w_in"] is w
+    with pytest.raises(ValueError):
+        tied_routers(params, 4, 2)
+    # at the rehearsal's size: every layer's held experts get the balanced
+    # 64 rows before the window (five steps have trained the routers: a
+    # token or two have left their twins), at two seeds
+    for seed in (3, 2 ** 31 + 11):
+        run = rehearse(CELL, seconds=0.2, seed=seed)
+        begin = run.facts["held"]["begin"]["held"]
+        assert len(begin) == 7 and all(abs(n - 64) <= 3 for n in begin), \
+            (seed, begin)
+        assert run.results["correct"]
 
 
 def test_a_program_without_the_counter_or_the_scopes_reports_nothing():

@@ -27,13 +27,15 @@ WIDTHS = {"hidden_size": 7680, "q_lora_rank": 1536, "kv_lora_rank": 512,
           "num_experts_per_tok": 8, "n_shared_experts": 1,
           "routed_scaling_factor": 2.5, "rms_norm_eps": 1e-05,
           "rope_theta": 25600000}
-MINE = ["step_device_ms.pgu", "train_mfu.pgu", "device_idle_share.pgu",
-        "fused_attn_share.pgu", "grouped_matmul_share.pgu",
+# by reader: the readers a model shares stand under one name, this cell in
+# their lists (PR 50 folded the tags that named a cell: `<reader>.pgu`)
+MINE = ["step_device_ms", "train_mfu", "device_idle_share.train",
+        "fused_attn_share", "grouped_matmul_share",
         "latent_attn_device_ms", "latent_proj_device_ms",
         "latent_kernel_roofline", "norm_out_device_ms",
-        "held_moe_device_ms.pgu", "dense_ffn_device_ms.pgu",
-        "shared_expert_device_ms.pgu", "kinds_head_loss_device_ms.pgu",
-        "held_slot_share.pgu", "expert_rows_fill.pgu"]
+        "held_moe_device_ms", "dense_ffn_device_ms",
+        "shared_expert_device_ms", "kinds_head_loss_device_ms",
+        "held_slot_share", "expert_rows_fill"]
 
 
 @pytest.fixture(scope="module")
@@ -125,36 +127,30 @@ def test_the_configuration_against_the_catalog(manifest):
 
 def test_the_cell_reports_what_the_issue_names(manifest):
     cell = harness.Cell(manifest, CELL)
-    names = [m["name"] for m in cell.per_layer]
-    assert names == ["compiles_in_window", "backend_start_s"] + MINE
-    for spec in cell.per_layer[2:]:
-        assert spec["workloads"] == [CELL]
-        assert spec["moves"] == "train_tokens_per_s"
+    by_name = {m["name"]: m for m in cell.per_layer}
+    assert set(MINE) | {"compiles_in_window", "backend_start_s"} \
+        <= set(by_name)
+    for name in MINE:
+        assert CELL in by_name[name]["workloads"], name
+        assert by_name[name]["moves"] == "train_tokens_per_s", name
     assert [w["chips"] for w in manifest["workloads"]].count(4) == 1
-    assert len(manifest["workloads"]) == 7
+    assert len(manifest["workloads"]) >= 7
     for _spec, mod in cell.readers():
         assert hasattr(mod, "read")
 
 
 def test_the_accepted_metrics_stand(manifest):
-    """This PR's entries follow every entry the benchmark had, in the order
-    it had them: the K-EXAONE cell's seventeen (PR 30) before them, and
-    `grouped_matmul_share` (PR 29) before those, each as it read. (Two
-    accepted tests assert that THEIR entries are the list's last, which
-    every later append falsifies: /conftest.py expects both to fail.)"""
-    names = [m["name"] for m in manifest["per_layer"]]
-    n = len(MINE)
-    assert names[-n:] == MINE
-    kex = [m["name"] for m in manifest["per_layer"]
-           if m.get("workloads") == [KEX]]
-    assert len(kex) == 17 and names[-n - 17:-n] == kex
-    assert kex[0] == "step_device_ms.kex"
-    assert kex[-1] == "held_dispatch_device_ms"
-    assert manifest["per_layer"][-n - 18] == {
-        "name": "grouped_matmul_share", "unit": "%", "better": "higher",
-        "source": "program_counter", "layer": "train step",
-        "moves": "train_tokens_per_s",
-        "workloads": ["olmoe-1b-7b-1c.lm-step-b2s4096"]}
+    """The K-EXAONE cell (PR 30) still reports what it reported, the held
+    readers under the names this cell reads them by, and both cells stand
+    among `grouped_matmul_share`'s (PR 29; its entry is held by its own
+    test). By name and as subsets: no append and no fold falsifies it."""
+    kex = {m["name"] for m in harness.Cell(manifest, KEX).per_layer}
+    assert kex >= {"step_device_ms", "held_dispatch_device_ms",
+                   "attn_full_device_ms", "held_experts_roofline"}
+    (spec,) = [m for m in manifest["per_layer"]
+               if m["name"] == "grouped_matmul_share"]
+    assert {"olmoe-1b-7b-1c.lm-step-b2s4096", KEX, CELL} \
+        <= set(spec["workloads"])
 
 
 def test_flops_against_a_hand_count(manifest):
@@ -238,7 +234,7 @@ def test_the_share_matches_its_plain_reference():
         assert held["slots"] == [128] * 4
         assert held["computed"] == held["held"]
         assert held["gathered"] == [128] * 4 and held["fallbacks"] == [0] * 4
-    assert 0.0 < run.values["held_slot_share.pgu"] < 100.0
+    assert 0.0 < run.values["held_slot_share"] < 100.0
     assert "attention" not in run.facts
     # 32 tokens are outside the kernel's contract: no blocks, no products
     assert run.facts["latent"] == {"layers": 5, "blocks": None,

@@ -73,8 +73,8 @@ def test_new_cells_report_what_the_issue_names(manifest):
     olmoe = {m["name"] for m in harness.Cell(manifest, OLMOE).per_layer}
     assert olmoe >= {"moe_device_ms", "moe_dispatch_device_ms",
                      "moe_experts_roofline", "expert_load_max_over_mean",
-                     "step_device_ms.moe", "train_mfu.moe",
-                     "device_idle_share.moe", "compiles_in_window",
+                     "step_device_ms", "train_mfu",
+                     "device_idle_share.train", "compiles_in_window",
                      "moe_attn_device_ms", "moe_head_loss_device_ms"}
     assert not olmoe & {"attn_device_ms", "head_loss_device_ms"}
     assert [w["chips"] for w in manifest["workloads"]].count(4) == 1
@@ -148,7 +148,7 @@ def test_the_expert_step_matches_its_plain_reference():
     assert run.values["expert_load_max_over_mean"] >= 1.0
     # no trace on the CPU: the scope readers report nothing and do not raise
     for name in ("moe_device_ms", "moe_dispatch_device_ms",
-                 "moe_experts_roofline", "step_device_ms.moe",
+                 "moe_experts_roofline", "step_device_ms",
                  "moe_attn_device_ms", "moe_head_loss_device_ms"):
         assert run.values[name] is None
 

@@ -3,12 +3,7 @@ program without the counter, the manifest's entry (asserted BY NAME AND BY
 CONTENT and never by its place in `per_layer`, so the next PR's append
 falsifies nothing here), the granite cell's list of metrics, and a CPU
 rehearsal of the cell (the CPU backend leaves every scan to the plain path:
-0, printed without a value).
-
-This append falsifies one line of `test_lm_ssm_train_step.py::
-test_the_cell_reports_what_the_issue_names` (the cell's exact list of
-names); /conftest.py expects it to fail, and what it guards is asserted
-again here, by name."""
+0, printed without a value)."""
 
 import json
 import os
@@ -18,7 +13,7 @@ import pytest
 
 from yardstick import harness
 from test_generators import rehearse, run_py
-from test_lm_ssm_train_step import CELL, NEW, TAGGED
+from test_lm_ssm_train_step import CELL, NEW, SHARED
 
 KEY = "scan_kernel_lowerings"
 NAME = "scan_kernel_share"
@@ -69,9 +64,7 @@ def test_the_granite_cell_reports_it_and_no_other_cell_does(manifest):
     this metric in it: every name the cell had, each read by a reader."""
     cell = harness.Cell(manifest, CELL)
     names = [m["name"] for m in cell.per_layer]
-    (tag,) = {n.split(".", 1)[1] for n in names if "." in n}
-    had = ["compiles_in_window", "backend_start_s"] + NEW + [
-        f"{n}.{tag}" for n in TAGGED]
+    had = ["compiles_in_window", "backend_start_s"] + NEW + SHARED
     assert set(names) >= set(had) | {NAME} and len(set(names)) == len(names)
     for _spec, mod in cell.readers():
         assert hasattr(mod, "read")
