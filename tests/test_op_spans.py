@@ -40,9 +40,11 @@ def _sample(monkeypatch, rate):
     config.load(refresh=True)
 
 
-def _job(calls=CALLS, own_chip=True, dup=False):
-    """`calls` Allreduces of one DeviceBuffer pair on 4 ranks. Returns
-    (results by rank, pvar snapshot, per-signature auto-arm stats)."""
+def _job(calls=CALLS, own_chip=True, dup=False, persistent=False):
+    """`calls` Allreduces of one DeviceBuffer pair on 4 ranks (`persistent`:
+    rounds of one `Allreduce_init`, the lane that donates its accumulator).
+    Returns (results by rank, pvar snapshot, per-signature auto-arm
+    stats)."""
     out = {}
 
     def body():
@@ -56,8 +58,14 @@ def _job(calls=CALLS, own_chip=True, dup=False):
             device=dev)
         recv = MPI.DeviceBuffer(jnp.zeros(COUNT, jnp.float32, device=dev),
                                 device=dev)
+        req = MPI.Allreduce_init(send, recv, MPI.SUM, comm) \
+            if persistent else None
         for _ in range(calls):
-            MPI.Allreduce(send, recv, MPI.SUM, comm)
+            if persistent:
+                MPI.Start(req)
+                MPI.Wait(req)
+            else:
+                MPI.Allreduce(send, recv, MPI.SUM, comm)
         out[r] = np.asarray(recv.value)
 
     before = _auto_stats()
@@ -243,6 +251,182 @@ def test_watcher_stamps_the_device_end_across_devices(monkeypatch):
         assert all(got.count(r) == per_round for r in armed), name
     folds = sum(c["ingraph_folds"] for c in perfvars.snapshot()["comms"])
     assert folds == len(armed)
+
+
+def _one_device(monkeypatch):
+    """A one-chip host: every rank owns the same device."""
+    from tpu_mpi._runtime import SpmdContext
+    monkeypatch.setattr(SpmdContext, "device_for",
+                        lambda self, rank: jax.devices()[0])
+
+
+def _watches(monkeypatch):
+    """Every hand-over to the watcher, as (cid, round, rank, stages)."""
+    seen, watch = [], perfvars.watch
+
+    def recording(sc, t0, *stages):
+        seen.append((sc.cid, sc.round, sc.rank, stages))
+        watch(sc, t0, *stages)
+    monkeypatch.setattr(perfvars, "watch", recording)
+    return seen
+
+
+@pytest.mark.parametrize("persistent", [False, True],
+                         ids=["plain_fold", "donated-chain"])
+def test_one_device_fold_is_stamped_under_the_last_arriver(monkeypatch,
+                                                           persistent):
+    """Four rank threads on ONE device: a sampled armed round hands its
+    fold's output, and nothing else, to the watcher, which stamps
+    ``fold.done`` under the last arriver's ``op``, after its dispatch."""
+    _one_device(monkeypatch)
+    _sample(monkeypatch, 1)
+    seen = _watches(monkeypatch)
+    got, _snap, _sigs = _job(persistent=persistent)
+    want = np.arange(COUNT, dtype=np.float32) * sum(range(1, N + 1))
+    assert all(np.array_equal(got[r], want) for r in range(N))
+    spans = tracectx.drain()
+    rounds = _rounds(spans)
+    armed = {k: v for k, v in rounds.items()
+             if all(op["lane"] == "armed" for op, _ in v.values())}
+    assert len(armed) >= CALLS - 4
+    assert not [s for s in spans
+                if s["name"] in ("copy_in.done", "copy_out.done")]
+    for (cid, rnd), ranks in armed.items():
+        (last,) = [r for r, (op, _) in ranks.items() if op["last"]]
+        for r, (op, kids) in ranks.items():
+            done = [k for k in kids if k["name"] == "fold.done"]
+            assert len(done) == (r == last), (rnd, r)
+            if r != last:
+                continue
+            (fold,) = [k for k in kids if k["name"] == "fold_dispatch"]
+            assert done[0]["parent"] == op["span"]
+            # the span begins where the combine began. Its end is the
+            # device's: a CPU device can be done before the launching
+            # thread is back from its combine (microseconds before
+            # ``fold_dispatch`` ends; on the chip, 0.7 ms after)
+            assert fold["t0"] <= done[0]["t0"] <= fold["t1"]
+            assert done[0]["t0"] <= done[0]["t1"]
+            assert done[0]["t1"] >= fold["t1"] - 1e-3
+        # one watch a round, of the output alone: never the operands
+        (stages,) = [st for c, rd, _r, st in seen if (c, rd) == (cid, rnd)]
+        ((name, out),) = stages
+        assert name == "fold.done" and out.shape == (COUNT,)
+    compiled = {s["function"] for s in spans if s["name"] == "fold.compile"}
+    assert compiled == ({"plain_fold", "chain"} if persistent
+                        else {"plain_fold"})
+
+
+def test_a_slot_donated_before_the_watcher_reaches_it_goes_unstamped(
+        monkeypatch):
+    """The donated lane's accumulator slots alternate, a pair a rank: the
+    output of a rank's fold is donated again by the next fold but one that
+    the same rank dispatches. A watcher that comes too late finds it gone:
+    nothing is raised, that round has no ``fold.done``, and every round
+    whose output is still alive has its own."""
+    import threading
+    _one_device(monkeypatch)
+    _sample(monkeypatch, 1)
+
+    class Gate:                     # holds the watcher until the job is over
+        open = threading.Event()
+
+        def block_until_ready(self):
+            assert self.open.wait(60)
+
+    first = perfvars._OpScope()
+    first.cid, first.round, first.rank = "gate", 0, 0
+    perfvars.watch(first, time.monotonic(), ("gate.done", Gate()))
+    try:
+        _job(calls=24, persistent=True)
+    finally:
+        Gate.open.set()
+    _settle()
+    spans = tracectx.drain()
+    assert [s for s in spans if s["name"] == "gate.done"]
+    stamped = sorted(s["round"] for s in spans if s["name"] == "fold.done")
+    folded = {}                     # rank -> the rounds it dispatched
+    for s in spans:
+        if s["name"] == "op" and s["last"]:
+            folded.setdefault(s["rank"], []).append(s["round"])
+    alive = sorted(rnd for rounds in folded.values()
+                   for rnd in sorted(rounds)[-2:])
+    assert stamped == alive and len(alive) < 24
+    watcher = [t for t in threading.enumerate()
+               if t.name == "tpu_mpi-span-watcher"]
+    assert len(watcher) == 1 and watcher[0].is_alive()
+
+
+@pytest.mark.parametrize("placement", ["one-device", "four-devices"])
+def test_no_watcher_is_started_with_sampling_off(monkeypatch, placement):
+    if placement == "one-device":
+        _one_device(monkeypatch)
+    monkeypatch.setattr(perfvars, "_watch_q", None)     # as in a new process
+    seen = _watches(monkeypatch)
+    _job()
+    _job(calls=4, persistent=True)
+    assert perfvars._watch_q is None and not seen   # the queue comes with
+    assert tracectx.drain() == []                   # the thread
+
+
+@pytest.mark.parametrize("own_chip", [False, True],
+                         ids=["star", "exchange"])
+def test_four_devices_enqueue_one_watch_of_a_fold_a_round(monkeypatch,
+                                                          own_chip):
+    """Ranks on a device each: the exchange (operands on their own chips)
+    and the star (every operand on rank 0's: ranks 1 to 3 register the
+    single-chip fold, rank 0 the exchange, whose rounds fall back to the
+    generic fold, which is watched only where bytes crossed chips) each
+    hand a round's fold over once, not twice."""
+    _sample(monkeypatch, 1)
+    seen = _watches(monkeypatch)
+    _job(own_chip=own_chip)
+    _settle()
+    folds = [(cid, rnd) for cid, rnd, _r, stages in seen
+             if any(name == "fold.done" for name, _a in stages)]
+    assert len(folds) == len(set(folds))
+    spans = tracectx.drain()
+    armed = {(op["cid"], op["round"]) for op in spans
+             if op["name"] == "op" and op["lane"] == "armed"}
+    registered = {(op["cid"], op["round"]) for op in spans
+                  if op["name"] == "op" and op["lane"] == "armed"
+                  and op["last"] and (own_chip or op["rank"] != 0)}
+    assert len(armed) >= CALLS - 4 and registered
+    assert registered <= set(folds)
+    done = [(s["cid"], s["round"]) for s in spans if s["name"] == "fold.done"]
+    assert sorted(done) == sorted(folds)
+    if own_chip:        # the operands were ready, then the output
+        assert all([n for n, _a in st if n != "copy_out.done"]
+                   in (["copy_in.done", "fold.done"], [])
+                   for _c, _rd, _r, st in seen)
+
+
+def test_t_prev_follows_the_thread(monkeypatch):
+    """``t_prev`` on an ``op`` span is when the thread's previous host-path
+    op ended, whatever its communicator: absent on a thread's first."""
+    _one_device(monkeypatch)
+    _sample(monkeypatch, 1)
+
+    def body():
+        comm = MPI.COMM_WORLD
+        other = MPI.Comm_dup(comm)
+        dev = comm.device
+        send = MPI.DeviceBuffer(jnp.ones(COUNT, jnp.float32, device=dev),
+                                device=dev)
+        recv = MPI.DeviceBuffer(jnp.zeros(COUNT, jnp.float32, device=dev),
+                                device=dev)
+        for on in (comm, comm, other, comm):
+            MPI.Allreduce(send, recv, MPI.SUM, on)
+
+    run_spmd(body, N)
+    ops = [s for s in tracectx.drain() if s["name"] == "op"]
+    for r in range(N):
+        mine = sorted((o for o in ops if o["rank"] == r),
+                      key=lambda o: o["t0"])
+        assert len(mine) == 4 and len({o["cid"] for o in mine}) == 2
+        assert "t_prev" not in mine[0]
+        for before, after in zip(mine, mine[1:]):
+            assert after["t_prev"] == before["t1"] <= after["t0"]
+        assert mine[2]["cid"] != mine[1]["cid"] == mine[3]["cid"]
 
 
 def test_plan_register_once_per_signature(monkeypatch):

@@ -279,15 +279,23 @@ def _colocate(arrs: Sequence[Any], home: Any) -> list:
     return out
 
 
-def _watch_fold(operands: Sequence[Any], out: Any) -> None:
-    """The device's end of a fold whose operands crossed chips (see
-    ``perfvars.watch``): from the start of their ``colocate`` span to when
-    they had all arrived, and to when the fold's output was ready."""
+def _watch_fold(operands: Sequence[Any], out: Any,
+                t0: Optional[float] = None) -> None:
+    """The device's end of a fold (see ``perfvars.watch``), one watch a
+    round. Where operands crossed chips: from the start of their
+    ``colocate`` span to when they had all arrived, and to when the fold's
+    output was ready. Where none did, only a registered fold is watched
+    (it gives ``t0``, where its combine began): its output alone, never the
+    operands, so nothing of a rank's is held beyond the result it gets."""
     sc = _pv.scope()
-    if sc is not None and sc.tree and sc.moved_in is not None:
+    if sc is None or not sc.tree:
+        return
+    if sc.moved_in is not None:
         t0 = sc.nested[-1][1]       # the start of their ``colocate``
         _pv.watch(sc, t0, ("copy_in.done", list(operands)),
                   ("fold.done", out))
+    elif t0 is not None:
+        _pv.watch(sc, t0, ("fold.done", out))
 
 
 def _concat(parts: Sequence[Any], home: Any = None) -> Any:
@@ -1865,6 +1873,8 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
             is_jax_array(c) and tuple(c.shape) == (count,) and c.dtype == dt
             for c in cs)
         if good:
+            sc = _pv.scope()
+            t0 = _pv.monotonic() if sc is not None and sc.tree else None
             cs = _colocate(cs, home)
             slot = ring[k & 1] if donate else None
             # copy-out contract (auto-armed lane, ``donate=False``): the
@@ -1877,7 +1887,9 @@ def _registered_device_fold(op: Op, count: int, dtype: Any, size: int,
                 out = ring[k & 1] = donated(slot, *cs)
             else:
                 out = plain(*cs)
-            _watch_fold(cs, out)
+            # a donated slot may be donated again (round k + 2) before the
+            # watcher reaches it: that round then goes unstamped
+            _watch_fold(cs, out, t0)
             return [out] * n
         # a peer contributed a host / reshaped payload this round: generic
         total = _reduce_arrays(list(cs), op)

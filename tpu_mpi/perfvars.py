@@ -49,8 +49,11 @@ pieces:
   :func:`enter_channel`). From there to its end a sampled op also holds one
   ``jax.profiler.TraceAnnotation("tpu_mpi:<coll>")`` carrying ``mono_ns``,
   so a device profile and the spans share a clock, and one watcher thread
-  stamps when its copies between chips and the fold they feed were done on
-  the device (``copy_in.done``, ``fold.done``, ``copy_out.done``).
+  stamps when a registered fold's output was ready on the device
+  (``fold.done``, on one chip as across chips) and, where bytes crossed
+  chips, when the copies were (``copy_in.done``, ``copy_out.done``). The
+  ``op`` span carries ``t_prev``, when the thread's previous op ended: the
+  caller's own time between two ops.
 - **Timed spans** on the event IR: when tracing is on, the op scope opened
   here stamps the recorded :class:`~tpu_mpi.analyze.events.Event` with
   ``t_start``/``t_end`` and the phase spans the channels observed, which
@@ -113,6 +116,7 @@ class _TLS(threading.local):
     # class-attribute defaults: fresh threads read these without the
     # AttributeError/getattr-default dance on the hot path
     scope = None                      # the open _OpScope of this thread
+    t_prev = None                     # when this thread's last op ended
     setup = None                      # id of the open setup_span, if any
     tracing = 0                       # JAX traces open on this thread
     cache_read = None                 # the open compile's "hit" | "miss"
@@ -406,11 +410,14 @@ def op_end(sc: _OpScope, comm: Any = None, coll: Optional[str] = None,
                          for i, (name, s0, s1) in enumerate(sc.spans)
                          if name not in _tc.RDV_PARTS[1:]]
     if sc.tree:
+        # with ``t_prev``, when this thread's last op ended: what the caller
+        # did between the two is on the tree
         _tc.emit_op(coll or "op", sc.cid, sc.round, sc.rank, nbytes, sc.lane,
                     sc.last, sc.t0, t1, sc.t_ann,
                     tuple(sc.spans + sc.nested if sc.nested else sc.spans),
                     tuple(sc.moved_in) if sc.moved_in else None,
-                    tuple(sc.moved_out) if sc.moved_out else None)
+                    tuple(sc.moved_out) if sc.moved_out else None,
+                    _tls.t_prev)
     elif sc.trace is not None:
         # per-rank request span: the op bracket parents under the request
         # context, and each measured phase nests under the op span
@@ -423,6 +430,7 @@ def op_end(sc: _OpScope, comm: Any = None, coll: Optional[str] = None,
             pctx = _tc.TraceCtx(rec["trace"], rec["span"], True)
             for name, s0, s1 in sc.spans:
                 _tc.emit_span(pctx, name, who, s0, s1)
+    _tls.t_prev = t1
     if not enabled() or coll is None:
         return
     acct = _acct(comm)
@@ -820,17 +828,19 @@ def build_snapshot() -> dict:
                 "kernels": dict(sorted(_build_kernels.items()))}
 
 
-# -- the device's end of a copy between chips --------------------------------
+# -- the device's end of a fold and of a copy between chips ------------------
 #
-# A host span around an asynchronous copy times the enqueue. An op that
-# publishes its span tree and enqueued copies between chips hands the arrays
-# to ONE watcher thread, which waits for them in the order they came
+# A host span around an asynchronous launch or copy times the enqueue. An op
+# that publishes its span tree hands what it enqueued (a registered fold's
+# output on any lane; across chips the operands and the results too) to ONE
+# watcher thread, which waits for the arrays in the order they came
 # (``block_until_ready`` releases the GIL) and publishes when they were
 # done. The rank threads never wait for it. It holds the arrays alive
-# meanwhile, which is what ``trace_sample`` bounds: watching EVERY round of
-# the large-message star cost the four-chip cell 29% of its bandwidth (the
-# folding chip's memory fills a round sooner; 16.2 against 22.8 GB/s), one
-# round in 8 nothing that can be read (PERF.md section 6, PR 23).
+# meanwhile, and no longer, which is what ``trace_sample`` bounds: watching
+# EVERY round of the large-message star cost the four-chip cell 29% of its
+# bandwidth (the folding chip's memory fills a round sooner; 16.2 against
+# 22.8 GB/s), one round in 8 nothing that can be read (PERF.md section 6,
+# PR 23).
 
 _watch_q: Any = None
 
@@ -845,7 +855,7 @@ def _watch_loop(q: Any) -> None:
                 _tc.emit_round_span(name, cid, rnd, rank, t0, monotonic())
         except RuntimeError:    # the array was donated or deleted meanwhile:
             pass                # this round's remaining stages go unstamped
-        del stages
+        stages = arrays = None  # nothing is held while the queue is empty
 
 
 def watch(sc: _OpScope, t0: float, *stages: Tuple[str, Any]) -> None:

@@ -296,13 +296,16 @@ def emit_op(coll: str, cid: Any, rnd: Any, rank: int, nbytes: Optional[int],
             lane: str, last: bool, t0: float, t1: float, t_ann: float,
             phases: tuple,
             moved_in: Optional[tuple] = None,
-            moved_out: Optional[tuple] = None) -> None:
+            moved_out: Optional[tuple] = None,
+            t_prev: Optional[float] = None) -> None:
     """Publish one op's span tree as ONE compact record. ``t_ann``: by
     then the op's profiler annotation had begun. ``phases`` is the
     op scope's ``(name, t0, t1)`` list; ``moved_in`` / ``moved_out`` =
-    (bytes, copies) that ``colocate`` / ``copyout`` moved between chips."""
+    (bytes, copies) that ``colocate`` / ``copyout`` moved between chips;
+    ``t_prev`` = when the thread's previous op ended (None: it had none), so
+    ``t0 - t_prev`` is the caller's own time between the two."""
     _publish(("op", coll, cid, rnd, rank, nbytes, lane, last, t0, t1,
-              t_ann, phases, moved_in, moved_out), 2 + len(phases))
+              t_ann, phases, moved_in, moved_out, t_prev), 2 + len(phases))
 
 
 def emit_round_span(name: str, cid: Any, rnd: Any, rank: int, t0: float,
@@ -334,13 +337,15 @@ def emit_setup_span(name: str, t0: float, t1: float, who: str, sid: str,
 
 def _expand_op(rec: tuple) -> List[dict]:
     (_tag, coll, cid, rnd, rank, nbytes, lane, last, t0, t1, t_ann, phases,
-     moved_in, moved_out) = rec
+     moved_in, moved_out, t_prev) = rec
     who = f"rank {rank}"
     trace = op_id = op_span_id(cid, rnd, rank)
     out = [{"trace": trace, "span": op_id, "parent": None, "name": "op",
             "who": who, "t0": t0, "t1": t1, "status": "ok", "coll": coll,
             "cid": cid, "round": rnd, "rank": rank, "nbytes": nbytes,
             "lane": lane, "last": last, "t_ann": t_ann}]
+    if t_prev is not None:
+        out[0]["t_prev"] = t_prev
     def span(sid, parent, name, s0, s1):
         return {"trace": trace, "span": sid, "parent": parent, "name": name,
                 "who": who, "t0": s0, "t1": s1, "status": "ok", "cid": cid,
