@@ -88,6 +88,9 @@ FULL = {
     "conv": (2048, 2560, 512, 1536, (1024,)),
     # the delta-rule scan with a decay a key channel: seq, heads (of 128)
     "channel_scan": (1024, 4),
+    # a delta-rule mixer's per-head norms: seq, heads (of 128), the rank of
+    # the gate's product (two blocks of 256 tokens of the Kimi cell's rows)
+    "head_norm": (512, 32, 128),
     "max_new": 8,
 }
 # Toy sizes for the tier-1 CPU test only.
@@ -105,6 +108,7 @@ TINY = {
     "window_attn": (4, 2, 256, 128, 100),
     "conv": (256, 448, 128, 256, (128,)),
     "channel_scan": (128, 2),
+    "head_norm": (256, 2, 128),
     "max_new": 4,
 }
 
@@ -704,6 +708,60 @@ def leg_kernels(sz: dict, platform: str) -> dict:
              f"path by {rel:.3e} (limit {limit:.0e})")
         assert rel < limit, f"delta_scan[a decay a channel] {name} off by {rel}"
         facts["channel_scan_rel_err"][name] = rel
+    # -- a delta-rule mixer's per-head norms over rows --------------------------
+    # q's L2 norm with its scale, and the gated RMSNorm with the gate's
+    # product inside the kernel, against the same float32 arithmetic on the
+    # same bfloat16 operands: the result and every gradient. (On the chip
+    # alone is a head's lane offset a loop's index into real VMEM.)
+    from tpu_mpi.xla import head_norm_kernels
+    t, heads, rank = sz["head_norm"]
+    width = head_norm_kernels.HEAD_WIDTH
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    rows, dout, g_in, w_gate = (
+        (jax.random.normal(kk, shape, jnp.float32) * size).astype(jnp.bfloat16)
+        for kk, shape, size in zip(keys, (
+            (2, t, heads * width), (2, t, heads * width), (2, t, rank),
+            (rank, heads * width)), (1.0, 1.0, 1.0, rank ** -0.5)))
+    leaf = (1.0 + 0.1 * jax.random.normal(keys[4], (width,))).astype(
+        jnp.bfloat16)
+
+    def plain_norm(x, scale, *gate_from, mean, eps):
+        by_head = x.astype(jnp.float32).reshape(2, t, heads, width)
+        squares = jnp.square(by_head)
+        stat = jnp.mean(squares, -1, keepdims=True) if mean \
+            else jnp.sum(squares, -1, keepdims=True)
+        out = by_head * jax.lax.rsqrt(stat + eps) * scale.astype(jnp.float32)
+        if gate_from:
+            out = out * jax.nn.sigmoid(jnp.dot(
+                *gate_from, preferred_element_type=jnp.float32)).reshape(
+                    out.shape)
+        return out.reshape(x.shape)
+
+    def norm_with_grads(norm):
+        def run(dout, *operands):
+            out, vjp = jax.vjp(norm, *operands)
+            return (out,) + vjp(dout.astype(out.dtype))
+        return jax.jit(run)
+    facts["head_norm_rel_err"] = {}
+    for what, kernel, plain, operands in (
+            ("l2", lambda x: head_norm_kernels.l2_norm(
+                x, scale=width ** -0.5, interpret=interpret),
+             lambda x: plain_norm(x, jnp.float32(width ** -0.5), mean=False,
+                                  eps=1e-6), (rows,)),
+            ("gated", lambda *a: head_norm_kernels.gated_rms_norm(
+                *a, act="sigmoid", eps=1e-5, interpret=interpret),
+             lambda *a: plain_norm(*a, mean=True, eps=1e-5),
+             (rows, leaf, g_in, w_gate))):
+        got = timed(f"head_norm[{what}]", norm_with_grads(kernel), dout,
+                    *operands)
+        want = norm_with_grads(plain)(dout, *operands)
+        for name, a, b in zip(("out", "dx", "dscale", "dg", "dw"), got, want):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            # one rounding of the result; the product's cotangent enters
+            # the MXU rounded to bfloat16, as the plain path's does
+            assert rel < 2e-2, f"head_norm[{what}] {name} off by {rel}"
+            facts["head_norm_rel_err"][f"{what} {name}"] = rel
     # which route the program itself gives an expert layer here, and what
     # its compiled forward and backward hold
     route, calls = _expert_layer_route(sz["expert_layer"])

@@ -49,12 +49,15 @@ def test_leg_kernels():
         "ring_allgather", "ring_allreduce", "ring_reduce_scatter",
         "pairwise_alltoall", "collective_permute", "ring_attention",
         "grouped_matmul", "grouped_row_sums", "causal_attention",
-        "conv_silu", "delta_scan"}
+        "conv_silu", "delta_scan", "head_norm"}
     assert facts["row_sums_rel_err"] < 1e-5
     assert set(facts["conv_rel_err"]) == {"out", "dx", "dw", "dbias"}
     assert set(facts["channel_scan_rel_err"]) == {"o", "dq", "dk", "dv",
                                                   "dg", "dbeta"}
     assert set(facts["window_attention_rel_err"]) == {"out", "dq", "dk", "dv"}
+    assert set(facts["head_norm_rel_err"]) == {
+        "l2 out", "l2 dx", "gated out", "gated dx", "gated dscale",
+        "gated dg", "gated dw"}
     # the grouped product ran (interpreted) against lax.ragged_dot; on the
     # CPU backend the program itself would select `lax.ragged_dot`
     assert set(facts["grouped_matmul_rel_err"]) == {"out", "d_lhs", "d_rhs"}

@@ -282,25 +282,28 @@ def test_the_counter_counts_once_a_traced_call(word, who, kernel_backend):
         "kernel": 0, "plain": 0}
 
 
+def traced_primitives(jaxpr, above=""):
+    """(name stack, primitive) of every equation of a traced program, a
+    `pallas_call` by its kernel's name, the kernels' bodies not entered; an
+    inner program (a jitted kernel's among them) once a call site."""
+    for eqn in jaxpr.eqns:
+        stack = f"{above}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            yield stack, str(eqn.params["name"])
+            continue
+        inner = list(jax.core.jaxprs_in_params(eqn.params))
+        for sub in inner:
+            yield from traced_primitives(sub, stack)
+        if not inner:
+            yield stack, eqn.primitive.name
+
+
 def conv_scope_primitives(cfg, word, kernel_backend):
     """(forward, backward): the primitives under a one-layer model's
     `mixer/conv` scope in the traced gradient of its trunk, with the kernels
-    chosen under ``word``; a `pallas_call` by its kernel's name, the kernels'
-    bodies not entered. For the mixers' tests (`test_ssm_layer`,
+    chosen under ``word``. For the mixers' tests (`test_ssm_layer`,
     `test_gdn_layer`, `test_sambay_layer`)."""
     from tpu_mpi.models import transformer as tf
-
-    def walk(jaxpr, above=""):
-        for eqn in jaxpr.eqns:
-            stack = f"{above}/{eqn.source_info.name_stack}"
-            if eqn.primitive.name == "pallas_call":
-                yield stack, str(eqn.params["name"])
-                continue
-            inner = list(jax.core.jaxprs_in_params(eqn.params))
-            for sub in inner:
-                yield from walk(sub, stack)
-            if not inner:
-                yield stack, eqn.primitive.name
     params = tf.transformer_init(jax.random.key(0), cfg)
     tokens = jnp.zeros((1, cfg.max_seq), jnp.int32)
     tf._block_traced_once.cache_clear()
@@ -309,7 +312,7 @@ def conv_scope_primitives(cfg, word, kernel_backend):
             lambda p: tf._trunk(cfg, p, tokens)[0].astype(jnp.float32).sum()
         ))(params)
     tf._block_traced_once.cache_clear()
-    found = [(s, p) for s, p in walk(traced.jaxpr)
+    found = [(s, p) for s, p in traced_primitives(traced.jaxpr)
              if "mixer" in s and "/conv" in s]
     return ({p for s, p in found if "transpose(" not in s},
             {p for s, p in found if "transpose(" in s})

@@ -817,6 +817,34 @@ def _the_convolution_is_its_kernels(hlo: str, names, layers, parts: int,
                 and "transpose(" not in n]
 
 
+def _the_norms_are_their_kernels(hlo: str, layers, forwards: dict) -> None:
+    """In a compiled step's text: under each delta-rule layer's `mixer/prep`
+    the L2 kernel, q's and k's, ``forwards["l2"]`` times forward and twice
+    backward, under its `mixer/gate_norm` the gated kernel
+    ``forwards["gated"]`` times forward and once backward, in no other
+    scope; and no `copy` or `transpose` of an array as large as a [8192,
+    2048] row's in those layers' `conv`, `prep`, `scan`, `gate_norm` or
+    `out_proj`: the kernels hand one another the rows as they are."""
+    import re
+    calls = re.findall(r"(?m)^.*custom_call_target=\"tpu_custom_call\".*"
+                       r"op_name=\"([^\"]*head_(\w+)_norm_(\w+)/[^\"]*)\"", hlo)
+    assert all("/mixer/" in name and f"/{scope}/" in name
+               for name, kind, _d in calls
+               for scope in [{"l2": "prep", "gated": "gate_norm"}[kind]])
+    assert sorted((kind, d, int(re.search(r"layer_(\d+)", name).group(1)))
+                  for name, kind, d in calls) == sorted(
+        (kind, d, i) for kind, back in (("l2", 2), ("gated", 1))
+        for d, n in (("bwd", back), ("fwd", forwards[kind]))
+        for i in layers for _ in range(n))
+    moved = re.findall(
+        r"(?m)^\s*(?:ROOT\s+)?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+        r"(?:copy|transpose|reshape)\(.*op_name=\"([^\"]*/mixer/[^\"]*)\"", hlo)
+    assert not [(dims, name) for dims, name in moved
+                if np.prod([int(d) for d in dims.split(",")]) >= 8192 * 2048
+                and any(f"/{scope}/" in name for scope in (
+                    "conv", "prep", "scan", "gate_norm", "out_proj"))]
+
+
 def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
         v5e_2x2, kernel_backend):
     """The benchmark's Qwen3-Next-80B-A3B share (yardstick/configs/
@@ -839,7 +867,7 @@ def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
     import re
     kernel_backend("mosaic")
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from tpu_mpi import xla
+    from tpu_mpi import perfvars, xla
     from tpu_mpi.models import transformer as tf
     from tpu_mpi.models.transformer import (TransformerConfig,
                                             transformer_init,
@@ -853,6 +881,7 @@ def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
     cfg = TransformerConfig(**fields)
     mesh = xla.make_mesh(dict(conf["mesh"]), devices=v5e_2x2[:1])
     tf._block_traced_once.cache_clear()
+    perfvars.reset()
     step, specs = transformer_train_step(cfg, mesh, lr=conf["lr"], donate=True)
     shapes = jax.eval_shape(lambda k: transformer_init(k, cfg),
                             jax.random.key(0))
@@ -898,10 +927,18 @@ def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
         "causal_attention_bwd", "causal_attention_fwd", "conv_silu_bwd",
         "conv_silu_fwd", "delta_scan_bwd", "delta_scan_fwd",
         "grouped_matmul_dlhs", "grouped_matmul_drhs", "grouped_matmul_fwd",
-        "grouped_row_sums"]
+        "grouped_row_sums", "head_gated_norm_bwd", "head_gated_norm_fwd",
+        "head_l2_norm_bwd", "head_l2_norm_fwd"]
     # q's, k's and v's; forward again for q and k alone, v is kept
     _the_convolution_is_its_kernels(hlo, names, (0, 1, 2, 4, 5, 6), 3,
                                     again=2)
+    # q's and k's L2 norms and the gated norm once each way: what the
+    # recomputed operands' norms give again nobody reads (the scan keeps
+    # its operands, a norm's backward reads the convolution's output alone)
+    _the_norms_are_their_kernels(hlo, (0, 1, 2, 4, 5, 6),
+                                 {"l2": 2, "gated": 1})
+    assert perfvars.snapshot()["head_norm_lowerings"] == {
+        "kernel": 3, "plain": 0}        # one trace of the delta-rule block
     assert kernels.count("causal_attention_fwd") == \
         kernels.count("causal_attention_bwd") == 1, kernels
     assert kernels.count("delta_scan_fwd") == \
@@ -914,6 +951,7 @@ def test_compiled_for_the_v5e_the_delta_rule_step_fits_one_chip(
     assert all("/mixer/scan/" in name for name, _d in scans)
     assert "while" not in "".join(n for n in names if "/mixer/scan/" in n)
     tf._block_traced_once.cache_clear()
+    perfvars.reset()
 
 
 def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
@@ -975,6 +1013,7 @@ def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
     snap = perfvars.snapshot()
     assert snap["delta_kernel_lowerings"] == {"kernel": 2, "plain": 0}
     assert snap["delta_decays"] == {"head": 0, "channel": 2}
+    assert snap["head_norm_lowerings"] == {"kernel": 6, "plain": 0}
     assert snap["attn_kinds"] == {"latent": "fused"}
     assert snap["rope_forms"] == {"dense": 0, "halves": 0}
     compiled = lowered.compile()
@@ -1014,10 +1053,15 @@ def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
         "causal_attention_bwd", "causal_attention_fwd", "conv_silu_bwd",
         "conv_silu_fwd", "delta_channel_scan_bwd", "delta_channel_scan_fwd",
         "grouped_matmul_dlhs", "grouped_matmul_drhs", "grouped_matmul_fwd",
-        "grouped_row_sums"]
+        "grouped_row_sums", "head_gated_norm_bwd", "head_gated_norm_fwd",
+        "head_l2_norm_bwd", "head_l2_norm_fwd"]
     # q's, k's and v's; forward again for all three: the half is recomputed
     _the_convolution_is_its_kernels(hlo, names, (0, 1, 2, 4, 5, 6), 3,
                                     again=3)
+    # and so are the norms: q's and k's twice forward each, the gated norm
+    # twice, not three times (it is no recomputed function of its own)
+    _the_norms_are_their_kernels(hlo, (0, 1, 2, 4, 5, 6),
+                                 {"l2": 4, "gated": 2})
     assert kernels.count("causal_attention_fwd") == \
         kernels.count("causal_attention_bwd") == 1, kernels
     # the scan's two kernels once each a KDA layer (the recomputed half keeps
@@ -1035,6 +1079,50 @@ def test_compiled_for_the_v5e_the_kda_step_fits_one_chip(v5e_2x2,
         n for n in names if "/mixer/" in n and "/scan/" in n)
     tf._block_traced_once.cache_clear()
     perfvars.reset()
+
+
+@pytest.mark.parametrize("what, width, gate", [
+    ("kimi's q and k", 4096, None),
+    ("qwen3-next's q and k", 2048, None),
+    ("qwen3-next's gated norm", 4096, "silu"),
+    ("kimi's gated norm", 4096, "sigmoid")])
+def test_compiled_for_the_v5e_the_head_norm_is_one_kernel_each_way(
+        what, width, gate, v5e_2x2):
+    """The per-head norms' kernel pair at the widths of the benchmark's two
+    delta-rule cells (one sequence of 8192 tokens, bfloat16: the L2 norm of
+    Kimi's 32 and Qwen3-Next's 16 key heads, the gated RMSNorm of 32 value
+    heads with z as rows and with the gate's 128-wide product inside the
+    kernel) lowers through Mosaic forward and backward (a lane offset that
+    is a loop's index, the product's three forms on the MXU, d w summed in
+    its float32 output block): a kernel each way and no loop of XLA's, and
+    the gradients come back with their operands' shapes and types."""
+    from jax.sharding import SingleDeviceSharding
+    from tpu_mpi.xla import head_norm_kernels as hk
+    one = SingleDeviceSharding(v5e_2x2[0])
+    bf16 = jnp.bfloat16
+    rows = (1, 8192, width)
+    shapes = {None: (rows,), "silu": (rows, (128,), rows),
+              "sigmoid": (rows, (128,), (1, 8192, 128), (128, width))}[gate]
+    operands = tuple(jax.ShapeDtypeStruct(shape, bf16, sharding=one)
+                     for shape in shapes)
+
+    def norm(x, *rest):
+        if gate is None:
+            return hk.l2_norm(x, scale=128 ** -0.5, interpret=False)
+        return hk.gated_rms_norm(x, *rest, act=gate, eps=1e-6,
+                                 interpret=False)
+
+    def both(*a):
+        out, back = jax.vjp(norm, *a)
+        return out, back(jnp.ones_like(out))
+    compiled = jax.jit(both).lower(*operands).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert " while(" not in hlo
+    out, grads = compiled.out_info
+    assert (out.shape, out.dtype) == (rows, bf16)
+    assert [(o.shape, o.dtype) for o in grads] \
+        == [(o.shape, o.dtype) for o in operands]
 
 
 def test_compiled_for_the_v5e_the_attention_kernel_with_values_wider_than_scores(

@@ -546,6 +546,21 @@ def test_a_delta_layers_conv_scope_holds_the_kernel_where_selected(
         gdn_value_dim=128, gdn_chunk=64), kernel_backend)
 
 
+def test_a_delta_layers_norms_are_the_kernels_where_selected(kernel_backend):
+    """At heads of 128 lanes (two key heads, four value heads, 128 tokens) the
+    traced gradient holds the L2 kernel for q and k under `mixer/prep`
+    (twice forward, twice backward) and the gated kernel under
+    `mixer/gate_norm` (once each way), no reciprocal root of XLA's in
+    either, and no float32 [1, t, heads, 128] array is turned in the
+    lowered module; on the CPU the plain arithmetic and no kernel."""
+    from test_head_norm_kernel import check_the_norm_scopes
+    check_the_norm_scopes(dataclasses.replace(
+        CFG, n_layers=1, mixer_kinds=("gdn",), remat_layers=(), max_seq=128,
+        gdn_key_heads=2, gdn_key_dim=128, gdn_value_heads=4,
+        gdn_value_dim=128, gdn_chunk=64, dtype=jnp.bfloat16), kernel_backend,
+        {"head_l2_norm_fwd": 2, "head_gated_norm_fwd": 1})
+
+
 # -- what is refused ------------------------------------------------------------
 
 @pytest.mark.parametrize("fields, match", [

@@ -619,6 +619,23 @@ def test_a_kda_layers_scopes_are_in_its_program(halves):
         assert f"/{scope}/" in text, scope
 
 
+def test_a_kda_layers_norms_are_the_kernels_where_selected(kernel_backend):
+    """At heads of 128 lanes (two heads, a rank of 128, 128 tokens) the
+    traced gradient holds the L2 kernel for q and k under `mixer/prep`
+    (four times forward: the half is recomputed; twice backward) and the
+    gated kernel, the gate's product inside it, under `mixer/gate_norm`
+    (twice forward, not three times: no recomputation of its own; once
+    backward), no reciprocal root of XLA's in either, and no float32 [1,
+    t, heads, 128] array is turned in the lowered module; on the CPU the
+    plain arithmetic and no kernel."""
+    from test_head_norm_kernel import check_the_norm_scopes
+    check_the_norm_scopes(dataclasses.replace(
+        CFG, n_layers=1, mixer_kinds=("kda",), ffn_kinds=("dense",),
+        remat_layers=(), max_seq=128, gdn_key_heads=2, gdn_key_dim=128,
+        gdn_value_heads=2, gdn_value_dim=128, gdn_chunk=64, kda_rank=128,
+        dtype=jnp.bfloat16), kernel_backend, {"head_l2_norm_fwd": 4, "head_gated_norm_fwd": 2})
+
+
 def test_the_backward_pass_keeps_the_states_alone(halves):
     """Of a KDA layer's half the backward pass keeps, beside its inputs, the
     state before each chunk: no [t, heads x key width] array (the decay, the

@@ -46,6 +46,7 @@ FRESH = {
     "delta_kernel_lowerings": {"kernel": 0, "plain": 0},
     "delta_decays": {"head": 0, "channel": 0},
     "conv_kernel_lowerings": {"kernel": 0, "plain": 0},
+    "head_norm_lowerings": {"kernel": 0, "plain": 0},
     "head_loss_lowerings": {"blocked": 0, "whole": 0},
     "head_loss_blocks": {},
 }
@@ -143,6 +144,20 @@ def _conv():
                                  cuts=(128,))
 
 
+def _head_norm():
+    x = jnp.zeros((1, 128, 256), F32)
+    return lambda: tf._l2_normed_rows(x, 2)
+
+
+def _gated_head_norm():
+    o, g_in = jnp.zeros((1, 128, 2, 128), F32), jnp.zeros((1, 128, 128), F32)
+    cfg = tf.TransformerConfig(vocab=64, d_model=256, n_heads=2, n_layers=1,
+                               d_ff=64, max_seq=128, dtype=F32)
+    return lambda: tf._head_norm_gated(cfg, o, jnp.ones((128,), F32),
+                                       "sigmoid", g_in,
+                                       jnp.zeros((128, 256), F32))
+
+
 # one small call of each choice, at a shape inside its kernel's contract
 CALLS = {
     "attention": (choice.ATTENTION, _attention),
@@ -154,6 +169,8 @@ CALLS = {
     "selective scan": (choice.SEL_SCAN, _sel_scan),
     "delta scan": (choice.DELTA_SCAN, _delta_scan),
     "convolution": (choice.CONV, _conv),
+    "head norm": (choice.HEAD_NORM, _head_norm),
+    "head norm, gated by a product": (choice.HEAD_NORM, _gated_head_norm),
 }
 
 
@@ -189,6 +206,7 @@ CONTRACTS = {
     "selective scan": (choice.SEL_SCAN, (512, 16, None), 2),
     "delta scan": (choice.DELTA_SCAN, (2, 1, 128, 128, 64, None), 5),
     "convolution": (choice.CONV, (128, 256, 4, None, 128, (128,)), 3),
+    "head norm": (choice.HEAD_NORM, (128, 256, 128, None, 128), 3),
 }
 
 

@@ -29,7 +29,7 @@ from ..parallel.ep import (grouped_products, held_row_buffer, moe_dropless,
 from ..parallel.ring import (fused_attention_selected, local_attention,
                              ring_attention)
 from ..parallel.tp import column_parallel, row_parallel
-from ..xla import choice
+from ..xla import choice, head_norm_kernels
 from ..xla import pallas_kernels as pk
 
 
@@ -1392,6 +1392,23 @@ def _l2_normed(x, eps: float = 1e-6):
                            + eps)
 
 
+def _l2_normed_rows(x, heads: int, scale: float = 1.0):
+    """The rows x [b, t, heads x width], of x's type, with each head's values
+    L2-normed (`_l2_normed`) and scaled by the constant ``scale``: float32
+    inside, rounded once. Heads of 128 lanes are one Pallas kernel each way
+    over the rows as they stand where a kernel can run (`choice.HEAD_NORM`,
+    `xla/head_norm_kernels.py`: its backward keeps x alone); else XLA's
+    passes over the four dimensions."""
+    b, t, width = x.shape
+    run = choice.decide(choice.HEAD_NORM, t, width, width // heads, x.dtype)
+    if run:
+        return head_norm_kernels.l2_norm(x, scale=scale,
+                                         interpret=run.interpret)
+    out = _l2_normed(x.reshape(b, t, heads, -1))
+    return (out if scale == 1.0 else out * scale).astype(x.dtype).reshape(
+        x.shape)
+
+
 def _delta_layer_alone(tp_axis: Optional[str], sp_axis: Optional[str]):
     """A delta-rule layer's state runs along the whole sequence and its
     convolution mixes a head's neighbours in time: `sp` > 1 and `tp` > 1 are
@@ -1404,16 +1421,40 @@ def _delta_layer_alone(tp_axis: Optional[str], sp_axis: Optional[str]):
                 f"sequence shards and its heads are not cut")
 
 
-def _head_norm_gated(cfg: TransformerConfig, o: jnp.ndarray, scale, gate,
+def _head_norm_gated(cfg: TransformerConfig, o: jnp.ndarray, scale, act: str,
                      *gate_from):
     """RMSNorm over each head's values of o [b, t, heads, width] (a plain
-    ``scale``) x ``gate(*gate_from)`` (float32, o's shape): norm first, gate
-    after. Recomputed in the backward pass from o and ``gate_from`` as they
-    stand: left to the compiler, a float32 copy of o is what it keeps."""
-    return _kept_as_rounded(jax.checkpoint(lambda o, *of: (
-        _rms_norm(o, of[-1], cfg.norm_eps).astype(jnp.float32)
-        * gate(*of[:-1]).reshape(o.shape)).astype(o.dtype))(
-            o, *gate_from, scale))
+    ``scale`` [width]) x ``act`` ("silu" or "sigmoid") of the gate's
+    float32 pre-activation: norm first, gate after. ``gate_from``: the
+    pre-activation as rows z [b, t, heads x width], or g_in [b, t, rank] and
+    w [rank, heads x width], whose product it is. Heads of 128 lanes are one
+    Pallas kernel each way over the rows where a kernel can run
+    (`choice.HEAD_NORM`, `xla/head_norm_kernels.py`): the product is taken
+    inside it, its backward keeps o, the scale and ``gate_from`` alone and
+    computes the statistics and the gate again, so nothing is recomputed
+    around it and its bfloat16 result needs no fence. On the plain path the
+    whole is recomputed in the backward pass from o and ``gate_from`` as
+    they stand (its own `jax.checkpoint`: left to the compiler, a float32
+    copy of o is what it keeps)."""
+    b, t, heads, width = o.shape
+    product = len(gate_from) == 2
+    run = choice.decide(
+        choice.HEAD_NORM, t, heads * width, width, o.dtype,
+        gate_from[0].shape[-1] if product else 0,
+        also=scale.shape == (width,) and all(g.dtype == o.dtype
+                                             for g in gate_from))
+    if run:
+        return head_norm_kernels.gated_rms_norm(
+            o.reshape(b, t, -1), scale, *gate_from, act=act,
+            eps=cfg.norm_eps, interpret=run.interpret).reshape(o.shape)
+
+    def plain(o, scale, *gate_from):
+        pre = jnp.dot(*gate_from, preferred_element_type=jnp.float32) \
+            if product else gate_from[0].astype(jnp.float32)
+        gate = jax.nn.silu(pre) if act == "silu" else jax.nn.sigmoid(pre)
+        return (_rms_norm(o, scale, cfg.norm_eps).astype(jnp.float32)
+                * gate.reshape(o.shape)).astype(o.dtype)
+    return _kept_as_rounded(jax.checkpoint(plain)(o, scale, *gate_from))
 
 
 def _gdn_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
@@ -1431,9 +1472,22 @@ def _gdn_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
     then RMSNorm over each head's values (`gdn_norm`, a plain scale) x
     silu(z): norm first, gate after, where `_ssm_mixer` gates first and
     norms the whole width; and the out-projection. Scopes: `in_proj`,
-    `conv`, `prep` (the cut into heads, the L2 norms, beta and g), `scan`,
-    `gate_norm`, `out_proj`. `sp` > 1 and `tp` > 1 are refused
-    (`_delta_layer_alone`)."""
+    `conv`, `prep` (the L2 norms, beta and g), `scan`, `gate_norm`,
+    `out_proj`. At heads of 128 lanes on a TPU the norms are the kernel pair
+    of `xla/head_norm_kernels.py` over the ROWS [b, t, heads x 128] the
+    convolution's kernels write and the scan's read (`_l2_normed_rows`,
+    `_head_norm_gated`): q, k, v and o are cut into heads by reshapes that
+    cancel against the scan's own, and no array is laid out again between
+    `conv` and `out_proj`. What runs again in the backward pass and what is
+    kept: the convolution and the L2 norms are one recomputed function of
+    the products as they stand (`scan_operands`), its results fenced as
+    rows: the convolution runs again for q and k, whose norms' backward
+    kernels read it and nothing else, and the norms' own results are not
+    computed again (the scan keeps its operands: the compiler drops what
+    nobody reads); the gated norm keeps o, z and its scale (the kernel's
+    inputs) and runs once each way, where the plain path recomputes it
+    under its own `jax.checkpoint`. `sp` > 1 and
+    `tp` > 1 are refused (`_delta_layer_alone`)."""
     _delta_layer_alone(tp_axis, sp_axis)
     from ..parallel import delta, ssm   # a program without such a layer
     #                                     pays no import for it
@@ -1456,24 +1510,24 @@ def _gdn_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
             parts = ssm.conv_silu(qkv, conv_w, cuts=(kw, 2 * kw))
         with jax.named_scope("prep"):
             q, k, v = parts
-            q, k = (_l2_normed(part.reshape(b, t, hk, cfg.gdn_key_dim))
-                    for part in (q, k))
-            q = (q * cfg.gdn_key_dim ** -0.5).astype(x.dtype)
             g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(f32) + dt_bias)
-            return (q, k.astype(x.dtype),
-                    v.reshape(b, t, hv, cfg.gdn_value_dim), g,
+            return (_l2_normed_rows(q, hk, cfg.gdn_key_dim ** -0.5),
+                    _l2_normed_rows(k, hk), v, g,
                     jax.nn.sigmoid(beta.astype(f32)))
     # recomputed in the backward pass from the products as they stand: the
     # convolution's output before and after silu and q and k before their
     # norms are 320 MB a layer at 8192 tokens that only elementwise ops read
-    operands = _kept_as_rounded(jax.checkpoint(scan_operands)(
+    # (q, k and v stay the ROWS the kernels write and the scan's read up to
+    # here: a fence over their four-dimensional form is an array of its own,
+    # a copy each way and layer, where the reshapes on either side cancel)
+    q, k, v, g, beta = _kept_as_rounded(jax.checkpoint(scan_operands)(
         qkv, beta, a, layer["conv_w"], layer["a_log"], layer["dt_bias"]))
     with jax.named_scope("scan"):
-        o = delta.delta_scan(*operands, cfg.gdn_chunk)
+        o = delta.delta_scan(
+            q.reshape(b, t, hk, -1), k.reshape(b, t, hk, -1),
+            v.reshape(b, t, hv, -1), g, beta, cfg.gdn_chunk)
     with jax.named_scope("gate_norm"):
-        o = _head_norm_gated(cfg, o, layer["gdn_norm"],
-                             lambda z: jax.nn.silu(z.astype(f32)),
-                             z.reshape(o.shape))
+        o = _head_norm_gated(cfg, o, layer["gdn_norm"], "silu", z)
     with jax.named_scope("out_proj"):
         return o.reshape(b, t, vw) @ layer["w_gdn_out"]
 
@@ -1497,7 +1551,11 @@ def _kda_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
     the stream that the backward pass computes again, keeping of it what
     the scan names `parallel.delta.KEPT` alone (the state before each
     chunk, so the chain over the chunks runs once each way; from the
-    kernels the scan's output too, so the forward kernel does): the decay
+    kernels the scan's output too, so the forward kernel does); the norms'
+    kernels (`_l2_normed_rows`, `_head_norm_gated`: rows in, rows out, the
+    gate's product inside) keep their inputs alone and hold no
+    recomputation of their own, so each runs forward twice, where the plain
+    gated norm's inner `jax.checkpoint` runs it three times: the decay
     is [t, heads x key width] float32, 134 MB a layer at 8192 tokens and 32
     heads of 128, the 12288-wide row 201 MB and the scan's and the gate's
     outputs 67 MB each, and with them kept the step of two periods does not
@@ -1519,11 +1577,10 @@ def _kda_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
         with jax.named_scope("conv"):
             q, k, v = ssm.conv_silu(qkv, layer["conv_w"], cuts=(w, 2 * w))
         with jax.named_scope("prep"):
-            q, k = (_l2_normed(part.reshape(b, t, cfg.gdn_key_heads,
-                                            cfg.gdn_key_dim))
-                    for part in (q, k))
-            q = (q * cfg.gdn_key_dim ** -0.5).astype(x.dtype)
-            k, v = k.astype(x.dtype), v.reshape(b, t, hv, cfg.gdn_value_dim)
+            q, k = (_l2_normed_rows(part, cfg.gdn_key_heads, scale).reshape(
+                b, t, cfg.gdn_key_heads, -1)
+                for part, scale in ((q, cfg.gdn_key_dim ** -0.5), (k, 1.0)))
+            v = v.reshape(b, t, hv, cfg.gdn_value_dim)
             beta = jax.nn.sigmoid(beta.astype(f32))
         with jax.named_scope("decay"):
             g = -jnp.exp(layer["a_log"])[:, None] * jax.nn.softplus(
@@ -1532,10 +1589,8 @@ def _kda_mixer(cfg: TransformerConfig, layer: dict, x: jnp.ndarray, *,
         with jax.named_scope("scan"):
             o = delta.delta_scan(q, k, v, g, beta, cfg.gdn_chunk)
         with jax.named_scope("gate_norm"):
-            o = _head_norm_gated(
-                cfg, o, layer["kda_norm"], lambda g_in, w_g: jax.nn.sigmoid(
-                    jnp.dot(g_in, w_g, preferred_element_type=f32)),
-                g_in, layer["w_kda_g"])
+            o = _head_norm_gated(cfg, o, layer["kda_norm"], "sigmoid", g_in,
+                                 layer["w_kda_g"])
         with jax.named_scope("out_proj"):
             return o.reshape(b, t, -1) @ layer["w_kda_out"]
     mine = ("ln1", "ln1_b", "w_kda_in", "w_kda_low", "conv_w", "a_log",

@@ -620,6 +620,10 @@ FAMILIES: Dict[str, Any] = {
     # `parallel.ssm.conv_silu`, a recurrent mixer's convolution and silu:
     # `xla/conv_kernels.py` or `causal_conv` and `jax.nn.silu`
     "conv_kernel_lowerings": ("kernel", "plain"),
+    # `models.transformer._l2_normed` and `_head_norm_gated`, a delta-rule
+    # mixer's per-head norms over rows: `xla/head_norm_kernels.py` or XLA's
+    # passes over [batch, t, heads, width]
+    "head_norm_lowerings": ("kernel", "plain"),
     # `models.transformer.head_loss` over blocks of tokens, or `_xent` of
     # the whole logits (the two pipelined steps), one count a traced loss
     "head_loss_lowerings": ("blocked", "whole"),

@@ -30,7 +30,8 @@ import jax
 
 from .. import perfvars
 from . import pallas_kernels as pk
-from . import conv_kernels, delta_kernels, sel_scan_kernels, ssm_kernels
+from . import (conv_kernels, delta_kernels, head_norm_kernels,
+               sel_scan_kernels, ssm_kernels)
 
 
 def backend() -> Optional[str]:
@@ -95,6 +96,9 @@ DELTA_SCAN = Choice(delta_kernels.delta_scan_selected,
 # `parallel.ssm.conv_silu`
 CONV = Choice(conv_kernels.conv_silu_blocks, "conv_kernel_lowerings",
               "kernel", "plain")
+# `models.transformer._l2_normed` and `_head_norm_gated`
+HEAD_NORM = Choice(head_norm_kernels.head_norm_blocks, "head_norm_lowerings",
+                   "kernel", "plain")
 
 
 class Run(NamedTuple):
